@@ -373,9 +373,10 @@ class ES:
             module = self.module
             table_data = self.table.data
 
-            def str_apply(shared, offs, c, obs):
+            def str_apply(shared, offs, c, obs, interpret):
                 return mlp_streamed_apply(
-                    module, shared, table_data, offs, c, obs, layer_offs
+                    module, shared, table_data, offs, c, obs, layer_offs,
+                    interpret=interpret,
                 )
 
         lr_apply, lr_spec = None, None
@@ -727,7 +728,7 @@ class ES:
                 # host_sync (D2H of the metrics).  sample/eval/update
                 # live inside the program; the split-path algorithms
                 # (novelty family) and the host/pooled engines emit them
-                # as real spans (docs/observability.md span taxonomy)
+                # as real spans (docs/observability.md span names)
                 with obs.phase("dispatch"):
                     self.state, metrics = self.engine.generation_step(
                         prev_state)

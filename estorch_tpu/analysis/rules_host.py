@@ -5,8 +5,8 @@ R17 unfenced-cross-host-barrier, R23 dropped-trace-context.
 
 R05 is the wedge class ``doctor.py`` exists to detect after the fact:
 a ``proc.wait()`` / ``proc.communicate()`` with no timeout turns a hung
-child into a hung training job — on a TPU pod that's a wedged tunnel
-window, not a stack trace.  Every wait on a subprocess must bound its
+child into a hung training job — on a TPU pod that's a silent stall,
+not a stack trace.  Every wait on a subprocess must bound its
 patience and escalate (kill, requeue, raise) itself.
 
 R06 is the bug family from rollout's ``_ci_takes_params``: when

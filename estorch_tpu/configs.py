@@ -157,18 +157,19 @@ def humanoid2d_device(**over):
     free-swinging arm counterweights — and the device-native stand-in for
     the MuJoCo-Humanoid configs (BASELINE config 3 stays on host/pooled).
 
-    obs_norm defaults ON (round-4 A/B, BENCHMARKS.md: Humanoid2D's obs
-    variance spans 165×, and normalization won 2/2 seeds on final mean
-    and AUC — passing round 3's 600-generation plateau by gen 80); pass
+    obs_norm defaults ON (an earlier round's learning-curve A/B:
+    Humanoid2D's obs variance spans 165×, and normalization won 2/2 seeds
+    on final mean and AUC — passing the raw-observation run's
+    600-generation plateau by gen 80); pass
     obs_norm=False for the raw-observation variant — including to
     RESTORE checkpoints saved before round 4 (the running stats are
     training state, so restore_checkpoint rejects an obs_norm
     mismatch).
 
-    obs_probe_episodes defaults to 4 here (round-5 A/B, BENCHMARKS.md:
-    4 probes tied 1 probe on one seed and found a 2.2× better optimum
-    on the other, at ~0.6% extra episode cost — the same faster-stats
-    lever as warmup).  The ENGINE default stays 1 (parity-minimal,
+    obs_probe_episodes defaults to 4 here (an earlier round's
+    learning-curve A/B: 4 probes tied 1 probe on one seed and found a
+    2.2× better optimum on the other, at ~0.6% extra episodes — the same
+    faster-stats lever as warmup).  The ENGINE default stays 1 (parity-minimal,
     goldens pinned); this is a recipe-level choice.  Unlike obs_norm,
     the probe count is NOT training state and restore does not gate on
     it — resuming a pre-round-5 run under this default accumulates
@@ -216,16 +217,18 @@ def humanoid2d_pop10k(**over):
     with rank-1 perturbations, running obs normalization, and a
     Humanoid-sized policy (256×256).
 
-    The engine-mode choices are evidence-driven (bench_ab_cpu.jsonl,
-    BENCHMARKS.md): at pop-10240 × 166k-params, `low_rank=1` measured 9.5×
-    the full-rank throughput with 3× less memory — the member noise state
-    drops from O(dim) to O(Σ(m+n)r) — and `obs_norm` measured +30-43%
-    held-out eval on real MuJoCo (3/3 HalfCheetah seeds).  The two compose
-    as of round 4 (normalization is an input-side transform, independent
-    of the noise representation).  eval_chunk bounds materialized member
-    weights the same way the bench's pop-10k point does.
-    obs_probe_episodes=4 per the round-5 probe-count A/B (see
-    humanoid2d_device)."""
+    Why these engine modes: `low_rank=1` shrinks the member noise state
+    from O(dim) to O(Σ(m+n)r), which is what lets 10240 members of a
+    256×256 policy fit; it beat the full-rank forward on the CPU mesh in
+    earlier rounds, and its standing against the other forwards on the
+    chip is not measured yet (ROADMAP S4).  `obs_norm` measured +30-43%
+    held-out eval on real MuJoCo (3/3 HalfCheetah seeds, pooled path).
+    The two compose (normalization is an input-side transform,
+    independent of the noise representation).  eval_chunk bounds
+    materialized member weights the same way the bench's pop-10k point
+    does.  obs_probe_episodes=4 per the probe-count A/B (see
+    humanoid2d_device).  This is the configuration `chip_smoke.py` trains
+    on the v5e: 75,018 parameters (Humanoid2D obs 25 → 256 → 256 → 10)."""
     from .envs import Humanoid2D
 
     return _planar_device(Humanoid2D(), 10240, (256, 256), 400, 2e-2,

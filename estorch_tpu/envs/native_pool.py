@@ -73,9 +73,11 @@ def _load_library() -> Optional[ctypes.CDLL]:
                         timeout=120,
                     )
         except (subprocess.SubprocessError, ImportError, OSError):
-            if not os.path.exists(_LIB_PATH):
-                return None
-            # stale-but-present: fall through and load it anyway
+            # no build, no library: a .so older than the tracked
+            # envpool.cpp implements some OTHER version of the envs, and
+            # loading it would pass every shape check while stepping
+            # different dynamics
+            return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError:

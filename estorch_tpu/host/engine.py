@@ -477,7 +477,7 @@ class HostEngine:
         from ..utils.fault import rank_weights_with_failures
 
         obs = self.telemetry
-        # span taxonomy (docs/observability.md): sample = per-generation
+        # span names (docs/observability.md): sample = per-generation
         # noise-offset derivation (cheap BY DESIGN — the shared-table
         # scheme regenerates ε instead of storing it; a fat sample span
         # here means that design broke); eval = every member rollout;

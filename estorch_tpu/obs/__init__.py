@@ -12,7 +12,7 @@ explain itself (docs/observability.md):
   failures, peak RSS — one snapshot per run;
 - **flight recorder + heartbeat** (`recorder.py`): ring buffer of recent
   spans/events + an atomically-rewritten last-known-state file that
-  bench.py, tpu_watch, and doctor read when a run stops answering;
+  bench.py, the supervisor, and doctor read when a run stops answering;
 - **sinks** (`sinks.py`): JSONL / TensorBoard / fan-out record writers
   (absorbed from ``utils.metrics``; old names still importable there);
 - **manifest** (`manifest.py`): config + jax version + device topology +

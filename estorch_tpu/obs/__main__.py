@@ -36,10 +36,11 @@ Subcommands (docs/observability.md):
       quantile are listed with a per-hop breakdown assembled from the
       store alone (obs/agg/traces.py owns the flags).
 
-  profile <run.jsonl> [--platform auto|cpu|tpu] [--json]
+  profile <run.jsonl> [--platform auto|cpu|DEVICE_KIND] [--json]
       Per-phase performance attribution (docs/observability.md
       "Profiling"): time share, achieved FLOP/s and bytes/s against the
-      platform roofline (v5e bf16 peak on TPU, a measured-GEMM
+      roofline (published peaks of the chip's device_kind, e.g.
+      "TPU v5 lite"; an unknown kind is an error; a measured-GEMM
       calibration on cpu), arithmetic intensity, MFU, and the compile
       ledger with the analytic-vs-XLA cross-check.  Degenerate inputs
       (phase-less records, truncated tail, zero compile events) degrade
@@ -153,10 +154,11 @@ def build_parser() -> argparse.ArgumentParser:
                              "run JSONL")
     pr.add_argument("jsonl", nargs="?", default=None,
                     help="run JSONL (one generation record per line)")
-    pr.add_argument("--platform", default="auto",
-                    choices=("auto", "cpu", "tpu"),
-                    help="roofline platform (auto: manifest.json beside "
-                         "the JSONL, else cpu)")
+    pr.add_argument("--platform", default="auto", metavar="WHICH",
+                    help="roofline: auto (the device_kind recorded in "
+                         "manifest.json beside the JSONL, else cpu), cpu, "
+                         "or a device_kind as jax reports it (e.g. "
+                         "'TPU v5 lite'); an unknown kind is an error")
     pr.add_argument("--manifest", default=None, metavar="PATH",
                     help="run manifest for platform auto-detection "
                          "(default: manifest.json beside the JSONL)")
@@ -334,8 +336,8 @@ def _cmd_trace(args) -> int:
 
 
 def _cmd_profile(args) -> int:
-    from .profile import (find_cost_model, format_profile, platform_roofline,
-                          profile_records)
+    from .profile import (device_roofline, find_cost_model, format_profile,
+                          platform_roofline, profile_records)
     from .profile.report import selfcheck as profile_selfcheck
 
     if args.selfcheck:
@@ -366,13 +368,20 @@ def _cmd_profile(args) -> int:
                 # per-device dicts; tolerate a bare dict too
                 if isinstance(devs, dict):
                     devs = [devs]
-                if any(str(d.get("platform", "")).lower() == "tpu"
-                       for d in devs if isinstance(d, dict)):
-                    platform = "tpu"
+                for d in devs:
+                    if (isinstance(d, dict)
+                            and str(d.get("platform", "")).lower() == "tpu"):
+                        platform = str(d.get("kind"))
+                        break
             except (OSError, ValueError) as e:
                 print(f"note: ignoring unreadable manifest {mf}: {e}",
                       file=sys.stderr)
-    roofline = platform_roofline(platform)
+    try:
+        roofline = (platform_roofline("cpu") if platform == "cpu"
+                    else device_roofline(platform))
+    except ValueError as e:
+        print(f"profile: {e}", file=sys.stderr)
+        return 3
     p = profile_records(records, roofline,
                         cost_model=find_cost_model(records))
     if args.as_json:

@@ -21,7 +21,7 @@ Deliberately stdlib-only and importable without jax (the ``obs
 profile`` CLI must diagnose runs from a wedged-runtime host, like every
 other obs surface).
 
-Phase mapping (docs/observability.md span taxonomy):
+Phase mapping (docs/observability.md span names):
 
 * ``sample`` — perturbation construction: table-row reads + scaled add;
 * ``eval``  — policy forwards over every member env-step;
@@ -35,7 +35,7 @@ from __future__ import annotations
 COST_MODEL_SCHEMA = 1
 
 # phases whose cost is the per-generation sum of every modeled phase —
-# the fused device program cannot be split host-side (spans taxonomy)
+# the fused device program cannot be split host-side (span names)
 FUSED_PHASES = ("device",)
 MODELED_PHASES = ("sample", "eval", "update")
 

@@ -1,19 +1,20 @@
-"""Platform rooflines: the denominators that make achieved rates honest.
+"""Rooflines: the denominators that make achieved rates honest.
 
-``obs profile`` divides per-phase achieved FLOP/s and bytes/s by a
-platform peak.  On TPU the peak is a datasheet fact (v5e bf16 MXU peak,
-HBM bandwidth — the same 197 TFLOP/s denominator bench.py has always
-used for ``mfu``).  On CPU there is no such number worth quoting: the
-"peak" of a loaded shared-core host is whatever it can actually do
-today — so the CPU roofline is MEASURED, not quoted: a short in-process
-GEMM (numpy → BLAS, the best compute this host offers python) and a
-large memcpy (stream bandwidth).  Every CPU-derived utilization is
-tagged ``cpu_calibrated`` so nobody mistakes "fraction of this host's
-measured GEMM rate" for an MFU against accelerator silicon.
+``obs profile`` divides per-phase achieved FLOP/s and bytes/s by a peak.
+For an accelerator the peak is a published fact about one kind of chip,
+so the table below is keyed by ``device_kind`` exactly as JAX reports it
+on the machine (``jax.devices()[0].device_kind``), each entry with the
+source of its numbers; a kind that is not in the table is an error, never
+a default.  On CPU there is no such number worth quoting: the "peak" of a
+loaded shared-core host is whatever it can actually do today — so the
+CPU roofline is MEASURED, not quoted: a short in-process GEMM (numpy →
+BLAS, the best compute this host offers python) and a large memcpy
+(stream bandwidth).  Every CPU-derived utilization is tagged
+``cpu_calibrated`` so nobody mistakes "fraction of this host's measured
+GEMM rate" for an MFU against accelerator silicon.
 
-Deliberately jax-free (numpy + stdlib): bench.py's driver and the
-``obs profile`` CLI both need a roofline on hosts where the device
-runtime is wedged.
+Deliberately jax-free (numpy + stdlib): the ``obs profile`` CLI reads a
+finished run's records and needs no device runtime.
 """
 
 from __future__ import annotations
@@ -22,17 +23,33 @@ import time
 
 import numpy as np
 
-# TPU v5e per-chip datasheet peaks: bf16 MXU FLOP/s (the bench.py
-# denominator since round 1) and HBM bandwidth
-V5E_BF16_PEAK_FLOPS = 197e12
-V5E_HBM_BYTES_PER_S = 819e9
-
-TPU_V5E_ROOFLINE = {
-    "platform": "tpu",
-    "basis": "tpu_v5e_bf16_peak",
-    "peak_flops_per_s": V5E_BF16_PEAK_FLOPS,
-    "peak_bytes_per_s": V5E_HBM_BYTES_PER_S,
+# device_kind (as `jax.devices()[0].device_kind` printed it on the chip
+# machine — chip_smoke.py's first lines) -> per-chip peaks and their source
+DEVICE_ROOFLINES = {
+    "TPU v5 lite": {
+        "device_kind": "TPU v5 lite",
+        "platform": "tpu",
+        "basis": "tpu_v5e_bf16_peak",
+        "peak_flops_per_s": 197e12,
+        "peak_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, \"TPU v5e\": 197 TFLOP/s "
+                  "bf16 and 819 GB/s of HBM bandwidth per chip",
+    },
 }
+
+
+def device_roofline(device_kind: str) -> dict:
+    """The published per-chip roofline of ``device_kind``.  Raises for a
+    kind the table does not hold: add the entry with its source before
+    reporting a utilization on new hardware."""
+    try:
+        return dict(DEVICE_ROOFLINES[device_kind])
+    except KeyError:
+        raise ValueError(
+            f"no roofline for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_ROOFLINES)} (obs/profile/roofline.py — add "
+            "the kind with the source of its peaks)") from None
+
 
 _CPU_CACHE: dict | None = None
 
@@ -82,20 +99,23 @@ def measure_cpu_roofline(budget_s: float = 0.25, gemm_n: int = 384,
 
 
 def platform_roofline(platform: str, measure: bool = True) -> dict:
-    """The roofline for ``platform``: datasheet on TPU, measured on CPU
-    (cached per process — the calibration GEMM should run once, not per
-    phase).  ``measure=False`` on CPU returns None-peaks with the
+    """The roofline of an OFF-CHIP run: measured on CPU (cached per
+    process — the calibration GEMM should run once, not per phase).
+    ``measure=False`` on CPU returns None-peaks with the
     ``cpu_calibrated`` basis, for callers that only want the tag.
 
-    Any OTHER platform (gpu, …) gets None-peaks and no basis: the host
-    GEMM calibration measures this host's CPU, and dividing an
-    accelerator's rate by it would produce exactly the dishonest
-    cross-silicon number the basis tag exists to prevent — rates-only
-    reporting is the honest answer until that platform gets its own
-    denominator."""
+    A TPU is not a platform-wide fact: its peaks come from
+    :func:`device_roofline`, by ``device_kind``.  Any OTHER platform
+    (gpu, …) gets None-peaks and no basis: the host GEMM calibration
+    measures this host's CPU, and dividing an accelerator's rate by it
+    would produce exactly the dishonest cross-silicon number the basis
+    tag exists to prevent — rates-only reporting is the honest answer
+    until that platform gets its own denominator."""
     global _CPU_CACHE
     if platform == "tpu":
-        return dict(TPU_V5E_ROOFLINE)
+        raise ValueError(
+            "a TPU roofline is keyed by device_kind: use "
+            "device_roofline(jax.devices()[0].device_kind)")
     if platform != "cpu":
         return {"platform": str(platform), "basis": None,
                 "peak_flops_per_s": None, "peak_bytes_per_s": None}

@@ -1,12 +1,12 @@
 """Flight recorder (ring buffer of recent spans/events) + heartbeat file.
 
-The failure mode these exist for: a run wedges — tunnel drop, hung env
-pool, deadlocked worker — and the only post-mortem evidence is a
+The failure mode these exist for: a run wedges — hung device runtime,
+hung env pool, deadlocked worker — and the only post-mortem evidence is a
 parent's ``timeout after 480s`` line.  The flight recorder keeps the
 last N span/event records in memory (dumpable on demand or at crash
 handlers); the heartbeat is the *externally visible* half: a tiny JSON
 file rewritten atomically at every phase transition, so any supervisor
-(bench.py stage parent, examples/tpu_watch.py, doctor.py) can read the
+(bench.py stage parent, resilience/supervisor.py, doctor.py) can read the
 last-known phase + generation + age of a child it cannot otherwise
 inspect.
 

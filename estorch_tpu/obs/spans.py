@@ -4,7 +4,7 @@ A *span* is one timed phase of a generation — ``sample`` / ``eval`` /
 ``update`` on the host and pooled backends, ``dispatch`` / ``device`` /
 ``host_sync`` on the fused device path (whose single XLA program cannot
 be split finer without de-fusing it; docs/observability.md has the full
-taxonomy).  Spans nest: a phase entered inside another is recorded under
+list of span names).  Spans nest: a phase entered inside another is recorded under
 ``parent/child`` (e.g. ``update/obsnorm_merge``), and the parent's time
 includes its children — per-phase *share* therefore sums top-level names
 only.
@@ -19,8 +19,9 @@ Overhead budget: a disabled Telemetry's ``phase()`` yields a cached
 no-op context manager (two attribute loads); an enabled one costs two
 ``perf_counter`` calls + dict update per span.  Heartbeat/file work only
 happens when a heartbeat path is configured (supervisors opt in via the
-``ESTORCH_OBS_HEARTBEAT`` env var).  Measured A/B: default-on spans are
-<2% of bench wall time (BENCHMARKS.md).
+``ESTORCH_OBS_HEARTBEAT`` env var).  The budget for default-on spans is
+<2% of generation wall time (``bench.py --obs-ab`` is the gate; not
+measured on the chip yet).
 """
 
 from __future__ import annotations

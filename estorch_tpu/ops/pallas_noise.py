@@ -5,25 +5,41 @@ The pure-JAX paths materialize per-member noise: the update reduction
 decomposed forward (models/decomposed.py) unravels a full (dim,) noise tree
 per member that then lives in HBM for the whole episode — O(population·dim)
 resident bytes at config-3 scale (10k × 166k ≈ 6.6 GB, more than a v5e's
-HBM).  These kernels never materialize ε: tiles are DMA'd from the table
-through double-buffered VMEM and consumed in place (ROADMAP item 1;
-SURVEY.md §7 design deltas 1/4).
+HBM).  These kernels never materialize ε: windows of the table are DMA'd
+through double-buffered VMEM and consumed in place (SURVEY.md §7 design
+deltas 1/4).
 
-Two kernels share the grid shape:
+What Mosaic dictates (learned by compiling for the v5e, not by reading):
+a noise row starts at an ARBITRARY offset of a 1-D f32 table, but a DMA
+out of HBM must start and end on the array's tiling — (8, 128) for the
+2-D f32 view, i.e. 1024-float boundaries.  So every kernel here
+
+1. views the table as ``(size/128, 128)`` (a bitcast, no copy),
+2. DMAs the ALIGNED window of rows that contains the wanted span
+   (:func:`_window`: start rounded down to a multiple of 8 rows, clamped
+   so the window never runs off the table), and
+3. realigns in VMEM (:func:`_shifted`): one dynamic lane rotate, a select
+   against the next row, one dynamic sublane rotate — after which flat
+   element k of the result is flat element k of the wanted span.
+
+Two kernels share that front end:
 
 - :func:`weighted_noise_sum` — the update reduction Σ_k w_k·ε_k.  Grid over
-  noise rows; each row is DMA'd once and FMA'd into a VMEM accumulator that
-  is only written back at the end.  Replaces gather→materialize→matvec with
-  a single streamed pass (no (chunk, dim) intermediates).
+  noise rows; each row's window is DMA'd once and FMA'd into a VMEM
+  accumulator that is only written back at the end.
 - :func:`population_noise_matvec` — the per-member noise term of the
   decomposed forward, y_i = c_i·(x_i @ E_i), with E_i = the member's table
-  slice viewed as a (d, h) matrix.  Grid over (members × row-blocks); each
-  row-block is one contiguous B·h-float DMA, consumed as B static AXPYs —
-  no reshape, no per-member weight materialization, ever.
+  slice viewed as a (d, h) matrix.  Grid over (members × row-blocks); the
+  realigned block W is a (rows, 128) FLAT view of E_i, contracted on the
+  MXU as ``A_i @ W`` with a small per-member coefficient matrix A_i built
+  from x_i outside the kernel.  That contraction can only express E's
+  columns when they line up with lanes: h a multiple of 128, or a divisor
+  of it.  Any other width (a 10-unit head) takes the gathered einsum — a
+  choice made from the static shape alone, identical on every platform.
 
-Both run in interpret mode on CPU (equivalence-tested against the pure-JAX
-paths in tests/test_pallas_noise.py) and compile to Mosaic on TPU.  The
-``interpret`` default follows the backend.
+``interpret`` is a required argument: the engine derives it from the
+platform of the mesh it runs on (never true on a TPU mesh), tests pass
+``True``.  Nothing here consults ``jax.default_backend()``.
 
 Relation to the param-sharded path: these kernels make TABLE noise
 never-materialized by streaming DMA; the sharded engine
@@ -45,9 +61,67 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
+SUBLANES = 8
+TILE = LANES * SUBLANES  # floats per (8, 128) f32 tile: the DMA alignment
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _round_up(a: int, b: int) -> int:
+    return _cdiv(a, b) * b
+
+
+def _table_rows(table_data: jax.Array, window_rows: int) -> int:
+    """Rows of the (rows, 128) table view, after checking the table can be
+    viewed that way and holds at least one DMA window."""
+    size = int(table_data.shape[0])
+    if size % TILE != 0:
+        raise ValueError(
+            f"the streamed-noise kernels view the table as (size/128, 128) "
+            f"f32 tiles; table size {size} is not a multiple of {TILE}")
+    rows = size // LANES
+    if rows < window_rows:
+        raise ValueError(
+            f"noise table of {size} floats is smaller than one DMA window "
+            f"({window_rows * LANES} floats); use a larger table_size")
+    return rows
+
+
+def _window(start, t_rows: int, window_rows: int):
+    """(first table row, flat shift) of the aligned DMA window holding the
+    span that begins at flat table index ``start``: the row is a multiple
+    of 8 (the HBM tiling) and clamped so ``row + window_rows`` stays on
+    the table — near the table's end the shift simply grows."""
+    row = jnp.minimum((start // TILE) * SUBLANES, t_rows - window_rows)
+    return pl.multiple_of(row, SUBLANES), start - row * LANES
+
+
+def _shifted(x: jax.Array, shift, rows_out: int) -> jax.Array:
+    """Rows ``[0, rows_out)`` of ``x`` (R, 128) advanced by ``shift`` flat
+    elements: ``out.ravel()[k] == x.ravel()[k + shift]``.  ``shift`` is a
+    traced scalar; the caller guarantees the span it asks for lies inside
+    ``x`` (wrapped-around elements only ever land past it)."""
+    n_rows = x.shape[0]
+    lane_shift = jax.lax.rem(shift, LANES)
+    row_shift = shift // LANES
+    # a[i, j] = x[i, (j + lane_shift) % 128]
+    a = pltpu.roll(x, jax.lax.rem(LANES - lane_shift, LANES), 1)
+    a_next = pltpu.roll(a, n_rows - 1, 0)  # a_next[i] = a[i + 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    b = jnp.where(lane < LANES - lane_shift, a, a_next)
+    c = pltpu.roll(b, jax.lax.rem(n_rows - row_shift, n_rows), 0)
+    return c[:rows_out, :]
+
+
+def _vmem_limit(window_rows: int) -> int | None:
+    """Scoped-VMEM request for a kernel whose largest live arrays are a
+    few window-sized buffers: the 16 MiB default up to ~0.5M floats per
+    window, more (of the v5e's 128 MiB) past it."""
+    need = 6 * window_rows * LANES * 4
+    return None if need <= (16 << 20) else min(need, 96 << 20)
 
 
 # --------------------------------------------------------------------------
@@ -55,16 +129,15 @@ def _default_interpret() -> bool:
 # --------------------------------------------------------------------------
 
 
-def _weighted_sum_kernel(dim: int):
-    """Kernel body factory (dim is static)."""
-
+def _weighted_sum_kernel(t_rows: int, window_rows: int, rows_out: int):
     def kernel(offs_ref, w_ref, table_ref, out_ref, buf, sem):
         i = pl.program_id(0)
         n = pl.num_programs(0)
 
         def dma(slot, row):
+            first, _ = _window(offs_ref[row], t_rows, window_rows)
             return pltpu.make_async_copy(
-                table_ref.at[pl.ds(offs_ref[row], dim)],
+                table_ref.at[pl.ds(first, window_rows), :],
                 buf.at[slot],
                 sem.at[slot],
             )
@@ -81,7 +154,8 @@ def _weighted_sum_kernel(dim: int):
 
         slot = jax.lax.rem(i, 2)
         dma(slot, i).wait()
-        out_ref[...] += w_ref[i] * buf[slot, :]
+        _, shift = _window(offs_ref[i], t_rows, window_rows)
+        out_ref[...] += w_ref[i] * _shifted(buf[slot], shift, rows_out)
 
     return kernel
 
@@ -92,36 +166,45 @@ def weighted_noise_sum(
     offsets: jax.Array,  # (n,) int32 row offsets
     weights: jax.Array,  # (n,) float32 weight per row
     dim: int,
-    interpret: bool | None = None,
+    interpret: bool,
 ) -> jax.Array:
     """Streamed Σ_k w_k·ε_k: one DMA per noise row, zero materialization.
 
     Drop-in for ops/gradient.py::rank_weighted_noise_sum (same contract);
-    VMEM cost is 3·dim floats (double buffer + accumulator), so it suits
-    dims up to ~1M params.  Callers with larger dims should keep the
-    chunked pure-JAX path.
+    VMEM cost is ~3·dim floats (double buffer + accumulator) plus the
+    realignment temporaries, so it suits dims up to ~1M params.  Callers
+    with larger dims should keep the chunked pure-JAX path.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     n = int(offsets.shape[0])
     if n == 0:
         return jnp.zeros((dim,), table_data.dtype)
+    rows_out = _round_up(_cdiv(dim, LANES), SUBLANES)
+    # any span [shift, shift + dim) with shift < TILE fits rows_out + 8 rows
+    window_rows = rows_out + SUBLANES
+    t_rows = _table_rows(table_data, window_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # offsets, weights
         grid=(n,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # table stays in HBM
-        out_specs=pl.BlockSpec((dim,), lambda i, *_: (0,), memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((rows_out, LANES), lambda i, *_: (0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, dim), table_data.dtype),
+            pltpu.VMEM((2, window_rows, LANES), table_data.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    return pl.pallas_call(
-        _weighted_sum_kernel(dim),
+    out = pl.pallas_call(
+        _weighted_sum_kernel(t_rows, window_rows, rows_out),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((dim,), table_data.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows_out, LANES), table_data.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(window_rows)),
         interpret=interpret,
-    )(offsets.astype(jnp.int32), weights.astype(table_data.dtype), table_data)
+    )(
+        offsets.astype(jnp.int32),
+        weights.astype(table_data.dtype),
+        table_data.reshape(t_rows, LANES),
+    )
+    return out.reshape(-1)[:dim]
 
 
 # --------------------------------------------------------------------------
@@ -129,37 +212,46 @@ def weighted_noise_sum(
 # --------------------------------------------------------------------------
 
 
-def _pick_row_block(d: int, h: int, budget_floats: int = 64 * 1024) -> int:
-    """Largest divisor of d whose B·h DMA fits the per-buffer budget.
+def lanes_regular(h: int) -> bool:
+    """True when a (d, h) matrix stored flat lines its columns up with the
+    128 lanes of a (rows, 128) view — the widths the streamed matvec
+    kernel can contract (see the module docstring)."""
+    return h % LANES == 0 or LANES % h == 0
 
-    Capped at 128 rows: the AXPY loop below unrolls B times, so an
-    unbounded B (e.g. a wide layer feeding a 1-unit head) would balloon
-    Mosaic compile time for no bandwidth gain.
-    """
+
+def _pick_row_block(d: int, h: int, budget_floats: int = 64 * 1024) -> int:
+    """Largest divisor of d whose B·h-float DMA fits the per-buffer budget
+    (256 KiB: two buffers plus the realignment temporaries stay far inside
+    scoped VMEM whatever the layer's size)."""
     best = 1
     for b in range(1, d + 1):
-        if d % b == 0 and b * h <= budget_floats and b <= 128:
+        if d % b == 0 and b * h <= budget_floats:
             best = b
     return best
 
 
-def _noise_matvec_kernel(d: int, h: int, block_rows: int, layer_offset: int):
-    n_blocks = d // block_rows
-
-    def kernel(offs_ref, c_ref, x_ref, table_ref, y_ref, buf, sem):
+def _noise_matvec_kernel(t_rows: int, window_rows: int, k_rows: int,
+                         block_floats: int, layer_offset: int):
+    def kernel(offs_ref, a_ref, table_ref, y_ref, buf, sem):
         i = pl.program_id(0)  # member
         k = pl.program_id(1)  # row block (inner axis)
         n_i = pl.num_programs(0)
+        n_k = pl.num_programs(1)
+
+        def window(member, blk):
+            return _window(
+                offs_ref[member] + layer_offset + blk * block_floats,
+                t_rows, window_rows)
 
         def dma(slot, member, blk):
-            start = offs_ref[member] + layer_offset + blk * (block_rows * h)
+            first, _ = window(member, blk)
             return pltpu.make_async_copy(
-                table_ref.at[pl.ds(start, block_rows * h)],
+                table_ref.at[pl.ds(first, window_rows), :],
                 buf.at[slot],
                 sem.at[slot],
             )
 
-        step = i * n_blocks + k
+        step = i * n_k + k
 
         @pl.when(step == 0)
         def _warmup():
@@ -169,13 +261,9 @@ def _noise_matvec_kernel(d: int, h: int, block_rows: int, layer_offset: int):
         # first block) while this one is consumed
         nxt = step + 1
 
-        @pl.when(nxt < n_i * n_blocks)
+        @pl.when(nxt < n_i * n_k)
         def _prefetch():
-            dma(
-                jax.lax.rem(nxt, 2),
-                nxt // n_blocks,
-                jax.lax.rem(nxt, n_blocks),
-            ).start()
+            dma(jax.lax.rem(nxt, 2), nxt // n_k, jax.lax.rem(nxt, n_k)).start()
 
         @pl.when(k == 0)
         def _init():
@@ -183,15 +271,41 @@ def _noise_matvec_kernel(d: int, h: int, block_rows: int, layer_offset: int):
 
         slot = jax.lax.rem(step, 2)
         dma(slot, i, k).wait()
-
-        # B static AXPYs against contiguous h-float views of the DMA'd
-        # block — the (B, h) matrix view never needs a reshape
-        acc = jnp.zeros((h,), y_ref.dtype)
-        for r in range(block_rows):
-            acc = acc + x_ref[0, r] * buf[slot, pl.ds(r * h, h)]
-        y_ref[0, :] += c_ref[i] * acc
+        _, shift = window(i, k)
+        w = _shifted(buf[slot], shift, k_rows)  # flat view of the E block
+        y_ref[0] += jnp.dot(a_ref[0, 0], w,
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     return kernel
+
+
+def _matvec_coefficients(x: jax.Array, d: int, h: int, block_rows: int,
+                         m_rows: int, k_rows: int) -> jax.Array:
+    """(n, n_blocks, m_rows, k_rows) coefficient matrices A with
+    ``A[i, b] @ W == `` the lane-layout of ``x[i, block b] @ E_block`` for
+    W the (k_rows, 128) flat view of that E block.
+
+    h = g·128: row q of W holds columns [128·(q%g), …) of E row q//g, so
+    A[m, q] = x[q//g]·[q%g == m] and output row m is y[128m : 128(m+1)].
+    128 = g·h: row q of W holds E rows q·g … q·g+g-1 side by side, so
+    A[m, q] = x[q·g + m] and y[j] = Σ_m out[m, m·h + j].
+    Rows/columns past the block are zero, so whatever the window holds
+    beyond the block (the next leaf's noise) contributes nothing."""
+    n = x.shape[0]
+    n_blocks = d // block_rows
+    xb = x.reshape(n, n_blocks, block_rows)
+    if h % LANES == 0:
+        g = h // LANES
+        a = jnp.einsum("nbr,mk->nbmrk", xb, jnp.eye(g, dtype=x.dtype))
+        a = a.reshape(n, n_blocks, g, block_rows * g)
+    else:
+        g = LANES // h
+        q = _cdiv(block_rows, g)
+        xb = jnp.pad(xb, ((0, 0), (0, 0), (0, q * g - block_rows)))
+        a = xb.reshape(n, n_blocks, q, g).transpose(0, 1, 3, 2)
+    return jnp.pad(a, ((0, 0), (0, 0), (0, m_rows - a.shape[2]),
+                       (0, k_rows - a.shape[3])))
 
 
 @partial(
@@ -206,7 +320,7 @@ def population_noise_matvec(
     layer_offset: int,  # this layer's kernel start WITHIN the member ε vector
     d: int,
     h: int,
-    interpret: bool | None = None,
+    interpret: bool,
     block_rows: int | None = None,
 ) -> jax.Array:
     """y[i] = c[i] · (x[i] @ E_i) with E_i streamed from the table.
@@ -214,50 +328,56 @@ def population_noise_matvec(
     ``E_i = table[offsets[i]+layer_offset : …+d·h]`` viewed row-major as
     (d, h) — exactly the layout ops/params.py's unravel gives a Dense
     kernel, so this reproduces models/decomposed.py's noise term without
-    materializing any member's noise tree.
+    materializing any member's noise tree.  Widths that are not
+    :func:`lanes_regular` gather their (small) E_i instead.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     n = int(x.shape[0])
+    dtype = table_data.dtype
+    offsets = offsets.astype(jnp.int32)
+    if not lanes_regular(h):
+        e = jax.vmap(lambda o: jax.lax.dynamic_slice(
+            table_data, (o + layer_offset,), (d * h,)))(offsets)
+        return c[:, None].astype(dtype) * jnp.einsum(
+            "nd,ndh->nh", x.astype(dtype), e.reshape(n, d, h),
+            precision=jax.lax.Precision.HIGHEST)
     if block_rows is None:
         block_rows = _pick_row_block(d, h)
     if d % block_rows != 0:
         raise ValueError(f"block_rows {block_rows} must divide d {d}")
-    if block_rows > 512:
-        raise ValueError(
-            f"block_rows {block_rows} would unroll {block_rows} AXPYs into "
-            "the kernel body; keep it <= 512 (auto-pick caps at 128)"
-        )
     n_blocks = d // block_rows
+    block_floats = block_rows * h
+    g = h // LANES if h % LANES == 0 else LANES // h
+    m_rows = _round_up(g, SUBLANES)
+    k_rows = _round_up(_cdiv(block_floats, LANES), SUBLANES)
+    window_rows = k_rows + SUBLANES
+    t_rows = _table_rows(table_data, window_rows)
+    a = _matvec_coefficients(x.astype(dtype), d, h, block_rows, m_rows, k_rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # offsets, c
+        num_scalar_prefetch=1,  # offsets
         grid=(n, n_blocks),
         in_specs=[
-            # x: one member's row-block per grid step — (1, B) in VMEM
-            pl.BlockSpec(
-                (1, block_rows), lambda i, k, *_: (i, k), memory_space=pltpu.VMEM
-            ),
+            pl.BlockSpec((1, 1, m_rows, k_rows),
+                         lambda i, k, *_: (i, k, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),  # table stays in HBM
         ],
-        out_specs=pl.BlockSpec(
-            (1, h), lambda i, k, *_: (i, 0), memory_space=pltpu.VMEM
-        ),
+        out_specs=pl.BlockSpec((1, m_rows, LANES), lambda i, k, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, block_rows * h), table_data.dtype),
+            pltpu.VMEM((2, window_rows, LANES), dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    return pl.pallas_call(
-        _noise_matvec_kernel(d, h, block_rows, layer_offset),
+    out = pl.pallas_call(
+        _noise_matvec_kernel(t_rows, window_rows, k_rows, block_floats,
+                             layer_offset),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, h), table_data.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, m_rows, LANES), dtype),
         interpret=interpret,
-    )(
-        offsets.astype(jnp.int32),
-        c.astype(table_data.dtype),
-        x.astype(table_data.dtype),
-        table_data,
-    )
+    )(offsets, a, table_data.reshape(t_rows, LANES))
+    if h % LANES == 0:
+        y = out[:, :g, :].reshape(n, h)
+    else:
+        y = jnp.einsum("nmmj->nj", out[:, :g, :].reshape(n, g, g, h))
+    return c[:, None].astype(dtype) * y
 
 
 # --------------------------------------------------------------------------
@@ -291,7 +411,7 @@ def mlp_streamed_apply(
     c: jax.Array,  # (n,) σ·sign
     obs: jax.Array,  # (n, obs_dim) population observation batch
     layer_offsets: dict[str, dict[str, int]],
-    interpret: bool | None = None,
+    interpret: bool,
 ) -> jax.Array:
     """Population-batched MLPPolicy forward, weights (shared + c·ε) with ε
     streamed from the table.

@@ -34,11 +34,10 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..envs.rollout import carry_init_takes_params, make_obs_probe, make_rollout
 from ..obs.spans import NULL_TELEMETRY
-from ..utils.backend import shard_map
 from ..ops.gradient import es_gradient, rank_weighted_noise_sum
 from ..ops.noise import NoiseTable, member_offsets, pair_signs, sample_pair_offsets
 from ..ops.params import ParamSpec
@@ -75,7 +74,9 @@ class EngineConfig:
     noise_kernel: bool = False  # Pallas streamed update reduction
     # (ops/pallas_noise.py): ε rows DMA'd from the HBM table through
     # double-buffered VMEM and FMA'd in place — no (chunk, dim)
-    # materialization. Interpret-mode off-TPU, Mosaic on-chip.
+    # materialization. Mosaic on a TPU mesh, the Pallas interpreter on any
+    # other (decided from the mesh's devices, never from the default
+    # backend).
     low_rank: int = 0  # >0: per-layer kernel noise E = A·Bᵀ/√r with r =
     # low_rank (ops/lowrank.py, PAPERS.md "ES at the Hyperscale"): member
     # noise state shrinks O(dim) → O(Σ(m+n)·r), the forward's noise term
@@ -208,6 +209,16 @@ class EvalResult(NamedTuple):
     steps: jax.Array  # () int32 — total alive env steps this generation
 
 
+def replicate_on_mesh(tree, mesh: Mesh):
+    """Commit every leaf of ``tree`` to ``mesh``, fully replicated — the
+    layout every replicated-engine program RETURNS its state in.  A state
+    built host-side (init, checkpoint restore) must enter in that same
+    layout: jit keys its executables on argument placement, so an
+    uncommitted first state would build the generation program once for
+    generation 0 and again for generation 1."""
+    return jax.device_put(tree, NamedSharding(mesh, P()))
+
+
 def _gen_keys(state: ESState) -> tuple[jax.Array, jax.Array]:
     """Per-generation streams: (offset key, rollout key). Identical everywhere."""
     base = jax.random.fold_in(state.key, state.generation)
@@ -251,9 +262,9 @@ def _bf16_io_apply(base_apply):
     """Observation/output dtype shim for the bf16 compute path.  Params must
     ALREADY be bf16 — they are cast ONCE per member where they are built
     (``_eval_local`` / center eval), never inside the per-step rollout scan,
-    so the steady-state episode loop is cast-free (round-1 VERDICT weak #6:
-    the old wrapper re-cast the whole weight pytree every policy call and
-    relied on XLA CSE to hoist it).  Output returns to float32."""
+    so the steady-state episode loop is cast-free (a wrapper that re-cast
+    the whole weight pytree every policy call would rely on XLA CSE to
+    hoist it).  Output returns to float32."""
 
     def wrapped(p, obs):
         _check_bf16_params(p)
@@ -419,6 +430,9 @@ class ESEngine:
         self.config = config
         self.mesh = mesh
         self.n_devices = mesh.devices.size
+        # the Pallas kernels compile through Mosaic on the chip this mesh
+        # is made of; anywhere else only the interpreter can run them
+        self._pallas_interpret = mesh.devices.flat[0].platform != "tpu"
         # Populations whose pair/member count does not divide the mesh are
         # PADDED up to the next multiple with zero-weighted ghost members:
         # ghosts re-evaluate clamped noise rows (values irrelevant), are
@@ -545,7 +559,7 @@ class ESEngine:
         # All inputs/outputs are fully replicated (P()); the population axis
         # only exists INSIDE the program (axis_index-derived shards).
         self._generation_step = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._generation_body,
                 mesh=mesh,
                 in_specs=(P(),),
@@ -555,7 +569,7 @@ class ESEngine:
         )
         # split path: evaluate, then apply host-computed weights
         self._evaluate = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._evaluate_body,
                 mesh=mesh,
                 in_specs=(P(),),
@@ -579,7 +593,7 @@ class ESEngine:
 
     def _build_update_programs(self):
         self._apply_weights = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._apply_weights_body,
                 mesh=self.mesh,
                 in_specs=(P(), P()),
@@ -800,7 +814,9 @@ class ESEngine:
                     obs_batch = normalize_obs(
                         obs_batch, state.obs_stats, float(self.config.obs_clip)
                     )
-                return self._streamed_apply(shared_tree, offs_c, c, obs_batch)
+                return self._streamed_apply(
+                    shared_tree, offs_c, c, obs_batch,
+                    interpret=self._pallas_interpret)
 
             res = self._rollout_batched(batched_apply, keys_c)
             return 0, (res.total_reward, res.bc, res.steps)
@@ -867,7 +883,8 @@ class ESEngine:
 
             row_w = _fold(w_local) if cfg.mirrored else w_local
             grad_local = weighted_noise_sum(
-                self.table.data, reduction_offs, row_w, dim=self.spec.dim
+                self.table.data, reduction_offs, row_w, dim=self.spec.dim,
+                interpret=self._pallas_interpret,
             ) / (cfg.population_size * state.sigma)
         elif cfg.mirrored:
             # local folded partial of the estimator; scaling commutes with psum
@@ -1017,14 +1034,14 @@ class ESEngine:
                 obs_stats = merge_obs_moments_np(
                     obs_stats, float(c), np.asarray(s), np.asarray(q)
                 )
-        return ESState(
+        return replicate_on_mesh(ESState(
             params_flat=params_flat,
             opt_state=self.optimizer.init(params_flat),
             key=key,
             generation=jnp.int32(0),
             sigma=jnp.float32(self.config.sigma),
             obs_stats=obs_stats,
-        )
+        ), self.mesh)
 
     def compile(self, state: ESState) -> float:
         """AOT-compile the fused generation program; returns seconds spent.
@@ -1136,7 +1153,7 @@ class ESEngine:
                 )
 
             self._noise_stats_progs[cache_n] = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=self.mesh, in_specs=(P(), P()),
                     out_specs=(P(), P()), check_vma=False,
                 )
@@ -1193,7 +1210,7 @@ class ESEngine:
                 return self._finish_update(state, grad_ascent)
 
             self._apply_weights_reuse_progs[cache_key] = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=self.mesh,
                     in_specs=(P(), P(), P(), P(), P(), P()),
                     out_specs=(P(), P()),

@@ -12,7 +12,7 @@ The hyperscale path (parallel/sharded.py, "Evolution Strategies at the
 Hyperscale", PAPERS.md arxiv 2511.16652) adds a second axis ``MODEL_AXIS``:
 a 2-D ``(pop, model)`` mesh where parameter leaves are sharded over
 ``model`` per regex partition rules (:func:`match_partition_rules`, the
-fmengine/EasyLM idiom — SNIPPETS.md [1]) and the population is sharded
+fmengine/EasyLM idiom) and the population is sharded
 over ``pop``, so neither the param tree nor any member's perturbation
 ever exists whole on one device.
 """
@@ -23,21 +23,31 @@ import re
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 POP_AXIS = "pop"
 MODEL_AXIS = "model"
 
 
+def _auto_mesh(shape: tuple[int, ...], names: tuple[str, ...],
+               devices: Sequence[jax.Device]) -> Mesh:
+    """Every mesh here has ``Auto`` axes: the engines place data with
+    ``shard_map``, ``with_sharding_constraint`` and ``NamedSharding``
+    operands, which ``jax.make_mesh``'s default ``Explicit`` axes refuse
+    (sharding would have to ride every array's type instead)."""
+    return jax.make_mesh(shape, names, (AxisType.Auto,) * len(names),
+                         devices=devices)
+
+
 def population_mesh(devices: Sequence[jax.Device] | None = None) -> Mesh:
     """1-D mesh over ``devices`` (default: all) with the population axis."""
     devs = list(devices) if devices is not None else jax.devices()
-    return jax.make_mesh((len(devs),), (POP_AXIS,), devices=devs)
+    return _auto_mesh((len(devs),), (POP_AXIS,), devs)
 
 
 def single_device_mesh(device: jax.Device | None = None) -> Mesh:
     dev = device if device is not None else jax.devices()[0]
-    return jax.make_mesh((1,), (POP_AXIS,), devices=[dev])
+    return _auto_mesh((1,), (POP_AXIS,), [dev])
 
 
 def hyperscale_mesh(
@@ -66,9 +76,8 @@ def hyperscale_mesh(
             f"mesh shape ({pop_shards}, {model_shards}) needs "
             f"{pop_shards * model_shards} devices, got {n}"
         )
-    return jax.make_mesh(
-        (pop_shards, model_shards), (POP_AXIS, MODEL_AXIS), devices=devs
-    )
+    return _auto_mesh((pop_shards, model_shards), (POP_AXIS, MODEL_AXIS),
+                      devs)
 
 
 def pairs_per_device(population_size: int, n_devices: int) -> int:
@@ -98,7 +107,7 @@ def padded_count(n: int, n_shards: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# regex partition rules  (SNIPPETS.md [1] `match_partition_rules` idiom)
+# regex partition rules  (the `match_partition_rules` idiom)
 # ---------------------------------------------------------------------------
 
 # Default rules for the bundled policy families (models/policies.py):
@@ -166,7 +175,7 @@ def match_partition_rules(rules, tree: Any, mesh: Mesh) -> Any:
     ``ShapeDtypeStruct``s (so optimizer-state shardings come from
     ``jax.eval_shape`` without materializing anything): optax states
     embed param-shaped subtrees under the same leaf names, so ONE rule
-    set covers params and optimizer state (SNIPPETS.md [1]).
+    set covers params and optimizer state.
     """
     compiled = [(re.compile(pat), spec) for pat, spec in rules]
 

@@ -28,7 +28,7 @@ from ..envs.gym_vec_pool import make_pool
 from ..obs.spans import NULL_TELEMETRY
 from ..ops.noise import member_offsets, pair_signs
 from ..utils.fault import rank_weights_with_failures
-from .engine import ESEngine, ESState
+from .engine import ESEngine, ESState, replicate_on_mesh
 
 
 class PooledEvalResult:
@@ -254,11 +254,11 @@ class PooledEngine:
         if self.obs_norm:
             # same init as the device path: count=1, mean=0, m2=1 → var 1
             d = self.pool.obs_dim
-            state = state._replace(obs_stats=(
+            state = state._replace(obs_stats=replicate_on_mesh((
                 jnp.float32(1.0),
                 jnp.zeros((d,), jnp.float32),
                 jnp.ones((d,), jnp.float32),
-            ))
+            ), self.core.mesh))
         return state
 
     # ---- obs_norm host-side helpers ----
@@ -492,7 +492,7 @@ class PooledEngine:
         self, state: ESState, n_episodes: int, seed: int = 0
     ) -> PooledEvalResult:
         """All ``n_episodes`` center-policy episodes in ONE pooled pass
-        (round-3 VERDICT weak #6: evaluate_policy ran them serially): a
+        (not serially, one episode after another): a
         fresh n_episodes-env pool steps in native threads while the device
         runs one batched forward per step.  Episode randomness comes from
         the pool seed, so ``seed`` picks the episode set.  Raw moments are
@@ -573,9 +573,9 @@ class PooledEngine:
                 self._pending_moments = None
                 if c1 > 0:
                     new_state = new_state._replace(
-                        obs_stats=merge_obs_moments_np(
+                        obs_stats=replicate_on_mesh(merge_obs_moments_np(
                             new_state.obs_stats, c1, s1, q1
-                        )
+                        ), self.core.mesh)
                     )
         else:
             # stale moments from a discarded evaluation: drop, never merge

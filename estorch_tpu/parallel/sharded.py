@@ -8,8 +8,8 @@ at the Hyperscale" recipe (PAPERS.md, arxiv 2511.16652) on a 2-D
 
 - **Sharded state.**  Params and optimizer state live as TREES whose
   leaves are sharded over ``model`` per regex partition rules
-  (:func:`~estorch_tpu.parallel.mesh.match_partition_rules`, SNIPPETS.md
-  [1]); optax's param-shaped subtrees resolve through the SAME rules, so
+  (:func:`~estorch_tpu.parallel.mesh.match_partition_rules`); optax's
+  param-shaped subtrees resolve through the SAME rules, so
   adam's moments shard exactly like the weights they smooth.
 - **In-program noise.**  ε is generated inside the jitted program, keyed
   on ``(key, generation, row, leaf)`` (ops/noise.py ``program_noise``):

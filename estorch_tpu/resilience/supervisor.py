@@ -370,7 +370,8 @@ class Supervisor:
                 reason = failure
                 break
             # exponential backoff: give a flapping environment (OOM killer,
-            # tunnel outage) room to recover instead of hammering it
+            # a chip still held by the dying child) room to recover
+            # instead of hammering it
             time.sleep(min(self.backoff_s * (2 ** (attempt - 1)),
                            self.backoff_max_s))
         self._write_provenance(ok)

@@ -154,7 +154,7 @@ class PolicyServer:
                                      install_compile_event_counters)
         from .warm import build_serving_batcher
 
-        counted = install_compile_event_counters()
+        install_compile_event_counters()
         before = compile_event_counts()
         t0 = time.perf_counter()
         bundle = load_bundle(bundle_path, install_warm=self.warm_install)
@@ -178,14 +178,10 @@ class PolicyServer:
         after = compile_event_counts()
         warm_installed = bool(bundle.warm_status
                               and bundle.warm_status.get("installed"))
-        if counted:
-            hits = int(after["cache_hits"] - before["cache_hits"])
-            fresh = int(after["programs"] - before["programs"]) - hits
-        else:  # no monitoring stream on this jax build: warmth unproven
-            hits, fresh = 0, None
+        hits = int(after["cache_hits"] - before["cache_hits"])
+        fresh = int(after["programs"] - before["programs"]) - hits
         self.obs.counters.gauge("warm_cache_hits", hits)
-        self.obs.counters.gauge(
-            "compiles_at_load", -1 if fresh is None else fresh)
+        self.obs.counters.gauge("compiles_at_load", fresh)
         self.obs.compile_event(
             "bundle_load", dt, count_recompiles=0, first_call=True,
             cache_hits=hits, fresh_builds=fresh,
@@ -601,9 +597,16 @@ def run_server(args) -> int:
     signal.signal(signal.SIGTERM, _on_signal)
     signal.signal(signal.SIGINT, _on_signal)
 
+    import jax  # the engine build above already brought the backend up
+
+    device = jax.devices()[0]
     url = f"http://{server.host}:{server.port}"
     ready = {
         "ready": True, "url": url, "pid": os.getpid(),
+        # the device the batched programs run on, as jax reports it: a
+        # caller that wants the chip checks here instead of assuming
+        "platform": device.platform, "device_kind": device.device_kind,
+        "device_count": len(jax.devices()),
         "version": server._engine.bundle.version,
         "max_batch": server.max_batch,
         "buckets": list(server._engine.batcher.buckets),

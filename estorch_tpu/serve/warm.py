@@ -15,9 +15,10 @@ This module moves that cost to EXPORT time, once:
   will ask for, auxiliary one-op programs included (a "zero fresh builds
   at load" proof fails on any program left out);
 * :func:`install_warmth` copies those entries into the serving process's
-  active cache directory (or a process-scoped temp dir when none is
-  configured) BEFORE any jax work, so every subsequent compile request
-  is a persistent-cache retrieval.  The bundle itself is never written
+  compile cache directory (``JAX_COMPILATION_CACHE_DIR`` where set, else
+  ``utils.backend.default_compilation_cache_dir()``) BEFORE any jax
+  work, so every subsequent compile request is a persistent-cache
+  retrieval.  The bundle itself is never written
   to — jax's cache touches per-entry atime files on read, and a bundle
   must stay immutable under its manifest checksums (possibly on a
   read-only mount).
@@ -199,24 +200,6 @@ def warm_bundle(
     return block, shas
 
 
-_PROCESS_WARM_CACHE_DIR: str | None = None
-
-
-def _process_warm_cache_dir() -> str:
-    """A process-scoped cache dir for warmth installs when the process
-    has no persistent cache configured — temp, cleaned at exit, so an
-    ephemeral serving process never pollutes durable per-user state."""
-    global _PROCESS_WARM_CACHE_DIR
-    if _PROCESS_WARM_CACHE_DIR is None:
-        import atexit
-        import tempfile
-
-        d = tempfile.mkdtemp(prefix="estorch_warm_cache_")
-        atexit.register(shutil.rmtree, d, ignore_errors=True)
-        _PROCESS_WARM_CACHE_DIR = d
-    return _PROCESS_WARM_CACHE_DIR
-
-
 def install_warmth(path: str, manifest: dict) -> dict:
     """Install a bundle's packed warmth into this process's persistent
     compilation cache; returns a structured status dict (never raises on
@@ -256,12 +239,19 @@ def install_warmth(path: str, manifest: dict) -> dict:
             f"this process runs {facts['platform']!r} — executables are "
             "not portable across backends; ignoring warmth")
         return out
-    from ..utils.backend import (current_compilation_cache_dir,
+    from ..utils.backend import (_disable_path_dependent_cache_keys,
+                                 current_compilation_cache_dir,
                                  enable_compilation_cache)
 
     cache_dir = current_compilation_cache_dir()
     if cache_dir is None:
-        cache_dir = enable_compilation_cache(_process_warm_cache_dir())
+        cache_dir = enable_compilation_cache()
+    else:
+        # a directory placed from outside (JAX_COMPILATION_CACHE_DIR) is
+        # left where it is; the warm entries' keys were still computed
+        # without the directory's path in them
+        _disable_path_dependent_cache_keys()
+        os.makedirs(cache_dir, exist_ok=True)
     warm_dir = os.path.join(os.path.abspath(path), WARM_DIR)
     n = 0
     for fname in warm.get("entries", {}):

@@ -1,7 +1,7 @@
 from .backend import (compile_event_counts, enable_compilation_cache,
                       enable_cpu_gloo_collectives, force_cpu_backend,
                       install_compile_event_counters,
-                      scoped_compilation_cache, set_host_device_count_flag)
+                      scoped_compilation_cache)
 from .checkpoint import (PeriodicCheckpointer, latest_checkpoint,
                          restore_checkpoint, save_checkpoint)
 from .fault import mask_and_renormalize, rank_weights_with_failures, valid_mask
@@ -15,7 +15,6 @@ __all__ = [
     "force_cpu_backend",
     "install_compile_event_counters",
     "scoped_compilation_cache",
-    "set_host_device_count_flag",
     "PeriodicCheckpointer",
     "latest_checkpoint",
     "restore_checkpoint",

@@ -319,21 +319,21 @@ def _unpack_state(es, packed: dict, host_opt=None):
         )
     import jax.numpy as jnp
 
-    from ..parallel.engine import ESState
+    from ..parallel.engine import ESState, replicate_on_mesh
 
     obs_stats = packed.get("obs_stats")
     if obs_stats is not None:
         obs_stats = tuple(
             jnp.asarray(x, jnp.float32) for x in obs_stats
         )
-    return ESState(
+    return replicate_on_mesh(ESState(
         params_flat=jnp.asarray(packed["params_flat"]),
         opt_state=packed["opt_state"],
         key=jnp.asarray(packed["key"]),
         generation=jnp.int32(packed["generation"]),
         sigma=jnp.float32(packed["sigma"]),
         obs_stats=obs_stats,
-    )
+    ), es.mesh)
 
 
 def latest_checkpoint(root: str) -> str | None:

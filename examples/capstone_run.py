@@ -1,4 +1,6 @@
-"""Long-budget capstone driver for any shipped recipe (CPU mesh).
+"""Long-budget capstone driver for any shipped recipe, on whatever
+backend JAX picks (the chip where there is one; JAX_PLATFORMS=cpu for a
+CPU run).
 
 Trains `configs.<name>` for a bounded generation budget with the full
 evidence protocol the round-4/5 capstones used: a JSONL learning curve,
@@ -24,10 +26,8 @@ def main():
 
     from estorch_tpu import configs
     from estorch_tpu.utils import (PeriodicCheckpointer,
-                                   enable_compilation_cache,
-                                   force_cpu_backend)
+                                   enable_compilation_cache)
 
-    force_cpu_backend(8)
     enable_compilation_cache()
 
     es = configs.CONFIGS[name](seed=seed)

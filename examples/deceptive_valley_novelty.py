@@ -66,9 +66,8 @@ def main():
 
     from estorch_tpu import ES, NSRA_ES, JaxAgent, MLPPolicy
     from estorch_tpu.envs import DeceptiveValley, Swimmer2D
-    from estorch_tpu.utils import enable_compilation_cache, force_cpu_backend
+    from estorch_tpu.utils import enable_compilation_cache
 
-    force_cpu_backend(8)
     enable_compilation_cache()
 
     base = Swimmer2D()

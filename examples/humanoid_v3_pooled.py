@@ -33,6 +33,9 @@ def main():
                                    enable_compilation_cache,
                                    force_cpu_backend, restore_checkpoint)
 
+    # pooled path: MuJoCo steps on the host CPU and inference is a small
+    # device-batched program.  One CPU device on purpose: the run is
+    # host-bound, so it should not hold a chip it would leave idle.
     force_cpu_backend(1)
     enable_compilation_cache()
 

@@ -8,7 +8,8 @@ d·ε ~ N(0, ‖Δθ/σ‖²), so with a coordinate-wise optimizer that means
 lr ≲ σ/√dim (here: σ=0.1, dim=386 → lr ≈ 3e-3).  Outside that regime
 IW_ES warns once and runs as vanilla ES (see algo/iwes.py).
 
-Measured on the 8-virtual-device CPU mesh, 3 seeds (BENCHMARKS.md round 2):
+Measured on the 8-virtual-device CPU mesh, 3 seeds (an earlier round's
+study; env-step counts are platform-independent):
 IW-ES reaches mean return 450 in ~25% fewer env-steps (2.11M vs 2.80M)
 and ends higher on every seed (489-494 vs 466-479), reusing in 99% of
 generations.  The win is in ENV-STEPS — exactly what matters when the env

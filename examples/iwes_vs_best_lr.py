@@ -62,9 +62,8 @@ def median_or_inf(vals):
 
 
 def main():
-    from estorch_tpu.utils import enable_compilation_cache, force_cpu_backend
+    from estorch_tpu.utils import enable_compilation_cache
 
-    force_cpu_backend(8)
     enable_compilation_cache()
 
     quick = "--quick" in sys.argv

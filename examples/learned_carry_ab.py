@@ -61,9 +61,8 @@ def main():
     pop = int(sys.argv[2]) if len(sys.argv) > 2 else 256
     n_seeds = int(sys.argv[3]) if len(sys.argv) > 3 else 2
 
-    from estorch_tpu.utils import enable_compilation_cache, force_cpu_backend
+    from estorch_tpu.utils import enable_compilation_cache
 
-    force_cpu_backend(8)
     enable_compilation_cache()
 
     rows = []

@@ -9,7 +9,8 @@ paths; BASELINE config 3).
 
 Within ~30 generations the population mean roughly triples as policies
 learn to balance; a 300-generation run reaches mean 160 / best 407 — best
-members hold the full 400-step horizon while moving (BENCHMARKS.md).
+members hold the full 400-step horizon while moving (an earlier round's
+CPU-mesh run).
 
 Run: python examples/locomotion_humanoid.py
 """

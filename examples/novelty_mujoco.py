@@ -147,6 +147,8 @@ def main():
 
     from estorch_tpu.utils import enable_compilation_cache, force_cpu_backend
 
+    # pooled path: MuJoCo steps on the host CPU; one CPU device on purpose
+    # (host-bound — it should not hold a chip it would leave idle)
     force_cpu_backend(1)
     enable_compilation_cache()
 

@@ -78,9 +78,8 @@ def run(obs_norm: bool, seed: int, gens: int, pop: int):
 
 
 def main():
-    from estorch_tpu.utils import enable_compilation_cache, force_cpu_backend
+    from estorch_tpu.utils import enable_compilation_cache
 
-    force_cpu_backend(8)
     enable_compilation_cache()
 
     print(json.dumps({"spread": measure_spread()}), flush=True)

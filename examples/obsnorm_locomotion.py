@@ -16,11 +16,6 @@ import numpy as np
 
 def run(obs_norm: bool, seed: int, gens: int, pop: int):
     from estorch_tpu import configs
-    from estorch_tpu.utils import force_cpu_backend
-
-    # A/B study: run on the virtual CPU mesh regardless of accelerator
-    # health — relative ordering is the result, not absolute throughput
-    force_cpu_backend(8)
 
     es = configs.walker2d_device(
         population_size=pop, seed=seed, obs_norm=obs_norm,

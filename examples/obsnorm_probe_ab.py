@@ -25,9 +25,8 @@ def main():
     n_seeds = int(sys.argv[3]) if len(sys.argv) > 3 else 2
 
     from estorch_tpu import configs
-    from estorch_tpu.utils import enable_compilation_cache, force_cpu_backend
+    from estorch_tpu.utils import enable_compilation_cache
 
-    force_cpu_backend(8)
     enable_compilation_cache()
 
     for seed in range(n_seeds):

@@ -20,9 +20,7 @@ def run(recurrent: bool, seed: int, gens: int, pop: int):
 
     from estorch_tpu import ES, JaxAgent, MLPPolicy, RecurrentPolicy
     from estorch_tpu.envs import PositionOnly, Walker2D
-    from estorch_tpu.utils import force_cpu_backend
 
-    force_cpu_backend(8)
     if recurrent:
         policy, pk = RecurrentPolicy, {
             "action_dim": 6, "hidden": (64,), "gru_size": 32,

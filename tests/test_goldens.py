@@ -6,14 +6,10 @@ legitimate algorithm change (e.g. a deliberate estimator fix) should update
 these values IN THE SAME COMMIT with a note; an unexpected diff here means
 the refactor changed numerics.
 
-Goldens are VERSION-KEYED: the values encode the jax.random stream of
-the jax version they were captured under (different jax versions draw
-different streams for the same seed — init params and every perturbation
-change, so trajectories are incomparable across versions, not merely
-fuzzy).  ``GOLDENS`` selects the set matching the running jax's
-major.minor at import time, falling back to the canonical round-5 set —
-so a NEW jax family fails loudly (record a set for it with the recipe
-below) instead of silently skipping regression protection.
+The values encode the jax.random stream of the jax they were captured
+under and hold on the installed one (0.9); a jax whose stream differs
+fails loudly here — re-capture with the recipe below, in the commit that
+moves the installation.
 """
 
 import jax
@@ -24,8 +20,7 @@ import pytest
 from estorch_tpu import ES, NS_ES, NSR_ES, NSRA_ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
 
-# canonical set — captured on the round-5 image's jax (0.5/0.6 family)
-GOLDENS_ROUND5 = {
+GOLDENS = {
     "ES": {"reward_means": [43.0, 40.375, 43.5625], "params_sum": -5.57803},
     # identical values to ES by construction: the decomposition identity
     # x@(W+cE) = x@W + c(x@E) is exact at these shapes on CPU f32 — if this
@@ -70,52 +65,6 @@ GOLDENS_ROUND5 = {
     "ES_recurrent_lowrank": {"reward_means": [11.0, 9.375, 9.375],
                              "params_sum": -1.73011},
 }
-
-# captured under jax 0.4.37 (this CI image), same recipe/settings —
-# every value differs from GOLDENS_ROUND5 because the 0.4 random stream
-# differs, NOT because the math does (the ES == ES_decomposed identity
-# holds exactly in both sets, which is the cross-version sanity anchor)
-GOLDENS_JAX04 = {
-    "ES": {"reward_means": [15.75, 17.75, 18.0625], "params_sum": -0.36088},
-    "ES_decomposed": {
-        "reward_means": [15.75, 17.75, 18.0625],
-        "params_sum": -0.36088,
-    },
-    "NS_ES": {
-        "reward_means": [18.0, 15.6875, 14.5625],
-        "meta_sums": [-0.34177, 2.15712],
-        "archive_sum": -0.15584,
-        "meta_indices": [1, 1, 1],
-    },
-    "NSR_ES": {
-        "reward_means": [18.0, 17.0625, 15.625],
-        "meta_sums": [-0.34177, 1.94687],
-        "archive_sum": -0.41252,
-        "meta_indices": [1, 1, 1],
-    },
-    "NSRA_ES": {
-        "reward_means": [18.0, 16.5625, 15.5625],
-        "meta_sums": [-0.34177, 2.07359],
-        "archive_sum": -0.40019,
-        "meta_indices": [1, 1, 1],
-    },
-    "ES_obsnorm": {
-        "reward_means": [15.75, 17.3125, 10.0625],
-        "params_sum": -0.39158,
-        "obs_count": 45.0,
-        "obs_mean_sum": -0.03055,
-    },
-    "ES_recurrent": {"reward_means": [9.3125, 9.4375, 9.25],
-                     "params_sum": -5.22087},
-    "ES_lowrank": {"reward_means": [17.6875, 16.0625, 23.0],
-                   "params_sum": -0.51577},
-    "ES_recurrent_lowrank": {"reward_means": [9.375, 9.3125, 9.25],
-                             "params_sum": -4.84677},
-}
-
-_GOLDENS_BY_JAX = {"0.4": GOLDENS_JAX04}
-GOLDENS = _GOLDENS_BY_JAX.get(
-    ".".join(jax.__version__.split(".")[:2]), GOLDENS_ROUND5)
 
 CLASSES = {"ES": ES, "ES_decomposed": ES, "NS_ES": NS_ES, "NSR_ES": NSR_ES,
            "NSRA_ES": NSRA_ES, "ES_obsnorm": ES, "ES_recurrent": ES,

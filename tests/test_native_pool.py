@@ -91,6 +91,23 @@ class TestPoolParity:
         assert done_seen
         pool.close()
 
+    def test_failed_rebuild_never_loads_the_stale_library(
+            self, native_available, monkeypatch):
+        """A libenvpool.so older than the tracked envpool.cpp whose
+        rebuild fails must NOT be loaded (it steps some other version of
+        the envs): no library, and the NumPy pool takes over."""
+        import subprocess
+
+        from estorch_tpu.envs import native_pool
+
+        def no_compiler(*a, **k):
+            raise subprocess.CalledProcessError(2, "make")
+
+        monkeypatch.setattr(native_pool, "_stale", lambda path: True)
+        monkeypatch.setattr(native_pool.subprocess, "run", no_compiler)
+        assert native_pool.os.path.exists(native_pool._LIB_PATH)
+        assert native_pool._load_library() is None
+
     def test_unknown_env_rejected(self):
         with pytest.raises(ValueError, match="unknown env"):
             NativeEnvPool("humanoid", 4)

@@ -320,7 +320,7 @@ class TestRealRecords:
         assert len(recs) == 3
         for rec in recs:
             assert validate_record(rec) == [], validate_record(rec)
-        # the fused device path's honest span taxonomy
+        # the fused device path's honest span names
         assert {"dispatch", "device", "host_sync"} <= set(recs[-1]["phases"])
         s = summarize(recs)
         assert s["generations"] == 3
@@ -454,7 +454,7 @@ class TestESIntegration:
         es.train(1, verbose=False, log_fn=recs.append)
         assert recs[0]["phases"] == {}
 
-        # default-on: the host backend emits the canonical taxonomy and
+        # default-on: the host backend emits the canonical span names and
         # the heartbeat env var is honored end to end
         hb_path = os.path.join(
             os.environ.get("TMPDIR", "/tmp"), f"hb_{os.getpid()}.json")

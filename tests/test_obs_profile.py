@@ -25,8 +25,9 @@ from estorch_tpu.obs.export.prometheus import (is_gauge, parse_exposition,
 from estorch_tpu.obs.profile import (CompileLedger, collect_compile_events,
                                      find_cost_model, format_profile,
                                      generation_cost, ledger_counters,
-                                     measure_cpu_roofline, phase_cost_for,
-                                     platform_roofline, profile_records)
+                                     device_roofline, measure_cpu_roofline,
+                                     phase_cost_for, platform_roofline,
+                                     profile_records)
 from estorch_tpu.obs.profile.report import selfcheck as profile_selfcheck
 from estorch_tpu.obs.spans import Telemetry
 
@@ -96,10 +97,20 @@ class TestRoofline:
         assert cal["peak_bytes_per_s"] > 0
         assert cal["basis"] == "cpu_calibrated"
 
-    def test_tpu_roofline_is_the_datasheet(self):
-        r = platform_roofline("tpu")
+    def test_tpu_roofline_is_keyed_by_device_kind_with_its_source(self):
+        r = device_roofline("TPU v5 lite")  # the string the chip reports
         assert r["peak_flops_per_s"] == 197e12
+        assert r["peak_bytes_per_s"] == 819e9
         assert r["basis"] == "tpu_v5e_bf16_peak"
+        assert "TPU v5e" in r["source"]
+
+    def test_unknown_device_kind_raises(self):
+        """A chip that is not in the table is an error, never a default —
+        and "tpu" is a platform, not a kind."""
+        with pytest.raises(ValueError, match="TPU v9"):
+            device_roofline("TPU v9")
+        with pytest.raises(ValueError, match="device_kind"):
+            platform_roofline("tpu")
 
     def test_unmeasured_cpu_roofline_keeps_the_tag(self):
         r = platform_roofline("cpu", measure=False)
@@ -590,112 +601,128 @@ def bench_mod():
     return bench
 
 
-def _fake_row(platform="cpu"):
-    return {"rate": 1000.0, "platform": platform, "dtype": "float32",
-            "mfu": 2.5e-05, "mfu_basis": "cpu_calibrated",
+def _fake_row(platform="tpu"):
+    return {"rate": 1000.0, "platform": platform,
+            "device_kind": "TPU v5 lite", "dtype": "bfloat16",
+            "mfu": 2.5e-05, "mfu_basis": "tpu_v5e_bf16_peak",
             "phases": {"device": {"share": 0.5, "seconds": 1.0,
                                   "mfu": 2.5e-05}},
             "compile": {"n_events": 1},
-            "peak_hbm_gb": None, "peak_rss_gb": 1.0, "cfg": {}}
+            "peak_hbm_gb": 1.0, "peak_rss_gb": 1.0, "cfg": {}}
 
 
 class _FakeDoctor:
     def __init__(self, verdict):
         self.verdict = verdict
+        self.calls = []
 
     def check_device(self, timeout_s=20.0, platform=None):
+        self.calls.append(platform)
         return dict(self.verdict)
 
 
+_NO_TPU = {"status": "failed", "reason": "no-device", "elapsed_s": 2.0,
+           "timeout_s": 60.0}
+_TPU_OK = {"status": "ok", "platform": "tpu", "n_devices": 1,
+           "elapsed_s": 2.0, "timeout_s": 60.0}
+
+
 class TestBenchPlatformDecision:
-    def _run_main(self, bench_mod, monkeypatch, capsys, probe,
-                  stage_result):
-        calls = {"measure_one": 0, "run_stage": 0, "run_stage_device": 0}
+    """The measured path has no fallback: no TPU, or a failed stage, is a
+    non-zero exit with one line and no device metric; --cpu is the only
+    way onto the CPU mesh."""
+
+    def _patch(self, bench_mod, monkeypatch, probe, stage_result):
+        calls = {"run_stage": 0}
 
         def fake_run_stage(cfg, timeout_s=480, force_cpu=False):
             calls["run_stage"] += 1
-            if not force_cpu:
-                # a stage child that would touch the default (possibly
-                # wedged) backend — the 480s-discovery path
-                calls["run_stage_device"] += 1
-            return stage_result if not force_cpu else _fake_row()
+            assert not force_cpu  # the default bench never asks for cpu
+            return stage_result
 
-        def fake_measure_one(cfg, force_cpu=False):
-            calls["measure_one"] += 1
-            assert force_cpu
-            return _fake_row()
-
+        doctor = _FakeDoctor(probe)
         monkeypatch.setattr(bench_mod, "_lock_or_warn", lambda *a, **k: None)
-        monkeypatch.setattr(bench_mod, "_load_doctor",
-                            lambda: _FakeDoctor(probe))
+        monkeypatch.setattr(bench_mod, "_load_doctor", lambda: doctor)
         monkeypatch.setattr(bench_mod, "run_stage", fake_run_stage)
-        monkeypatch.setattr(bench_mod, "measure_one", fake_measure_one)
         monkeypatch.setattr(bench_mod, "measure_reference_style_baseline",
                             lambda budget_s=6.0: 100.0)
+        return doctor, calls
+
+    def test_no_tpu_exits_nonzero_and_prints_no_device_metric(
+            self, bench_mod, monkeypatch, capsys):
+        doctor, calls = self._patch(bench_mod, monkeypatch, _NO_TPU,
+                                    _fake_row())
+        with pytest.raises(SystemExit) as ei:
+            bench_mod.main()
+        assert ei.value.code not in (0, None)
+        cap = capsys.readouterr()
+        assert doctor.calls == ["tpu"]  # it asked for the CHIP
+        assert calls["run_stage"] == 0  # nothing measured anywhere
+        assert not [ln for ln in cap.out.splitlines() if ln.startswith("{")]
+        assert "per_chip" not in cap.out + cap.err
+        assert "mfu" not in cap.out
+        why = [ln for ln in cap.err.splitlines() if "FAILED" in ln]
+        assert len(why) == 1 and "no TPU" in why[0]
+
+    def test_a_failed_stage_fails_the_run(self, bench_mod, monkeypatch,
+                                          capsys):
+        """Probe ok but a stage died: exit non-zero, no JSON line — a
+        null row in an exit-0 artifact is how failures used to hide."""
+        _, calls = self._patch(bench_mod, monkeypatch, _TPU_OK, None)
+        with pytest.raises(SystemExit) as ei:
+            bench_mod.main()
+        assert ei.value.code not in (0, None)
+        assert calls["run_stage"] == 1
+        cap = capsys.readouterr()
+        assert not [ln for ln in cap.out.splitlines() if ln.startswith("{")]
+        assert "stage failed" in cap.err
+
+    def test_on_the_chip_the_headline_names_its_device(self, bench_mod,
+                                                       monkeypatch, capsys):
+        self._patch(bench_mod, monkeypatch, _TPU_OK, _fake_row())
         bench_mod.main()
         out = capsys.readouterr().out
-        line = [ln for ln in out.splitlines() if ln.startswith("{")][-1]
-        return json.loads(line), calls
-
-    def test_probe_ok_measures_and_fills_mfu(self, bench_mod, monkeypatch,
-                                             capsys):
-        """Acceptance: non-null mfu_headline tagged cpu_calibrated, the
-        typed probe verdict in extras, no fallback prose in the unit."""
-        probe = {"status": "ok", "platform": "cpu", "n_devices": 8,
-                 "elapsed_s": 2.0, "timeout_s": 20.0}
-        row, calls = self._run_main(bench_mod, monkeypatch, capsys, probe,
-                                    _fake_row())
-        assert row["extras"]["mfu_headline"] == 2.5e-05
-        assert row["extras"]["mfu_basis"] == "cpu_calibrated"
+        row = json.loads([ln for ln in out.splitlines()
+                          if ln.startswith("{")][-1])
+        assert row["metric"] == "env_steps_per_sec_per_chip"
+        assert row["platform"] == "tpu"
+        assert row["extras"]["device_kind"] == "TPU v5 lite"
         assert row["extras"]["device_probe"]["status"] == "ok"
-        assert row["extras"]["device_probe"]["cpu_fallback"] is False
-        assert row["extras"]["phases_headline"]["device"]["mfu"] > 0
-        assert "TPU-PATH-FAILED" not in row["unit"]
-        assert row["platform"] == "cpu"
-        assert calls["measure_one"] == 0
+        assert "cpu_fallback" not in row["extras"]["device_probe"]
 
-    def test_stage_drivers_share_the_probe_decision(self, bench_mod,
-                                                    monkeypatch):
-        """--regress/--stage-ab/--obs-ab go through _probe_or_force_cpu:
-        a failed probe forces the cpu fallback up front (one probe
-        timeout, not a full stage timeout per repeat) and an explicit
-        --cpu skips the probe entirely."""
-        calls = {"probe": 0}
-
-        class CountingDoctor(_FakeDoctor):
-            def check_device(self, timeout_s=20.0, platform=None):
-                calls["probe"] += 1
-                return dict(self.verdict)
-
-        bad = CountingDoctor({"status": "failed", "reason": "init-hang",
-                              "elapsed_s": 20.0, "timeout_s": 20.0})
+    def test_stage_drivers_cpu_is_explicit_only(self, bench_mod,
+                                                monkeypatch):
+        """--regress/--stage-ab/--obs-ab/--capture-baseline share one
+        decision: --cpu skips the probe entirely, anything else needs the
+        chip (a healthy CPU backend is "wrong-platform", not ok)."""
+        bad = _FakeDoctor({"status": "failed", "reason": "wrong-platform",
+                           "platform": "cpu", "elapsed_s": 3.0,
+                           "timeout_s": 60.0})
         monkeypatch.setattr(bench_mod, "_load_doctor", lambda: bad)
-        assert bench_mod._probe_or_force_cpu(False) is True
-        assert calls["probe"] == 1
-        # explicit --cpu: no probe spent
-        assert bench_mod._probe_or_force_cpu(True) is True
-        assert calls["probe"] == 1
-        ok = CountingDoctor({"status": "ok", "platform": "cpu",
-                             "n_devices": 8, "elapsed_s": 2.0,
-                             "timeout_s": 20.0})
+        got = bench_mod._require_tpu_unless(True)
+        assert got["requested_platform"] == "cpu" and bad.calls == []
+        with pytest.raises(SystemExit) as ei:
+            bench_mod._require_tpu_unless(False)
+        assert ei.value.code not in (0, None) and bad.calls == ["tpu"]
+        ok = _FakeDoctor(_TPU_OK)
         monkeypatch.setattr(bench_mod, "_load_doctor", lambda: ok)
-        assert bench_mod._probe_or_force_cpu(False) is False
+        assert bench_mod._require_tpu_unless(False)["platform"] == "tpu"
 
-    def test_probe_failure_skips_the_480s_discovery(self, bench_mod,
-                                                    monkeypatch, capsys):
-        """A failed probe goes STRAIGHT to the cpu fallback — zero stage
-        children launched, the reason code recorded in the artifact."""
-        probe = {"status": "failed", "reason": "init-hang",
-                 "elapsed_s": 20.0, "timeout_s": 20.0}
-        row, calls = self._run_main(bench_mod, monkeypatch, capsys, probe,
-                                    None)
-        # zero stage children on the possibly-wedged default backend (the
-        # cpu-relative extras stages run force_cpu and are safe)
-        assert calls["run_stage_device"] == 0
-        assert calls["measure_one"] == 1
-        assert row["extras"]["device_probe"]["reason"] == "init-hang"
-        assert row["extras"]["device_probe"]["cpu_fallback"] is True
-        assert row["extras"]["mfu_headline"] is not None
+    def test_cpu_request_still_measures_without_device_metrics(
+            self, bench_mod):
+        """--cpu (what the CPU tests use) measures on the CPU mesh; the
+        row has no MFU and is never named per-chip.  Without --cpu the
+        same call refuses instead of measuring the wrong silicon."""
+        cfg = {"env": "pendulum", "hidden": [8, 8], "population": 16,
+               "horizon": 5, "gens": 1, "telemetry": False}
+        with pytest.raises(bench_mod.NoTpuError, match="'cpu'"):
+            bench_mod.measure_one(cfg)
+        row = bench_mod.measure_one(cfg, force_cpu=True)
+        assert row["platform"] == "cpu" and row["rate"] > 0
+        assert row["mfu"] is None and row["mfu_basis"] is None
+        assert bench_mod._rate_metric(row["platform"]) \
+            == "env_steps_per_sec_cpu_mesh"
+        assert bench_mod._rate_metric("tpu") == "env_steps_per_sec_per_chip"
 
 
 class TestBenchScratchHygiene:

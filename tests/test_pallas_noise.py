@@ -5,8 +5,9 @@ The kernels must be bit-compatible REORDERINGS of existing math:
 - population_noise_matvec ≡ the c·(x@E) noise term of models/decomposed.py
 - mlp_streamed_apply ≡ mlp_decomposed_apply over a population batch
 
-On CPU they run in interpret mode; the SAME code compiles to Mosaic on TPU
-(bench.py A/Bs it on-chip when the chip is reachable).
+On CPU they run in interpret mode (``interpret=True`` is passed here, never
+derived from the backend); ``chip_smoke.py`` lowers the SAME code through
+Mosaic on the chip and compares it with the same references.
 """
 
 import jax
@@ -26,7 +27,8 @@ TABLE = make_noise_table(1 << 16, seed=3)
 
 
 class TestWeightedNoiseSum:
-    @pytest.mark.parametrize("n,dim", [(1, 8), (7, 33), (64, 128), (33, 257)])
+    @pytest.mark.parametrize("n,dim", [(1, 8), (7, 33), (64, 128), (33, 257),
+                                       (5, 3000)])
     def test_matches_pure_jax(self, n, dim):
         key = jax.random.key(n * 1000 + dim)
         offs = jax.random.randint(key, (n,), 0, TABLE.size - dim, dtype=jnp.int32)
@@ -55,9 +57,33 @@ class TestWeightedNoiseSum:
         )
         np.testing.assert_array_equal(np.asarray(got), np.zeros(8, np.float32))
 
+    def test_every_alignment_and_the_table_end(self):
+        """Rows are DMA'd as ALIGNED windows and realigned in VMEM: every
+        lane/sublane phase of the start offset, and the last legal offset
+        (where the window is clamped to the table), must give the slice."""
+        dim = 300
+        last = TABLE.size - dim
+        offs = jnp.array([0, 1, 127, 128, 129, 1023, 1024, 1025, 2047,
+                          last - 1024, last - 1, last], jnp.int32)
+        for k, o in enumerate(np.asarray(offs)):
+            w = jnp.zeros(offs.shape[0]).at[k].set(1.0)
+            got = weighted_noise_sum(TABLE.data, offs, w, dim=dim,
+                                     interpret=True)
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(TABLE.data[o:o + dim]),
+                err_msg=f"offset {o}")
+
+    def test_table_must_tile(self):
+        with pytest.raises(ValueError, match="multiple of 1024"):
+            weighted_noise_sum(jnp.zeros(1000), jnp.zeros((1,), jnp.int32),
+                               jnp.ones(1), dim=8, interpret=True)
+
 
 class TestPopulationNoiseMatvec:
-    @pytest.mark.parametrize("n,d,h", [(4, 8, 16), (6, 17, 5), (16, 32, 32), (3, 64, 7)])
+    # h a divisor of 128, a multiple of it, and neither (the gathered path)
+    @pytest.mark.parametrize("n,d,h", [(4, 8, 16), (6, 17, 5), (16, 32, 32),
+                                       (3, 64, 7), (3, 5, 256), (5, 9, 128),
+                                       (4, 13, 64)])
     def test_matches_einsum_oracle(self, n, d, h):
         key = jax.random.key(n + 10 * d + 100 * h)
         offs = jax.random.randint(key, (n,), 0, TABLE.size - d * h - 64, dtype=jnp.int32)
@@ -80,7 +106,7 @@ class TestPopulationNoiseMatvec:
     def test_explicit_block_rows(self):
         """A forced non-trivial row blocking must not change the result."""
         key = jax.random.key(0)
-        n, d, h = 4, 12, 6
+        n, d, h = 4, 12, 8
         offs = jax.random.randint(key, (n,), 0, TABLE.size - d * h, dtype=jnp.int32)
         c = jnp.ones((n,))
         x = jax.random.normal(jax.random.fold_in(key, 1), (n, d))

@@ -1062,7 +1062,7 @@ class TestWarmBundle:
         assert not os.path.isdir(os.path.join(path, "warm"))
 
     def test_warm_roundtrip_fresh_process_zero_fresh_builds(
-            self, small_es, warm_bundle_path):
+            self, small_es, warm_bundle_path, tmp_path):
         """THE warm-bundle acceptance: a fresh --cpu-devices-pinned
         process loads the warm bundle and serves its first request with
         ZERO fresh XLA builds (every program a persistent-cache hit, per
@@ -1095,9 +1095,12 @@ class TestWarmBundle:
         # serving never wrote into the bundle: checksums still hold
         validate_bundle(warm_bundle_path)
 
-        # control leg: same bundle, warmth ignored -> the JIT storm
-        proc, ready = _spawn_server(warm_bundle_path, max_batch=4,
-                                    extra_args=["--no-warm"])
+        # control leg: same bundle, warmth ignored -> the JIT storm.  The
+        # warm leg installed the bundle's entries into the compile cache
+        # its process was given; the control gets an empty one of its own
+        proc, ready = _spawn_server(
+            warm_bundle_path, max_batch=4, extra_args=["--no-warm"],
+            extra_env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc")})
         try:
             cold = ready["cold_start"]
             assert cold["warm"]["installed"] is False

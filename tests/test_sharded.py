@@ -465,9 +465,10 @@ class TestShardedES:
         with pytest.raises(ValueError, match="obs_norm"):
             ES(shard_params=True, **{**es_cls_common, "obs_norm": True})
 
-    def test_bench_sharded_row_reports_mfu(self, devices8):
-        """The sharded bench row: non-null mfu derived from the
-        shard-aware cost model (acceptance criterion 3)."""
+    def test_bench_sharded_row_off_chip(self, devices8):
+        """The sharded bench row on the CPU mesh (an explicit request):
+        FLOPs come from the shard-aware cost model, and there is no MFU —
+        a utilization exists only against a chip's published peak."""
         import os
         import sys
 
@@ -480,14 +481,87 @@ class TestShardedES:
         row = bench.measure_one(
             {"env": "synthetic", "hidden": [16, 16], "population": 16,
              "horizon": 20, "gens": 1, "eval_chunk": 8, "shard": True,
-             "telemetry": True})
-        assert row["mfu"] is not None
-        assert row["mfu_basis"] == "cpu_calibrated"
+             "telemetry": True}, force_cpu=True)
+        assert row["mfu"] is None and row["mfu_basis"] is None
+        assert row["platform"] == "cpu"
         assert row["dtype"] == "float32"
         shard = row["shard"]
         assert shard["mfu_from_cost_model"] is True
         assert shard["noise_mode"] == "program"
         assert shard["per_device_peak_bytes"]
+
+
+class TestOneCompilePerProgram:
+    """A state built on the host enters the mesh programs in the layout
+    they return it in, so generation 1 reuses generation 0's executable:
+    after generation 0 NOTHING is built (programs acquired == 0, cache
+    hits included), on the replicated engine, the sharded engine, the
+    novelty family's split path, and after a checkpoint restore."""
+
+    @staticmethod
+    def _common():
+        import optax as _optax
+
+        from estorch_tpu import JaxAgent
+        from estorch_tpu.envs import CartPole
+
+        return dict(
+            policy=MLPPolicy, agent=JaxAgent, optimizer=_optax.adam,
+            population_size=16, sigma=0.1, seed=0,
+            policy_kwargs={"action_dim": 2, "hidden": (8,)},
+            agent_kwargs={"env": CartPole(), "horizon": 10},
+            optimizer_kwargs={"learning_rate": 1e-2}, table_size=1 << 14,
+            telemetry=False,
+        )
+
+    @staticmethod
+    def _programs_per_generation(es, gens=3):
+        from estorch_tpu.utils.backend import (compile_event_counts,
+                                               install_compile_event_counters)
+
+        install_compile_event_counters()
+        out = []
+        for _ in range(gens):
+            before = compile_event_counts()["programs"]
+            es.train(1, verbose=False)
+            out.append(compile_event_counts()["programs"] - before)
+        return out
+
+    def test_meshes_have_auto_axes(self, devices8):
+        from jax.sharding import AxisType
+
+        from estorch_tpu.parallel.mesh import (hyperscale_mesh,
+                                               population_mesh,
+                                               single_device_mesh)
+
+        for mesh in (population_mesh(), single_device_mesh(),
+                     hyperscale_mesh(2, 4)):
+            assert all(t == AxisType.Auto for t in mesh.axis_types), mesh
+
+    def test_replicated_and_sharded_build_nothing_after_generation_0(
+            self, devices8):
+        from estorch_tpu import ES
+
+        for kw in ({"obs_norm": True, "low_rank": 1},
+                   {"shard_params": True, "model_shards": 2}):
+            es = ES(**{**self._common(), **kw})
+            built = self._programs_per_generation(es)
+            assert built[0] >= 1 and built[1:] == [0, 0], (kw, built)
+
+    def test_split_path_and_restore_keep_the_layout(self, devices8,
+                                                    tmp_path):
+        from estorch_tpu import ES, NSR_ES
+        from estorch_tpu.utils import restore_checkpoint, save_checkpoint
+
+        ns = NSR_ES(meta_population_size=2, k=3, **self._common())
+        built = self._programs_per_generation(ns)
+        assert built[1:] == [0, 0], built
+
+        es = ES(**{**self._common(), "obs_norm": True})
+        es.train(1, verbose=False)
+        save_checkpoint(es, str(tmp_path / "ck"))
+        restore_checkpoint(es, str(tmp_path / "ck"))
+        assert self._programs_per_generation(es, gens=2) == [0, 0]
 
 
 class TestResilienceWithDonation:

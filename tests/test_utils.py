@@ -344,60 +344,88 @@ class TestProfiler:
 
 
 class TestCompilationCache:
-    def test_enable_compilation_cache_persists_executables(self, tmp_path):
-        """enable_compilation_cache points XLA's persistent cache at the
-        directory and compiled programs actually land there (the 20-40s
-        fresh-process compile is what the cache exists to kill)."""
-        import jax
-        import jax.numpy as jnp
+    """One compile cache, placeable: JAX_COMPILATION_CACHE_DIR wins and no
+    code replaces it; unset, the default is one fixed directory in the
+    checkout.  Each case runs in a child — the variable is read when jax
+    is imported, and this process's cache must stay where conftest put it.
+    """
 
-        from estorch_tpu.utils import enable_compilation_cache
+    _CHILD = r"""
+import json, os, sys
+import jax, jax.numpy as jnp
+from estorch_tpu.utils import backend, enable_compilation_cache
+from estorch_tpu.utils.backend import (current_compilation_cache_dir,
+                                       default_compilation_cache_dir)
+explicit = sys.argv[1] or None
+if sys.argv[3]:  # stand-in for the in-checkout default: keep the suite's
+    backend.default_compilation_cache_dir = lambda: sys.argv[3]  # run out
+got = enable_compilation_cache(explicit, min_compile_time_s=0.0)
+jax.jit(lambda x: (x @ x.T).sum())(jnp.ones((64, 64))).block_until_ready()
+# a bundle that packs warmth installs into the SAME directory
+from estorch_tpu.serve.warm import install_warmth
+warm = {"format": "xla_cache", "jax_version": jax.__version__,
+        "platform": jax.default_backend(),
+        "device_count": len(jax.devices()), "entries": {}}
+status = install_warmth(sys.argv[2], {"warm": warm})
+print(json.dumps({"returned": got, "live": current_compilation_cache_dir(),
+                  "default": default_compilation_cache_dir(),
+                  "warm_dir": status["cache_dir"],
+                  "entries": len(os.listdir(got))}))
+"""
 
-        cache_dir = str(tmp_path / "xla")
-        got = enable_compilation_cache(cache_dir, min_compile_time_s=0.0)
-        assert got == cache_dir
-        try:
-            @jax.jit
-            def f(x):
-                return (x @ x.T).sum()
+    def _child(self, tmp_path, explicit="", default="", **env_over):
+        import json
+        import os
+        import subprocess
+        import sys
 
-            f(jnp.ones((64, 64))).block_until_ready()
-            import os
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": repo}
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        env.update(env_over)
+        r = subprocess.run(
+            [sys.executable, "-c", self._CHILD, explicit, str(tmp_path),
+             default],
+            capture_output=True, text=True, env=env, cwd=str(tmp_path),
+            timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
 
-            entries = os.listdir(cache_dir)
-            assert entries, "no cache entries written"
-        finally:
-            # restore defaults so later tests don't write into tmp_path —
-            # the config alone is not enough: JAX pins the cache object on
-            # first use, so it must be reset too
-            jax.config.update("jax_compilation_cache_dir", None)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
-            from estorch_tpu.utils.backend import _reset_live_cache
+    def test_environment_places_the_cache_and_code_leaves_it(self, tmp_path):
+        placed = str(tmp_path / "placed")
+        out = self._child(tmp_path, explicit=str(tmp_path / "ignored"),
+                          JAX_COMPILATION_CACHE_DIR=placed)
+        assert out["returned"] == out["live"] == out["warm_dir"] == placed
+        assert out["entries"] > 0, "no cache entries written"
+        assert not (tmp_path / "ignored").exists()
 
-            _reset_live_cache()
+    def test_explicit_directory_when_the_environment_is_silent(
+            self, tmp_path):
+        want = str(tmp_path / "xla")
+        out = self._child(tmp_path, explicit=want)
+        assert out["returned"] == out["live"] == out["warm_dir"] == want
+        assert out["entries"] > 0
 
-    def test_default_dir_created(self, monkeypatch, tmp_path):
-        import jax
+    def test_default_is_one_fixed_git_ignored_path_in_the_checkout(
+            self, tmp_path):
+        import os
 
-        from estorch_tpu.utils import enable_compilation_cache
+        from estorch_tpu.utils.backend import default_compilation_cache_dir
 
-        monkeypatch.setenv("HOME", str(tmp_path))
-        try:
-            d = enable_compilation_cache()
-            assert d.startswith(str(tmp_path))
-            import os
+        # unset and nothing passed: the default directory, whatever it is
+        stand_in = str(tmp_path / "default")
+        out = self._child(tmp_path, default=stand_in)
+        assert out["returned"] == out["live"] == out["warm_dir"] == stand_in
 
-            assert os.path.isdir(d)
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
-            from estorch_tpu.utils.backend import _reset_live_cache
-
-            _reset_live_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        d = default_compilation_cache_dir()
+        assert d == os.path.join(repo, ".xla_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".xla_cache/" in f.read().split()
+        # nothing on the warm-install path makes a directory of its own
+        with open(os.path.join(repo, "estorch_tpu", "serve",
+                               "warm.py")) as f:
+            assert "mkdtemp" not in f.read()
 
 
 class TestAsyncCheckpoint:
