@@ -20,6 +20,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import ENV, POLICY, stage
+
 
 def carry_init_takes_params(carry_init: Callable[..., Any]) -> bool:
     """Whether ``carry_init`` is the params-aware form (``carry_init(params)
@@ -112,45 +114,51 @@ def make_rollout(
         n_metrics = len(env.metric_names)
 
     def rollout(params: Any, key: jax.Array):
-        state0, obs0 = env.reset(key)
+        with stage(ENV):
+            state0, obs0 = env.reset(key)
         # episode-start carry may be learned: carry_init reads it from the
         # member's (perturbed) params when the policy asks for that
         if stateful:
-            h0 = carry_init(params) if _ci_takes_params else carry_init()
+            with stage(POLICY):
+                h0 = carry_init(params) if _ci_takes_params else carry_init()
         else:
             h0 = None
         zeros = jnp.zeros_like(obs0, dtype=jnp.float32)
 
         def step_fn(carry, _):
             state, obs, done, total, steps, h, moments = carry
-            alive = jnp.logical_not(done)
-            alive_f = alive.astype(jnp.float32)
-            if with_obs_moments:
-                cnt, osum, osumsq = moments
-                of = obs.astype(jnp.float32)
-                moments = (
-                    cnt + alive_f,
-                    osum + alive_f * of,
-                    osumsq + alive_f * of * of,
-                )
-            if stateful:
-                out, h_new = policy_apply(params, obs, h)
-            else:
-                out, h_new = policy_apply(params, obs), h
-            action = select_action(out, discrete)
-            nstate, nobs, reward, ndone = env.step(state, action)
-            if with_env_metrics:
-                # metrics of the state this alive step REACHED; frozen
-                # (post-termination) pseudo-steps contribute nothing
-                moments = moments + alive_f * env.step_metrics(nstate)
-            total = total + reward * alive_f
-            steps = steps + alive.astype(jnp.int32)
-            # freeze state/obs after termination so BC reads the final frame
-            keep = lambda new, old: jnp.where(alive, new, old)
-            state_next = jax.tree_util.tree_map(keep, nstate, state)
-            obs_next = keep(nobs, obs)
-            h_next = jax.tree_util.tree_map(keep, h_new, h)
-            done_next = done | ndone
+            with stage(ENV):
+                alive = jnp.logical_not(done)
+                alive_f = alive.astype(jnp.float32)
+                if with_obs_moments:
+                    cnt, osum, osumsq = moments
+                    of = obs.astype(jnp.float32)
+                    moments = (
+                        cnt + alive_f,
+                        osum + alive_f * of,
+                        osumsq + alive_f * of * of,
+                    )
+            with stage(POLICY):
+                if stateful:
+                    out, h_new = policy_apply(params, obs, h)
+                else:
+                    out, h_new = policy_apply(params, obs), h
+                action = select_action(out, discrete)
+            with stage(ENV):
+                nstate, nobs, reward, ndone = env.step(state, action)
+                if with_env_metrics:
+                    # metrics of the state this alive step REACHED; frozen
+                    # (post-termination) pseudo-steps contribute nothing
+                    moments = moments + alive_f * env.step_metrics(nstate)
+                total = total + reward * alive_f
+                steps = steps + alive.astype(jnp.int32)
+                # freeze state/obs after termination so BC reads the final
+                # frame
+                keep = lambda new, old: jnp.where(alive, new, old)
+                state_next = jax.tree_util.tree_map(keep, nstate, state)
+                obs_next = keep(nobs, obs)
+                h_next = jax.tree_util.tree_map(keep, h_new, h)
+                done_next = done | ndone
             return (
                 state_next, obs_next, done_next, total, steps, h_next, moments
             ), None
@@ -173,7 +181,8 @@ def make_rollout(
         (state, obs, done, total, steps, _, moments), _ = jax.lax.scan(
             step_fn, init, None, length=horizon
         )
-        bc = env.behavior(state, obs).astype(jnp.float32)
+        with stage(ENV):
+            bc = env.behavior(state, obs).astype(jnp.float32)
         res = RolloutResult(total_reward=total, bc=bc, steps=steps)
         return (
             (res, moments) if (with_obs_moments or with_env_metrics) else res
@@ -244,25 +253,28 @@ def make_batched_rollout(
     v_behavior = jax.vmap(env.behavior)
 
     def rollout(batched_apply, keys: jax.Array) -> RolloutResult:
-        states0, obs0 = v_reset(keys)
+        with stage(ENV):
+            states0, obs0 = v_reset(keys)
         n = obs0.shape[0]
 
         def step_fn(carry, _):
             states, obs, done, total, steps = carry
-            out = batched_apply(obs)
-            action = select_action(out, discrete)
-            nstate, nobs, reward, ndone = v_step(states, action)
-            alive = jnp.logical_not(done)
-            total = total + reward * alive.astype(jnp.float32)
-            steps = steps + alive.astype(jnp.int32)
+            with stage(POLICY):
+                out = batched_apply(obs)
+                action = select_action(out, discrete)
+            with stage(ENV):
+                nstate, nobs, reward, ndone = v_step(states, action)
+                alive = jnp.logical_not(done)
+                total = total + reward * alive.astype(jnp.float32)
+                steps = steps + alive.astype(jnp.int32)
 
-            def keep(new, old):
-                mask = alive.reshape((-1,) + (1,) * (new.ndim - 1))
-                return jnp.where(mask, new, old)
+                def keep(new, old):
+                    mask = alive.reshape((-1,) + (1,) * (new.ndim - 1))
+                    return jnp.where(mask, new, old)
 
-            states_next = jax.tree_util.tree_map(keep, nstate, states)
-            obs_next = keep(nobs, obs)
-            done_next = done | ndone
+                states_next = jax.tree_util.tree_map(keep, nstate, states)
+                obs_next = keep(nobs, obs)
+                done_next = done | ndone
             return (states_next, obs_next, done_next, total, steps), None
 
         init = (
@@ -275,7 +287,8 @@ def make_batched_rollout(
         (states, obs, done, total, steps), _ = jax.lax.scan(
             step_fn, init, None, length=horizon
         )
-        bc = v_behavior(states, obs).astype(jnp.float32)
+        with stage(ENV):
+            bc = v_behavior(states, obs).astype(jnp.float32)
         return RolloutResult(total_reward=total, bc=bc, steps=steps)
 
     return rollout

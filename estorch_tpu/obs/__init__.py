@@ -24,8 +24,8 @@ explain itself (docs/observability.md):
   trace-event export (``obs trace``), and the ``obs regress`` perf gate
   over committed ``BENCH_*.json`` baselines.
 
-``utils.metrics`` and ``utils.profiler`` remain as re-export shims for
-backward compatibility.
+``utils.metrics`` remains as a re-export shim for backward
+compatibility; the jax-profiler hooks live in ``obs.trace`` alone.
 """
 
 from . import export  # noqa: F401  (prometheus/sidecar/trace/regress)
@@ -42,7 +42,7 @@ from .spans import NULL_TELEMETRY, Telemetry, resolve_telemetry
 from .summarize import (format_summary, load_records,
                         load_records_tolerant, selfcheck, summarize,
                         validate_record)
-from .trace import annotate, timed_generations, trace
+from .trace import STAGES, annotate, stage, trace
 
 __all__ = [
     "Counters",
@@ -80,7 +80,8 @@ __all__ = [
     "selfcheck",
     "summarize",
     "validate_record",
+    "STAGES",
     "annotate",
-    "timed_generations",
+    "stage",
     "trace",
 ]

@@ -2,12 +2,21 @@
 
 A *span* is one timed phase of a generation — ``sample`` / ``eval`` /
 ``update`` on the host and pooled backends, ``dispatch`` / ``device`` /
-``host_sync`` on the fused device path (whose single XLA program cannot
-be split finer without de-fusing it; docs/observability.md has the full
-list of span names).  Spans nest: a phase entered inside another is recorded under
-``parent/child`` (e.g. ``update/obsnorm_merge``), and the parent's time
-includes its children — per-phase *share* therefore sums top-level names
-only.
+``host_sync`` on the fused device path (docs/observability.md has the
+full list of span names).  The host cannot see inside the fused
+program's ``device`` span; the program names its own stages
+(``es.noise``, ``es.policy``, ... — obs/trace.py ``STAGES``) and a
+profiler trace splits the span by them.  Spans nest: a phase entered
+inside another is recorded under ``parent/child`` (e.g.
+``update/obsnorm_merge``), and the parent's time includes its children —
+per-phase *share* therefore sums top-level names only.
+
+Every phase is also a span in the profiler's trace: it enters
+``obs.trace.annotate(name, generation=...)`` for its life, so under
+``jax.profiler`` the phases sit on a ``/host:CPU`` line of the same
+``.xplane.pb`` as the device operations, with true starts and ends and
+the generation they belong to.  With no profiler running that is about a
+microsecond a phase.
 
 Device honesty: wall-clocking an async-dispatched jitted call measures
 dispatch, not compute (esguard R07).  Every device span either contains
@@ -17,7 +26,7 @@ its own materialization (``np.asarray`` of an output) or passes
 
 Overhead budget: a disabled Telemetry's ``phase()`` yields a cached
 no-op context manager (two attribute loads); an enabled one costs two
-``perf_counter`` calls + dict update per span.  Heartbeat/file work only
+``perf_counter`` calls, a trace annotation + dict update per span.  Heartbeat/file work only
 happens when a heartbeat path is configured (supervisors opt in via the
 ``ESTORCH_OBS_HEARTBEAT`` env var).  The budget for default-on spans is
 <2% of generation wall time (``bench.py --obs-ab`` is the gate; not
@@ -35,6 +44,7 @@ from .counters import Counters, NullCounters
 from .hist import Histograms, NullHistograms
 from .profile.ledger import CompileLedger, ledger_counters
 from .recorder import HEARTBEAT_ENV, FlightRecorder, Heartbeat
+from .trace import annotate
 
 OBS_DISABLE_ENV = "ESTORCH_OBS"  # "0" disables default-on telemetry
 
@@ -75,6 +85,7 @@ class Telemetry:
         self._acc: dict[str, float] = {}
         self._acc_lock = threading.Lock()
         self._tls = threading.local()
+        self._annotate = annotate  # swapped for a no-op where jax is absent
         # performance-attribution facts (obs/profile/): the per-program
         # compile ledger and the run's analytic cost model — engines feed
         # the first, ES sets the second, `obs profile` joins them
@@ -121,11 +132,19 @@ class Telemetry:
             self.heartbeat.beat(full, self.generation,
                                 self.counters.snapshot(),
                                 hists=self.hists.snapshot(compact=True))
+        try:
+            # the phase as a span in the profiler's trace (obs/trace.py)
+            annotation = self._annotate(full, generation=self.generation)
+        except ImportError:
+            # no jax profiler in this process: phases still record
+            self._annotate = lambda name, **ids: _NULL_CM
+            annotation = _NULL_CM
         t0 = time.perf_counter()
         try:
-            yield
-            if fence is not None:
-                fence()
+            with annotation:
+                yield
+                if fence is not None:
+                    fence()
         finally:
             dt = time.perf_counter() - t0
             stack.pop()

@@ -1,23 +1,60 @@
-"""Device-trace hooks (migrated from ``utils/profiler.py``, which remains
-a re-export shim).
+"""Device-trace hooks: the one vocabulary of stages inside a generation
+program, and the host's spans on the profiler's clock.
 
 Span telemetry (obs/spans.py) answers "which phase got slower" for free
-on every run; these helpers are the heavyweight next step when a phase
-needs opening up:
+on every run; a ``jax.profiler`` trace is the heavyweight next step when
+a phase needs opening up.  Three hooks make such a trace readable:
 
 - ``trace(logdir)``: context manager around ``jax.profiler`` producing a
   Perfetto/XPlane trace of the compiled generation programs;
-- ``timed_generations(es, n)``: per-generation wall/device split using
-  ``block_until_ready`` fences — the cheap always-available option;
-- ``annotate(name)`` via ``jax.profiler.TraceAnnotation`` for host-side
-  phases (novelty k-NN, archive ops) so they show up inside device
-  traces.
+- ``stage(name)``: the name-stack scope ``es.<name>`` for one of
+  ``STAGES``.  The engines wrap every stage of a generation in one, so
+  each operation of the compiled program carries its stage in its
+  ``op_name`` (and, on a TPU, in the ``tf_op`` of its trace events).
+  Scopes are metadata only: the executable's instructions and the
+  compile-cache key do not change.  An operation belongs to the INNERMOST
+  ``es.<stage>`` of its name stack; a fusion to the stage of its root;
+- ``annotate(name, **ids)``: ``jax.profiler.TraceAnnotation``, a host
+  span in the same trace.  Every ``Telemetry.phase`` enters one, so
+  ``dispatch``/``device``/``host_sync``/``record`` sit on a ``/host:CPU``
+  line beside the device operations, with true starts and ends.
+
+A trace shows the metadata of the executable that RAN: the persistent
+compile cache keys programs without their metadata, so an entry written
+before a scope existed is served without it.  Profile on a cache
+directory of its own, or with
+``jax_compilation_cache_include_metadata_in_key`` on
+(docs/observability.md, "Stages inside the generation program").
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
+
+SCOPE_PREFIX = "es."
+
+# the stages of one generation, in program order (docs/observability.md)
+STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE) = (
+    "sample",    # offsets, signs, member keys
+    "noise",     # reading eps: the table gather and the slab it builds
+    "perturb",   # theta + sigma * sign * eps, unravel, cast
+    "policy",    # obs normalisation, the forward, select_action
+    "env",       # reset, physics, done masking, reward/step accumulation
+    "gather",    # collectives
+    "rank",      # centered ranks
+    "grad",      # the second pass over the noise, the weighted sum
+    "update",    # weight decay, optax step, sigma decay, obs-norm probe
+)
+
+
+def stage(name: str):
+    """Name-stack scope of one generation stage; a context manager and a
+    decorator.  Trace-time only: nothing runs per call of the program."""
+    import jax
+
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; the stages are {STAGES}")
+    return jax.named_scope(SCOPE_PREFIX + name)
 
 
 @contextlib.contextmanager
@@ -32,34 +69,10 @@ def trace(logdir: str):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Host-phase annotation visible in device traces (no-op off-trace)."""
+def annotate(name: str, **ids):
+    """Host span visible in device traces; ``ids`` (e.g. ``generation=``)
+    ride along as the event's stats.  About a microsecond while no
+    profiler runs."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
-
-
-def timed_generations(es, n: int = 5, warmup: int = 1) -> dict:
-    """Run ``n`` timed generations; returns aggregate timing stats.
-
-    Forces AOT compile (via train's first call) and a ``warmup``
-    generation first so results measure steady-state execution only.
-    The wall clock is fenced: ``es.train`` blocks on the updated
-    parameters every generation, so the delta below measures executed
-    compute, not async dispatch (esguard R07 contract).
-    """
-    es.train(warmup, verbose=False)
-    t0 = time.perf_counter()
-    es.train(n, verbose=False)
-    wall = time.perf_counter() - t0
-    recs = es.history[-n:]
-    steps = sum(r["env_steps"] for r in recs)
-    return {
-        "generations": n,
-        "wall_s": wall,
-        "gen_per_sec": n / wall,
-        "env_steps": steps,
-        "env_steps_per_sec": steps / wall,
-        "mean_gen_wall_s": wall / n,
-        "compile_time_s": es.compile_time_s,
-    }
+    return jax.profiler.TraceAnnotation(name, **ids)

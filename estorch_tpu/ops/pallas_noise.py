@@ -61,6 +61,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.trace import NOISE, stage
+
 LANES = 128
 SUBLANES = 8
 TILE = LANES * SUBLANES  # floats per (8, 128) f32 tile: the DMA alignment
@@ -430,16 +432,21 @@ def mlp_streamed_apply(
         w = shared_params[name]["kernel"]
         b = shared_params[name]["bias"]
         d, h = int(w.shape[0]), int(w.shape[1])
-        noise_term = population_noise_matvec(
-            table_data, offsets, c, x,
-            layer_offset=layer_offsets[name]["kernel"],
-            d=d, h=h, interpret=interpret,
-        )
-        # bias noise: h floats per member — a tiny gather, not worth a DMA
-        bias_off = layer_offsets[name]["bias"]
-        nb = jax.vmap(
-            lambda o: jax.lax.dynamic_slice(table_data, (o + bias_off,), (h,))
-        )(offsets)
+        # the forward reads eps itself: the streaming kernel and the bias
+        # gather are this form's noise stage, inside the policy's
+        with stage(NOISE):
+            noise_term = population_noise_matvec(
+                table_data, offsets, c, x,
+                layer_offset=layer_offsets[name]["kernel"],
+                d=d, h=h, interpret=interpret,
+            )
+            # bias noise: h floats per member — a tiny gather, not worth a
+            # DMA
+            bias_off = layer_offsets[name]["bias"]
+            nb = jax.vmap(
+                lambda o: jax.lax.dynamic_slice(
+                    table_data, (o + bias_off,), (h,))
+            )(offsets)
         x = x @ w + noise_term + b + c[:, None] * nb
         if name != "head":
             x = module.activation(x)

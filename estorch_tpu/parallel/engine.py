@@ -38,6 +38,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..envs.rollout import carry_init_takes_params, make_obs_probe, make_rollout
 from ..obs.spans import NULL_TELEMETRY
+from ..obs.trace import (GATHER, GRAD, NOISE, PERTURB, RANK, SAMPLE, UPDATE,
+                         stage)
 from ..ops.gradient import es_gradient, rank_weighted_noise_sum
 from ..ops.noise import NoiseTable, member_offsets, pair_signs, sample_pair_offsets
 from ..ops.params import ParamSpec
@@ -621,6 +623,7 @@ class ESEngine:
 
     # ---- shard-local bodies (run once per device under shard_map) ----
 
+    @stage(SAMPLE)
     def _local_offsets_signs_keys(self, state: ESState):
         """This device's (reduction offsets, member offsets, signs, keys).
 
@@ -697,15 +700,17 @@ class ESEngine:
             # fuse across the population.  The f32 original stays around for
             # the recurrent low_rank branch, which perturbs in f32 and casts
             # per member (the standard path's theta ordering)
-            center_f32 = self.spec.unravel(state.params_flat)
-            shared_tree = self._member_cast(center_f32)
+            with stage(PERTURB):
+                center_f32 = self.spec.unravel(state.params_flat)
+                shared_tree = self._member_cast(center_f32)
 
         def chunk_body(_, xs):
             offs_c, signs_c, keys_c = xs
 
             def member_eval(off, sign, key):
                 if cfg.low_rank:
-                    nvec = self.table.slice(off, self.noise_dim)
+                    with stage(NOISE):
+                        nvec = self.table.slice(off, self.noise_dim)
                     if self._carry_init is not None:
                         # recurrent: dense perturbation materialized ONCE
                         # per episode (ops/lowrank.py tree form) — noise
@@ -713,44 +718,48 @@ class ESEngine:
                         # standard carry-threaded scan
                         from ..ops.lowrank import lowrank_tree_perturb
 
-                        theta_tree = lowrank_tree_perturb(
-                            self.lr_spec, center_f32, nvec,
-                            state.sigma * sign,
-                        )
+                        with stage(PERTURB):
+                            theta_tree = lowrank_tree_perturb(
+                                self.lr_spec, center_f32, nvec,
+                                state.sigma * sign,
+                            )
+                            params = self._member_cast(theta_tree)
                         rollout = self._rollout
-                        params = self._member_cast(theta_tree)
                         if self._obs_norm:
                             params = (params, state.obs_stats)
                         return self._member_rollout(rollout, params, key)
                     # MLP: packed (A||B||bias) factors — dim is the LR
                     # noise_dim, and no dense noise matrix ever exists on
                     # this path
-                    lrn = self.lr_spec.unpack(nvec)
                     rollout = self._rollout_lowrank
-                    params = (
-                        shared_tree,
-                        self._member_cast(lrn),
-                        self._member_cast(state.sigma * sign),
-                    )
+                    with stage(PERTURB):
+                        params = (
+                            shared_tree,
+                            self._member_cast(self.lr_spec.unpack(nvec)),
+                            self._member_cast(state.sigma * sign),
+                        )
                     if self._obs_norm:
                         params = (params, state.obs_stats)
                     return self._member_rollout(rollout, params, key)
-                eps = self.table.slice(off, dim)
+                with stage(NOISE):
+                    eps = self.table.slice(off, dim)
                 if cfg.decomposed:
                     rollout = self._rollout_decomposed
-                    params = (
-                        shared_tree,
-                        self._member_cast(self.spec.unravel(eps)),
-                        self._member_cast(state.sigma * sign),
-                    )
+                    with stage(PERTURB):
+                        params = (
+                            shared_tree,
+                            self._member_cast(self.spec.unravel(eps)),
+                            self._member_cast(state.sigma * sign),
+                        )
                     if self._obs_norm:
                         params = (params, state.obs_stats)
                 else:
                     rollout = self._rollout
-                    theta = state.params_flat + state.sigma * sign * eps
-                    # once-per-member cast (bf16 path): the rollout scan
-                    # below runs on dtype-pure params, no per-step casts
-                    params = self._member_cast(self.spec.unravel(theta))
+                    with stage(PERTURB):
+                        theta = state.params_flat + state.sigma * sign * eps
+                        # once-per-member cast (bf16 path): the rollout scan
+                        # below runs on dtype-pure params, no per-step casts
+                        params = self._member_cast(self.spec.unravel(theta))
                     if self._obs_norm:
                         # every member this generation normalizes with the
                         # SAME stats snapshot (vmap broadcasts the pack)
@@ -801,11 +810,13 @@ class ESEngine:
         """Population-batched evaluation with the Pallas streamed forward:
         one policy call per env step for the whole chunk, every layer's ε
         DMA'd from the table — no member noise tree is ever materialized."""
-        shared_tree = self.spec.unravel(state.params_flat)
+        with stage(PERTURB):
+            shared_tree = self.spec.unravel(state.params_flat)
 
         def chunk_body(_, xs):
             offs_c, signs_c, keys_c = xs
-            c = state.sigma * signs_c
+            with stage(PERTURB):
+                c = state.sigma * signs_c
 
             def batched_apply(obs_batch):
                 if self._obs_norm:
@@ -823,6 +834,7 @@ class ESEngine:
 
         return self._scan_chunks(chunk_body, member_offs, signs, member_keys, n_chunks)
 
+    @stage(GATHER)
     def _gather_global(self, fitness_local, bc_local, steps_local):
         """Device-major all_gather → identical global arrays on every device.
 
@@ -845,6 +857,7 @@ class ESEngine:
             bc = bc[: cfg.population_size]
         return fitness, bc, steps
 
+    @stage(GRAD)
     def _local_grad(self, state: ESState, weights, reduction_offs):
         """This device's pre-psum partial of the rank-weighted estimator.
 
@@ -903,9 +916,11 @@ class ESEngine:
     def _update_from_weights(self, state: ESState, weights, reduction_offs):
         """Optax step from per-member rank weights. Identical on all devices."""
         grad_local = self._local_grad(state, weights, reduction_offs)
-        grad_ascent = jax.lax.psum(grad_local, POP_AXIS)
+        with stage(GATHER):
+            grad_ascent = jax.lax.psum(grad_local, POP_AXIS)
         return self._finish_update(state, grad_ascent)
 
+    @stage(UPDATE)
     def _finish_update(self, state: ESState, grad_ascent):
         """Weight decay + optax step + σ annealing from a replicated ascent
         direction (identical on every device by construction)."""
@@ -968,22 +983,25 @@ class ESEngine:
         # NaN-safe ranking: a failed rollout (NaN/inf fitness) is dropped and
         # survivors renormalized — same semantics as the host backend's
         # utils/fault.py::rank_weights_with_failures, but inside the program
-        weights, n_valid = centered_rank_safe(fitness)
+        with stage(RANK):
+            weights, n_valid = centered_rank_safe(fitness)
         new_state, gnorm = self._update_from_weights(state, weights, red_offs)
+        with stage(UPDATE):
+            # post-update anomaly guard input: replicated boolean — a
+            # non-finite parameter vector or update norm after the optax
+            # step means ES.train must reject this generation (restore the
+            # previous state) instead of training on poisoned params
+            update_finite = jnp.logical_and(
+                jnp.isfinite(gnorm),
+                jnp.isfinite(new_state.params_flat).all(),
+            )
         metrics = {
             "fitness": fitness,
             "bc": bc,
             "steps": steps,
             "grad_norm": gnorm,
             "n_valid": n_valid,
-            # post-update anomaly guard input: replicated boolean — a
-            # non-finite parameter vector or update norm after the optax
-            # step means ES.train must reject this generation (restore the
-            # previous state) instead of training on poisoned params
-            "update_finite": jnp.logical_and(
-                jnp.isfinite(gnorm),
-                jnp.isfinite(new_state.params_flat).all(),
-            ),
+            "update_finite": update_finite,
         }
         return new_state, metrics
 

@@ -54,6 +54,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..envs.rollout import make_rollout
 from ..obs.spans import NULL_TELEMETRY
+from ..obs.trace import (GATHER, GRAD, NOISE, PERTURB, RANK, SAMPLE, UPDATE,
+                         stage)
 from ..ops.gradient import fold_mirrored_weights
 from ..ops.lowrank import lowrank_program_factors, lowrank_program_leaf_noise
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
@@ -346,17 +348,22 @@ class ShardedESEngine:
         """Evaluate one chunk of (global) member ids: build the chunk's
         perturbed trees leaf-by-leaf (each block sharded (pop, *rule)) and
         vmap the rollout over members."""
-        rows, signs = self._member_rows_signs(ids)
-        keys = jnp.take(member_keys, rows, axis=0)
-        scale = state.sigma * signs  # (chunk,)
+        with stage(SAMPLE):
+            rows, signs = self._member_rows_signs(ids)
+            keys = jnp.take(member_keys, rows, axis=0)
+        with stage(PERTURB):
+            scale = state.sigma * signs  # (chunk,)
         leaves = jax.tree_util.tree_leaves(state.params)
         theta_leaves = []
         for i, leaf in enumerate(leaves):
-            eps = self._row_noise(i, leaf_keys[i], offsets, rows, table_data)
-            eps = jax.lax.with_sharding_constraint(
-                eps, self._batched_shardings[i])
-            b = scale.reshape((ids.shape[0],) + (1,) * leaf.ndim)
-            theta_leaves.append(leaf[None] + b * eps)
+            with stage(NOISE):
+                eps = self._row_noise(
+                    i, leaf_keys[i], offsets, rows, table_data)
+                eps = jax.lax.with_sharding_constraint(
+                    eps, self._batched_shardings[i])
+            with stage(PERTURB):
+                b = scale.reshape((ids.shape[0],) + (1,) * leaf.ndim)
+                theta_leaves.append(leaf[None] + b * eps)
         theta = jax.tree_util.tree_unflatten(self._treedef, theta_leaves)
         res = jax.vmap(self._rollout, in_axes=(0, 0))(theta, keys)
         return res.total_reward, res.bc, res.steps
@@ -366,8 +373,9 @@ class ShardedESEngine:
         # rollout keys: one per PAIR when mirrored (common random numbers
         # across the ± twins), one per member otherwise — the replicated
         # engine's exact keying, so table-mode fitness matches it
-        member_keys = jax.random.split(rkey, self.rows_global)
-        ids = jnp.arange(self.members_padded, dtype=jnp.int32)
+        with stage(SAMPLE):
+            member_keys = jax.random.split(rkey, self.rows_global)
+            ids = jnp.arange(self.members_padded, dtype=jnp.int32)
         if self.n_eval_chunks == 1:
             f, bc, st = self._eval_chunk_body(
                 state, offsets, leaf_keys, member_keys, ids, table_data)
@@ -381,12 +389,18 @@ class ShardedESEngine:
             f = f.reshape(self.members_padded)
             bc = bc.reshape(self.members_padded, self.bc_dim)
             st = st.reshape(self.members_padded)
-        alive = jnp.arange(self.members_padded) < cfg.population_size
-        steps = jnp.where(alive, st, 0).sum()
-        return (f[: cfg.population_size], bc[: cfg.population_size], steps)
+        # no explicit collective here (GSPMD places them); the stage keeps
+        # the replicated engine's name for the same work: ghost masking and
+        # the global views
+        with stage(GATHER):
+            alive = jnp.arange(self.members_padded) < cfg.population_size
+            steps = jnp.where(alive, st, 0).sum()
+            return (f[: cfg.population_size], bc[: cfg.population_size],
+                    steps)
 
     # ------------------------------------------------------------- update
 
+    @stage(GRAD)
     def _weighted_noise_sum(self, state, offsets, leaf_keys, weights,
                             table_data):
         """grad tree = Σ_rows w_row · ε_row / (population · σ), chunked
@@ -457,16 +471,11 @@ class ShardedESEngine:
 
     # ------------------------------------------------------------- body
 
-    def _generation_body(self, state: ShardedESState, table_data):
+    @stage(UPDATE)
+    def _finish_update(self, state: ShardedESState, grad, n_valid):
+        """Weight decay + optax step + σ annealing + the in-program
+        anomaly rollback, from the grad tree."""
         cfg = self.config
-        okey, rkey = _gen_keys(state)
-        offsets = self._offsets(okey)
-        leaf_keys = self._leaf_keys(okey)
-        fitness, bc, steps = self._eval_all(
-            state, offsets, leaf_keys, rkey, table_data)
-        weights, n_valid = centered_rank_safe(fitness)
-        grad = self._weighted_noise_sum(
-            state, offsets, leaf_keys, weights, table_data)
         if cfg.weight_decay > 0.0:
             grad = jax.tree_util.tree_map(
                 lambda g, p: g - cfg.weight_decay * p, grad, state.params)
@@ -504,21 +513,39 @@ class ShardedESEngine:
             generation=jnp.where(ok, state.generation + 1, state.generation),
             sigma=jnp.where(ok, new_sigma, state.sigma),
         )
+        return new_state, gnorm, update_finite
+
+    def _generation_body(self, state: ShardedESState, table_data):
+        with stage(SAMPLE):
+            okey, rkey = _gen_keys(state)
+            offsets = self._offsets(okey)
+            leaf_keys = self._leaf_keys(okey)
+        fitness, bc, steps = self._eval_all(
+            state, offsets, leaf_keys, rkey, table_data)
+        with stage(RANK):
+            weights, n_valid = centered_rank_safe(fitness)
+        grad = self._weighted_noise_sum(
+            state, offsets, leaf_keys, weights, table_data)
+        new_state, gnorm, update_finite = self._finish_update(
+            state, grad, n_valid)
         # In-program best-member reconstruction: ES.train snapshots the
         # generation's best θ on improvement; with the pre-step center
         # donated it cannot be rebuilt host-side afterwards, so the
         # program emits it — sharded like the params (per-device cost =
         # one extra param shard; the host gathers only on improvement).
-        safe_fit = jnp.where(jnp.isfinite(fitness), fitness, -jnp.inf)
-        best_rows, best_signs = self._member_rows_signs(
-            jnp.argmax(safe_fit)[None])
+        with stage(RANK):
+            safe_fit = jnp.where(jnp.isfinite(fitness), fitness, -jnp.inf)
+            best_rows, best_signs = self._member_rows_signs(
+                jnp.argmax(safe_fit)[None])
         best_leaves = []
         for i, leaf in enumerate(jax.tree_util.tree_leaves(state.params)):
-            eps = self._row_noise(
-                i, leaf_keys[i], offsets, best_rows, table_data)[0]
-            best_leaves.append(jax.lax.with_sharding_constraint(
-                leaf + state.sigma * best_signs[0] * eps,
-                self._param_sharding_leaves[i]))
+            with stage(NOISE):
+                eps = self._row_noise(
+                    i, leaf_keys[i], offsets, best_rows, table_data)[0]
+            with stage(PERTURB):
+                best_leaves.append(jax.lax.with_sharding_constraint(
+                    leaf + state.sigma * best_signs[0] * eps,
+                    self._param_sharding_leaves[i]))
         metrics = {
             "fitness": fitness,
             "bc": bc,

@@ -1,3 +1,4 @@
+from ..obs.trace import annotate, trace
 from .backend import (compile_event_counts, enable_compilation_cache,
                       enable_cpu_gloo_collectives, force_cpu_backend,
                       install_compile_event_counters,
@@ -6,7 +7,6 @@ from .checkpoint import (PeriodicCheckpointer, latest_checkpoint,
                          restore_checkpoint, save_checkpoint)
 from .fault import mask_and_renormalize, rank_weights_with_failures, valid_mask
 from .metrics import JsonlWriter, MultiWriter, TensorBoardWriter
-from .profiler import annotate, timed_generations, trace
 
 __all__ = [
     "compile_event_counts",
@@ -26,6 +26,5 @@ __all__ = [
     "MultiWriter",
     "TensorBoardWriter",
     "annotate",
-    "timed_generations",
     "trace",
 ]

@@ -1,0 +1,165 @@
+"""The stage vocabulary of a generation (obs/trace.py) as the compiled
+programs carry it, and ``Telemetry.phase`` as a span in the profiler's
+trace (docs/observability.md, "Stages inside the generation program").
+
+No wall-clock assertion anywhere: the xdist workers share the CPU.
+"""
+
+import re
+import sys
+
+import jax
+import optax
+import pytest
+
+from estorch_tpu import ES, JaxAgent, MLPPolicy
+from estorch_tpu.envs import CartPole
+from estorch_tpu.obs.spans import Telemetry
+from estorch_tpu.obs.trace import (ENV, GATHER, GRAD, NOISE, PERTURB, POLICY,
+                                   RANK, SAMPLE, SCOPE_PREFIX, STAGES, UPDATE,
+                                   annotate, stage, trace)
+
+SCOPE = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(SCOPE_PREFIX)
+                   + r"([a-z_]+)")
+MATMUL = re.compile(r" = \S+ (?:dot|convolution)\(.*op_name=\"([^\"]*)\"")
+PHASES = ("dispatch", "device", "host_sync", "record")
+
+# every form runs every stage; what differs is where the stage's work is
+FORMS = {
+    "standard": {},
+    "decomposed": {"decomposed": True},
+    "low_rank": {"low_rank": 1},
+    "streamed": {"streamed": True},
+    "obs_norm": {"obs_norm": True},
+    "sharded_program": {"shard_params": True},
+    "sharded_table": {"shard_params": True, "noise_mode": "table"},
+}
+
+
+def _es(**over):
+    kw = dict(
+        policy=MLPPolicy, agent=JaxAgent, optimizer=optax.adam,
+        population_size=16, sigma=0.05, seed=0,
+        policy_kwargs={"action_dim": 2, "hidden": (8,)},
+        agent_kwargs={"env": CartPole(), "horizon": 10},
+        optimizer_kwargs={"learning_rate": 1e-2}, table_size=1 << 14)
+    kw.update(over)
+    return ES(**kw)
+
+
+@pytest.fixture
+def keyed_by_source():
+    """The suite's persistent compile cache keys programs WITHOUT their
+    metadata, so an entry written before a scope existed would be served
+    with the old ``op_name``s (the hazard docs/observability.md names).
+    With the metadata in the key this test compiles what it reads."""
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    yield
+    jax.config.update(flag, before)
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_compiled_generation_names_every_stage(form, keyed_by_source):
+    es = _es(**FORMS[form])
+    engine = es.engine
+    args = (es.state,)
+    if getattr(engine, "noise_mode", None) == "table":
+        args += (engine.table.data,)
+    text = engine._generation_step.lower(*args).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    found = {s for name in names for s in SCOPE.findall(name)}
+    assert found == set(STAGES), (
+        f"{form}: stages missing {set(STAGES) - found}, unknown "
+        f"{found - set(STAGES)}")
+    # the matmuls of the rollout's while body are the forward's or the
+    # physics'; the only others are the update's second pass over the noise
+    matmuls = [SCOPE.findall(m) for line in text.splitlines()
+               for m in MATMUL.findall(line)]
+    assert matmuls, f"{form}: no dot or convolution in the compiled text"
+    for stack in matmuls:
+        assert stack, f"{form}: a dot outside every stage"
+        assert GRAD in stack or POLICY in stack or ENV in stack, stack
+    assert any(POLICY in stack for stack in matmuls)
+
+
+@pytest.mark.parametrize("use", ["context", "decorator"])
+def test_stage_scopes_a_name_stack(use):
+    assert len(set(STAGES)) == len(STAGES) == 9
+    assert (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD,
+            UPDATE) == STAGES
+    if use == "context":
+        def f(x):
+            with stage(NOISE):
+                return x * 2.0
+    else:
+        @stage(NOISE)
+        def f(x):
+            return x * 2.0
+    text = jax.jit(f).lower(1.0).as_text(debug_info=True)
+    assert SCOPE_PREFIX + NOISE in text
+    with pytest.raises(ValueError, match="unknown stage"):
+        stage("forward")
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    events = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                stats = dict(e.stats)
+                if "generation" in stats:
+                    events.append((e.start_ns, e.start_ns + e.duration_ns,
+                                   e.name, int(stats["generation"])))
+    return sorted(events)
+
+
+def test_phases_are_spans_in_the_profilers_trace(tmp_path):
+    es = _es()
+    es.train(1, verbose=False)      # compile outside the trace
+    first = es.obs.generation
+    nested = Telemetry()
+    with trace(str(tmp_path)):
+        es.train(2, verbose=False)
+        with nested.phase("update"):
+            with nested.phase("obsnorm_merge"):
+                pass
+    events = _host_events(tmp_path)
+    ours = [e for e in events if e[2] in PHASES]
+    # dispatch, device, host_sync, record of each generation, in that
+    # order, none overlapping the next, each carrying its generation
+    assert [e[2] for e in ours] == list(PHASES) * 2
+    assert [e[3] for e in ours] == [first] * 4 + [first + 1] * 4
+    for a, b in zip(ours, ours[1:]):
+        assert a[1] <= b[0], (a, b)
+    # the records are what they were: same keys, nested names joined by "/"
+    for rec in es.history[-2:]:
+        assert set(rec["phases"]) == set(PHASES)
+        assert all(v >= 0.0 for v in rec["phases"].values())
+    assert set(nested.take_phases()) == {"update", "update/obsnorm_merge"}
+    outer, = [e for e in events if e[2] == "update"]
+    inner, = [e for e in events if e[2] == "update/obsnorm_merge"]
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+@pytest.mark.parametrize("profiler", ["importable", "not_importable"])
+def test_phase_records_with_no_profiler_running(profiler, monkeypatch):
+    if profiler == "not_importable":
+        monkeypatch.setitem(sys.modules, "jax", None)   # ``import jax`` raises
+        with pytest.raises(ImportError):
+            annotate("dispatch")
+    obs = Telemetry()
+    for _ in range(2):      # the second phase takes the swapped-in no-op
+        with obs.phase("dispatch"):
+            with obs.phase("lookup"):
+                pass
+    assert [e["name"] for e in obs.recorder.events()
+            if e["kind"] == "span"] == ["dispatch/lookup", "dispatch"] * 2
+    assert obs.hists.snapshot()["phase/dispatch"]["count"] == 2
+    phases = obs.take_phases()
+    assert set(phases) == {"dispatch", "dispatch/lookup"}
+    assert phases["dispatch"] >= phases["dispatch/lookup"] >= 0.0

@@ -18,7 +18,6 @@ from estorch_tpu.utils import (
     rank_weights_with_failures,
     restore_checkpoint,
     save_checkpoint,
-    timed_generations,
     valid_mask,
 )
 
@@ -322,14 +321,6 @@ class TestFaultTolerance:
 
 
 class TestProfiler:
-    def test_timed_generations(self):
-        es = _device_es()
-        stats = timed_generations(es, n=2, warmup=1)
-        assert stats["generations"] == 2
-        assert stats["env_steps"] > 0
-        assert stats["env_steps_per_sec"] > 0
-        assert stats["compile_time_s"] is not None
-
     @pytest.mark.slow
     def test_trace_writes_profile(self, tmp_path):
         from estorch_tpu.utils import annotate, trace
