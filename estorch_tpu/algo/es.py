@@ -344,16 +344,20 @@ class ES:
             self.state = self.engine.init_state(flat, state_key)
             self._post_engine_init()
             return
-        dec_apply = None
-        if self._decomposed:
-            from ..models.decomposed import mlp_decomposed_apply, supports_decomposed
+        from ..models.decomposed import mlp_decomposed_apply, supports_decomposed
 
-            if not supports_decomposed(self.module):
-                raise ValueError(
-                    "decomposed=True currently supports MLPPolicy without VBN "
-                    "(models/decomposed.py); got "
-                    f"{type(self.module).__name__}"
-                )
+        dec_apply = None
+        has_decomposed_form = supports_decomposed(self.module)
+        if self._decomposed and not has_decomposed_form:
+            raise ValueError(
+                "decomposed=True currently supports MLPPolicy without VBN "
+                "(models/decomposed.py); got "
+                f"{type(self.module).__name__}"
+            )
+        if has_decomposed_form:
+            # built whenever the module has a decomposed form, not only
+            # under decomposed=True: the engine takes the pair-shared
+            # forward for mirrored runs by itself (ESEngine.forward_form)
             module = self.module
 
             def dec_apply(shared, noise, c, obs):
@@ -361,10 +365,9 @@ class ES:
 
         str_apply = None
         if self._streamed:
-            from ..models.decomposed import supports_decomposed
             from ..ops.pallas_noise import flat_layer_offsets, mlp_streamed_apply
 
-            if not supports_decomposed(self.module):
+            if not has_decomposed_form:
                 raise ValueError(
                     "streamed=True currently supports MLPPolicy without VBN "
                     f"(ops/pallas_noise.py); got {type(self.module).__name__}"
@@ -381,7 +384,7 @@ class ES:
 
         lr_apply, lr_spec = None, None
         if self._low_rank:
-            from ..models.decomposed import mlp_lowrank_apply, supports_decomposed
+            from ..models.decomposed import mlp_lowrank_apply
             from ..ops.lowrank import make_lowrank_spec, make_lowrank_tree_spec
 
             if self._recurrent:
@@ -393,7 +396,7 @@ class ES:
                 lr_spec = make_lowrank_tree_spec(
                     self._spec.unravel(flat), self._low_rank
                 )
-            elif not supports_decomposed(self.module):
+            elif not has_decomposed_form:
                 raise ValueError(
                     "low_rank supports MLPPolicy without VBN "
                     "(ops/lowrank.py) and recurrent policies (tree form); "
@@ -519,6 +522,12 @@ class ES:
         # (host sample/eval/update, pooled obsnorm merge, engine compile
         # events) land in the same per-generation accumulator
         self.engine.telemetry = self.obs
+        if hasattr(self.engine, "forward_form"):
+            # so a record's counters and a flight-recorder dump say which
+            # forward ran (the string is skipped by the numeric exporters)
+            self.obs.counters.gauge("forward_form", self.engine.forward_form)
+            self.obs.counters.gauge("noise_rows_per_generation",
+                                    self.engine.noise_rows_per_generation)
         # analytic FLOPs/bytes model of this configuration (obs/profile/):
         # rides the first generation record so `obs profile` can turn the
         # phase spans into achieved rates against a roofline.  Building it
@@ -1104,6 +1113,12 @@ class ES:
             "obs_norm": self._obs_norm,
             "low_rank": self._low_rank,
             "decomposed": self._decomposed,
+            # which forward the engine resolved at build, and how many
+            # noise-table rows it gathers per generation (None: an engine
+            # that does not evaluate on the device path)
+            "forward_form": getattr(self.engine, "forward_form", None),
+            "noise_rows_per_generation": getattr(
+                self.engine, "noise_rows_per_generation", None),
             "streamed": self._streamed,
             "shard_params": self._shard_params,
         }

@@ -1,17 +1,28 @@
-"""Decomposed population forward: one shared matmul + a streamed noise term.
+"""Decomposed population forward: one shared matmul + a noise term.
 
 For a linear layer with shared center weights W and per-member noise E_i,
 
     z_i = x_i @ (W + c_i E_i)  =  x_i @ W  +  c_i (x_i @ E_i),   c_i = σ s_i
 
 — exact (a reordering of the same contractions, not an approximation).  The
-engine's standard path materializes W + c_i E_i per member, so every layer
-is a batched per-member matvec.  Decomposed, the W-term of every layer is a
-SINGLE dense (population, d) @ (d, h) matmul (W enters vmap un-batched), a
-shape the MXU eats whole; only the noise term remains per-member.  On TPU a
-Pallas kernel can further stream E_i from the HBM table tile-by-tile
-(ROADMAP item 1); this module is the pure-JAX form that already exposes the
-big matmul to XLA.
+materialized path builds W + c_i E_i per member, so every layer is a batched
+per-member matvec that streams one weight set per member from HBM at every
+step.  Decomposed, the W-term of every layer is a SINGLE dense
+(population, d) @ (d, h) matmul (W enters vmap un-batched), a shape the MXU
+eats whole.  What the noise term reads depends on who vmaps this function
+(parallel/engine.py ``_eval_local``):
+
+- **pair-shared** (mirrored runs, the engine's choice whenever this forward
+  applies): members 2k and 2k+1 are θ ± σ·ε_k, so the engine vmaps over
+  PAIRS with ε_k un-batched across the pair's two members — x₊@ε_k and
+  x₋@ε_k are one [2,d]×[d,h] product and ε is read once per pair per step:
+  half the weight bytes of the materialized path.
+- **per-member** (``decomposed=True`` on unmirrored runs): one ε tree per
+  member, the same bytes per step as materialized weights; only the W-term
+  gains.
+
+On TPU a Pallas kernel can instead stream E_i from the HBM table tile by
+tile (``streamed``, ops/pallas_noise.py); this module is the pure-JAX form.
 
 Scope: MLPPolicy-shaped networks (Dense stacks, tanh/… activations,
 optional continuous squash).  VBN layers are not yet supported here — the
@@ -52,18 +63,26 @@ def mlp_decomposed_apply(
 
     ``noise_params`` is the member's ε unraveled into the SAME pytree shape
     as ``shared_params`` (ops/params.py spec.unravel of the raw table
-    slice); ``scale`` is σ·sign (a traced scalar).
+    slice); ``scale`` is σ·sign (a traced float32 scalar).
+
+    Rounds as often as the materialized layer does and no more: both dots
+    accumulate into float32, the sum is formed in float32 and cast ONCE to
+    the compute dtype (that of the kernels).
     """
     names = _ordered_dense_names(shared_params)
     x = obs
-    for i, name in enumerate(names):
+    f32 = jnp.float32
+    for name in names:
         w = shared_params[name]["kernel"]
         b = shared_params[name]["bias"]
         nw = noise_params[name]["kernel"]
         nb = noise_params[name]["bias"]
         # x @ w is shared across members (un-batched under vmap → one dense
-        # population-wide matmul); x @ nw is the per-member noise term
-        x = (x @ w) + scale * (x @ nw) + b + scale * nb
+        # population-wide matmul); x @ nw is the noise term
+        z = (jnp.dot(x, w, preferred_element_type=f32)
+             + scale * jnp.dot(x, nw, preferred_element_type=f32)
+             + b.astype(f32) + scale * nb.astype(f32))
+        x = z.astype(w.dtype)
         if name != "head":
             x = module.activation(x)
     if not module.discrete:

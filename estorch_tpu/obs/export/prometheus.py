@@ -61,6 +61,9 @@ GAUGE_NAMES = frozenset({
     # elastic multi-host membership (algo/scheduler.py _HostSource):
     # live-host count is a level, not a monotone count
     "elastic_hosts",
+    # noise-table rows one generation's evaluation gathers: a fact of the
+    # forward the engine resolved at build (parallel/engine.py forward_form)
+    "noise_rows_per_generation",
 })
 
 _METRIC_OK = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")

@@ -178,8 +178,12 @@ def scenario_variant_key(seed: int, variant) -> jax.Array:
 def member_noise(table: NoiseTable, offsets: jax.Array, signs: jax.Array, dim: int) -> jax.Array:
     """Materialize signed noise rows for a batch of members: (n, dim).
 
-    Only used for small batches (tests, chunked gradient accumulation);
-    the engine never materializes the full population's noise at once.
+    Only used for small batches (tests, chunked gradient accumulation).
+    The engine's own evaluation gathers its rows inside ``_eval_local``
+    (parallel/engine.py) and DOES hold a whole chunk's noise at once —
+    with ``eval_chunk=0`` the whole local shard's: one row per member, or
+    one per antithetic pair in the pair-shared form; only ``streamed`` and
+    ``low_rank`` avoid a ``(rows, dim)`` slab.
     """
     rows = jax.vmap(lambda o: table.slice(o, dim))(offsets)
     return rows * signs[:, None]

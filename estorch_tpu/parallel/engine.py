@@ -69,10 +69,13 @@ class EngineConfig:
     episodes_per_member: int = 1  # rollouts averaged per member (device
     # path only): reduces fitness noise AND raises per-step batch (n·e rows
     # through the policy matmuls — better MXU use for small populations)
-    decomposed: bool = False  # z = x@W + c(x@E): the shared-W term of every
-    # layer becomes ONE population-wide dense matmul (W un-batched under
-    # vmap) instead of per-member matvecs against materialized perturbed
-    # weights; needs a decomposed_apply (models/decomposed.py)
+    decomposed: bool = False  # z = x@W + c(x@E) for UNMIRRORED runs: the
+    # shared-W term of every layer becomes one population-wide dense matmul
+    # (W un-batched under vmap); the noise term stays a per-member matvec
+    # against a per-member ε tree, so it reads the same bytes per step as
+    # materialized weights. Mirrored runs need no flag: the engine takes
+    # the pair-shared form of the same identity whenever it is given a
+    # decomposed_apply (ESEngine.forward_form; models/decomposed.py)
     noise_kernel: bool = False  # Pallas streamed update reduction
     # (ops/pallas_noise.py): ε rows DMA'd from the HBM table through
     # double-buffered VMEM and FMA'd in place — no (chunk, dim)
@@ -459,6 +462,26 @@ class ESEngine:
             self.rows_padded = self.members_local * self.n_devices
         self.members_padded = self.members_local * self.n_devices
         self.eval_chunk = _choose_eval_chunk(config.eval_chunk, self.members_local)
+        # which forward the generation program runs, resolved once from
+        # what the engine can observe (run manifest + telemetry gauges)
+        if config.streamed:
+            self.forward_form = "streamed"
+        elif config.low_rank:
+            self.forward_form = "low_rank"
+        elif (config.mirrored and decomposed_apply is not None
+              and carry_init is None and self.eval_chunk % 2 == 0):
+            # an antithetic pair's two members share ONE noise tree: per
+            # scan step the program reads ε once per pair, half the bytes
+            # of per-member perturbed weights (pairs must not straddle
+            # chunks, hence the even chunk)
+            self.forward_form = "pair_shared"
+        else:
+            self.forward_form = (
+                "decomposed" if config.decomposed else "materialised")
+        # noise-table rows the evaluation gathers per generation
+        self.noise_rows_per_generation = (
+            config.population_size // 2 if self.forward_form == "pair_shared"
+            else config.population_size)
 
         self._obs_norm = config.obs_norm  # always False when env is None
         # (the guard above rejects obs_norm for update-only engines)
@@ -508,7 +531,7 @@ class ESEngine:
         )
 
         self._rollout_batched = None
-        if config.streamed:
+        if self.forward_form in ("streamed", "pair_shared"):
             from ..envs.rollout import make_batched_rollout
 
             self._rollout_batched = make_batched_rollout(env, config.horizon)
@@ -537,26 +560,25 @@ class ESEngine:
             self._rollout_lowrank = make_rollout(env, lr_packed_apply, config.horizon)
 
         self._rollout_decomposed = None
-        if config.decomposed:
-            def packed_apply(packed, obs):
-                shared, noise, c = packed
-                return decomposed_apply(shared, noise, c, obs)
+        if self.forward_form in ("pair_shared", "decomposed"):
+            def decomposed_forward(shared, noise, c, stats, obs):
+                # one raw observation → f32 policy output.  The trees arrive
+                # pre-cast from _eval_local; the scale c stays f32 (a bf16
+                # σ·sign would be 0.1–0.4% off σ).  Raw obs are normalized in
+                # f32, then cast — the same order as the standard path above
+                if config.obs_norm:
+                    obs = normalize_obs(obs, stats, clip)
+                if self._bf16:
+                    _check_bf16_params((shared, noise))
+                    obs = _bf16_obs(obs)
+                return decomposed_apply(shared, noise, c, obs).astype(
+                    jnp.float32)
 
-            if self._bf16:
-                # packed (shared, noise, c) params — INCLUDING the scale c —
-                # arrive pre-cast from _eval_local; only obs/output shim here
-                packed_apply = _bf16_io_apply(packed_apply)
-
-            if config.obs_norm:
-                base_dec_apply = packed_apply
-
-                def packed_apply(packed, obs):
-                    inner, stats = packed
-                    return base_dec_apply(inner, normalize_obs(obs, stats, clip))
-
-            self._rollout_decomposed = make_rollout(
-                env, packed_apply, config.horizon
-            )
+            self._decomposed_forward = decomposed_forward
+            if self.forward_form == "decomposed":
+                self._rollout_decomposed = make_rollout(
+                    env, lambda packed, obs: decomposed_forward(*packed, obs),
+                    config.horizon)
 
         # All inputs/outputs are fully replicated (P()); the population axis
         # only exists INSIDE the program (axis_index-derived shards).
@@ -694,6 +716,10 @@ class ESEngine:
             return self._eval_local_streamed(
                 state, member_offs, signs, member_keys, n_chunks
             )
+        if self.forward_form == "pair_shared":
+            return self._eval_local_pairs(
+                state, member_offs, signs, member_keys, n_chunks
+            )
         if cfg.decomposed or cfg.low_rank:
             # shared center tree: unraveled (and, for bf16, cast) ONCE,
             # enters the member vmap as an un-batched constant — its matmuls
@@ -746,13 +772,13 @@ class ESEngine:
                 if cfg.decomposed:
                     rollout = self._rollout_decomposed
                     with stage(PERTURB):
+                        # (centre, ε tree, c, stats): c = σ·sign stays f32
                         params = (
                             shared_tree,
                             self._member_cast(self.spec.unravel(eps)),
-                            self._member_cast(state.sigma * sign),
+                            state.sigma * sign,
+                            state.obs_stats,
                         )
-                    if self._obs_norm:
-                        params = (params, state.obs_stats)
                 else:
                     rollout = self._rollout
                     with stage(PERTURB):
@@ -805,6 +831,63 @@ class ESEngine:
             bc.reshape(self.members_local, self.bc_dim),
             st.reshape(self.members_local),
         )
+
+    def _eval_local_pairs(self, state, member_offs, signs, member_keys, n_chunks):
+        """Pair-shared evaluation (``forward_form == "pair_shared"``): one
+        table row and one ε tree per antithetic PAIR.  The decomposed
+        forward is vmapped pairs ⊃ signs (⊃ episodes) with ε batched over
+        the pairs only, so x₊@ε and x₋@ε are one [2,m]×[m,n] product that
+        reads ε once — half the weight bytes per step of per-member
+        perturbed weights.  Only the forward sees the pair axis: the env
+        steps a flat member axis (one policy call per step for the chunk),
+        whose arrays tile the way the per-member path's do; a (pairs, 2) env
+        batch put the sign axis in the sublanes of every physics array on
+        the v5e and cost more than the forward saved."""
+        cfg = self.config
+        n_ep = cfg.episodes_per_member
+        with stage(PERTURB):
+            shared_tree = self._member_cast(
+                self.spec.unravel(state.params_flat))
+        forward = self._decomposed_forward  # (centre, ε, c, stats, obs)
+        if n_ep > 1:
+            forward = jax.vmap(forward, in_axes=(None, None, None, None, 0))
+        forward = jax.vmap(forward, in_axes=(None, None, 0, None, 0))
+        forward = jax.vmap(forward, in_axes=(None, 0, 0, None, 0))
+
+        def chunk_body(_, xs):
+            # chunks are even and member-major (2k, 2k+1): whole pairs
+            offs_c, signs_c, keys_c = xs
+            lead = (offs_c.shape[0] // 2, 2) + ((n_ep,) if n_ep > 1 else ())
+            with stage(NOISE):
+                eps_c = jax.vmap(
+                    lambda off: self.table.slice(off, self.spec.dim)
+                )(offs_c[::2])
+            with stage(PERTURB):
+                noise_c = jax.vmap(
+                    lambda eps: self._member_cast(self.spec.unravel(eps))
+                )(eps_c)
+                c_c = state.sigma * signs_c.reshape(lead[:2])
+
+            def batched_apply(obs_batch):
+                out = forward(shared_tree, noise_c, c_c, state.obs_stats,
+                              obs_batch.reshape(lead + obs_batch.shape[1:]))
+                return out.reshape(obs_batch.shape[:1] + out.shape[len(lead):])
+
+            if n_ep > 1:
+                keys_c = jax.vmap(
+                    lambda key: jax.random.split(key, n_ep)
+                )(keys_c).reshape(-1, keys_c.shape[-1])
+            res = self._rollout_batched(batched_apply, keys_c)
+            f, bc, st = res.total_reward, res.bc, res.steps
+            if n_ep > 1:
+                # as _member_rollout: mean return, first episode's BC,
+                # steps summed
+                f = f.reshape(-1, n_ep).mean(axis=1)
+                bc = bc.reshape(-1, n_ep, self.bc_dim)[:, 0]
+                st = st.reshape(-1, n_ep).sum(axis=1)
+            return 0, (f, bc, st)
+
+        return self._scan_chunks(chunk_body, member_offs, signs, member_keys, n_chunks)
 
     def _eval_local_streamed(self, state, member_offs, signs, member_keys, n_chunks):
         """Population-batched evaluation with the Pallas streamed forward:

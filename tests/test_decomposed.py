@@ -1,6 +1,10 @@
 """Decomposed population forward: z = x@W + c(x@E) must be EXACTLY the
-standard materialized-weights path (it is a reordering, not an
-approximation), across feature combinations."""
+materialized-weights path (it is a reordering, not an approximation),
+across feature combinations — in both forms the engine runs it: pair-shared
+(mirrored runs, chosen by the engine) and per-member (``decomposed=True`` on
+unmirrored runs)."""
+
+import re
 
 import numpy as np
 import optax
@@ -8,8 +12,10 @@ import pytest
 
 import jax
 
-from estorch_tpu import ES, JaxAgent, MLPPolicy, PooledAgent
+from estorch_tpu import (ES, NS_ES, JaxAgent, MLPPolicy, PooledAgent,
+                         RecurrentPolicy)
 from estorch_tpu.envs import CartPole, Pendulum
+from estorch_tpu.parallel.engine import ESEngine
 
 
 def _pair(decomposed, **over):
@@ -27,6 +33,17 @@ def _pair(decomposed, **over):
     )
     kw.update(over)
     return ES(decomposed=decomposed, **kw)
+
+
+def _materialised(es):
+    """``es`` with its engine rebuilt WITHOUT a decomposed_apply: the
+    materialized-weights path, which no public option selects for a mirrored
+    MLP any more.  The state is engine-agnostic and carries over."""
+    es.engine = ESEngine(es.env, es._policy_apply, es._spec, es.table,
+                         es.optimizer, es.config, es.mesh)
+    es.engine.telemetry = es.obs
+    assert es.engine.forward_form == "materialised"
+    return es
 
 
 def _assert_equivalent(a, b, gens=3, exact=True, params_atol=1e-3):
@@ -51,9 +68,133 @@ def _assert_equivalent(a, b, gens=3, exact=True, params_atol=1e-3):
         np.testing.assert_allclose(pa, pb, rtol=1e-3, atol=params_atol)
 
 
+CONTINUOUS = dict(
+    policy_kwargs={"action_dim": 1, "hidden": (16,), "discrete": False,
+                   "action_scale": 2.0},
+    agent_kwargs={"env": Pendulum(), "horizon": 40},
+)
+
+# case → (ES keywords, exact?, params_atol)
+PAIR_CASES = {
+    "f32": ({}, True, None),
+    # bf16 admits a near-tie argmax flip between the two orderings
+    "bf16": ({"compute_dtype": "bfloat16"}, False, 0.1),
+    "obs_norm": ({"obs_norm": True}, True, None),
+    # 32 members over the suite's 8 devices: 4 a device, 2 chunks of one pair
+    "chunks": ({"eval_chunk": 2}, True, None),
+    # continuous rewards accumulate transcendental terms: rounding shows
+    "episodes": ({"episodes_per_member": 2, **CONTINUOUS}, False, 1e-3),
+}
+
+
+class TestPairSharedEquivalence:
+    """The engine's choice for a mirrored MLP against the materialized
+    path it replaces."""
+
+    @pytest.mark.parametrize("case", sorted(PAIR_CASES))
+    def test_matches_materialised(self, case):
+        over, exact, atol = PAIR_CASES[case]
+        pair = _pair(False, **over)
+        assert pair.engine.forward_form == "pair_shared"
+        if case == "chunks":
+            assert pair.engine.members_local // pair.engine.eval_chunk >= 2
+        _assert_equivalent(_materialised(_pair(False, **over)), pair,
+                           exact=exact, **({} if atol is None
+                                           else {"params_atol": atol}))
+
+    def test_matches_materialised_ns_es(self):
+        def make():
+            kw = dict(
+                policy=MLPPolicy, agent=JaxAgent, optimizer=optax.adam,
+                population_size=16, sigma=0.1, seed=7,
+                policy_kwargs={"action_dim": 2, "hidden": (8,)},
+                agent_kwargs={"env": CartPole(), "horizon": 50},
+                optimizer_kwargs={"learning_rate": 1e-2},
+                table_size=1 << 15, meta_population_size=2, k=3)
+            return NS_ES(**kw)
+
+        a, b = _materialised(make()), make()
+        assert b.engine.forward_form == "pair_shared"
+        a.train(3, verbose=False)
+        b.train(3, verbose=False)
+        for ra, rb in zip(a.history, b.history):
+            assert ra["reward_mean"] == pytest.approx(rb["reward_mean"],
+                                                      rel=1e-6)
+        for sa, sb in zip(a.meta_states, b.meta_states):
+            np.testing.assert_allclose(np.asarray(sa.params_flat),
+                                       np.asarray(sb.params_flat),
+                                       rtol=1e-4, atol=1e-5)
+
+
+ARRAY = re.compile(r"\b[a-z]+[0-9]+\[([0-9,]+)\]")
+
+
+class TestPairSharedStructure:
+    def test_program_holds_noise_per_pair_not_per_member(self):
+        """CPU compile at a small population: ε is gathered and unraveled
+        once per PAIR, and the noise dot is pair-batched ``[pairs, 2, ·]``.
+        Sizes chosen so no other array can be mistaken for the noise: 24
+        members, 12 pairs, dim 4·20+20+20·3+3 = 163."""
+        members, pairs, hidden = 24, 12, 20
+        es = _pair(False, population_size=members, device=jax.devices()[:1],
+                   policy_kwargs={"action_dim": 3, "hidden": (hidden,)},
+                   agent_kwargs={"env": CartPole(), "horizon": 5})
+        dim = es._spec.dim
+        assert dim == 163 and es.engine.noise_rows_per_generation == pairs
+        text = es.engine._generation_step.lower(es.state).compile().as_text()
+        shapes = {tuple(int(d) for d in m.split(","))
+                  for m in ARRAY.findall(text)}
+        assert (pairs, dim) in shapes, "no [pairs, dim] noise slab"
+        assert (members, dim) not in shapes, "a [members, dim] array exists"
+        assert (pairs, 4, hidden) in shapes, "ε kernels are not per pair"
+        assert not {s for s in shapes if s[0] == members and len(s) == 3
+                    and s[1:] in ((4, hidden), (hidden, 3))}, (
+            "a per-member weight or noise tensor exists")
+        dots = [ln for ln in text.splitlines()
+                if re.search(r" = \S+ dot\(", ln)]
+        assert any(re.search(rf"\[{pairs},2,{hidden}\]\S* dot\(", ln)
+                   for ln in dots), "no pair-batched [pairs, 2, ·] noise dot"
+
+    @pytest.mark.parametrize("name,over,form", [
+        ("mirrored_mlp", {}, "pair_shared"),
+        ("mirrored_mlp_decomposed_flag", {"decomposed": True}, "pair_shared"),
+        ("unmirrored", {"mirrored": False}, "materialised"),
+        ("unmirrored_decomposed", {"mirrored": False, "decomposed": True},
+         "decomposed"),
+        ("recurrent", {"policy": RecurrentPolicy,
+                       "policy_kwargs": {"action_dim": 2, "hidden": (8,),
+                                         "gru_size": 8}}, "materialised"),
+        ("vbn", {"policy_kwargs": {"action_dim": 2, "hidden": (16,),
+                                   "use_vbn": True}}, "materialised"),
+        ("low_rank", {"low_rank": 1}, "low_rank"),
+        ("streamed", {"streamed": True}, "streamed"),
+        # 6 members a device (8 devices) in chunks of 3: a pair must not
+        # straddle two chunks
+        ("odd_chunk", {"population_size": 48, "eval_chunk": 3},
+         "materialised"),
+    ])
+    def test_selection_rule(self, name, over, form):
+        over = dict(over)
+        es = _pair(over.pop("decomposed", False),
+                   agent_kwargs={"env": CartPole(), "horizon": 5}, **over)
+        assert es.engine.forward_form == form
+        rows = es.population_size // 2 if form == "pair_shared" \
+            else es.population_size
+        assert es.engine.noise_rows_per_generation == rows
+        cfg = es.run_manifest()["config"]
+        assert cfg["forward_form"] == form
+        assert cfg["noise_rows_per_generation"] == rows
+        gauges = es.obs.counters.snapshot()
+        assert gauges["forward_form"] == form
+        assert gauges["noise_rows_per_generation"] == rows
+        if name == "odd_chunk":
+            assert es.engine.eval_chunk == 3
+            es.train(1, verbose=False)  # the form it resolves to still runs
+
+
 class TestDecomposedEquivalence:
     def test_identical_to_standard_path(self):
-        _assert_equivalent(_pair(False), _pair(True))
+        _assert_equivalent(_materialised(_pair(False)), _pair(True))
 
     def test_identical_with_unmirrored_and_annealing(self):
         over = dict(mirrored=False, sigma_decay=0.9, sigma_min=0.02)
@@ -62,19 +203,14 @@ class TestDecomposedEquivalence:
     def test_continuous_with_episodes_matches_to_rounding(self):
         """Continuous rewards accumulate transcendental terms, so reordered
         matmul rounding shows at ~1e-7 — tolerance, not exactness, here."""
-        over = dict(
-            policy_kwargs={"action_dim": 1, "hidden": (16,), "discrete": False,
-                           "action_scale": 2.0},
-            agent_kwargs={"env": Pendulum(), "horizon": 40},
-            episodes_per_member=2,
-        )
+        over = dict(episodes_per_member=2, mirrored=False, **CONTINUOUS)
         _assert_equivalent(_pair(False, **over), _pair(True, **over), exact=False)
 
     def test_bf16_close_to_standard_bf16(self):
         # bf16 admits a near-tie argmax flip between the two orderings
         # (observed on XLA:CPU jax 0.4: one flipped member ⇒ ~5e-2 param
         # drift over 3 gens); f32 exactness above pins the identity itself
-        over = dict(compute_dtype="bfloat16")
+        over = dict(compute_dtype="bfloat16", mirrored=False)
         _assert_equivalent(_pair(False, **over), _pair(True, **over),
                            exact=False, params_atol=0.1)
 
