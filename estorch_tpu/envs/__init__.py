@@ -8,6 +8,7 @@ from .locomotion import (Cheetah2D, DeceptiveValley, Hopper2D,
                          Swimmer2D, Walker2D)
 from .pendulum import Pendulum
 from .rollout import RolloutResult, make_population_rollout, make_rollout, select_action
+from .sequence import TokenScoreEnv
 from .synthetic import RecallEnv, SyntheticEnv
 
 __all__ = [
@@ -27,6 +28,7 @@ __all__ = [
     "Pendulum",
     "RecallEnv",
     "SyntheticEnv",
+    "TokenScoreEnv",
     "RolloutResult",
     "make_population_rollout",
     "make_rollout",

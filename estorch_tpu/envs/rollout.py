@@ -100,6 +100,8 @@ def make_rollout(
     locomotion family's upright fraction) — measured gait claims instead
     of reward-scale ones.
     """
+    if getattr(env, "whole_episode", False):
+        return _make_whole_episode_rollout(env, policy_apply, carry_init)
     discrete = bool(env.discrete)
     stateful = carry_init is not None
     if stateful:
@@ -187,6 +189,26 @@ def make_rollout(
         return (
             (res, moments) if (with_obs_moments or with_env_metrics) else res
         )
+
+    return rollout
+
+
+def _make_whole_episode_rollout(env, policy_apply, carry_init):
+    """An env that scores a whole episode in one call (``whole_episode``:
+    envs/sequence.py): one policy call over the sequence the reset hands
+    out, no step scan, nothing to mask."""
+    if carry_init is not None:
+        raise ValueError("a whole-episode env calls the policy once; it "
+                         "threads no carry")
+
+    def rollout(params: Any, key: jax.Array):
+        with stage(ENV):
+            state, obs = env.reset(key)
+        with stage(POLICY):
+            out = policy_apply(params, obs)
+        with stage(ENV):
+            total, bc, steps = env.score(state, obs, out)
+        return RolloutResult(total_reward=total, bc=bc, steps=steps)
 
     return rollout
 

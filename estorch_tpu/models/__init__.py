@@ -1,3 +1,4 @@
+from .hybrid_lm import HybridLM
 from .policies import MLPPolicy, NatureCNN, RecurrentNatureCNN, RecurrentPolicy
 from .vbn import VirtualBatchNorm, capture_reference_stats
 
@@ -16,6 +17,7 @@ def __getattr__(name):
 
 
 __all__ = [
+    "HybridLM",
     "MLPPolicy",
     "NatureCNN",
     "RecurrentNatureCNN",

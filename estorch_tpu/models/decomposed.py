@@ -96,25 +96,23 @@ def mlp_lowrank_apply(
     """Exact MLPPolicy forward with weights (shared + scale·A Bᵀ/√r), never
     materializing any dense noise matrix.
 
-    ``lr_noise`` is {name: (A, B, bias_noise)} from LowRankSpec.unpack
-    (ops/lowrank.py); ``scale`` is σ·sign.  The noise term costs
-    O((m+n)·r) per step instead of O(m·n):
-        x @ (W + c·A Bᵀ/√r) = x@W + (c/√r)·((x@A) @ Bᵀ)
+    ``lr_noise`` mirrors the params with ``(A, B)`` factors (or, where
+    factoring would not save, the dense E) at each kernel and the dense
+    bias noise (ops/lowrank.py ``LowRankTreeSpec.unpack``); ``scale`` is
+    σ·sign.  Every layer is the perturbed-dense primitive
+    (models/perturbed.py): the noise term costs O((m+n)·r) per step
+    instead of O(m·n).
     """
+    from .perturbed import perturbed_dense
+
     names = _ordered_dense_names(shared_params)
     x = obs
     for name in names:
         w = shared_params[name]["kernel"]
         b = shared_params[name]["bias"]
-        a, bt, nb = lr_noise[name]
-        if bt is None:
-            # dense-fallback layer (rank ≥ min(m, n)): a IS the full E
-            noise_term = scale * (x @ a)
-        else:
-            r = a.shape[-1]
-            c = scale / jnp.sqrt(jnp.asarray(r, x.dtype))
-            noise_term = c * ((x @ a) @ bt.T)
-        x = (x @ w) + noise_term + b + scale * nb
+        nb = lr_noise[name]["bias"]
+        z = perturbed_dense(x, w, lr_noise[name]["kernel"], scale)
+        x = (z + b + scale * nb).astype(w.dtype)
         if name != "head":
             x = module.activation(x)
     if not module.discrete:

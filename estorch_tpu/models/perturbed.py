@@ -1,0 +1,121 @@
+"""The perturbed-dense primitive: ``x @ (W + c·E)`` without forming the sum.
+
+Every ES member evaluates the centre ``W`` plus its own ``c·E`` (``c =
+σ·sign``).  Materialised, that is one weight matrix per member.  Here ``W``
+enters un-batched, so under a ``vmap`` over members (or over antithetic
+pairs and their two signs) the shared term ``x @ W`` of every projection is
+ONE population-wide matmul, and only the correction is per member:
+
+- ``E = A·Bᵀ/√r`` (``noise = (A, B)``, ops/lowrank.py):
+  ``x@W + (c/√r)·((x@A)@Bᵀ)``, O((m+n)·r) per row instead of O(m·n);
+- dense ``E`` (``noise`` an array; small leaves where factoring would not
+  save): ``x@W + c·(x@E)``.
+
+The embedding lookup and the tied head take the same factors:
+``E[tok] + c·A[tok]·Bᵀ/√r`` and ``h@Eᵀ + c·(h@B)@Aᵀ/√r``.  Both dots
+accumulate in float32 and the sum is formed in float32; callers cast once.
+The corrections carry the ``es.perturb`` stage scope (obs/trace.py).
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+from ..obs.trace import PERTURB, stage
+
+F32 = jnp.float32
+
+
+def is_factored(noise) -> bool:
+    """``(A, B)`` factors, as opposed to a dense noise array."""
+    return isinstance(noise, (tuple, list))
+
+
+def _outer(xa, b):
+    """``xa @ bᵀ`` for ``xa [..., r]``, ``b [n, r]``: for the small ranks
+    ES uses, a sum of ``r`` broadcast products (it fuses into the add that
+    consumes it; a K=1 matmul would not)."""
+    b = b.astype(F32)
+    r = b.shape[-1]
+    if r > 4:
+        return jnp.dot(xa, b.T, preferred_element_type=F32)
+    out = xa[..., 0:1] * b[:, 0]
+    for k in range(1, r):
+        out = out + xa[..., k:k + 1] * b[:, k]
+    return out
+
+
+def perturbed_dense(x, w, noise, c, transposed: bool = False):
+    """float32 ``x @ (W + c·E)``; ``W`` is ``[m, n]`` (``transposed``: the
+    product is with ``Wᵀ``, ``x`` is ``[..., n]``, as a tied head reads an
+    embedding).  ``noise`` is ``None`` (the centre alone), ``(A [m, r],
+    B [n, r])`` or a dense ``[m, n]`` array; ``c`` a scalar."""
+    contract = ((x.ndim - 1,), (1 if transposed else 0,))
+    y = jnp.tensordot(x, w, axes=contract, preferred_element_type=F32)
+    if noise is None:
+        return y
+    with stage(PERTURB):
+        if not is_factored(noise):
+            return y + c * jnp.tensordot(
+                x, noise.astype(x.dtype), axes=contract,
+                preferred_element_type=F32)
+        a, b = (noise[1], noise[0]) if transposed else noise
+        r = a.shape[-1]
+        xa = jnp.dot(x, a.astype(x.dtype), preferred_element_type=F32)
+        scale = c / jnp.sqrt(jnp.asarray(r, F32))
+        return y + scale * _outer(xa, b)
+
+
+def perturbed_embed(tokens, table, noise, c):
+    """float32 rows ``(E + c·A·Bᵀ/√r)[tokens]``: the lookup reads the
+    centre's rows and the factor ``A``'s rows, never a perturbed table."""
+    rows = jnp.take(table, tokens, axis=0).astype(F32)
+    if noise is None:
+        return rows
+    with stage(PERTURB):
+        if not is_factored(noise):
+            return rows + c * jnp.take(noise, tokens, axis=0).astype(F32)
+        a, b = noise
+        scale = c / jnp.sqrt(jnp.asarray(a.shape[-1], F32))
+        return rows + scale * _outer(jnp.take(a, tokens, axis=0).astype(F32),
+                                     b)
+
+
+def perturbed_leaf(w, noise, c):
+    """float32 ``w + c·e`` for the small leaves no matmul reads (norm
+    scales, conv taps, per-head scalars): materialised per member."""
+    w = w.astype(F32)
+    if noise is None:
+        return w
+    with stage(PERTURB):
+        return w + c * noise.astype(F32)
+
+
+def perturbed_forward(module):
+    """``(params, noise, c, obs) -> policy output`` of ``params + c·noise``
+    for a module that has such a form (``noise`` as
+    ``LowRankTreeSpec.unpack`` gives it), else ``None``.  A module brings
+    its own as ``perturbed_apply``; the MLP's is models/decomposed.py's."""
+    own = getattr(module, "perturbed_apply", None)
+    if own is not None:
+        return own
+    from .decomposed import mlp_lowrank_apply, supports_decomposed
+
+    if not supports_decomposed(module):
+        return None
+
+    def mlp_apply(params, noise, c, obs):
+        return mlp_lowrank_apply(module, params, noise, c, obs)
+
+    return mlp_apply
+
+
+def lowrank_spec_for(module, params, rank: int):
+    """The module's noise layout: the tree spec (ops/lowrank.py); an MLP
+    keeps the kernels-then-biases order its runs have always drawn."""
+    from ..ops.lowrank import make_lowrank_spec, make_lowrank_tree_spec
+    from .decomposed import supports_decomposed
+
+    make = (make_lowrank_spec if supports_decomposed(module)
+            else make_lowrank_tree_spec)
+    return make(params, rank)

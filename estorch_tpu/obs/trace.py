@@ -34,16 +34,23 @@ import contextlib
 SCOPE_PREFIX = "es."
 
 # the stages of one generation, in program order (docs/observability.md)
-STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE) = (
+STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
+          DENSE, SSM, ATTN, HEAD) = (
     "sample",    # offsets, signs, member keys
     "noise",     # reading eps: the table gather and the slab it builds
-    "perturb",   # theta + sigma * sign * eps, unravel, cast
+    "perturb",   # theta + sigma * sign * eps, unravel, cast; the rank-r
+                 # corrections of the perturbed-dense primitive
     "policy",    # obs normalisation, the forward, select_action
     "env",       # reset, physics, done masking, reward/step accumulation
     "gather",    # collectives
     "rank",      # centered ranks
     "grad",      # the second pass over the noise, the weighted sum
     "update",    # weight decay, optax step, sigma decay, obs-norm probe
+    # nested inside es.policy by a sequence model (models/hybrid_lm.py)
+    "dense",     # the shared x@W projections and the gated FFN
+    "ssm",       # conv1d, dt and decay, the chunked scan, the gated norm
+    "attn",      # scores, softmax, P.V
+    "head",      # tied-embedding logits, log-softmax, the score
 )
 
 

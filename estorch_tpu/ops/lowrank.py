@@ -48,78 +48,91 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
-class LowRankSpec:
-    """Static layout of one member's low-rank noise vector.
+class LowRankTreeSpec:
+    """Static layout of one member's noise vector over ANY param pytree
+    (leaf index = position in ``jax.tree_util.tree_flatten``).
 
-    ``lr_layers``: tuple of (name, m, n, a_off, b_off) — kernel noise
-    factors A (m, r) and B (n, r) at those offsets into the noise vector.
-    ``dense_layers``: tuple of (name, m, n, off) — layers where factoring
-    would not save ((m+n)·rank ≥ m·n): exact dense kernel noise.
-    ``biases``: tuple of (name, n, off) — dense bias noise.
+    ``lr_leaves``: (leaf_index, m, n, a_off, b_off): 2-D leaves where
+    factoring saves ((m+n)·rank < m·n) carry factors A (m, r) and B (n, r)
+    at those offsets.  ``dense_leaves``: (leaf_index, shape, size, off):
+    every other leaf (biases, conv taps, per-head scalars, norm scales, a
+    16×1 head at any rank) carries exact dense noise, which is exact AND
+    no larger.  Offsets are assigned in ``order`` (default: leaf order).
     """
 
     rank: int
     noise_dim: int
-    lr_layers: tuple
-    dense_layers: tuple
-    biases: tuple
+    treedef: Any
+    lr_leaves: tuple
+    dense_leaves: tuple
 
-    def unpack(self, noise_vec: jax.Array) -> dict:
-        """(noise_dim,) slice → {name: (A, B, bias)} / {name: (E, None, bias)}.
-
-        A 3-tuple per layer: low-rank layers carry (A, B, bias_noise); dense
-        -fallback layers carry (E, None, bias_noise).  ``None`` is a pytree
-        structural marker, so the dict vmaps/casts cleanly.
-        """
+    def unpack(self, noise_vec: jax.Array) -> Any:
+        """(noise_dim,) slice → the params' pytree with ``(A, B)`` at each
+        factored leaf and the dense noise array at every other: what a
+        perturbed forward (models/perturbed.py) reads.  ``noise_vec`` may
+        carry leading batch axes (one row per pair)."""
         r = self.rank
-        out = {}
-        for name, m, n, a_off, b_off in self.lr_layers:
-            a = jax.lax.dynamic_slice(noise_vec, (a_off,), (m * r,)).reshape(m, r)
-            b = jax.lax.dynamic_slice(noise_vec, (b_off,), (n * r,)).reshape(n, r)
-            out[name] = [a, b, None]
-        for name, m, n, off in self.dense_layers:
-            e = jax.lax.dynamic_slice(noise_vec, (off,), (m * n,)).reshape(m, n)
-            out[name] = [e, None, None]
-        for name, n, off in self.biases:
-            nb = jax.lax.dynamic_slice(noise_vec, (off,), (n,))
-            out[name][2] = nb
-        return {k: tuple(v) for k, v in out.items()}
+        lead = noise_vec.shape[:-1]
+        leaves = [None] * (len(self.lr_leaves) + len(self.dense_leaves))
+        for i, m, n, a_off, b_off in self.lr_leaves:
+            leaves[i] = (
+                noise_vec[..., a_off:a_off + m * r].reshape(lead + (m, r)),
+                noise_vec[..., b_off:b_off + n * r].reshape(lead + (n, r)))
+        for i, shape, size, off in self.dense_leaves:
+            leaves[i] = noise_vec[..., off:off + size].reshape(lead + shape)
+        return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
 
-def make_lowrank_spec(params: Any, rank: int) -> LowRankSpec:
-    """Layout from an MLP-shaped param tree ({name: {kernel, bias}})."""
-    from ..models.decomposed import _ordered_dense_names
-
+def make_lowrank_tree_spec(params: Any, rank: int,
+                           order=None) -> LowRankTreeSpec:
+    """Layout from ANY param pytree (arrays or ``ShapeDtypeStruct``s).
+    ``order``: the leaf indices in the order their noise is laid out."""
     if rank < 1:
         raise ValueError(f"low_rank must be >= 1, got {rank}")
-    names = _ordered_dense_names(params)
-    lr_layers, dense_layers, biases = [], [], []
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    lr_leaves, dense_leaves = [], []
     off = 0
-    for name in names:
-        m, n = params[name]["kernel"].shape
+    for i in (range(len(leaves)) if order is None else order):
+        shape = tuple(int(d) for d in leaves[i].shape)
         # low-rank only where it actually SAVES: (m+n)·r < m·n (this also
         # implies r < min(m, n), since mn/(m+n) < min(m, n)); otherwise the
-        # factors would cost more noise floats than exact dense Gaussian —
+        # factors would cost more noise floats than exact dense Gaussian,
         # an approximation strictly worse than the thing it approximates
-        if rank * (m + n) < m * n:
-            lr_layers.append((name, m, n, off, off + m * rank))
+        if len(shape) == 2 and rank * (shape[0] + shape[1]) < shape[0] * shape[1]:
+            m, n = shape
+            lr_leaves.append((i, m, n, off, off + m * rank))
             off += (m + n) * rank
         else:
-            dense_layers.append((name, m, n, off))
-            off += m * n
-    for name in names:
-        (n,) = params[name]["bias"].shape
-        biases.append((name, n, off))
-        off += n
-    return LowRankSpec(
-        rank=rank, noise_dim=off, lr_layers=tuple(lr_layers),
-        dense_layers=tuple(dense_layers), biases=tuple(biases),
+            size = 1
+            for s in shape:
+                size *= s
+            dense_leaves.append((i, shape, size, off))
+            off += size
+    return LowRankTreeSpec(
+        rank=rank, noise_dim=off, treedef=treedef,
+        lr_leaves=tuple(sorted(lr_leaves)),
+        dense_leaves=tuple(sorted(dense_leaves)),
     )
+
+
+def make_lowrank_spec(params: Any, rank: int) -> LowRankTreeSpec:
+    """The MLP case ({name: {kernel, bias}}): the tree spec with the noise
+    laid out kernels first (layer order), then biases: the layout the
+    MLP runs have always drawn from the table, so their noise is
+    unchanged."""
+    from ..models.decomposed import _ordered_dense_names
+
+    paths = [tuple(str(k.key) for k in path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(params)[0]]
+    names = _ordered_dense_names(params)
+    order = ([paths.index((n, "kernel")) for n in names]
+             + [paths.index((n, "bias")) for n in names])
+    return make_lowrank_tree_spec(params, rank, order=order)
 
 
 def lowrank_program_factors(rank: int, m: int, n: int, key: jax.Array):
     """In-program (A, B) factors for one leaf/row — the sharded path's
-    table-free twin of :meth:`LowRankSpec.unpack` (parallel/sharded.py):
+    table-free twin of :meth:`LowRankTreeSpec.unpack` (parallel/sharded.py):
     instead of unpacking factors from a table slice, they are generated
     from the (key, generation, row, leaf) chain (ops/noise.py).  Same
     statistics (entries of A·Bᵀ/√r are zero-mean unit-variance), same
@@ -137,116 +150,10 @@ def lowrank_program_leaf_noise(rank: int, m: int, n: int, key: jax.Array) -> jax
     return (a @ b.T) / jnp.sqrt(jnp.float32(rank))
 
 
-def dense_kernel(spec_rank: int, a, b):
-    """One layer's dense E from its unpacked factors (oracle/snapshot path)."""
-    if b is None:
-        return a  # dense-fallback layer: a IS E
-    return (a @ b.T) / jnp.sqrt(jnp.float32(spec_rank))
-
-
-def lowrank_noise_tree(lr_spec: LowRankSpec, noise_vec: jax.Array) -> dict:
-    """Materialize the DENSE noise pytree {name: {kernel, bias}} one member's
-    slice represents — snapshot/debug path (member_params), not the hot path.
-    """
-    unpacked = lr_spec.unpack(noise_vec)
-    return {
-        name: {"kernel": dense_kernel(lr_spec.rank, a, b), "bias": nb}
-        for name, (a, b, nb) in unpacked.items()
-    }
-
-
-def lowrank_weighted_sum(
-    lr_spec: LowRankSpec, noise_mat: jax.Array, weights: jax.Array
-) -> dict:
-    """Σ_i w_i · dense(noise_i) without materializing any member's dense E.
-
-    ``noise_mat``: (k, noise_dim) stacked member/pair slices;
-    ``weights``: (k,) — rank weights (mirrored: already pair-folded w⁺−w⁻,
-    exact because a pair shares ONE slice, so ±E share (A, B) and fold like
-    full-rank noise).  Returns the dense {name: {kernel, bias}} pytree of
-    the weighted sum.
-    """
-    r = lr_spec.rank
-    k = noise_mat.shape[0]
-    scale = 1.0 / jnp.sqrt(jnp.float32(r))
-    out = {}
-    for name, m, n, a_off, b_off in lr_spec.lr_layers:
-        a = jax.lax.dynamic_slice(noise_mat, (0, a_off), (k, m * r)).reshape(k, m, r)
-        b = jax.lax.dynamic_slice(noise_mat, (0, b_off), (k, n * r)).reshape(k, n, r)
-        kernel = jnp.einsum("kmr,knr->mn", a * weights[:, None, None], b) * scale
-        out[name] = {"kernel": kernel}
-    for name, m, n, off in lr_spec.dense_layers:
-        e = jax.lax.dynamic_slice(noise_mat, (0, off), (k, m * n))
-        out[name] = {"kernel": (weights @ e).reshape(m, n)}
-    for name, n, off in lr_spec.biases:
-        nb = jax.lax.dynamic_slice(noise_mat, (0, off), (k, n))
-        out[name]["bias"] = weights @ nb
-    return out
-
-
-# ---- generic pytree form (recurrent / arbitrary policies) -----------------
-#
-# The MLP spec above is keyed by layer NAME because its consumer
-# (models/decomposed.py::mlp_lowrank_apply) restructures the MLP forward
-# around the layer identity — the per-STEP noise term stays O((m+n)·r).
-# Recurrent cells thread a carry through the episode scan, so their forward
-# cannot be restructured the same way without reimplementing every cell.
-# The tree form instead materializes each member's dense perturbation ONCE
-# PER EPISODE (amortized over the horizon's steps — the per-step forward is
-# then the standard rollout, carry threading included), while keeping the
-# two properties that matter at population scale: the per-member noise
-# STATE stays O(noise_dim) (the HBM win — table slices, never dense ε), and
-# the update is the same no-materialization einsum per factored leaf.
-# Transient per-chunk materialization equals what the standard path already
-# does with W + σ·s·ε.
-#
-# Any 2-D leaf where factoring saves ((m+n)·r < m·n) is factored; all other
-# leaves (biases, conv kernels, carry-init vectors) carry exact dense noise.
-
-
-@dataclasses.dataclass(frozen=True)
-class LowRankTreeSpec:
-    """Static layout of one member's low-rank noise vector over an
-    arbitrary param pytree (leaf order = ``jax.tree_util.tree_flatten``).
-
-    ``lr_leaves``: (leaf_index, m, n, a_off, b_off) — factored 2-D leaves.
-    ``dense_leaves``: (leaf_index, shape, size, off) — exact dense noise.
-    """
-
-    rank: int
-    noise_dim: int
-    treedef: Any
-    lr_leaves: tuple
-    dense_leaves: tuple
-
-
-def make_lowrank_tree_spec(params: Any, rank: int) -> LowRankTreeSpec:
-    """Layout from ANY param pytree — the recurrent-policy entry point."""
-    if rank < 1:
-        raise ValueError(f"low_rank must be >= 1, got {rank}")
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    lr_leaves, dense_leaves = [], []
-    off = 0
-    for i, leaf in enumerate(leaves):
-        shape = tuple(leaf.shape)
-        if leaf.ndim == 2 and rank * (shape[0] + shape[1]) < shape[0] * shape[1]:
-            m, n = shape
-            lr_leaves.append((i, m, n, off, off + m * rank))
-            off += (m + n) * rank
-        else:
-            size = 1
-            for s in shape:
-                size *= s
-            dense_leaves.append((i, shape, size, off))
-            off += size
-    return LowRankTreeSpec(
-        rank=rank, noise_dim=off, treedef=treedef,
-        lr_leaves=tuple(lr_leaves), dense_leaves=tuple(dense_leaves),
-    )
-
-
 def lowrank_tree_noise(spec: LowRankTreeSpec, noise_vec: jax.Array) -> Any:
-    """Materialize the dense noise pytree one member's slice represents."""
+    """Materialize the DENSE noise pytree one member's slice represents:
+    snapshot/debug path (member_params) and the recurrent policies'
+    once-per-episode perturbation, not the per-step hot path."""
     r = spec.rank
     scale = 1.0 / jnp.sqrt(jnp.float32(r))
     leaves = [None] * (len(spec.lr_leaves) + len(spec.dense_leaves))
@@ -262,8 +169,11 @@ def lowrank_tree_noise(spec: LowRankTreeSpec, noise_vec: jax.Array) -> Any:
 def lowrank_tree_perturb(
     spec: LowRankTreeSpec, params: Any, noise_vec: jax.Array, scale
 ) -> Any:
-    """``params + scale · dense(noise_vec)`` — one member's perturbed tree,
-    materialized once per episode (see the module-section comment)."""
+    """``params + scale · dense(noise_vec)``: one member's perturbed tree.
+    Recurrent cells thread a carry through the episode scan, so their
+    forward is not restructured around the factors; the dense perturbation
+    is materialized ONCE PER EPISODE (amortized over the horizon) while the
+    noise STATE stays O(noise_dim) and the update stays factored."""
     noise = lowrank_tree_noise(spec, noise_vec)
     return jax.tree_util.tree_map(lambda w, e: w + scale * e, params, noise)
 
@@ -272,8 +182,12 @@ def lowrank_tree_weighted_sum(
     spec: LowRankTreeSpec, noise_mat: jax.Array, weights: jax.Array
 ) -> Any:
     """Σ_i w_i · dense(noise_i) as a pytree, without materializing any
-    member's dense noise — the tree twin of :func:`lowrank_weighted_sum`
-    (same pair-folding argument: ±E share (A, B))."""
+    member's dense noise.
+
+    ``noise_mat``: (k, noise_dim) stacked member/pair slices; ``weights``:
+    (k,) rank weights (mirrored: already pair-folded w⁺−w⁻, exact because
+    a pair shares ONE slice, so ±E share (A, B) and fold like full-rank
+    noise).  One MXU contraction per factored leaf."""
     r = spec.rank
     k = noise_mat.shape[0]
     scale = 1.0 / jnp.sqrt(jnp.float32(r))

@@ -957,17 +957,13 @@ class ESEngine:
             # one einsum per layer over the stacked factor slices — no dense
             # E_i is ever materialized (ops/lowrank.py)
             from ..ops.gradient import fold_mirrored_weights as _fold_lr
-            from ..ops.lowrank import (lowrank_tree_weighted_sum,
-                                       lowrank_weighted_sum)
+            from ..ops.lowrank import lowrank_tree_weighted_sum
 
             row_w = _fold_lr(w_local) if cfg.mirrored else w_local
             noise_local = jax.vmap(
                 lambda o: self.table.slice(o, self.noise_dim)
             )(reduction_offs)
-            wsum = (lowrank_tree_weighted_sum
-                    if hasattr(self.lr_spec, "treedef")
-                    else lowrank_weighted_sum)
-            tree = wsum(self.lr_spec, noise_local, row_w)
+            tree = lowrank_tree_weighted_sum(self.lr_spec, noise_local, row_w)
             grad_local = self.spec.flatten(tree) / (
                 cfg.population_size * state.sigma
             )
@@ -1344,11 +1340,10 @@ class ESEngine:
             off = all_offsets[member_index]
             sign = 1.0
         if self.config.low_rank:
-            from ..ops.lowrank import lowrank_noise_tree, lowrank_tree_noise
+            from ..ops.lowrank import lowrank_tree_noise
 
-            mk = (lowrank_tree_noise if hasattr(self.lr_spec, "treedef")
-                  else lowrank_noise_tree)
-            dense = mk(self.lr_spec, self.table.slice(off, self.noise_dim))
+            dense = lowrank_tree_noise(
+                self.lr_spec, self.table.slice(off, self.noise_dim))
             return state.params_flat + state.sigma * sign * self.spec.flatten(dense)
         eps = self.table.slice(off, self.spec.dim)
         return state.params_flat + state.sigma * sign * eps

@@ -116,11 +116,33 @@ def padded_count(n: int, n_shards: int) -> int:
 # everything else replicates.  The trailing catch-all makes the defaults
 # total over ANY tree; strict user rule sets omit it and get the
 # unmatched-leaf error instead.
-DEFAULT_PARTITION_RULES = (
+# The sequence model's leaves (models/hybrid_lm.py) come first.
+# Projections are column- then row-parallel in pairs (in_z/in_x/in_dt →
+# out_proj, gate/up → down, q/k/v → o), so one all-reduce closes each
+# pair; Mamba heads, their conv channels, dt, A_log, D and the gated norm
+# go by head; the embedding by vocabulary row; B and C (one group, read by
+# every head) and the block norms replicate.
+HYBRID_LM_PARTITION_RULES = (
+    (r"embed/embedding$", P(MODEL_AXIS, None)),
+    (r"mamba/(in_z|in_x|in_dt)$", P(None, MODEL_AXIS)),
+    (r"mamba/conv_x_kernel$", P(None, None, MODEL_AXIS)),
+    (r"mamba/(conv_x_bias|A_log|D|dt_bias|norm_scale)$", P(MODEL_AXIS)),
+    (r"mamba/(in_bc|conv_bc_kernel|conv_bc_bias)$", P()),
+    (r"mamba/out_proj$", P(MODEL_AXIS, None)),
+    (r"attn/(q|k|v)$", P(None, MODEL_AXIS)),
+    (r"attn/o$", P(MODEL_AXIS, None)),
+    (r"mlp/(gate|up)$", P(None, MODEL_AXIS)),
+    (r"mlp/down$", P(MODEL_AXIS, None)),
+    (r"(norm1|norm2|final_norm)/scale$", P()),
+)
+
+CATCH_ALL = r".*"
+
+DEFAULT_PARTITION_RULES = HYBRID_LM_PARTITION_RULES + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),
-    (r".*", P()),
+    (CATCH_ALL, P()),
 )
 
 
@@ -164,7 +186,8 @@ def _fit_spec_to_shape(spec: P, shape, mesh: Mesh) -> P:
     return P(*out)
 
 
-def match_partition_rules(rules, tree: Any, mesh: Mesh) -> Any:
+def match_partition_rules(rules, tree: Any, mesh: Mesh,
+                          log_unmatched: bool = True) -> Any:
     """Pytree of ``NamedSharding`` from ``(regex, PartitionSpec)`` rules.
 
     Each leaf's '/'-joined tree path is matched against the rules in
@@ -176,8 +199,23 @@ def match_partition_rules(rules, tree: Any, mesh: Mesh) -> Any:
     ``jax.eval_shape`` without materializing anything): optax states
     embed param-shaped subtrees under the same leaf names, so ONE rule
     set covers params and optimizer state.
+
+    A leaf that only the catch-all ``(".*", P())`` matched is replicated
+    as before, and named with its bytes in one warning (``log_unmatched``;
+    :func:`sharding_summary` says the same per leaf): a rule set written
+    for other models must not replicate a wide leaf in silence.
     """
     compiled = [(re.compile(pat), spec) for pat, spec in rules]
+    fell_through = unmatched_leaves(rules, tree) if log_unmatched else {}
+    if fell_through:
+        import logging
+
+        logging.getLogger(__name__).warning(
+            "partition rules: only the catch-all %r matched %d leaves, "
+            "which therefore replicate on every device (%d bytes each "
+            "device): %s", CATCH_ALL, len(fell_through),
+            sum(fell_through.values()),
+            ", ".join(f"{k} ({v} B)" for k, v in fell_through.items()))
 
     def leaf_sharding(path, leaf):
         name = _leaf_path_name(path)
@@ -199,13 +237,41 @@ def match_partition_rules(rules, tree: Any, mesh: Mesh) -> Any:
     return jax.tree_util.tree_map_with_path(leaf_sharding, tree)
 
 
-def sharding_summary(tree: Any, shardings: Any) -> dict[str, str]:
+def unmatched_leaves(rules, tree: Any) -> dict[str, int]:
+    """{leaf path: bytes} of the non-scalar leaves that no rule but the
+    catch-all ``(".*", P())`` matched: the leaves a rule set written for
+    other models replicates without having been asked to."""
+    named = [re.compile(pat) for pat, _ in rules if pat != CATCH_ALL]
+    out: dict[str, int] = {}
+
+    def visit(path, leaf):
+        name = _leaf_path_name(path)
+        shape = tuple(getattr(leaf, "shape", ()))
+        size = 1
+        for d in shape:
+            size *= d
+        if size > 1 and not any(p.search(name) for p in named):
+            out[name] = size * int(leaf.dtype.itemsize)
+
+    jax.tree_util.tree_map_with_path(visit, tree)
+    return out
+
+
+def sharding_summary(tree: Any, shardings: Any,
+                     rules=None) -> dict[str, str]:
     """{leaf path: spec} — what the rules actually resolved to (incl.
-    divisibility fallbacks), for logs/manifests and the coverage tests."""
+    divisibility fallbacks), for logs/manifests and the coverage tests.
+    Given the ``rules``, a leaf that only the catch-all matched says so
+    and gives its bytes."""
     out: dict[str, str] = {}
+    fell_through = unmatched_leaves(rules, tree) if rules else {}
 
     def visit(path, leaf, sh):
-        out[_leaf_path_name(path)] = str(sh.spec)
+        name = _leaf_path_name(path)
+        out[name] = str(sh.spec)
+        if name in fell_through:
+            out[name] += (f" (catch-all: no rule names this leaf; "
+                          f"{fell_through[name]} bytes replicated)")
 
     jax.tree_util.tree_map_with_path(visit, tree, shardings)
     return out

@@ -36,10 +36,26 @@ f32 reduction order — the forward's contractions over model-sharded
 dims and the update psum may reassociate, so cross-path comparisons are
 ``allclose`` at f32, not bit-equal; docs/sharding.md).
 
-Scope: feedforward device-native envs, f32, one episode per member.
-obs_norm / decomposed / streamed / noise_kernel / recurrent carries stay
-on the replicated engine (their machinery assumes a replicated flat
-vector); the ctor rejects them loudly.
+Two evaluation bodies, chosen by the engine from what it observes
+(``forward_form``; no option):
+
+- ``perturbed``: low-rank table noise on a policy with a perturbed forward
+  (models/perturbed.py).  No member's weights are ever built: the centre
+  enters the member ``vmap`` un-batched (cast to the compute dtype once a
+  generation) and sharded over ``model``, the factor rows of each
+  antithetic pair are sliced from the table at the pair's offset, batched
+  over pairs and sharded over ``pop``, and both signs of a pair read one
+  factor row.  Every projection is one population-wide matmul plus a
+  rank-r correction; the update is one contraction per leaf over the
+  pairs' factors, sharded like the leaf.  This is the form a model too wide
+  for a perturbed copy per member needs.
+- ``materialised``: ``leaf[None] + σ·s·ε`` per member of a chunk, for
+  full-rank noise and in-program low-rank noise on small trees.
+
+Scope: feedforward device-native envs (whole-episode sequence envs
+included), one episode per member.  obs_norm / decomposed / streamed /
+noise_kernel / recurrent carries stay on the replicated engine (their
+machinery assumes a replicated flat vector); the ctor rejects them loudly.
 """
 
 from __future__ import annotations
@@ -57,16 +73,21 @@ from ..obs.spans import NULL_TELEMETRY
 from ..obs.trace import (GATHER, GRAD, NOISE, PERTURB, RANK, SAMPLE, UPDATE,
                          stage)
 from ..ops.gradient import fold_mirrored_weights
-from ..ops.lowrank import lowrank_program_factors, lowrank_program_leaf_noise
+from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
+                           lowrank_tree_noise, lowrank_tree_weighted_sum)
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
-from .engine import EngineConfig, _choose_eval_chunk, _gen_keys
+from .engine import (EngineConfig, _bf16_io_apply, _bf16_obs,
+                     _choose_eval_chunk, _gen_keys)
 from .mesh import (DEFAULT_PARTITION_RULES, MODEL_AXIS, POP_AXIS,
                    match_partition_rules, padded_count, sharding_summary)
 
 NOISE_MODES = ("program", "table")
+# perturbed form: a chunk's widest activation ([chunk·horizon, widest
+# projection] float32, a device's share) is held under this many bytes
+ACTIVATION_BUDGET_BYTES = 256 * 2**20
 
 
 def _rng_scope(partitionable: bool):
@@ -133,6 +154,8 @@ class ShardedESEngine:
         mesh: Mesh,
         partition_rules=None,
         noise_mode: str = "program",
+        perturbed_apply: Callable[..., Any] | None = None,
+        lowrank_spec=None,
     ):
         for flag in ("decomposed", "streamed", "noise_kernel", "obs_norm"):
             if getattr(config, flag):
@@ -140,11 +163,6 @@ class ShardedESEngine:
                     f"{flag} is a replicated-engine option; the sharded "
                     "path's noise/state layout replaces it (docs/sharding.md)"
                 )
-        if config.compute_dtype != "float32":
-            raise ValueError(
-                "the sharded engine runs in float32 (the parity contract "
-                "vs the replicated path is stated at f32)"
-            )
         if config.episodes_per_member != 1:
             raise ValueError(
                 "episodes_per_member is a replicated-engine option for now")
@@ -158,11 +176,14 @@ class ShardedESEngine:
         if noise_mode == "table":
             if table is None:
                 raise ValueError("noise_mode='table' needs a NoiseTable")
-            if config.low_rank:
+            if config.low_rank and (perturbed_apply is None
+                                    or lowrank_spec is None):
                 raise ValueError(
-                    "low_rank noise is generated in-program on the sharded "
-                    "path (noise_mode='program'); the table packs full-rank "
-                    "rows only"
+                    "low_rank rows from the table need a policy with a "
+                    "perturbed forward and its noise layout "
+                    "(models/perturbed.py; ES builds both); without one, "
+                    "low_rank noise is generated in-program "
+                    "(noise_mode='program')"
                 )
         missing = {POP_AXIS, MODEL_AXIS} - set(mesh.axis_names)
         if missing:
@@ -180,6 +201,15 @@ class ShardedESEngine:
         self.config = config
         self.mesh = mesh
         self.noise_mode = noise_mode
+        # which evaluation body the generation program runs, resolved once
+        # from what the engine observes (run manifest + telemetry gauges)
+        self.forward_form = (
+            "perturbed" if noise_mode == "table" and config.low_rank
+            else "materialised")
+        self.lr_spec = lowrank_spec if self.forward_form == "perturbed" else None
+        self._perturbed_apply = perturbed_apply
+        self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
+                       else jnp.float32)
         self.n_devices = int(mesh.devices.size)
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.pop_shards = int(axis_sizes[POP_AXIS])
@@ -217,7 +247,7 @@ class ShardedESEngine:
             self.partition_rules, params_shape, mesh)
         opt_shape = jax.eval_shape(optimizer.init, params_shape)
         self.opt_shardings = match_partition_rules(
-            self.partition_rules, opt_shape, mesh)
+            self.partition_rules, opt_shape, mesh, log_unmatched=False)
         self._repl = NamedSharding(mesh, P())
         self.state_shardings = ShardedESState(
             params=self.param_shardings,
@@ -260,7 +290,28 @@ class ShardedESEngine:
         self.grad_chunk = gchunk_per_shard * self.pop_shards
         self.n_grad_chunks = self.rows_padded // self.grad_chunk
 
-        self._rollout = make_rollout(env, policy_apply, cfg.horizon)
+        # noise-table rows (or in-program rows) the evaluation reads per
+        # generation, and the floats of one row
+        self.noise_rows_per_generation = self.rows_global
+        self.noise_dim = (self.lr_spec.noise_dim if self.lr_spec is not None
+                          else spec.dim)
+
+        bf16 = self._dtype == jnp.bfloat16
+        if self.forward_form == "perturbed":
+            self._size_pair_chunks()
+
+            def packed_apply(packed, obs):
+                shared, noise, c = packed
+                out = perturbed_apply(shared, noise, c,
+                                      _bf16_obs(obs) if bf16 else obs)
+                return jax.tree_util.tree_map(
+                    lambda o: o.astype(jnp.float32), out)
+
+            self._rollout = make_rollout(env, packed_apply, cfg.horizon)
+        else:
+            self._rollout = make_rollout(
+                env, _bf16_io_apply(policy_apply) if bf16 else policy_apply,
+                cfg.horizon)
 
         # metrics shardings: scalars/vectors replicated, the in-program
         # best-member tree sharded exactly like the params it perturbs
@@ -330,7 +381,16 @@ class ShardedESEngine:
         if self.noise_mode != "table":
             return None
         return sample_pair_offsets(
-            okey, self.rows_global, self.table.size, self.spec.dim)
+            okey, self.rows_global, self.table.size, self.noise_dim)
+
+    def all_pair_offsets(self, state: ShardedESState) -> jax.Array:
+        """Table mode: this generation's per-PAIR (mirrored) or per-MEMBER
+        offsets, the same derivation the program performs, so an outside
+        evaluator (the benchmark's reference) perturbs with the same
+        noise."""
+        if self.noise_mode != "table":
+            raise ValueError("program-mode noise has no table offsets")
+        return self._offsets(_gen_keys(state)[0])
 
     # ------------------------------------------------------------- eval
 
@@ -363,10 +423,98 @@ class ShardedESEngine:
                     eps, self._batched_shardings[i])
             with stage(PERTURB):
                 b = scale.reshape((ids.shape[0],) + (1,) * leaf.ndim)
-                theta_leaves.append(leaf[None] + b * eps)
+                theta_leaves.append((leaf[None] + b * eps).astype(self._dtype))
         theta = jax.tree_util.tree_unflatten(self._treedef, theta_leaves)
         res = jax.vmap(self._rollout, in_axes=(0, 0))(theta, keys)
         return res.total_reward, res.bc, res.steps
+
+    def _size_pair_chunks(self):
+        """Perturbed form: antithetic pairs (unmirrored: members) per
+        evaluation chunk.  ``eval_chunk`` members when the caller set it;
+        otherwise as many as keep a device's share of the chunk's widest
+        activation, ``[chunk·horizon, widest projection]`` float32, under
+        ``ACTIVATION_BUDGET_BYTES``.  A chunk holds whole pairs and a
+        multiple of ``pop_shards`` rows."""
+        cfg = self.config
+        per_row = 2 if cfg.mirrored else 1
+        rows_per_shard = self.rows_padded // self.pop_shards
+        if cfg.eval_chunk > 0:
+            req = max(1, cfg.eval_chunk // (per_row * self.pop_shards))
+        else:
+            widest = max([n for _, _, n, _, _ in self.lr_spec.lr_leaves]
+                         or [1])
+            per_member = 4 * cfg.horizon * -(-widest // self.model_shards)
+            req = max(1, ACTIVATION_BUDGET_BYTES // (per_member * per_row))
+        rows_chunk_per_shard = _choose_eval_chunk(req, rows_per_shard)
+        self.pair_chunk = rows_chunk_per_shard * self.pop_shards
+        self.n_pair_chunks = self.rows_padded // self.pair_chunk
+        self.eval_chunk = self.pair_chunk * per_row
+        self.n_eval_chunks = self.n_pair_chunks
+        self.members_padded = self.rows_padded * per_row
+
+    def _eval_all_perturbed(self, state, center, noise_rows, rkey):
+        """Evaluate every member without building any member's weights:
+        ``center`` (the compute-dtype copy of the params, sharded like them)
+        is closed over un-batched, ``noise_rows [rows_padded, noise_dim]``
+        are unpacked per chunk into factor trees batched over pairs, and
+        the two signs of a pair read the one tree."""
+        cfg = self.config
+        with stage(SAMPLE):
+            member_keys = jax.random.split(rkey, self.rows_global)
+            keys = jnp.take(member_keys, self._padded_rows(), axis=0)
+            signs = (jnp.asarray([1.0, -1.0], jnp.float32) if cfg.mirrored
+                     else jnp.ones((1,), jnp.float32))
+        pair_rows = NamedSharding(self.mesh, P(POP_AXIS, None))
+
+        def chunk_body(noise_c, keys_c):
+            with stage(NOISE):
+                noise_c = jax.lax.with_sharding_constraint(noise_c, pair_rows)
+                noise_tree = self.lr_spec.unpack(noise_c)
+
+            def pair_eval(noise_p, key):
+                def sign_eval(sign):
+                    with stage(PERTURB):
+                        c = state.sigma * sign
+                    return self._rollout((center, noise_p, c), key)
+
+                return jax.vmap(sign_eval)(signs)
+
+            res = jax.vmap(pair_eval, spmd_axis_name=POP_AXIS)(
+                noise_tree, keys_c)
+            return res.total_reward, res.bc, res.steps
+
+        if self.n_pair_chunks == 1:
+            f, bc, st = chunk_body(noise_rows, keys)
+        else:
+            n, k = self.n_pair_chunks, self.pair_chunk
+            _, (f, bc, st) = jax.lax.scan(
+                lambda _, xs: (0, chunk_body(*xs)), 0,
+                (noise_rows.reshape(n, k, self.noise_dim),
+                 keys.reshape((n, k) + keys.shape[1:])))
+        # (chunks, pairs, signs) is member order: member 2k+s is pair k
+        f = f.reshape(self.members_padded)
+        bc = bc.reshape(self.members_padded, self.bc_dim)
+        st = st.reshape(self.members_padded)
+        with stage(GATHER):
+            alive = jnp.arange(self.members_padded) < cfg.population_size
+            steps = jnp.where(alive, st, 0).sum()
+            return (f[: cfg.population_size], bc[: cfg.population_size],
+                    steps)
+
+    def _noise_rows(self, offsets, table_data):
+        """Perturbed form: every pair's ``noise_dim`` floats, sliced from
+        the table at the pair's offset (ghost rows repeat the last): the
+        one read of the noise, shared by evaluation, update and best-member
+        reconstruction.  Small (rows × noise_dim), so it is replicated."""
+        with stage(NOISE):
+            return jax.vmap(lambda o: jax.lax.dynamic_slice(
+                table_data, (o,), (self.noise_dim,)))(
+                    offsets[self._padded_rows()])
+
+    def _padded_rows(self):
+        """Row index per padded row: ghost rows repeat the last real one."""
+        return jnp.minimum(jnp.arange(self.rows_padded, dtype=jnp.int32),
+                           self.rows_global - 1)
 
     def _eval_all(self, state, offsets, leaf_keys, rkey, table_data):
         cfg = self.config
@@ -399,6 +547,23 @@ class ShardedESEngine:
                     steps)
 
     # ------------------------------------------------------------- update
+
+    @stage(GRAD)
+    def _weighted_factor_sum(self, state, noise_rows, weights):
+        """Perturbed form: grad tree from the generation's noise rows, one
+        contraction per leaf over the pairs' factors (ΔW = Σ w·A·Bᵀ/√r),
+        constrained to the leaf's own sharding so that no device ever holds
+        a whole leaf of it."""
+        cfg = self.config
+        row_w = fold_mirrored_weights(weights) if cfg.mirrored else weights
+        pad = self.rows_padded - self.rows_global
+        if pad:
+            row_w = jnp.concatenate([row_w, jnp.zeros((pad,), row_w.dtype)])
+        denom = jnp.float32(cfg.population_size) * state.sigma
+        tree = lowrank_tree_weighted_sum(self.lr_spec, noise_rows, row_w)
+        return jax.tree_util.tree_map(
+            lambda g, sh: jax.lax.with_sharding_constraint(g, sh) / denom,
+            tree, self.param_shardings)
 
     @stage(GRAD)
     def _weighted_noise_sum(self, state, offsets, leaf_keys, weights,
@@ -489,18 +654,17 @@ class ShardedESEngine:
         if cfg.sigma_decay != 1.0:
             new_sigma = jnp.maximum(
                 state.sigma * cfg.sigma_decay, cfg.sigma_min)
-        params_finite = jnp.array(True)
-        for leaf in jax.tree_util.tree_leaves(new_params):
-            params_finite = jnp.logical_and(
-                params_finite, jnp.isfinite(leaf).all())
-        update_finite = jnp.logical_and(jnp.isfinite(gnorm), params_finite)
         # In-program anomaly rollback: donation destroys the caller's
         # pre-step buffers, so the restore the replicated path's ES.train
         # does host-side ("reject instead of training on poison",
         # docs/resilience.md) happens HERE — a rejected generation emits
         # the input state unchanged (same generation → the deterministic
         # re-run contract holds) and ES.train only counts/announces it.
-        ok = jnp.logical_and(update_finite, n_valid >= 2)
+        # The gate reads the GRADIENT (and the valid count), known before
+        # any leaf is stepped, so each leaf is stepped and selected in
+        # place; gating on the stepped params would hold a second whole
+        # state (params + moments) until the last leaf was checked.
+        ok = jnp.logical_and(jnp.isfinite(gnorm), n_valid >= 2)
 
         def keep(new, old):
             return jax.tree_util.tree_map(
@@ -513,6 +677,14 @@ class ShardedESEngine:
             generation=jnp.where(ok, state.generation + 1, state.generation),
             sigma=jnp.where(ok, new_sigma, state.sigma),
         )
+        # reported, not gated on: finite gradients through optax give
+        # finite params; if they ever do not, ES.train counts the
+        # generation rejected and stops after its limit of repeats
+        params_finite = jnp.array(True)
+        for leaf in jax.tree_util.tree_leaves(new_state.params):
+            params_finite = jnp.logical_and(
+                params_finite, jnp.isfinite(leaf).all())
+        update_finite = jnp.logical_and(jnp.isfinite(gnorm), params_finite)
         return new_state, gnorm, update_finite
 
     def _generation_body(self, state: ShardedESState, table_data):
@@ -520,12 +692,27 @@ class ShardedESEngine:
             okey, rkey = _gen_keys(state)
             offsets = self._offsets(okey)
             leaf_keys = self._leaf_keys(okey)
-        fitness, bc, steps = self._eval_all(
-            state, offsets, leaf_keys, rkey, table_data)
+        perturbed = self.forward_form == "perturbed"
+        if perturbed:
+            # the pairs' rows, read once for evaluation, update and best
+            noise_rows = self._noise_rows(offsets, table_data)
+            with stage(PERTURB):
+                center = jax.tree_util.tree_map(
+                    lambda x, sh: jax.lax.with_sharding_constraint(
+                        x.astype(self._dtype), sh),
+                    state.params, self.param_shardings)
+            fitness, bc, steps = self._eval_all_perturbed(
+                state, center, noise_rows, rkey)
+        else:
+            fitness, bc, steps = self._eval_all(
+                state, offsets, leaf_keys, rkey, table_data)
         with stage(RANK):
             weights, n_valid = centered_rank_safe(fitness)
-        grad = self._weighted_noise_sum(
-            state, offsets, leaf_keys, weights, table_data)
+        if perturbed:
+            grad = self._weighted_factor_sum(state, noise_rows, weights)
+        else:
+            grad = self._weighted_noise_sum(
+                state, offsets, leaf_keys, weights, table_data)
         new_state, gnorm, update_finite = self._finish_update(
             state, grad, n_valid)
         # In-program best-member reconstruction: ES.train snapshots the
@@ -537,10 +724,14 @@ class ShardedESEngine:
             safe_fit = jnp.where(jnp.isfinite(fitness), fitness, -jnp.inf)
             best_rows, best_signs = self._member_rows_signs(
                 jnp.argmax(safe_fit)[None])
+        if perturbed:
+            with stage(NOISE):
+                best_eps = jax.tree_util.tree_leaves(lowrank_tree_noise(
+                    self.lr_spec, noise_rows[best_rows[0]]))
         best_leaves = []
         for i, leaf in enumerate(jax.tree_util.tree_leaves(state.params)):
             with stage(NOISE):
-                eps = self._row_noise(
+                eps = best_eps[i] if perturbed else self._row_noise(
                     i, leaf_keys[i], offsets, best_rows, table_data)[0]
             with stage(PERTURB):
                 best_leaves.append(jax.lax.with_sharding_constraint(
@@ -568,8 +759,22 @@ class ShardedESEngine:
 
         chex.assert_shape(params_flat, (self.spec.dim,))
         chex.assert_tree_all_finite(params_flat)
-        params = jax.device_put(
-            self.spec.unravel(jnp.asarray(params_flat)), self.param_shardings)
+        # place leaf by leaf, each slice straight onto its shards: the flat
+        # vector is never replicated over the mesh and no second whole
+        # tree exists anywhere (a host vector is read where it lies)
+        import numpy as np
+
+        on_host = isinstance(params_flat, np.ndarray)
+        flat = params_flat if on_host else jnp.asarray(params_flat)
+        leaves, at = [], 0
+        for shape, size, sharding in zip(self.leaf_shapes, self.leaf_sizes,
+                                         self._param_sharding_leaves):
+            part = (flat[at:at + size] if on_host else
+                    jax.lax.dynamic_slice(flat, (jnp.int32(at),), (size,)))
+            leaves.append(jax.device_put(
+                part.reshape(shape).astype(jnp.float32), sharding))
+            at += size
+        params = jax.tree_util.tree_unflatten(self._treedef, leaves)
         # init the optimizer state ON the mesh: out_shardings places the
         # param-shaped moments without a replicated round-trip
         opt_state = jax.jit(
@@ -602,6 +807,18 @@ class ShardedESEngine:
         self.telemetry.compile_event("generation_step_sharded", dt,
                                      compiled=compiled, first_call=True)
         return dt
+
+    @property
+    def param_bytes_per_chip(self) -> int:
+        """Float32 bytes of the centre one device holds, from the resolved
+        shardings (optimizer moments are param-shaped multiples of it)."""
+        total = 0
+        for shape, sh in zip(self.leaf_shapes, self._param_sharding_leaves):
+            n = 4
+            for d in sh.shard_shape(shape):
+                n *= d
+            total += n
+        return total
 
     def memory_facts(self) -> dict:
         """XLA per-device byte facts of the compiled generation program
@@ -639,10 +856,14 @@ class ShardedESEngine:
             row, sign = idx, 1.0
         row = jnp.int32(row)
         table_data = self.table.data if self.noise_mode == "table" else None
+        if self.forward_form == "perturbed":
+            dense = jax.tree_util.tree_leaves(lowrank_tree_noise(
+                self.lr_spec, self.table.slice(offsets[row], self.noise_dim)))
         flats = []
         for i, leaf in enumerate(jax.tree_util.tree_leaves(state.params)):
-            eps = self._row_noise(
-                i, leaf_keys[i], offsets, row[None], table_data)[0]
+            eps = dense[i] if self.forward_form == "perturbed" else (
+                self._row_noise(
+                    i, leaf_keys[i], offsets, row[None], table_data)[0])
             flats.append(
                 (jax.device_get(leaf) + jax.device_get(
                     state.sigma * sign * eps)).reshape(-1))
@@ -655,4 +876,5 @@ class ShardedESEngine:
         divisibility fallbacks (manifests, tests, docs examples)."""
         params_shape = jax.eval_shape(
             self.spec.unravel, jax.ShapeDtypeStruct((self.spec.dim,), jnp.float32))
-        return sharding_summary(params_shape, self.param_shardings)
+        return sharding_summary(params_shape, self.param_shardings,
+                                self.partition_rules)

@@ -15,8 +15,8 @@ import pytest
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole, Pendulum
 from estorch_tpu.ops.lowrank import (
-    lowrank_noise_tree,
-    lowrank_weighted_sum,
+    lowrank_tree_noise,
+    lowrank_tree_weighted_sum,
     make_lowrank_spec,
 )
 
@@ -40,24 +40,50 @@ class TestSpec:
         spec = make_lowrank_spec(params, rank=2)
         # kernels: (6+8)*2 + (8+3)*2 = 50; biases: 8 + 3 = 11
         assert spec.noise_dim == 50 + 11
-        unpacked = spec.unpack(jnp.arange(spec.noise_dim, dtype=jnp.float32))
-        a, b, nb = unpacked["dense_0"]
+        vec = jnp.arange(spec.noise_dim, dtype=jnp.float32)
+        unpacked = spec.unpack(vec)
+        (a, b), nb = unpacked["dense_0"]["kernel"], unpacked["dense_0"]["bias"]
         assert a.shape == (6, 2) and b.shape == (8, 2) and nb.shape == (8,)
-        a, b, nb = unpacked["head"]
+        (a, b), nb = unpacked["head"]["kernel"], unpacked["head"]["bias"]
         assert a.shape == (8, 2) and b.shape == (3, 2) and nb.shape == (3,)
+        # the MLP case of the tree spec keeps the layout its runs have
+        # always drawn: kernels in layer order (A then B), then biases
+        assert float(unpacked["dense_0"]["kernel"][0][0, 0]) == 0.0
+        assert float(unpacked["dense_0"]["kernel"][1][0, 0]) == 12.0
+        assert float(a[0, 0]) == 28.0 and float(b[0, 0]) == 44.0
+        assert float(unpacked["dense_0"]["bias"][0]) == 50.0
+        assert float(nb[0]) == 58.0
+        # a leading batch axis (one row per pair) unpacks leaf by leaf
+        batched = spec.unpack(jnp.stack([vec, vec + 1.0]))
+        assert batched["head"]["kernel"][1].shape == (2, 3, 2)
+        np.testing.assert_array_equal(batched["head"]["bias"][1], nb + 1.0)
+
+    def test_tree_spec_lays_noise_out_in_leaf_order_by_default(self):
+        from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
+
+        params = _mlp_params(jax.random.key(0), dims=(6, 8, 3))
+        tree = make_lowrank_tree_spec(params, 2)
+        mlp = make_lowrank_spec(params, rank=2)
+        assert tree.noise_dim == mlp.noise_dim == 61
+        assert type(tree) is type(mlp)
+        # leaf order: dense_0/bias first; the MLP order: dense_0/kernel
+        assert tree.dense_leaves[0][-1] == 0 and mlp.lr_leaves[0][3] == 0
+        with pytest.raises(ValueError, match="low_rank"):
+            make_lowrank_tree_spec(params, 0)
 
     def test_dense_fallback_when_rank_not_low(self):
         """rank ≥ min(m, n) layers get exact dense noise (same size, exact
         Gaussian) instead of a fake low-rank factorization."""
         params = _mlp_params(jax.random.key(0), dims=(6, 8, 3))
         spec = make_lowrank_spec(params, rank=3)  # head is 8x3 → dense
-        assert [l[0] for l in spec.lr_layers] == ["dense_0"]
-        assert [l[0] for l in spec.dense_layers] == ["head"]
+        # leaves in tree order: dense_0/{bias, kernel}, head/{bias, kernel}
+        assert [l[0] for l in spec.lr_leaves] == [1]
+        assert [l[0] for l in spec.dense_leaves] == [0, 2, 3]
         # dense_0: (6+8)*3 = 42; head dense: 8*3 = 24; biases: 8+3 = 11
         assert spec.noise_dim == 42 + 24 + 11
         unpacked = spec.unpack(jnp.arange(spec.noise_dim, dtype=jnp.float32))
-        e, none_marker, nb = unpacked["head"]
-        assert none_marker is None
+        e, nb = unpacked["head"]["kernel"], unpacked["head"]["bias"]
+        assert not isinstance(e, tuple)
         assert e.shape == (8, 3) and nb.shape == (3,)
 
     def test_unit_variance_entries(self):
@@ -68,7 +94,7 @@ class TestSpec:
         vals = []
         for s in range(200):
             noise = jax.random.normal(jax.random.key(s), (spec.noise_dim,))
-            dense = lowrank_noise_tree(spec, noise)
+            dense = lowrank_tree_noise(spec, noise)
             vals.append(np.asarray(dense["dense_0"]["kernel"]).ravel())
         flat = np.concatenate(vals)
         assert abs(flat.mean()) < 0.01
@@ -82,18 +108,18 @@ class TestUpdateReduction:
         k = 9
         noise = jax.random.normal(jax.random.key(2), (k, spec.noise_dim))
         w = jax.random.normal(jax.random.key(3), (k,))
-        got = lowrank_weighted_sum(spec, noise, w)
+        got = lowrank_tree_weighted_sum(spec, noise, w)
         # oracle: materialize every member's dense tree and sum
         for name in ("dense_0", "head"):
             want_k = sum(
-                float(w[i]) * np.asarray(lowrank_noise_tree(spec, noise[i])[name]["kernel"])
+                float(w[i]) * np.asarray(lowrank_tree_noise(spec, noise[i])[name]["kernel"])
                 for i in range(k)
             )
             np.testing.assert_allclose(
                 np.asarray(got[name]["kernel"]), want_k, rtol=1e-5, atol=1e-5
             )
             want_b = sum(
-                float(w[i]) * np.asarray(lowrank_noise_tree(spec, noise[i])[name]["bias"])
+                float(w[i]) * np.asarray(lowrank_tree_noise(spec, noise[i])[name]["bias"])
                 for i in range(k)
             )
             np.testing.assert_allclose(
@@ -116,7 +142,7 @@ class TestForward:
 
         got = mlp_lowrank_apply(module, params, spec.unpack(noise), c, obs)
 
-        dense = lowrank_noise_tree(spec, noise)
+        dense = lowrank_tree_noise(spec, noise)
         perturbed = jax.tree_util.tree_map(
             lambda p, e: p + c * e, params, dense
         )

@@ -459,9 +459,12 @@ class TestShardedES:
 
         with pytest.raises(ValueError, match="shard_params=True"):
             ES(**{**es_cls_common, "model_shards": 4})
-        with pytest.raises(ValueError, match="float32"):
-            ES(shard_params=True,
-               **{**es_cls_common, "compute_dtype": "bfloat16"})
+        # bfloat16 is no longer refused: an f32 centre, a bf16 forward
+        es = ES(shard_params=True,
+                **{**es_cls_common, "compute_dtype": "bfloat16"})
+        es.train(1, verbose=False)
+        assert np.isfinite(np.asarray(es.state.params_flat)).all()
+        assert es.state.params_flat.dtype == jnp.float32
         with pytest.raises(ValueError, match="obs_norm"):
             ES(shard_params=True, **{**es_cls_common, "obs_norm": True})
 
@@ -603,3 +606,178 @@ class TestResilienceWithDonation:
         np.testing.assert_array_equal(
             np.asarray(es.state.params_flat),
             np.asarray(clean.state.params_flat))
+
+
+# ---------------------------------------------------------------------
+# the non-materialising form: a sequence model, low-rank rows from the
+# table, factors batched over pairs, the centre un-batched (ISSUE 26)
+# ---------------------------------------------------------------------
+
+def _lm_es(devices, model_shards, **over):
+    import lm_tiny
+    from estorch_tpu import ES, JaxAgent
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import HybridLM
+
+    kw = dict(
+        policy=HybridLM, agent=JaxAgent, optimizer=optax.adam,
+        population_size=8, sigma=0.02, policy_kwargs=lm_tiny.TINY,
+        agent_kwargs={"env": TokenScoreEnv(**lm_tiny.ENV)},
+        optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
+        model_shards=model_shards, low_rank=1, noise_mode="table",
+        table_size=1 << 18, device=list(devices))
+    kw.update(over)
+    return ES(**kw)
+
+
+class TestPerturbedForm:
+    @pytest.fixture(scope="class")
+    def one_device(self, devices8):
+        es = _lm_es(devices8[:1], 1)
+        offsets = np.asarray(es.engine.all_pair_offsets(es.state))
+        es.train(2, verbose=False)
+        return dict(fitness=[r["reward_mean"] for r in es.history],
+                    params=np.asarray(es.state.params_flat), offsets=offsets)
+
+    @pytest.mark.parametrize("pop, model", [(2, 2), (1, 4), (4, 1)])
+    def test_mesh_shapes_match_one_device(self, one_device, devices8, pop,
+                                          model):
+        """Fitness and updated parameters, allclose at f32: GSPMD's
+        all-reduces reassociate float32 sums, nothing else differs."""
+        es = _lm_es(devices8[:4], model)
+        assert es.engine.forward_form == "perturbed"
+        assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
+        np.testing.assert_array_equal(
+            es.engine.all_pair_offsets(es.state), one_device["offsets"])
+        es.train(2, verbose=False)
+        np.testing.assert_allclose(
+            [r["reward_mean"] for r in es.history], one_device["fitness"],
+            rtol=2e-6)
+        np.testing.assert_allclose(np.asarray(es.state.params_flat),
+                                   one_device["params"], atol=1e-5, rtol=0)
+        cfg = es.run_manifest()["config"]
+        assert cfg["forward_form"] == "perturbed"
+        gauges = es.obs.counters
+        assert gauges.get("forward_form") == "perturbed"
+        assert gauges.get("mesh_shape") == f"{pop}x{model}"
+        assert gauges.get("noise_rows_per_generation") == 4
+        assert gauges.get("tokens_per_generation") == 8 * 21
+        assert 0 < gauges.get("param_bytes_per_chip") <= 4 * es._spec.dim
+
+    def test_bfloat16_forward_over_a_float32_centre(self, one_device,
+                                                    devices8):
+        es = _lm_es(devices8[:4], 2, compute_dtype="bfloat16")
+        es.train(2, verbose=False)
+        params = np.asarray(es.state.params_flat)
+        assert params.dtype == np.float32 and np.isfinite(params).all()
+        # Adam's steps are +-lr wherever the ranks agree; bf16 may swap
+        # the ranks of near-equal members, so "close" is a few steps
+        assert np.abs(params - one_device["params"]).max() <= 4 * 1e-2
+        np.testing.assert_allclose(
+            [r["reward_mean"] for r in es.history], one_device["fitness"],
+            rtol=1e-3)
+        assert all(leaf.dtype == jnp.float32 for leaf in
+                   jax.tree_util.tree_leaves(es.state.opt_state)
+                   if jnp.issubdtype(leaf.dtype, jnp.floating))
+
+    def test_no_member_weights_and_no_whole_tree_gather(self, devices8):
+        """The lowered program holds no ``[members | pairs, m, n]`` array
+        for any factored leaf, and the compiled one gathers no whole
+        leaf."""
+        import re
+
+        es = _lm_es(devices8[:4], 2)
+        eng = es.engine
+        lowered = eng._generation_step.lower(es.state, eng.table.data)
+        text = lowered.as_text()
+        batch = {eng.pair_chunk, eng.eval_chunk, eng.rows_padded,
+                 eng.members_padded, 2}
+        for _, m, n, _, _ in eng.lr_spec.lr_leaves:
+            for lead in batch:
+                for dims in (f"{lead}x{m}x{n}x", f"{lead}x2x{m}x{n}x"):
+                    assert f"tensor<{dims}" not in text, dims
+        compiled = lowered.compile().as_text()
+        gathered = re.findall(r"= (\w+)\[([\d,]*)\]\S* all-gather", compiled)
+        whole = {tuple(s) for s in eng.leaf_shapes if len(s) == 2}
+        for _, dims in gathered:
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            assert shape not in whole or shape[0] * shape[1] <= 4096, shape
+
+    def test_best_member_stays_sharded_until_it_is_read(self, devices8):
+        es = _lm_es(devices8[:4], 2)
+        es.train(1, verbose=False)
+        held = es._best
+        assert not isinstance(held, np.ndarray)        # the sharded tree
+        leaf = jax.tree_util.tree_leaves(held)[0]
+        assert len(leaf.sharding.device_set) == 4
+        flat = es._best_flat                            # read: gathered now
+        assert isinstance(flat, np.ndarray) and flat.shape == (es._spec.dim,)
+        assert isinstance(es._best, np.ndarray)
+
+    def test_member_params_match_the_emitted_best_member(self, devices8):
+        es = _lm_es(devices8[:4], 2)
+        state0_params = np.asarray(es.state.params_flat)
+        want = {i: np.asarray(es.engine.member_params(es.state, i))
+                for i in range(8)}
+        assert np.abs(want[0] - state0_params).max() > 0
+        # members 2k and 2k+1 mirror each other around the centre
+        np.testing.assert_allclose(want[2] + want[3], 2 * state0_params,
+                                   atol=1e-6)
+        es.train(1, verbose=False)
+        got = es._best_flat
+        assert min(np.abs(got - w).max() for w in want.values()) < 1e-6
+
+    def test_low_rank_table_rows_need_a_perturbed_forward(self, setup,
+                                                          devices8):
+        mesh = hyperscale_mesh(2, 2, devices8[:4])
+        cfg = EngineConfig(population_size=8, sigma=0.1, horizon=5,
+                           low_rank=1)
+        with pytest.raises(ValueError, match="perturbed forward"):
+            _sharded(setup, mesh, noise_mode="table", cfg=cfg)
+
+    def test_mlp_takes_the_perturbed_form_too(self, devices8):
+        """Any policy with a perturbed forward: the MLP's is
+        mlp_lowrank_apply on the same primitive."""
+        from estorch_tpu import ES, JaxAgent
+
+        es = ES(policy=MLPPolicy, agent=JaxAgent, optimizer=optax.adam,
+                population_size=16, sigma=0.05,
+                policy_kwargs={"action_dim": 2, "hidden": (16,)},
+                agent_kwargs={"env": CartPole(), "horizon": 20},
+                optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
+                model_shards=2, low_rank=1, noise_mode="table",
+                table_size=1 << 16, device=list(devices8[:4]))
+        assert es.engine.forward_form == "perturbed"
+        es.train(2, verbose=False)
+        assert np.isfinite(np.asarray(es.state.params_flat)).all()
+        assert es.history[-1]["env_steps"] > 0
+
+
+class TestCatchAllIsReported:
+    def test_unmatched_leaves_are_named_with_their_bytes(self, devices8,
+                                                         caplog):
+        import logging
+
+        from estorch_tpu.parallel.mesh import unmatched_leaves
+
+        mesh = hyperscale_mesh(2, 2, devices8[:4])
+        tree = {"dense_0": {"kernel": jnp.zeros((4, 8)), "bias": jnp.zeros(8)},
+                "mixer": {"strange_matrix": jnp.zeros((512, 1024))},
+                "scalar": jnp.zeros(())}
+        assert unmatched_leaves(DEFAULT_PARTITION_RULES, tree) == {
+            "mixer/strange_matrix": 512 * 1024 * 4}
+        with caplog.at_level(logging.WARNING, "estorch_tpu.parallel.mesh"):
+            sh = match_partition_rules(DEFAULT_PARTITION_RULES, tree, mesh)
+        assert all(a is None for a in sh["mixer"]["strange_matrix"].spec)
+        hits = [r for r in caplog.records if "catch-all" in r.getMessage()]
+        assert len(hits) == 1
+        assert "mixer/strange_matrix (2097152 B)" in hits[0].getMessage()
+        report = sharding_summary(tree, sh, DEFAULT_PARTITION_RULES)
+        assert "catch-all" in report["mixer/strange_matrix"]
+        assert "2097152 bytes" in report["mixer/strange_matrix"]
+        assert "catch-all" not in report["dense_0/kernel"]
+        caplog.clear()
+        with caplog.at_level(logging.WARNING, "estorch_tpu.parallel.mesh"):
+            match_partition_rules(DEFAULT_PARTITION_RULES,
+                                  {"dense_0": tree["dense_0"]}, mesh)
+        assert not caplog.records

@@ -15,9 +15,14 @@ import pytest
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
 from estorch_tpu.obs.spans import Telemetry
-from estorch_tpu.obs.trace import (ENV, GATHER, GRAD, NOISE, PERTURB, POLICY,
-                                   RANK, SAMPLE, SCOPE_PREFIX, STAGES, UPDATE,
+from estorch_tpu.obs.trace import (ATTN, DENSE, ENV, GATHER, GRAD, HEAD, NOISE,
+                                   PERTURB, POLICY, RANK, SAMPLE,
+                                   SCOPE_PREFIX, SSM, STAGES, UPDATE,
                                    annotate, stage, trace)
+
+# the stages of every generation program; a sequence model nests four more
+# inside es.policy (DENSE, SSM, ATTN, HEAD)
+GENERATION_STAGES = STAGES[:9]
 
 SCOPE = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(SCOPE_PREFIX)
                    + r"([a-z_]+)")
@@ -70,9 +75,9 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source):
     text = engine._generation_step.lower(*args).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     found = {s for name in names for s in SCOPE.findall(name)}
-    assert found == set(STAGES), (
-        f"{form}: stages missing {set(STAGES) - found}, unknown "
-        f"{found - set(STAGES)}")
+    assert found == set(GENERATION_STAGES), (
+        f"{form}: stages missing {set(GENERATION_STAGES) - found}, unknown "
+        f"{found - set(GENERATION_STAGES)}")
     # the matmuls of the rollout's while body are the forward's or the
     # physics'; the only others are the update's second pass over the noise
     matmuls = [SCOPE.findall(m) for line in text.splitlines()
@@ -84,11 +89,50 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source):
     assert any(POLICY in stack for stack in matmuls)
 
 
+def test_sequence_model_names_its_layers_inside_the_policy_stage(
+        keyed_by_source):
+    """The sharded engine's perturbed form on a HybridLM: every stage of a
+    generation, and es.dense / es.ssm / es.attn / es.head nested inside
+    es.policy, the rank-r corrections under es.perturb."""
+    import lm_tiny
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import HybridLM
+
+    es = ES(policy=HybridLM, agent=JaxAgent, optimizer=optax.adam,
+            population_size=8, sigma=0.02, policy_kwargs=lm_tiny.TINY,
+            agent_kwargs={"env": TokenScoreEnv(**lm_tiny.ENV)},
+            optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
+            model_shards=2, low_rank=1, noise_mode="table",
+            table_size=1 << 18, device=jax.devices()[:4])
+    engine = es.engine
+    text = engine._generation_step.lower(
+        es.state, engine.table.data).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    found = {s for name in names for s in SCOPE.findall(name)}
+    assert found == set(STAGES), (set(STAGES) - found, found - set(STAGES))
+    for inner in (DENSE, SSM, ATTN, HEAD):
+        stacks = [SCOPE.findall(n) for n in names
+                  if SCOPE_PREFIX + inner in n]
+        # (the compiler shortens a few names to their last scope, e.g.
+        # "es.attn/reduce_max": those carry no outer stage at all)
+        nested = [st for st in stacks if POLICY in st]
+        assert len(nested) > len(stacks) // 2, inner
+        assert all(st.index(POLICY) < st.index(inner) for st in nested), inner
+    # the projections are matmuls under es.dense or es.head; the
+    # corrections are nested one deeper, under es.perturb
+    matmuls = [SCOPE.findall(m) for line in text.splitlines()
+               for m in MATMUL.findall(line)]
+    assert any(st[-1] == DENSE for st in matmuls)
+    assert any(st[-1] == HEAD for st in matmuls)
+    assert any(st[-1] == PERTURB and DENSE in st
+               for name in names for st in [SCOPE.findall(name)] if st)
+
+
 @pytest.mark.parametrize("use", ["context", "decorator"])
 def test_stage_scopes_a_name_stack(use):
-    assert len(set(STAGES)) == len(STAGES) == 9
+    assert len(set(STAGES)) == len(STAGES) == 13
     assert (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD,
-            UPDATE) == STAGES
+            UPDATE, DENSE, SSM, ATTN, HEAD) == STAGES
     if use == "context":
         def f(x):
             with stage(NOISE):
