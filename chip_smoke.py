@@ -12,8 +12,9 @@ width of a shipped model, with weights made from a seed:
   spans every device jax reports, env steps per generation inside what the
   config implies, finite fitness, finite parameters that moved, ZERO XLA
   programs built after generation 0, non-zero peak HBM on every mesh device.
-  Then the two Pallas kernels are lowered through Mosaic at this policy's
-  shapes and compared with their ``jnp`` references; on a host with more
+  Then the Pallas kernels (row gather, weighted sum, streamed forward) are
+  lowered through Mosaic at this policy's shapes and compared with their
+  ``jnp`` references; on a host with more
   than one chip, the multi-chip checks run in the same process (all-gather
   and all-reduce over N participants in the compiled program; one chip vs
   all chips from one seed — same fitness, same update, same first trained
@@ -78,8 +79,9 @@ def _np(x):
 
 
 def _kernel_check(es) -> None:
-    """Both Pallas kernels through Mosaic (interpret=False) at this
-    policy's shapes, against their jnp references at the tolerances of
+    """The Pallas kernels through Mosaic (interpret=False) at this
+    policy's shapes, against their jnp references: the row gather bit for
+    bit, the other two at the tolerances of
     tests/test_pallas_noise.py — except the reduction's absolute floor,
     which is the f32 forward-error bound of an n-term sum (n·eps·max|w|·
     max|ε|): over 75,018 outputs some sums land near zero, where the
@@ -94,6 +96,7 @@ def _kernel_check(es) -> None:
     from estorch_tpu.models.decomposed import mlp_decomposed_apply
     from estorch_tpu.ops import rank_weighted_noise_sum
     from estorch_tpu.ops.pallas_noise import (flat_layer_offsets,
+                                              gather_noise_rows,
                                               mlp_streamed_apply,
                                               weighted_noise_sum)
 
@@ -110,6 +113,14 @@ def _kernel_check(es) -> None:
                                      jnp.int32))
     w = jax.random.normal(jax.random.fold_in(key, 1), (n,))
     t0 = time.perf_counter()
+    for dtype in (jnp.bfloat16, jnp.float32):
+        got = gather_noise_rows(table.data, offs, dim=dim, dtype=dtype,
+                                interpret=False)
+        want = jax.vmap(lambda o: table.slice(o, dim))(offs).astype(dtype)
+        np.testing.assert_array_equal(_np(got.astype(jnp.float32)),
+                                      _np(want.astype(jnp.float32)))
+    print(f"kernel gather_noise_rows: dim {dim}, {n} rows, bf16 and f32: "
+          "compiled by Mosaic, equal to table.slice bit for bit", flush=True)
     with jax.default_matmul_precision("highest"):
         got = weighted_noise_sum(table.data, offs, w, dim=dim,
                                  interpret=False)

@@ -545,6 +545,10 @@ class ES:
             self.obs.counters.gauge("forward_form", self.engine.forward_form)
             self.obs.counters.gauge("noise_rows_per_generation",
                                     self.engine.noise_rows_per_generation)
+        if hasattr(self.engine, "noise_gather_form"):
+            # "dma" says the row kernels of ops/pallas_noise.py engaged
+            self.obs.counters.gauge("noise_gather_form",
+                                    self.engine.noise_gather_form)
         if self._shard_params:
             self.obs.counters.gauge("mesh_shape", "x".join(
                 str(n) for n in self.mesh.devices.shape))
@@ -1166,6 +1170,10 @@ class ES:
             "forward_form": getattr(self.engine, "forward_form", None),
             "noise_rows_per_generation": getattr(
                 self.engine, "noise_rows_per_generation", None),
+            # how whole rows leave the noise table ("dma" | "slice"; None:
+            # an engine with no replicated-table gather of its own)
+            "noise_gather_form": getattr(
+                self.engine, "noise_gather_form", None),
             "streamed": self._streamed,
             "shard_params": self._shard_params,
         }

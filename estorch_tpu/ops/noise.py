@@ -11,8 +11,13 @@ design and the `north_star` of BASELINE.json:
 - noise never crosses the wire: every device derives identical offsets from a
   shared PRNG key, so the update is reconstructed locally and reduced with a
   single ``lax.psum``;
-- perturbation is a ``vmap``-ed dynamic-slice + axpy — a contiguous HBM read
-  that XLA fuses into the policy matmuls, instead of Python-loop RNG;
+- a member's noise is one contiguous span of the table, addressed by an
+  integer, instead of Python-loop RNG.  How whole rows LEAVE the table is
+  the engine's choice (``ESEngine.noise_gather_form``): ``NoiseTable.slice``
+  under ``vmap`` is the portable form and the oracle, but XLA lowers it on
+  the TPU to a sequential loop that copies one unaligned row per step
+  (3-5% of the HBM peak), so on a TPU mesh the pair-shared evaluation and
+  the update move rows by DMA instead (ops/pallas_noise.py);
 - antithetic pairs (mirrored sampling, Salimans et al. 2017 §2) share an
   offset with flipped sign, halving table reads and variance.
 
@@ -43,7 +48,9 @@ class NoiseTable:
     size: int
 
     def slice(self, offset: jax.Array, dim: int) -> jax.Array:
-        """Noise vector of length ``dim`` starting at ``offset`` (traced ok)."""
+        """Noise vector of length ``dim`` starting at ``offset`` (traced ok).
+        The definition of a noise row; many rows at once on a TPU are
+        ops/pallas_noise.py::gather_noise_rows, bit-identical to this."""
         return jax.lax.dynamic_slice(self.data, (offset,), (dim,))
 
 

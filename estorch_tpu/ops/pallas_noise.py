@@ -1,18 +1,16 @@
-"""Pallas TPU kernels that stream ε directly from the HBM noise table.
+"""Pallas TPU kernels that move ε out of the HBM noise table by DMA.
 
-The pure-JAX paths materialize per-member noise: the update reduction
-(ops/gradient.py) gathers (chunk, dim) blocks before contracting, and the
-decomposed forward (models/decomposed.py) unravels a full (dim,) noise tree
-per member that then lives in HBM for the whole episode — O(population·dim)
-resident bytes at config-3 scale (10k × 166k ≈ 6.6 GB, more than a v5e's
-HBM).  These kernels never materialize ε: windows of the table are DMA'd
-through double-buffered VMEM and consumed in place (SURVEY.md §7 design
-deltas 1/4).
+A noise row is ``table[o : o + dim]`` at an ARBITRARY offset ``o`` of a 1-D
+f32 table the chip tiles by 1024.  The pure-JAX paths read it with
+``NoiseTable.slice`` under ``vmap``, which XLA lowers on the TPU to a
+sequential loop copying one unaligned row per iteration into one sublane
+of every tile of a 2-D array: 24-37 GB/s on a v5e whose HBM moves 819
+(PERF.md).  Here rows leave the table as whole aligned windows, many bytes
+in flight, and are realigned on the chip.
 
 What Mosaic dictates (learned by compiling for the v5e, not by reading):
-a noise row starts at an ARBITRARY offset of a 1-D f32 table, but a DMA
-out of HBM must start and end on the array's tiling — (8, 128) for the
-2-D f32 view, i.e. 1024-float boundaries.  So every kernel here
+a DMA out of HBM must start and end on the array's tiling — (8, 128) for
+the 2-D f32 view, i.e. 1024-float boundaries.  So every kernel here
 
 1. views the table as ``(size/128, 128)`` (a bitcast, no copy),
 2. DMAs the ALIGNED window of rows that contains the wanted span
@@ -22,11 +20,22 @@ out of HBM must start and end on the array's tiling — (8, 128) for the
    against the next row, one dynamic sublane rotate — after which flat
    element k of the result is flat element k of the wanted span.
 
-Two kernels share that front end:
+Three kernels share that front end.  Two move WHOLE rows, on one DMA
+schedule (:func:`_this_step_row`: a few window buffers, the next rows' DMAs
+in flight under this row's realignment), and are what a generation of the
+replicated engine runs on a TPU mesh (``ESEngine.noise_gather_form ==
+"dma"``):
 
-- :func:`weighted_noise_sum` — the update reduction Σ_k w_k·ε_k.  Grid over
-  noise rows; each row's window is DMA'd once and FMA'd into a VMEM
-  accumulator that is only written back at the end.
+- :func:`gather_noise_rows` — the evaluation's pass: ``(n, dim)`` rows,
+  cast to the compute dtype in VMEM and written to a ``(n, rows, 128)``
+  slab through a pipelined output block; bit-identical to
+  ``table.slice(o, dim).astype(dtype)``.
+- :func:`weighted_noise_sum` — the update's pass, Σ_k w_k·ε_k: each row is
+  FMA'd (f32, VPU) into a VMEM accumulator that is only written back at
+  the end; no ``(chunk, dim)`` block is ever materialized.
+
+The third never materializes a member's ε at all:
+
 - :func:`population_noise_matvec` — the per-member noise term of the
   decomposed forward, y_i = c_i·(x_i @ E_i), with E_i = the member's table
   slice viewed as a (d, h) matrix.  Grid over (members × row-blocks); the
@@ -66,6 +75,10 @@ from ..obs.trace import NOISE, stage
 LANES = 128
 SUBLANES = 8
 TILE = LANES * SUBLANES  # floats per (8, 128) f32 tile: the DMA alignment
+ROW_SLOTS = 3  # window buffers of the row kernels: two rows' DMAs in flight
+# under a third's realignment (on the v5e the weighted sum is 13% faster
+# than with two, a fourth adds nothing, and several rows a grid step add
+# under 1.5%: PERF.md)
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -120,10 +133,123 @@ def _shifted(x: jax.Array, shift, rows_out: int) -> jax.Array:
 
 def _vmem_limit(window_rows: int) -> int | None:
     """Scoped-VMEM request for a kernel whose largest live arrays are a
-    few window-sized buffers: the 16 MiB default up to ~0.5M floats per
-    window, more (of the v5e's 128 MiB) past it."""
-    need = 6 * window_rows * LANES * 4
+    few window-sized buffers (the slots, the accumulator or the output
+    block's two, the realignment's temporaries): the 16 MiB default up to
+    ~0.4M floats per window, more (of the v5e's 128 MiB) past it."""
+    need = (ROW_SLOTS + 6) * window_rows * LANES * 4
     return None if need <= (16 << 20) else min(need, 96 << 20)
+
+
+# --------------------------------------------------------------------------
+# whole noise rows: table[o_k : o_k + dim] for many k
+# --------------------------------------------------------------------------
+
+
+def _row_geometry(table_data: jax.Array, dim: int, sublanes: int):
+    """(table rows, window rows, output rows) of the (·, 128) views a row
+    of ``dim`` floats needs: the output rounded up to the output dtype's
+    sublane tile, the window one f32 tile longer so that any span
+    ``[shift, shift + dim)`` with shift < TILE lies inside it."""
+    rows_out = _round_up(_cdiv(dim, LANES), sublanes)
+    window_rows = rows_out + SUBLANES
+    return _table_rows(table_data, window_rows), window_rows, rows_out
+
+
+def rows_fit_dma(table_data: jax.Array, dim: int) -> bool:
+    """Whether the row kernels can serve rows of ``dim`` floats from this
+    table: f32, whole (8, 128) tiles, at least one DMA window long."""
+    size = int(table_data.shape[0])
+    # the longest window: output rows rounded to bf16's 16-sublane tile
+    window_rows = _round_up(_cdiv(dim, LANES), 2 * SUBLANES) + SUBLANES
+    return (table_data.dtype == jnp.float32 and size % TILE == 0
+            and size >= window_rows * LANES)
+
+
+def _this_step_row(offs_ref, table_ref, buf, sem, t_rows: int, n: int,
+                   rows_out: int) -> jax.Array:
+    """Grid step ``i`` of a row kernel over ``n`` rows: noise row ``i``,
+    realigned, as ``(rows_out, 128)`` f32.  Its aligned window landed in
+    slot ``i % slots`` while earlier rows were consumed; the windows of the
+    next ``slots − 1`` rows are in flight when this returns."""
+    slots, window_rows = buf.shape[0], buf.shape[1]
+    i = pl.program_id(0)
+
+    def dma(r):
+        first, _ = _window(offs_ref[r], t_rows, window_rows)
+        slot = jax.lax.rem(r, slots)
+        return pltpu.make_async_copy(
+            table_ref.at[pl.ds(first, window_rows), :],
+            buf.at[slot],
+            sem.at[slot],
+        )
+
+    @pl.when(i == 0)
+    def _prime():
+        for r in range(min(slots - 1, n)):
+            dma(r).start()
+
+    # row i − 1 has been consumed: its slot takes row i + slots − 1
+    @pl.when(i + slots - 1 < n)
+    def _prefetch():
+        dma(i + slots - 1).start()
+
+    dma(i).wait()
+    _, shift = _window(offs_ref[i], t_rows, window_rows)
+    return _shifted(buf[jax.lax.rem(i, slots)], shift, rows_out)
+
+
+def _row_scratch(table_data: jax.Array, window_rows: int):
+    return [
+        pltpu.VMEM((ROW_SLOTS, window_rows, LANES), table_data.dtype),
+        pltpu.SemaphoreType.DMA((ROW_SLOTS,)),
+    ]
+
+
+def _gather_kernel(t_rows: int, n: int, rows_out: int):
+    def kernel(offs_ref, table_ref, out_ref, buf, sem):
+        row = _this_step_row(offs_ref, table_ref, buf, sem, t_rows, n,
+                             rows_out)
+        out_ref[0] = row.astype(out_ref.dtype)
+
+    return kernel
+
+
+@partial(jax.jit, static_argnames=("dim", "dtype", "interpret"))
+def gather_noise_rows(
+    table_data: jax.Array,  # (table_size,) float32 — NoiseTable.data
+    offsets: jax.Array,  # (n,) int32 row offsets
+    dim: int,
+    dtype,  # the rows' dtype: the cast happens in VMEM, after the DMA
+    interpret: bool,
+) -> jax.Array:
+    """``(n, dim)`` noise rows, ``table[o : o + dim].astype(dtype)`` bit for
+    bit (ops/noise.py::NoiseTable.slice), moved by DMA.
+
+    The kernel writes a ``(n, rows_out, 128)`` slab whose row k, read
+    flat, is noise row k followed by padding up to the tile; the caller
+    gets its ``[:, :dim]``."""
+    n = int(offsets.shape[0])
+    dtype = jnp.dtype(dtype)
+    if n == 0:
+        return jnp.zeros((0, dim), dtype)
+    t_rows, window_rows, rows_out = _row_geometry(
+        table_data, dim, SUBLANES * 4 // dtype.itemsize)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # offsets
+        grid=(n,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # table stays in HBM
+        out_specs=pl.BlockSpec((1, rows_out, LANES), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=_row_scratch(table_data, window_rows),
+    )
+    out = pl.pallas_call(
+        _gather_kernel(t_rows, n, rows_out),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, rows_out, LANES), dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(window_rows)),
+        interpret=interpret,
+    )(offsets.astype(jnp.int32), table_data.reshape(t_rows, LANES))
+    return out.reshape(n, rows_out * LANES)[:, :dim]
 
 
 # --------------------------------------------------------------------------
@@ -131,33 +257,15 @@ def _vmem_limit(window_rows: int) -> int | None:
 # --------------------------------------------------------------------------
 
 
-def _weighted_sum_kernel(t_rows: int, window_rows: int, rows_out: int):
+def _weighted_sum_kernel(t_rows: int, n: int, rows_out: int):
     def kernel(offs_ref, w_ref, table_ref, out_ref, buf, sem):
-        i = pl.program_id(0)
-        n = pl.num_programs(0)
-
-        def dma(slot, row):
-            first, _ = _window(offs_ref[row], t_rows, window_rows)
-            return pltpu.make_async_copy(
-                table_ref.at[pl.ds(first, window_rows), :],
-                buf.at[slot],
-                sem.at[slot],
-            )
-
-        @pl.when(i == 0)
+        @pl.when(pl.program_id(0) == 0)
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
-            dma(0, 0).start()
 
-        # double buffering: next row's DMA flies while this row is consumed
-        @pl.when(i + 1 < n)
-        def _prefetch():
-            dma((i + 1) % 2, i + 1).start()
-
-        slot = jax.lax.rem(i, 2)
-        dma(slot, i).wait()
-        _, shift = _window(offs_ref[i], t_rows, window_rows)
-        out_ref[...] += w_ref[i] * _shifted(buf[slot], shift, rows_out)
+        row = _this_step_row(offs_ref, table_ref, buf, sem, t_rows, n,
+                             rows_out)
+        out_ref[...] += w_ref[pl.program_id(0)] * row
 
     return kernel
 
@@ -173,29 +281,24 @@ def weighted_noise_sum(
     """Streamed Σ_k w_k·ε_k: one DMA per noise row, zero materialization.
 
     Drop-in for ops/gradient.py::rank_weighted_noise_sum (same contract);
-    VMEM cost is ~3·dim floats (double buffer + accumulator) plus the
-    realignment temporaries, so it suits dims up to ~1M params.  Callers
-    with larger dims should keep the chunked pure-JAX path.
+    the FMA is f32 on the VPU.  VMEM cost is ~4·dim floats (the window
+    buffers and the accumulator, which is only written back at the end)
+    plus the realignment temporaries, so it suits dims up to ~1M params.
+    Callers with larger dims should keep the chunked pure-JAX path.
     """
     n = int(offsets.shape[0])
     if n == 0:
         return jnp.zeros((dim,), table_data.dtype)
-    rows_out = _round_up(_cdiv(dim, LANES), SUBLANES)
-    # any span [shift, shift + dim) with shift < TILE fits rows_out + 8 rows
-    window_rows = rows_out + SUBLANES
-    t_rows = _table_rows(table_data, window_rows)
+    t_rows, window_rows, rows_out = _row_geometry(table_data, dim, SUBLANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # offsets, weights
         grid=(n,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # table stays in HBM
         out_specs=pl.BlockSpec((rows_out, LANES), lambda i, *_: (0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, window_rows, LANES), table_data.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        scratch_shapes=_row_scratch(table_data, window_rows),
     )
     out = pl.pallas_call(
-        _weighted_sum_kernel(t_rows, window_rows, rows_out),
+        _weighted_sum_kernel(t_rows, n, rows_out),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_out, LANES), table_data.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -76,12 +76,13 @@ class EngineConfig:
     # materialized weights. Mirrored runs need no flag: the engine takes
     # the pair-shared form of the same identity whenever it is given a
     # decomposed_apply (ESEngine.forward_form; models/decomposed.py)
-    noise_kernel: bool = False  # Pallas streamed update reduction
-    # (ops/pallas_noise.py): ε rows DMA'd from the HBM table through
-    # double-buffered VMEM and FMA'd in place — no (chunk, dim)
-    # materialization. Mosaic on a TPU mesh, the Pallas interpreter on any
-    # other (decided from the mesh's devices, never from the default
-    # backend).
+    noise_kernel: bool = False  # True forces the DMA form of the table's
+    # row gather (ops/pallas_noise.py: rows leave the HBM table as aligned
+    # windows and are realigned in VMEM; the update FMAs them in place, no
+    # (chunk, dim) materialization) — Mosaic on a TPU mesh, the Pallas
+    # interpreter on any other (decided from the mesh's devices, never
+    # from the default backend).  False: the engine decides
+    # (ESEngine.noise_gather_form: "dma" on a TPU mesh, "slice" elsewhere)
     low_rank: int = 0  # >0: per-layer kernel noise E = A·Bᵀ/√r with r =
     # low_rank (ops/lowrank.py, PAPERS.md "ES at the Hyperscale"): member
     # noise state shrinks O(dim) → O(Σ(m+n)·r), the forward's noise term
@@ -301,7 +302,8 @@ def _choose_eval_chunk(requested: int, local_members: int) -> int:
     return c
 
 
-NOISE_KERNEL_MAX_DIM = 1_000_000  # 3·dim f32 ≈ 12 MiB of ~16 MiB v5e VMEM
+NOISE_KERNEL_MAX_DIM = 1_000_000  # the row kernels hold a few windows of
+# dim f32 each in VMEM (ops/pallas_noise.py); both compile for the v5e here
 
 
 class ESEngine:
@@ -482,6 +484,12 @@ class ESEngine:
         self.noise_rows_per_generation = (
             config.population_size // 2 if self.forward_form == "pair_shared"
             else config.population_size)
+        # how whole rows leave the table in the pair-shared evaluation and
+        # in the update's second pass, resolved once like forward_form:
+        # "dma" (ops/pallas_noise.py) where Mosaic can compile the kernels
+        # for this mesh and table, "slice" (vmap of NoiseTable.slice: the
+        # CPU path and the oracle) anywhere else
+        self.noise_gather_form = self._resolve_noise_gather_form()
 
         self._obs_norm = config.obs_norm  # always False when env is None
         # (the guard above rejects obs_norm for update-only engines)
@@ -614,6 +622,18 @@ class ESEngine:
         # evaluates the unperturbed center policy (reference's `es.policy`):
         # used for best-snapshot logging and the novelty family's archive BCs
         self._center_eval = jax.jit(center_eval)
+
+    def _resolve_noise_gather_form(self) -> str:
+        """``"dma"`` or ``"slice"``: see ``noise_gather_form``."""
+        if self.config.noise_kernel:
+            return "dma"
+        if self._pallas_interpret or self.config.low_rank:
+            return "slice"
+        from ..ops.pallas_noise import rows_fit_dma
+
+        fits = (self.spec.dim <= NOISE_KERNEL_MAX_DIM
+                and rows_fit_dma(self.table.data, self.spec.dim))
+        return "dma" if fits else "slice"
 
     def _build_update_programs(self):
         self._apply_weights = jax.jit(
@@ -859,9 +879,19 @@ class ESEngine:
             offs_c, signs_c, keys_c = xs
             lead = (offs_c.shape[0] // 2, 2) + ((n_ep,) if n_ep > 1 else ())
             with stage(NOISE):
-                eps_c = jax.vmap(
-                    lambda off: self.table.slice(off, self.spec.dim)
-                )(offs_c[::2])
+                if self.noise_gather_form == "dma":
+                    from ..ops.pallas_noise import gather_noise_rows
+
+                    # cast in VMEM, after the DMA: the same bits as the
+                    # cast below gives the slice form's f32 rows
+                    eps_c = gather_noise_rows(
+                        self.table.data, offs_c[::2], dim=self.spec.dim,
+                        dtype=jnp.bfloat16 if self._bf16 else jnp.float32,
+                        interpret=self._pallas_interpret)
+                else:
+                    eps_c = jax.vmap(
+                        lambda off: self.table.slice(off, self.spec.dim)
+                    )(offs_c[::2])
             with stage(PERTURB):
                 noise_c = jax.vmap(
                     lambda eps: self._member_cast(self.spec.unravel(eps))
@@ -967,9 +997,10 @@ class ESEngine:
             grad_local = self.spec.flatten(tree) / (
                 cfg.population_size * state.sigma
             )
-        elif cfg.noise_kernel:
+        elif self.noise_gather_form == "dma":
             # Pallas streamed reduction: each ε row is DMA'd once and FMA'd
-            # into a VMEM accumulator — no materialized noise blocks
+            # (f32, on the VPU) into a VMEM accumulator — no materialized
+            # noise blocks
             from ..ops.gradient import fold_mirrored_weights as _fold
             from ..ops.pallas_noise import weighted_noise_sum
 

@@ -1,6 +1,7 @@
 """Pallas streamed-noise kernels vs their pure-JAX twins (interpret mode).
 
 The kernels must be bit-compatible REORDERINGS of existing math:
+- gather_noise_rows ≡ vmap(NoiseTable.slice).astype(dtype), bit for bit
 - weighted_noise_sum ≡ ops/gradient.py::rank_weighted_noise_sum
 - population_noise_matvec ≡ the c·(x@E) noise term of models/decomposed.py
 - mlp_streamed_apply ≡ mlp_decomposed_apply over a population batch
@@ -18,12 +19,83 @@ import pytest
 from estorch_tpu.ops import make_noise_table, make_param_spec, rank_weighted_noise_sum
 from estorch_tpu.ops.pallas_noise import (
     flat_layer_offsets,
+    gather_noise_rows,
     mlp_streamed_apply,
     population_noise_matvec,
+    rows_fit_dma,
     weighted_noise_sum,
 )
 
 TABLE = make_noise_table(1 << 16, seed=3)
+
+
+def _slice_rows(offs, dim, dtype):
+    """The oracle: today's form of the gather."""
+    return jax.vmap(lambda o: TABLE.slice(o, dim))(offs).astype(dtype)
+
+
+def _assert_same_bits(got, want):
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.float32)),
+        np.asarray(want.astype(jnp.float32)))
+
+
+class TestGatherNoiseRows:
+    """The evaluation's pass over the table: rows by DMA of the aligned
+    window, realigned and cast in VMEM, equal to the slice form's bit for
+    bit whatever the offset's phase, the dim or the blocking."""
+
+    # lane shifts (offset % 128), sublane shifts (offset // 128 % 8), both,
+    # the table's first and last legal offsets (the last clamps the window)
+    @pytest.mark.parametrize("offset", [0, 1, 64, 127, 128, 129, 384, 896,
+                                        1023, 1024, 1025, 2047, 5000,
+                                        "last-1024", "last-1", "last"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_every_shift_class_is_the_slice(self, offset, dtype):
+        dim = 300
+        last = TABLE.size - dim
+        if isinstance(offset, str):
+            offset = last + int(offset[4:] or 0)
+        offs = jnp.array([offset, 777], jnp.int32)
+        got = gather_noise_rows(TABLE.data, offs, dim=dim, dtype=dtype,
+                                interpret=True)
+        _assert_same_bits(got, _slice_rows(offs, dim, dtype))
+
+    # dims that are and are not multiples of 128 (and of the 1024 tile);
+    # one row, fewer rows than window buffers, more
+    @pytest.mark.parametrize("n,dim", [
+        (1, 8), (1, 128), (2, 33), (3, 256), (13, 1024), (5, 3000),
+        (16, 257), (9, 2048), (4, 640)])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_dims_and_row_counts(self, n, dim, dtype):
+        key = jax.random.key(n * 1000 + dim)
+        offs = jax.random.randint(key, (n,), 0, TABLE.size - dim + 1,
+                                  dtype=jnp.int32)
+        got = gather_noise_rows(TABLE.data, offs, dim=dim, dtype=dtype,
+                                interpret=True)
+        _assert_same_bits(got, _slice_rows(offs, dim, dtype))
+
+    def test_no_rows(self):
+        got = gather_noise_rows(TABLE.data, jnp.zeros((0,), jnp.int32),
+                                dim=8, dtype=jnp.bfloat16, interpret=True)
+        assert got.shape == (0, 8) and got.dtype == jnp.bfloat16
+
+    @pytest.mark.parametrize("size,dim,dtype,fits", [
+        (1 << 16, 300, jnp.float32, True),
+        (1 << 16, 300, jnp.bfloat16, False),  # the windows are f32 tiles
+        (1000, 8, jnp.float32, False),  # not whole (8, 128) tiles
+        (4096, 3000, jnp.float32, False),  # no room for one window
+        (8192, 3000, jnp.float32, True)])
+    def test_which_tables_the_kernels_serve(self, size, dim, dtype, fits):
+        assert rows_fit_dma(jnp.zeros((size,), dtype), dim) is fits
+        if fits:  # and what it admits does run, at the table's very end
+            table = jax.random.normal(jax.random.key(0), (size,))
+            offs = jnp.array([size - dim, 0], jnp.int32)
+            got = gather_noise_rows(table, offs, dim=dim, dtype=dtype,
+                                    interpret=True)
+            np.testing.assert_array_equal(
+                np.asarray(got[0]), np.asarray(table[size - dim:]))
 
 
 class TestWeightedNoiseSum:
@@ -36,6 +108,22 @@ class TestWeightedNoiseSum:
         got = weighted_noise_sum(TABLE.data, offs, w, dim=dim, interpret=True)
         want = rank_weighted_noise_sum(TABLE, offs, w, dim=dim)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+
+    # the FMA is f32 on the VPU: the yardstick is the reduction at the
+    # highest matmul precision
+    @pytest.mark.parametrize("n,dim", [(1, 8), (2, 33), (64, 128), (33, 257),
+                                       (5, 3000), (12, 1024)])
+    def test_matches_highest_precision(self, n, dim):
+        key = jax.random.key(n * 1000 + dim)
+        offs = jax.random.randint(key, (n,), 0, TABLE.size - dim + 1,
+                                  dtype=jnp.int32)
+        w = jax.random.normal(jax.random.fold_in(key, 1), (n,))
+        got = weighted_noise_sum(TABLE.data, offs, w, dim=dim,
+                                 interpret=True)
+        with jax.default_matmul_precision("highest"):
+            want = rank_weighted_noise_sum(TABLE, offs, w, dim=dim)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=2e-6)
 
     def test_zero_weights_zero_sum(self):
         offs = jnp.array([5, 10, 15], jnp.int32)
@@ -328,6 +416,86 @@ class TestEngineNoiseKernel:
 
         with pytest.raises(ValueError, match="noise_kernel"):
             ES(P, A, torch.optim.Adam, population_size=4, noise_kernel=True)
+
+
+class TestNoiseGatherForm:
+    """``ESEngine.noise_gather_form``: resolved once at build, reported in
+    the manifest and the gauges, and — forced with ``noise_kernel=True``,
+    interpreted — the same generation as the slice form."""
+
+    @staticmethod
+    def _es(**over):
+        import optax
+
+        from estorch_tpu import ES, JaxAgent, MLPPolicy
+        from estorch_tpu.envs import CartPole
+
+        kw = dict(
+            population_size=32, sigma=0.1, seed=0,
+            policy_kwargs={"action_dim": 2, "hidden": (16,)},
+            agent_kwargs={"env": CartPole(), "horizon": 30},
+            optimizer_kwargs={"learning_rate": 3e-2}, table_size=1 << 16)
+        kw.update(over)
+        return ES(MLPPolicy, JaxAgent, optax.adam, **kw)
+
+    @pytest.mark.parametrize("name,over,form", [
+        # a CPU mesh cannot run a Mosaic kernel: the engine keeps the slice
+        ("cpu_mesh", {}, "slice"),
+        ("cpu_mesh_bf16", {"compute_dtype": "bfloat16"}, "slice"),
+        ("cpu_mesh_unmirrored", {"mirrored": False}, "slice"),
+        ("low_rank", {"low_rank": 1}, "slice"),
+        ("forced", {"noise_kernel": True}, "dma"),
+        ("forced_streamed", {"noise_kernel": True, "streamed": True}, "dma"),
+    ])
+    def test_resolved_and_reported(self, name, over, form):
+        es = self._es(**over)
+        assert es.engine.noise_gather_form == form
+        assert es.run_manifest()["config"]["noise_gather_form"] == form
+        assert es.obs.counters.snapshot()["noise_gather_form"] == form
+
+    def test_update_only_engines_follow_the_rule(self):
+        """The pooled path's update program calls ``_local_grad`` too."""
+        import optax
+
+        from estorch_tpu import ES, MLPPolicy, PooledAgent
+
+        def mk(**over):
+            return ES(MLPPolicy, PooledAgent, optax.adam, population_size=8,
+                      sigma=0.1,
+                      policy_kwargs={"action_dim": 2, "hidden": (8,)},
+                      agent_kwargs={"env_name": "cartpole", "horizon": 10},
+                      optimizer_kwargs={"learning_rate": 1e-2},
+                      table_size=1 << 14, **over)
+
+        assert mk().engine.core.noise_gather_form == "slice"
+        assert mk(noise_kernel=True).engine.core.noise_gather_form == "dma"
+
+    @pytest.mark.parametrize("over", [
+        {}, {"compute_dtype": "bfloat16"}, {"episodes_per_member": 2},
+        {"population_size": 36},  # ghost pairs on the 8-device mesh
+        {"obs_norm": True}, {"mirrored": False},
+    ], ids=["f32", "bf16", "episodes2", "padded", "obs_norm", "unmirrored"])
+    def test_forced_dma_generation_equals_slice(self, over, devices8):
+        """Pair-shared (and, unmirrored, materialised) generations: the
+        gathered rows are the slice form's bits, so the fitness is EQUAL;
+        the update's f32 FMA against the slice form's matmul agrees to f32
+        tolerance."""
+        ref, dma = self._es(**over), self._es(noise_kernel=True, **over)
+        want = "materialised" if over.get("mirrored") is False \
+            else "pair_shared"
+        assert ref.engine.forward_form == dma.engine.forward_form == want
+        assert (ref.engine.noise_gather_form, dma.engine.noise_gather_form) \
+            == ("slice", "dma")
+        s_ref, s_dma = ref.state, dma.state
+        for gen in range(2):
+            s_ref, m_ref = ref.engine.generation_step(s_ref)
+            s_dma, m_dma = dma.engine.generation_step(s_dma)
+            np.testing.assert_array_equal(
+                np.asarray(m_ref["fitness"]), np.asarray(m_dma["fitness"]),
+                err_msg=f"gen {gen}")
+            np.testing.assert_allclose(
+                np.asarray(s_ref.params_flat), np.asarray(s_dma.params_flat),
+                rtol=1e-5, atol=1e-6, err_msg=f"gen {gen}")
 
 
 def test_noise_kernel_rejects_dims_past_vmem_budget():

@@ -9,6 +9,7 @@ import re
 import sys
 
 import jax
+import jax.numpy as jnp
 import optax
 import pytest
 
@@ -32,6 +33,7 @@ PHASES = ("dispatch", "device", "host_sync", "record")
 # every form runs every stage; what differs is where the stage's work is
 FORMS = {
     "standard": {},
+    "noise_dma": {"noise_kernel": True},  # the row kernels, interpreted
     "decomposed": {"decomposed": True},
     "low_rank": {"low_rank": 1},
     "streamed": {"streamed": True},
@@ -126,6 +128,94 @@ def test_sequence_model_names_its_layers_inside_the_policy_stage(
     assert any(st[-1] == HEAD for st in matmuls)
     assert any(st[-1] == PERTURB and DENSE in st
                for name in names for st in [SCOPE.findall(name)] if st)
+
+
+# ---- compiled for a described TPU v5e: Mosaic runs, nothing executes ----
+# (the ONE file of the suite that loads the TPU's compiler: a second file
+# could land on another xdist worker, which could not load it as well)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described v5e 2x2: a device to compile for, with no
+    chip attached.  The persistent compile cache is off meanwhile (an entry
+    written for a TPU cannot be read back here and warns at every read)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2",
+            chips_per_host_bounds=(2, 2, 1), num_slices=1)
+    except Exception as e:  # no libtpu here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def test_dma_form_books_its_kernels_to_noise_and_grad(v5e_chip):
+    """On a TPU mesh the engine resolves ``noise_gather_form`` to "dma" by
+    itself, and the two Mosaic custom calls of the compiled generation
+    program sit under es.noise (the evaluation's gather) and es.grad (the
+    update's weighted sum): the device trace books them to those stages,
+    not to ``unscoped``."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from estorch_tpu.models.decomposed import mlp_decomposed_apply
+    from estorch_tpu.parallel import ESEngine
+    from estorch_tpu.parallel.mesh import population_mesh
+
+    es = _es(compute_dtype="bfloat16", table_size=1 << 16)  # the pieces
+    assert es.engine.noise_gather_form == "slice"  # a CPU mesh
+    mesh = population_mesh([v5e_chip])
+    engine = ESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, mesh,
+        decomposed_apply=lambda shared, noise, c, obs: mlp_decomposed_apply(
+            es.module, shared, noise, c, obs))
+    assert engine.forward_form == "pair_shared"
+    assert engine.noise_gather_form == "dma"
+    replicated = NamedSharding(mesh, PartitionSpec())
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=replicated),
+        es.state)
+    text = engine._generation_step.lower(state).compile().as_text()
+    kernels = [SCOPE.findall(name) for line in text.splitlines()
+               if "tpu_custom_call" in line
+               for name in re.findall(r'op_name="([^"]*)"', line)]
+    assert sorted(stack[-1] for stack in kernels) == [GRAD, NOISE], kernels
+
+
+@pytest.mark.parametrize("dim,rows", [(75018, 5120), (166673, 2048)],
+                         ids=["humanoid2d", "synth376"])
+@pytest.mark.parametrize("kernel", ["gather_bf16", "gather_f32", "sum"])
+def test_row_kernels_compile_for_the_v5e_at_the_cells_widths(
+        kernel, dim, rows, v5e_chip):
+    """Mosaic accepts both row kernels at the two one-chip cells' widths
+    (aligned DMA windows, VMEM within the scoped limit): what interpret
+    mode cannot show, at no chip time."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_noise import (gather_noise_rows,
+                                              weighted_noise_sum)
+
+    one = SingleDeviceSharding(v5e_chip)
+    table = jax.ShapeDtypeStruct((1 << 25,), jnp.float32, sharding=one)
+    offs = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one)
+    if kernel == "sum":
+        w = jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=one)
+        compiled = jax.jit(lambda t, o, w: weighted_noise_sum(
+            t, o, w, dim=dim, interpret=False)).lower(table, offs, w).compile()
+    else:
+        dtype = jnp.bfloat16 if kernel == "gather_bf16" else jnp.float32
+        compiled = jax.jit(lambda t, o: gather_noise_rows(
+            t, o, dim=dim, dtype=dtype, interpret=False)).lower(
+                table, offs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("use", ["context", "decorator"])
