@@ -43,8 +43,7 @@ only process on the chip while it lives):
                                         ask for the CPU mesh — harness
                                         validation only)
     bench.py --stage-ab                 run the curated A/B subset (see
-                                        AB_MATRIX; not a full cross — e.g.
-                                        streamed is f32-only by design),
+                                        AB_MATRIX; not a full cross),
                                         one JSON line per config as it lands
     bench.py --obs-ab                   telemetry-overhead A/B: spans on vs
                                         off on the headline config (the <2%
@@ -344,9 +343,6 @@ def measure_one(cfg, force_cpu=False):
         optimizer_kwargs={"learning_rate": 1e-2},
         eval_chunk=cfg.get("eval_chunk", 0),
         compute_dtype=dtype,
-        decomposed=cfg.get("decomposed", False),
-        noise_kernel=cfg.get("noise_kernel", False),
-        streamed=cfg.get("streamed", False),
         low_rank=cfg.get("low_rank", 0),
         obs_norm=cfg.get("obs_norm", False),
         # default None: spans on, heartbeat picked up from the env var the
@@ -570,20 +566,9 @@ AB_MATRIX = [
     # (label, base-config, overrides)
     ("small/standard/f32", SMALL, {"dtype": "float32"}),
     ("small/standard/bf16", SMALL, {"dtype": "bfloat16"}),
-    ("small/decomposed/f32", SMALL, {"dtype": "float32", "decomposed": True}),
-    ("small/decomposed/bf16", SMALL, {"dtype": "bfloat16", "decomposed": True}),
-    ("small/decomposed/bf16+nk", SMALL,
-     {"dtype": "bfloat16", "decomposed": True, "noise_kernel": True}),
-    ("small/streamed/f32", SMALL, {"dtype": "float32", "streamed": True}),
-    ("small/streamed/f32+nk", SMALL,
-     {"dtype": "float32", "streamed": True, "noise_kernel": True}),
     ("big/standard/bf16", BIG, {"dtype": "bfloat16"}),
-    ("big/decomposed/bf16", BIG, {"dtype": "bfloat16", "decomposed": True}),
-    ("big/streamed/f32", BIG, {"dtype": "float32", "streamed": True}),
     ("big/lowrank1/bf16", BIG, {"dtype": "bfloat16", "low_rank": 1}),
     ("big/lowrank4/bf16", BIG, {"dtype": "bfloat16", "low_rank": 4}),
-    ("pop10k/decomposed/bf16", POP10K,
-     {"dtype": "bfloat16", "decomposed": True, "gens": 3}),
     ("pop10k/lowrank1/bf16", POP10K,
      {"dtype": "bfloat16", "low_rank": 1, "gens": 3}),
     ("loco/standard/bf16", LOCO, {"dtype": "bfloat16", "gens": 3}),
@@ -2931,7 +2916,7 @@ usage: bench.py [MODE]
 no arguments        full headline benchmark (needs a TPU: exits non-zero
                     with one line when the probe finds none or a stage
                     fails; prints exactly one JSON line)
-  --stage-ab        standard-vs-decomposed forward A/B
+  --stage-ab        the curated forward A/B (AB_MATRIX)
   --obs-ab          telemetry-overhead A/B
   --chaos [--selfcheck]   recovery-overhead A/B under injected faults
                     (clean vs kills vs a mixed straggler+kill plan on

@@ -12,7 +12,7 @@ width of a shipped model, with weights made from a seed:
   spans every device jax reports, env steps per generation inside what the
   config implies, finite fitness, finite parameters that moved, ZERO XLA
   programs built after generation 0, non-zero peak HBM on every mesh device.
-  Then the Pallas kernels (row gather, weighted sum, streamed forward) are
+  Then the Pallas kernels (row gather, weighted sum) are
   lowered through Mosaic at this policy's shapes and compared with their
   ``jnp`` references; on a host with more
   than one chip, the multi-chip checks run in the same process (all-gather
@@ -81,7 +81,7 @@ def _np(x):
 def _kernel_check(es) -> None:
     """The Pallas kernels through Mosaic (interpret=False) at this
     policy's shapes, against their jnp references: the row gather bit for
-    bit, the other two at the tolerances of
+    bit, the weighted sum at the tolerances of
     tests/test_pallas_noise.py — except the reduction's absolute floor,
     which is the f32 forward-error bound of an n-term sum (n·eps·max|w|·
     max|ε|): over 75,018 outputs some sums land near zero, where the
@@ -93,17 +93,12 @@ def _kernel_check(es) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from estorch_tpu.models.decomposed import mlp_decomposed_apply
     from estorch_tpu.ops import rank_weighted_noise_sum
-    from estorch_tpu.ops.pallas_noise import (flat_layer_offsets,
-                                              gather_noise_rows,
-                                              mlp_streamed_apply,
+    from estorch_tpu.ops.pallas_noise import (gather_noise_rows,
                                               weighted_noise_sum)
 
-    spec, table, module = es._spec, es.table, es.module
+    spec, table = es._spec, es.table
     dim = int(spec.dim)
-    params = spec.unravel(_np(es.state.params_flat))
-    shapes = [tuple(v["kernel"].shape) for _, v in sorted(params.items())]
     key = jax.random.key(20)
     n = 64
     last = table.size - dim
@@ -131,20 +126,6 @@ def _kernel_check(es) -> None:
         print(f"kernel weighted_noise_sum: dim {dim}, {n} rows: compiled by "
               f"Mosaic, matches rank_weighted_noise_sum (max abs err "
               f"{float(jnp.abs(got - want).max()):.2e})", flush=True)
-
-        c = 0.08 * jnp.where(jnp.arange(n) % 2 == 0, 1.0, -1.0)
-        obs = jax.random.normal(jax.random.fold_in(key, 2),
-                                (n, int(es.env.obs_dim)))
-        got = mlp_streamed_apply(module, params, table.data, offs, c, obs,
-                                 flat_layer_offsets(params), interpret=False)
-        want = jax.vmap(
-            lambda o, ci, ob: mlp_decomposed_apply(
-                module, params, spec.unravel(table.slice(o, dim)), ci, ob)
-        )(offs, c, obs)
-        np.testing.assert_allclose(_np(got), _np(want), rtol=1e-4, atol=1e-5)
-        print(f"kernel mlp_streamed_apply: layers {shapes}, {n} members: "
-              f"compiled by Mosaic, matches mlp_decomposed_apply (max abs "
-              f"err {float(jnp.abs(got - want).max()):.2e})", flush=True)
     print(f"kernels: {time.perf_counter() - t0:.1f} s", flush=True)
 
 

@@ -106,9 +106,6 @@ class ES:
         mirrored: bool = True,
         episodes_per_member: int = 1,
         worker_mode: str = "thread",
-        decomposed: bool = False,
-        noise_kernel: bool = False,
-        streamed: bool = False,
         low_rank: int = 0,
         obs_norm: bool = False,
         obs_clip: float = 5.0,
@@ -140,9 +137,6 @@ class ES:
         self._sigma_min = float(sigma_min)
         self._mirrored = bool(mirrored)
         self._episodes_per_member = int(episodes_per_member)
-        self._decomposed = bool(decomposed)
-        self._noise_kernel = bool(noise_kernel)
-        self._streamed = bool(streamed)
         self._low_rank = int(low_rank)
         self._obs_norm = bool(obs_norm)
         self._obs_clip = float(obs_clip)
@@ -214,19 +208,6 @@ class ES:
                 raise ValueError(
                     "episodes_per_member is a device-path option; host agents "
                     "control their own rollout count inside rollout()"
-                )
-            if decomposed:
-                raise ValueError(
-                    "decomposed is a device-path option (models/decomposed.py)"
-                )
-            if noise_kernel:
-                raise ValueError(
-                    "noise_kernel is a device/pooled-path option "
-                    "(ops/pallas_noise.py streams from the device table)"
-                )
-            if streamed:
-                raise ValueError(
-                    "streamed is a device-path option (ops/pallas_noise.py)"
                 )
             if low_rank:
                 raise ValueError(
@@ -357,40 +338,14 @@ class ES:
         from ..models.decomposed import mlp_decomposed_apply, supports_decomposed
 
         dec_apply = None
-        has_decomposed_form = supports_decomposed(self.module)
-        if self._decomposed and not has_decomposed_form:
-            raise ValueError(
-                "decomposed=True currently supports MLPPolicy without VBN "
-                "(models/decomposed.py); got "
-                f"{type(self.module).__name__}"
-            )
-        if has_decomposed_form:
-            # built whenever the module has a decomposed form, not only
-            # under decomposed=True: the engine takes the pair-shared
-            # forward for mirrored runs by itself (ESEngine.forward_form)
+        if supports_decomposed(self.module):
+            # how the engine learns the module has the x@W + c·(x@ε) form:
+            # it takes the pair-shared forward for mirrored runs by itself
+            # (ESEngine.forward_form)
             module = self.module
 
             def dec_apply(shared, noise, c, obs):
                 return mlp_decomposed_apply(module, shared, noise, c, obs)
-
-        str_apply = None
-        if self._streamed:
-            from ..ops.pallas_noise import flat_layer_offsets, mlp_streamed_apply
-
-            if not has_decomposed_form:
-                raise ValueError(
-                    "streamed=True currently supports MLPPolicy without VBN "
-                    f"(ops/pallas_noise.py); got {type(self.module).__name__}"
-                )
-            layer_offs = flat_layer_offsets(self._spec.unravel(flat))
-            module = self.module
-            table_data = self.table.data
-
-            def str_apply(shared, offs, c, obs, interpret):
-                return mlp_streamed_apply(
-                    module, shared, table_data, offs, c, obs, layer_offs,
-                    interpret=interpret,
-                )
 
         lr_apply, lr_spec = None, None
         if self._low_rank:
@@ -419,7 +374,6 @@ class ES:
             self.env, self._policy_apply, self._spec, self.table,
             self.optimizer, self.config, self.mesh,
             decomposed_apply=dec_apply,
-            streamed_apply=str_apply,
             lowrank_apply=lr_apply,
             lowrank_spec=lr_spec,
             carry_init=self.module.carry_init if self._recurrent else None,
@@ -514,9 +468,6 @@ class ES:
             sigma_min=self._sigma_min,
             mirrored=self._mirrored,
             episodes_per_member=self._episodes_per_member,
-            decomposed=self._decomposed,
-            noise_kernel=self._noise_kernel,
-            streamed=self._streamed,
             low_rank=self._low_rank,
             obs_norm=self._obs_norm,
             obs_clip=self._obs_clip,
@@ -1163,7 +1114,6 @@ class ES:
             "mirrored": self._mirrored,
             "obs_norm": self._obs_norm,
             "low_rank": self._low_rank,
-            "decomposed": self._decomposed,
             # which forward the engine resolved at build, and how many
             # noise-table rows it gathers per generation (None: an engine
             # that does not evaluate on the device path)
@@ -1174,7 +1124,6 @@ class ES:
             # an engine with no replicated-table gather of its own)
             "noise_gather_form": getattr(
                 self.engine, "noise_gather_form", None),
-            "streamed": self._streamed,
             "shard_params": self._shard_params,
         }
         if self._scenarios is not None:

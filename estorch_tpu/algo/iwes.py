@@ -127,11 +127,6 @@ class IW_ES(ES):
                 "generally has no rank-r preimage, so the factor-space "
                 "importance ratio is ill-posed (ROADMAP item 7)"
             )
-        if self._streamed or self._noise_kernel:
-            raise ValueError(
-                "IW_ES supports the standard/decomposed forwards; "
-                "streamed/noise_kernel are untested with reuse"
-            )
         if self._obs_norm:
             raise ValueError(
                 "IW_ES does not support obs_norm: buffered generations' "

@@ -1202,12 +1202,6 @@ class ElasticScheduler(GenerationScheduler):
                 "host's fitness was measured under OLDER running stats, "
                 "so the density ratio's fixed-f(θ) assumption silently "
                 "breaks (same refusal as IW_ES)")
-        if getattr(es, "_streamed", False) or getattr(es, "_noise_kernel",
-                                                      False):
-            raise ValueError(
-                "elastic folding supports the standard/decomposed "
-                "forwards; streamed/noise_kernel are untested with the "
-                "reuse-update program")
 
     def _sigma_of(self, st) -> float:
         return float(np.asarray(st.sigma))

@@ -264,9 +264,10 @@ def make_batched_rollout(
 
     ``rollout(batched_apply, keys)``: ``batched_apply(obs_batch (n, obs_dim))
     -> (n, act)`` closes over whatever per-member parameterization the
-    caller uses — this is the entry point for the Pallas streamed forward
-    (ops/pallas_noise.py::mlp_streamed_apply), whose population kernel
-    cannot live under a member vmap.  Env dynamics are vmapped; masking
+    caller uses — the entry point of the pair-shared forward
+    (parallel/engine.py ``_eval_local_pairs``), which vmaps the policy over
+    antithetic pairs while the env steps a flat member axis.  Env dynamics
+    are vmapped; masking
     semantics are identical to :func:`make_rollout`.
     """
     discrete = bool(env.discrete)

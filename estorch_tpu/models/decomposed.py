@@ -9,20 +9,15 @@ materialized path builds W + c_i E_i per member, so every layer is a batched
 per-member matvec that streams one weight set per member from HBM at every
 step.  Decomposed, the W-term of every layer is a SINGLE dense
 (population, d) @ (d, h) matmul (W enters vmap un-batched), a shape the MXU
-eats whole.  What the noise term reads depends on who vmaps this function
-(parallel/engine.py ``_eval_local``):
-
-- **pair-shared** (mirrored runs, the engine's choice whenever this forward
-  applies): members 2k and 2k+1 are θ ± σ·ε_k, so the engine vmaps over
-  PAIRS with ε_k un-batched across the pair's two members — x₊@ε_k and
-  x₋@ε_k are one [2,d]×[d,h] product and ε is read once per pair per step:
-  half the weight bytes of the materialized path.
-- **per-member** (``decomposed=True`` on unmirrored runs): one ε tree per
-  member, the same bytes per step as materialized weights; only the W-term
-  gains.
-
-On TPU a Pallas kernel can instead stream E_i from the HBM table tile by
-tile (``streamed``, ops/pallas_noise.py); this module is the pure-JAX form.
+eats whole.  The noise term pays off when an antithetic pair shares it
+(parallel/engine.py ``_eval_local_pairs``, the engine's choice for every
+mirrored run this forward applies to): members 2k and 2k+1 are θ ± σ·ε_k,
+so the engine vmaps over PAIRS with ε_k un-batched across the pair's two
+members — x₊@ε_k and x₋@ε_k are one [2,d]×[d,h] product and ε is read once
+per pair per step: half the weight bytes of the materialized path.  Vmapped
+per member instead it would read one ε tree per member, the same bytes per
+step as materialized weights, so unmirrored runs take the materialised
+forward.
 
 Scope: MLPPolicy-shaped networks (Dense stacks, tanh/… activations,
 optional continuous squash).  VBN layers are not yet supported here — the

@@ -127,8 +127,8 @@ def member_offsets(pair_offsets: jax.Array) -> jax.Array:
 # threefry is counter-based, so the values are identical on every mesh
 # shape and no ε buffer ever exists host-side or whole on one device —
 # under GSPMD each device computes exactly its shard of each normal()
-# (the same no-materialization idea as the ops/pallas_noise.py streamed
-# kernels, moved from DMA engines into the RNG).  These three helpers
+# (the same no-materialization idea as ops/pallas_noise.py's weighted sum,
+# moved from DMA engines into the RNG).  These three helpers
 # define THE keying contract in one place so the eval-side perturbation
 # and the update-side reduction can never diverge.
 
@@ -189,8 +189,8 @@ def member_noise(table: NoiseTable, offsets: jax.Array, signs: jax.Array, dim: i
     The engine's own evaluation gathers its rows inside ``_eval_local``
     (parallel/engine.py) and DOES hold a whole chunk's noise at once —
     with ``eval_chunk=0`` the whole local shard's: one row per member, or
-    one per antithetic pair in the pair-shared form; only ``streamed`` and
-    ``low_rank`` avoid a ``(rows, dim)`` slab.
+    one per antithetic pair in the pair-shared form; only ``low_rank``
+    avoids a ``(rows, dim)`` slab.
     """
     rows = jax.vmap(lambda o: table.slice(o, dim))(offsets)
     return rows * signs[:, None]

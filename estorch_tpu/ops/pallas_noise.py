@@ -20,7 +20,7 @@ the 2-D f32 view, i.e. 1024-float boundaries.  So every kernel here
    against the next row, one dynamic sublane rotate — after which flat
    element k of the result is flat element k of the wanted span.
 
-Three kernels share that front end.  Two move WHOLE rows, on one DMA
+Two kernels share that front end.  Both move WHOLE rows, on one DMA
 schedule (:func:`_this_step_row`: a few window buffers, the next rows' DMAs
 in flight under this row's realignment), and are what a generation of the
 replicated engine runs on a TPU mesh (``ESEngine.noise_gather_form ==
@@ -33,18 +33,6 @@ replicated engine runs on a TPU mesh (``ESEngine.noise_gather_form ==
 - :func:`weighted_noise_sum` — the update's pass, Σ_k w_k·ε_k: each row is
   FMA'd (f32, VPU) into a VMEM accumulator that is only written back at
   the end; no ``(chunk, dim)`` block is ever materialized.
-
-The third never materializes a member's ε at all:
-
-- :func:`population_noise_matvec` — the per-member noise term of the
-  decomposed forward, y_i = c_i·(x_i @ E_i), with E_i = the member's table
-  slice viewed as a (d, h) matrix.  Grid over (members × row-blocks); the
-  realigned block W is a (rows, 128) FLAT view of E_i, contracted on the
-  MXU as ``A_i @ W`` with a small per-member coefficient matrix A_i built
-  from x_i outside the kernel.  That contraction can only express E's
-  columns when they line up with lanes: h a multiple of 128, or a divisor
-  of it.  Any other width (a 10-unit head) takes the gathered einsum — a
-  choice made from the static shape alone, identical on every platform.
 
 ``interpret`` is a required argument: the engine derives it from the
 platform of the mesh it runs on (never true on a TPU mesh), tests pass
@@ -70,8 +58,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..obs.trace import NOISE, stage
-
 LANES = 128
 SUBLANES = 8
 TILE = LANES * SUBLANES  # floats per (8, 128) f32 tile: the DMA alignment
@@ -95,7 +81,7 @@ def _table_rows(table_data: jax.Array, window_rows: int) -> int:
     size = int(table_data.shape[0])
     if size % TILE != 0:
         raise ValueError(
-            f"the streamed-noise kernels view the table as (size/128, 128) "
+            f"the row kernels view the table as (size/128, 128) "
             f"f32 tiles; table size {size} is not a multiple of {TILE}")
     rows = size // LANES
     if rows < window_rows:
@@ -311,248 +297,3 @@ def weighted_noise_sum(
     )
     return out.reshape(-1)[:dim]
 
-
-# --------------------------------------------------------------------------
-# decomposed-forward noise term: y_i = c_i · (x_i @ E_i)
-# --------------------------------------------------------------------------
-
-
-def lanes_regular(h: int) -> bool:
-    """True when a (d, h) matrix stored flat lines its columns up with the
-    128 lanes of a (rows, 128) view — the widths the streamed matvec
-    kernel can contract (see the module docstring)."""
-    return h % LANES == 0 or LANES % h == 0
-
-
-def _pick_row_block(d: int, h: int, budget_floats: int = 64 * 1024) -> int:
-    """Largest divisor of d whose B·h-float DMA fits the per-buffer budget
-    (256 KiB: two buffers plus the realignment temporaries stay far inside
-    scoped VMEM whatever the layer's size)."""
-    best = 1
-    for b in range(1, d + 1):
-        if d % b == 0 and b * h <= budget_floats:
-            best = b
-    return best
-
-
-def _noise_matvec_kernel(t_rows: int, window_rows: int, k_rows: int,
-                         block_floats: int, layer_offset: int):
-    def kernel(offs_ref, a_ref, table_ref, y_ref, buf, sem):
-        i = pl.program_id(0)  # member
-        k = pl.program_id(1)  # row block (inner axis)
-        n_i = pl.num_programs(0)
-        n_k = pl.num_programs(1)
-
-        def window(member, blk):
-            return _window(
-                offs_ref[member] + layer_offset + blk * block_floats,
-                t_rows, window_rows)
-
-        def dma(slot, member, blk):
-            first, _ = window(member, blk)
-            return pltpu.make_async_copy(
-                table_ref.at[pl.ds(first, window_rows), :],
-                buf.at[slot],
-                sem.at[slot],
-            )
-
-        step = i * n_k + k
-
-        @pl.when(step == 0)
-        def _warmup():
-            dma(0, 0, 0).start()
-
-        # prefetch the NEXT grid step's block (possibly the next member's
-        # first block) while this one is consumed
-        nxt = step + 1
-
-        @pl.when(nxt < n_i * n_k)
-        def _prefetch():
-            dma(jax.lax.rem(nxt, 2), nxt // n_k, jax.lax.rem(nxt, n_k)).start()
-
-        @pl.when(k == 0)
-        def _init():
-            y_ref[...] = jnp.zeros_like(y_ref)
-
-        slot = jax.lax.rem(step, 2)
-        dma(slot, i, k).wait()
-        _, shift = window(i, k)
-        w = _shifted(buf[slot], shift, k_rows)  # flat view of the E block
-        y_ref[0] += jnp.dot(a_ref[0, 0], w,
-                            preferred_element_type=jnp.float32,
-                            precision=jax.lax.Precision.HIGHEST)
-
-    return kernel
-
-
-def _matvec_coefficients(x: jax.Array, d: int, h: int, block_rows: int,
-                         m_rows: int, k_rows: int) -> jax.Array:
-    """(n, n_blocks, m_rows, k_rows) coefficient matrices A with
-    ``A[i, b] @ W == `` the lane-layout of ``x[i, block b] @ E_block`` for
-    W the (k_rows, 128) flat view of that E block.
-
-    h = g·128: row q of W holds columns [128·(q%g), …) of E row q//g, so
-    A[m, q] = x[q//g]·[q%g == m] and output row m is y[128m : 128(m+1)].
-    128 = g·h: row q of W holds E rows q·g … q·g+g-1 side by side, so
-    A[m, q] = x[q·g + m] and y[j] = Σ_m out[m, m·h + j].
-    Rows/columns past the block are zero, so whatever the window holds
-    beyond the block (the next leaf's noise) contributes nothing."""
-    n = x.shape[0]
-    n_blocks = d // block_rows
-    xb = x.reshape(n, n_blocks, block_rows)
-    if h % LANES == 0:
-        g = h // LANES
-        a = jnp.einsum("nbr,mk->nbmrk", xb, jnp.eye(g, dtype=x.dtype))
-        a = a.reshape(n, n_blocks, g, block_rows * g)
-    else:
-        g = LANES // h
-        q = _cdiv(block_rows, g)
-        xb = jnp.pad(xb, ((0, 0), (0, 0), (0, q * g - block_rows)))
-        a = xb.reshape(n, n_blocks, q, g).transpose(0, 1, 3, 2)
-    return jnp.pad(a, ((0, 0), (0, 0), (0, m_rows - a.shape[2]),
-                       (0, k_rows - a.shape[3])))
-
-
-@partial(
-    jax.jit,
-    static_argnames=("d", "h", "layer_offset", "interpret", "block_rows"),
-)
-def population_noise_matvec(
-    table_data: jax.Array,  # (table_size,) float32
-    offsets: jax.Array,  # (n,) int32 — each member's flat-ε start offset
-    c: jax.Array,  # (n,) float32 — σ·sign per member
-    x: jax.Array,  # (n, d) float32 — the layer's input batch
-    layer_offset: int,  # this layer's kernel start WITHIN the member ε vector
-    d: int,
-    h: int,
-    interpret: bool,
-    block_rows: int | None = None,
-) -> jax.Array:
-    """y[i] = c[i] · (x[i] @ E_i) with E_i streamed from the table.
-
-    ``E_i = table[offsets[i]+layer_offset : …+d·h]`` viewed row-major as
-    (d, h) — exactly the layout ops/params.py's unravel gives a Dense
-    kernel, so this reproduces models/decomposed.py's noise term without
-    materializing any member's noise tree.  Widths that are not
-    :func:`lanes_regular` gather their (small) E_i instead.
-    """
-    n = int(x.shape[0])
-    dtype = table_data.dtype
-    offsets = offsets.astype(jnp.int32)
-    if not lanes_regular(h):
-        e = jax.vmap(lambda o: jax.lax.dynamic_slice(
-            table_data, (o + layer_offset,), (d * h,)))(offsets)
-        return c[:, None].astype(dtype) * jnp.einsum(
-            "nd,ndh->nh", x.astype(dtype), e.reshape(n, d, h),
-            precision=jax.lax.Precision.HIGHEST)
-    if block_rows is None:
-        block_rows = _pick_row_block(d, h)
-    if d % block_rows != 0:
-        raise ValueError(f"block_rows {block_rows} must divide d {d}")
-    n_blocks = d // block_rows
-    block_floats = block_rows * h
-    g = h // LANES if h % LANES == 0 else LANES // h
-    m_rows = _round_up(g, SUBLANES)
-    k_rows = _round_up(_cdiv(block_floats, LANES), SUBLANES)
-    window_rows = k_rows + SUBLANES
-    t_rows = _table_rows(table_data, window_rows)
-    a = _matvec_coefficients(x.astype(dtype), d, h, block_rows, m_rows, k_rows)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # offsets
-        grid=(n, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, m_rows, k_rows),
-                         lambda i, k, *_: (i, k, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # table stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, m_rows, LANES), lambda i, k, *_: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, window_rows, LANES), dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    out = pl.pallas_call(
-        _noise_matvec_kernel(t_rows, window_rows, k_rows, block_floats,
-                             layer_offset),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, m_rows, LANES), dtype),
-        interpret=interpret,
-    )(offsets, a, table_data.reshape(t_rows, LANES))
-    if h % LANES == 0:
-        y = out[:, :g, :].reshape(n, h)
-    else:
-        y = jnp.einsum("nmmj->nj", out[:, :g, :].reshape(n, g, g, h))
-    return c[:, None].astype(dtype) * y
-
-
-# --------------------------------------------------------------------------
-# full streamed MLP forward (population-batched)
-# --------------------------------------------------------------------------
-
-
-def flat_layer_offsets(params) -> dict[str, dict[str, int]]:
-    """Each leaf's start offset within the ravel_pytree flat vector.
-
-    ravel_pytree flattens in tree order (sorted dict keys), each leaf
-    row-major — the layout every table slice is unraveled with, so these
-    offsets address a member's ε exactly like spec.unravel does.
-    """
-    flat, _ = jax.tree_util.tree_flatten_with_path(params)
-    offsets: dict[str, dict[str, int]] = {}
-    pos = 0
-    for path, leaf in flat:
-        layer = path[0].key
-        name = path[1].key
-        offsets.setdefault(layer, {})[name] = pos
-        pos += int(leaf.size)
-    return offsets
-
-
-def mlp_streamed_apply(
-    module,
-    shared_params,
-    table_data: jax.Array,
-    offsets: jax.Array,  # (n,) member ε start offsets
-    c: jax.Array,  # (n,) σ·sign
-    obs: jax.Array,  # (n, obs_dim) population observation batch
-    layer_offsets: dict[str, dict[str, int]],
-    interpret: bool,
-) -> jax.Array:
-    """Population-batched MLPPolicy forward, weights (shared + c·ε) with ε
-    streamed from the table.
-
-    The shared-W term of every layer is one dense (n, d) @ (d, h) matmul
-    (MXU); the noise term streams through :func:`population_noise_matvec`;
-    bias noise is a tiny (n, h) gather.  Bit-for-bit this reorders the same
-    contractions as models/decomposed.py::mlp_decomposed_apply, which the
-    tests pin to float tolerance.
-    """
-    from ..models.decomposed import _ordered_dense_names
-
-    names = _ordered_dense_names(shared_params)
-    x = obs
-    for name in names:
-        w = shared_params[name]["kernel"]
-        b = shared_params[name]["bias"]
-        d, h = int(w.shape[0]), int(w.shape[1])
-        # the forward reads eps itself: the streaming kernel and the bias
-        # gather are this form's noise stage, inside the policy's
-        with stage(NOISE):
-            noise_term = population_noise_matvec(
-                table_data, offsets, c, x,
-                layer_offset=layer_offsets[name]["kernel"],
-                d=d, h=h, interpret=interpret,
-            )
-            # bias noise: h floats per member — a tiny gather, not worth a
-            # DMA
-            bias_off = layer_offsets[name]["bias"]
-            nb = jax.vmap(
-                lambda o: jax.lax.dynamic_slice(
-                    table_data, (o + bias_off,), (h,))
-            )(offsets)
-        x = x @ w + noise_term + b + c[:, None] * nb
-        if name != "head":
-            x = module.activation(x)
-    if not module.discrete:
-        x = jnp.tanh(x) * module.action_scale
-    return x

@@ -69,39 +69,18 @@ class EngineConfig:
     episodes_per_member: int = 1  # rollouts averaged per member (device
     # path only): reduces fitness noise AND raises per-step batch (n·e rows
     # through the policy matmuls — better MXU use for small populations)
-    decomposed: bool = False  # z = x@W + c(x@E) for UNMIRRORED runs: the
-    # shared-W term of every layer becomes one population-wide dense matmul
-    # (W un-batched under vmap); the noise term stays a per-member matvec
-    # against a per-member ε tree, so it reads the same bytes per step as
-    # materialized weights. Mirrored runs need no flag: the engine takes
-    # the pair-shared form of the same identity whenever it is given a
-    # decomposed_apply (ESEngine.forward_form; models/decomposed.py)
-    noise_kernel: bool = False  # True forces the DMA form of the table's
-    # row gather (ops/pallas_noise.py: rows leave the HBM table as aligned
-    # windows and are realigned in VMEM; the update FMAs them in place, no
-    # (chunk, dim) materialization) — Mosaic on a TPU mesh, the Pallas
-    # interpreter on any other (decided from the mesh's devices, never
-    # from the default backend).  False: the engine decides
-    # (ESEngine.noise_gather_form: "dma" on a TPU mesh, "slice" elsewhere)
     low_rank: int = 0  # >0: per-layer kernel noise E = A·Bᵀ/√r with r =
     # low_rank (ops/lowrank.py, PAPERS.md "ES at the Hyperscale"): member
     # noise state shrinks O(dim) → O(Σ(m+n)·r), the forward's noise term
     # O(m·n) → O((m+n)·r) per step, and the update is one einsum per layer
-    # over the population.  Approximates isotropic ES (exact for biases);
-    # mutually exclusive with decomposed/streamed/noise_kernel.
-    streamed: bool = False  # Pallas streamed FORWARD: the decomposed
-    # population forward with every layer's ε tiles DMA'd from the table —
-    # no member's noise tree is ever materialized, so resident noise bytes
-    # drop from O(population·dim) to O(2·tile). Implies a population-
-    # batched rollout (one policy call per step for the whole local shard).
-    # Needs a streamed_apply (ES builds it for MLPPolicy); f32 only.
+    # over the population.  Approximates isotropic ES (exact for biases).
     obs_norm: bool = False  # running observation normalization (the
     # OpenAI-ES MuJoCo staple the reference never had): every policy input
     # is (obs - mean)·rsqrt(var) clipped to ±obs_clip, with the running
     # raw-obs moments carried in ESState.obs_stats and refreshed each
     # generation from obs_probe_episodes center-policy episodes — fully
     # in-program, replicated on every device. Composes with every noise
-    # representation (standard/recurrent/decomposed/streamed/low_rank):
+    # representation (materialised/recurrent/pair_shared/low_rank):
     # normalization is an input-side transform, applied to raw obs in f32
     # before any forward. NOTE the stats-refresh data source differs by
     # backend: the device path feeds obs_stats from center-policy probe
@@ -303,7 +282,9 @@ def _choose_eval_chunk(requested: int, local_members: int) -> int:
 
 
 NOISE_KERNEL_MAX_DIM = 1_000_000  # the row kernels hold a few windows of
-# dim f32 each in VMEM (ops/pallas_noise.py); both compile for the v5e here
+# dim f32 each in VMEM (weighted_noise_sum: 3·dim, double buffer and
+# accumulator; ops/pallas_noise.py); both compile for the v5e here, and past
+# it the chunked pure-JAX forms ("slice") handle any dim
 
 
 class ESEngine:
@@ -325,23 +306,13 @@ class ESEngine:
         optimizer: optax.GradientTransformation,
         config: EngineConfig,
         mesh: Mesh,
-        decomposed_apply=None,
-        streamed_apply=None,
+        decomposed_apply=None,  # (centre, ε tree, c, obs) -> out: given
+        # when the module has the x@W + c·(x@ε) form (models/decomposed.py)
         lowrank_apply=None,
         lowrank_spec=None,
         carry_init=None,
     ):
         self.env = env
-        if carry_init is not None and (config.decomposed or config.streamed):
-            # these paths restructure the FORWARD around the MLP layer
-            # identity (models/decomposed.py) and have no recurrent form.
-            # low_rank composes: the tree form (ops/lowrank.py) materializes
-            # each member's perturbation once per episode and runs the
-            # standard carry-threaded rollout
-            raise ValueError(
-                "recurrent policies run the standard forward; they are "
-                "mutually exclusive with decomposed/streamed"
-            )
         if config.obs_norm:
             if env is None:
                 raise ValueError(
@@ -349,11 +320,6 @@ class ESEngine:
                     "running stats in-program; it is a device-path option"
                 )
         if config.low_rank:
-            if config.decomposed or config.streamed or config.noise_kernel:
-                raise ValueError(
-                    "low_rank replaces the full-rank noise pathway; it is "
-                    "mutually exclusive with decomposed/streamed/noise_kernel"
-                )
             if lowrank_spec is None or (
                 lowrank_apply is None and env is not None and carry_init is None
             ):
@@ -370,40 +336,6 @@ class ESEngine:
         self.noise_dim = (
             self.lr_spec.noise_dim if config.low_rank else spec.dim
         )
-        if config.decomposed and decomposed_apply is None and env is not None:
-            raise ValueError(
-                "EngineConfig.decomposed=True needs a decomposed_apply "
-                "(models/decomposed.py::mlp_decomposed_apply for MLPPolicy)"
-            )
-        if config.streamed:
-            if config.decomposed:
-                raise ValueError(
-                    "streamed IS the kernel form of decomposed — enable one"
-                )
-            if config.episodes_per_member != 1:
-                raise ValueError(
-                    "streamed currently supports episodes_per_member=1"
-                )
-            if config.compute_dtype != "float32":
-                raise ValueError(
-                    "streamed runs in float32 (the table and kernel are f32)"
-                )
-            if streamed_apply is None and env is not None:
-                raise ValueError(
-                    "EngineConfig.streamed=True needs a streamed_apply "
-                    "(ops/pallas_noise.py::mlp_streamed_apply for MLPPolicy)"
-                )
-        self._streamed_apply = streamed_apply
-        if config.noise_kernel and spec.dim > NOISE_KERNEL_MAX_DIM:
-            # weighted_noise_sum holds 3·dim f32 in VMEM (double buffer +
-            # accumulator, ops/pallas_noise.py) — past ~1M params that blows
-            # the ~16 MiB v5e VMEM budget as an opaque Mosaic error, so fail
-            # loudly here instead (chunked pure-JAX reduction handles any dim)
-            raise ValueError(
-                f"noise_kernel=True supports up to {NOISE_KERNEL_MAX_DIM:,} "
-                f"params (3·dim f32 must fit VMEM); got dim={spec.dim:,}. "
-                "Drop noise_kernel to use the chunked pure-JAX reduction."
-            )
         if config.compute_dtype not in ("float32", "bfloat16"):
             raise ValueError(
                 f"compute_dtype must be float32 or bfloat16, got {config.compute_dtype!r}"
@@ -466,9 +398,7 @@ class ESEngine:
         self.eval_chunk = _choose_eval_chunk(config.eval_chunk, self.members_local)
         # which forward the generation program runs, resolved once from
         # what the engine can observe (run manifest + telemetry gauges)
-        if config.streamed:
-            self.forward_form = "streamed"
-        elif config.low_rank:
+        if config.low_rank:
             self.forward_form = "low_rank"
         elif (config.mirrored and decomposed_apply is not None
               and carry_init is None and self.eval_chunk % 2 == 0):
@@ -478,8 +408,7 @@ class ESEngine:
             # chunks, hence the even chunk)
             self.forward_form = "pair_shared"
         else:
-            self.forward_form = (
-                "decomposed" if config.decomposed else "materialised")
+            self.forward_form = "materialised"
         # noise-table rows the evaluation gathers per generation
         self.noise_rows_per_generation = (
             config.population_size // 2 if self.forward_form == "pair_shared"
@@ -538,12 +467,6 @@ class ESEngine:
             if config.obs_norm else None
         )
 
-        self._rollout_batched = None
-        if self.forward_form in ("streamed", "pair_shared"):
-            from ..envs.rollout import make_batched_rollout
-
-            self._rollout_batched = make_batched_rollout(env, config.horizon)
-
         self._rollout_lowrank = None
         if config.low_rank and carry_init is None:
             # the MLP per-step factored form; recurrent low_rank reuses
@@ -567,11 +490,14 @@ class ESEngine:
 
             self._rollout_lowrank = make_rollout(env, lr_packed_apply, config.horizon)
 
-        self._rollout_decomposed = None
-        if self.forward_form in ("pair_shared", "decomposed"):
+        if self.forward_form == "pair_shared":
+            from ..envs.rollout import make_batched_rollout
+
+            self._rollout_batched = make_batched_rollout(env, config.horizon)
+
             def decomposed_forward(shared, noise, c, stats, obs):
                 # one raw observation → f32 policy output.  The trees arrive
-                # pre-cast from _eval_local; the scale c stays f32 (a bf16
+                # pre-cast from _eval_local_pairs; the scale c stays f32 (a bf16
                 # σ·sign would be 0.1–0.4% off σ).  Raw obs are normalized in
                 # f32, then cast — the same order as the standard path above
                 if config.obs_norm:
@@ -583,10 +509,6 @@ class ESEngine:
                     jnp.float32)
 
             self._decomposed_forward = decomposed_forward
-            if self.forward_form == "decomposed":
-                self._rollout_decomposed = make_rollout(
-                    env, lambda packed, obs: decomposed_forward(*packed, obs),
-                    config.horizon)
 
         # All inputs/outputs are fully replicated (P()); the population axis
         # only exists INSIDE the program (axis_index-derived shards).
@@ -625,8 +547,6 @@ class ESEngine:
 
     def _resolve_noise_gather_form(self) -> str:
         """``"dma"`` or ``"slice"``: see ``noise_gather_form``."""
-        if self.config.noise_kernel:
-            return "dma"
         if self._pallas_interpret or self.config.low_rank:
             return "slice"
         from ..ops.pallas_noise import rows_fit_dma
@@ -728,94 +648,89 @@ class ESEngine:
             [weights, jnp.zeros((pad,), weights.dtype)])
 
     def _eval_local(self, state: ESState, member_offs, signs, member_keys):
-        """Rollout this device's members in eval_chunk-sized compiled chunks."""
-        cfg = self.config
+        """Rollout this device's members in eval_chunk-sized compiled
+        chunks, through the one body ``forward_form`` names."""
+        body = {
+            "pair_shared": self._eval_local_pairs,
+            "low_rank": self._eval_local_lowrank,
+            "materialised": self._eval_local_materialised,
+        }[self.forward_form]
+        return body(state, member_offs, signs, member_keys)
+
+    def _eval_local_materialised(self, state, member_offs, signs, member_keys):
+        """Per-member evaluation (``forward_form == "materialised"``): each
+        member's θ = centre + σ·sign·ε is built once and rolled out alone
+        under the member vmap.  Unmirrored, recurrent, VBN and conv runs,
+        and mirrored runs whose chunk is odd."""
         dim = self.spec.dim
-        n_chunks = self.members_local // self.eval_chunk
-        if cfg.streamed:
-            return self._eval_local_streamed(
-                state, member_offs, signs, member_keys, n_chunks
-            )
-        if self.forward_form == "pair_shared":
-            return self._eval_local_pairs(
-                state, member_offs, signs, member_keys, n_chunks
-            )
-        if cfg.decomposed or cfg.low_rank:
-            # shared center tree: unraveled (and, for bf16, cast) ONCE,
-            # enters the member vmap as an un-batched constant — its matmuls
-            # fuse across the population.  The f32 original stays around for
-            # the recurrent low_rank branch, which perturbs in f32 and casts
-            # per member (the standard path's theta ordering)
+
+        def member_eval(off, sign, key):
+            with stage(NOISE):
+                eps = self.table.slice(off, dim)
             with stage(PERTURB):
-                center_f32 = self.spec.unravel(state.params_flat)
-                shared_tree = self._member_cast(center_f32)
+                theta = state.params_flat + state.sigma * sign * eps
+                # once-per-member cast (bf16 path): the rollout scan
+                # below runs on dtype-pure params, no per-step casts
+                params = self._member_cast(self.spec.unravel(theta))
+            if self._obs_norm:
+                # every member this generation normalizes with the
+                # SAME stats snapshot (vmap broadcasts the pack)
+                params = (params, state.obs_stats)
+            return self._member_rollout(self._rollout, params, key)
+
+        return self._scan_members(member_eval, member_offs, signs, member_keys)
+
+    def _eval_local_lowrank(self, state, member_offs, signs, member_keys):
+        """Low-rank evaluation (``forward_form == "low_rank"``): a member's
+        table row is the packed (A‖B‖bias) factors of ops/lowrank.py."""
+        # shared center tree: unraveled (and, for bf16, cast) ONCE, enters
+        # the member vmap as an un-batched constant — its matmuls fuse
+        # across the population.  The f32 original stays around for the
+        # recurrent branch, which perturbs in f32 and casts per member (the
+        # materialised body's theta ordering)
+        with stage(PERTURB):
+            center_f32 = self.spec.unravel(state.params_flat)
+            shared_tree = self._member_cast(center_f32)
+
+        def member_eval(off, sign, key):
+            with stage(NOISE):
+                nvec = self.table.slice(off, self.noise_dim)
+            if self._carry_init is not None:
+                # recurrent: dense perturbation materialized ONCE per
+                # episode (ops/lowrank.py tree form) — noise STATE stays
+                # O(noise_dim); the rollout is the standard carry-threaded
+                # scan
+                from ..ops.lowrank import lowrank_tree_perturb
+
+                with stage(PERTURB):
+                    theta_tree = lowrank_tree_perturb(
+                        self.lr_spec, center_f32, nvec, state.sigma * sign)
+                    params = self._member_cast(theta_tree)
+                rollout = self._rollout
+            else:
+                # MLP: the factors stay packed — no dense noise matrix
+                # ever exists on this path
+                rollout = self._rollout_lowrank
+                with stage(PERTURB):
+                    params = (
+                        shared_tree,
+                        self._member_cast(self.lr_spec.unpack(nvec)),
+                        self._member_cast(state.sigma * sign),
+                    )
+            if self._obs_norm:
+                params = (params, state.obs_stats)
+            return self._member_rollout(rollout, params, key)
+
+        return self._scan_members(member_eval, member_offs, signs, member_keys)
+
+    def _scan_members(self, member_eval, member_offs, signs, member_keys):
+        """``member_eval(offset, sign, key)`` vmapped over each chunk."""
 
         def chunk_body(_, xs):
-            offs_c, signs_c, keys_c = xs
-
-            def member_eval(off, sign, key):
-                if cfg.low_rank:
-                    with stage(NOISE):
-                        nvec = self.table.slice(off, self.noise_dim)
-                    if self._carry_init is not None:
-                        # recurrent: dense perturbation materialized ONCE
-                        # per episode (ops/lowrank.py tree form) — noise
-                        # STATE stays O(noise_dim); the rollout is the
-                        # standard carry-threaded scan
-                        from ..ops.lowrank import lowrank_tree_perturb
-
-                        with stage(PERTURB):
-                            theta_tree = lowrank_tree_perturb(
-                                self.lr_spec, center_f32, nvec,
-                                state.sigma * sign,
-                            )
-                            params = self._member_cast(theta_tree)
-                        rollout = self._rollout
-                        if self._obs_norm:
-                            params = (params, state.obs_stats)
-                        return self._member_rollout(rollout, params, key)
-                    # MLP: packed (A||B||bias) factors — dim is the LR
-                    # noise_dim, and no dense noise matrix ever exists on
-                    # this path
-                    rollout = self._rollout_lowrank
-                    with stage(PERTURB):
-                        params = (
-                            shared_tree,
-                            self._member_cast(self.lr_spec.unpack(nvec)),
-                            self._member_cast(state.sigma * sign),
-                        )
-                    if self._obs_norm:
-                        params = (params, state.obs_stats)
-                    return self._member_rollout(rollout, params, key)
-                with stage(NOISE):
-                    eps = self.table.slice(off, dim)
-                if cfg.decomposed:
-                    rollout = self._rollout_decomposed
-                    with stage(PERTURB):
-                        # (centre, ε tree, c, stats): c = σ·sign stays f32
-                        params = (
-                            shared_tree,
-                            self._member_cast(self.spec.unravel(eps)),
-                            state.sigma * sign,
-                            state.obs_stats,
-                        )
-                else:
-                    rollout = self._rollout
-                    with stage(PERTURB):
-                        theta = state.params_flat + state.sigma * sign * eps
-                        # once-per-member cast (bf16 path): the rollout scan
-                        # below runs on dtype-pure params, no per-step casts
-                        params = self._member_cast(self.spec.unravel(theta))
-                    if self._obs_norm:
-                        # every member this generation normalizes with the
-                        # SAME stats snapshot (vmap broadcasts the pack)
-                        params = (params, state.obs_stats)
-                return self._member_rollout(rollout, params, key)
-
-            f, bc, st = jax.vmap(member_eval)(offs_c, signs_c, keys_c)
+            f, bc, st = jax.vmap(member_eval)(*xs)
             return 0, (f, bc, st)
 
-        return self._scan_chunks(chunk_body, member_offs, signs, member_keys, n_chunks)
+        return self._scan_chunks(chunk_body, member_offs, signs, member_keys)
 
     def _member_rollout(self, rollout, params, key):
         """One member's fitness/bc/steps, honoring episodes_per_member."""
@@ -832,11 +747,12 @@ class ESEngine:
         res = rollout(params, key)
         return res.total_reward, res.bc, res.steps
 
-    def _scan_chunks(self, chunk_body, member_offs, signs, member_keys, n_chunks):
+    def _scan_chunks(self, chunk_body, member_offs, signs, member_keys):
         """Dispatch the local shard through ``chunk_body`` in eval_chunk
         pieces (single-chunk: no 1-iteration scan layer) and restore the
-        member-major result shapes.  Shared by the standard/decomposed vmap
-        path and the streamed batched path."""
+        member-major result shapes.  Shared by the per-member vmap bodies
+        and the pair-shared batched body."""
+        n_chunks = self.members_local // self.eval_chunk
         if n_chunks == 1:
             _, (f, bc, st) = chunk_body(0, (member_offs, signs, member_keys))
         else:
@@ -852,7 +768,7 @@ class ESEngine:
             st.reshape(self.members_local),
         )
 
-    def _eval_local_pairs(self, state, member_offs, signs, member_keys, n_chunks):
+    def _eval_local_pairs(self, state, member_offs, signs, member_keys):
         """Pair-shared evaluation (``forward_form == "pair_shared"``): one
         table row and one ε tree per antithetic PAIR.  The decomposed
         forward is vmapped pairs ⊃ signs (⊃ episodes) with ε batched over
@@ -917,35 +833,7 @@ class ESEngine:
                 st = st.reshape(-1, n_ep).sum(axis=1)
             return 0, (f, bc, st)
 
-        return self._scan_chunks(chunk_body, member_offs, signs, member_keys, n_chunks)
-
-    def _eval_local_streamed(self, state, member_offs, signs, member_keys, n_chunks):
-        """Population-batched evaluation with the Pallas streamed forward:
-        one policy call per env step for the whole chunk, every layer's ε
-        DMA'd from the table — no member noise tree is ever materialized."""
-        with stage(PERTURB):
-            shared_tree = self.spec.unravel(state.params_flat)
-
-        def chunk_body(_, xs):
-            offs_c, signs_c, keys_c = xs
-            with stage(PERTURB):
-                c = state.sigma * signs_c
-
-            def batched_apply(obs_batch):
-                if self._obs_norm:
-                    # stats broadcast over the population batch dim; streamed
-                    # is f32-only so no dtype shim is needed
-                    obs_batch = normalize_obs(
-                        obs_batch, state.obs_stats, float(self.config.obs_clip)
-                    )
-                return self._streamed_apply(
-                    shared_tree, offs_c, c, obs_batch,
-                    interpret=self._pallas_interpret)
-
-            res = self._rollout_batched(batched_apply, keys_c)
-            return 0, (res.total_reward, res.bc, res.steps)
-
-        return self._scan_chunks(chunk_body, member_offs, signs, member_keys, n_chunks)
+        return self._scan_chunks(chunk_body, member_offs, signs, member_keys)
 
     @stage(GATHER)
     def _gather_global(self, fitness_local, bc_local, steps_local):
@@ -998,7 +886,7 @@ class ESEngine:
                 cfg.population_size * state.sigma
             )
         elif self.noise_gather_form == "dma":
-            # Pallas streamed reduction: each ε row is DMA'd once and FMA'd
+            # Pallas row reduction: each ε row is DMA'd once and FMA'd
             # (f32, on the VPU) into a VMEM accumulator — no materialized
             # noise blocks
             from ..ops.gradient import fold_mirrored_weights as _fold

@@ -71,16 +71,6 @@ class PooledEngine:
                 "episodes_per_member is a device-path option; the pooled "
                 "path rolls one episode per member env"
             )
-        if config.streamed:
-            raise ValueError(
-                "streamed is a device-path option; the pooled path's policy "
-                "forward runs per env step against materialized thetas"
-            )
-        if config.decomposed:
-            raise ValueError(
-                "decomposed is a device-path option; the pooled path "
-                "materializes per-member thetas for its batched forward"
-            )
         if config.low_rank:
             raise ValueError(
                 "low_rank is a device-path option (ops/lowrank.py); the "

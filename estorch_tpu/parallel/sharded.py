@@ -53,9 +53,9 @@ Two evaluation bodies, chosen by the engine from what it observes
   full-rank noise and in-program low-rank noise on small trees.
 
 Scope: feedforward device-native envs (whole-episode sequence envs
-included), one episode per member.  obs_norm / decomposed / streamed /
-noise_kernel / recurrent carries stay on the replicated engine (their
-machinery assumes a replicated flat vector); the ctor rejects them loudly.
+included), one episode per member.  obs_norm and recurrent carries stay on
+the replicated engine (their machinery assumes a replicated flat vector);
+the ctor rejects them loudly.
 """
 
 from __future__ import annotations
@@ -157,12 +157,11 @@ class ShardedESEngine:
         perturbed_apply: Callable[..., Any] | None = None,
         lowrank_spec=None,
     ):
-        for flag in ("decomposed", "streamed", "noise_kernel", "obs_norm"):
-            if getattr(config, flag):
-                raise ValueError(
-                    f"{flag} is a replicated-engine option; the sharded "
-                    "path's noise/state layout replaces it (docs/sharding.md)"
-                )
+        if config.obs_norm:
+            raise ValueError(
+                "obs_norm is a replicated-engine option; the sharded "
+                "path's noise/state layout replaces it (docs/sharding.md)"
+            )
         if config.episodes_per_member != 1:
             raise ValueError(
                 "episodes_per_member is a replicated-engine option for now")

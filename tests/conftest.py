@@ -41,6 +41,8 @@ if _CACHE:
 
     enable_compilation_cache()
 
+import contextlib  # noqa: E402
+
 import pytest  # noqa: E402
 
 
@@ -49,3 +51,35 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual CPU devices, got {devs}"
     return devs
+
+
+def materialised(es):
+    """``es`` with its engine rebuilt WITHOUT a decomposed_apply: the
+    materialized-weights path, which no public option selects for a mirrored
+    MLP.  The state is engine-agnostic and carries over."""
+    from estorch_tpu.parallel.engine import ESEngine
+
+    es.engine = ESEngine(es.env, es._policy_apply, es._spec, es.table,
+                         es.optimizer, es.config, es.mesh)
+    es.engine.telemetry = es.obs
+    assert es.engine.forward_form == "materialised"
+    return es
+
+
+@pytest.fixture
+def dma_gather(monkeypatch):
+    """``with dma_gather():`` — engines built inside resolve
+    ``noise_gather_form == "dma"`` on the suite's CPU mesh, where the rule
+    says "slice"; ``_pallas_interpret`` comes from the mesh, so the row
+    kernels of ops/pallas_noise.py run under the Pallas interpreter.  A
+    fake substituted by the test: nothing in the package reads it."""
+    from estorch_tpu.parallel.engine import ESEngine
+
+    @contextlib.contextmanager
+    def forced():
+        with monkeypatch.context() as m:
+            m.setattr(ESEngine, "_resolve_noise_gather_form",
+                      lambda self: "dma")
+            yield
+
+    return forced

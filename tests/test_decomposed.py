@@ -1,8 +1,8 @@
 """Decomposed population forward: z = x@W + c(x@E) must be EXACTLY the
 materialized-weights path (it is a reordering, not an approximation),
-across feature combinations — in both forms the engine runs it: pair-shared
-(mirrored runs, chosen by the engine) and per-member (``decomposed=True`` on
-unmirrored runs)."""
+across feature combinations — in the form the engine runs it: pair-shared
+(mirrored runs, chosen by the engine).  Which form a run takes is the
+engine's rule (``ESEngine.forward_form``), pinned here facet by facet."""
 
 import re
 
@@ -10,15 +10,15 @@ import numpy as np
 import optax
 import pytest
 
+import flax.linen as nn
 import jax
+from conftest import materialised
 
-from estorch_tpu import (ES, NS_ES, JaxAgent, MLPPolicy, PooledAgent,
-                         RecurrentPolicy)
+from estorch_tpu import ES, NS_ES, JaxAgent, MLPPolicy, RecurrentPolicy
 from estorch_tpu.envs import CartPole, Pendulum
-from estorch_tpu.parallel.engine import ESEngine
 
 
-def _pair(decomposed, **over):
+def _pair(**over):
     kw = dict(
         policy=MLPPolicy,
         agent=JaxAgent,
@@ -32,18 +32,17 @@ def _pair(decomposed, **over):
         table_size=1 << 16,
     )
     kw.update(over)
-    return ES(decomposed=decomposed, **kw)
+    return ES(**kw)
 
 
-def _materialised(es):
-    """``es`` with its engine rebuilt WITHOUT a decomposed_apply: the
-    materialized-weights path, which no public option selects for a mirrored
-    MLP any more.  The state is engine-agnostic and carries over."""
-    es.engine = ESEngine(es.env, es._policy_apply, es._spec, es.table,
-                         es.optimizer, es.config, es.mesh)
-    es.engine.telemetry = es.obs
-    assert es.engine.forward_form == "materialised"
-    return es
+class TwoLayer(nn.Module):
+    """A policy that is not an MLPPolicy: no decomposed form to offer."""
+
+    action_dim: int
+
+    @nn.compact
+    def __call__(self, obs):
+        return nn.Dense(self.action_dim)(nn.tanh(nn.Dense(8)(obs)))
 
 
 def _assert_equivalent(a, b, gens=3, exact=True, params_atol=1e-3):
@@ -94,11 +93,11 @@ class TestPairSharedEquivalence:
     @pytest.mark.parametrize("case", sorted(PAIR_CASES))
     def test_matches_materialised(self, case):
         over, exact, atol = PAIR_CASES[case]
-        pair = _pair(False, **over)
+        pair = _pair(**over)
         assert pair.engine.forward_form == "pair_shared"
         if case == "chunks":
             assert pair.engine.members_local // pair.engine.eval_chunk >= 2
-        _assert_equivalent(_materialised(_pair(False, **over)), pair,
+        _assert_equivalent(materialised(_pair(**over)), pair,
                            exact=exact, **({} if atol is None
                                            else {"params_atol": atol}))
 
@@ -113,7 +112,7 @@ class TestPairSharedEquivalence:
                 table_size=1 << 15, meta_population_size=2, k=3)
             return NS_ES(**kw)
 
-        a, b = _materialised(make()), make()
+        a, b = materialised(make()), make()
         assert b.engine.forward_form == "pair_shared"
         a.train(3, verbose=False)
         b.train(3, verbose=False)
@@ -136,7 +135,7 @@ class TestPairSharedStructure:
         Sizes chosen so no other array can be mistaken for the noise: 24
         members, 12 pairs, dim 4·20+20+20·3+3 = 163."""
         members, pairs, hidden = 24, 12, 20
-        es = _pair(False, population_size=members, device=jax.devices()[:1],
+        es = _pair(population_size=members, device=jax.devices()[:1],
                    policy_kwargs={"action_dim": 3, "hidden": (hidden,)},
                    agent_kwargs={"env": CartPole(), "horizon": 5})
         dim = es._spec.dim
@@ -157,26 +156,28 @@ class TestPairSharedStructure:
 
     @pytest.mark.parametrize("name,over,form", [
         ("mirrored_mlp", {}, "pair_shared"),
-        ("mirrored_mlp_decomposed_flag", {"decomposed": True}, "pair_shared"),
+        # what the forward computes in, how often and on what input does
+        # not enter the rule
+        ("bf16", {"compute_dtype": "bfloat16"}, "pair_shared"),
+        ("episodes2", {"episodes_per_member": 2}, "pair_shared"),
+        ("obs_norm", {"obs_norm": True}, "pair_shared"),
         ("unmirrored", {"mirrored": False}, "materialised"),
-        ("unmirrored_decomposed", {"mirrored": False, "decomposed": True},
-         "decomposed"),
         ("recurrent", {"policy": RecurrentPolicy,
                        "policy_kwargs": {"action_dim": 2, "hidden": (8,),
                                          "gru_size": 8}}, "materialised"),
         ("vbn", {"policy_kwargs": {"action_dim": 2, "hidden": (16,),
                                    "use_vbn": True}}, "materialised"),
+        ("not_an_mlp_policy", {"policy": TwoLayer,
+                               "policy_kwargs": {"action_dim": 2}},
+         "materialised"),
         ("low_rank", {"low_rank": 1}, "low_rank"),
-        ("streamed", {"streamed": True}, "streamed"),
         # 6 members a device (8 devices) in chunks of 3: a pair must not
         # straddle two chunks
         ("odd_chunk", {"population_size": 48, "eval_chunk": 3},
          "materialised"),
     ])
     def test_selection_rule(self, name, over, form):
-        over = dict(over)
-        es = _pair(over.pop("decomposed", False),
-                   agent_kwargs={"env": CartPole(), "horizon": 5}, **over)
+        es = _pair(agent_kwargs={"env": CartPole(), "horizon": 5}, **over)
         assert es.engine.forward_form == form
         rows = es.population_size // 2 if form == "pair_shared" \
             else es.population_size
@@ -190,58 +191,3 @@ class TestPairSharedStructure:
         if name == "odd_chunk":
             assert es.engine.eval_chunk == 3
             es.train(1, verbose=False)  # the form it resolves to still runs
-
-
-class TestDecomposedEquivalence:
-    def test_identical_to_standard_path(self):
-        _assert_equivalent(_materialised(_pair(False)), _pair(True))
-
-    def test_identical_with_unmirrored_and_annealing(self):
-        over = dict(mirrored=False, sigma_decay=0.9, sigma_min=0.02)
-        _assert_equivalent(_pair(False, **over), _pair(True, **over))
-
-    def test_continuous_with_episodes_matches_to_rounding(self):
-        """Continuous rewards accumulate transcendental terms, so reordered
-        matmul rounding shows at ~1e-7 — tolerance, not exactness, here."""
-        over = dict(episodes_per_member=2, mirrored=False, **CONTINUOUS)
-        _assert_equivalent(_pair(False, **over), _pair(True, **over), exact=False)
-
-    def test_bf16_close_to_standard_bf16(self):
-        # bf16 admits a near-tie argmax flip between the two orderings
-        # (observed on XLA:CPU jax 0.4: one flipped member ⇒ ~5e-2 param
-        # drift over 3 gens); f32 exactness above pins the identity itself
-        over = dict(compute_dtype="bfloat16", mirrored=False)
-        _assert_equivalent(_pair(False, **over), _pair(True, **over),
-                           exact=False, params_atol=0.1)
-
-
-class TestDecomposedValidation:
-    def test_vbn_rejected(self):
-        with pytest.raises(ValueError, match="decomposed"):
-            _pair(True, policy_kwargs={"action_dim": 2, "hidden": (16,),
-                                       "use_vbn": True})
-
-    def test_host_rejected(self):
-        import torch
-
-        class P(torch.nn.Module):
-            def __init__(self):
-                super().__init__()
-                self.l = torch.nn.Linear(4, 2)
-
-            def forward(self, x):
-                return self.l(x)
-
-        class A:
-            def rollout(self, policy):
-                return 0.0
-
-        with pytest.raises(ValueError, match="device-path"):
-            ES(P, A, __import__("torch").optim.Adam, population_size=8,
-               optimizer_kwargs={"lr": 1e-2}, table_size=1 << 12,
-               decomposed=True)
-
-    def test_pooled_rejected(self):
-        with pytest.raises(ValueError, match="device-path"):
-            _pair(True, agent=PooledAgent,
-                  agent_kwargs={"env_name": "cartpole", "horizon": 30})

@@ -173,6 +173,50 @@ class TestMeshValidation:
         assert float(res.total_reward) == float(ev.fitness[5])
 
 
+    @pytest.mark.parametrize("case,over", [
+        ("sigma_decay", {"sigma_decay": 0.5, "sigma_min": 0.02}),
+        ("episodes2", {"episodes_per_member": 2}),
+        ("bf16", {"compute_dtype": "bfloat16"}),
+    ], ids=["sigma_decay", "episodes2", "bf16"])
+    def test_unmirrored_members_match_their_own_rollout(self, setup, case,
+                                                        over):
+        """The materialised body on an UNMIRRORED run, member by member:
+        ``member_params(state, i)`` rolled out alone, with member i's own
+        key, is ``fitness[i]`` — under an annealed σ, over two episodes a
+        member, and in bf16."""
+        import estorch_tpu.parallel.engine as eng_mod
+        from estorch_tpu.envs.rollout import make_rollout
+
+        cfg = EngineConfig(population_size=32, sigma=0.1, horizon=100,
+                           eval_chunk=2, mirrored=False, **over)
+        e = ESEngine(setup["env"], setup["apply"], setup["spec"],
+                     setup["table"], setup["opt"], cfg, population_mesh())
+        assert e.forward_form == "materialised"
+        s = e.init_state(setup["flat"], jax.random.PRNGKey(3))
+        if case == "sigma_decay":
+            s, _ = e.generation_step(s)  # σ is 0.05 from here on
+            assert float(s.sigma) == pytest.approx(0.05)
+        fitness = np.asarray(e.evaluate(s).fitness)
+        assert len(set(fitness.tolist())) > 1, "every member scored the same"
+
+        apply, cast = setup["apply"], lambda tree: tree
+        if case == "bf16":
+            apply = eng_mod._bf16_io_apply(apply)
+            cast = lambda tree: eng_mod._cast_leaves(tree, jnp.bfloat16)
+        rollout = make_rollout(setup["env"], apply, cfg.horizon)
+        _, rkey = eng_mod._gen_keys(s)
+        keys = jax.random.split(rkey, cfg.population_size)
+        # 4 members a device in 2 chunks: both chunks, first and last device
+        for i in (0, 3, 13, 31):
+            params = cast(setup["spec"].unravel(e.member_params(s, i)))
+            if case == "episodes2":
+                want = np.mean([float(rollout(params, k).total_reward)
+                                for k in jax.random.split(keys[i], 2)])
+            else:
+                want = float(rollout(params, keys[i]).total_reward)
+            assert want == float(fitness[i]), (case, i)
+
+
 class TestSigmaAnnealing:
     def test_sigma_decays_with_floor(self, setup):
         cfg = EngineConfig(
@@ -456,3 +500,24 @@ class TestLearning:
             if first_mean is None:
                 first_mean = mean
         assert mean > first_mean + 20, (first_mean, mean)
+
+
+def test_driver_entry_point_dry_run(devices8, capsys):
+    """``__graft_entry__.dryrun_multichip`` is what the driver calls to see
+    every forward form of the engine compile and run over a mesh; nothing
+    else in this suite runs it."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "__graft_entry__.py")
+    spec = importlib.util.spec_from_file_location("graft_entry", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    module.dryrun_multichip(len(devices8))
+    out = capsys.readouterr().out
+    for mode in ("mirrored", "unmirrored", "pair_shared", "lowrank",
+                 "recurrent", "obsnorm", "obsnorm_lowrank",
+                 "recurrent_lowrank"):
+        assert f"dryrun_multichip(8)[{mode}]" in out, mode
+    assert "dryrun_multichip(8): OK" in out

@@ -16,16 +16,19 @@ import jax
 import numpy as np
 import optax
 import pytest
+from conftest import materialised
 
 from estorch_tpu import ES, NS_ES, NSR_ES, NSRA_ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
 
 GOLDENS = {
     "ES": {"reward_means": [43.0, 40.375, 43.5625], "params_sum": -5.57803},
-    # identical values to ES by construction: the decomposition identity
-    # x@(W+cE) = x@W + c(x@E) is exact at these shapes on CPU f32 — if this
-    # golden ever drifts from ES's, the decomposed forward broke
-    "ES_decomposed": {
+    # identical values to ES by construction: ES runs the pair-shared
+    # forward, this one the materialised weights (conftest.materialised),
+    # and the decomposition identity x@(W+cE) = x@W + c(x@E) is exact at
+    # these shapes on CPU f32 — if the two goldens ever drift apart, one of
+    # the two forwards broke
+    "ES_materialised": {
         "reward_means": [43.0, 40.375, 43.5625],
         "params_sum": -5.57803,
     },
@@ -66,12 +69,12 @@ GOLDENS = {
                              "params_sum": -1.73011},
 }
 
-CLASSES = {"ES": ES, "ES_decomposed": ES, "NS_ES": NS_ES, "NSR_ES": NSR_ES,
+CLASSES = {"ES": ES, "ES_materialised": ES, "NS_ES": NS_ES, "NSR_ES": NSR_ES,
            "NSRA_ES": NSRA_ES, "ES_obsnorm": ES, "ES_recurrent": ES,
            "ES_lowrank": ES, "ES_recurrent_lowrank": ES}
 EXTRA = {
     "ES": {},
-    "ES_decomposed": {"decomposed": True},
+    "ES_materialised": {},
     "NS_ES": {"meta_population_size": 2, "k": 3},
     "NSR_ES": {"meta_population_size": 2, "k": 3},
     "NSRA_ES": {"meta_population_size": 2, "k": 3, "weight": 0.7},
@@ -105,6 +108,12 @@ def _run(name):
         table_size=1 << 15,
         **EXTRA[name],
     )
+    if name == "ES_materialised":
+        es = materialised(es)
+    else:
+        assert es.engine.forward_form == (
+            "low_rank" if "lowrank" in name
+            else "materialised" if recurrent else "pair_shared")
     es.train(3, verbose=False)
     return es
 

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import materialised
 
 from estorch_tpu import ES, IW_ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
@@ -146,14 +147,15 @@ class TestUpdate:
         assert any(r["reused_prev"] for r in es2.history)
         assert all(r["ess"] >= 0.0 for r in es2.history)
 
-    def test_decomposed_forward_is_equivalent(self):
-        """IW_ES advertises the decomposed forward (ctor accepts it, only
-        streamed/noise_kernel are rejected); since the decomposition is an
-        exact identity at f32, the whole reuse trajectory must match the
-        standard forward bit-for-bit — offsets, fitness, ESS decisions,
-        and the combined update."""
-        es_std = _make()
-        es_dec = _make(decomposed=True)
+    def test_pair_shared_forward_is_equivalent(self):
+        """IW_ES runs whichever forward the engine resolves; since the
+        decomposition behind the pair-shared one is an exact identity at
+        f32, the whole reuse trajectory must match the materialised
+        forward — offsets, fitness, ESS decisions, and the combined
+        update."""
+        es_std = materialised(_make())
+        es_dec = _make()
+        assert es_dec.engine.forward_form == "pair_shared"
         es_std.train(5, verbose=False)
         es_dec.train(5, verbose=False)
         assert ([r["reused_prev"] for r in es_std.history]

@@ -250,9 +250,23 @@ class TestEngineIntegration:
                 low_rank=1,
             )
 
-    def test_mutually_exclusive_with_other_modes(self):
-        with pytest.raises(ValueError, match="mutually exclusive"):
-            _make_es(decomposed=True)
+    def test_needs_a_policy_with_a_perturbed_forward(self):
+        """low_rank is a property of the search distribution, not a forward
+        any module can run: one without a perturbed form (models/
+        perturbed.py) and without a carry is refused at construction."""
+        import flax.linen as nn
+
+        class TwoLayer(nn.Module):
+            @nn.compact
+            def __call__(self, obs):
+                return nn.Dense(2)(nn.tanh(nn.Dense(8)(obs)))
+
+        with pytest.raises(ValueError, match="perturbed forward"):
+            ES(policy=TwoLayer, agent=JaxAgent, optimizer=optax.adam,
+               population_size=16, sigma=0.1,
+               agent_kwargs={"env": CartPole(), "horizon": 50},
+               optimizer_kwargs={"learning_rate": 1e-2},
+               table_size=1 << 15, low_rank=1)
 
     def test_learnability_pendulum(self):
         """Rank-1 ES must still learn: Pendulum mean return improves."""

@@ -169,18 +169,19 @@ def test_iwes_in_algo_matrix_on_device():
     assert es.generation == 2
 
 
-@pytest.mark.parametrize("mode", ["decomposed", "low_rank", "streamed"])
+@pytest.mark.parametrize("mode", ["pair_shared", "materialised", "low_rank"])
 def test_engine_modes_run_all_algorithms(mode):
-    """Every device forward mode composes with the novelty family (they all
-    share _eval_local), not just vanilla ES."""
-    over = {"decomposed": dict(decomposed=True),
-            "low_rank": dict(low_rank=1),
-            "streamed": dict(streamed=True)}[mode]
+    """Every forward form of the replicated engine composes with the
+    novelty family (they all sit behind _eval_local), not just vanilla ES."""
+    over = {"pair_shared": dict(),
+            "materialised": dict(mirrored=False),
+            "low_rank": dict(low_rank=1)}[mode]
     from estorch_tpu import NSR_ES
 
     kw = dict(BACKENDS["device"])
     es = NSR_ES(population_size=16, sigma=0.05, seed=0, table_size=1 << 14,
                 meta_population_size=2, k=3, **kw, **over)
+    assert es.engine.forward_form == mode
     es.train(2, verbose=False)
     assert len(es.history) == 2
     assert np.isfinite(es.history[-1]["reward_mean"])

@@ -149,15 +149,16 @@ class TestBf16ComputePath:
                 + ", ".join(str(e.outvars[0].aval) for e in bad)
             )
 
-    def test_no_per_step_param_cast_decomposed(self):
+    def test_no_per_step_param_cast_pair_shared(self):
         es = ES(
             MLPPolicy, JaxAgent, optax.adam,
             population_size=32, sigma=0.1, seed=0,
             policy_kwargs={"action_dim": 2, "hidden": (16,)},
             agent_kwargs={"env": CartPole(), "horizon": 100},
             optimizer_kwargs={"learning_rate": 3e-2},
-            table_size=1 << 16, compute_dtype="bfloat16", decomposed=True,
+            table_size=1 << 16, compute_dtype="bfloat16",
         )
+        assert es.engine.forward_form == "pair_shared"
         scans = self._episode_scans(es.engine._generation_step, (es.state,), 100)
         assert scans, "episode scan (length=100) not found in the program"
         for s in scans:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import materialised
 
 from estorch_tpu import ES, JaxAgent, MLPPolicy, RecurrentPolicy
 from estorch_tpu.envs import CartPole, Pendulum
@@ -310,8 +311,8 @@ class TestObsNormModeCombos:
     """obs_norm composes with every noise representation (round-3 VERDICT
     missing #2: the north-star Humanoid config wants obs_norm AND low_rank).
     Normalization is an input-side transform — each specialized forward
-    (decomposed, streamed, low_rank) normalizes raw obs in f32 against the
-    same per-generation stats snapshot the standard path uses."""
+    (pair_shared, low_rank) normalizes raw obs in f32 against the same
+    per-generation stats snapshot the materialised path uses."""
 
     def _es(self, **over):
         kw = dict(
@@ -325,10 +326,12 @@ class TestObsNormModeCombos:
         kw.update(over)
         return ES(**kw)
 
-    def test_decomposed_identical_to_standard(self):
-        """decomposed is a reordering, not an approximation — with obs_norm
-        on, params AND refreshed obs stats must match the standard path."""
-        a, b = self._es(), self._es(decomposed=True)
+    def test_pair_shared_identical_to_materialised(self):
+        """The pair-shared forward is a reordering, not an approximation —
+        with obs_norm on, params AND refreshed obs stats must match the
+        materialised path."""
+        a, b = materialised(self._es()), self._es()
+        assert b.engine.forward_form == "pair_shared"
         a.train(3, verbose=False)
         b.train(3, verbose=False)
         for ra, rb in zip(a.history, b.history):
@@ -341,21 +344,6 @@ class TestObsNormModeCombos:
         for sa, sb in zip(a.state.obs_stats, b.state.obs_stats):
             np.testing.assert_allclose(np.asarray(sa), np.asarray(sb),
                                        rtol=1e-5, atol=1e-6)
-
-    @pytest.mark.slow
-    def test_streamed_matches_decomposed(self):
-        """streamed is the Pallas kernel form of decomposed — same math,
-        obs normalized before the population-batched forward."""
-        a, b = self._es(decomposed=True), self._es(streamed=True)
-        a.train(2, verbose=False)
-        b.train(2, verbose=False)
-        for ra, rb in zip(a.history, b.history):
-            assert ra["reward_mean"] == pytest.approx(
-                rb["reward_mean"], rel=1e-5, abs=1.0)
-        np.testing.assert_allclose(
-            np.asarray(a.state.params_flat), np.asarray(b.state.params_flat),
-            rtol=1e-4, atol=1e-5,
-        )
 
     def test_low_rank_trains_and_stats_exact(self):
         """low_rank is a different search distribution (no standard-path

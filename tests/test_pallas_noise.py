@@ -1,10 +1,8 @@
-"""Pallas streamed-noise kernels vs their pure-JAX twins (interpret mode).
+"""The Pallas row kernels vs their pure-JAX twins (interpret mode).
 
 The kernels must be bit-compatible REORDERINGS of existing math:
 - gather_noise_rows ≡ vmap(NoiseTable.slice).astype(dtype), bit for bit
 - weighted_noise_sum ≡ ops/gradient.py::rank_weighted_noise_sum
-- population_noise_matvec ≡ the c·(x@E) noise term of models/decomposed.py
-- mlp_streamed_apply ≡ mlp_decomposed_apply over a population batch
 
 On CPU they run in interpret mode (``interpret=True`` is passed here, never
 derived from the backend); ``chip_smoke.py`` lowers the SAME code through
@@ -18,10 +16,7 @@ import pytest
 
 from estorch_tpu.ops import make_noise_table, make_param_spec, rank_weighted_noise_sum
 from estorch_tpu.ops.pallas_noise import (
-    flat_layer_offsets,
     gather_noise_rows,
-    mlp_streamed_apply,
-    population_noise_matvec,
     rows_fit_dma,
     weighted_noise_sum,
 )
@@ -167,123 +162,12 @@ class TestWeightedNoiseSum:
                                jnp.ones(1), dim=8, interpret=True)
 
 
-class TestPopulationNoiseMatvec:
-    # h a divisor of 128, a multiple of it, and neither (the gathered path)
-    @pytest.mark.parametrize("n,d,h", [(4, 8, 16), (6, 17, 5), (16, 32, 32),
-                                       (3, 64, 7), (3, 5, 256), (5, 9, 128),
-                                       (4, 13, 64)])
-    def test_matches_einsum_oracle(self, n, d, h):
-        key = jax.random.key(n + 10 * d + 100 * h)
-        offs = jax.random.randint(key, (n,), 0, TABLE.size - d * h - 64, dtype=jnp.int32)
-        c = jax.random.normal(jax.random.fold_in(key, 1), (n,))
-        x = jax.random.normal(jax.random.fold_in(key, 2), (n, d))
-        layer_off = 32
-
-        got = population_noise_matvec(
-            TABLE.data, offs, c, x, layer_offset=layer_off, d=d, h=h, interpret=True
-        )
-        # oracle: materialize each member's E and einsum
-        E = jax.vmap(
-            lambda o: jax.lax.dynamic_slice(TABLE.data, (o + layer_off,), (d * h,))
-        )(offs).reshape(n, d, h)
-        want = c[:, None] * jnp.einsum("nd,ndh->nh", x, E)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5
-        )
-
-    def test_explicit_block_rows(self):
-        """A forced non-trivial row blocking must not change the result."""
-        key = jax.random.key(0)
-        n, d, h = 4, 12, 8
-        offs = jax.random.randint(key, (n,), 0, TABLE.size - d * h, dtype=jnp.int32)
-        c = jnp.ones((n,))
-        x = jax.random.normal(jax.random.fold_in(key, 1), (n, d))
-        a = population_noise_matvec(
-            TABLE.data, offs, c, x, layer_offset=0, d=d, h=h,
-            interpret=True, block_rows=3,
-        )
-        b = population_noise_matvec(
-            TABLE.data, offs, c, x, layer_offset=0, d=d, h=h,
-            interpret=True, block_rows=12,
-        )
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
-
-    def test_indivisible_block_rows_rejected(self):
-        with pytest.raises(ValueError, match="divide"):
-            population_noise_matvec(
-                TABLE.data, jnp.zeros((2,), jnp.int32), jnp.ones((2,)),
-                jnp.ones((2, 10)), layer_offset=0, d=10, h=4,
-                interpret=True, block_rows=3,
-            )
-
-
-class TestStreamedMLPForward:
-    def _setup(self, n=6, obs_dim=5, hidden=(8, 8), act=3):
-        from estorch_tpu.models import MLPPolicy
-
-        module = MLPPolicy(action_dim=act, hidden=hidden, discrete=False)
-        obs0 = jnp.zeros(obs_dim)
-        params = module.init(jax.random.PRNGKey(0), obs0)["params"]
-        flat, spec = make_param_spec(params)
-        key = jax.random.key(9)
-        offs = jax.random.randint(
-            key, (n,), 0, TABLE.size - spec.dim, dtype=jnp.int32
-        )
-        c = 0.1 * jax.random.normal(jax.random.fold_in(key, 1), (n,))
-        obs = jax.random.normal(jax.random.fold_in(key, 2), (n, obs_dim))
-        return module, params, spec, offs, c, obs
-
-    def test_matches_decomposed_apply(self):
-        """Streamed forward ≡ pure-JAX decomposed forward, member by member."""
-        from estorch_tpu.models.decomposed import mlp_decomposed_apply
-
-        module, params, spec, offs, c, obs = self._setup()
-        lo = flat_layer_offsets(params)
-        got = mlp_streamed_apply(
-            module, params, TABLE.data, offs, c, obs, lo, interpret=True
-        )
-        for i in range(obs.shape[0]):
-            eps_tree = spec.unravel(TABLE.slice(offs[i], spec.dim))
-            want_i = mlp_decomposed_apply(module, params, eps_tree, c[i], obs[i])
-            np.testing.assert_allclose(
-                np.asarray(got[i]), np.asarray(want_i), rtol=1e-4, atol=1e-5,
-                err_msg=f"member {i}",
-            )
-
-    def test_matches_materialized_perturbation(self):
-        """…and ≡ the STANDARD engine path: apply(θ + c·ε) directly."""
-        module, params, spec, offs, c, obs = self._setup(hidden=(16,))
-        lo = flat_layer_offsets(params)
-        flat = spec.flatten(params)
-        got = mlp_streamed_apply(
-            module, params, TABLE.data, offs, c, obs, lo, interpret=True
-        )
-        for i in range(obs.shape[0]):
-            theta = flat + c[i] * TABLE.slice(offs[i], spec.dim)
-            want_i = module.apply({"params": spec.unravel(theta)}, obs[i])
-            np.testing.assert_allclose(
-                np.asarray(got[i]), np.asarray(want_i), rtol=1e-4, atol=1e-5,
-                err_msg=f"member {i}",
-            )
-
-    def test_layer_offsets_cover_flat_vector(self):
-        _, params, spec, *_ = self._setup()
-        lo = flat_layer_offsets(params)
-        total = sum(
-            int(np.prod(leaf.shape))
-            for leaf in jax.tree_util.tree_leaves(params)
-        )
-        assert total == spec.dim
-        all_offs = sorted(o for layer in lo.values() for o in layer.values())
-        assert all_offs[0] == 0
-        assert all(b > a for a, b in zip(all_offs, all_offs[1:]))
-
-
 class TestEngineNoiseKernel:
-    """noise_kernel=True must reproduce the chunked pure-JAX update inside
-    the real sharded generation program (8 virtual devices, interpret mode)."""
+    """The DMA form of the row gather must reproduce the chunked pure-JAX
+    update inside the real sharded generation program (8 virtual devices,
+    interpret mode; ``dma_gather``, tests/conftest.py, in place of a TPU)."""
 
-    def _engines(self, mirrored):
+    def _engines(self, mirrored, dma_gather):
         import optax
 
         from estorch_tpu.envs import CartPole
@@ -299,21 +183,24 @@ class TestEngineNoiseKernel:
             "b2": jnp.zeros(2),
         }
         flat, spec = make_param_spec(params)
-        out = []
-        for nk in (False, True):
-            cfg = EngineConfig(
-                population_size=32, sigma=0.1, horizon=30,
-                mirrored=mirrored, noise_kernel=nk,
-            )
-            out.append(
-                ESEngine(CartPole(), apply, spec, TABLE,
-                         optax.adam(1e-2), cfg, population_mesh())
-            )
-        return out, flat
+        cfg = EngineConfig(
+            population_size=32, sigma=0.1, horizon=30, mirrored=mirrored)
+
+        def engine():
+            return ESEngine(CartPole(), apply, spec, TABLE,
+                            optax.adam(1e-2), cfg, population_mesh())
+
+        ref = engine()
+        with dma_gather():
+            kern = engine()
+        assert (ref.noise_gather_form, kern.noise_gather_form) \
+            == ("slice", "dma")
+        return (ref, kern), flat
 
     @pytest.mark.parametrize("mirrored", [True, False])
-    def test_kernel_update_matches_pure_jax(self, mirrored, devices8):
-        (ref, kern), flat = self._engines(mirrored)
+    def test_kernel_update_matches_pure_jax(self, mirrored, devices8,
+                                            dma_gather):
+        (ref, kern), flat = self._engines(mirrored, dma_gather)
         s_ref = ref.init_state(flat, jax.random.PRNGKey(5))
         s_k = kern.init_state(flat, jax.random.PRNGKey(5))
         for gen in range(2):
@@ -328,100 +215,12 @@ class TestEngineNoiseKernel:
                 rtol=1e-5, atol=1e-6, err_msg=f"gen {gen}",
             )
 
-    def test_streamed_engine_matches_standard(self, devices8):
-        """The FULL streamed path (batched rollout + Pallas forward) must
-        reproduce the standard engine's fitness and update on the mesh."""
-        import optax
-
-        from estorch_tpu import ES, JaxAgent, MLPPolicy
-        from estorch_tpu.envs import CartPole
-
-        def mk(**over):
-            return ES(
-                MLPPolicy, JaxAgent, optax.adam,
-                population_size=32, sigma=0.1, seed=0,
-                policy_kwargs={"action_dim": 2, "hidden": (16,)},
-                agent_kwargs={"env": CartPole(), "horizon": 60},
-                optimizer_kwargs={"learning_rate": 3e-2},
-                table_size=1 << 16, **over,
-            )
-
-        std, stream = mk(), mk(streamed=True)
-        for gen in range(2):
-            std.train(1, verbose=False)
-            stream.train(1, verbose=False)
-            np.testing.assert_allclose(
-                np.asarray(stream.state.params_flat),
-                np.asarray(std.state.params_flat),
-                rtol=2e-5, atol=1e-6, err_msg=f"gen {gen}",
-            )
-        # fitness recorded identically (CartPole argmax actions: float-
-        # associativity can only flip near-ties, so allow tiny disagreement)
-        f_std = [r["reward_mean"] for r in std.history]
-        f_str = [r["reward_mean"] for r in stream.history]
-        np.testing.assert_allclose(f_str, f_std, rtol=0.1)
-
-    def test_streamed_learns(self, devices8):
-        import optax
-
-        from estorch_tpu import ES, JaxAgent, MLPPolicy
-        from estorch_tpu.envs import CartPole
-
-        es = ES(
-            MLPPolicy, JaxAgent, optax.adam,
-            population_size=32, sigma=0.1, seed=0,
-            policy_kwargs={"action_dim": 2, "hidden": (16,)},
-            agent_kwargs={"env": CartPole(), "horizon": 100},
-            optimizer_kwargs={"learning_rate": 3e-2},
-            table_size=1 << 16, streamed=True, noise_kernel=True,
-        )
-        es.train(8, verbose=False)
-        first = es.history[0]["reward_mean"]
-        last = es.history[-1]["reward_mean"]
-        assert last > first + 15, (first, last)
-
-    def test_streamed_rejected_on_pooled(self):
-        """streamed must fail LOUDLY on the pooled path, not silently run
-        the standard materialized forward."""
-        import optax
-
-        from estorch_tpu import ES, MLPPolicy, PooledAgent
-
-        with pytest.raises(ValueError, match="streamed"):
-            ES(
-                MLPPolicy, PooledAgent, optax.adam,
-                population_size=8, sigma=0.1,
-                policy_kwargs={"action_dim": 2, "hidden": (8,)},
-                agent_kwargs={"env_name": "cartpole", "horizon": 10},
-                optimizer_kwargs={"learning_rate": 1e-2},
-                table_size=1 << 14, streamed=True,
-            )
-
-    def test_rejected_on_host_backend(self):
-        import torch
-
-        from estorch_tpu import ES
-
-        class P(torch.nn.Module):
-            def __init__(self):
-                super().__init__()
-                self.lin = torch.nn.Linear(2, 2)
-
-            def forward(self, x):
-                return self.lin(x)
-
-        class A:
-            def rollout(self, policy):
-                return 0.0
-
-        with pytest.raises(ValueError, match="noise_kernel"):
-            ES(P, A, torch.optim.Adam, population_size=4, noise_kernel=True)
-
 
 class TestNoiseGatherForm:
     """``ESEngine.noise_gather_form``: resolved once at build, reported in
-    the manifest and the gauges, and — forced with ``noise_kernel=True``,
-    interpreted — the same generation as the slice form."""
+    the manifest and the gauges, and — forced by ``dma_gather``
+    (tests/conftest.py), interpreted — the same generation as the slice
+    form.  The rule on a TPU mesh: tests/test_trace_stages.py."""
 
     @staticmethod
     def _es(**over):
@@ -444,8 +243,6 @@ class TestNoiseGatherForm:
         ("cpu_mesh_bf16", {"compute_dtype": "bfloat16"}, "slice"),
         ("cpu_mesh_unmirrored", {"mirrored": False}, "slice"),
         ("low_rank", {"low_rank": 1}, "slice"),
-        ("forced", {"noise_kernel": True}, "dma"),
-        ("forced_streamed", {"noise_kernel": True, "streamed": True}, "dma"),
     ])
     def test_resolved_and_reported(self, name, over, form):
         es = self._es(**over)
@@ -453,7 +250,7 @@ class TestNoiseGatherForm:
         assert es.run_manifest()["config"]["noise_gather_form"] == form
         assert es.obs.counters.snapshot()["noise_gather_form"] == form
 
-    def test_update_only_engines_follow_the_rule(self):
+    def test_update_only_engines_follow_the_rule(self, dma_gather):
         """The pooled path's update program calls ``_local_grad`` too."""
         import optax
 
@@ -468,19 +265,23 @@ class TestNoiseGatherForm:
                       table_size=1 << 14, **over)
 
         assert mk().engine.core.noise_gather_form == "slice"
-        assert mk(noise_kernel=True).engine.core.noise_gather_form == "dma"
+        with dma_gather():  # what the core engine resolves is what it reports
+            assert mk().engine.core.noise_gather_form == "dma"
 
     @pytest.mark.parametrize("over", [
         {}, {"compute_dtype": "bfloat16"}, {"episodes_per_member": 2},
         {"population_size": 36},  # ghost pairs on the 8-device mesh
         {"obs_norm": True}, {"mirrored": False},
     ], ids=["f32", "bf16", "episodes2", "padded", "obs_norm", "unmirrored"])
-    def test_forced_dma_generation_equals_slice(self, over, devices8):
+    def test_forced_dma_generation_equals_slice(self, over, devices8,
+                                                dma_gather):
         """Pair-shared (and, unmirrored, materialised) generations: the
         gathered rows are the slice form's bits, so the fitness is EQUAL;
         the update's f32 FMA against the slice form's matmul agrees to f32
         tolerance."""
-        ref, dma = self._es(**over), self._es(noise_kernel=True, **over)
+        ref = self._es(**over)
+        with dma_gather():
+            dma = self._es(**over)
         want = "materialised" if over.get("mirrored") is False \
             else "pair_shared"
         assert ref.engine.forward_form == dma.engine.forward_form == want
@@ -498,26 +299,35 @@ class TestNoiseGatherForm:
                 rtol=1e-5, atol=1e-6, err_msg=f"gen {gen}")
 
 
-def test_noise_kernel_rejects_dims_past_vmem_budget():
-    """>1M params with noise_kernel=True must fail loudly at construction
-    (3·dim f32 VMEM cost, parallel/engine.py::NOISE_KERNEL_MAX_DIM), not as
-    an opaque Mosaic compile error inside the generation step."""
+def test_dims_past_the_vmem_budget_resolve_slice():
+    """>1M params: ``weighted_noise_sum``'s 3·dim f32 would not fit VMEM
+    (parallel/engine.py::NOISE_KERNEL_MAX_DIM), so where a chip would run
+    the kernels the rule keeps the chunked pure-JAX forms for such a run."""
     import optax
 
     from estorch_tpu import ES, JaxAgent, MLPPolicy
     from estorch_tpu.envs import SyntheticEnv
+    from estorch_tpu.parallel.engine import NOISE_KERNEL_MAX_DIM
 
     env = SyntheticEnv()  # obs 376: hidden 1024x1024 → ~1.45M params
-    with pytest.raises(ValueError, match="noise_kernel.*1,000,000"):
-        ES(
+
+    def resolved_on_a_chip(hidden):
+        engine = ES(
             policy=MLPPolicy,
             agent=JaxAgent,
             optimizer=optax.adam,
             population_size=8,
             policy_kwargs={"action_dim": env.action_dim,
-                           "hidden": (1024, 1024), "discrete": False},
+                           "hidden": hidden, "discrete": False},
             agent_kwargs={"env": env, "horizon": 10},
             optimizer_kwargs={"learning_rate": 1e-2},
             table_size=1 << 21,
-            noise_kernel=True,
-        )
+        ).engine
+        assert engine.noise_gather_form == "slice"  # a CPU mesh
+        engine._pallas_interpret = False  # what a TPU mesh makes it
+        return engine.spec.dim, engine._resolve_noise_gather_form()
+
+    dim, form = resolved_on_a_chip((1024, 1024))
+    assert dim > NOISE_KERNEL_MAX_DIM and form == "slice"
+    dim, form = resolved_on_a_chip((8,))
+    assert dim <= NOISE_KERNEL_MAX_DIM and form == "dma"

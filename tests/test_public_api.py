@@ -88,6 +88,29 @@ def test_es_constructor_signature_matches_reference():
         assert name in params, f"reference ctor arg {name!r} missing"
 
 
+def test_es_options_are_a_written_list():
+    """Every keyword of ``ES.__init__``, in order.  Each one multiplies the
+    configurations tests and benchmark cells have to cover: a new option
+    is an edit to this list, visible in review, not a drive-by."""
+    from estorch_tpu import ES
+
+    options = [
+        # the reference's
+        "policy", "agent", "optimizer", "population_size", "sigma", "device",
+        "policy_kwargs", "agent_kwargs", "optimizer_kwargs",
+        # this implementation's
+        "seed", "table_size", "eval_chunk", "grad_chunk", "weight_decay",
+        "mesh", "vbn_batch", "compute_dtype", "sigma_decay", "sigma_min",
+        "mirrored", "episodes_per_member", "worker_mode", "low_rank",
+        "obs_norm", "obs_clip", "obs_probe_episodes", "obs_warmup_episodes",
+        "telemetry", "shard_params", "model_shards", "partition_rules",
+        "noise_mode", "scenarios",
+    ]
+    assert len(options) == 33
+    params = list(inspect.signature(ES.__init__).parameters)
+    assert params == ["self"] + options
+
+
 def test_train_signature_matches_reference():
     from estorch_tpu import ES
 

@@ -170,16 +170,6 @@ class TestRecurrentTraining:
         assert np.isfinite(es.history[-1]["reward_mean"])
 
 
-class TestRecurrentGuards:
-    def test_decomposed_rejected(self):
-        with pytest.raises(ValueError, match="decomposed"):
-            _make_es(RecurrentPolicy, RECURRENT_PK, decomposed=True)
-
-    def test_streamed_rejected(self):
-        with pytest.raises(ValueError, match="streamed|recurrent"):
-            _make_es(RecurrentPolicy, RECURRENT_PK, streamed=True)
-
-
 class TestRecurrentLowRank:
     """Recurrent × low_rank (round-4 verdict next #7): factored noise over
     the whole recurrent tree — trunk, cell gates, head — with per-episode

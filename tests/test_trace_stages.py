@@ -5,6 +5,7 @@ trace (docs/observability.md, "Stages inside the generation program").
 No wall-clock assertion anywhere: the xdist workers share the CPU.
 """
 
+import contextlib
 import re
 import sys
 
@@ -33,10 +34,9 @@ PHASES = ("dispatch", "device", "host_sync", "record")
 # every form runs every stage; what differs is where the stage's work is
 FORMS = {
     "standard": {},
-    "noise_dma": {"noise_kernel": True},  # the row kernels, interpreted
-    "decomposed": {"decomposed": True},
+    "noise_dma": {},  # the row kernels, interpreted (conftest.dma_gather)
+    "unmirrored": {"mirrored": False},
     "low_rank": {"low_rank": 1},
-    "streamed": {"streamed": True},
     "obs_norm": {"obs_norm": True},
     "sharded_program": {"shard_params": True},
     "sharded_table": {"shard_params": True, "noise_mode": "table"},
@@ -68,9 +68,14 @@ def keyed_by_source():
 
 
 @pytest.mark.parametrize("form", sorted(FORMS))
-def test_compiled_generation_names_every_stage(form, keyed_by_source):
-    es = _es(**FORMS[form])
+def test_compiled_generation_names_every_stage(form, keyed_by_source,
+                                               dma_gather):
+    with dma_gather() if form == "noise_dma" else contextlib.nullcontext():
+        es = _es(**FORMS[form])
     engine = es.engine
+    if form in ("standard", "noise_dma"):
+        assert engine.noise_gather_form == (
+            "dma" if form == "noise_dma" else "slice")
     args = (es.state,)
     if getattr(engine, "noise_mode", None) == "table":
         args += (engine.table.data,)
@@ -188,6 +193,44 @@ def test_dma_form_books_its_kernels_to_noise_and_grad(v5e_chip):
                if "tpu_custom_call" in line
                for name in re.findall(r'op_name="([^"]*)"', line)]
     assert sorted(stack[-1] for stack in kernels) == [GRAD, NOISE], kernels
+
+
+@pytest.mark.parametrize("case,form", [
+    ("f32_tileable_table", "dma"),
+    ("dim_past_the_vmem_budget", "slice"),
+    ("table_not_whole_tiles", "slice"),
+    ("bf16_table", "slice"),
+    ("low_rank", "slice"),
+    ("update_only", "dma"),
+])
+def test_gather_rule_on_a_tpu_mesh(case, form, v5e_chip):
+    """``ESEngine.noise_gather_form`` on a mesh of TPU devices, each arm of
+    the rule (platform, table dtype and tiling, ``spec.dim``, ``low_rank``)
+    — what a chip run resolves, with nothing compiled."""
+    from estorch_tpu.ops import make_noise_table, make_param_spec
+    from estorch_tpu.parallel import ESEngine
+    from estorch_tpu.parallel.engine import NOISE_KERNEL_MAX_DIM
+    from estorch_tpu.parallel.mesh import population_mesh
+
+    es = _es(low_rank=1 if case == "low_rank" else 0, table_size=1 << 16)
+    assert es.engine.noise_gather_form == "slice"  # a CPU mesh
+    spec, table, env = es._spec, es.table, es.env
+    if case == "dim_past_the_vmem_budget":
+        _, spec = make_param_spec(
+            {"w": jnp.zeros((NOISE_KERNEL_MAX_DIM + 1,), jnp.float32)})
+    elif case == "table_not_whole_tiles":
+        table = make_noise_table((1 << 16) + 8, seed=0)
+    elif case == "bf16_table":
+        table = make_noise_table(1 << 16, seed=0, dtype=jnp.bfloat16)
+    elif case == "update_only":  # the pooled path's core engine
+        env = None
+    lowrank = {}
+    if case == "low_rank":
+        lr_apply, lr_spec = es._perturbed_form(es.state.params_flat)
+        lowrank = {"lowrank_apply": lr_apply, "lowrank_spec": lr_spec}
+    engine = ESEngine(env, es._policy_apply, spec, table, es.optimizer,
+                      es.config, population_mesh([v5e_chip]), **lowrank)
+    assert engine.noise_gather_form == form
 
 
 @pytest.mark.parametrize("dim,rows", [(75018, 5120), (166673, 2048)],
