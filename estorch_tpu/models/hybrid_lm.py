@@ -35,9 +35,9 @@ norm, and the log-softmax in float32.
 
 Mamba-2 in the chunked form (chunk ``mamba_chunk_size``): inside a chunk
 ``y = (C·Bᵀ ∘ decay) · (dt·x)``, across chunks a scan over the per-chunk
-states.  Attention in blocks of queries, so that no ``[T, T]`` score
-outlives its block; the head in blocks of positions, so that the
-``[T, vocab]`` logits never exist.
+states.  Attention in blocks of queries, each scored against the keys it can
+see and no others, so that no ``[T, T]`` score exists; the head in blocks of
+positions, so that the ``[T, vocab]`` logits never exist.
 
 As an ES policy the module maps a token sequence ``[T]`` to ``(log p of
 each next token [T-1], the last position's logits [vocab])``; a sequence
@@ -303,6 +303,13 @@ class HybridLM:
         return self._dense(p, noise, c, "down", act)
 
     def _attention(self, p, noise, c, u):
+        """Causal attention with grouped heads, block-causal: query block
+        ``i`` is scored against the keys ``[0, end of block i)`` and no
+        others, so (n+1)/(2n) of the ``[T, T]`` score tiles of ``n`` blocks
+        are computed, and a masked score (``exp(-inf) = 0``) exists only
+        inside the diagonal tile.  The loop over blocks is unrolled: the
+        program grows with ``T / attention_block``, so a much longer
+        sequence should raise the block, not the count."""
         dtype, t = u.dtype, u.shape[0]
         nq, nkv, hd = (self.num_attention_heads, self.num_key_value_heads,
                        self.head_dim)
@@ -312,29 +319,30 @@ class HybridLM:
         q = self._dense(p, noise, c, "q", u).astype(dtype)
         k = self._dense(p, noise, c, "k", u).astype(dtype)
         v = self._dense(p, noise, c, "v", u).astype(dtype)
-        block = min(self.attention_block, t)
-        n_blocks = -(-t // block)
-        pad = n_blocks * block - t
         # query head j reads key/value head j // (nq / nkv)
-        qb = jnp.pad(q, ((0, pad), (0, 0))).reshape(
-            n_blocks, block, nkv, nq // nkv, hd)
+        qh = q.reshape(t, nkv, nq // nkv, hd)
         kh, vh = k.reshape(t, nkv, hd), v.reshape(t, nkv, hd)
-        starts = jnp.arange(n_blocks) * block
-
-        def one_block(xs):
-            q_b, start = xs
+        block = min(self.attention_block, t)
+        ctx = []
+        for start in range(0, t, block):
+            stop = min(start + block, t)
+            q_b = qh[start:stop]
+            if ctx:
+                # one block at a time: left free, the TPU scheduler runs
+                # every block's softmax before the first P·V and holds all
+                # their float32 scores at once (T²/2 of them)
+                q_b, _ = jax.lax.optimization_barrier((q_b, ctx[-1]))
             with stage(ATTN):
-                s = jnp.einsum("qkgd,skd->kgqs", q_b, kh,
+                s = jnp.einsum("qkgd,skd->kgqs", q_b, kh[:stop],
                                preferred_element_type=F32) * scale
-                rows = start + jnp.arange(block)
-                mask = jnp.arange(t)[None, :] <= rows[:, None]
+                mask = (jnp.arange(stop)[None, :]
+                        <= jnp.arange(start, stop)[:, None])
                 s = jnp.where(mask, s, -jnp.inf)
                 prob = jax.nn.softmax(s, axis=-1).astype(dtype)
-                return jnp.einsum("kgqs,skd->qkgd", prob, vh,
-                                  preferred_element_type=F32).astype(dtype)
-
-        ctx = jax.lax.map(one_block, (qb, starts))
-        ctx = ctx.reshape(n_blocks * block, nq * hd)[:t]
+                ctx.append(jnp.einsum(
+                    "kgqs,skd->qkgd", prob, vh[:stop],
+                    preferred_element_type=F32).astype(dtype))
+        ctx = jnp.concatenate(ctx).reshape(t, nq * hd)
         return self._dense(p, noise, c, "o", ctx)
 
     def _mamba(self, p, noise, c, u):

@@ -136,6 +136,148 @@ def test_bfloat16_operands_accumulate_in_float32(tiny):
     assert 1e-6 < float(jnp.abs(logp - full).max()) < 0.05
 
 
+# ---------------------------------------------------------- attention
+
+ATTN_LENGTHS, ATTN_BLOCKS = (5, 16, 21, 40), (8, 16, 64)
+
+
+def _attention_case(length, block, dtype=jnp.float32):
+    """A tiny model with that query block, its attention leaves drawn at a
+    scale that spreads the softmax, and a ``[length, hidden]`` input."""
+    lm = HybridLM(**{**lm_tiny.TINY, "attention_block": block})
+    shapes = lm.param_shapes()["layer_01"]["attn"]
+    keys = jax.random.split(jax.random.PRNGKey(100 * length + block), 5)
+    p = {name: (0.2 * jax.random.normal(k, shapes[name].shape)).astype(dtype)
+         for name, k in zip(sorted(shapes), keys)}
+    u = jax.random.normal(keys[4], (length, lm.hidden_size)).astype(dtype)
+    return lm, p, u
+
+
+def _whole_sequence_attention(lm, p, u):
+    """The oracle: one masked softmax over the whole ``[T, T]`` score,
+    float32, the key/value heads expanded to the query heads by ``repeat``."""
+    t, nq, hd = u.shape[0], lm.num_attention_heads, lm.head_dim
+    groups = nq // lm.num_key_value_heads
+    q = (u @ p["q"]).reshape(t, nq, hd)
+    k = jnp.repeat((u @ p["k"]).reshape(t, -1, hd), groups, axis=1)
+    v = jnp.repeat((u @ p["v"]).reshape(t, -1, hd), groups, axis=1)
+    s = jnp.einsum("qhd,shd->hqs", q, k) * lm.attention_multiplier
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    ctx = jnp.einsum("hqs,shd->qhd", jax.nn.softmax(s, axis=-1), v)
+    return ctx.reshape(t, nq * hd) @ p["o"]
+
+
+@pytest.mark.parametrize("block", ATTN_BLOCKS)
+@pytest.mark.parametrize("length", ATTN_LENGTHS)
+def test_block_causal_attention_matches_a_whole_sequence_softmax(length,
+                                                                 block):
+    """Ragged tails (5, 21 and 40 at block 16), exact multiples and one
+    block (everything at block 64): a score left out is one whose
+    probability the mask makes exactly 0."""
+    lm, p, u = _attention_case(length, block)
+    got = lm._attention(p, None, 0.0, u)
+    want = _whole_sequence_attention(lm, p, u)
+    assert got.shape == (length, lm.hidden_size) and got.dtype == jnp.float32
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("block", ATTN_BLOCKS)
+@pytest.mark.parametrize("length", ATTN_LENGTHS)
+def test_block_causal_attention_under_pairs_and_signs(length, block):
+    """As the engine calls it: ``vmap`` over pairs (each with its own
+    rank-2 factors) of a ``vmap`` over the two signs ``c = ±σ``, the
+    factors read once a pair; the oracle materialises
+    ``W + c·A·Bᵀ/√r`` for each of the members."""
+    lm, p, u = _attention_case(length, block)
+    pairs, rank, sigma = 2, 2, 0.1
+    spec = make_lowrank_tree_spec(p, rank)
+    noise = jax.random.normal(jax.random.PRNGKey(length + block),
+                              (pairs, spec.noise_dim))
+    signs = sigma * jnp.asarray([1.0, -1.0])
+
+    def pair(row):
+        factors = spec.unpack(row)
+        return jax.vmap(lambda c: lm._attention(p, factors, c, u))(signs)
+
+    got = jax.vmap(pair)(noise)
+    assert got.shape == (pairs, 2, length, lm.hidden_size)
+    centre = _whole_sequence_attention(lm, p, u)
+    for i in range(pairs):
+        factors = spec.unpack(noise[i])
+        for j, c in enumerate(signs):
+            member = {name: p[name] + c * (factors[name][0]
+                                           @ factors[name][1].T)
+                      / np.sqrt(rank) for name in p}
+            want = _whole_sequence_attention(lm, member, u)
+            assert float(jnp.abs(want - centre).max()) > 0.05
+            np.testing.assert_allclose(got[i, j], want, atol=1e-5, rtol=0)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def _attention_equations(lm, p, u):
+    return list(_equations(jax.make_jaxpr(
+        lambda p, u: lm._attention(p, None, 0.0, u))(p, u).jaxpr))
+
+
+def test_attention_of_bfloat16_operands_stays_float32_inside():
+    """Projections, scores and P·V all accumulate in float32, and the
+    softmax between them is float32: the only bfloat16 values are the
+    matmuls' operands."""
+    lm, p, u = _attention_case(40, 16, jnp.bfloat16)
+    eqns = _attention_equations(lm, p, u)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 4 + 2 * 3
+    for e in dots:
+        assert {v.aval.dtype for v in e.invars} == {jnp.dtype(jnp.bfloat16)}
+        assert e.outvars[0].aval.dtype == jnp.float32
+    soft = [e for e in eqns if e.primitive.name in ("exp", "reduce_max",
+                                                    "reduce_sum", "div")]
+    assert soft and all(e.outvars[0].aval.dtype == jnp.float32 for e in soft)
+    assert lm._attention(p, None, 0.0, u).dtype == jnp.float32
+
+
+def test_each_query_block_is_scored_against_its_prefix_only():
+    """The mechanism's counter, read from the program: at 8 blocks the
+    score matmuls' key extents are block, 2·block, … T, which is 36 of the
+    64 ``[block, block]`` tiles of ``T²``, and no loop is left to hide a
+    wider one."""
+    block, n_blocks = 16, 8
+    length = block * n_blocks
+    lm, p, u = _attention_case(length, block)
+    assert lm.head_dim not in range(block, length + 1, block)
+    eqns = _attention_equations(lm, p, u)
+    assert not {e.primitive.name for e in eqns} & {"scan", "while"}
+    scored = []
+    for e in eqns:
+        if e.primitive.name != "dot_general":
+            continue
+        contract, batch = e.params["dimension_numbers"]
+        if not batch[0] or (e.invars[0].aval.shape[contract[0][0]]
+                            != lm.head_dim):
+            continue        # a projection, or P·V (which contracts keys)
+        # the keys are the operand without the axis of grouped query heads
+        side = 0 if e.invars[0].aval.ndim == 3 else 1
+        keys, queries = e.invars[side].aval.shape, e.invars[1 - side].aval.shape
+        free = [n for axis, n in enumerate(keys)
+                if axis not in contract[side] + batch[side]]
+        assert len(keys) == 3 and len(queries) == 4 and len(free) == 1
+        assert block in queries
+        scored.append(free[0])
+    assert scored == [block * (i + 1) for i in range(n_blocks)]
+    assert sum(block * s for s in scored) * 64 == 36 * length * length
+
+
 def test_init_draws_the_declared_tree(tiny):
     lm = tiny["lm"]
     params = lm.init(jax.random.PRNGKey(0), None)["params"]
