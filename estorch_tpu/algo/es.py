@@ -328,6 +328,7 @@ class ES:
                 partition_rules=self._partition_rules,
                 noise_mode=self._noise_mode,
                 perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+                leaf_rows=getattr(self.module, "leaf_rows", None),
             )
             # the whole flat vector leaves the device before the sharded
             # state is placed from it, a leaf at a time: a tree this
@@ -366,7 +367,8 @@ class ES:
                     raise ValueError(
                         "low_rank needs a policy with a perturbed forward "
                         "(models/perturbed.py: MLPPolicy without VBN, "
-                        "HybridLM) or a recurrent policy (tree form); "
+                        "HybridLM, LoopedLM) or a recurrent policy (tree "
+                        "form); "
                         f"got {type(self.module).__name__}"
                     )
 
@@ -380,6 +382,22 @@ class ES:
         )
         self.state = self.engine.init_state(flat, state_key)
         self._post_engine_init()
+
+    def _sequence_facts(self) -> dict:
+        """What a whole-episode (token sequence) run works through a
+        generation: gauges and ``run_manifest()["config"]``; a looped model
+        (models/looped_lm.py) adds its passes and the layer-applications a
+        token goes through."""
+        if not getattr(getattr(self, "env", None), "whole_episode", False):
+            return {}
+        facts = {"tokens_per_generation":
+                 self.population_size * self.config.horizon}
+        steps = getattr(self.module, "total_ut_steps", None)
+        if steps is not None:
+            facts["loop_steps"] = int(steps)
+            facts["layer_applications_per_token"] = (
+                int(steps) * len(self.module.layer_types))
+        return facts
 
     def _perturbed_form(self, flat):
         """``(perturbed apply, noise layout)`` of the module for
@@ -505,10 +523,8 @@ class ES:
                 str(n) for n in self.mesh.devices.shape))
             self.obs.counters.gauge("param_bytes_per_chip",
                                     self.engine.param_bytes_per_chip)
-        if getattr(getattr(self, "env", None), "whole_episode", False):
-            self.obs.counters.gauge(
-                "tokens_per_generation",
-                self.population_size * self.config.horizon)
+        for name, value in self._sequence_facts().items():
+            self.obs.counters.gauge(name, value)
         # analytic FLOPs/bytes model of this configuration (obs/profile/):
         # rides the first generation record so `obs profile` can turn the
         # phase spans into achieved rates against a roofline.  Building it
@@ -1002,9 +1018,13 @@ class ES:
             self.best_reward = gen_best
             idx = int(np.nanargmax(fitness))
             if metrics is not None and "best_theta" in metrics:
-                # kept as the program emitted it, sharded; gathered into a
-                # flat host vector only when somebody reads _best_flat
-                self._best_flat = metrics["best_theta"]
+                # kept sharded, on the mesh (a later best in the buffers of
+                # the one it replaces); gathered into a flat host vector
+                # only when somebody reads _best_flat
+                held = (None if self._best is None
+                        or isinstance(self._best, np.ndarray) else self._best)
+                self._best_flat = self.engine.keep_best(
+                    metrics["best_theta"], held)
             else:
                 self._best_flat = np.asarray(
                     self.engine.member_params(prev_state, idx))
@@ -1125,6 +1145,7 @@ class ES:
             "noise_gather_form": getattr(
                 self.engine, "noise_gather_form", None),
             "shard_params": self._shard_params,
+            **self._sequence_facts(),
         }
         if self._scenarios is not None:
             # scenario provenance: the distribution spec + draw seed ARE

@@ -1,4 +1,5 @@
 from .hybrid_lm import HybridLM
+from .looped_lm import LoopedLM
 from .policies import MLPPolicy, NatureCNN, RecurrentNatureCNN, RecurrentPolicy
 from .vbn import VirtualBatchNorm, capture_reference_stats
 
@@ -18,6 +19,7 @@ def __getattr__(name):
 
 __all__ = [
     "HybridLM",
+    "LoopedLM",
     "MLPPolicy",
     "NatureCNN",
     "RecurrentNatureCNN",

@@ -47,25 +47,19 @@ env (envs/sequence.py) turns that into fitness and behaviour.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import ATTN, DENSE, HEAD, SSM, stage
+from ..obs.trace import HEAD, SSM, stage
+from . import lm_blocks
+from .lm_blocks import layer_name, rmsnorm as _rmsnorm, subtree
 from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
 
 MAMBA, ATTENTION = "mamba", "attention"
-
-
-def layer_name(i: int) -> str:
-    return f"layer_{i:02d}"
-
-
-def _rmsnorm(x, scale, eps):
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
 def _causal_conv(x, taps, bias):
@@ -175,28 +169,22 @@ class HybridLM:
         return {"params": jax.jit(self._draw)(key)}
 
     def _draw(self, key):
-        shapes = self.param_shapes()
-        paths = [p for p, _ in
-                 jax.tree_util.tree_flatten_with_path(shapes)[0]]
-        leaves, treedef = jax.tree_util.tree_flatten(shapes)
         bound = 1.0 / math.sqrt(self.mamba_d_conv)
-        out = []
-        for i, (path, leaf) in enumerate(zip(paths, leaves)):
-            name, k = str(path[-1].key), jax.random.fold_in(key, i)
+
+        def value_of(name, k, shape):
             if name in ("scale", "norm_scale", "D"):
-                v = jnp.ones(leaf.shape, F32)
-            elif name == "A_log":
-                v = jnp.log(jax.random.uniform(k, leaf.shape, F32, 1.0, 16.0))
-            elif name == "dt_bias":
+                return jnp.ones(shape, F32)
+            if name == "A_log":
+                return jnp.log(jax.random.uniform(k, shape, F32, 1.0, 16.0))
+            if name == "dt_bias":
                 dt = jnp.exp(jax.random.uniform(
-                    k, leaf.shape, F32, math.log(1e-3), math.log(1e-1)))
-                v = dt + jnp.log(-jnp.expm1(-dt))
-            elif name.startswith("conv_"):
-                v = jax.random.uniform(k, leaf.shape, F32, -bound, bound)
-            else:
-                v = self.init_std * jax.random.normal(k, leaf.shape, F32)
-            out.append(v)
-        return jax.tree_util.tree_unflatten(treedef, out)
+                    k, shape, F32, math.log(1e-3), math.log(1e-1)))
+                return dt + jnp.log(-jnp.expm1(-dt))
+            if name.startswith("conv_"):
+                return jax.random.uniform(k, shape, F32, -bound, bound)
+            return self.init_std * jax.random.normal(k, shape, F32)
+
+        return lm_blocks.draw_tree(self.param_shapes(), key, value_of)
 
     # ------------------------------------------------------------ apply
 
@@ -217,37 +205,17 @@ class HybridLM:
         (ops/lowrank.py ``unpack``); ``None`` is the centre alone."""
         h = self.hidden(params, noise, c, tokens)
         table = params["embed"]["embedding"]
-        e_noise = None if noise is None else noise["embed"]["embedding"]
-        t = tokens.shape[0]
-        block = min(self.head_block, t)
-        n_blocks = -(-t // block)
-        pad = n_blocks * block - t
-        # the target of position t is token t+1; the last position has none
-        targets = jnp.pad(tokens[1:], (0, pad + 1))
-        hp = jnp.pad(h, ((0, pad), (0, 0)))
+        e_noise = subtree(noise, "embed", "embedding")
 
-        def score(xs):
-            h_b, tgt_b = xs
-            with stage(HEAD):
-                logits = perturbed_dense(
-                    h_b, table, e_noise, c, transposed=True
-                ) / self.logits_scaling
-                lse = jax.nn.logsumexp(logits, axis=-1)
-                picked = jnp.take_along_axis(
-                    logits, tgt_b[:, None], axis=-1)[:, 0]
-                return picked - lse
+        def tied_head(h_b):
+            return perturbed_dense(h_b, table, e_noise, c, transposed=True)
 
-        logp = jax.lax.map(score, (
-            hp.reshape(n_blocks, block, -1),
-            targets.reshape(n_blocks, block)))
-        with stage(HEAD):
-            last = perturbed_dense(h[-1:], table, e_noise, c,
-                                   transposed=True)[0] / self.logits_scaling
-        return logp.reshape(-1)[:t - 1], last
+        return lm_blocks.score_next_tokens(
+            h, tokens, tied_head, self.head_block, self.logits_scaling)
 
     def logits(self, params, tokens, noise=None, c=0.0):
         h = self.hidden(params, noise, c, tokens)
-        e_noise = None if noise is None else noise["embed"]["embedding"]
+        e_noise = subtree(noise, "embed", "embedding")
         with stage(HEAD):
             return perturbed_dense(
                 h, params["embed"]["embedding"], e_noise, c, transposed=True
@@ -256,15 +224,7 @@ class HybridLM:
     def hidden(self, params, noise, c, tokens):
         """Final-norm hidden states ``[T, hidden]`` in the compute dtype."""
         dtype = params["embed"]["embedding"].dtype
-
-        def nz(*path):
-            node = noise
-            for k in path:
-                if node is None:
-                    return None
-                node = node[k]
-            return node
-
+        nz = functools.partial(subtree, noise)
         x = self.embedding_multiplier * perturbed_embed(
             tokens, params["embed"]["embedding"], nz("embed", "embedding"), c)
         for i, kind in enumerate(self.layer_types):
@@ -288,62 +248,27 @@ class HybridLM:
 
     # ----------------------------------------------------------- layers
 
+    # the pieces shared with the looped model (models/lm_blocks.py); a
+    # subclass that replaces ``_dense`` changes every projection
+
     @staticmethod
     def _dense(p, noise, c, name, x):
-        with stage(DENSE):
-            return perturbed_dense(
-                x, p[name], None if noise is None else noise[name], c)
+        return lm_blocks.dense(p, noise, c, name, x)
 
     def _mlp(self, p, noise, c, u):
-        dtype = u.dtype
-        gate = self._dense(p, noise, c, "gate", u)
-        up = self._dense(p, noise, c, "up", u)
-        with stage(DENSE):
-            act = (jax.nn.silu(gate) * up).astype(dtype)
-        return self._dense(p, noise, c, "down", act)
+        return lm_blocks.gated_mlp(self._dense, p, noise, c, u)
 
     def _attention(self, p, noise, c, u):
-        """Causal attention with grouped heads, block-causal: query block
-        ``i`` is scored against the keys ``[0, end of block i)`` and no
-        others, so (n+1)/(2n) of the ``[T, T]`` score tiles of ``n`` blocks
-        are computed, and a masked score (``exp(-inf) = 0``) exists only
-        inside the diagonal tile.  The loop over blocks is unrolled: the
-        program grows with ``T / attention_block``, so a much longer
-        sequence should raise the block, not the count."""
-        dtype, t = u.dtype, u.shape[0]
-        nq, nkv, hd = (self.num_attention_heads, self.num_key_value_heads,
-                       self.head_dim)
+        """Block-causal attention with grouped heads and no positional
+        encoding (``lm_blocks.causal_attention``)."""
         scale = (self.attention_multiplier
                  if self.attention_multiplier is not None
-                 else 1.0 / math.sqrt(hd))
-        q = self._dense(p, noise, c, "q", u).astype(dtype)
-        k = self._dense(p, noise, c, "k", u).astype(dtype)
-        v = self._dense(p, noise, c, "v", u).astype(dtype)
-        # query head j reads key/value head j // (nq / nkv)
-        qh = q.reshape(t, nkv, nq // nkv, hd)
-        kh, vh = k.reshape(t, nkv, hd), v.reshape(t, nkv, hd)
-        block = min(self.attention_block, t)
-        ctx = []
-        for start in range(0, t, block):
-            stop = min(start + block, t)
-            q_b = qh[start:stop]
-            if ctx:
-                # one block at a time: left free, the TPU scheduler runs
-                # every block's softmax before the first P·V and holds all
-                # their float32 scores at once (T²/2 of them)
-                q_b, _ = jax.lax.optimization_barrier((q_b, ctx[-1]))
-            with stage(ATTN):
-                s = jnp.einsum("qkgd,skd->kgqs", q_b, kh[:stop],
-                               preferred_element_type=F32) * scale
-                mask = (jnp.arange(stop)[None, :]
-                        <= jnp.arange(start, stop)[:, None])
-                s = jnp.where(mask, s, -jnp.inf)
-                prob = jax.nn.softmax(s, axis=-1).astype(dtype)
-                ctx.append(jnp.einsum(
-                    "kgqs,skd->qkgd", prob, vh[:stop],
-                    preferred_element_type=F32).astype(dtype))
-        ctx = jnp.concatenate(ctx).reshape(t, nq * hd)
-        return self._dense(p, noise, c, "o", ctx)
+                 else 1.0 / math.sqrt(self.head_dim))
+        return lm_blocks.causal_attention(
+            self._dense, p, noise, c, u,
+            num_heads=self.num_attention_heads,
+            num_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+            scale=scale, block=self.attention_block)
 
     def _mamba(self, p, noise, c, u):
         dtype, t = u.dtype, u.shape[0]

@@ -35,7 +35,7 @@ SCOPE_PREFIX = "es."
 
 # the stages of one generation, in program order (docs/observability.md)
 STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
-          DENSE, SSM, ATTN, HEAD) = (
+          DENSE, SSM, ATTN, HEAD, ROPE, EXIT) = (
     "sample",    # offsets, signs, member keys
     "noise",     # reading eps: the table gather and the slab it builds
     "perturb",   # theta + sigma * sign * eps, unravel, cast; the rank-r
@@ -46,11 +46,15 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "rank",      # centered ranks
     "grad",      # the second pass over the noise, the weighted sum
     "update",    # weight decay, optax step, sigma decay, obs-norm probe
-    # nested inside es.policy by a sequence model (models/hybrid_lm.py)
+    # nested inside es.policy by a sequence model (models/hybrid_lm.py,
+    # models/looped_lm.py on the pieces of models/lm_blocks.py)
     "dense",     # the shared x@W projections and the gated FFN
     "ssm",       # conv1d, dt and decay, the chunked scan, the gated norm
     "attn",      # scores, softmax, P.V
-    "head",      # tied-embedding logits, log-softmax, the score
+    "head",      # the logits (tied or not), log-softmax, the score
+    "rope",      # rotary positions: cos/sin, rotating queries and keys
+    "exit",      # a looped model's exit gate, the exit distribution and
+                 # the weighting of the per-pass scores
 )
 
 

@@ -116,12 +116,14 @@ def padded_count(n: int, n_shards: int) -> int:
 # everything else replicates.  The trailing catch-all makes the defaults
 # total over ANY tree; strict user rule sets omit it and get the
 # unmatched-leaf error instead.
-# The sequence model's leaves (models/hybrid_lm.py) come first.
-# Projections are column- then row-parallel in pairs (in_z/in_x/in_dt →
-# out_proj, gate/up → down, q/k/v → o), so one all-reduce closes each
-# pair; Mamba heads, their conv channels, dt, A_log, D and the gated norm
-# go by head; the embedding by vocabulary row; B and C (one group, read by
-# every head) and the block norms replicate.
+# The sequence models' leaves (models/hybrid_lm.py, models/looped_lm.py)
+# come first.  Projections are column- then row-parallel in pairs
+# (in_z/in_x/in_dt → out_proj, gate/up → down, q/k/v → o), so one
+# all-reduce closes each pair; Mamba heads, their conv channels, dt,
+# A_log, D and the gated norm go by head; the embedding by vocabulary row
+# and an untied head by vocabulary column; B and C (one group, read by
+# every head), the block norms and a looped model's exit gate (one
+# column) replicate.
 HYBRID_LM_PARTITION_RULES = (
     (r"embed/embedding$", P(MODEL_AXIS, None)),
     (r"mamba/(in_z|in_x|in_dt)$", P(None, MODEL_AXIS)),
@@ -133,7 +135,9 @@ HYBRID_LM_PARTITION_RULES = (
     (r"attn/o$", P(MODEL_AXIS, None)),
     (r"mlp/(gate|up)$", P(None, MODEL_AXIS)),
     (r"mlp/down$", P(MODEL_AXIS, None)),
-    (r"(norm1|norm2|final_norm)/scale$", P()),
+    (r"(norm[1-4]|final_norm)/scale$", P()),
+    (r"head/kernel$", P(None, MODEL_AXIS)),
+    (r"exit_gate/(kernel|bias)$", P()),
 )
 
 CATCH_ALL = r".*"

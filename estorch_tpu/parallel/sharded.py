@@ -82,11 +82,13 @@ from ..ops.ranks import centered_rank_safe
 from .engine import (EngineConfig, _bf16_io_apply, _bf16_obs,
                      _choose_eval_chunk, _gen_keys)
 from .mesh import (DEFAULT_PARTITION_RULES, MODEL_AXIS, POP_AXIS,
-                   match_partition_rules, padded_count, sharding_summary)
+                   _leaf_path_name, match_partition_rules, padded_count,
+                   sharding_summary)
 
 NOISE_MODES = ("program", "table")
-# perturbed form: a chunk's widest activation ([chunk·horizon, widest
-# projection] float32, a device's share) is held under this many bytes
+# perturbed form: a chunk's widest activation (float32 [chunk · positions a
+# leaf is applied to at once, the leaf's output width], a device's share)
+# is held under this many bytes
 ACTIVATION_BUDGET_BYTES = 256 * 2**20
 
 
@@ -156,6 +158,7 @@ class ShardedESEngine:
         noise_mode: str = "program",
         perturbed_apply: Callable[..., Any] | None = None,
         lowrank_spec=None,
+        leaf_rows: dict[str, int] | None = None,
     ):
         if config.obs_norm:
             raise ValueError(
@@ -207,6 +210,9 @@ class ShardedESEngine:
             else "materialised")
         self.lr_spec = lowrank_spec if self.forward_form == "perturbed" else None
         self._perturbed_apply = perturbed_apply
+        # {leaf path: positions per application} of the leaves the policy
+        # runs in blocks of positions (a sequence model's untied head)
+        self._leaf_rows = dict(leaf_rows or {})
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
         self.n_devices = int(mesh.devices.size)
@@ -219,6 +225,9 @@ class ShardedESEngine:
         params_shape = jax.eval_shape(
             spec.unravel, jax.ShapeDtypeStruct((spec.dim,), jnp.float32))
         leaves, self._treedef = jax.tree_util.tree_flatten(params_shape)
+        self.leaf_paths = [
+            _leaf_path_name(path) for path, _ in
+            jax.tree_util.tree_flatten_with_path(params_shape)[0]]
         self.leaf_shapes = [tuple(int(d) for d in l.shape) for l in leaves]
         import math
 
@@ -339,6 +348,14 @@ class ShardedESEngine:
                 out_shardings=(self.state_shardings, metrics_shardings),
             )
         self._compiled_facts: dict | None = None
+        # a best member that replaces an older one is copied INTO the older
+        # one's (donated) buffers; ``keep_unused``: the donated tree is read
+        # by nothing, and pruned it would alias nothing
+        self._copy_into = jax.jit(
+            lambda new, old: jax.tree_util.tree_map(jnp.copy, new),
+            donate_argnums=(1,), keep_unused=True,
+            out_shardings=self.param_shardings)
+        self._copy_into_compiled = None
 
     # ------------------------------------------------------------- noise
 
@@ -427,11 +444,24 @@ class ShardedESEngine:
         res = jax.vmap(self._rollout, in_axes=(0, 0))(theta, keys)
         return res.total_reward, res.bc, res.steps
 
+    def _widest_activation(self) -> int:
+        """Floats of the widest activation ONE member holds on a device:
+        over the factored leaves, the positions the leaf is applied to at
+        once times its output width over ``model``.  A leaf sees the whole
+        horizon unless the policy runs it in blocks of positions
+        (``leaf_rows``: an untied head is ``[head_block, vocab]``, never
+        ``[horizon, vocab]``)."""
+        horizon = self.config.horizon
+        return max([
+            min(horizon, self._leaf_rows.get(self.leaf_paths[i], horizon))
+            * -(-n // self.model_shards)
+            for i, _, n, _, _ in self.lr_spec.lr_leaves] or [1])
+
     def _size_pair_chunks(self):
         """Perturbed form: antithetic pairs (unmirrored: members) per
         evaluation chunk.  ``eval_chunk`` members when the caller set it;
         otherwise as many as keep a device's share of the chunk's widest
-        activation, ``[chunk·horizon, widest projection]`` float32, under
+        activation (:meth:`_widest_activation`, float32) under
         ``ACTIVATION_BUDGET_BYTES``.  A chunk holds whole pairs and a
         multiple of ``pop_shards`` rows."""
         cfg = self.config
@@ -440,9 +470,7 @@ class ShardedESEngine:
         if cfg.eval_chunk > 0:
             req = max(1, cfg.eval_chunk // (per_row * self.pop_shards))
         else:
-            widest = max([n for _, _, n, _, _ in self.lr_spec.lr_leaves]
-                         or [1])
-            per_member = 4 * cfg.horizon * -(-widest // self.model_shards)
+            per_member = 4 * self._widest_activation()
             req = max(1, ACTIVATION_BUDGET_BYTES // (per_member * per_row))
         rows_chunk_per_shard = _choose_eval_chunk(req, rows_per_shard)
         self.pair_chunk = rows_chunk_per_shard * self.pop_shards
@@ -805,7 +833,24 @@ class ShardedESEngine:
         self._compiled_facts = compiled_cost_facts(compiled)
         self.telemetry.compile_event("generation_step_sharded", dt,
                                      compiled=compiled, first_call=True)
+        # built here, called as built: the first best member that replaces
+        # another may come generations later, and nothing is to compile then
+        self._copy_into_compiled = self._copy_into.lower(
+            state.params, state.params).compile()
         return dt
+
+    def keep_best(self, best_theta, held=None):
+        """The tree to hold on the mesh for a generation's best member
+        (``metrics["best_theta"]``).  The first is the program's own output.
+        One that replaces an older tree ``held`` is copied into ``held``'s
+        buffers, so that the held tree and the program's next output both
+        stay where they were: on a chip nearly full of state a generation's
+        time depends on where its param-sized output lands (two levels 1.6%
+        apart, flipping at every new best: PERF.md §6, PR 31)."""
+        if held is None:
+            return best_theta
+        copy = self._copy_into_compiled or self._copy_into
+        return copy(best_theta, held)
 
     @property
     def param_bytes_per_chip(self) -> int:

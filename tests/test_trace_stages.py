@@ -17,13 +17,13 @@ import pytest
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
 from estorch_tpu.obs.spans import Telemetry
-from estorch_tpu.obs.trace import (ATTN, DENSE, ENV, GATHER, GRAD, HEAD, NOISE,
-                                   PERTURB, POLICY, RANK, SAMPLE,
+from estorch_tpu.obs.trace import (ATTN, DENSE, ENV, EXIT, GATHER, GRAD, HEAD,
+                                   NOISE, PERTURB, POLICY, RANK, ROPE, SAMPLE,
                                    SCOPE_PREFIX, SSM, STAGES, UPDATE,
                                    annotate, stage, trace)
 
-# the stages of every generation program; a sequence model nests four more
-# inside es.policy (DENSE, SSM, ATTN, HEAD)
+# the stages of every generation program; a sequence model nests more
+# inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT)
 GENERATION_STAGES = STAGES[:9]
 
 SCOPE = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(SCOPE_PREFIX)
@@ -116,7 +116,10 @@ def test_sequence_model_names_its_layers_inside_the_policy_stage(
         es.state, engine.table.data).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     found = {s for name in names for s in SCOPE.findall(name)}
-    assert found == set(STAGES), (set(STAGES) - found, found - set(STAGES))
+    # every stage but the looped model's two (it rotates nothing, exits
+    # nowhere)
+    want = set(STAGES) - {ROPE, EXIT}
+    assert found == want, (want - found, found - want)
     for inner in (DENSE, SSM, ATTN, HEAD):
         stacks = [SCOPE.findall(n) for n in names
                   if SCOPE_PREFIX + inner in n]
@@ -133,6 +136,48 @@ def test_sequence_model_names_its_layers_inside_the_policy_stage(
     assert any(st[-1] == HEAD for st in matmuls)
     assert any(st[-1] == PERTURB and DENSE in st
                for name in names for st in [SCOPE.findall(name)] if st)
+
+
+def test_looped_model_names_its_layers_inside_the_policy_stage(
+        keyed_by_source):
+    """The sharded engine's perturbed form on a LoopedLM, one device: every
+    stage of a generation, es.dense / es.attn / es.head from the pieces it
+    shares with HybridLM and its own es.rope / es.exit, nested inside
+    es.policy; no es.ssm.  The gauges say how deep the loop is."""
+    import loop_tiny
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import LoopedLM
+
+    es = ES(policy=LoopedLM, agent=JaxAgent, optimizer=optax.adam,
+            population_size=8, sigma=0.02, policy_kwargs=loop_tiny.TINY,
+            agent_kwargs={"env": TokenScoreEnv(**loop_tiny.ENV)},
+            optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
+            model_shards=1, low_rank=1, noise_mode="table",
+            table_size=1 << 18, device=jax.devices()[:1])
+    engine = es.engine
+    text = engine._generation_step.lower(
+        es.state, engine.table.data).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    found = {s for name in names for s in SCOPE.findall(name)}
+    want = set(STAGES) - {SSM}
+    assert found == want, (want - found, found - want)
+    for inner in (DENSE, ATTN, HEAD, ROPE, EXIT):
+        stacks = [SCOPE.findall(n) for n in names
+                  if SCOPE_PREFIX + inner in n]
+        nested = [st for st in stacks if POLICY in st]
+        assert len(nested) > len(stacks) // 2, inner
+        assert all(st.index(POLICY) < st.index(inner) for st in nested), inner
+    matmuls = [SCOPE.findall(m) for line in text.splitlines()
+               for m in MATMUL.findall(line)]
+    assert any(st[-1] == DENSE for st in matmuls)
+    assert any(st[-1] == HEAD for st in matmuls)
+    # the rotation's sines and cosines, and the gate's sigmoid (its exp)
+    assert any(SCOPE.findall(n)[-1:] == [ROPE] and ("sin" in n or "cos" in n)
+               for n in names)
+    assert any(SCOPE.findall(n)[-1:] == [EXIT] and n.endswith("exp")
+               for n in names)
+    assert es.obs.counters.get("loop_steps") == 4
+    assert es.obs.counters.get("layer_applications_per_token") == 8
 
 
 # ---- compiled for a described TPU v5e: Mosaic runs, nothing executes ----
@@ -263,9 +308,9 @@ def test_row_kernels_compile_for_the_v5e_at_the_cells_widths(
 
 @pytest.mark.parametrize("use", ["context", "decorator"])
 def test_stage_scopes_a_name_stack(use):
-    assert len(set(STAGES)) == len(STAGES) == 13
+    assert len(set(STAGES)) == len(STAGES) == 15
     assert (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD,
-            UPDATE, DENSE, SSM, ATTN, HEAD) == STAGES
+            UPDATE, DENSE, SSM, ATTN, HEAD, ROPE, EXIT) == STAGES
     if use == "context":
         def f(x):
             with stage(NOISE):
