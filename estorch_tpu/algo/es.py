@@ -329,6 +329,9 @@ class ES:
                 noise_mode=self._noise_mode,
                 perturbed_apply=lr_apply, lowrank_spec=lr_spec,
                 leaf_rows=getattr(self.module, "leaf_rows", None),
+                # a sequence model's heads (models/lm_blocks.py); None
+                # for a policy without attention
+                attention_head_dim=getattr(self.module, "head_dim", None),
             )
             # the whole flat vector leaves the device before the sharded
             # state is placed from it, a leaf at a time: a tree this
@@ -518,6 +521,10 @@ class ES:
             # "dma" says the row kernels of ops/pallas_noise.py engaged
             self.obs.counters.gauge("noise_gather_form",
                                     self.engine.noise_gather_form)
+        if getattr(self.engine, "attention_form", None) is not None:
+            # "kernel" says ops/pallas_attention.py engaged
+            self.obs.counters.gauge("attention_form",
+                                    self.engine.attention_form)
         if self._shard_params:
             self.obs.counters.gauge("mesh_shape", "x".join(
                 str(n) for n in self.mesh.devices.shape))
@@ -1144,6 +1151,9 @@ class ES:
             # an engine with no replicated-table gather of its own)
             "noise_gather_form": getattr(
                 self.engine, "noise_gather_form", None),
+            # which form the policy's causal attention takes ("kernel" |
+            # "xla"; None: a policy without one, or the replicated engine)
+            "attention_form": getattr(self.engine, "attention_form", None),
             "shard_params": self._shard_params,
             **self._sequence_facts(),
         }

@@ -1,8 +1,29 @@
 """The pieces both sequence models are built from (models/hybrid_lm.py,
-models/looped_lm.py): ONE RMSNorm, ONE gated SiLU FFN, ONE block-causal
+models/looped_lm.py): ONE RMSNorm, ONE gated SiLU FFN, ONE causal
 attention and ONE blocked next-token scorer, each on the perturbed-dense
 primitive (models/perturbed.py), so that an optimisation of one is
 measured on both models.
+
+The attention's core (rotated q, k, v -> context) has TWO forms of one
+algorithm, same mathematics, same tiles, same precision:
+
+- ``"xla"``: block-causal einsums and a softmax, whose float32 score
+  tiles XLA holds in HBM.  It runs anywhere: the CPU path, every test's
+  oracle, and what a mesh of several devices takes.
+- ``"kernel"``: ops/pallas_attention.py, an online softmax whose scores
+  never leave VMEM.
+
+Which one a program takes is not an option of a model or of ``ES``: the
+ENGINE resolves it once at build from what it observes
+(``ShardedESEngine.attention_form``, by the one rule
+``ops.pallas_attention.attention_form``: TPU devices, ONE device on the
+mesh so the operands are whole on it, ``head_dim % 128 == 0``, the
+sequence a whole number of the kernel's blocks) and opens
+``pallas_attention.kernel_scope`` around its trace of the policy.
+:func:`causal_attention` takes the kernel inside that scope and the XLA
+form everywhere else, so ``apply`` outside an engine is the XLA form.
+``ES`` hands the engine the model's ``head_dim``, as it hands it
+``leaf_rows``.
 
 Functions, not a base class: a model hands in its own ``dense`` (the
 ``(p, noise, c, name, x) -> x @ (p[name] + c·noise[name])`` of the class,
@@ -21,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import ATTN, DENSE, HEAD, ROPE, stage
+from ..ops import pallas_attention
 from .perturbed import F32, perturbed_dense
 
 
@@ -96,11 +118,16 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
     ``i`` is scored against the keys ``[0, end of block i)`` and no
     others, so (n+1)/(2n) of the ``[T, T]`` score tiles of ``n`` blocks
     are computed, and a masked score (``exp(-inf) = 0``) exists only
-    inside the diagonal tile.  The loop over blocks is unrolled: the
-    program grows with ``T / block``, so a much longer sequence should
-    raise the block, not the count.  ``rotary``: ``(cos, sin)`` of
+    inside the diagonal tile.  ``rotary``: ``(cos, sin)`` of
     :func:`rotary_tables`, applied to queries and keys; ``None``: no
-    positional encoding."""
+    positional encoding.
+
+    Inside an engine's ``pallas_attention.kernel_scope`` the core is the
+    Pallas kernel (its own blocks, scores in VMEM); anywhere else the XLA
+    form below, in blocks of ``block`` (the module's text has the rule).
+    The XLA form's loop over blocks is unrolled: the program grows with
+    ``T / block``, so a much longer sequence should raise the block, not
+    the count."""
     dtype, t = u.dtype, u.shape[0]
     nq, nkv, hd = num_heads, num_kv_heads, head_dim
 
@@ -113,6 +140,14 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
     q = rotated(dense(p, noise, c, "q", u), nq).astype(dtype)
     k = rotated(dense(p, noise, c, "k", u), nkv).astype(dtype)
     v = dense(p, noise, c, "v", u).astype(dtype)
+    interpret = pallas_attention.scoped_interpret()
+    if interpret is not None:
+        with stage(ATTN):
+            ctx = pallas_attention.causal_attention(
+                q.reshape(t, nq * hd), k.reshape(t, nkv * hd), v,
+                num_heads=nq, num_kv_heads=nkv, head_dim=hd, scale=scale,
+                interpret=interpret)
+        return dense(p, noise, c, "o", ctx)
     # query head j reads key/value head j // (nq / nkv)
     qh = q.reshape(t, nkv, nq // nkv, hd)
     kh, vh = k.reshape(t, nkv, hd), v.reshape(t, nkv, hd)

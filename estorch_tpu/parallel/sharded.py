@@ -77,6 +77,7 @@ from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
                            lowrank_tree_noise, lowrank_tree_weighted_sum)
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
+from ..ops.pallas_attention import attention_form, kernel_scope
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
 from .engine import (EngineConfig, _bf16_io_apply, _bf16_obs,
@@ -159,6 +160,7 @@ class ShardedESEngine:
         perturbed_apply: Callable[..., Any] | None = None,
         lowrank_spec=None,
         leaf_rows: dict[str, int] | None = None,
+        attention_head_dim: int | None = None,
     ):
         if config.obs_norm:
             raise ValueError(
@@ -213,6 +215,17 @@ class ShardedESEngine:
         # {leaf path: positions per application} of the leaves the policy
         # runs in blocks of positions (a sequence model's untied head)
         self._leaf_rows = dict(leaf_rows or {})
+        # the Pallas kernels compile through Mosaic on the chip this mesh
+        # is made of; anywhere else only the interpreter can run them
+        self._pallas_interpret = mesh.devices.flat[0].platform != "tpu"
+        # "kernel" | "xla": which form the policy's causal attention takes
+        # in this engine's programs (models/lm_blocks.py has the two forms);
+        # None for a policy that has none.  Resolved once, here, from the
+        # mesh, the sequence length and the policy's head size (run
+        # manifest + telemetry gauge)
+        self.attention_form = (
+            None if attention_head_dim is None
+            else self._resolve_attention_form(attention_head_dim))
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
         self.n_devices = int(mesh.devices.size)
@@ -315,11 +328,12 @@ class ShardedESEngine:
                 return jax.tree_util.tree_map(
                     lambda o: o.astype(jnp.float32), out)
 
-            self._rollout = make_rollout(env, packed_apply, cfg.horizon)
+            self._rollout = self._in_attention_form(
+                make_rollout(env, packed_apply, cfg.horizon))
         else:
-            self._rollout = make_rollout(
+            self._rollout = self._in_attention_form(make_rollout(
                 env, _bf16_io_apply(policy_apply) if bf16 else policy_apply,
-                cfg.horizon)
+                cfg.horizon))
 
         # metrics shardings: scalars/vectors replicated, the in-program
         # best-member tree sharded exactly like the params it perturbs
@@ -356,6 +370,25 @@ class ShardedESEngine:
             donate_argnums=(1,), keep_unused=True,
             out_shardings=self.param_shardings)
         self._copy_into_compiled = None
+
+    def _resolve_attention_form(self, head_dim: int) -> str:
+        """``"kernel"`` or ``"xla"``: see ``attention_form``."""
+        return attention_form(
+            self.mesh.devices.flat[0].platform, int(self.mesh.devices.size),
+            head_dim, self.config.horizon)
+
+    def _in_attention_form(self, rollout):
+        """``rollout`` traced in this engine's attention form: the policy's
+        ``causal_attention`` learns of the kernel by the scope that is open
+        while it is traced, and takes the XLA form with none."""
+        if self.attention_form != "kernel":
+            return rollout
+
+        def scoped(*args):
+            with kernel_scope(self._pallas_interpret):
+                return rollout(*args)
+
+        return scoped
 
     # ------------------------------------------------------------- noise
 

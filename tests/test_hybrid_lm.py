@@ -278,6 +278,42 @@ def test_each_query_block_is_scored_against_its_prefix_only():
     assert sum(block * s for s in scored) * 64 == 36 * length * length
 
 
+def test_outputs_are_what_they_were_before_the_kernel_existed():
+    """``apply`` outside an engine is the XLA form: these numbers were
+    printed by the tree before ops/pallas_attention.py (PR 31's) and by
+    this one, equal to every digit."""
+    lm = HybridLM(**lm_tiny.TINY)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (21,), 0, 64)
+    variables = jax.tree_util.tree_map(
+        lambda x: x * 10.0 if x.ndim == 2 else x,
+        lm.init(jax.random.PRNGKey(0), tokens))
+    score, last = lm.apply(variables, tokens)
+    np.testing.assert_allclose(
+        score[:4], [-3.970382, -4.0645003, -4.1236715, -4.1806355],
+        rtol=2e-6)
+    np.testing.assert_allclose(
+        last[:4], [-0.08525772, -0.009714369, -0.13818261, -0.20358337],
+        rtol=2e-5)
+    np.testing.assert_allclose(float(score.sum()), -83.3136215209961,
+                               rtol=2e-6)
+
+
+@pytest.mark.parametrize("length", [21, 16])
+def test_forced_through_the_interpreted_kernel_it_agrees(tiny, length):
+    """Inside a ``kernel_scope`` the one attention layer runs the Pallas
+    kernel (interpreted here; no rotary, grouped heads, the model's own
+    ``attention_multiplier`` as the scale) and the outputs agree with the
+    XLA form's to the order of float32 sums."""
+    from estorch_tpu.ops.pallas_attention import kernel_scope
+
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (length,), 0, 64)
+    want = tiny["lm"].apply({"params": tiny["params"]}, tokens)
+    with kernel_scope(interpret=True):
+        got = tiny["lm"].apply({"params": tiny["params"]}, tokens)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-4, rtol=0)
+
+
 def test_init_draws_the_declared_tree(tiny):
     lm = tiny["lm"]
     params = lm.init(jax.random.PRNGKey(0), None)["params"]

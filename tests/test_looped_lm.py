@@ -108,6 +108,57 @@ def test_apply_is_the_centre_alone(tiny):
         np.testing.assert_array_equal(g, w)
 
 
+def _spread_apply(lm):
+    """``apply`` of seeded weights at ten times their initial spread (so
+    that attention's softmax is not flat) on 21 seeded tokens."""
+    tokens = _tokens(21)
+    variables = jax.tree_util.tree_map(
+        lambda x: x * 10.0 if x.ndim == 2 else x,
+        lm.init(jax.random.PRNGKey(0), tokens))
+    return variables, tokens, lm.apply(variables, tokens)
+
+
+def test_outputs_are_what_they_were_before_the_kernel_existed():
+    """``apply`` outside an engine is the XLA form: these numbers were
+    printed by the tree before ops/pallas_attention.py (PR 31's) and by
+    this one, equal to every digit."""
+    _, _, (score, last) = _spread_apply(LoopedLM(**loop_tiny.TINY))
+    np.testing.assert_allclose(
+        score[:4], [-3.7878175, -5.580817, -4.9464254, -5.6830497],
+        rtol=2e-6)
+    np.testing.assert_allclose(
+        last[:4], [-0.1570187, -1.9164678, -0.34257308, 0.24097651],
+        rtol=2e-5)
+    np.testing.assert_allclose(float(score.sum()), -96.71588134765625,
+                               rtol=2e-6)
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, TOL),
+                                        (jnp.bfloat16, 0.1)])
+@pytest.mark.parametrize("length", [21, 16])
+def test_forced_through_the_interpreted_kernel_it_agrees(tiny, dtype, tol,
+                                                         length):
+    """The whole model, both forms of its attention: inside a
+    ``kernel_scope`` every layer-application of every pass runs the Pallas
+    kernel (interpreted here), and scores and logits agree with the XLA
+    form's to the order of float32 sums, or to bfloat16's rounding of the
+    probabilities where the operands are bfloat16 (a score is a log p of
+    about -4, a logit about 1)."""
+    from estorch_tpu.ops.pallas_attention import kernel_scope
+
+    tokens = _tokens(length, 4)
+    params = jax.tree_util.tree_map(lambda x: x.astype(dtype),
+                                    tiny["params"])
+    factors = tiny["spec"].unpack(tiny["noise"])
+    want = tiny["lm"].perturbed_apply(params, factors, 0.05, tokens)
+    with kernel_scope(interpret=True):
+        got = tiny["lm"].perturbed_apply(params, factors, 0.05, tokens)
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.float32 and bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, atol=tol, rtol=0)
+    assert float(jnp.abs(got[0] - want[0]).max()) > 0.0  # another program
+
+
 # ------------------------------------- (b) every leaf's correction, 4 uses
 
 LEAVES = [path for path, _ in loop_tiny.reference().system_layout(

@@ -186,10 +186,17 @@ def test_looped_model_names_its_layers_inside_the_policy_stage(
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One chip of a described v5e 2x2: a device to compile for, with no
-    chip attached.  The persistent compile cache is off meanwhile (an entry
-    written for a TPU cannot be read back here and warns at every read)."""
+def v5e_chip(v5e_2x2):
+    """One chip of the described v5e 2x2."""
+    return v5e_2x2[0]
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    """The four chips of a described v5e 2x2: devices to compile for, with
+    no chip attached.  The persistent compile cache is off meanwhile (an
+    entry written for a TPU cannot be read back here and warns at every
+    read)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -202,7 +209,7 @@ def v5e_chip():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield topo.devices[0]
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
 
@@ -304,6 +311,101 @@ def test_row_kernels_compile_for_the_v5e_at_the_cells_widths(
             t, o, dim=dim, dtype=dtype, interpret=False)).lower(
                 table, offs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_attention_kernel_compiles_for_the_v5e_at_the_looped_cells_shapes(
+        dtype, v5e_chip):
+    """Mosaic accepts the attention kernel at ``ouro-2.6b-es-4k-1chip``'s
+    shapes (16 heads of 128 as column blocks of a [4096, 2048] array, the
+    kernel's own blocks, one pair's two signs through ``vmap``), with
+    nothing else in the program: no score tensor, no copy."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import causal_attention
+
+    operand = jax.ShapeDtypeStruct(
+        (1, 2, 4096, 16 * 128), dtype,
+        sharding=SingleDeviceSharding(v5e_chip))
+    text = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
+        q, k, v, num_heads=16, num_kv_heads=16, head_dim=128,
+        scale=128 ** -0.5, interpret=False)))).lower(
+            operand, operand, operand).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "f32[" not in text.split("ENTRY")[1] or dtype == jnp.float32
+
+
+def _looped_engine_on(devices, model_shards, head_dim, length):
+    """A small looped model's sharded engine on a mesh of described TPU
+    ``devices``: its pieces from an ES built on the CPU, as the engine of
+    a chip run would get them."""
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import LoopedLM
+    from estorch_tpu.parallel.mesh import hyperscale_mesh
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    es = _es(
+        policy=LoopedLM, population_size=4, sigma=0.02,
+        policy_kwargs=dict(
+            layer_types=("full_attention",), vocab_size=256, hidden_size=128,
+            intermediate_size=256, num_attention_heads=2,
+            num_key_value_heads=1, head_dim=head_dim, total_ut_steps=2,
+            attention_block=128, head_block=128),
+        agent_kwargs={"env": TokenScoreEnv(
+            vocab_size=256, seq_len=length, corpus_sequences=4)},
+        shard_params=True, low_rank=1, noise_mode="table",
+        compute_dtype="bfloat16", table_size=1 << 18,
+        device=jax.devices()[:1])
+    assert es.engine.attention_form == "xla"  # a CPU mesh
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    engine = ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=model_shards, devices=devices),
+        partition_rules=es._partition_rules, noise_mode="table",
+        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        leaf_rows=es.module.leaf_rows,
+        attention_head_dim=es.module.head_dim)
+    return es, engine
+
+
+@pytest.mark.parametrize("n_devices, model_shards, head_dim, length, form", [
+    (1, 1, 128, 256, "kernel"),
+    (1, 1, 64, 256, "xla"),     # granite's head: half a lane tile
+    (1, 1, 128, 200, "xla"),    # no block of the kernel divides it
+    (4, 2, 128, 256, "xla"),    # granite's mesh: operands not whole
+    (4, 1, 128, 256, "xla"),
+])
+def test_attention_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim,
+                                      length, form, v5e_2x2):
+    """``ShardedESEngine.attention_form`` on meshes of TPU devices, each
+    arm of the rule (devices on the mesh, ``head_dim``, the sequence) —
+    what a chip run resolves, with nothing compiled."""
+    _, engine = _looped_engine_on(v5e_2x2[:n_devices], model_shards,
+                                  head_dim, length)
+    assert engine.attention_form == form
+
+
+def test_kernel_form_books_its_kernel_to_attn(v5e_chip):
+    """On a one-device TPU mesh the engine takes the attention kernel by
+    itself, and the Mosaic custom call of the compiled generation program
+    sits under es.attn inside es.policy, where the XLA form's score
+    fusions were: the device trace books it to ``loop.attn_share``."""
+    es, engine = _looped_engine_on([v5e_chip], 1, 128, 256)
+    assert engine.attention_form == "kernel"
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        es.state, engine.state_shardings)
+    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
+                                 sharding=engine._repl)
+    text = engine._generation_step.lower(state, table).compile().as_text()
+    kernels = [(name, SCOPE.findall(name)) for line in text.splitlines()
+               if "tpu_custom_call" in line
+               for name in re.findall(r'op_name="([^"]*)"', line)]
+    assert kernels and all(
+        stack[-2:] == [POLICY, ATTN] and "causal_attention" in name
+        for name, stack in kernels), kernels
 
 
 @pytest.mark.parametrize("use", ["context", "decorator"])
