@@ -329,9 +329,16 @@ class ES:
                 noise_mode=self._noise_mode,
                 perturbed_apply=lr_apply, lowrank_spec=lr_spec,
                 leaf_rows=getattr(self.module, "leaf_rows", None),
-                # a sequence model's heads (models/lm_blocks.py); None
-                # for a policy without attention
-                attention_head_dim=getattr(self.module, "head_dim", None),
+                # the width a sequence model's heads are SCORED at, which
+                # the attention form's rule reads (models/lm_blocks.py);
+                # None for a policy without attention
+                attention_head_dim=getattr(self.module, "qk_head_dim", None),
+                # a sparse-expert model (models/moe_lm.py): what its stacked
+                # leaves see of a sequence, what stays float32, its load
+                leaf_rows_per_token=getattr(
+                    self.module, "leaf_rows_per_token", None),
+                float32_leaves=getattr(self.module, "float32_leaves", ()),
+                expert_load=hasattr(self.module, "stacked_leaves"),
             )
             # the whole flat vector leaves the device before the sharded
             # state is placed from it, a leaf at a time: a tree this
@@ -400,6 +407,13 @@ class ES:
             facts["loop_steps"] = int(steps)
             facts["layer_applications_per_token"] = (
                 int(steps) * len(self.module.layer_types))
+        if hasattr(self.module, "experts_total"):
+            # a sparse-expert model (models/moe_lm.py)
+            facts.update(
+                experts_held=int(self.module.n_routed_experts),
+                experts_total=int(self.module.experts_total),
+                experts_per_token=int(self.module.num_experts_per_tok),
+                mtp_depth=int(self.module.num_nextn_predict_layers))
         return facts
 
     def _perturbed_form(self, flat):
@@ -799,6 +813,13 @@ class ES:
                 metrics=metrics if self._shard_params else None,
             )
             self._attach_scenarios(record, fitness, metrics)
+            if "expert_load" in metrics:
+                # (token, k) pairs that landed on each held expert over
+                # the population's expert layers (models/moe_lm.py)
+                load = np.asarray(metrics["expert_load"], np.float64)
+                record["routed_pairs"] = int(load.sum())
+                record["expert_load_max_over_mean"] = float(
+                    load.max() / max(load.mean(), 1e-12))
             self._emit_record(record, log_fn, verbose)
             done += 1
             # the sharded program's best member is param-sized: let go of

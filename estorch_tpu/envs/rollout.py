@@ -51,6 +51,9 @@ class RolloutResult(NamedTuple):
     total_reward: jax.Array  # () float32 — the episode return (fitness)
     bc: jax.Array  # (bc_dim,) float32 — behavior characterization
     steps: jax.Array  # () int32 — alive steps actually taken
+    # what a whole-episode policy returns beyond what its env scores (a
+    # sparse-expert model's pairs per held expert); None for every other
+    extras: Any = None
 
 
 def select_action(policy_out: jax.Array, discrete: bool) -> jax.Array:
@@ -208,7 +211,8 @@ def _make_whole_episode_rollout(env, policy_apply, carry_init):
             out = policy_apply(params, obs)
         with stage(ENV):
             total, bc, steps = env.score(state, obs, out)
-        return RolloutResult(total_reward=total, bc=bc, steps=steps)
+        return RolloutResult(total_reward=total, bc=bc, steps=steps,
+                             extras=tuple(out[2:]) or None)
 
     return rollout
 

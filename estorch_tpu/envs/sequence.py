@@ -6,7 +6,9 @@ models (PAPERS.md, arXiv 2511.16652 and 2509.24372).  There is no step to
 scan: the env hands the policy a whole sequence and scores what comes back
 in one call, which ``envs/rollout.py::make_rollout`` recognises by
 ``whole_episode``.  The policy (models/hybrid_lm.py) returns ``(log p of
-each next token [T-1], the last position's logits [vocab])``.
+each next token [T-1], the last position's logits [vocab])``; what a model
+returns after those two (models/moe_lm.py: its experts' load) the rollout
+passes on as ``RolloutResult.extras``.
 """
 
 from __future__ import annotations
@@ -76,7 +78,7 @@ class TokenScoreEnv:
     def score(self, state, tokens, policy_out):
         """``(fitness, behaviour, steps)`` of one whole episode."""
         del state, tokens
-        next_logp, last_logits = policy_out
+        next_logp, last_logits = policy_out[:2]
         return (jnp.mean(next_logp.astype(jnp.float32)),
                 jnp.take(last_logits, self.probe_ids()).astype(jnp.float32),
                 jnp.int32(self.seq_len))

@@ -1,5 +1,6 @@
 from .hybrid_lm import HybridLM
 from .looped_lm import LoopedLM
+from .moe_lm import MoELM
 from .policies import MLPPolicy, NatureCNN, RecurrentNatureCNN, RecurrentPolicy
 from .vbn import VirtualBatchNorm, capture_reference_stats
 
@@ -21,6 +22,7 @@ __all__ = [
     "HybridLM",
     "LoopedLM",
     "MLPPolicy",
+    "MoELM",
     "NatureCNN",
     "RecurrentNatureCNN",
     "TorchRunningObsNorm",

@@ -124,6 +124,12 @@ class HybridLM:
         return (self.attention_head_dim
                 or self.hidden_size // self.num_attention_heads)
 
+    @property
+    def qk_head_dim(self) -> int:
+        """A head's width where it is scored (the attention form's rule
+        reads it, ops/pallas_attention.py)."""
+        return self.head_dim
+
     def param_shapes(self) -> dict:
         """The parameter tree as shapes (float32)."""
         h, n, k = self.hidden_size, self.mamba_d_state, self.mamba_d_conv

@@ -1,11 +1,17 @@
-"""The pieces both sequence models are built from (models/hybrid_lm.py,
-models/looped_lm.py): ONE RMSNorm, ONE gated SiLU FFN, ONE causal
-attention and ONE blocked next-token scorer, each on the perturbed-dense
-primitive (models/perturbed.py), so that an optimisation of one is
-measured on both models.
+"""The pieces the three sequence models are built from
+(models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py): ONE RMSNorm,
+ONE gated SiLU FFN, ONE causal attention core, ONE expert layer and ONE
+blocked next-token scorer, each on the perturbed-dense primitive
+(models/perturbed.py), so that an optimisation of one is measured on every
+model that calls it.
 
-The attention's core (rotated q, k, v -> context) has TWO forms of one
-algorithm, same mathematics, same tiles, same precision:
+The attention's core (:func:`attention_core`: rotated q, k, v -> context)
+takes TWO widths: queries and keys of one, values of another (latent
+attention's heads are 192 wide where they are scored and 128 where they
+are summed; the other two models' are one width).  A model makes its own
+q, k, v (:func:`causal_attention` is the plain q/k/v/o form two of them
+share).  The core has TWO forms of one algorithm, same mathematics, same
+tiles, same precision:
 
 - ``"xla"``: block-causal einsums and a softmax, whose float32 score
   tiles XLA holds in HBM.  It runs anywhere: the CPU path, every test's
@@ -23,7 +29,18 @@ sequence a whole number of the kernel's blocks) and opens
 :func:`causal_attention` takes the kernel inside that scope and the XLA
 form everywhere else, so ``apply`` outside an engine is the XLA form.
 ``ES`` hands the engine the model's ``head_dim``, as it hands it
-``leaf_rows``.
+``leaf_rows``: the query/key width (``qk_head_dim``), which is what the
+kernel's blocks are cut by.
+
+The expert layer (:func:`routed_experts`) is told which experts it holds:
+it routes over all of them (:func:`route`), computes what its own experts
+give for the (token, k) pairs routed to them and leaves the rest out.  One
+implementation: the pairs of ALL members under the ``vmap``s around it are
+sorted by expert together, so that the centre's stacked ``[E, m, n]``
+leaves go through one grouped matmul whose work follows the rows routed,
+and only the rank-r correction is per (member, expert).  Static shapes and
+no drop: the sorted rows are taken ``capacity`` at a time, as many times as
+there are rows.
 
 Functions, not a base class: a model hands in its own ``dense`` (the
 ``(p, noise, c, name, x) -> x @ (p[name] + c·noise[name])`` of the class,
@@ -41,9 +58,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import ATTN, DENSE, HEAD, ROPE, stage
+import functools
+
+from ..obs.trace import (ATTN, DENSE, DISPATCH, EXPERT, HEAD, ROPE, ROUTE,
+                         stage)
 from ..ops import pallas_attention
-from .perturbed import F32, perturbed_dense
+from .perturbed import (F32, perturbed_dense, perturbed_grouped_dense,
+                        perturbed_leaf)
+
+# rows the expert layer takes at a time, over what a uniform router sends
+# its held experts: one pass nearly always, and the loop takes the rest
+EXPERT_CAPACITY_MARGIN = 1.25
 
 
 def layer_name(i: int) -> str:
@@ -101,33 +126,28 @@ def rotary_tables(length: int, head_dim: int, theta: float):
         return jnp.cos(angle), jnp.sin(angle)
 
 
-def rotate(x, cos, sin):
-    """Rotary embedding in the halves convention (``x·cos +
-    rotate_half(x)·sin``) of ``x [T, heads, head_dim]`` float32."""
+def rotate(x, cos, sin, interleaved: bool = False):
+    """Rotary embedding of ``x [T, heads, head_dim]`` float32.  Frequency
+    ``i`` turns the pair ``(x_i, x_{i+d/2})`` in the halves convention
+    (``x·cos + rotate_half(x)·sin``) and the pair ``(x_{2i}, x_{2i+1})``
+    when ``interleaved``; each pair stays where it was."""
     half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+    x1, x2 = ((x[..., 0::2], x[..., 1::2]) if interleaved
+              else (x[..., :half], x[..., half:]))
     cos, sin = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
+    y1, y2 = x1 * cos - x2 * sin, x2 * cos + x1 * sin
+    if interleaved:
+        return jnp.stack([y1, y2], axis=-1).reshape(x.shape)
+    return jnp.concatenate([y1, y2], axis=-1)
 
 
 def causal_attention(dense, p, noise, c, u, *, num_heads: int,
                      num_kv_heads: int, head_dim: int, scale: float,
                      block: int, rotary=None):
-    """Causal attention with grouped heads, block-causal: query block
-    ``i`` is scored against the keys ``[0, end of block i)`` and no
-    others, so (n+1)/(2n) of the ``[T, T]`` score tiles of ``n`` blocks
-    are computed, and a masked score (``exp(-inf) = 0``) exists only
-    inside the diagonal tile.  ``rotary``: ``(cos, sin)`` of
-    :func:`rotary_tables`, applied to queries and keys; ``None``: no
-    positional encoding.
-
-    Inside an engine's ``pallas_attention.kernel_scope`` the core is the
-    Pallas kernel (its own blocks, scores in VMEM); anywhere else the XLA
-    form below, in blocks of ``block`` (the module's text has the rule).
-    The XLA form's loop over blocks is unrolled: the program grows with
-    ``T / block``, so a much longer sequence should raise the block, not
-    the count."""
+    """Causal attention with grouped heads from the four projections
+    ``q``, ``k``, ``v``, ``o`` of ``p``, heads of ONE width.  ``rotary``:
+    ``(cos, sin)`` of :func:`rotary_tables`, applied to queries and keys;
+    ``None``: no positional encoding.  The core is :func:`attention_core`."""
     dtype, t = u.dtype, u.shape[0]
     nq, nkv, hd = num_heads, num_kv_heads, head_dim
 
@@ -140,17 +160,44 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
     q = rotated(dense(p, noise, c, "q", u), nq).astype(dtype)
     k = rotated(dense(p, noise, c, "k", u), nkv).astype(dtype)
     v = dense(p, noise, c, "v", u).astype(dtype)
+    ctx = attention_core(q, k, v, num_heads=nq, num_kv_heads=nkv,
+                         scale=scale, block=block)
+    return dense(p, noise, c, "o", ctx)
+
+
+def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
+                   scale: float, block: int):
+    """``context [T, heads · value width]`` of causal attention with
+    grouped heads: ``q [T, heads(, ·) qk width]``, ``k [T, kv heads(, ·)
+    qk width]`` and ``v [T, kv heads(, ·) value width]`` in the compute
+    dtype, heads split or not.  The value width may differ from the
+    query/key width.  Block-causal: query block ``i`` is scored against
+    the keys ``[0, end of block i)`` and no others, so (n+1)/(2n) of the
+    ``[T, T]`` score tiles of ``n`` blocks are computed, and a masked score
+    (``exp(-inf) = 0``) exists only inside the diagonal tile.
+
+    Inside an engine's ``pallas_attention.kernel_scope`` the core is the
+    Pallas kernel (its own blocks, scores in VMEM; heads of one width);
+    anywhere else the XLA form below, in blocks of ``block`` (the module's
+    text has the rule).  The XLA form's loop over blocks is unrolled: the
+    program grows with ``T / block``, so a much longer sequence should
+    raise the block, not the count."""
+    dtype, t = q.dtype, q.shape[0]
+    nq, nkv = num_heads, num_kv_heads
+    hd, vd = q.size // (t * nq), v.size // (t * nkv)
     interpret = pallas_attention.scoped_interpret()
     if interpret is not None:
+        if vd != hd:
+            raise ValueError(f"the attention kernel has heads of one width; "
+                             f"got {hd} for q/k and {vd} for v")
         with stage(ATTN):
-            ctx = pallas_attention.causal_attention(
+            return pallas_attention.causal_attention(
                 q.reshape(t, nq * hd), k.reshape(t, nkv * hd), v,
                 num_heads=nq, num_kv_heads=nkv, head_dim=hd, scale=scale,
                 interpret=interpret)
-        return dense(p, noise, c, "o", ctx)
     # query head j reads key/value head j // (nq / nkv)
     qh = q.reshape(t, nkv, nq // nkv, hd)
-    kh, vh = k.reshape(t, nkv, hd), v.reshape(t, nkv, hd)
+    kh, vh = k.reshape(t, nkv, hd), v.reshape(t, nkv, vd)
     block = min(block, t)
     ctx = []
     for start in range(0, t, block):
@@ -171,8 +218,167 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
             ctx.append(jnp.einsum(
                 "kgqs,skd->qkgd", prob, vh[:stop],
                 preferred_element_type=F32).astype(dtype))
-    ctx = jnp.concatenate(ctx).reshape(t, nq * hd)
-    return dense(p, noise, c, "o", ctx)
+    return jnp.concatenate(ctx).reshape(t, nq * vd)
+
+
+# ------------------------------------------------------- the expert layer
+
+def route(p, noise, c, u, *, top_k: int, scaling: float):
+    """``(experts [T, top_k] int32, weights [T, top_k] float32)`` of the
+    tokens ``u [T, hidden]`` float32 over ALL the experts the router
+    ``p["router"] [hidden, experts]`` scores, held here or not: ``s =
+    sigmoid(u W_r)``, the ``top_k`` of ``s + bias`` (ties to the lower
+    index), weights ``s`` at the chosen (the selection bias enters the
+    choice only), renormalised to sum ``scaling``.  All in float32, the
+    matmul at ``highest`` precision: a rounding of the scores picks another
+    expert."""
+    with stage(ROUTE), jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(perturbed_dense(
+            u.astype(F32), p["router"].astype(F32),
+            None if noise is None else noise["router"], c))
+        bias = perturbed_leaf(
+            p["router_bias"],
+            None if noise is None else noise["router_bias"], c)
+        _, experts = jax.lax.top_k(s + bias, top_k)
+        w = jnp.take_along_axis(s, experts, axis=-1)
+        return experts, scaling * w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+
+
+def expert_capacity(pairs: int, held: int, total: int) -> int:
+    """Rows the expert layer takes at a time for ``pairs`` (token, k)
+    pairs routed over ``total`` experts of which ``held`` are here: what a
+    uniform router sends here and ``EXPERT_CAPACITY_MARGIN`` over it, a
+    whole number of 512-row tiles (of 8 rows where that is more than
+    all), and never more than all the pairs."""
+    want = -(-int(pairs * held * EXPERT_CAPACITY_MARGIN) // total)
+    tile = 512 if want >= 512 else 8
+    return min(-(-want // tile) * tile, -(-pairs // 8) * 8)
+
+
+def routed_experts(p, noise, c, u, experts, weights, *, first_held: int,
+                   total: int):
+    """``(Σ_k weights_k · expert_{experts_k}(u) over the pairs whose expert
+    is HELD here [T, hidden] float32, pairs per held expert [held]
+    int32)``: the held experts are ``first_held … first_held + held - 1``
+    of ``total``, ``held`` the leading axis of the stacked ``p["gate"]``,
+    ``p["up"] [held, hidden, width]`` and ``p["down"] [held, width,
+    hidden]`` (gated SiLU).  What the other experts would have added is
+    left out.  ``held == total`` is the uncut layer.
+
+    Under the engine's ``vmap``s over members the centre ``p`` is not
+    batched, and the pairs of every member are handled together (the
+    module's text): one sort, one grouped matmul a leaf and pass."""
+    core = _expert_core(int(first_held), int(total))
+    c = jnp.asarray(c, F32).reshape(1)
+    noise = None if noise is None else {
+        n: tuple(f[None] for f in noise[n]) for n in ("gate", "up", "down")}
+    y, load = core(u[None], experts[None], weights[None], c,
+                   {n: p[n] for n in ("gate", "up", "down")}, noise)
+    return y[0], load[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_core(first_held: int, total: int):
+    """The expert layer over a set of members, ``custom_vmap``'d so that a
+    ``vmap`` over more members (pairs, signs) GROWS the set instead of
+    batching the sort and the grouped matmul: ``(u [M, T, hidden], experts
+    [M, T, K], weights [M, T, K], c [M], centre {gate, up, down}, noise
+    {name: (A [M, E, m, r], B [M, E, n, r])} | None) -> (y [M, T, hidden],
+    load [M, E])``."""
+
+    def impl(u, experts, weights, c, centre, noise):
+        return _experts_of_members(u, experts, weights, c, centre, noise,
+                                   first_held, total)
+
+    core = jax.custom_batching.custom_vmap(impl)
+
+    @core.def_vmap
+    def rule(axis_size, in_batched, u, experts, weights, c, centre, noise):
+        if any(jax.tree_util.tree_leaves(in_batched[4])):
+            # each member its own weights (the materialised form): no
+            # centre to share, the members go one by one
+            axes = jax.tree_util.tree_map(lambda b: 0 if b else None,
+                                          in_batched)
+            return jax.vmap(impl, in_axes=axes)(
+                u, experts, weights, c, centre, noise), (True, True)
+
+        def merged(x, batched):
+            if not batched:
+                x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+            return x.reshape((axis_size * x.shape[1],) + x.shape[2:])
+
+        u, experts, weights, c, noise = jax.tree_util.tree_map(
+            merged, (u, experts, weights, c, noise),
+            (*in_batched[:4], in_batched[5]))
+        y, load = core(u, experts, weights, c, centre, noise)
+        return ((y.reshape((axis_size, -1) + y.shape[1:]),
+                 load.reshape((axis_size, -1) + load.shape[1:])),
+                (True, True))
+
+    return core
+
+
+def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
+                        total):
+    """:func:`_expert_core` written out: sort the pairs by held expert
+    (the others last), then ``capacity`` sorted rows at a time: gather the
+    tokens, the gated FFN as grouped matmuls with each row's (member,
+    expert) correction, scatter-add the weighted rows back."""
+    n_members, t, hidden = u.shape
+    k, held = experts.shape[-1], centre["gate"].shape[0]
+    pairs, tokens = n_members * t * k, n_members * t
+    cap = expert_capacity(pairs, held, total)
+    with stage(DISPATCH):
+        local = experts.reshape(pairs) - first_held
+        group = jnp.where((local >= 0) & (local < held), local,
+                          held).astype(jnp.int32)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        load = (group.reshape(n_members, t * k, 1)
+                == jnp.arange(held, dtype=jnp.int32)).sum(
+                    axis=1, dtype=jnp.int32)
+        sizes_all = load.sum(axis=0)
+        ends = jnp.cumsum(sizes_all)
+        starts, routed = ends - sizes_all, ends[-1]
+        # a window past the last pair reads pairs nobody holds
+        order_p = jnp.concatenate([order, jnp.full((cap,), pairs, jnp.int32)])
+        group_p = jnp.concatenate(
+            [group[order], jnp.full((cap,), held, jnp.int32)])
+        x_flat, w_flat = u.reshape(tokens, hidden), weights.reshape(pairs)
+
+    def grouped(name, x, sizes, row_expert, row_member):
+        return perturbed_grouped_dense(
+            x, centre[name], sizes, None if noise is None else noise[name],
+            c, row_expert, row_member)
+
+    def one_pass(carry):
+        start, y = carry
+        with stage(DISPATCH):
+            pair = jax.lax.dynamic_slice(order_p, (start,), (cap,))
+            row_expert = jnp.minimum(
+                jax.lax.dynamic_slice(group_p, (start,), (cap,)), held - 1)
+            valid = start + jnp.arange(cap, dtype=jnp.int32) < routed
+            token = jnp.minimum(pair // k, tokens - 1)
+            row_member = token // t
+            sizes = (jnp.clip(ends - start, 0, cap)
+                     - jnp.clip(starts - start, 0, cap)).astype(jnp.int32)
+            x = jnp.take(x_flat, token, axis=0)
+            w = jnp.where(valid, jnp.take(
+                w_flat, jnp.minimum(pair, pairs - 1)), 0.0)
+        with stage(EXPERT):
+            gate = grouped("gate", x, sizes, row_expert, row_member)
+            up = grouped("up", x, sizes, row_expert, row_member)
+            act = (jax.nn.silu(gate) * up).astype(x.dtype)
+            out = grouped("down", act, sizes, row_expert, row_member)
+        with stage(DISPATCH):
+            # the rows past the routed ones land nowhere
+            y = y.at[jnp.where(valid, token, tokens)].add(
+                out * w[:, None], mode="drop")
+        return start + cap, y
+
+    _, y = jax.lax.while_loop(
+        lambda carry: carry[0] < routed, one_pass,
+        (jnp.int32(0), jnp.zeros((tokens, hidden), F32)))
+    return y.reshape(n_members, t, hidden), load
 
 
 def score_next_tokens(h, tokens, project, block: int, logits_scaling=None):

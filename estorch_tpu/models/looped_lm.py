@@ -127,6 +127,12 @@ class LoopedLM:
             is_leaf=lambda s: isinstance(s, tuple))
 
     @property
+    def qk_head_dim(self) -> int:
+        """A head's width where it is scored (the attention form's rule
+        reads it, ops/pallas_attention.py)."""
+        return self.head_dim
+
+    @property
     def leaf_rows(self) -> dict:
         """Leaves a token sequence does NOT pass whole: ``{leaf path:
         positions per application}``.  The head runs in blocks of

@@ -11,6 +11,11 @@ ONE population-wide matmul, and only the correction is per member:
 - dense ``E`` (``noise`` an array; small leaves where factoring would not
   save): ``x@W + c·(x@E)``.
 
+A stack of experts ``W [E, m, n]`` takes the grouped form
+(:func:`perturbed_grouped_dense`): rows sorted by expert go through ONE
+grouped matmul against the stack, whichever members they belong to, and
+the correction is per row from its (member, expert)'s factor pair.
+
 The embedding lookup and the tied head take the same factors:
 ``E[tok] + c·A[tok]·Bᵀ/√r`` and ``h@Eᵀ + c·(h@B)@Aᵀ/√r``.  Both dots
 accumulate in float32 and the sum is formed in float32; callers cast once.
@@ -19,6 +24,7 @@ The corrections carry the ``es.perturb`` stage scope (obs/trace.py).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..obs.trace import PERTURB, stage
@@ -64,6 +70,33 @@ def perturbed_dense(x, w, noise, c, transposed: bool = False):
         xa = jnp.dot(x, a.astype(x.dtype), preferred_element_type=F32)
         scale = c / jnp.sqrt(jnp.asarray(r, F32))
         return y + scale * _outer(xa, b)
+
+
+def perturbed_grouped_dense(x, w, group_sizes, noise, c, row_expert,
+                            row_member):
+    """float32 rows ``x[i] @ (W[e_i] + c[m_i]·E[m_i, e_i])`` of a stack of
+    experts ``W [E, m, n]``, for rows ``x [R, m]`` SORTED by expert:
+    expert ``e``'s rows are the ``group_sizes[e]`` after those of the
+    experts before it; rows past ``sum(group_sizes)`` come out as whatever
+    the correction alone gives (the caller masks them).  The centre's
+    product is one grouped matmul (``jax.lax.ragged_dot``; on a TPU a
+    kernel whose work follows the rows in the groups, not ``E`` × rows)
+    shared by every member whose rows are among ``x``.  ``noise``: ``None``
+    or ``(A [M, E, m, r], B [M, E, n, r])``, one factor pair per (member,
+    expert); ``c [M]``; ``row_expert``, ``row_member`` ``[R]`` say whose
+    pair corrects each row."""
+    y = jax.lax.ragged_dot(x, w, group_sizes, preferred_element_type=F32)
+    if noise is None:
+        return y
+    with stage(PERTURB):
+        a, b = noise
+        r = a.shape[-1]
+        xa = jnp.einsum("im,imr->ir", x,
+                        a[row_member, row_expert].astype(x.dtype),
+                        preferred_element_type=F32)
+        scale = jnp.take(c, row_member) / jnp.sqrt(jnp.asarray(r, F32))
+        return y + jnp.einsum("ir,inr->in", xa * scale[:, None],
+                              b[row_member, row_expert].astype(F32))
 
 
 def perturbed_embed(tokens, table, noise, c):
@@ -116,6 +149,8 @@ def lowrank_spec_for(module, params, rank: int):
     from ..ops.lowrank import make_lowrank_spec, make_lowrank_tree_spec
     from .decomposed import supports_decomposed
 
-    make = (make_lowrank_spec if supports_decomposed(module)
-            else make_lowrank_tree_spec)
-    return make(params, rank)
+    if supports_decomposed(module):
+        return make_lowrank_spec(params, rank)
+    # a model names the leaves whose leading axis indexes experts
+    return make_lowrank_tree_spec(
+        params, rank, stacked=getattr(module, "stacked_leaves", ()))

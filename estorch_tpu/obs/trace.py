@@ -35,7 +35,7 @@ SCOPE_PREFIX = "es."
 
 # the stages of one generation, in program order (docs/observability.md)
 STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
-          DENSE, SSM, ATTN, HEAD, ROPE, EXIT) = (
+          DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH, EXPERT) = (
     "sample",    # offsets, signs, member keys
     "noise",     # reading eps: the table gather and the slab it builds
     "perturb",   # theta + sigma * sign * eps, unravel, cast; the rank-r
@@ -47,7 +47,8 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "grad",      # the second pass over the noise, the weighted sum
     "update",    # weight decay, optax step, sigma decay, obs-norm probe
     # nested inside es.policy by a sequence model (models/hybrid_lm.py,
-    # models/looped_lm.py on the pieces of models/lm_blocks.py)
+    # models/looped_lm.py, models/moe_lm.py on the pieces of
+    # models/lm_blocks.py)
     "dense",     # the shared x@W projections and the gated FFN
     "ssm",       # conv1d, dt and decay, the chunked scan, the gated norm
     "attn",      # scores, softmax, P.V
@@ -55,6 +56,12 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "rope",      # rotary positions: cos/sin, rotating queries and keys
     "exit",      # a looped model's exit gate, the exit distribution and
                  # the weighting of the per-pass scores
+    "route",     # an expert layer's router: its matmul, sigmoid, selection
+                 # bias, top-k and the renormalised weights
+    "dispatch",  # sorting (token, k) pairs by held expert, the gather into
+                 # expert order and the weighted combine back
+    "expert",    # the grouped matmuls over the routed rows and the
+                 # experts' gated activation
 )
 
 

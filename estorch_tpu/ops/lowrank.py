@@ -54,10 +54,15 @@ class LowRankTreeSpec:
 
     ``lr_leaves``: (leaf_index, m, n, a_off, b_off): 2-D leaves where
     factoring saves ((m+n)·rank < m·n) carry factors A (m, r) and B (n, r)
-    at those offsets.  ``dense_leaves``: (leaf_index, shape, size, off):
-    every other leaf (biases, conv taps, per-head scalars, norm scales, a
-    16×1 head at any rank) carries exact dense noise, which is exact AND
-    no larger.  Offsets are assigned in ``order`` (default: leaf order).
+    at those offsets.  ``stacked_leaves``: (leaf_index, e, m, n, a_off,
+    b_off): leaves ``[e, m, n]`` whose leading axis indexes experts (the
+    caller names them) carry ONE factor pair per expert, A (e, m, r) and
+    B (e, n, r): ``E[k] = A[k]·B[k]ᵀ/√r``, independent across experts as
+    the entries of a dense E would be.  ``dense_leaves``: (leaf_index,
+    shape, size, off): every other leaf (biases, conv taps, per-head
+    scalars, norm scales, a 16×1 head at any rank) carries exact dense
+    noise, which is exact AND no larger.  Offsets are assigned in
+    ``order`` (default: leaf order).
     """
 
     rank: int
@@ -65,6 +70,12 @@ class LowRankTreeSpec:
     treedef: Any
     lr_leaves: tuple
     dense_leaves: tuple
+    stacked_leaves: tuple = ()
+
+    @property
+    def n_leaves(self) -> int:
+        return (len(self.lr_leaves) + len(self.dense_leaves)
+                + len(self.stacked_leaves))
 
     def unpack(self, noise_vec: jax.Array) -> Any:
         """(noise_dim,) slice → the params' pytree with ``(A, B)`` at each
@@ -73,27 +84,47 @@ class LowRankTreeSpec:
         carry leading batch axes (one row per pair)."""
         r = self.rank
         lead = noise_vec.shape[:-1]
-        leaves = [None] * (len(self.lr_leaves) + len(self.dense_leaves))
+        leaves = [None] * self.n_leaves
         for i, m, n, a_off, b_off in self.lr_leaves:
             leaves[i] = (
                 noise_vec[..., a_off:a_off + m * r].reshape(lead + (m, r)),
                 noise_vec[..., b_off:b_off + n * r].reshape(lead + (n, r)))
+        for i, e, m, n, a_off, b_off in self.stacked_leaves:
+            leaves[i] = (
+                noise_vec[..., a_off:a_off + e * m * r].reshape(
+                    lead + (e, m, r)),
+                noise_vec[..., b_off:b_off + e * n * r].reshape(
+                    lead + (e, n, r)))
         for i, shape, size, off in self.dense_leaves:
             leaves[i] = noise_vec[..., off:off + size].reshape(lead + shape)
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
 
-def make_lowrank_tree_spec(params: Any, rank: int,
-                           order=None) -> LowRankTreeSpec:
+def make_lowrank_tree_spec(params: Any, rank: int, order=None,
+                           stacked=()) -> LowRankTreeSpec:
     """Layout from ANY param pytree (arrays or ``ShapeDtypeStruct``s).
-    ``order``: the leaf indices in the order their noise is laid out."""
+    ``order``: the leaf indices in the order their noise is laid out.
+    ``stacked``: the '/'-joined paths of the leaves ``[e, m, n]`` whose
+    leading axis indexes experts (a model's ``stacked_leaves``): each is
+    factored per expert where that saves, by the 2-D rule on ``(m, n)``."""
     if rank < 1:
         raise ValueError(f"low_rank must be >= 1, got {rank}")
     leaves, treedef = jax.tree_util.tree_flatten(params)
-    lr_leaves, dense_leaves = [], []
+    stacked_at = set()
+    if stacked:
+        paths = ["/".join(str(getattr(k, "key", k)) for k in path)
+                 for path, _ in jax.tree_util.tree_flatten_with_path(params)[0]]
+        stacked_at = {paths.index(p) for p in stacked}
+    lr_leaves, dense_leaves, stacked_leaves = [], [], []
     off = 0
     for i in (range(len(leaves)) if order is None else order):
         shape = tuple(int(d) for d in leaves[i].shape)
+        if (i in stacked_at and len(shape) == 3
+                and rank * (shape[1] + shape[2]) < shape[1] * shape[2]):
+            e, m, n = shape
+            stacked_leaves.append((i, e, m, n, off, off + e * m * rank))
+            off += e * (m + n) * rank
+            continue
         # low-rank only where it actually SAVES: (m+n)·r < m·n (this also
         # implies r < min(m, n), since mn/(m+n) < min(m, n)); otherwise the
         # factors would cost more noise floats than exact dense Gaussian,
@@ -112,6 +143,7 @@ def make_lowrank_tree_spec(params: Any, rank: int,
         rank=rank, noise_dim=off, treedef=treedef,
         lr_leaves=tuple(sorted(lr_leaves)),
         dense_leaves=tuple(sorted(dense_leaves)),
+        stacked_leaves=tuple(sorted(stacked_leaves)),
     )
 
 
@@ -156,11 +188,15 @@ def lowrank_tree_noise(spec: LowRankTreeSpec, noise_vec: jax.Array) -> Any:
     once-per-episode perturbation, not the per-step hot path."""
     r = spec.rank
     scale = 1.0 / jnp.sqrt(jnp.float32(r))
-    leaves = [None] * (len(spec.lr_leaves) + len(spec.dense_leaves))
+    leaves = [None] * spec.n_leaves
     for i, m, n, a_off, b_off in spec.lr_leaves:
         a = noise_vec[a_off:a_off + m * r].reshape(m, r)
         b = noise_vec[b_off:b_off + n * r].reshape(n, r)
         leaves[i] = (a @ b.T) * scale
+    for i, e, m, n, a_off, b_off in spec.stacked_leaves:
+        a = noise_vec[a_off:a_off + e * m * r].reshape(e, m, r)
+        b = noise_vec[b_off:b_off + e * n * r].reshape(e, n, r)
+        leaves[i] = jnp.einsum("emr,enr->emn", a, b) * scale
     for i, shape, size, off in spec.dense_leaves:
         leaves[i] = noise_vec[off:off + size].reshape(shape)
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
@@ -191,11 +227,16 @@ def lowrank_tree_weighted_sum(
     r = spec.rank
     k = noise_mat.shape[0]
     scale = 1.0 / jnp.sqrt(jnp.float32(r))
-    leaves = [None] * (len(spec.lr_leaves) + len(spec.dense_leaves))
+    leaves = [None] * spec.n_leaves
     for i, m, n, a_off, b_off in spec.lr_leaves:
         a = noise_mat[:, a_off:a_off + m * r].reshape(k, m, r)
         b = noise_mat[:, b_off:b_off + n * r].reshape(k, n, r)
         leaves[i] = jnp.einsum("kmr,knr->mn", a * weights[:, None, None], b) * scale
+    for i, e, m, n, a_off, b_off in spec.stacked_leaves:
+        a = noise_mat[:, a_off:a_off + e * m * r].reshape(k, e, m, r)
+        b = noise_mat[:, b_off:b_off + e * n * r].reshape(k, e, n, r)
+        leaves[i] = jnp.einsum(
+            "kemr,kenr->emn", a * weights[:, None, None, None], b) * scale
     for i, shape, size, off in spec.dense_leaves:
         e = noise_mat[:, off:off + size]
         leaves[i] = (weights @ e).reshape(shape)

@@ -116,8 +116,8 @@ def padded_count(n: int, n_shards: int) -> int:
 # everything else replicates.  The trailing catch-all makes the defaults
 # total over ANY tree; strict user rule sets omit it and get the
 # unmatched-leaf error instead.
-# The sequence models' leaves (models/hybrid_lm.py, models/looped_lm.py)
-# come first.  Projections are column- then row-parallel in pairs
+# The sequence models' leaves (models/hybrid_lm.py, models/looped_lm.py;
+# models/moe_lm.py's own follow in MOE_LM_PARTITION_RULES) come first.  Projections are column- then row-parallel in pairs
 # (in_z/in_x/in_dt → out_proj, gate/up → down, q/k/v → o), so one
 # all-reduce closes each pair; Mamba heads, their conv channels, dt,
 # A_log, D and the gated norm go by head; the embedding by vocabulary row
@@ -140,9 +140,28 @@ HYBRID_LM_PARTITION_RULES = (
     (r"exit_gate/(kernel|bias)$", P()),
 )
 
+# A sparse-expert model with latent attention (models/moe_lm.py).  The
+# STACKED expert leaves ``[experts, m, n]`` shard their EXPERT axis: a
+# device holds whole experts, as expert parallelism does.  Latent
+# attention's up-projections go by head (column-parallel, closed by the
+# row-parallel ``attn/o`` above); its two down-projections are narrow and
+# feed a norm over their whole width, so they replicate, as do the norms,
+# the router and its selection bias (every device routes every token).
+# The shared expert is a gated FFN like ``mlp``.
+MOE_LM_PARTITION_RULES = (
+    (r"experts/(gate|up|down)$", P(MODEL_AXIS, None, None)),
+    (r"shared/(gate|up)$", P(None, MODEL_AXIS)),
+    (r"shared/down$", P(MODEL_AXIS, None)),
+    (r"attn/(q_b|kv_b)$", P(None, MODEL_AXIS)),
+    (r"attn/(q_a|kv_a)$", P()),
+    (r"(q_norm|kv_norm|embed_norm|hidden_norm)/scale$", P()),
+    (r"moe/(router|router_bias)$", P()),
+    (r"mtp/eh$", P(None, MODEL_AXIS)),
+)
+
 CATCH_ALL = r".*"
 
-DEFAULT_PARTITION_RULES = HYBRID_LM_PARTITION_RULES + (
+DEFAULT_PARTITION_RULES = HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),

@@ -60,6 +60,7 @@ the ctor rejects them loudly.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -161,6 +162,9 @@ class ShardedESEngine:
         lowrank_spec=None,
         leaf_rows: dict[str, int] | None = None,
         attention_head_dim: int | None = None,
+        leaf_rows_per_token: dict[str, float] | None = None,
+        float32_leaves=(),
+        expert_load: bool = False,
     ):
         if config.obs_norm:
             raise ValueError(
@@ -215,6 +219,12 @@ class ShardedESEngine:
         # {leaf path: positions per application} of the leaves the policy
         # runs in blocks of positions (a sequence model's untied head)
         self._leaf_rows = dict(leaf_rows or {})
+        # {stacked leaf path: rows of the leaf's input per position}: an
+        # expert layer applies a held expert to the pairs routed to it
+        self._leaf_rows_per_token = dict(leaf_rows_per_token or {})
+        # the policy returns its experts' load after what the env scores
+        # (models/moe_lm.py); the perturbed form sums it into the metrics
+        self._expert_load = bool(expert_load)
         # the Pallas kernels compile through Mosaic on the chip this mesh
         # is made of; anywhere else only the interpreter can run them
         self._pallas_interpret = mesh.devices.flat[0].platform != "tpu"
@@ -242,14 +252,21 @@ class ShardedESEngine:
             _leaf_path_name(path) for path, _ in
             jax.tree_util.tree_flatten_with_path(params_shape)[0]]
         self.leaf_shapes = [tuple(int(d) for d in l.shape) for l in leaves]
-        import math
-
         self.leaf_sizes = [math.prod(s) if s else 1 for s in self.leaf_shapes]
         offs, pos = [], 0
         for sz in self.leaf_sizes:
             offs.append(pos)
             pos += sz
         self.leaf_flat_offsets = offs  # table-mode: leaf start within a row
+        # the dtype each leaf has in the copy the forward reads: the
+        # compute dtype, but float32 for the leaves the model names (a
+        # router: a rounding of its scores picks another expert)
+        keep = set(float32_leaves)
+        if keep - set(self.leaf_paths):
+            raise ValueError("float32_leaves names no leaf: "
+                             f"{sorted(keep - set(self.leaf_paths))}")
+        self._leaf_dtypes = [jnp.float32 if path in keep else self._dtype
+                             for path in self.leaf_paths]
 
         # low_rank: which leaves draw factored noise — the SAME
         # (m+n)·r < m·n save-or-dense rule as ops/lowrank.py specs
@@ -343,6 +360,8 @@ class ShardedESEngine:
             "update_finite": self._repl, "sigma": self._repl,
             "best_theta": self.param_shardings,
         }
+        if self._expert_load and self.forward_form == "perturbed":
+            metrics_shardings["expert_load"] = self._repl
         # table mode threads the table as a replicated OPERAND, not a
         # closure: a closed-over array lowers as an embedded HLO constant
         # — at table size that bloats the module past the persistent
@@ -472,7 +491,8 @@ class ShardedESEngine:
                     eps, self._batched_shardings[i])
             with stage(PERTURB):
                 b = scale.reshape((ids.shape[0],) + (1,) * leaf.ndim)
-                theta_leaves.append((leaf[None] + b * eps).astype(self._dtype))
+                theta_leaves.append(
+                    (leaf[None] + b * eps).astype(self._leaf_dtypes[i]))
         theta = jax.tree_util.tree_unflatten(self._treedef, theta_leaves)
         res = jax.vmap(self._rollout, in_axes=(0, 0))(theta, keys)
         return res.total_reward, res.bc, res.steps
@@ -483,12 +503,17 @@ class ShardedESEngine:
         once times its output width over ``model``.  A leaf sees the whole
         horizon unless the policy runs it in blocks of positions
         (``leaf_rows``: an untied head is ``[head_block, vocab]``, never
-        ``[horizon, vocab]``)."""
+        ``[horizon, vocab]``).  A stacked expert leaf sees the rows routed
+        to its experts (``leaf_rows_per_token`` a position), whole on every
+        device: its expert axis is what ``model`` divides."""
         horizon = self.config.horizon
         return max([
             min(horizon, self._leaf_rows.get(self.leaf_paths[i], horizon))
             * -(-n // self.model_shards)
-            for i, _, n, _, _ in self.lr_spec.lr_leaves] or [1])
+            for i, _, n, _, _ in self.lr_spec.lr_leaves] + [
+            math.ceil(horizon * self._leaf_rows_per_token.get(
+                self.leaf_paths[i], 1.0)) * n
+            for i, _, _, n, _, _ in self.lr_spec.stacked_leaves] or [1])
 
     def _size_pair_chunks(self):
         """Perturbed form: antithetic pairs (unmirrored: members) per
@@ -541,13 +566,14 @@ class ShardedESEngine:
 
             res = jax.vmap(pair_eval, spmd_axis_name=POP_AXIS)(
                 noise_tree, keys_c)
-            return res.total_reward, res.bc, res.steps
+            load = res.extras[0] if self._expert_load else None
+            return res.total_reward, res.bc, res.steps, load
 
         if self.n_pair_chunks == 1:
-            f, bc, st = chunk_body(noise_rows, keys)
+            f, bc, st, load = chunk_body(noise_rows, keys)
         else:
             n, k = self.n_pair_chunks, self.pair_chunk
-            _, (f, bc, st) = jax.lax.scan(
+            _, (f, bc, st, load) = jax.lax.scan(
                 lambda _, xs: (0, chunk_body(*xs)), 0,
                 (noise_rows.reshape(n, k, self.noise_dim),
                  keys.reshape((n, k) + keys.shape[1:])))
@@ -558,8 +584,13 @@ class ShardedESEngine:
         with stage(GATHER):
             alive = jnp.arange(self.members_padded) < cfg.population_size
             steps = jnp.where(alive, st, 0).sum()
+            if load is not None:
+                # [(chunks,) pairs, signs, held] -> pairs per held expert
+                # over the real members
+                load = load.reshape(self.members_padded, -1)
+                load = jnp.where(alive[:, None], load, 0).sum(axis=0)
             return (f[: cfg.population_size], bc[: cfg.population_size],
-                    steps)
+                    steps, load)
 
     def _noise_rows(self, offsets, table_data):
         """Perturbed form: every pair's ``noise_dim`` floats, sliced from
@@ -757,11 +788,12 @@ class ShardedESEngine:
             # the pairs' rows, read once for evaluation, update and best
             noise_rows = self._noise_rows(offsets, table_data)
             with stage(PERTURB):
-                center = jax.tree_util.tree_map(
-                    lambda x, sh: jax.lax.with_sharding_constraint(
-                        x.astype(self._dtype), sh),
-                    state.params, self.param_shardings)
-            fitness, bc, steps = self._eval_all_perturbed(
+                center = jax.tree_util.tree_unflatten(self._treedef, [
+                    jax.lax.with_sharding_constraint(x.astype(dtype), sh)
+                    for x, dtype, sh in zip(
+                        jax.tree_util.tree_leaves(state.params),
+                        self._leaf_dtypes, self._param_sharding_leaves)])
+            fitness, bc, steps, expert_load = self._eval_all_perturbed(
                 state, center, noise_rows, rkey)
         else:
             fitness, bc, steps = self._eval_all(
@@ -810,6 +842,8 @@ class ShardedESEngine:
             "best_theta": jax.tree_util.tree_unflatten(
                 self._treedef, best_leaves),
         }
+        if perturbed and expert_load is not None:
+            metrics["expert_load"] = expert_load
         return new_state, metrics
 
     # ------------------------------------------------------------- public

@@ -17,14 +17,17 @@ import pytest
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
 from estorch_tpu.obs.spans import Telemetry
-from estorch_tpu.obs.trace import (ATTN, DENSE, ENV, EXIT, GATHER, GRAD, HEAD,
-                                   NOISE, PERTURB, POLICY, RANK, ROPE, SAMPLE,
-                                   SCOPE_PREFIX, SSM, STAGES, UPDATE,
-                                   annotate, stage, trace)
+from estorch_tpu.obs.trace import (ATTN, DENSE, DISPATCH, ENV, EXIT, EXPERT,
+                                   GATHER, GRAD, HEAD, NOISE, PERTURB, POLICY,
+                                   RANK, ROPE, ROUTE, SAMPLE, SCOPE_PREFIX,
+                                   SSM, STAGES, UPDATE, annotate, stage,
+                                   trace)
 
 # the stages of every generation program; a sequence model nests more
-# inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT)
+# inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT; a
+# sparse-expert one ROPE, ROUTE, DISPATCH and EXPERT)
 GENERATION_STAGES = STAGES[:9]
+EXPERT_STAGES = {ROUTE, DISPATCH, EXPERT}
 
 SCOPE = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(SCOPE_PREFIX)
                    + r"([a-z_]+)")
@@ -118,7 +121,7 @@ def test_sequence_model_names_its_layers_inside_the_policy_stage(
     found = {s for name in names for s in SCOPE.findall(name)}
     # every stage but the looped model's two (it rotates nothing, exits
     # nowhere)
-    want = set(STAGES) - {ROPE, EXIT}
+    want = set(STAGES) - {ROPE, EXIT} - EXPERT_STAGES
     assert found == want, (want - found, found - want)
     for inner in (DENSE, SSM, ATTN, HEAD):
         stacks = [SCOPE.findall(n) for n in names
@@ -159,7 +162,7 @@ def test_looped_model_names_its_layers_inside_the_policy_stage(
         es.state, engine.table.data).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     found = {s for name in names for s in SCOPE.findall(name)}
-    want = set(STAGES) - {SSM}
+    want = set(STAGES) - {SSM} - EXPERT_STAGES
     assert found == want, (want - found, found - want)
     for inner in (DENSE, ATTN, HEAD, ROPE, EXIT):
         stacks = [SCOPE.findall(n) for n in names
@@ -408,11 +411,54 @@ def test_kernel_form_books_its_kernel_to_attn(v5e_chip):
         for name, stack in kernels), kernels
 
 
+def test_expert_model_names_its_layers_inside_the_policy_stage(
+        keyed_by_source):
+    """The sharded engine's perturbed form on a MoELM, one device: every
+    stage of a generation, es.dense / es.attn / es.head / es.rope from the
+    pieces it shares with the other two models and its own es.route /
+    es.dispatch / es.expert, nested inside es.policy; no es.ssm, no
+    es.exit.  The grouped matmuls sit under es.expert, their per-(member,
+    expert) corrections one deeper under es.perturb, the sort under
+    es.dispatch."""
+    import moe_tiny
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import MoELM
+
+    es = ES(policy=MoELM, agent=JaxAgent, optimizer=optax.adam,
+            population_size=8, sigma=0.02, policy_kwargs=moe_tiny.TINY,
+            agent_kwargs={"env": TokenScoreEnv(**moe_tiny.ENV)},
+            optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
+            model_shards=1, low_rank=1, noise_mode="table",
+            table_size=1 << 18, device=jax.devices()[:1])
+    engine = es.engine
+    text = engine._generation_step.lower(
+        es.state, engine.table.data).as_text(debug_info=True)
+    names = re.findall(r'loc\("(jit\([^"]*)"', text)
+    found = {s for name in names for s in SCOPE.findall(name)}
+    want = set(STAGES) - {SSM, EXIT}
+    assert found == want, (want - found, found - want)
+    for inner in (DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT):
+        stacks = [SCOPE.findall(n) for n in names
+                  if SCOPE_PREFIX + inner in n]
+        assert stacks and all(
+            POLICY in st and st.index(POLICY) < st.index(inner)
+            for st in stacks), inner
+    stacks = [(tuple(SCOPE.findall(n)), n) for n in names
+              if SCOPE.findall(n)]
+    assert any(st[-1] == EXPERT and "ragged_dot" in n for st, n in stacks)
+    assert any(st[-2:] == (EXPERT, PERTURB) for st, _ in stacks)
+    assert any(st[-1] == DISPATCH and "sort" in n for st, n in stacks)
+    assert any(st[-1] == DISPATCH and "scatter" in n for st, n in stacks)
+    assert any(st[-1] == ROUTE and "top_k" in n for st, n in stacks)
+    assert es.obs.counters.get("experts_held") == 4
+
+
 @pytest.mark.parametrize("use", ["context", "decorator"])
 def test_stage_scopes_a_name_stack(use):
-    assert len(set(STAGES)) == len(STAGES) == 15
+    assert len(set(STAGES)) == len(STAGES) == 18
     assert (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD,
-            UPDATE, DENSE, SSM, ATTN, HEAD, ROPE, EXIT) == STAGES
+            UPDATE, DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH,
+            EXPERT) == STAGES
     if use == "context":
         def f(x):
             with stage(NOISE):
