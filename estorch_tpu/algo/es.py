@@ -329,10 +329,11 @@ class ES:
                 noise_mode=self._noise_mode,
                 perturbed_apply=lr_apply, lowrank_spec=lr_spec,
                 leaf_rows=getattr(self.module, "leaf_rows", None),
-                # the width a sequence model's heads are SCORED at, which
+                # the widths a sequence model's attention is cut by, which
                 # the attention form's rule reads (models/lm_blocks.py);
                 # None for a policy without attention
-                attention_head_dim=getattr(self.module, "qk_head_dim", None),
+                attention_widths=getattr(self.module, "attention_widths",
+                                         None),
                 # a sparse-expert model (models/moe_lm.py): what its stacked
                 # leaves see of a sequence, what stays float32, its load
                 leaf_rows_per_token=getattr(

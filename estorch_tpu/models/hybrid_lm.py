@@ -125,9 +125,9 @@ class HybridLM:
                 or self.hidden_size // self.num_attention_heads)
 
     @property
-    def qk_head_dim(self) -> int:
-        """A head's width where it is scored (the attention form's rule
-        reads it, ops/pallas_attention.py)."""
+    def attention_widths(self) -> int:
+        """Heads of ONE width, scored and summed (the attention form's
+        rule reads it, ops/pallas_attention.py)."""
         return self.head_dim
 
     def param_shapes(self) -> dict:

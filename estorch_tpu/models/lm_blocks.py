@@ -5,32 +5,43 @@ blocked next-token scorer, each on the perturbed-dense primitive
 (models/perturbed.py), so that an optimisation of one is measured on every
 model that calls it.
 
-The attention's core (:func:`attention_core`: rotated q, k, v -> context)
-takes TWO widths: queries and keys of one, values of another (latent
-attention's heads are 192 wide where they are scored and 128 where they
-are summed; the other two models' are one width).  A model makes its own
-q, k, v (:func:`causal_attention` is the plain q/k/v/o form two of them
-share).  The core has TWO forms of one algorithm, same mathematics, same
-tiles, same precision:
+The attention's core (:func:`attention_core`: rotated parts -> context)
+takes its operands in PARTS: per head a query and a key of one width and
+values of another, and optionally a second query part per head with ONE
+key part that every head reads.  The score is the sum of the two
+contractions, ``q kᵀ + q_shared k_sharedᵀ``: the dot of the concatenated
+parts, which is how DeepSeek-V3 writes latent attention's score (heads
+128 + 64 wide where they are scored, the 64 rotated and its key shared,
+128 where they are summed; the other two models' heads are one width with
+no shared part).  The values may come BESIDE their keys in one array, as
+latent attention's ``kv_b`` writes them (``v=None``): the kernel reads
+both where they lie, the XLA form cuts them apart.  A model makes its own
+parts (:func:`causal_attention`
+is the plain q/k/v/o form two of them share).  The core has TWO forms of
+one algorithm, same mathematics, same tiles, same precision:
 
 - ``"xla"``: block-causal einsums and a softmax, whose float32 score
-  tiles XLA holds in HBM.  It runs anywhere: the CPU path, every test's
-  oracle, and what a mesh of several devices takes.
+  tiles XLA holds in HBM; a shared part is concatenated onto q and k, the
+  key's broadcast to every head.  It runs anywhere: the CPU path, every
+  test's oracle, and what a mesh of several devices takes.
 - ``"kernel"``: ops/pallas_attention.py, an online softmax whose scores
-  never leave VMEM.
+  never leave VMEM; the shared part is a second contraction inside the
+  tile, its key read by every head from the one ``[T, width]`` array.
 
 Which one a program takes is not an option of a model or of ``ES``: the
 ENGINE resolves it once at build from what it observes
 (``ShardedESEngine.attention_form``, by the one rule
-``ops.pallas_attention.attention_form``: TPU devices, ONE device on the
-mesh so the operands are whole on it, ``head_dim % 128 == 0``, the
-sequence a whole number of the kernel's blocks) and opens
-``pallas_attention.kernel_scope`` around its trace of the policy.
-:func:`causal_attention` takes the kernel inside that scope and the XLA
+``ops.pallas_attention.attention_form``: TPU devices; ONE device on the
+mesh so the operands are whole on it; a head's own query/key part and its
+values whole numbers of 128-lane column blocks; a shared part of 64 or a
+multiple of 128; the sequence a whole number of the kernel's blocks) and
+opens ``pallas_attention.kernel_scope`` around its trace of the policy.
+:func:`attention_core` takes the kernel inside that scope and the XLA
 form everywhere else, so ``apply`` outside an engine is the XLA form.
-``ES`` hands the engine the model's ``head_dim``, as it hands it
-``leaf_rows``: the query/key width (``qk_head_dim``), which is what the
-kernel's blocks are cut by.
+``ES`` hands the engine the model's ``attention_widths``, as it hands it
+``leaf_rows``: the widths the kernel's column blocks are cut by, one
+``int`` for heads of one width or ``(a head's own part, the shared part,
+the value width)``.
 
 The expert layer (:func:`routed_experts`) is told which experts it holds:
 it routes over all of them (:func:`route`), computes what its own experts
@@ -166,35 +177,57 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
 
 
 def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
-                   scale: float, block: int):
+                   scale: float, block: int, q_shared=None, k_shared=None):
     """``context [T, heads · value width]`` of causal attention with
     grouped heads: ``q [T, heads(, ·) qk width]``, ``k [T, kv heads(, ·)
     qk width]`` and ``v [T, kv heads(, ·) value width]`` in the compute
     dtype, heads split or not.  The value width may differ from the
-    query/key width.  Block-causal: query block ``i`` is scored against
+    query/key width.  ``v=None``: ``k [T, kv heads(, ·) qk width + value
+    width]`` holds each head's key with its values beside it, as one
+    projection wrote them.  ``q_shared [T, heads(, ·) shared width]`` and
+    ``k_shared [T, shared width]``: a second part of every head's query
+    and ONE key part that every head reads (latent attention's rotated
+    part); the score is ``q kᵀ + q_shared k_sharedᵀ``, the dot of the
+    concatenated parts.  Block-causal: query block ``i`` is scored against
     the keys ``[0, end of block i)`` and no others, so (n+1)/(2n) of the
     ``[T, T]`` score tiles of ``n`` blocks are computed, and a masked score
     (``exp(-inf) = 0``) exists only inside the diagonal tile.
 
     Inside an engine's ``pallas_attention.kernel_scope`` the core is the
-    Pallas kernel (its own blocks, scores in VMEM; heads of one width);
-    anywhere else the XLA form below, in blocks of ``block`` (the module's
-    text has the rule).  The XLA form's loop over blocks is unrolled: the
-    program grows with ``T / block``, so a much longer sequence should
-    raise the block, not the count."""
+    Pallas kernel (its own blocks, scores in VMEM, the shared part a
+    second contraction in the tile: the key part is never broadcast);
+    anywhere else the XLA form below, in blocks of ``block``, which
+    concatenates the shared parts onto q and k, the key's broadcast to
+    every head (the module's text has the rule).  The XLA form's loop over
+    blocks is unrolled: the program grows with ``T / block``, so a much
+    longer sequence should raise the block, not the count."""
     dtype, t = q.dtype, q.shape[0]
     nq, nkv = num_heads, num_kv_heads
-    hd, vd = q.size // (t * nq), v.size // (t * nkv)
+    hd = q.size // (t * nq)
     interpret = pallas_attention.scoped_interpret()
+    if v is None and (interpret is None or k.size != 2 * t * nkv * hd):
+        # cut the values from beside the keys: the XLA form's einsums
+        # read them apart (and the kernel's column blocks are one width)
+        kv = k.reshape(t, nkv, -1)
+        k, v = kv[..., :hd], kv[..., hd:]
+    vd = hd if v is None else v.size // (t * nkv)
     if interpret is not None:
-        if vd != hd:
-            raise ValueError(f"the attention kernel has heads of one width; "
-                             f"got {hd} for q/k and {vd} for v")
         with stage(ATTN):
             return pallas_attention.causal_attention(
-                q.reshape(t, nq * hd), k.reshape(t, nkv * hd), v,
-                num_heads=nq, num_kv_heads=nkv, head_dim=hd, scale=scale,
-                interpret=interpret)
+                q.reshape(t, nq * hd), k.reshape(t, -1),
+                None if v is None else v.reshape(t, nkv * vd),
+                None if q_shared is None else q_shared.reshape(t, -1),
+                k_shared, num_heads=nq, num_kv_heads=nkv, head_dim=hd,
+                value_dim=vd, scale=scale, interpret=interpret)
+    if q_shared is not None:
+        with stage(ROPE):
+            dr = k_shared.shape[-1]
+            q = jnp.concatenate([q.reshape(t, nq, hd),
+                                 q_shared.reshape(t, nq, dr)], axis=-1)
+            k = jnp.concatenate(
+                [k.reshape(t, nkv, hd),
+                 jnp.broadcast_to(k_shared[:, None], (t, nkv, dr))], axis=-1)
+            hd += dr
     # query head j reads key/value head j // (nq / nkv)
     qh = q.reshape(t, nkv, nq // nkv, hd)
     kh, vh = k.reshape(t, nkv, hd), v.reshape(t, nkv, vd)

@@ -127,9 +127,9 @@ class LoopedLM:
             is_leaf=lambda s: isinstance(s, tuple))
 
     @property
-    def qk_head_dim(self) -> int:
-        """A head's width where it is scored (the attention form's rule
-        reads it, ops/pallas_attention.py)."""
+    def attention_widths(self) -> int:
+        """Heads of ONE width, scored and summed (the attention form's
+        rule reads it, ops/pallas_attention.py)."""
         return self.head_dim
 
     @property

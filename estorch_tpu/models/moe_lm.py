@@ -72,7 +72,8 @@ import jax.numpy as jnp
 from ..obs.trace import DENSE, HEAD, ROPE, stage
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
-from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
+from .perturbed import (F32, leaf_columns, perturbed_dense, perturbed_embed,
+                        perturbed_leaf)
 
 DENSE_LAYER, MOE_LAYER = "dense", "moe"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -156,9 +157,17 @@ class MoELM:
 
     @property
     def qk_head_dim(self) -> int:
-        """A head's width where it is scored (the attention form's rule
-        reads it, ops/pallas_attention.py)."""
+        """A head's width where it is scored."""
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def attention_widths(self) -> tuple:
+        """``(a head's own query/key part, the rotated part whose key all
+        heads share, the value width)``: what the attention core's parts
+        are cut by (the attention form's rule reads it,
+        ops/pallas_attention.py)."""
+        return (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                self.v_head_dim)
 
     def _layer_shapes(self, kind: str) -> dict:
         h, nh = self.hidden_size, self.num_attention_heads
@@ -379,21 +388,29 @@ class MoELM:
         def norm(name, y):
             return self._norm(p, noise, c, name, y).astype(dtype)
 
-        q = self._dense(p, noise, c, "q_b", norm(
-            "q_norm", self._dense(p, noise, c, "q_a", u))).reshape(
-                t, nh, dn + dr)
+        c_q = norm("q_norm", self._dense(p, noise, c, "q_a", u))
+
+        def q_part(cols):
+            # q_b a part at a time: what each head scores with its own key
+            # goes to the core as the matmul writes it, and only the part
+            # to rotate takes the rotation's layout
+            w, n = leaf_columns(p["q_b"], subtree(noise, "q_b"), nh, cols)
+            return self._dense({"q_b": w}, None if n is None else {"q_b": n},
+                               c, "q_b", c_q).reshape(t, nh, -1)
+
+        q_nope, q_rope = q_part(slice(0, dn)), q_part(slice(dn, None))
         kv_a = self._dense(p, noise, c, "kv_a", u)
         kv = self._dense(p, noise, c, "kv_b", norm(
             "kv_norm", kv_a[:, :self.kv_lora_rank])).reshape(t, nh, dn + dv)
         with stage(ROPE):
-            q_rope = lm_blocks.rotate(q[..., dn:], *rotary, interleaved=True)
+            q_rope = lm_blocks.rotate(q_rope, *rotary, interleaved=True)
             k_rope = lm_blocks.rotate(kv_a[:, None, self.kv_lora_rank:],
-                                      *rotary, interleaved=True)
-            q = jnp.concatenate([q[..., :dn], q_rope], axis=-1).astype(dtype)
-            k = jnp.concatenate(
-                [kv[..., :dn], jnp.broadcast_to(k_rope, (t, nh, dr))],
-                axis=-1).astype(dtype)
+                                      *rotary, interleaved=True)[:, 0]
+        # kv_b wrote each head's unrotated key with its values beside it:
+        # the core reads both out of that one array
         ctx = lm_blocks.attention_core(
-            q, k, kv[..., dn:].astype(dtype), num_heads=nh, num_kv_heads=nh,
-            scale=1.0 / math.sqrt(dn + dr), block=self.attention_block)
+            q_nope.astype(dtype), kv.astype(dtype), None,
+            q_shared=q_rope.astype(dtype), k_shared=k_rope.astype(dtype),
+            num_heads=nh, num_kv_heads=nh, scale=1.0 / math.sqrt(dn + dr),
+            block=self.attention_block)
         return self._dense(p, noise, c, "o", ctx)

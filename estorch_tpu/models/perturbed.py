@@ -72,6 +72,28 @@ def perturbed_dense(x, w, noise, c, transposed: bool = False):
         return y + scale * _outer(xa, b)
 
 
+def leaf_columns(w, noise, groups: int, cols: slice):
+    """``(w, noise)`` of the leaf ``w [m, groups · width]`` cut to the
+    columns ``cols`` of each group's ``width`` (a head's), its noise cut
+    alike (a factor pair's ``B [groups · width, r]`` by rows, a dense one
+    by columns): ``x @ (W + c·E)`` cut by columns IS the product with the
+    cut leaf, column for column.  A projection whose output is split
+    before its readers is computed a part at a time, so that each part
+    leaves its matmul in the layout its reader wants; cut AFTER the
+    matmul, XLA writes the whole output once in one layout, then cuts it
+    and transposes the parts (PERF.md, PR 34: 0.065 s a generation)."""
+    def cut(x, axis):
+        shape = x.shape
+        x = x.reshape(*shape[:axis], groups, -1, *shape[axis + 1:])
+        x = x[(slice(None),) * (axis + 1) + (cols,)]
+        return x.reshape(*shape[:axis], -1, *shape[axis + 1:])
+
+    if noise is not None:
+        noise = ((noise[0], cut(noise[1], 0)) if is_factored(noise)
+                 else cut(noise, 1))
+    return cut(w, 1), noise
+
+
 def perturbed_grouped_dense(x, w, group_sizes, noise, c, row_expert,
                             row_member):
     """float32 rows ``x[i] @ (W[e_i] + c[m_i]·E[m_i, e_i])`` of a stack of

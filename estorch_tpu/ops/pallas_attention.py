@@ -16,7 +16,28 @@ Layout: no transposes around the kernel.  q is read as the 2-D array
 projections produce, in blocks of ``(block, head_dim)`` whose column-block
 index IS the head (query head ``j`` reads key/value column block ``j //
 (heads / kv_heads)``), and the context is written straight into ``[T,
-heads·head_dim]``, the layout the output projection reads.
+heads·value_dim]``, the layout the output projection reads.  Where ONE
+projection writes each head's key with its values beside it (latent
+attention's ``kv_b``: ``[T, heads·(128 + 128)]``), the kernel reads both
+out of that array, key column block ``2g`` and values ``2g + 1``
+(``v=None``): no k and no v is cut from it, and XLA then has the
+projection write the kernel's row-major tiling itself.
+
+The score may have a SECOND term: ``s = q kᵀ + q_shared k_sharedᵀ``, each
+head's second query part against ONE key part ``[T, width]`` that every
+head reads (latent attention's rotated 64, which the XLA form broadcasts
+to ``[T, heads, 64]``; DeepSeek-V3 writes the score as this sum).  In the
+tile the parts are put side by side in VMEM and contracted once, 256
+deep, so the MXU adds the terms where it accumulates; mask, running max
+and sum, P·V and the divide are the single-term kernel's.  A shared width
+that is not whole 128-lane blocks packs TWO heads a block: column block
+``h // 2`` of ``q_shared [T, heads·64]`` holds heads ``2⌊h/2⌋`` and the
+next, and the key part is laid out ``[T, 256] = [k, 0 | 0, k]``, whose
+column block ``h % 2`` picks head ``h``'s half by its zeros.  Exact (the
+other head's lanes meet zeros), no padded copy of q (+8.4 MB a member and
+layer), and the v5e's MXU contracts 128 deep whatever the width.  The
+term is a branch at TRACE time: without a shared part the traced kernel is
+the single-term one, operand for operand.
 
 Grid ``(heads, query blocks, key blocks)``, the key axis innermost and
 sequential; running max, sum and a float32 accumulator in VMEM scratch.
@@ -41,11 +62,19 @@ array, so a head is a column block only where ``head_dim % 128 == 0``
 VMEM, is ROADMAP R4's); the float32 score tile, its exponential and the
 bfloat16 probabilities of a block pair live on the kernel's stack in scoped
 VMEM, 16 MiB by default: blocks of 1024 x 1024 fit, 2048 x 2048 ask for
-24.6 MiB and are refused.  Around the kernel XLA keeps q, k, v and the
+24.6 MiB and are refused.  The second term changes none of that: Mosaic
+never holds two float32 ``[1024, 1024]`` products (it compiles the sum of
+two products and the one 256-deep product alike, in bfloat16 and float32,
+at every block pair that fits without it; the call's pipelined blocks are
+9.31 MiB against 8.95 MiB, the two new operands' buffers; 1024 queries x
+2048 keys fits too, 14.9 MiB, and multiplies a fifth more masked scores).
+Around the kernel XLA keeps q, k, v and the
 context in the row-major ``(8, 128)`` tiling the custom call states; the
 rotation before it prefers positions in the lanes, so one transposing copy
 of q and one of k precede each call (0.012 s a generation in the looped
-cell against 0.526 s saved: PERF.md, PR 32).
+cell against 0.526 s saved: PERF.md, PR 32), and a projection whose output
+is CUT before the kernel (latent attention's q into its two parts) is
+written positions-in-lanes and transposed after the cut (PERF.md, PR 34).
 
 ``interpret`` is a required argument, as in ops/pallas_noise.py: the engine
 derives it from the platform of the mesh it runs on (never true on a TPU
@@ -78,6 +107,14 @@ LANES = 128
 # a 512 tile, and a block's rows are 256-byte pieces of a [T, heads · 128]
 # array, so few large steps beat many small ones although 10 of 16 tiles of
 # 1024 hold more masked scores than 36 of 64 of 512.
+# With the second score term, at the sparse-expert cell's shapes (2 members
+# x 32 heads x 4,096 x (128 + 64 shared), values 128; PERF.md, PR 34): 3.92
+# ms at 1024 x 1024 as one 256-deep product (4.01 as the sum of two
+# products, 4.15 with q's shared part zero-padded to 128 lanes a head), 4.41
+# at 512 x 1024, 5.96 at 512 x 512, 6.53 at 1024 x 512, 7.23 at 2048 x 512;
+# the single-term kernel on the same 32 heads 3.02 ms (16 heads: 1.45), so
+# the second term costs what 128 more of contraction depth cost the MXU at
+# its peak (0.17 TFLOP in 0.90 ms); the XLA form 12.34 ms.
 BLOCKS = (1024, 512, 256, 128)
 
 # contract the last dimension of both operands: q [bq, hd] · k [bk, hd]ᵀ
@@ -96,17 +133,26 @@ def kernel_block(length: int) -> int | None:
     return next((b for b in BLOCKS if length % b == 0), None)
 
 
-def attention_form(platform: str, n_devices: int, head_dim: int,
-                   length: int) -> str:
+def attention_form(platform: str, n_devices: int, widths, length: int) -> str:
     """``"kernel"`` or ``"xla"`` for a program on a mesh of ``n_devices``
-    devices of ``platform`` that runs attention with heads of ``head_dim``
-    over ``length`` positions.  The kernel is taken when, and only when,
-    ALL hold: the devices are TPUs; there is one of them, so the
-    attention's operands are whole on it (under GSPMD an unwrapped
-    ``pallas_call`` would be replicated, not partitioned); a head is a
-    whole number of 128-lane column blocks; the sequence is a whole number
-    of the kernel's blocks (:func:`kernel_block`)."""
-    fits = head_dim % LANES == 0 and kernel_block(length) is not None
+    devices of ``platform`` that runs attention over ``length`` positions
+    with heads of ``widths``, as the model states them: one width (an
+    ``int``) for heads scored and summed at it, or ``(a head's own
+    query/key part, a shared part, the value width)`` where every head
+    also scores a second part against ONE key all heads read (0: none).
+    These are what the kernel's column blocks are cut by.  The kernel is
+    taken when, and only when, ALL hold: the devices are TPUs; there is
+    one of them, so the attention's operands are whole on it (under GSPMD
+    an unwrapped ``pallas_call`` would be replicated, not partitioned); a
+    head's own part and its values are whole numbers of 128-lane column
+    blocks; the shared part is a whole number of them or half of one (two
+    heads a block); the sequence is a whole number of the kernel's blocks
+    (:func:`kernel_block`)."""
+    head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
+                           else widths)
+    fits = (head % LANES == 0 and value % LANES == 0
+            and (shared % LANES == 0 or shared == LANES // 2)
+            and kernel_block(length) is not None)
     return ("kernel" if platform == "tpu" and n_devices == 1 and fits
             else "xla")
 
@@ -117,7 +163,7 @@ _SCOPE: contextvars.ContextVar = contextvars.ContextVar(
 
 @contextlib.contextmanager
 def kernel_scope(interpret: bool):
-    """While a policy is traced inside, ``lm_blocks.causal_attention`` takes
+    """While a policy is traced inside, ``lm_blocks.attention_core`` takes
     the kernel (under the Pallas interpreter where ``interpret``).  The
     engine that resolved ``attention_form == "kernel"`` opens it around its
     own trace of the policy; nothing else does.  The scope acts at TRACE
@@ -146,8 +192,10 @@ def _last_visible(i, block_q: int, block_k: int):
     return ((i + 1) * block_q - 1) // block_k
 
 
-def _attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                      scale: float, block_q: int, block_k: int):
+def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
+                      block_k: int):
+    # with a shared score term: each head's second query part, the one key
+    *shared, o_ref, m_ref, l_ref, acc_ref = refs
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_visible(i, block_q, block_k)
 
@@ -160,7 +208,14 @@ def _attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     def fold(masked: bool):
         """This key block into the running max, sum and accumulator."""
         v = v_ref[...]
-        s = jax.lax.dot_general(q_ref[...], k_ref[...], _QK_DIMS,
+        q, k = q_ref[...], k_ref[...]
+        if shared:
+            # q kᵀ + q_shared k_sharedᵀ as ONE contraction over the parts
+            # side by side: the MXU sums both terms where it accumulates
+            qs_ref, ks_ref = shared
+            q = jnp.concatenate([q, qs_ref[...]], axis=1)
+            k = jnp.concatenate([k, ks_ref[...]], axis=1)
+        s = jax.lax.dot_general(q, k, _QK_DIMS,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
             rows = i * block_q + jax.lax.broadcasted_iota(
@@ -197,32 +252,48 @@ def _attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_heads", "num_kv_heads", "head_dim", "scale", "block_q", "block_k",
-    "interpret"))
+    "num_heads", "num_kv_heads", "head_dim", "value_dim", "scale", "block_q",
+    "block_k", "interpret"))
 def causal_attention(
     q: jax.Array,  # [T, num_heads · head_dim], rotated, compute dtype
     k: jax.Array,  # [T, num_kv_heads · head_dim], rotated, compute dtype
-    v: jax.Array,  # [T, num_kv_heads · head_dim], compute dtype
+    v: jax.Array | None,  # [T, num_kv_heads · value_dim]; None: beside k
+    q_shared: jax.Array | None = None,  # [T, num_heads · shared width]
+    k_shared: jax.Array | None = None,  # [T, shared width]: ONE key part
     *,
     num_heads: int,
     num_kv_heads: int,
     head_dim: int,
     scale: float,
     interpret: bool,
+    value_dim: int | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
 ) -> jax.Array:
-    """The context ``softmax(scale · q kᵀ + causal mask) v`` per head,
-    ``[T, num_heads · head_dim]`` in q's dtype, with grouped heads (query
-    head ``j`` reads key/value head ``j // (num_heads / num_kv_heads)``).
+    """The context ``softmax(scale · s + causal mask) v`` per head, ``[T,
+    num_heads · value_dim]`` in q's dtype, with grouped heads (query head
+    ``j`` reads key/value head ``j // (num_heads / num_kv_heads)``) whose
+    values are ``value_dim`` wide (``head_dim`` where not given).  The
+    score ``s`` is ``q kᵀ``, and with a shared part ``q kᵀ + q_shared
+    k_sharedᵀ``: a second contraction of each head's ``q_shared`` part
+    with the ONE key part every head reads (latent attention's rotated
+    part), summed in float32 where the tile lies.  Without one the traced
+    kernel is the single-term one.
+
+    ``v=None``: ``k`` is ``[T, num_kv_heads · (head_dim + value_dim)]``
+    and holds each head's key with its values beside it, as ONE projection
+    wrote them (latent attention's ``kv_b``); the kernel reads both out of
+    that array, so no k and no v is cut from it first.  The two widths
+    must be equal (the key is column block ``2g``, the values ``2g + 1``).
 
     ``block_q``, ``block_k``: rows of a query and of a key block;
     :func:`kernel_block` of ``T`` where not given (the whole sequence where
-    it has none, which only the interpreter runs).  On the chip
-    ``head_dim`` and the blocks must be multiples of 128
-    (:func:`attention_form` is where an engine asks); under the interpreter
-    any sizes with ``T % block == 0`` run."""
+    it has none, which only the interpreter runs).  On the chip the
+    widths and the blocks must be multiples of 128, the shared width 64
+    or a multiple of 128 (:func:`attention_form` is where an engine asks);
+    under the interpreter any sizes with ``T % block == 0`` run."""
     t = q.shape[0]
+    value_dim = value_dim or head_dim
     own = kernel_block(t) or t
     block_q, block_k = min(block_q or own, t), min(block_k or own, t)
     if t % block_q or t % block_k:
@@ -231,39 +302,95 @@ def causal_attention(
             f"({block_q}, {block_k}) blocks")
     if num_heads % num_kv_heads:
         raise ValueError("query heads must be a multiple of key/value heads")
-    if (q.shape != (t, num_heads * head_dim)
-            or k.shape != (t, num_kv_heads * head_dim) or v.shape != k.shape):
+    beside = v is None
+    if beside and value_dim != head_dim:
         raise ValueError(
-            f"q {q.shape}, k {k.shape}, v {v.shape} are not [T, heads · "
-            f"{head_dim}] of {num_heads} and {num_kv_heads} heads")
+            f"values beside their keys are column blocks of one width; "
+            f"got {head_dim} and {value_dim}")
+    k_width = head_dim + value_dim if beside else head_dim
+    if (q.shape != (t, num_heads * head_dim)
+            or k.shape != (t, num_kv_heads * k_width)
+            or not (beside or v.shape == (t, num_kv_heads * value_dim))):
+        raise ValueError(
+            f"q {q.shape}, k {k.shape}, v {None if beside else v.shape} are "
+            f"not [T, heads · {head_dim}], [T, heads · {k_width}] and [T, "
+            f"heads · {value_dim}] of {num_heads} and {num_kv_heads} heads")
     group = num_heads // num_kv_heads
 
-    def kv_block(h, i, j):
+    def kv_row(i, j):
         # beyond the diagonal: the block already there, so nothing moves
-        return jnp.minimum(j, _last_visible(i, block_q, block_k)), h // group
+        return jnp.minimum(j, _last_visible(i, block_q, block_k))
+
+    def kv_block(h, i, j):
+        return kv_row(i, j), h // group
+
+    def key_beside(h, i, j):
+        return kv_row(i, j), 2 * (h // group)
+
+    def values_beside(h, i, j):
+        return kv_row(i, j), 2 * (h // group) + 1
+
+    operands = [q, k, k if beside else v]
+    in_specs = [
+        pl.BlockSpec((block_q, head_dim), lambda h, i, j: (i, h)),
+        pl.BlockSpec((block_k, head_dim), key_beside if beside else kv_block),
+        pl.BlockSpec((block_k, value_dim),
+                     values_beside if beside else kv_block),
+    ]
+    shared = q_shared is not None
+    if shared != (k_shared is not None):
+        raise ValueError("a shared score term needs q_shared AND k_shared")
+    if shared:
+        width = k_shared.shape[-1]
+        if (q_shared.shape != (t, num_heads * width)
+                or k_shared.shape != (t, width)):
+            raise ValueError(
+                f"q_shared {q_shared.shape} and k_shared {k_shared.shape} "
+                f"are not [T, {num_heads} · width] and [T, width]")
+        if width % LANES == 0:
+            # a head's part is a column block, the key's the one block
+            q_col, k_col = (lambda h: h), (lambda h: 0)
+        else:
+            # two heads a column block (latent attention's 64 in 128
+            # lanes): block h // 2 of q_shared holds heads 2⌊h/2⌋ and the
+            # next, and the key is laid out [k, 0 | 0, k], whose column
+            # block h % 2 picks head h's half by its zeros: exact, no
+            # padded copy of q, and the MXU contracts 128 deep whatever
+            # the width
+            if num_heads % 2:
+                raise ValueError(
+                    f"a shared part of {width} (not whole 128-lane blocks) "
+                    f"packs two heads a block: {num_heads} heads are odd")
+            zero = jnp.zeros_like(k_shared)
+            k_shared = jnp.concatenate(
+                [k_shared, zero, zero, k_shared], axis=1)
+            width, q_col, k_col = 2 * width, (lambda h: h // 2), (
+                lambda h: h % 2)
+        operands += [q_shared, k_shared]
+        in_specs += [
+            pl.BlockSpec((block_q, width), lambda h, i, j: (i, q_col(h))),
+            pl.BlockSpec((block_k, width),
+                         lambda h, i, j: (kv_row(i, j), k_col(h))),
+        ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(num_heads, t // block_q, t // block_k),
-        in_specs=[
-            pl.BlockSpec((block_q, head_dim), lambda h, i, j: (i, h)),
-            pl.BlockSpec((block_k, head_dim), kv_block),
-            pl.BlockSpec((block_k, head_dim), kv_block),
-        ],
-        out_specs=pl.BlockSpec((block_q, head_dim), lambda h, i, j: (i, h)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block_q, value_dim), lambda h, i, j: (i, h)),
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, LANES), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, head_dim), jnp.float32),  # accumulator
+            pltpu.VMEM((block_q, value_dim), jnp.float32),  # accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, block_q=block_q,
                           block_k=block_k),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, num_heads * value_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="causal_attention",
         interpret=interpret,
-    )(q, k, v)
+    )(*operands)

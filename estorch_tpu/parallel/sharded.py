@@ -161,7 +161,7 @@ class ShardedESEngine:
         perturbed_apply: Callable[..., Any] | None = None,
         lowrank_spec=None,
         leaf_rows: dict[str, int] | None = None,
-        attention_head_dim: int | None = None,
+        attention_widths: int | tuple | None = None,
         leaf_rows_per_token: dict[str, float] | None = None,
         float32_leaves=(),
         expert_load: bool = False,
@@ -231,11 +231,11 @@ class ShardedESEngine:
         # "kernel" | "xla": which form the policy's causal attention takes
         # in this engine's programs (models/lm_blocks.py has the two forms);
         # None for a policy that has none.  Resolved once, here, from the
-        # mesh, the sequence length and the policy's head size (run
+        # mesh, the sequence length and the widths the policy states (run
         # manifest + telemetry gauge)
         self.attention_form = (
-            None if attention_head_dim is None
-            else self._resolve_attention_form(attention_head_dim))
+            None if attention_widths is None
+            else self._resolve_attention_form(attention_widths))
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
         self.n_devices = int(mesh.devices.size)
@@ -390,11 +390,11 @@ class ShardedESEngine:
             out_shardings=self.param_shardings)
         self._copy_into_compiled = None
 
-    def _resolve_attention_form(self, head_dim: int) -> str:
+    def _resolve_attention_form(self, widths) -> str:
         """``"kernel"`` or ``"xla"``: see ``attention_form``."""
         return attention_form(
             self.mesh.devices.flat[0].platform, int(self.mesh.devices.size),
-            head_dim, self.config.horizon)
+            widths, self.config.horizon)
 
     def _in_attention_form(self, rollout):
         """``rollout`` traced in this engine's attention form: the policy's

@@ -16,7 +16,8 @@ from jax.flatten_util import ravel_pytree
 
 import moe_tiny
 from estorch_tpu.models import LoopedLM, MoELM, lm_blocks
-from estorch_tpu.models.perturbed import perturbed_grouped_dense
+from estorch_tpu.models.perturbed import (leaf_columns, perturbed_dense,
+                                          perturbed_grouped_dense)
 from estorch_tpu.ops.lowrank import (lowrank_tree_noise,
                                      lowrank_tree_weighted_sum,
                                      make_lowrank_tree_spec)
@@ -435,16 +436,24 @@ def test_one_core_for_the_three_models(tiny):
     np.testing.assert_allclose(got, ctx @ p["o"], atol=1e-5)
 
 
-@pytest.mark.parametrize("head_dim, length, want", [
-    (192, 4096, "xla"),        # latent attention's 128 + 64: not whole lanes
+@pytest.mark.parametrize("widths, length, want", [
+    # latent attention as the model states it: 128 a head scored with its
+    # own key, 64 with the ONE rotated key (two heads a lane block), values
+    # of 128
+    ((128, 64, 128), 4096, "kernel"),
+    ((128, 64, 128), 4000, "xla"),     # no block divides the sequence
+    ((128, 128, 128), 4096, "kernel"),  # a shared part of whole lane blocks
+    ((128, 32, 128), 4096, "xla"),     # four heads a block: not written
+    ((192, 0, 128), 4096, "xla"),      # the 192 unsplit: not whole lanes
+    ((128, 64, 64), 4096, "xla"),      # values of half a lane block
     (128, 4096, "kernel"),     # the looped model's, as before
     (64, 4096, "xla"),
     (256, 4096, "kernel"),
 ])
-def test_attention_form_reads_the_query_key_width(head_dim, length, want):
-    assert attention_form("tpu", 1, head_dim, length) == want
-    assert attention_form("cpu", 1, head_dim, length) == "xla"
-    assert attention_form("tpu", 4, head_dim, length) == "xla"
+def test_attention_form_reads_the_query_key_width(widths, length, want):
+    assert attention_form("tpu", 1, widths, length) == want
+    assert attention_form("cpu", 1, widths, length) == "xla"
+    assert attention_form("tpu", 4, widths, length) == "xla"
 
 
 def test_the_models_say_the_width_their_heads_are_scored_at(tiny):
@@ -453,22 +462,54 @@ def test_the_models_say_the_width_their_heads_are_scored_at(tiny):
     from estorch_tpu.models import HybridLM
 
     assert tiny["lm"].qk_head_dim == 8 + 4
-    assert LoopedLM(**loop_tiny.TINY).qk_head_dim == 8
+    assert tiny["lm"].attention_widths == (8, 4, 6)
+    assert LoopedLM(**loop_tiny.TINY).attention_widths == 8
     hybrid = HybridLM(**lm_tiny.TINY)
-    assert hybrid.qk_head_dim == hybrid.head_dim
+    assert hybrid.attention_widths == hybrid.head_dim
     published = MoELM(**moe_tiny.published()["build"]["kwargs"][
         "policy_kwargs"])
     assert published.qk_head_dim == 192
+    assert published.attention_widths == (128, 64, 128)
 
 
-def test_the_kernel_refuses_heads_of_two_widths():
+@pytest.mark.parametrize("noise_form", ["factored", "dense", "none"])
+@pytest.mark.parametrize("cols", [slice(0, 8), slice(8, None)],
+                         ids=["own", "rotated"])
+def test_a_projection_cut_by_columns_is_the_cut_of_the_projection(
+        noise_form, cols):
+    """``q_b`` is computed a part at a time (``leaf_columns``): the leaf
+    ``[m, heads · 12]`` and its noise cut to a head's first 8 columns or
+    its last 4 give those columns of the whole perturbed projection."""
+    m, nh, width, r = 10, 3, 12, 2
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(ks[0], (7, m))
+    w = jax.random.normal(ks[1], (m, nh * width))
+    noise = {"factored": (jax.random.normal(ks[2], (m, r)),
+                          jax.random.normal(ks[3], (nh * width, r))),
+             "dense": jax.random.normal(ks[2], (m, nh * width)),
+             "none": None}[noise_form]
+    whole = perturbed_dense(x, w, noise, 0.3).reshape(7, nh, width)
+    w_cut, noise_cut = leaf_columns(w, noise, nh, cols)
+    part = perturbed_dense(x, w_cut, noise_cut, 0.3)
+    np.testing.assert_allclose(part, whole[..., cols].reshape(7, -1),
+                               atol=1e-5)
+
+
+def test_the_kernel_takes_heads_of_two_widths():
+    """Heads 12 wide where they are scored and 6 where they are summed,
+    through the core inside a scope: the kernel, equal to the XLA form."""
     from estorch_tpu.ops.pallas_attention import kernel_scope
 
-    q = k = jnp.zeros((16, 2, 12))
-    with kernel_scope(interpret=True), pytest.raises(ValueError,
-                                                     match="one width"):
-        lm_blocks.attention_core(q, k, jnp.zeros((16, 2, 6)), num_heads=2,
-                                 num_kv_heads=2, scale=1.0, block=8)
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (16, 2, d))
+               for i, d in enumerate((12, 12, 6)))
+    kw = dict(num_heads=2, num_kv_heads=2, scale=0.3, block=8)
+    want = lm_blocks.attention_core(q, k, v, **kw)
+    with kernel_scope(interpret=True):
+        assert "pallas_call" in str(jax.make_jaxpr(
+            lambda *a: lm_blocks.attention_core(*a, **kw))(q, k, v))
+        got = lm_blocks.attention_core(q, k, v, **kw)
+    assert got.shape == (16, 2 * 6)
+    np.testing.assert_allclose(got, want, atol=1e-5)
 
 
 # ------------------------------------------------------ (f) the MTP term

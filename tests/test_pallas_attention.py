@@ -16,7 +16,8 @@ import pytest
 
 import lm_tiny
 import loop_tiny
-from estorch_tpu.models import HybridLM, LoopedLM, lm_blocks
+import moe_tiny
+from estorch_tpu.models import HybridLM, LoopedLM, MoELM, lm_blocks
 from estorch_tpu.ops import pallas_attention
 from estorch_tpu.ops.pallas_attention import (attention_form,
                                               causal_attention, kernel_block,
@@ -28,6 +29,22 @@ F32_TOL = 1e-5
 # bfloat16 operands and probabilities: measured up to 9e-3 on values of
 # magnitude 1 (one bfloat16 ulp there is 8e-3)
 BF16_TOL = 3e-2
+# rows 0, 13, 31 and every 21st column of the single-term kernel's context
+# for ``_parts(32, 4, 16, 8, 16, dtype, seed=4)`` at scale 0.25 in blocks
+# of (16, 8), as the kernel gave them BEFORE it had a second term (on this
+# sandbox both trees give the same 2,048 floats to the last bit: sha256
+# c9f9946e… and fcabeae9…, PR 34)
+SINGLE_TERM_PINNED = {
+    "f32": [[0.9168287515640259, -0.2092902511358261, 0.3858985900878906,
+             0.48941105604171753],
+            [-0.2431069314479828, 0.7872141003608704, 0.13836808502674103,
+             -0.2825695872306824],
+            [-0.1279822438955307, -0.250384658575058, -0.07654104381799698,
+             -0.08762632310390472]],
+    "bf16": [[0.91796875, -0.208984375, 0.38671875, 0.490234375],
+             [-0.244140625, 0.7890625, 0.138671875, -0.283203125],
+             [-0.1279296875, -0.25, -0.076171875, -0.08740234375]],
+}
 
 
 def _qkv(t, nq, nkv, dtype, seed=0, spread=1.0):
@@ -35,6 +52,18 @@ def _qkv(t, nq, nkv, dtype, seed=0, spread=1.0):
     return tuple(
         (spread * jax.random.normal(k, (t, n * HD), jnp.float32)).astype(dtype)
         for k, n in zip(ks, (nq, nkv, nkv)))
+
+
+def _parts(t, nh, head, shared, value, dtype, seed=0):
+    """``(q, k, v, q_shared, k_shared)`` of ``nh`` heads: each head's own
+    query/key part, its values, its second query part and the ONE key part
+    every head reads (the last two ``None`` where ``shared`` is 0)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shapes = ((t, nh * head), (t, nh * head), (t, nh * value),
+              (t, nh * shared), (t, shared))
+    parts = [jax.random.normal(k, s, jnp.float32).astype(dtype)
+             for k, s in zip(ks, shapes)]
+    return (*parts[:3], *(parts[3:] if shared else (None, None)))
 
 
 def _plain(q, k, v, nq, nkv, scale):
@@ -113,6 +142,134 @@ class TestKernelAgainstBothForms:
         np.testing.assert_allclose(
             _f32(_kernel(q, k, v, 4, 2, 0.25, block_q, block_k)),
             _f32(_plain(q, k, v, 4, 2, 0.25)), atol=F32_TOL)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("head, shared, value, block_q, block_k", [
+        (128, 64, 128, 128, 128),  # latent attention: two heads a lane block
+        (16, 8, 16, 16, 8),        # tiny, key blocks the diagonal crosses
+        (16, 8, 16, 8, 16),
+        (8, 4, 6, 32, 32),         # the tiny MoELM's: values of their own
+        (8, 128, 8, 16, 16),       # a shared part of whole lane blocks
+        (16, 0, 16, 16, 8),        # no shared part: the single-term kernel
+        (8, 0, 6, 8, 8),
+    ])
+    def test_shared_key_term_is_the_xla_form_of_the_core(
+            self, head, shared, value, block_q, block_k, dtype):
+        """The score as the SUM of two contractions, the second with ONE
+        key part every head reads, against the XLA form of
+        ``attention_core`` on the same parts (which concatenates them and
+        broadcasts the key part), directly and through the core's own
+        dispatch inside a scope."""
+        t, nh, scale = 256 if head == 128 else 32, 4, 0.3
+        q, k, v, qs, ks = _parts(t, nh, head, shared, value, dtype)
+        kw = dict(num_heads=nh, num_kv_heads=nh, scale=scale)
+        xla = lm_blocks.attention_core(q, k, v, q_shared=qs, k_shared=ks,
+                                       block=8, **kw)
+        assert xla.shape == (t, nh * value) and xla.dtype == dtype
+        got = causal_attention(
+            q, k, v, qs, ks, head_dim=head, value_dim=value, block_q=block_q,
+            block_k=block_k, interpret=True, **kw)
+        assert got.shape == xla.shape and got.dtype == dtype
+        tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
+        np.testing.assert_allclose(_f32(got), _f32(xla), atol=tol)
+        with kernel_scope(interpret=True):
+            scoped = lm_blocks.attention_core(
+                q, k, v, q_shared=qs, k_shared=ks, block=8, **kw)
+        np.testing.assert_allclose(_f32(scoped), _f32(xla), atol=tol)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("head, shared, block_q, block_k", [
+        (128, 64, 128, 128),   # latent attention's kv_b: [key | values] x 32
+        (16, 8, 16, 8),
+        (16, 0, 8, 16),        # no shared part
+    ])
+    def test_values_beside_their_keys_are_read_where_they_lie(
+            self, head, shared, block_q, block_k, dtype):
+        """``v=None``: each head's key with its values beside it in ONE
+        array, as one projection wrote them.  The kernel reads both out of
+        it (column blocks ``2g`` and ``2g + 1``) and gives, bit for bit,
+        what it gives for k and v cut apart; the core's XLA form cuts
+        them apart itself."""
+        t, nh = 256 if head == 128 else 32, 4
+        q, k, v, qs, ks = _parts(t, nh, head, shared, head, dtype, seed=2)
+        kv = jnp.concatenate([k.reshape(t, nh, head),
+                              v.reshape(t, nh, head)], axis=-1)
+        kw = dict(num_heads=nh, num_kv_heads=nh, scale=0.3)
+        apart = causal_attention(
+            q, k, v, qs, ks, head_dim=head, block_q=block_q, block_k=block_k,
+            interpret=True, **kw)
+        beside = causal_attention(
+            q, kv.reshape(t, -1), None, qs, ks, head_dim=head,
+            block_q=block_q, block_k=block_k, interpret=True, **kw)
+        np.testing.assert_array_equal(_f32(beside), _f32(apart))
+        xla = lm_blocks.attention_core(q, k, v, q_shared=qs, k_shared=ks,
+                                       block=8, **kw)
+        np.testing.assert_array_equal(
+            _f32(lm_blocks.attention_core(q, kv, None, q_shared=qs,
+                                          k_shared=ks, block=8, **kw)),
+            _f32(xla))
+        with kernel_scope(interpret=True):
+            scoped = lm_blocks.attention_core(q, kv, None, q_shared=qs,
+                                              k_shared=ks, block=8, **kw)
+        np.testing.assert_allclose(
+            _f32(scoped), _f32(xla),
+            atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_without_a_shared_part_the_kernel_is_the_single_term_one(
+            self, dtype):
+        """The shared term is a branch at trace time: with no shared part
+        the ``pallas_call`` takes q, k, v alone and scores with ONE
+        contraction of q and k as they are, as before; its output is what
+        the two-term kernel gives when the shared parts are zero, and the
+        values pinned above (the kernel before it had a second term gave
+        them on the same operands)."""
+        t, nh = 32, 4
+        q, k, v, qs, ks = _parts(t, nh, 16, 8, 16, dtype, seed=4)
+        kw = dict(num_heads=nh, num_kv_heads=nh, head_dim=16, scale=0.25,
+                  block_q=16, block_k=8, interpret=True)
+        one = jax.make_jaxpr(lambda *a: causal_attention(*a, **kw))(q, k, v)
+        two = jax.make_jaxpr(lambda *a: causal_attention(*a, **kw))(
+            q, k, v, qs, ks)
+        # Q·Kᵀ and P·V in each of the two folds (masked, not masked); the
+        # parts are put side by side in the two-term kernel alone (and
+        # [k, 0 | 0, k] is built before it)
+        assert (str(one).count("dot_general"),
+                str(two).count("dot_general")) == (4, 4)
+        assert (str(one).count("concatenate"),
+                str(two).count("concatenate")) == (0, 5)
+        single = causal_attention(q, k, v, **kw)
+        zeros = causal_attention(q, k, v, jnp.zeros_like(qs),
+                                 jnp.zeros_like(ks), **kw)
+        np.testing.assert_allclose(
+            _f32(single), _f32(zeros),
+            atol=1e-6 if dtype == jnp.float32 else 8e-3)
+        # the same bits here; elsewhere XLA:CPU may sum in another order
+        name, rtol = (("f32", 2e-6) if dtype == jnp.float32
+                      else ("bf16", 8e-3))
+        np.testing.assert_allclose(_f32(single)[[0, 13, 31], ::21],
+                                   SINGLE_TERM_PINNED[name], rtol=rtol)
+
+    @pytest.mark.parametrize("case, match", [
+        ("no key", "q_shared AND k_shared"), ("shape", "are not"),
+        ("odd heads", "odd"), ("beside", "one width")])
+    def test_shared_parts_are_validated(self, case, match):
+        q, k, v, qs, ks = _parts(16, 4, 8, 4, 8, jnp.float32)
+        kw = dict(num_heads=4, num_kv_heads=4, head_dim=8, scale=1.0,
+                  interpret=True)
+        if case == "no key":
+            ks = None
+        elif case == "shape":
+            qs = qs[:, :-4]
+        elif case == "beside":
+            # values beside their keys, but of another width
+            k, v = jnp.concatenate([k, v[:, :24]], axis=1), None
+            kw["value_dim"] = 6
+        else:
+            q, k, v, qs = q[:, :24], k[:, :24], v[:, :24], qs[:, :12]
+            kw.update(num_heads=3, num_kv_heads=3)
+        with pytest.raises(ValueError, match=match):
+            causal_attention(q, k, v, qs, ks, **kw)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_under_the_engines_nesting(self, dtype):
@@ -197,12 +354,18 @@ class TestKernelAgainstBothForms:
 # --------------------------------------------------------------- the rule
 
 class TestTheRule:
-    @pytest.mark.parametrize("platform, devices, head_dim, length, form", [
+    @pytest.mark.parametrize("platform, devices, widths, length, form", [
         ("tpu", 1, 128, 4096, "kernel"),   # ouro-2.6b-es-4k-1chip
         ("tpu", 4, 64, 4096, "xla"),       # granite-h-micro-es-4k-4chip
         ("tpu", 4, 128, 4096, "xla"),      # operands sharded: needs shard_map
         ("tpu", 1, 64, 4096, "xla"),       # a head is half a lane tile
-        ("tpu", 1, 192, 4096, "xla"),
+        ("tpu", 1, 192, 4096, "xla"),      # latent attention's, unsplit
+        ("tpu", 1, (128, 64, 128), 4096, "kernel"),  # joyai-flash-es-4k-1chip
+        ("tpu", 4, (128, 64, 128), 4096, "xla"),
+        ("tpu", 1, (128, 0, 128), 4096, "kernel"),   # no shared part
+        ("tpu", 1, (128, 64, 192), 4096, "xla"),     # values not whole lanes
+        ("tpu", 1, (128, 96, 128), 4096, "xla"),     # nor 64 nor whole lanes
+        ("cpu", 1, (128, 64, 128), 4096, "xla"),
         ("tpu", 1, 256, 4096, "kernel"),
         ("tpu", 1, 128, 4000, "xla"),      # no block divides the sequence
         ("tpu", 1, 128, 384, "kernel"),    # three blocks of 128
@@ -213,8 +376,8 @@ class TestTheRule:
         ("gpu", 1, 128, 4096, "xla"),
     ])
     def test_form_from_what_the_engine_observes(self, platform, devices,
-                                                head_dim, length, form):
-        assert attention_form(platform, devices, head_dim, length) == form
+                                                widths, length, form):
+        assert attention_form(platform, devices, widths, length) == form
 
     @pytest.mark.parametrize("length, block", [
         (4096, 1024), (1024, 1024), (1536, 512), (768, 256), (384, 128),
@@ -246,8 +409,9 @@ class TestTheRule:
         assert "pallas_call" in inside
 
     def test_models_have_the_head_size_es_hands_the_engine(self):
-        assert LoopedLM(**loop_tiny.TINY).head_dim == 8
-        assert HybridLM(**lm_tiny.TINY).head_dim == 8
+        assert LoopedLM(**loop_tiny.TINY).attention_widths == 8
+        assert HybridLM(**lm_tiny.TINY).attention_widths == 8
+        assert MoELM(**moe_tiny.TINY).attention_widths == (8, 4, 6)
 
 
 # ----------------------------------------------------- through the engine
@@ -256,8 +420,9 @@ def _lm_es(devices, model_shards=1, policy=LoopedLM, **over):
     from estorch_tpu import ES, JaxAgent
     from estorch_tpu.envs import TokenScoreEnv
 
-    tiny, env = ((loop_tiny.TINY, loop_tiny.ENV) if policy is LoopedLM
-                 else (lm_tiny.TINY, lm_tiny.ENV))
+    tiny, env = {LoopedLM: (loop_tiny.TINY, loop_tiny.ENV),
+                 HybridLM: (lm_tiny.TINY, lm_tiny.ENV),
+                 MoELM: (moe_tiny.TINY, moe_tiny.ENV)}[policy]
     kw = dict(
         policy=policy, agent=JaxAgent, optimizer=optax.adam,
         population_size=8, sigma=0.02, policy_kwargs=tiny,
@@ -284,14 +449,14 @@ def kernel_attention(monkeypatch):
     def forced():
         with monkeypatch.context() as m:
             m.setattr(ShardedESEngine, "_resolve_attention_form",
-                      lambda self, head_dim: "kernel")
+                      lambda self, widths: "kernel")
             yield
 
     return forced
 
 
 class TestThroughTheShardedEngine:
-    @pytest.mark.parametrize("policy", [LoopedLM, HybridLM])
+    @pytest.mark.parametrize("policy", [LoopedLM, HybridLM, MoELM])
     @pytest.mark.parametrize("n_devices, model_shards", [(1, 1), (4, 2)])
     def test_every_cpu_mesh_resolves_xla(self, devices8, policy, n_devices,
                                          model_shards):
@@ -314,7 +479,7 @@ class TestThroughTheShardedEngine:
         assert es.run_manifest()["config"]["attention_form"] is None
         assert "attention_form" not in es.obs.counters.snapshot()
 
-    @pytest.mark.parametrize("policy", [LoopedLM, HybridLM])
+    @pytest.mark.parametrize("policy", [LoopedLM, HybridLM, MoELM])
     def test_forced_kernel_runs_the_generation_the_xla_form_runs(
             self, devices8, kernel_attention, policy):
         """Two generations through ``ES.train`` on one device, the policy's

@@ -318,43 +318,64 @@ def test_row_kernels_compile_for_the_v5e_at_the_cells_widths(
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads, shared", [(16, 0), (32, 64), (32, 128)],
+                         ids=["looped", "latent", "shared128"])
 def test_attention_kernel_compiles_for_the_v5e_at_the_looped_cells_shapes(
-        dtype, v5e_chip):
+        heads, shared, dtype, v5e_chip):
     """Mosaic accepts the attention kernel at ``ouro-2.6b-es-4k-1chip``'s
     shapes (16 heads of 128 as column blocks of a [4096, 2048] array, the
     kernel's own blocks, one pair's two signs through ``vmap``), with
-    nothing else in the program: no score tensor, no copy."""
+    nothing else in the program: no score tensor, no copy; and at
+    ``joyai-flash-es-4k-1chip``'s (32 heads of 128 with a second score
+    term of 64, two heads a lane block, against ONE key part; keys and
+    values column blocks of ONE ``[4096, 32 · 256]`` array), where the
+    only other operations lay that key part out as ``[k, 0 | 0, k]``."""
     from jax.sharding import SingleDeviceSharding
 
     from estorch_tpu.ops.pallas_attention import causal_attention
 
-    operand = jax.ShapeDtypeStruct(
-        (1, 2, 4096, 16 * 128), dtype,
-        sharding=SingleDeviceSharding(v5e_chip))
-    text = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
-        q, k, v, num_heads=16, num_kv_heads=16, head_dim=128,
-        scale=128 ** -0.5, interpret=False)))).lower(
-            operand, operand, operand).compile().as_text()
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (1, 2, 4096, width), dtype,
+            sharding=SingleDeviceSharding(v5e_chip))
+
+    # latent attention's kv_b writes each head's key with its values
+    # beside it, and the kernel reads both out of that one array
+    beside = shared == 64
+    parts = (operand(heads * 128),
+             operand(heads * (256 if beside else 128)),
+             None if beside else operand(heads * 128)) + (
+        (operand(heads * shared), operand(shared)) if shared else ())
+    text = jax.jit(jax.vmap(jax.vmap(lambda *parts: causal_attention(
+        *parts, num_heads=heads, num_kv_heads=heads, head_dim=128,
+        scale=(128 + shared) ** -0.5, interpret=False)))).lower(
+            *parts).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "f32[" not in text.split("ENTRY")[1] or dtype == jnp.float32
+    assert shared or " copy(" not in text.split("ENTRY")[1]
 
 
-def _looped_engine_on(devices, model_shards, head_dim, length):
+def _looped_engine_on(devices, model_shards, head_dim, length, latent=False):
     """A small looped model's sharded engine on a mesh of described TPU
     ``devices``: its pieces from an ES built on the CPU, as the engine of
-    a chip run would get them."""
+    a chip run would get them.  ``latent``: a small sparse-expert model
+    with latent attention instead, heads ``head_dim`` + 64 shared wide."""
     from estorch_tpu.envs import TokenScoreEnv
-    from estorch_tpu.models import LoopedLM
+    from estorch_tpu.models import LoopedLM, MoELM
     from estorch_tpu.parallel.mesh import hyperscale_mesh
     from estorch_tpu.parallel.sharded import ShardedESEngine
 
+    sizes = dict(vocab_size=256, hidden_size=128, intermediate_size=256,
+                 num_attention_heads=2, attention_block=128, head_block=128)
     es = _es(
-        policy=LoopedLM, population_size=4, sigma=0.02,
+        policy=MoELM if latent else LoopedLM, population_size=4, sigma=0.02,
         policy_kwargs=dict(
-            layer_types=("full_attention",), vocab_size=256, hidden_size=128,
-            intermediate_size=256, num_attention_heads=2,
-            num_key_value_heads=1, head_dim=head_dim, total_ut_steps=2,
-            attention_block=128, head_block=128),
+            layer_types=("moe",), moe_intermediate_size=64, q_lora_rank=32,
+            kv_lora_rank=32, qk_nope_head_dim=head_dim, qk_rope_head_dim=64,
+            v_head_dim=head_dim, n_routed_experts=4, **sizes) if latent
+        else dict(
+            layer_types=("full_attention",), num_key_value_heads=1,
+            head_dim=head_dim, total_ut_steps=2, **sizes),
         agent_kwargs={"env": TokenScoreEnv(
             vocab_size=256, seq_len=length, corpus_sequences=4)},
         shard_params=True, low_rank=1, noise_mode="table",
@@ -369,7 +390,10 @@ def _looped_engine_on(devices, model_shards, head_dim, length):
         partition_rules=es._partition_rules, noise_mode="table",
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
         leaf_rows=es.module.leaf_rows,
-        attention_head_dim=es.module.head_dim)
+        attention_widths=es.module.attention_widths,
+        leaf_rows_per_token=getattr(es.module, "leaf_rows_per_token", None),
+        float32_leaves=getattr(es.module, "float32_leaves", ()),
+        expert_load=latent)
     return es, engine
 
 
@@ -390,12 +414,15 @@ def test_attention_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim,
     assert engine.attention_form == form
 
 
-def test_kernel_form_books_its_kernel_to_attn(v5e_chip):
+@pytest.mark.parametrize("latent", [False, True], ids=["looped", "latent"])
+def test_kernel_form_books_its_kernel_to_attn(latent, v5e_chip):
     """On a one-device TPU mesh the engine takes the attention kernel by
     itself, and the Mosaic custom call of the compiled generation program
     sits under es.attn inside es.policy, where the XLA form's score
-    fusions were: the device trace books it to ``loop.attn_share``."""
-    es, engine = _looped_engine_on([v5e_chip], 1, 128, 256)
+    fusions were: the device trace books it to ``loop.attn_share``
+    (``moe.attn_share`` for latent attention, whose widths 128 + 64 shared
+    the rule is told by the model)."""
+    es, engine = _looped_engine_on([v5e_chip], 1, 128, 256, latent=latent)
     assert engine.attention_form == "kernel"
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
@@ -403,8 +430,9 @@ def test_kernel_form_books_its_kernel_to_attn(v5e_chip):
     table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
                                  sharding=engine._repl)
     text = engine._generation_step.lower(state, table).compile().as_text()
+    # (a sparse-expert program's grouped matmuls are custom calls too)
     kernels = [(name, SCOPE.findall(name)) for line in text.splitlines()
-               if "tpu_custom_call" in line
+               if "tpu_custom_call" in line and "ragged" not in line
                for name in re.findall(r'op_name="([^"]*)"', line)]
     assert kernels and all(
         stack[-2:] == [POLICY, ATTN] and "causal_attention" in name
