@@ -982,9 +982,17 @@ class ES:
         parameter tree, population/horizon/noise-representation from the
         config.  Diagnostic only — returns None rather than ever failing
         construction (an exotic policy without 2-D kernels has no matmul
-        model, and that is a note in ``obs profile``, not an error)."""
+        model, and that is a note in ``obs profile``, not an error).
+
+        A sequence policy (one that states ``_sequence_facts``) is NOT
+        modelled: "every 2-D leaf is a matmul of one env-step" would count
+        an untied embedding as a matmul and leave out stacked experts and
+        attention, and ``obs profile`` says nothing rather than something
+        wrong (the benchmark's ``part.*`` metrics count those models)."""
         from ..obs.profile.costmodel import generation_cost
 
+        if self._sequence_facts():
+            return None
         try:
             if self.backend == "host":
                 params = list(self.engine.master.parameters())

@@ -54,7 +54,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import HEAD, SSM, stage
+from ..obs.trace import HEAD, SSM, part, stage
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm as _rmsnorm, subtree
 from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
@@ -216,13 +216,15 @@ class HybridLM:
         def tied_head(h_b):
             return perturbed_dense(h_b, table, e_noise, c, transposed=True)
 
+        # the head reads the embedding transposed: its part is ``embed``
         return lm_blocks.score_next_tokens(
-            h, tokens, tied_head, self.head_block, self.logits_scaling)
+            h, tokens, tied_head, self.head_block, self.logits_scaling,
+            leaf="embed")
 
     def logits(self, params, tokens, noise=None, c=0.0):
         h = self.hidden(params, noise, c, tokens)
         e_noise = subtree(noise, "embed", "embedding")
-        with stage(HEAD):
+        with stage(HEAD), part("embed"):
             return perturbed_dense(
                 h, params["embed"]["embedding"], e_noise, c, transposed=True
             ) / self.logits_scaling

@@ -72,7 +72,7 @@ import jax.numpy as jnp
 import functools
 
 from ..obs.trace import (ATTN, DENSE, DISPATCH, EXPERT, HEAD, ROPE, ROUTE,
-                         stage)
+                         part, stage)
 from ..ops import pallas_attention
 from .perturbed import (F32, perturbed_dense, perturbed_grouped_dense,
                         perturbed_leaf)
@@ -102,8 +102,10 @@ def rmsnorm(x, scale, eps):
 
 
 def dense(p, noise, c, name, x):
-    """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``."""
-    with stage(DENSE):
+    """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``, the
+    part ``of.<name>``: every projection of the three models says here
+    which leaf it multiplies (obs/trace.py)."""
+    with stage(DENSE), part(name):
         return perturbed_dense(
             x, p[name], None if noise is None else noise[name], c)
 
@@ -122,7 +124,7 @@ def gated_mlp(dense, p, noise, c, u):
     dtype = u.dtype
     gate = dense(p, noise, c, "gate", u)
     up = dense(p, noise, c, "up", u)
-    with stage(DENSE):
+    with stage(DENSE), part("down"):    # the operand ``down`` multiplies
         act = (jax.nn.silu(gate) * up).astype(dtype)
     return dense(p, noise, c, "down", act)
 
@@ -266,9 +268,10 @@ def route(p, noise, c, u, *, top_k: int, scaling: float):
     matmul at ``highest`` precision: a rounding of the scores picks another
     expert."""
     with stage(ROUTE), jax.default_matmul_precision("highest"):
-        s = jax.nn.sigmoid(perturbed_dense(
-            u.astype(F32), p["router"].astype(F32),
-            None if noise is None else noise["router"], c))
+        with part("router"):
+            s = jax.nn.sigmoid(perturbed_dense(
+                u.astype(F32), p["router"].astype(F32),
+                None if noise is None else noise["router"], c))
         bias = perturbed_leaf(
             p["router_bias"],
             None if noise is None else noise["router_bias"], c)
@@ -379,9 +382,11 @@ def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
         x_flat, w_flat = u.reshape(tokens, hidden), weights.reshape(pairs)
 
     def grouped(name, x, sizes, row_expert, row_member):
-        return perturbed_grouped_dense(
-            x, centre[name], sizes, None if noise is None else noise[name],
-            c, row_expert, row_member)
+        with part(name):
+            return perturbed_grouped_dense(
+                x, centre[name], sizes,
+                None if noise is None else noise[name], c, row_expert,
+                row_member)
 
     def one_pass(carry):
         start, y = carry
@@ -397,10 +402,12 @@ def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
             x = jnp.take(x_flat, token, axis=0)
             w = jnp.where(valid, jnp.take(
                 w_flat, jnp.minimum(pair, pairs - 1)), 0.0)
-        with stage(EXPERT):
+        # the routed experts' leaves read ``experts.gate`` … in a trace
+        with stage(EXPERT), part("experts"):
             gate = grouped("gate", x, sizes, row_expert, row_member)
             up = grouped("up", x, sizes, row_expert, row_member)
-            act = (jax.nn.silu(gate) * up).astype(x.dtype)
+            with part("down"):
+                act = (jax.nn.silu(gate) * up).astype(x.dtype)
             out = grouped("down", act, sizes, row_expert, row_member)
         with stage(DISPATCH):
             # the rows past the routed ones land nowhere
@@ -414,13 +421,16 @@ def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
     return y.reshape(n_members, t, hidden), load
 
 
-def score_next_tokens(h, tokens, project, block: int, logits_scaling=None):
+def score_next_tokens(h, tokens, project, block: int, logits_scaling=None,
+                      *, leaf: str):
     """``(log p(tokens[t+1] | tokens[:t+1]) [T-1], the last position's
     logits [vocab])`` float32 from the hidden states ``h [T, hidden]``, in
     blocks of ``block`` positions so that the ``[T, vocab]`` logits never
     exist.  ``project(h_block)`` is the model's head matmul (tied or not),
     float32; the logits are divided by ``logits_scaling`` where the model
-    has one."""
+    has one.  ``leaf`` is the key of the leaf ``project`` multiplies
+    (``"head"``, a tied ``"embed"``): the logits, their log-softmax and
+    the picked scores are that part of ``es.head`` (obs/trace.py)."""
 
     def scaled(y):
         return y if logits_scaling is None else y / logits_scaling
@@ -435,7 +445,7 @@ def score_next_tokens(h, tokens, project, block: int, logits_scaling=None):
 
     def score(xs):
         h_b, tgt_b = xs
-        with stage(HEAD):
+        with stage(HEAD), part(leaf):
             logits = scaled(project(h_b))
             lse = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(
@@ -445,6 +455,6 @@ def score_next_tokens(h, tokens, project, block: int, logits_scaling=None):
     logp = jax.lax.map(score, (
         hp.reshape(n_blocks, block, -1),
         targets.reshape(n_blocks, block)))
-    with stage(HEAD):
+    with stage(HEAD), part(leaf):
         last = scaled(project(h[-1:])[0])
     return logp.reshape(-1)[:t - 1], last

@@ -51,7 +51,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import EXIT, stage
+from ..obs.trace import EXIT, part, stage
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
 from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
@@ -215,14 +215,16 @@ class LoopedLM:
                                                             "kernel")
         logp, last = lm_blocks.score_next_tokens(
             h, tokens, lambda h_b: perturbed_dense(h_b, kernel, k_noise, c),
-            self.head_block)
+            self.head_block, leaf="head")
         with stage(EXIT):
             gate = params["exit_gate"]
-            lam = jax.nn.sigmoid(
-                perturbed_dense(h, gate["kernel"],
-                                subtree(noise, "exit_gate", "kernel"), c)[:, 0]
-                + perturbed_leaf(gate["bias"],
-                                 subtree(noise, "exit_gate", "bias"), c))
+            with part("exit_gate"):
+                lam = jax.nn.sigmoid(
+                    perturbed_dense(
+                        h, gate["kernel"],
+                        subtree(noise, "exit_gate", "kernel"), c)[:, 0]
+                    + perturbed_leaf(gate["bias"],
+                                     subtree(noise, "exit_gate", "bias"), c))
             # the last pass takes whatever probability is left
             exit_p = jnp.where(step == self.total_ut_steps - 1, remain,
                                lam * remain)

@@ -69,7 +69,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import DENSE, HEAD, ROPE, stage
+from ..obs.trace import DENSE, HEAD, ROPE, part, stage
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
 from .perturbed import (F32, leaf_columns, perturbed_dense, perturbed_embed,
@@ -301,11 +301,13 @@ class MoELM:
         def norm(p, n, name, y):
             return self._norm(p, n, c, name, y)
 
+        def head(h_b):
+            return perturbed_dense(h_b, kernel, k_noise, c)
+
         def scored(h32, targets):
             return lm_blocks.score_next_tokens(
-                h32.astype(dtype), targets,
-                lambda h_b: perturbed_dense(h_b, kernel, k_noise, c),
-                self.head_block)
+                h32.astype(dtype), targets, head, self.head_block,
+                leaf="head")
 
         x = perturbed_embed(tokens, table, t_noise, c)
         load = jnp.zeros((self.n_routed_experts,), jnp.int32)
@@ -316,10 +318,9 @@ class MoELM:
             load = load + n_pairs
         h = norm(params, noise, "final_norm", x)
         main, _ = scored(h, tokens)
-        with stage(HEAD):
-            last = jnp.mean(perturbed_dense(
-                h.astype(dtype)[-self.behaviour_positions:], kernel, k_noise,
-                c), axis=0)
+        with stage(HEAD), part("head"):
+            last = jnp.mean(head(h.astype(dtype)[-self.behaviour_positions:]),
+                            axis=0)
 
         # the MTP module reads position t's state beside token t+1 and
         # predicts token t+2; the last position has no next token (it is
@@ -327,10 +328,11 @@ class MoELM:
         p, n = params["mtp"], subtree(noise, "mtp")
         shifted = jnp.concatenate([tokens[1:], jnp.zeros((1,), tokens.dtype)])
         with stage(DENSE):
-            both = jnp.concatenate(
-                [norm(p, n, "embed_norm",
-                      perturbed_embed(shifted, table, t_noise, c)),
-                 norm(p, n, "hidden_norm", h)], axis=-1).astype(dtype)
+            rows = perturbed_embed(shifted, table, t_noise, c)
+            with part("eh"):        # the operand ``eh`` multiplies
+                both = jnp.concatenate(
+                    [norm(p, n, "embed_norm", rows),
+                     norm(p, n, "hidden_norm", h)], axis=-1).astype(dtype)
         z = lm_blocks.dense(p, n, c, "eh", both)
         z, n_pairs = self._layer(MOE_LAYER, p["layer"], subtree(n, "layer"),
                                  c, z, rotary, dtype)
@@ -374,9 +376,10 @@ class MoELM:
             moe["experts"], subtree(m_noise, "experts"), c, u.astype(dtype),
             experts, weights, first_held=self.first_expert_held,
             total=self.experts_total)
-        shared = lm_blocks.gated_mlp(
-            self._dense, moe["shared"], subtree(m_noise, "shared"), c,
-            u.astype(dtype))
+        u = u.astype(dtype)
+        with part("shared"):    # its leaves read ``shared.gate`` … in a trace
+            shared = lm_blocks.gated_mlp(
+                self._dense, moe["shared"], subtree(m_noise, "shared"), c, u)
         return x + shared + routed, load
 
     def _attention(self, p, noise, c, u, rotary):

@@ -19,7 +19,10 @@ the correction is per row from its (member, expert)'s factor pair.
 The embedding lookup and the tied head take the same factors:
 ``E[tok] + c·A[tok]·Bᵀ/√r`` and ``h@Eᵀ + c·(h@B)@Aᵀ/√r``.  Both dots
 accumulate in float32 and the sum is formed in float32; callers cast once.
-The corrections carry the ``es.perturb`` stage scope (obs/trace.py).
+The corrections carry the ``es.perturb`` stage scope (obs/trace.py); which
+leaf a product reads is the CALLER's to say (``part``: ``lm_blocks.dense``
+for every projection, the models for their heads), but for the embedding
+lookup, which has one name everywhere and says ``of.embed`` itself.
 """
 
 from __future__ import annotations
@@ -27,7 +30,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import PERTURB, stage
+from ..obs.trace import PERTURB, part, stage
 
 F32 = jnp.float32
 
@@ -124,16 +127,17 @@ def perturbed_grouped_dense(x, w, group_sizes, noise, c, row_expert,
 def perturbed_embed(tokens, table, noise, c):
     """float32 rows ``(E + c·A·Bᵀ/√r)[tokens]``: the lookup reads the
     centre's rows and the factor ``A``'s rows, never a perturbed table."""
-    rows = jnp.take(table, tokens, axis=0).astype(F32)
-    if noise is None:
-        return rows
-    with stage(PERTURB):
-        if not is_factored(noise):
-            return rows + c * jnp.take(noise, tokens, axis=0).astype(F32)
-        a, b = noise
-        scale = c / jnp.sqrt(jnp.asarray(a.shape[-1], F32))
-        return rows + scale * _outer(jnp.take(a, tokens, axis=0).astype(F32),
-                                     b)
+    with part("embed"):
+        rows = jnp.take(table, tokens, axis=0).astype(F32)
+        if noise is None:
+            return rows
+        with stage(PERTURB):
+            if not is_factored(noise):
+                return rows + c * jnp.take(noise, tokens, axis=0).astype(F32)
+            a, b = noise
+            scale = c / jnp.sqrt(jnp.asarray(a.shape[-1], F32))
+            return rows + scale * _outer(
+                jnp.take(a, tokens, axis=0).astype(F32), b)
 
 
 def perturbed_leaf(w, noise, c):

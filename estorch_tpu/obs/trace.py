@@ -3,7 +3,7 @@ program, and the host's spans on the profiler's clock.
 
 Span telemetry (obs/spans.py) answers "which phase got slower" for free
 on every run; a ``jax.profiler`` trace is the heavyweight next step when
-a phase needs opening up.  Three hooks make such a trace readable:
+a phase needs opening up.  Four hooks make such a trace readable:
 
 - ``trace(logdir)``: context manager around ``jax.profiler`` producing a
   Perfetto/XPlane trace of the compiled generation programs;
@@ -14,6 +14,18 @@ a phase needs opening up.  Three hooks make such a trace readable:
   Scopes are metadata only: the executable's instructions and the
   compile-cache key do not change.  An operation belongs to the INNERMOST
   ``es.<stage>`` of its name stack; a fusion to the stage of its root;
+- ``part(name)``: the name-stack scope ``of.<name>`` beneath a stage, for
+  the parameter leaf an operation multiplies.  The name IS the leaf's key
+  in the model's parameter tree (``gate``, ``q``, ``in_x``, ``kv_b``,
+  ``head``, ``embed`` ...), the word ``parallel/mesh.py``'s partition
+  rules and ``ops/lowrank.py``'s specs already use: no second list to keep
+  in step with the models.  Parts nest into a path where one key serves
+  two places the caller can tell apart (``of.shared/.../of.gate`` reads
+  ``shared.gate``).  A part is no stage: ``of.`` never matches a stage
+  scope, so every operation books to the stage it booked to before, and a
+  correction the compiler did not fuse into its projection reads
+  ``.../es.dense/of.gate/es.perturb``.  A new projection gets its part by
+  going through ``models/lm_blocks.dense``;
 - ``annotate(name, **ids)``: ``jax.profiler.TraceAnnotation``, a host
   span in the same trace.  Every ``Telemetry.phase`` enters one, so
   ``dispatch``/``device``/``host_sync``/``record`` sit on a ``/host:CPU``
@@ -30,8 +42,12 @@ directory of its own, or with
 from __future__ import annotations
 
 import contextlib
+import re
 
 SCOPE_PREFIX = "es."
+# a part's scope: a prefix of its own, which no stage scope can match
+PART_PREFIX = "of."
+_PART_NAME = re.compile(r"[A-Za-z0-9_]+(?:\.[A-Za-z0-9_]+)*")
 
 # the stages of one generation, in program order (docs/observability.md)
 STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
@@ -73,6 +89,20 @@ def stage(name: str):
     if name not in STAGES:
         raise ValueError(f"unknown stage {name!r}; the stages are {STAGES}")
     return jax.named_scope(SCOPE_PREFIX + name)
+
+
+def part(name: str):
+    """Name-stack scope ``of.<name>`` of the parameter leaf the operations
+    inside multiply (or prepare the operand of); entered inside a stage.
+    ``name`` is the leaf's key, words of letters, digits and ``_`` joined
+    by ``.``.  Trace-time only, as :func:`stage`; this module stays the
+    one caller of ``jax.named_scope``."""
+    import jax
+
+    if not _PART_NAME.fullmatch(name):
+        raise ValueError(f"a part is named by a parameter leaf's key, words "
+                         f"joined by '.'; got {name!r}")
+    return jax.named_scope(PART_PREFIX + name)
 
 
 @contextlib.contextmanager

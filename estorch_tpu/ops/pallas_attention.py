@@ -192,6 +192,36 @@ def _last_visible(i, block_q: int, block_k: int):
     return ((i + 1) * block_q - 1) // block_k
 
 
+def attention_cost(length: int, num_heads: int, num_kv_heads: int,
+                   head_dim: int, value_dim: int, shared_dim: int,
+                   block_q: int, block_k: int,
+                   itemsize: int) -> pl.CostEstimate:
+    """What ONE call of the kernel does, from its grid and blocks: the
+    declaration ``pallas_call`` hands XLA (the scheduler reads it, and a
+    profiler's trace carries it as the custom call's ``flops`` and
+    ``bytes_accessed``, which XLA cannot count for a Mosaic body).  FLOPs:
+    the tiles the kernel COMPUTES (key blocks up to the query block's last
+    row; the grid steps beyond are skipped and not counted), each ``2 ·
+    block_q · block_k · (head_dim + shared_dim + value_dim)``: the widths
+    the model states, although the MXU contracts a shared 64 as 128 deep
+    and multiplies the masked half of a diagonal tile too.
+    Transcendentals: a tile's exponentials, one a score and one a row for
+    the rescaling.  Bytes: q, k, v, the shared parts and the context ONCE
+    each (the algorithm's least; k and v are fetched again for every query
+    block that sees them, 2.5 times at four blocks).  ``vmap`` scales all
+    three by the members in front of the grid."""
+    tiles = sum(_last_visible(i, block_q, block_k) + 1
+                for i in range(length // block_q))
+    elements = length * (
+        num_heads * (head_dim + shared_dim + value_dim)        # q, q_shared, out
+        + num_kv_heads * (head_dim + value_dim) + shared_dim)  # k, v, k_shared
+    return pl.CostEstimate(
+        flops=2 * num_heads * tiles * block_q * block_k
+        * (head_dim + shared_dim + value_dim),
+        transcendentals=num_heads * tiles * block_q * (block_k + 1),
+        bytes_accessed=elements * itemsize)
+
+
 def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
                       block_k: int):
     # with a shared score term: each head's second query part, the one key
@@ -340,6 +370,11 @@ def causal_attention(
     shared = q_shared is not None
     if shared != (k_shared is not None):
         raise ValueError("a shared score term needs q_shared AND k_shared")
+    # from the widths the model states, before a shared 64 is packed below
+    cost = attention_cost(
+        t, num_heads, num_kv_heads, head_dim, value_dim,
+        k_shared.shape[-1] if shared else 0, block_q, block_k,
+        q.dtype.itemsize)
     if shared:
         width = k_shared.shape[-1]
         if (q_shared.shape != (t, num_heads * width)
@@ -391,6 +426,7 @@ def causal_attention(
         out_shape=jax.ShapeDtypeStruct((t, num_heads * value_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=cost,
         name="causal_attention",
         interpret=interpret,
     )(*operands)

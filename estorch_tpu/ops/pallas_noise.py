@@ -151,6 +151,22 @@ def rows_fit_dma(table_data: jax.Array, dim: int) -> bool:
             and size >= window_rows * LANES)
 
 
+def row_kernel_cost(n: int, window_rows: int, rows_out: int,
+                    out_itemsize: int, summed: bool) -> pl.CostEstimate:
+    """What one call of a row kernel over ``n`` noise rows does, from its
+    geometry: the declaration ``pallas_call`` hands XLA (a profiler's
+    trace carries it as the custom call's ``flops`` and
+    ``bytes_accessed``).  Bytes: every row's aligned f32 window read from
+    the table, plus what is written: the ``(n, rows_out, 128)`` slab in
+    the rows' dtype for the gather; ONE f32 ``(rows_out, 128)`` sum, with
+    a multiply and an add a float and row, where ``summed``."""
+    read = n * window_rows * LANES * 4
+    written = (1 if summed else n) * rows_out * LANES * out_itemsize
+    return pl.CostEstimate(
+        flops=2 * n * rows_out * LANES if summed else 0,
+        transcendentals=0, bytes_accessed=read + written)
+
+
 def _this_step_row(offs_ref, table_ref, buf, sem, t_rows: int, n: int,
                    rows_out: int) -> jax.Array:
     """Grid step ``i`` of a row kernel over ``n`` rows: noise row ``i``,
@@ -233,6 +249,8 @@ def gather_noise_rows(
         out_shape=jax.ShapeDtypeStruct((n, rows_out, LANES), dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(window_rows)),
+        cost_estimate=row_kernel_cost(n, window_rows, rows_out,
+                                      dtype.itemsize, summed=False),
         interpret=interpret,
     )(offsets.astype(jnp.int32), table_data.reshape(t_rows, LANES))
     return out.reshape(n, rows_out * LANES)[:, :dim]
@@ -289,6 +307,8 @@ def weighted_noise_sum(
         out_shape=jax.ShapeDtypeStruct((rows_out, LANES), table_data.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(window_rows)),
+        cost_estimate=row_kernel_cost(n, window_rows, rows_out, 4,
+                                      summed=True),
         interpret=interpret,
     )(
         offsets.astype(jnp.int32),

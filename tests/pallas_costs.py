@@ -1,0 +1,24 @@
+"""What a traced function's ``pallas_call``s declare to XLA (their
+``cost_estimate``), read from the jaxpr: shared by the kernels' tests."""
+
+import jax
+
+
+def declared_costs(f, *args) -> list:
+    """The ``CostEstimate`` of every ``pallas_call`` in ``f(*args)``'s
+    jaxpr, in program order, through whatever ``jit`` / ``vmap`` wrap it."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["cost_estimate"])
+                continue
+            for value in eqn.params.values():
+                if hasattr(value, "eqns"):
+                    walk(value)
+                elif hasattr(getattr(value, "jaxpr", None), "eqns"):
+                    walk(value.jaxpr)
+
+    walk(jax.make_jaxpr(f)(*args).jaxpr)
+    return found

@@ -582,6 +582,7 @@ class TestMixedSchemaBaselines:
         base = str(tmp_path / "BENCH_r06.json")
         self._r06(base, eval_s=0.05)  # baseline 37% faster at eval
         v = compare_phase_files(cur, base)
+        print("REGRESS_VERDICT", v)
         assert v["verdict"] == "regress"
         assert v["regressed_phases"] == ["eval"]
 
@@ -765,10 +766,13 @@ class TestExportE2E:
         scrape_errors: list[str] = []
         stop = threading.Event()
 
+        # the sidecar listens from its constructor on (bind + listen), so
+        # the first scrape needs no wait; a scrape may be slow on a host
+        # that six test workers and their children share, never unanswered
         def scraper():
             while not stop.is_set():
                 try:
-                    with urllib.request.urlopen(url, timeout=10) as r:
+                    with urllib.request.urlopen(url, timeout=120) as r:
                         body = r.read().decode()
                     series.append(samples_by_name(parse_exposition(body)))
                 except Exception as e:  # noqa: BLE001 — collected and
@@ -784,13 +788,14 @@ class TestExportE2E:
                              startup_grace_s=300.0)
             res = sup.run()
             # one last scrape AFTER the final publish: the post-run truth
-            with urllib.request.urlopen(url, timeout=10) as r:
+            with urllib.request.urlopen(url, timeout=120) as r:
                 series.append(samples_by_name(
                     parse_exposition(r.read().decode())))
         finally:
             stop.set()
-            t.join(timeout=10)
+            t.join(timeout=130)
             sc.close()
+        assert not t.is_alive(), "the scraper never came back"
         assert res["ok"], f"supervisor failed: {res}"
         assert len(res["restarts"]) == 1  # exactly the gen-5 SIGKILL
 
@@ -834,14 +839,37 @@ class TestExportE2E:
         clean = tmp_path / "BENCH_clean.json"
         clean.write_text(json.dumps({"parsed": {
             "metric": "env_steps_per_sec", "value": med}}))
-        assert obs_main(["regress", str(root / "run.jsonl"),
-                         "--baseline", str(clean), "--json"]) == 0
-        # a copied baseline claiming 2.5x the measured rate = a 60% drop,
-        # far outside any band this noisy host can legitimately learn
+
+        def verdict(baseline):
+            code = obs_main(["regress", str(root / "run.jsonl"),
+                             "--baseline", str(baseline), "--json"])
+            out = capsys.readouterr().out.strip().splitlines()[-1]
+            return code, json.loads(out)
+
+        code, v = verdict(clean)
+        assert code == 0 and v["verdict"] == "pass", v
+        # the slowdown is injected RELATIVE to the band the gate learned
+        # from this run's eight millisecond generations (1.48 MAD /
+        # median).  On a host shared with five other test workers that
+        # band has read 24% in one run and 98% in the next (rates of one
+        # run spread over a decade), so a fixed "2.5x the measured rate =
+        # a 60% drop" was flagged in one and swallowed in the other: the
+        # flake of ROADMAP D10.  A baseline whose drop lies halfway
+        # between the band and 100% is beyond ANY band under 100%
+        band = v["band_pct"]
         slow = tmp_path / "BENCH_slow.json"
-        slow.write_text(json.dumps({"parsed": {
-            "metric": "env_steps_per_sec", "value": med * 2.5}}))
-        assert obs_main(["regress", str(root / "run.jsonl"),
-                         "--baseline", str(slow), "--json"]) == 1
-        v = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert v["verdict"] == "regress" and v["drop_pct"] > 30.0
+        if band < 99.0:
+            drop = (band + 100.0) / 2.0
+            slow.write_text(json.dumps({"parsed": {
+                "metric": "env_steps_per_sec",
+                "value": med / (1.0 - drop / 100.0)}}))
+            code, v = verdict(slow)
+            assert code == 1 and v["verdict"] == "regress", v
+            assert v["drop_pct"] > v["band_pct"] == band
+        else:
+            # a run too noisy to learn anything from: the gate must not
+            # manufacture an alarm out of it either
+            slow.write_text(json.dumps({"parsed": {
+                "metric": "env_steps_per_sec", "value": med * 2.0}}))
+            code, v = verdict(slow)
+            assert code == 0 and v["verdict"] == "pass", v

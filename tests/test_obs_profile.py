@@ -575,6 +575,34 @@ class TestRealRun:
         assert "eval" in p["phases"]
         es.engine.close()
 
+    def test_sequence_policy_is_not_modelled(self):
+        """A policy that states sequence facts gets NO cost model: "every
+        2-D leaf is a matmul of one env-step" counts an untied embedding
+        as a matmul and leaves out attention; `obs profile` then says it
+        has no model instead of printing a wrong MFU."""
+        import jax
+        import loop_tiny
+        import optax
+
+        from estorch_tpu import ES, JaxAgent
+        from estorch_tpu.envs import TokenScoreEnv
+        from estorch_tpu.models import LoopedLM
+
+        es = ES(policy=LoopedLM, agent=JaxAgent, optimizer=optax.adam,
+                population_size=8, sigma=0.02, policy_kwargs=loop_tiny.TINY,
+                agent_kwargs={"env": TokenScoreEnv(**loop_tiny.ENV)},
+                optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
+                model_shards=1, low_rank=1, noise_mode="table",
+                table_size=1 << 18, device=jax.devices()[:1])
+        assert es._sequence_facts()["tokens_per_generation"] > 0
+        assert es._build_cost_model() is None
+        assert es.obs.cost_model is None
+        es.train(1, verbose=False)
+        assert "cost_model" not in es.history[0]
+        p = profile_records(es.history, platform_roofline("cpu"))
+        assert p["has_cost_model"] is False
+        assert any("no cost_model" in n for n in p["notes"])
+
     def test_ledger_gauges_reach_the_registry(self, profiled_run):
         es, _ = profiled_run
         snap = es.obs.counters.snapshot()

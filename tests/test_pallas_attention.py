@@ -515,3 +515,63 @@ class TestThroughTheShardedEngine:
         np.testing.assert_allclose(got["fitness"], want["fitness"],
                                    atol=2e-2)
         assert np.isfinite(np.asarray(got["fitness"])).all()
+
+
+class TestTheDeclaredCost:
+    """``attention_cost``: what the kernel tells XLA it does, against a
+    count made tile by tile, and what ``pallas_call`` is handed."""
+
+    @pytest.mark.parametrize("length, block_q, block_k", [
+        (64, 16, 16), (64, 32, 16), (64, 16, 32), (96, 32, 32), (32, 32, 32)])
+    @pytest.mark.parametrize("shared", [0, 4])
+    def test_against_a_count_tile_by_tile(self, length, block_q, block_k,
+                                          shared):
+        heads, kv_heads, hd, vd, itemsize = 4, 2, 8, 16, 2
+        flops = exps = 0
+        for _ in range(heads):
+            for i in range(length // block_q):
+                for j in range(length // block_k):
+                    # a tile is computed where its first key is no later
+                    # than the query block's last row
+                    if j * block_k <= (i + 1) * block_q - 1:
+                        flops += 2 * block_q * block_k * (hd + shared + vd)
+                        exps += block_q * block_k + block_q
+        elements = length * (heads * (hd + shared + vd)
+                             + kv_heads * (hd + vd) + shared)
+        cost = pallas_attention.attention_cost(
+            length, heads, kv_heads, hd, vd, shared, block_q, block_k,
+            itemsize)
+        assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
+            flops, exps, elements * itemsize)
+
+    def test_ten_tiles_of_sixteen_over_the_exact_triangle(self):
+        # the cells' geometry: 4,096 positions in blocks of 1,024
+        cost = pallas_attention.attention_cost(4096, 16, 16, 128, 128, 0,
+                                               1024, 1024, 2)
+        exact = 16 * 2 * (128 + 128) * 4096 * 4097 // 2
+        assert cost.flops / exact == pytest.approx(1.25, abs=1e-3)
+
+    @pytest.mark.parametrize("shared", [False, True])
+    def test_the_call_declares_it_and_vmap_scales_it(self, shared):
+        from pallas_costs import declared_costs
+
+        t, heads, kv_heads, dr = 32, 4, 4, 4
+        q, k, v, qs, ks = _parts(t, heads, HD, dr, 16, jnp.float32, seed=1)
+        args = (q, k, v) + ((qs, ks) if shared else ())
+
+        def call(*a):
+            return causal_attention(*a, num_heads=heads,
+                                    num_kv_heads=kv_heads, head_dim=HD,
+                                    value_dim=16, scale=0.25, interpret=True,
+                                    block_q=16, block_k=8)
+
+        want = pallas_attention.attention_cost(
+            t, heads, kv_heads, HD, 16, dr if shared else 0, 16, 8, 4)
+        one, = declared_costs(call, *args)
+        assert one == want
+        members = 3
+        many, = declared_costs(jax.vmap(call), *(
+            jnp.stack([x] * members) for x in args))
+        assert (many.flops, many.transcendentals, many.bytes_accessed) == (
+            members * want.flops, members * want.transcendentals,
+            members * want.bytes_accessed)

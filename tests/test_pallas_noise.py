@@ -331,3 +331,70 @@ def test_dims_past_the_vmem_budget_resolve_slice():
     assert dim > NOISE_KERNEL_MAX_DIM and form == "slice"
     dim, form = resolved_on_a_chip((8,))
     assert dim <= NOISE_KERNEL_MAX_DIM and form == "dma"
+
+
+class TestTheDeclaredCost:
+    """``row_kernel_cost``: what the row kernels tell XLA they move, against
+    a count made row by row, and what ``pallas_call`` is handed."""
+
+    @pytest.mark.parametrize("n, dim, dtype", [
+        (5, 300, jnp.float32), (3, 1000, jnp.bfloat16), (1, 128, jnp.float32)])
+    def test_gather_against_a_count_row_by_row(self, n, dim, dtype):
+        from pallas_costs import declared_costs
+
+        from estorch_tpu.ops import pallas_noise
+
+        sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
+        rows_out = -(-(-(-dim // 128)) // sublanes) * sublanes
+        window_rows = rows_out + 8
+        read = written = 0
+        for _ in range(n):
+            read += window_rows * 128 * 4           # the aligned f32 window
+            written += rows_out * 128 * jnp.dtype(dtype).itemsize
+        want = pallas_noise.row_kernel_cost(
+            n, window_rows, rows_out, jnp.dtype(dtype).itemsize, summed=False)
+        assert (want.flops, want.transcendentals, want.bytes_accessed) == (
+            0, 0, read + written)
+        offs = jnp.arange(n, dtype=jnp.int32) * 7
+        got, = declared_costs(
+            lambda o: gather_noise_rows(TABLE.data, o, dim, dtype, True), offs)
+        assert got == want
+
+    @pytest.mark.parametrize("n, dim", [(5, 300), (2, 4096)])
+    def test_sum_against_a_count_row_by_row(self, n, dim):
+        from pallas_costs import declared_costs
+
+        from estorch_tpu.ops import pallas_noise
+
+        rows_out = -(-(-(-dim // 128)) // 8) * 8
+        window_rows = rows_out + 8
+        flops = read = 0
+        for _ in range(n):
+            read += window_rows * 128 * 4
+            flops += 2 * rows_out * 128             # a multiply and an add
+        want = pallas_noise.row_kernel_cost(n, window_rows, rows_out, 4,
+                                            summed=True)
+        assert (want.flops, want.bytes_accessed) == (
+            flops, read + rows_out * 128 * 4)
+        offs = jnp.arange(n, dtype=jnp.int32) * 7
+        w = jnp.ones((n,), jnp.float32)
+        got, = declared_costs(
+            lambda o, w: weighted_noise_sum(TABLE.data, o, w, dim, True),
+            offs, w)
+        assert got == want
+
+    def test_under_vmap_each_members_call_declares_its_own(self):
+        # offsets are scalar-prefetched: pallas_call's batching rule runs
+        # the kernel once a member in a loop, so a trace sums one
+        # declaration per call (the attention kernel's members go in front
+        # of its grid instead, and its declaration is scaled)
+        from pallas_costs import declared_costs
+
+        offs = jnp.arange(6, dtype=jnp.int32).reshape(2, 3) * 11
+
+        def call(o):
+            return gather_noise_rows(TABLE.data, o, 300, jnp.float32, True)
+
+        one, = declared_costs(call, offs[0])
+        looped, = declared_costs(jax.vmap(call), offs)
+        assert looped == one

@@ -19,9 +19,9 @@ from estorch_tpu.envs import CartPole
 from estorch_tpu.obs.spans import Telemetry
 from estorch_tpu.obs.trace import (ATTN, DENSE, DISPATCH, ENV, EXIT, EXPERT,
                                    GATHER, GRAD, HEAD, NOISE, PERTURB, POLICY,
-                                   RANK, ROPE, ROUTE, SAMPLE, SCOPE_PREFIX,
-                                   SSM, STAGES, UPDATE, annotate, stage,
-                                   trace)
+                                   PART_PREFIX, RANK, ROPE, ROUTE, SAMPLE,
+                                   SCOPE_PREFIX, SSM, STAGES, UPDATE,
+                                   annotate, part, stage, trace)
 
 # the stages of every generation program; a sequence model nests more
 # inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT; a
@@ -99,88 +99,182 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source,
     assert any(POLICY in stack for stack in matmuls)
 
 
-def test_sequence_model_names_its_layers_inside_the_policy_stage(
-        keyed_by_source):
-    """The sharded engine's perturbed form on a HybridLM: every stage of a
-    generation, and es.dense / es.ssm / es.attn / es.head nested inside
-    es.policy, the rank-r corrections under es.perturb."""
-    import lm_tiny
-    from estorch_tpu.envs import TokenScoreEnv
-    from estorch_tpu.models import HybridLM
+# the three sequence models on the sharded engine's perturbed form: what
+# each is built from, the stages its forward does NOT name, and the layers
+# it nests inside es.policy
+SEQUENCE_MODELS = {
+    "sequence": dict(policy="HybridLM", tiny="lm_tiny", devices=4,
+                     model_shards=2, absent={ROPE, EXIT} | EXPERT_STAGES,
+                     inner=(DENSE, SSM, ATTN, HEAD)),
+    "looped": dict(policy="LoopedLM", tiny="loop_tiny", devices=1,
+                   model_shards=1, absent={SSM} | EXPERT_STAGES,
+                   inner=(DENSE, ATTN, HEAD, ROPE, EXIT)),
+    "expert": dict(policy="MoELM", tiny="moe_tiny", devices=1,
+                   model_shards=1, absent={SSM, EXIT},
+                   inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT)),
+}
+PART = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(PART_PREFIX)
+                  + r"([A-Za-z0-9_.]+)")
 
-    es = ES(policy=HybridLM, agent=JaxAgent, optimizer=optax.adam,
-            population_size=8, sigma=0.02, policy_kwargs=lm_tiny.TINY,
-            agent_kwargs={"env": TokenScoreEnv(**lm_tiny.ENV)},
-            optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
-            model_shards=2, low_rank=1, noise_mode="table",
-            table_size=1 << 18, device=jax.devices()[:4])
+
+def _sequence_es(case):
+    import importlib
+
+    from estorch_tpu import models
+    from estorch_tpu.envs import TokenScoreEnv
+
+    tiny = importlib.import_module(case["tiny"])
+    return ES(policy=getattr(models, case["policy"]), agent=JaxAgent,
+              optimizer=optax.adam, population_size=8, sigma=0.02,
+              policy_kwargs=tiny.TINY,
+              agent_kwargs={"env": TokenScoreEnv(**tiny.ENV)},
+              optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
+              model_shards=case["model_shards"], low_rank=1,
+              noise_mode="table", table_size=1 << 18,
+              device=jax.devices()[:case["devices"]])
+
+
+def _multiplied_leaves(module) -> dict:
+    """``{part: the stage it sits beneath}`` of every parameter leaf a
+    forward of ``module`` multiplies: the matrices of its tree (the conv
+    taps are added up, not multiplied by), named by their key, a module's
+    one ``kernel`` / ``embedding`` by the module's, a shared or routed
+    expert's with that path element in front."""
+    leaves = jax.tree_util.tree_flatten_with_path(module.param_shapes())[0]
+    own_stage = {"router": ROUTE, "exit_gate": EXIT, "head": HEAD,
+                 "embed": POLICY}
+    parts = {}
+    for path, leaf in leaves:
+        keys = [str(k.key) for k in path]
+        if len(leaf.shape) < 2 or keys[-1].startswith("conv_"):
+            continue
+        if keys[-1] in ("kernel", "embedding"):
+            keys = keys[:-1]
+        words = [k for k in keys[:-1] if k in ("shared", "experts")]
+        name = ".".join(words + [keys[-1]])
+        parts[name] = (EXPERT if "experts" in words
+                       else own_stage.get(name, DENSE))
+    return parts
+
+
+@pytest.mark.parametrize("model", sorted(SEQUENCE_MODELS))
+def test_model_names_its_layers_inside_the_policy_stage(model,
+                                                        keyed_by_source):
+    """The sharded engine's perturbed form on each sequence model: every
+    stage of a generation but the ones the model has no work for, its
+    layers' stages nested inside es.policy, the rank-r corrections under
+    es.perturb; every leaf a forward multiplies a part beneath its stage,
+    and no part moves an operation to another stage."""
+    from benchmark import stage_reduce
+
+    case = SEQUENCE_MODELS[model]
+    es = _sequence_es(case)
     engine = es.engine
-    text = engine._generation_step.lower(
-        es.state, engine.table.data).compile().as_text()
-    names = re.findall(r'op_name="([^"]*)"', text)
+    lowered = engine._generation_step.lower(es.state, engine.table.data)
+    if model == "expert":
+        text = lowered.as_text(debug_info=True)
+        names = re.findall(r'loc\("(jit\([^"]*)"', text)
+    else:
+        text = lowered.compile().as_text()
+        names = re.findall(r'op_name="([^"]*)"', text)
     found = {s for name in names for s in SCOPE.findall(name)}
-    # every stage but the looped model's two (it rotates nothing, exits
-    # nowhere)
-    want = set(STAGES) - {ROPE, EXIT} - EXPERT_STAGES
+    want = set(STAGES) - case["absent"]
     assert found == want, (want - found, found - want)
-    for inner in (DENSE, SSM, ATTN, HEAD):
+    for inner in case["inner"]:
         stacks = [SCOPE.findall(n) for n in names
                   if SCOPE_PREFIX + inner in n]
         # (the compiler shortens a few names to their last scope, e.g.
-        # "es.attn/reduce_max": those carry no outer stage at all)
+        # "es.attn/reduce_max": those carry no outer stage at all; the
+        # lowered text of the expert model shortens none)
         nested = [st for st in stacks if POLICY in st]
-        assert len(nested) > len(stacks) // 2, inner
+        assert stacks and len(nested) > len(stacks) // 2, inner
+        assert model != "expert" or len(nested) == len(stacks), inner
         assert all(st.index(POLICY) < st.index(inner) for st in nested), inner
-    # the projections are matmuls under es.dense or es.head; the
-    # corrections are nested one deeper, under es.perturb
-    matmuls = [SCOPE.findall(m) for line in text.splitlines()
-               for m in MATMUL.findall(line)]
-    assert any(st[-1] == DENSE for st in matmuls)
-    assert any(st[-1] == HEAD for st in matmuls)
-    assert any(st[-1] == PERTURB and DENSE in st
-               for name in names for st in [SCOPE.findall(name)] if st)
+    stacks = [(tuple(SCOPE.findall(n)), n) for n in names if SCOPE.findall(n)]
+    if model == "expert":
+        # the grouped matmuls sit under es.expert, their per-(member,
+        # expert) corrections one deeper, the sort under es.dispatch
+        assert any(st[-1] == EXPERT and "ragged_dot" in n for st, n in stacks)
+        assert any(st[-2:] == (EXPERT, PERTURB) for st, _ in stacks)
+        assert any(st[-1] == DISPATCH and "sort" in n for st, n in stacks)
+        assert any(st[-1] == DISPATCH and "scatter" in n for st, n in stacks)
+        assert any(st[-1] == ROUTE and "top_k" in n for st, n in stacks)
+        assert es.obs.counters.get("experts_held") == 4
+    else:
+        # the projections are matmuls under es.dense or es.head; the
+        # corrections are nested one deeper, under es.perturb
+        matmuls = [SCOPE.findall(m) for line in text.splitlines()
+                   for m in MATMUL.findall(line)]
+        assert any(st[-1] == DENSE for st in matmuls)
+        assert any(st[-1] == HEAD for st in matmuls)
+        assert any(st[-1] == PERTURB and DENSE in st for st, _ in stacks)
+    if model == "looped":
+        # the rotation's sines and cosines, and the gate's sigmoid (its exp)
+        assert any(st[-1] == ROPE and ("sin" in n or "cos" in n)
+                   for st, n in stacks)
+        assert any(st[-1] == EXIT and n.endswith("exp") for st, n in stacks)
+        assert es.obs.counters.get("loop_steps") == 4
+        assert es.obs.counters.get("layer_applications_per_token") == 8
+
+    # ---- the parts: every multiplied leaf, beneath its stage
+    parted = [(".".join(PART.findall(n)), n) for n in names
+              if PART.search(n)]
+    leaves = _multiplied_leaves(es.module)
+    assert {p for p, _ in parted} == set(leaves), (
+        set(leaves) - {p for p, _ in parted},
+        {p for p, _ in parted} - set(leaves))
+    for part_name, beneath in leaves.items():
+        last = PART_PREFIX + part_name.split(".")[-1]
+        assert any(beneath in SCOPE.findall(n[:n.rindex(last)])
+                   for p, n in parted if p == part_name), (part_name, beneath)
+    if model == "sequence":     # the tied head reads the embedding, too
+        assert any(p == "embed" and HEAD in SCOPE.findall(n)
+                   for p, n in parted)
+    # an unfused correction keeps es.perturb INSIDE its part
+    assert any(n[n.rindex(PART_PREFIX):].count(SCOPE_PREFIX + PERTURB)
+               for _, n in parted)
+    # a part is no stage: with or without it a name stack books alike
+    for _, n in parted:
+        bare = "/".join(c for c in n.split("/") if not PART.search(c))
+        assert stage_reduce.stage_of(n) == stage_reduce.stage_of(bare), n
+        assert stage_reduce.stage_of(n) != stage_reduce.UNSCOPED, n
 
 
-def test_looped_model_names_its_layers_inside_the_policy_stage(
-        keyed_by_source):
-    """The sharded engine's perturbed form on a LoopedLM, one device: every
-    stage of a generation, es.dense / es.attn / es.head from the pieces it
-    shares with HybridLM and its own es.rope / es.exit, nested inside
-    es.policy; no es.ssm.  The gauges say how deep the loop is."""
-    import loop_tiny
-    from estorch_tpu.envs import TokenScoreEnv
-    from estorch_tpu.models import LoopedLM
+@pytest.mark.parametrize("model", sorted(SEQUENCE_MODELS))
+def test_parts_are_metadata_only(model, monkeypatch):
+    """A model's perturbed forward lowered with the parts and with
+    ``part`` a no-op is the same program, text for text (the StableHLO
+    text leaves the locations out): a part adds no operation."""
+    import importlib
 
-    es = ES(policy=LoopedLM, agent=JaxAgent, optimizer=optax.adam,
-            population_size=8, sigma=0.02, policy_kwargs=loop_tiny.TINY,
-            agent_kwargs={"env": TokenScoreEnv(**loop_tiny.ENV)},
-            optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
-            model_shards=1, low_rank=1, noise_mode="table",
-            table_size=1 << 18, device=jax.devices()[:1])
-    engine = es.engine
-    text = engine._generation_step.lower(
-        es.state, engine.table.data).compile().as_text()
-    names = re.findall(r'op_name="([^"]*)"', text)
-    found = {s for name in names for s in SCOPE.findall(name)}
-    want = set(STAGES) - {SSM} - EXPERT_STAGES
-    assert found == want, (want - found, found - want)
-    for inner in (DENSE, ATTN, HEAD, ROPE, EXIT):
-        stacks = [SCOPE.findall(n) for n in names
-                  if SCOPE_PREFIX + inner in n]
-        nested = [st for st in stacks if POLICY in st]
-        assert len(nested) > len(stacks) // 2, inner
-        assert all(st.index(POLICY) < st.index(inner) for st in nested), inner
-    matmuls = [SCOPE.findall(m) for line in text.splitlines()
-               for m in MATMUL.findall(line)]
-    assert any(st[-1] == DENSE for st in matmuls)
-    assert any(st[-1] == HEAD for st in matmuls)
-    # the rotation's sines and cosines, and the gate's sigmoid (its exp)
-    assert any(SCOPE.findall(n)[-1:] == [ROPE] and ("sin" in n or "cos" in n)
-               for n in names)
-    assert any(SCOPE.findall(n)[-1:] == [EXIT] and n.endswith("exp")
-               for n in names)
-    assert es.obs.counters.get("loop_steps") == 4
-    assert es.obs.counters.get("layer_applications_per_token") == 8
+    from estorch_tpu import models
+    from estorch_tpu.models import (hybrid_lm, lm_blocks, looped_lm, moe_lm,
+                                    perturbed)
+
+    case = SEQUENCE_MODELS[model]
+    tiny = importlib.import_module(case["tiny"])
+    module = getattr(models, case["policy"])(**tiny.TINY)
+    shapes = module.param_shapes()
+    spec = perturbed.lowrank_spec_for(module, shapes, 1)
+    tokens = jax.ShapeDtypeStruct((tiny.ENV["seq_len"],), jnp.int32)
+    row = jax.ShapeDtypeStruct((spec.noise_dim,), jnp.float32)
+
+    def forward(params, noise_row, toks):
+        return module.perturbed_apply(params, spec.unpack(noise_row), 0.02,
+                                      toks)
+
+    def lowered():
+        # a fresh function each time: jit's cache would hand back the first
+        return jax.jit(lambda *a: forward(*a)).lower(shapes, row, tokens)
+
+    with_parts = lowered()
+    assert PART_PREFIX in with_parts.as_text(debug_info=True)
+    for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm):
+        monkeypatch.setattr(mod, "part",
+                            lambda name: contextlib.nullcontext())
+    without = lowered()
+    assert PART_PREFIX not in without.as_text(debug_info=True)
+    assert with_parts.as_text() == without.as_text()
 
 
 # ---- compiled for a described TPU v5e: Mosaic runs, nothing executes ----
@@ -439,48 +533,6 @@ def test_kernel_form_books_its_kernel_to_attn(latent, v5e_chip):
         for name, stack in kernels), kernels
 
 
-def test_expert_model_names_its_layers_inside_the_policy_stage(
-        keyed_by_source):
-    """The sharded engine's perturbed form on a MoELM, one device: every
-    stage of a generation, es.dense / es.attn / es.head / es.rope from the
-    pieces it shares with the other two models and its own es.route /
-    es.dispatch / es.expert, nested inside es.policy; no es.ssm, no
-    es.exit.  The grouped matmuls sit under es.expert, their per-(member,
-    expert) corrections one deeper under es.perturb, the sort under
-    es.dispatch."""
-    import moe_tiny
-    from estorch_tpu.envs import TokenScoreEnv
-    from estorch_tpu.models import MoELM
-
-    es = ES(policy=MoELM, agent=JaxAgent, optimizer=optax.adam,
-            population_size=8, sigma=0.02, policy_kwargs=moe_tiny.TINY,
-            agent_kwargs={"env": TokenScoreEnv(**moe_tiny.ENV)},
-            optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
-            model_shards=1, low_rank=1, noise_mode="table",
-            table_size=1 << 18, device=jax.devices()[:1])
-    engine = es.engine
-    text = engine._generation_step.lower(
-        es.state, engine.table.data).as_text(debug_info=True)
-    names = re.findall(r'loc\("(jit\([^"]*)"', text)
-    found = {s for name in names for s in SCOPE.findall(name)}
-    want = set(STAGES) - {SSM, EXIT}
-    assert found == want, (want - found, found - want)
-    for inner in (DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT):
-        stacks = [SCOPE.findall(n) for n in names
-                  if SCOPE_PREFIX + inner in n]
-        assert stacks and all(
-            POLICY in st and st.index(POLICY) < st.index(inner)
-            for st in stacks), inner
-    stacks = [(tuple(SCOPE.findall(n)), n) for n in names
-              if SCOPE.findall(n)]
-    assert any(st[-1] == EXPERT and "ragged_dot" in n for st, n in stacks)
-    assert any(st[-2:] == (EXPERT, PERTURB) for st, _ in stacks)
-    assert any(st[-1] == DISPATCH and "sort" in n for st, n in stacks)
-    assert any(st[-1] == DISPATCH and "scatter" in n for st, n in stacks)
-    assert any(st[-1] == ROUTE and "top_k" in n for st, n in stacks)
-    assert es.obs.counters.get("experts_held") == 4
-
-
 @pytest.mark.parametrize("use", ["context", "decorator"])
 def test_stage_scopes_a_name_stack(use):
     assert len(set(STAGES)) == len(STAGES) == 18
@@ -499,6 +551,22 @@ def test_stage_scopes_a_name_stack(use):
     assert SCOPE_PREFIX + NOISE in text
     with pytest.raises(ValueError, match="unknown stage"):
         stage("forward")
+
+
+def test_part_scopes_a_name_stack_beneath_a_stage():
+    def f(x):
+        with stage(DENSE), part("shared"), part("gate"):
+            return x * 2.0
+
+    text = jax.jit(f).lower(1.0).as_text(debug_info=True)
+    assert (SCOPE_PREFIX + DENSE + "/" + PART_PREFIX + "shared/"
+            + PART_PREFIX + "gate") in text
+    # a part's prefix is its own: no stage scope can be read out of one
+    assert not SCOPE.findall(PART_PREFIX + DENSE)
+    assert not PART_PREFIX.startswith(SCOPE_PREFIX)
+    for bad in ("", "a/b", "x y", ".gate", "gate.", "es.dense/x"):
+        with pytest.raises(ValueError, match="parameter leaf"):
+            part(bad)
 
 
 def _host_events(trace_dir):
