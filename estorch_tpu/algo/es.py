@@ -334,6 +334,9 @@ class ES:
                 # None for a policy without attention
                 attention_widths=getattr(self.module, "attention_widths",
                                          None),
+                # the width its next-token head contracts, for the head
+                # form's rule; None for a policy without one
+                head_width=getattr(self.module, "head_width", None),
                 # a sparse-expert model (models/moe_lm.py): what its stacked
                 # leaves see of a sequence, what stays float32, its load
                 leaf_rows_per_token=getattr(
@@ -540,6 +543,9 @@ class ES:
             # "kernel" says ops/pallas_attention.py engaged
             self.obs.counters.gauge("attention_form",
                                     self.engine.attention_form)
+        if getattr(self.engine, "head_form", None) is not None:
+            # "kernel" says ops/pallas_head.py engaged
+            self.obs.counters.gauge("head_form", self.engine.head_form)
         if self._shard_params:
             self.obs.counters.gauge("mesh_shape", "x".join(
                 str(n) for n in self.mesh.devices.shape))
@@ -1184,6 +1190,9 @@ class ES:
             # which form the policy's causal attention takes ("kernel" |
             # "xla"; None: a policy without one, or the replicated engine)
             "attention_form": getattr(self.engine, "attention_form", None),
+            # which form the policy's next-token head takes ("kernel" |
+            # "xla"; None: a policy without one, or the replicated engine)
+            "head_form": getattr(self.engine, "head_form", None),
             "shard_params": self._shard_params,
             **self._sequence_facts(),
         }

@@ -130,6 +130,12 @@ class HybridLM:
         rule reads it, ops/pallas_attention.py)."""
         return self.head_dim
 
+    @property
+    def head_width(self) -> int:
+        """The width the next-token head contracts (the head form's rule
+        reads it, ops/pallas_head.py)."""
+        return self.hidden_size
+
     def param_shapes(self) -> dict:
         """The parameter tree as shapes (float32)."""
         h, n, k = self.hidden_size, self.mamba_d_state, self.mamba_d_conv
@@ -210,16 +216,11 @@ class HybridLM:
         with ``(A, B)`` factors or a dense array at each leaf
         (ops/lowrank.py ``unpack``); ``None`` is the centre alone."""
         h = self.hidden(params, noise, c, tokens)
-        table = params["embed"]["embedding"]
-        e_noise = subtree(noise, "embed", "embedding")
-
-        def tied_head(h_b):
-            return perturbed_dense(h_b, table, e_noise, c, transposed=True)
-
         # the head reads the embedding transposed: its part is ``embed``
         return lm_blocks.score_next_tokens(
-            h, tokens, tied_head, self.head_block, self.logits_scaling,
-            leaf="embed")
+            h, tokens, params["embed"]["embedding"],
+            subtree(noise, "embed", "embedding"), c, self.head_block,
+            self.logits_scaling, leaf="embed", transposed=True)
 
     def logits(self, params, tokens, noise=None, c=0.0):
         h = self.hidden(params, noise, c, tokens)

@@ -1,9 +1,10 @@
 """The pieces the three sequence models are built from
 (models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py): ONE RMSNorm,
 ONE gated SiLU FFN, ONE causal attention core, ONE expert layer and ONE
-blocked next-token scorer, each on the perturbed-dense primitive
+next-token scorer, each on the perturbed-dense primitive
 (models/perturbed.py), so that an optimisation of one is measured on every
-model that calls it.
+model that calls it.  The attention core and the scorer have TWO forms each
+of one algorithm, and the rule that picks is below.
 
 The attention's core (:func:`attention_core`: rotated parts -> context)
 takes its operands in PARTS: per head a query and a key of one width and
@@ -43,6 +44,30 @@ form everywhere else, so ``apply`` outside an engine is the XLA form.
 ``int`` for heads of one width or ``(a head's own part, the shared part,
 the value width)``.
 
+The next-token scorer (:func:`score_next_tokens`: hidden states and the
+head's leaf -> each next token's log-probability) takes the leaf itself,
+its noise, ``c`` and whether it is read transposed (a tied embedding), not
+a closure, because its two forms multiply it differently:
+
+- ``"xla"``: float32 logits ``[block, vocab]`` a block of ``head_block``
+  positions through ``perturbed_dense``, ``logsumexp`` and a gather: the
+  logits are written, re-laid out and re-read through HBM.  It runs
+  anywhere, as the attention's XLA form does.
+- ``"kernel"``: ops/pallas_head.py: a row tile x vocabulary tile of logits
+  (the matmul, the rank-r correction, the scaling) is folded into a running
+  max and sum and the target's logit picked where it lies in VMEM; the
+  members under the ``vmap``s around it become rows of ONE call, so ``W``
+  is never copied a member; any vocabulary (a short last tile is masked).
+
+It takes the kernel inside the SAME ``kernel_scope`` (so: TPU devices, ONE
+on the mesh, an attention whose form is the kernel) where its own shapes
+fit (``pallas_head.fits``: a hidden width of whole 128-lane blocks and at
+most 16 KiB a row, the sequence a whole number of the kernel's row tiles)
+and the noise is factored or none.  The engine says which at build
+(``ShardedESEngine.head_form`` by ``pallas_head.head_form``, from the
+``head_width`` the model states).  The last position's logits (the
+behaviour) are the one-row XLA matmul in both forms.
+
 The expert layer (:func:`routed_experts`) is told which experts it holds:
 it routes over all of them (:func:`route`), computes what its own experts
 give for the (token, k) pairs routed to them and leaves the rest out.  One
@@ -71,11 +96,11 @@ import jax.numpy as jnp
 
 import functools
 
-from ..obs.trace import (ATTN, DENSE, DISPATCH, EXPERT, HEAD, ROPE, ROUTE,
-                         part, stage)
-from ..ops import pallas_attention
-from .perturbed import (F32, perturbed_dense, perturbed_grouped_dense,
-                        perturbed_leaf)
+from ..obs.trace import (ATTN, DENSE, DISPATCH, EXPERT, HEAD, PERTURB, ROPE,
+                         ROUTE, part, stage)
+from ..ops import pallas_attention, pallas_head
+from .perturbed import (F32, is_factored, perturbed_dense,
+                        perturbed_grouped_dense, perturbed_leaf)
 
 # rows the expert layer takes at a time, over what a uniform router sends
 # its held experts: one pass nearly always, and the loop takes the rest
@@ -421,21 +446,60 @@ def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
     return y.reshape(n_members, t, hidden), load
 
 
-def score_next_tokens(h, tokens, project, block: int, logits_scaling=None,
-                      *, leaf: str):
+def score_next_tokens(h, tokens, w, noise, c, block: int,
+                      logits_scaling=None, *, leaf: str,
+                      transposed: bool = False):
     """``(log p(tokens[t+1] | tokens[:t+1]) [T-1], the last position's
-    logits [vocab])`` float32 from the hidden states ``h [T, hidden]``, in
-    blocks of ``block`` positions so that the ``[T, vocab]`` logits never
-    exist.  ``project(h_block)`` is the model's head matmul (tied or not),
-    float32; the logits are divided by ``logits_scaling`` where the model
-    has one.  ``leaf`` is the key of the leaf ``project`` multiplies
+    logits [vocab])`` float32 from the hidden states ``h [T, hidden]`` and
+    the head ``w + c·noise``: ``w [hidden, vocab]``, or a tied embedding
+    ``[vocab, hidden]`` read ``transposed``; ``noise`` its ``(A, B)``
+    factors, a dense array or ``None`` (models/perturbed.py).  The logits
+    are divided by ``logits_scaling`` where the model has one; the ``[T,
+    vocab]`` logits never exist.  ``leaf`` is the key of the leaf ``w``
     (``"head"``, a tied ``"embed"``): the logits, their log-softmax and
-    the picked scores are that part of ``es.head`` (obs/trace.py)."""
+    the picked scores are that part of ``es.head`` (obs/trace.py).
+
+    TWO forms of one algorithm (the module's text has the rule).  Inside
+    an engine's ``pallas_attention.kernel_scope``, where the shapes fit
+    (``pallas_head.fits``) and the noise is factored or none, the Pallas
+    kernel of ops/pallas_head.py: a tile of logits is made, reduced and
+    picked from in VMEM, all ``T`` rows in tiles of the kernel's own.
+    Anywhere else the XLA form: float32 logits ``[block, vocab]`` a block
+    of ``block`` positions, ``logsumexp`` and a gather.  The last
+    position's logits are the one-row XLA matmul in both."""
 
     def scaled(y):
         return y if logits_scaling is None else y / logits_scaling
 
+    def project(h_b):
+        return perturbed_dense(h_b, w, noise, c, transposed)
+
+    def last_logits():
+        with stage(HEAD), part(leaf):
+            return scaled(project(h[-1:])[0])
+
     t = tokens.shape[0]
+    interpret = pallas_attention.scoped_interpret()
+    if (interpret is not None
+            and pallas_head.fits(h.shape[-1], t, h.dtype.itemsize)
+            and (noise is None or is_factored(noise))):
+        # the target of position t is token t+1; the last position's is
+        # scored against token 0 and left out
+        targets = jnp.pad(tokens[1:], (0, 1))
+        with stage(HEAD), part(leaf):
+            xs = bt = None
+            if noise is not None:
+                with stage(PERTURB):
+                    a, b = (noise[1], noise[0]) if transposed else noise
+                    xs = jnp.dot(h, a.astype(h.dtype),
+                                 preferred_element_type=F32) * (
+                        c / jnp.sqrt(jnp.asarray(a.shape[-1], F32)))
+                    bt = b.astype(F32).T
+            logp = pallas_head.score_rows(
+                h, w, targets, xs, bt, transposed=transposed,
+                logits_scaling=logits_scaling, interpret=interpret)
+        return logp[:t - 1], last_logits()
+
     block = min(block, t)
     n_blocks = -(-t // block)
     pad = n_blocks * block - t
@@ -455,6 +519,5 @@ def score_next_tokens(h, tokens, project, block: int, logits_scaling=None,
     logp = jax.lax.map(score, (
         hp.reshape(n_blocks, block, -1),
         targets.reshape(n_blocks, block)))
-    with stage(HEAD), part(leaf):
-        last = scaled(project(h[-1:])[0])
+    last = last_logits()
     return logp.reshape(-1)[:t - 1], last

@@ -133,6 +133,12 @@ class LoopedLM:
         return self.head_dim
 
     @property
+    def head_width(self) -> int:
+        """The width the next-token head contracts (the head form's rule
+        reads it, ops/pallas_head.py)."""
+        return self.hidden_size
+
+    @property
     def leaf_rows(self) -> dict:
         """Leaves a token sequence does NOT pass whole: ``{leaf path:
         positions per application}``.  The head runs in blocks of
@@ -211,11 +217,10 @@ class LoopedLM:
             params["final_norm"]["scale"],
             subtree(noise, "final_norm", "scale"), c), self.rms_norm_eps)
         h = h32.astype(dtype)
-        kernel, k_noise = params["head"]["kernel"], subtree(noise, "head",
-                                                            "kernel")
         logp, last = lm_blocks.score_next_tokens(
-            h, tokens, lambda h_b: perturbed_dense(h_b, kernel, k_noise, c),
-            self.head_block, leaf="head")
+            h, tokens, params["head"]["kernel"],
+            subtree(noise, "head", "kernel"), c, self.head_block,
+            leaf="head")
         with stage(EXIT):
             gate = params["exit_gate"]
             with part("exit_gate"):

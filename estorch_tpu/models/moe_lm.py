@@ -169,6 +169,12 @@ class MoELM:
         return (self.qk_nope_head_dim, self.qk_rope_head_dim,
                 self.v_head_dim)
 
+    @property
+    def head_width(self) -> int:
+        """The width the next-token head contracts (the head form's rule
+        reads it, ops/pallas_head.py)."""
+        return self.hidden_size
+
     def _layer_shapes(self, kind: str) -> dict:
         h, nh = self.hidden_size, self.num_attention_heads
         tree: dict[str, Any] = {
@@ -301,13 +307,10 @@ class MoELM:
         def norm(p, n, name, y):
             return self._norm(p, n, c, name, y)
 
-        def head(h_b):
-            return perturbed_dense(h_b, kernel, k_noise, c)
-
         def scored(h32, targets):
             return lm_blocks.score_next_tokens(
-                h32.astype(dtype), targets, head, self.head_block,
-                leaf="head")
+                h32.astype(dtype), targets, kernel, k_noise, c,
+                self.head_block, leaf="head")
 
         x = perturbed_embed(tokens, table, t_noise, c)
         load = jnp.zeros((self.n_routed_experts,), jnp.int32)
@@ -319,8 +322,9 @@ class MoELM:
         h = norm(params, noise, "final_norm", x)
         main, _ = scored(h, tokens)
         with stage(HEAD), part("head"):
-            last = jnp.mean(head(h.astype(dtype)[-self.behaviour_positions:]),
-                            axis=0)
+            last = jnp.mean(perturbed_dense(
+                h.astype(dtype)[-self.behaviour_positions:], kernel, k_noise,
+                c), axis=0)
 
         # the MTP module reads position t's state beside token t+1 and
         # predicts token t+2; the last position has no next token (it is

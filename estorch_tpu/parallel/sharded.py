@@ -79,6 +79,7 @@ from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
 from ..ops.pallas_attention import attention_form, kernel_scope
+from ..ops.pallas_head import head_form
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
 from .engine import (EngineConfig, _bf16_io_apply, _bf16_obs,
@@ -162,6 +163,7 @@ class ShardedESEngine:
         lowrank_spec=None,
         leaf_rows: dict[str, int] | None = None,
         attention_widths: int | tuple | None = None,
+        head_width: int | None = None,
         leaf_rows_per_token: dict[str, float] | None = None,
         float32_leaves=(),
         expert_load: bool = False,
@@ -238,6 +240,14 @@ class ShardedESEngine:
             else self._resolve_attention_form(attention_widths))
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
+        # "kernel" | "xla": which form the policy's next-token head takes
+        # (models/lm_blocks.py::score_next_tokens); None for a policy that
+        # states no head.  The kernel is taken inside the scope the
+        # attention form opens, where the head's own shapes fit
+        self.head_form = (
+            None if head_width is None
+            else head_form(self.attention_form, head_width, config.horizon,
+                           jnp.dtype(self._dtype).itemsize))
         self.n_devices = int(mesh.devices.size)
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.pop_shards = int(axis_sizes[POP_AXIS])

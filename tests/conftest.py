@@ -83,3 +83,24 @@ def dma_gather(monkeypatch):
             yield
 
     return forced
+
+
+@pytest.fixture
+def kernel_attention(monkeypatch):
+    """``with kernel_attention():`` — sharded engines built inside resolve
+    ``attention_form == "kernel"`` on the suite's CPU mesh, where the rule
+    says "xla", and open their ``kernel_scope``; ``_pallas_interpret``
+    comes from the mesh, so the kernels run under the Pallas interpreter.
+    The next-token head's form then follows from its own shapes
+    (ops/pallas_head.py).  A fake substituted by the test: nothing in the
+    package reads it."""
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    @contextlib.contextmanager
+    def forced():
+        with monkeypatch.context() as m:
+            m.setattr(ShardedESEngine, "_resolve_attention_form",
+                      lambda self, widths: "kernel")
+            yield
+
+    return forced

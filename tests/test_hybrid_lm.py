@@ -314,6 +314,39 @@ def test_forced_through_the_interpreted_kernel_it_agrees(tiny, length):
         np.testing.assert_allclose(g, w, atol=1e-4, rtol=0)
 
 
+@pytest.mark.parametrize("sign", [0.0, 1.0, -1.0])
+def test_the_heads_kernel_is_reached_through_the_same_scope(sign):
+    """A hidden width of one 128-lane block over 512 positions fits the
+    head's rule (ops/pallas_head.py): inside a ``kernel_scope`` the TIED
+    head (the embedding read transposed, its factors swapped, the logits
+    divided by ``logits_scaling``) scores in the head's kernel, interpreted
+    here: the centre and both members of a pair."""
+    from estorch_tpu.ops.pallas_attention import kernel_scope
+
+    lm = HybridLM(**{**lm_tiny.TINY, "hidden_size": 128,
+                     "layer_types": ("attention", "mamba"),
+                     "mamba_chunk_size": 64, "attention_block": 128,
+                     "head_block": 96})
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (512,), 0, 64)
+    params = jax.tree_util.tree_map(
+        lambda x: 3.0 * x, lm.init(jax.random.PRNGKey(2))["params"])
+    spec = make_lowrank_tree_spec(lm.param_shapes(), 2)
+    factors = None if sign == 0.0 else spec.unpack(
+        jax.random.normal(jax.random.PRNGKey(5), (spec.noise_dim,)))
+    c = 0.05 * sign
+    want = lm.perturbed_apply(params, factors, c, tokens)
+    with kernel_scope(interpret=True):
+        program = str(jax.make_jaxpr(
+            lambda p, f: lm.perturbed_apply(p, f, c, tokens))(
+                params, factors))
+        got = lm.perturbed_apply(params, factors, c, tokens)
+    assert "next_token_scores" in program
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, atol=TOL, rtol=0)
+    assert float(jnp.abs(got[0] - want[0]).max()) > 0.0  # another program
+
+
 def test_init_draws_the_declared_tree(tiny):
     lm = tiny["lm"]
     params = lm.init(jax.random.PRNGKey(0), None)["params"]

@@ -159,6 +159,40 @@ def test_forced_through_the_interpreted_kernel_it_agrees(tiny, dtype, tol,
     assert float(jnp.abs(got[0] - want[0]).max()) > 0.0  # another program
 
 
+@pytest.mark.parametrize("rank", [1, 2])
+def test_the_heads_kernel_is_reached_through_the_same_scope(rank):
+    """A hidden width of one 128-lane block over 512 positions fits the
+    head's rule (ops/pallas_head.py): inside a ``kernel_scope`` every pass
+    scores its next tokens in the head's kernel (interpreted here) beside
+    the attention's, handed the leaf, its factors and ``c`` as the XLA form
+    is, and scores and last logits agree to the order of float32 sums."""
+    from estorch_tpu.ops.pallas_attention import kernel_scope
+
+    lm = LoopedLM(**{**loop_tiny.TINY, "hidden_size": 128,
+                     "layer_types": ("full_attention",),
+                     "total_ut_steps": 2, "attention_block": 128,
+                     "head_block": 96})
+    tokens = _tokens(512, 4)
+    params = jax.tree_util.tree_map(
+        lambda x: 3.0 * x, lm.init(jax.random.PRNGKey(2))["params"])
+    spec = make_lowrank_tree_spec(lm.param_shapes(), rank)
+    factors = spec.unpack(
+        jax.random.normal(jax.random.PRNGKey(5), (spec.noise_dim,)))
+    want = lm.perturbed_apply(params, factors, 0.05, tokens)
+    with kernel_scope(interpret=True):
+        program = str(jax.make_jaxpr(
+            lambda p, f: lm.perturbed_apply(p, f, 0.05, tokens))(
+                params, factors))
+        got = lm.perturbed_apply(params, factors, 0.05, tokens)
+    assert "next_token_scores" in program and "causal_attention" in program
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, atol=TOL, rtol=0)
+    assert float(jnp.abs(got[0] - want[0]).max()) > 0.0  # another program
+    centre = lm.perturbed_apply(params, None, 0.0, tokens)
+    assert float(jnp.abs(got[0] - centre[0]).max()) > 1e-3  # the correction
+
+
 # ------------------------------------- (b) every leaf's correction, 4 uses
 
 LEAVES = [path for path, _ in loop_tiny.reference().system_layout(

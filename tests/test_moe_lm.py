@@ -512,6 +512,39 @@ def test_the_kernel_takes_heads_of_two_widths():
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
+def test_the_heads_kernel_is_reached_through_the_same_scope():
+    """A hidden width of one 128-lane block over 512 positions fits the
+    head's rule (ops/pallas_head.py): inside a ``kernel_scope`` the main
+    head and the MTP head both score in the head's kernel (interpreted
+    here), handed the leaf, its factors and ``c`` as the XLA form is; the
+    averaged last logits stay the XLA matmul."""
+    from estorch_tpu.ops.pallas_attention import kernel_scope
+
+    lm = MoELM(**{**moe_tiny.TINY, "hidden_size": 128,
+                  "layer_types": ("moe",), "attention_block": 128,
+                  "head_block": 96, "behaviour_positions": 8})
+    tokens = _tokens(512, 4)
+    params = jax.tree_util.tree_map(
+        lambda x: 3.0 * x, lm.init(jax.random.PRNGKey(2))["params"])
+    spec = make_lowrank_tree_spec(lm.param_shapes(), 2,
+                                  stacked=lm.stacked_leaves)
+    factors = spec.unpack(
+        jax.random.normal(jax.random.PRNGKey(5), (spec.noise_dim,)))
+    want = lm.heads(params, factors, 0.05, tokens)
+    with kernel_scope(interpret=True):
+        program = str(jax.make_jaxpr(
+            lambda p, f: lm.heads(p, f, 0.05, tokens))(params, factors))
+        got = lm.heads(params, factors, 0.05, tokens)
+    assert program.count("next_token_scores") >= 2      # main and MTP
+    for g, w in zip(got[:3], want[:3]):
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, atol=TOL, rtol=0)
+    np.testing.assert_array_equal(got[3], want[3])
+    assert float(jnp.abs(got[0] - want[0]).max()) > 0.0  # another program
+    centre = lm.heads(params, None, 0.0, tokens)
+    assert float(jnp.abs(got[1] - centre[1]).max()) > 1e-3  # the correction
+
+
 # ------------------------------------------------------ (f) the MTP term
 
 def test_the_mtp_term_scores_the_token_after_next(ref, tiny):

@@ -434,27 +434,6 @@ def _lm_es(devices, model_shards=1, policy=LoopedLM, **over):
     return ES(**kw)
 
 
-@pytest.fixture
-def kernel_attention(monkeypatch):
-    """``with kernel_attention():`` — sharded engines built inside resolve
-    ``attention_form == "kernel"`` on the suite's CPU mesh, where the rule
-    says "xla"; ``_pallas_interpret`` comes from the mesh, so the kernel
-    runs under the Pallas interpreter.  A fake substituted by the test:
-    nothing in the package reads it."""
-    import contextlib
-
-    from estorch_tpu.parallel.sharded import ShardedESEngine
-
-    @contextlib.contextmanager
-    def forced():
-        with monkeypatch.context() as m:
-            m.setattr(ShardedESEngine, "_resolve_attention_form",
-                      lambda self, widths: "kernel")
-            yield
-
-    return forced
-
-
 class TestThroughTheShardedEngine:
     @pytest.mark.parametrize("policy", [LoopedLM, HybridLM, MoELM])
     @pytest.mark.parametrize("n_devices, model_shards", [(1, 1), (4, 2)])

@@ -449,6 +449,59 @@ def test_attention_kernel_compiles_for_the_v5e_at_the_looped_cells_shapes(
     assert shared or " copy(" not in text.split("ENTRY")[1]
 
 
+@pytest.mark.parametrize("hidden, vocab, tied, dtype", [
+    (2048, 49152, False, jnp.bfloat16), (2048, 49152, False, jnp.float32),
+    (2048, 16160, False, jnp.bfloat16), (2048, 100352, True, jnp.bfloat16),
+    (8192, 32768, False, jnp.bfloat16), (4096, 32768, True, jnp.float32),
+], ids=["looped", "looped_f32", "tail", "tied", "widest_bf16", "widest_f32"])
+def test_head_kernel_compiles_for_the_v5e_at_the_cells_shapes(
+        hidden, vocab, tied, dtype, v5e_chip):
+    """Mosaic accepts the head's kernel at the one-chip sequence cells'
+    shapes (one pair's two signs x 4,096 positions x hidden 2,048 through
+    the engine's ``vmap``s, tiles of the kernel's own, the scoped-VMEM limit
+    it asks for): ``ouro-2.6b-es-4k-1chip``'s ``[2048, 49152]`` head,
+    ``joyai-flash-es-4k-1chip``'s ``[2048, 16160]`` (a short last tile,
+    masked) and a tied ``[100352, 2048]`` embedding read transposed
+    (granite's, were it on one chip); and at the widest rows its rule
+    admits, 16 KiB of hidden state (row tiles of 512).  ONE custom call
+    holds all rows, and ``W`` reaches it as the un-batched parameter it
+    is."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.models import lm_blocks
+    from estorch_tpu.ops.pallas_attention import kernel_scope
+
+    def on_chip(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=SingleDeviceSharding(v5e_chip))
+
+    w_shape = (vocab, hidden) if tied else (hidden, vocab)
+
+    def scores(h, tokens, w, a, b):
+        def pair(hp, tp, ap, bp):
+            return jax.vmap(lambda hs, sign: lm_blocks.score_next_tokens(
+                hs, tp, w, (ap, bp), 0.002 * sign, 512,
+                8.0 if tied else None, leaf="embed" if tied else "head",
+                transposed=tied)[0])(hp, jnp.asarray([1.0, -1.0]))
+        return jax.vmap(pair)(h, tokens, a, b)
+
+    with kernel_scope(interpret=False):
+        text = jax.jit(scores).lower(
+            on_chip((1, 2, 4096, hidden), dtype),
+            on_chip((1, 4096), jnp.int32),
+            on_chip(w_shape, dtype), on_chip((1, w_shape[0], 1), jnp.float32),
+            on_chip((1, w_shape[1], 1), jnp.float32)).compile().as_text()
+    entry = text.split("ENTRY")[1]
+    calls = [line for line in entry.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "next_token_scores" in calls[0]
+    layout = "bf16" if dtype == jnp.bfloat16 else "f32"
+    assert f"{layout}[{w_shape[0]},{w_shape[1]}]{{1,0}}" in calls[0]
+    # no logits block, and no stack of W, anywhere in the program
+    assert not re.search(rf"f32\[[\d,]*512,{vocab}\]", text)
+    assert not re.search(rf"\[\d+,{w_shape[0]},{w_shape[1]}\]", text)
+
+
 def _looped_engine_on(devices, model_shards, head_dim, length, latent=False):
     """A small looped model's sharded engine on a mesh of described TPU
     ``devices``: its pieces from an ES built on the CPU, as the engine of
@@ -485,6 +538,7 @@ def _looped_engine_on(devices, model_shards, head_dim, length, latent=False):
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
         leaf_rows=es.module.leaf_rows,
         attention_widths=es.module.attention_widths,
+        head_width=es.module.head_width,
         leaf_rows_per_token=getattr(es.module, "leaf_rows_per_token", None),
         float32_leaves=getattr(es.module, "float32_leaves", ()),
         expert_load=latent)
@@ -506,6 +560,48 @@ def test_attention_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim,
     _, engine = _looped_engine_on(v5e_2x2[:n_devices], model_shards,
                                   head_dim, length)
     assert engine.attention_form == form
+
+
+@pytest.mark.parametrize("n_devices, model_shards, head_dim, length, form", [
+    (1, 1, 128, 512, "kernel"),
+    (1, 1, 128, 256, "xla"),    # no row tile of the head's divides it
+    (1, 1, 64, 512, "xla"),     # the attention's form opens no scope
+    (4, 2, 128, 512, "xla"),    # granite's mesh
+])
+def test_head_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim, length,
+                                 form, v5e_2x2):
+    """``ShardedESEngine.head_form`` on meshes of TPU devices: the kernel
+    inside the scope a one-device mesh opens where the head's own shapes
+    fit (hidden 128 here), the XLA form on every other."""
+    _, engine = _looped_engine_on(v5e_2x2[:n_devices], model_shards,
+                                  head_dim, length)
+    assert engine.head_form == form
+
+
+@pytest.mark.parametrize("latent", [False, True], ids=["looped", "latent"])
+def test_kernel_form_books_the_heads_kernel_to_head(latent, v5e_chip):
+    """On a one-device TPU mesh, over 512 positions, the engine's scope
+    gives the head its kernel too: the compiled generation program holds
+    the Mosaic call ``next_token_scores`` under es.head inside es.policy,
+    in the part of the head's leaf, where the XLA form's logits were: the
+    device trace books it to ``loop.head_share`` / ``moe.head_share`` and
+    to ``part.head_flops_util`` (two calls for the latent model: the main
+    head and the MTP head)."""
+    es, engine = _looped_engine_on([v5e_chip], 1, 128, 512, latent=latent)
+    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        es.state, engine.state_shardings)
+    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
+                                 sharding=engine._repl)
+    text = engine._generation_step.lower(state, table).compile().as_text()
+    heads = [name for line in text.splitlines()
+             if "tpu_custom_call" in line and "next_token_scores" in line
+             for name in re.findall(r'op_name="([^"]*)"', line)]
+    assert len(heads) == (2 if latent else 1), heads
+    for name in heads:
+        assert SCOPE.findall(name)[-2:] == [POLICY, HEAD], name
+        assert PART.findall(name) == ["head"], name
 
 
 @pytest.mark.parametrize("latent", [False, True], ids=["looped", "latent"])
