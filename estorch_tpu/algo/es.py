@@ -343,6 +343,12 @@ class ES:
                     self.module, "leaf_rows_per_token", None),
                 float32_leaves=getattr(self.module, "float32_leaves", ()),
                 expert_load=hasattr(self.module, "stacked_leaves"),
+                # a model with windowed layers and with 2-D leaves no
+                # matmul reads (models/sambay_lm.py)
+                attention_window=getattr(self.module, "attention_window",
+                                         None),
+                dense_noise_leaves=getattr(
+                    self.module, "dense_noise_leaves", ()),
             )
             # the whole flat vector leaves the device before the sharded
             # state is placed from it, a leaf at a time: a tree this
@@ -418,6 +424,15 @@ class ES:
                 experts_total=int(self.module.experts_total),
                 experts_per_token=int(self.module.num_experts_per_tok),
                 mtp_depth=int(self.module.num_nextn_predict_layers))
+        if hasattr(self.module, "kv_shared_by"):
+            # layers of several kinds, two of which hand state to the layers
+            # above them (models/sambay_lm.py)
+            facts.update(
+                layer_kinds=",".join(self.module.layer_types),
+                window=int(self.module.sliding_window),
+                scan_chunk=int(self.module.scan_chunk),
+                kv_shared_by=int(self.module.kv_shared_by),
+                memory_shared_by=int(self.module.memory_shared_by))
         return facts
 
     def _perturbed_form(self, flat):
@@ -1193,6 +1208,10 @@ class ES:
             # which form the policy's next-token head takes ("kernel" |
             # "xla"; None: a policy without one, or the replicated engine)
             "head_form": getattr(self.engine, "head_form", None),
+            # which condition of the attention form's rule decided (and,
+            # where that is "xla", the head form with it)
+            "attention_form_why": getattr(
+                self.engine, "attention_form_why", None),
             "shard_params": self._shard_params,
             **self._sequence_facts(),
         }

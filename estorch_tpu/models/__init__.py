@@ -1,6 +1,7 @@
 from .hybrid_lm import HybridLM
 from .looped_lm import LoopedLM
 from .moe_lm import MoELM
+from .sambay_lm import SambaYLM
 from .policies import MLPPolicy, NatureCNN, RecurrentNatureCNN, RecurrentPolicy
 from .vbn import VirtualBatchNorm, capture_reference_stats
 
@@ -27,6 +28,7 @@ __all__ = [
     "RecurrentNatureCNN",
     "TorchRunningObsNorm",
     "RecurrentPolicy",
+    "SambaYLM",
     "VirtualBatchNorm",
     "TorchVirtualBatchNorm",
     "capture_reference_stats",

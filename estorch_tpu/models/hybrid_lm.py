@@ -56,22 +56,11 @@ import jax.numpy as jnp
 
 from ..obs.trace import HEAD, SSM, part, stage
 from . import lm_blocks
-from .lm_blocks import layer_name, rmsnorm as _rmsnorm, subtree
+from .lm_blocks import (causal_conv as _causal_conv, layer_name,
+                        rmsnorm as _rmsnorm, subtree)
 from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
 
 MAMBA, ATTENTION = "mamba", "attention"
-
-
-def _causal_conv(x, taps, bias):
-    """Depthwise causal conv over time: ``y_t = Σ_k taps[k]·x_{t-(K-1-k)} +
-    bias`` (``taps [K, 1, C]``, the last tap multiplies the current step, as
-    torch's ``Conv1d(padding=K-1)[..., :T]`` does)."""
-    k_taps, t = taps.shape[0], x.shape[0]
-    padded = jnp.pad(x, ((k_taps - 1, 0), (0, 0)))
-    y = bias
-    for k in range(k_taps):
-        y = y + taps[k, 0] * padded[k:k + t]
-    return y
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,6 +1,8 @@
-"""The pieces the three sequence models are built from
-(models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py): ONE RMSNorm,
-ONE gated SiLU FFN, ONE causal attention core, ONE expert layer and ONE
+"""The pieces the four sequence models are built from
+(models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py,
+models/sambay_lm.py): ONE RMSNorm, ONE LayerNorm, ONE gated SiLU FFN, ONE
+causal attention core (full or banded), ONE differential combine, ONE
+depthwise causal conv, ONE expert layer and ONE
 next-token scorer, each on the perturbed-dense primitive
 (models/perturbed.py), so that an optimisation of one is measured on every
 model that calls it.  The attention core and the scorer have TWO forms each
@@ -18,8 +20,14 @@ no shared part).  The values may come BESIDE their keys in one array, as
 latent attention's ``kv_b`` writes them (``v=None``): the kernel reads
 both where they lie, the XLA form cuts them apart.  A model makes its own
 parts (:func:`causal_attention`
-is the plain q/k/v/o form two of them share).  The core has TWO forms of
-one algorithm, same mathematics, same tiles, same precision:
+is the plain q/k/v/o form two of them share).  With a ``window`` a query
+sees the keys ``(t - window, t]`` and no others: the XLA form skips the key
+blocks wholly outside that band as it skips those above the diagonal; the
+kernel has no band yet, and the rule below turns a windowed model away.
+Differential attention (arXiv 2410.05258) is ONE call of the core with both
+softmax maps as heads of it, then :func:`differential_combine`.  The core
+has TWO forms of one algorithm, same mathematics, same tiles, same
+precision:
 
 - ``"xla"``: block-causal einsums and a softmax, whose float32 score
   tiles XLA holds in HBM; a shared part is concatenated onto q and k, the
@@ -96,8 +104,8 @@ import jax.numpy as jnp
 
 import functools
 
-from ..obs.trace import (ATTN, DENSE, DISPATCH, EXPERT, HEAD, PERTURB, ROPE,
-                         ROUTE, part, stage)
+from ..obs.trace import (ATTN, DENSE, DIFF, DISPATCH, EXPERT, HEAD, PERTURB,
+                         ROPE, ROUTE, part, stage)
 from ..ops import pallas_attention, pallas_head
 from .perturbed import (F32, is_factored, perturbed_dense,
                         perturbed_grouped_dense, perturbed_leaf)
@@ -126,13 +134,38 @@ def rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def dense(p, noise, c, name, x):
+def layernorm(x, scale, bias, eps):
+    """float32 LayerNorm with bias over the last axis."""
+    x = x.astype(F32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(
+        jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale + bias
+
+
+def causal_conv(x, taps, bias):
+    """Depthwise causal conv over time: ``y_t = Σ_k taps[k]·x_{t-(K-1-k)} +
+    bias`` (``taps [K, 1, C]``, the last tap multiplies the current step, as
+    torch's ``Conv1d(padding=K-1)[..., :T]`` does)."""
+    k_taps, t = taps.shape[0], x.shape[0]
+    padded = jnp.pad(x, ((k_taps - 1, 0), (0, 0)))
+    y = bias
+    for k in range(k_taps):
+        y = y + taps[k, 0] * padded[k:k + t]
+    return y
+
+
+def dense(p, noise, c, name, x, bias: str | None = None):
     """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``, the
-    part ``of.<name>``: every projection of the three models says here
-    which leaf it multiplies (obs/trace.py)."""
+    part ``of.<name>``: every projection of the four models says here
+    which leaf it multiplies (obs/trace.py).  ``bias``: the key of a bias
+    leaf of ``p`` (perturbed like any small leaf), added to the product."""
     with stage(DENSE), part(name):
-        return perturbed_dense(
+        y = perturbed_dense(
             x, p[name], None if noise is None else noise[name], c)
+        if bias is None:
+            return y
+        return y + perturbed_leaf(
+            p[bias], None if noise is None else noise[bias], c)
 
 
 def subtree(noise, *path):
@@ -204,7 +237,8 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
 
 
 def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
-                   scale: float, block: int, q_shared=None, k_shared=None):
+                   scale: float, block: int, q_shared=None, k_shared=None,
+                   window: int | None = None):
     """``context [T, heads · value width]`` of causal attention with
     grouped heads: ``q [T, heads(, ·) qk width]``, ``k [T, kv heads(, ·)
     qk width]`` and ``v [T, kv heads(, ·) value width]`` in the compute
@@ -218,7 +252,12 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     concatenated parts.  Block-causal: query block ``i`` is scored against
     the keys ``[0, end of block i)`` and no others, so (n+1)/(2n) of the
     ``[T, T]`` score tiles of ``n`` blocks are computed, and a masked score
-    (``exp(-inf) = 0``) exists only inside the diagonal tile.
+    (``exp(-inf) = 0``) exists only inside the diagonal tile.  ``window``:
+    query ``t`` sees the keys ``(t - window, t]`` alone; query block ``i``
+    is then scored against the key blocks that hold ``(start of block i -
+    window, end of block i)`` and no others, so a window of one block costs
+    two key blocks a query block however long the sequence, and masked
+    scores exist in the first and the last of them.
 
     Inside an engine's ``pallas_attention.kernel_scope`` the core is the
     Pallas kernel (its own blocks, scores in VMEM, the shared part a
@@ -232,6 +271,11 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     nq, nkv = num_heads, num_kv_heads
     hd = q.size // (t * nq)
     interpret = pallas_attention.scoped_interpret()
+    if window is not None and interpret is not None:
+        raise NotImplementedError(
+            "the attention kernel has no band: a model with a window is "
+            "turned away by ops.pallas_attention.attention_form, and no "
+            "kernel scope should be open around it")
     if v is None and (interpret is None or k.size != 2 * t * nkv * hd):
         # cut the values from beside the keys: the XLA form's einsums
         # read them apart (and the kernel's column blocks are one width)
@@ -262,6 +306,9 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     ctx = []
     for start in range(0, t, block):
         stop = min(start + block, t)
+        # the first key block any query of this block sees
+        first = (0 if window is None
+                 else max(0, (start - window + 1) // block * block))
         q_b = qh[start:stop]
         if ctx:
             # one block at a time: left free, the TPU scheduler runs
@@ -269,16 +316,36 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
             # their float32 scores at once (T²/2 of them)
             q_b, _ = jax.lax.optimization_barrier((q_b, ctx[-1]))
         with stage(ATTN):
-            s = jnp.einsum("qkgd,skd->kgqs", q_b, kh[:stop],
+            s = jnp.einsum("qkgd,skd->kgqs", q_b, kh[first:stop],
                            preferred_element_type=F32) * scale
-            mask = (jnp.arange(stop)[None, :]
-                    <= jnp.arange(start, stop)[:, None])
+            keys = (jnp.arange(stop) if first == 0
+                    else jnp.arange(first, stop))[None, :]
+            queries = jnp.arange(start, stop)[:, None]
+            mask = keys <= queries
+            if window is not None:
+                mask = mask & (keys > queries - window)
             s = jnp.where(mask, s, -jnp.inf)
             prob = jax.nn.softmax(s, axis=-1).astype(dtype)
             ctx.append(jnp.einsum(
-                "kgqs,skd->qkgd", prob, vh[:stop],
+                "kgqs,skd->qkgd", prob, vh[first:stop],
                 preferred_element_type=F32).astype(dtype))
     return jnp.concatenate(ctx).reshape(t, nq * vd)
+
+
+def differential_combine(ctx, lam, gamma, *, pairs: int, group: int,
+                         lambda_init: float, eps: float):
+    """Differential attention's combine (arXiv 2410.05258):
+    ``RMSNorm(A₁v - λ·A₂v; γ, eps) · (1 - lambda_init)``, float32 ``[T,
+    pairs · group · value width]``, from ONE call of :func:`attention_core`
+    whose heads were both softmax maps: ``ctx [T, pairs · 2 · group · value
+    width]`` holds, per key/value pair, map 1's ``group`` heads and then map
+    2's (key head ``2p + m`` is map ``m`` of pair ``p``, and each value pair
+    is read by both).  ``lam`` a scalar, ``gamma [value width]``."""
+    t = ctx.shape[0]
+    with stage(DIFF):
+        maps = ctx.astype(F32).reshape(t, pairs, 2, group, -1)
+        out = rmsnorm(maps[:, :, 0] - lam * maps[:, :, 1], gamma, eps)
+        return (out * (1.0 - lambda_init)).reshape(t, -1)
 
 
 # ------------------------------------------------------- the expert layer

@@ -177,6 +177,8 @@ def lowrank_spec_for(module, params, rank: int):
 
     if supports_decomposed(module):
         return make_lowrank_spec(params, rank)
-    # a model names the leaves whose leading axis indexes experts
+    # a model names the leaves whose leading axis indexes experts, and
+    # the 2-D leaves no matmul reads
     return make_lowrank_tree_spec(
-        params, rank, stacked=getattr(module, "stacked_leaves", ()))
+        params, rank, stacked=getattr(module, "stacked_leaves", ()),
+        dense=getattr(module, "dense_noise_leaves", ()))

@@ -51,7 +51,8 @@ _PART_NAME = re.compile(r"[A-Za-z0-9_]+(?:\.[A-Za-z0-9_]+)*")
 
 # the stages of one generation, in program order (docs/observability.md)
 STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
-          DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH, EXPERT) = (
+          DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH, EXPERT, GMU,
+          DIFF) = (
     "sample",    # offsets, signs, member keys
     "noise",     # reading eps: the table gather and the slab it builds
     "perturb",   # theta + sigma * sign * eps, unravel, cast; the rank-r
@@ -63,11 +64,13 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "grad",      # the second pass over the noise, the weighted sum
     "update",    # weight decay, optax step, sigma decay, obs-norm probe
     # nested inside es.policy by a sequence model (models/hybrid_lm.py,
-    # models/looped_lm.py, models/moe_lm.py on the pieces of
-    # models/lm_blocks.py)
+    # models/looped_lm.py, models/moe_lm.py, models/sambay_lm.py on the
+    # pieces of models/lm_blocks.py)
     "dense",     # the shared x@W projections and the gated FFN
-    "ssm",       # conv1d, dt and decay, the chunked scan, the gated norm
-    "attn",      # scores, softmax, P.V
+    "ssm",       # conv1d, dt and decay, the scan (Mamba-2's chunked form,
+                 # Mamba-1's selective one), the gate
+    "attn",      # scores, softmax, P.V (a model with several kinds of
+                 # attention names each a part: of.window, of.full, of.cross)
     "head",      # the logits (tied or not), log-softmax, the score
     "rope",      # rotary positions: cos/sin, rotating queries and keys
     "exit",      # a looped model's exit gate, the exit distribution and
@@ -78,6 +81,11 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
                  # expert order and the weighted combine back
     "expert",    # the grouped matmuls over the routed rows and the
                  # experts' gated activation
+    "gmu",       # a gated memory unit's gate product silu(u W1) * m, m the
+                 # scan output an earlier layer handed on (its two
+                 # projections are es.dense parts gmu_in, gmu_out)
+    "diff",      # differential attention's combine: lambda, A1 v - lambda
+                 # A2 v, the norm over a head pair's values, the scale
 )
 
 

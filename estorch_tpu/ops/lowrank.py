@@ -101,20 +101,26 @@ class LowRankTreeSpec:
 
 
 def make_lowrank_tree_spec(params: Any, rank: int, order=None,
-                           stacked=()) -> LowRankTreeSpec:
+                           stacked=(), dense=()) -> LowRankTreeSpec:
     """Layout from ANY param pytree (arrays or ``ShapeDtypeStruct``s).
     ``order``: the leaf indices in the order their noise is laid out.
     ``stacked``: the '/'-joined paths of the leaves ``[e, m, n]`` whose
     leading axis indexes experts (a model's ``stacked_leaves``): each is
-    factored per expert where that saves, by the 2-D rule on ``(m, n)``."""
+    factored per expert where that saves, by the 2-D rule on ``(m, n)``.
+    ``dense``: the paths of 2-D leaves that take dense noise whatever the
+    rule says of their shape (a model's ``dense_noise_leaves``: a leaf no
+    matmul reads, such as Mamba-1's ``A_log [d_inner, d_state]``, is a
+    table of independent scalars, and a rank-r product is no model of
+    those)."""
     if rank < 1:
         raise ValueError(f"low_rank must be >= 1, got {rank}")
     leaves, treedef = jax.tree_util.tree_flatten(params)
-    stacked_at = set()
-    if stacked:
+    stacked_at, dense_at = set(), set()
+    if stacked or dense:
         paths = ["/".join(str(getattr(k, "key", k)) for k in path)
                  for path, _ in jax.tree_util.tree_flatten_with_path(params)[0]]
         stacked_at = {paths.index(p) for p in stacked}
+        dense_at = {paths.index(p) for p in dense}
     lr_leaves, dense_leaves, stacked_leaves = [], [], []
     off = 0
     for i in (range(len(leaves)) if order is None else order):
@@ -129,7 +135,8 @@ def make_lowrank_tree_spec(params: Any, rank: int, order=None,
         # implies r < min(m, n), since mn/(m+n) < min(m, n)); otherwise the
         # factors would cost more noise floats than exact dense Gaussian,
         # an approximation strictly worse than the thing it approximates
-        if len(shape) == 2 and rank * (shape[0] + shape[1]) < shape[0] * shape[1]:
+        if (len(shape) == 2 and i not in dense_at
+                and rank * (shape[0] + shape[1]) < shape[0] * shape[1]):
             m, n = shape
             lr_leaves.append((i, m, n, off, off + m * rank))
             off += (m + n) * rank

@@ -133,8 +133,16 @@ def kernel_block(length: int) -> int | None:
     return next((b for b in BLOCKS if length % b == 0), None)
 
 
-def attention_form(platform: str, n_devices: int, widths, length: int) -> str:
-    """``"kernel"`` or ``"xla"`` for a program on a mesh of ``n_devices``
+def attention_form(platform: str, n_devices: int, widths, length: int,
+                   window: int | None = None) -> str:
+    """``"kernel"`` or ``"xla"``: :func:`attention_form_why` without its
+    reason."""
+    return attention_form_why(platform, n_devices, widths, length, window)[0]
+
+
+def attention_form_why(platform: str, n_devices: int, widths, length: int,
+                       window: int | None = None) -> tuple[str, str]:
+    """``("kernel" | "xla", why)`` for a program on a mesh of ``n_devices``
     devices of ``platform`` that runs attention over ``length`` positions
     with heads of ``widths``, as the model states them: one width (an
     ``int``) for heads scored and summed at it, or ``(a head's own
@@ -147,14 +155,29 @@ def attention_form(platform: str, n_devices: int, widths, length: int) -> str:
     head's own part and its values are whole numbers of 128-lane column
     blocks; the shared part is a whole number of them or half of one (two
     heads a block); the sequence is a whole number of the kernel's blocks
-    (:func:`kernel_block`)."""
+    (:func:`kernel_block`); no layer has a ``window`` (the kernel has no
+    band).  ``why`` names the first of these that fails (the engine logs
+    it and the run manifest carries it)."""
     head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
                            else widths)
-    fits = (head % LANES == 0 and value % LANES == 0
-            and (shared % LANES == 0 or shared == LANES // 2)
-            and kernel_block(length) is not None)
-    return ("kernel" if platform == "tpu" and n_devices == 1 and fits
-            else "xla")
+    failed = [why for ok, why in (
+        (platform == "tpu", f"the devices are {platform!r}, not TPUs"),
+        (n_devices == 1, f"{n_devices} devices on the mesh: the operands "
+                         "are not whole on one"),
+        (head % LANES == 0 and value % LANES == 0,
+         f"a head's own part is {head} wide and its values {value}: not "
+         f"whole {LANES}-lane column blocks"),
+        (shared % LANES == 0 or shared == LANES // 2,
+         f"the shared part is {shared} wide: neither whole {LANES}-lane "
+         "blocks nor half of one"),
+        (kernel_block(length) is not None,
+         f"no block of {BLOCKS} divides {length} positions"),
+        (window is None, f"a layer has a window of {window}: the kernel "
+                         "has no band"),
+    ) if not ok]
+    if failed:
+        return "xla", failed[0]
+    return "kernel", "one TPU device, whole column blocks, whole row blocks"
 
 
 _SCOPE: contextvars.ContextVar = contextvars.ContextVar(

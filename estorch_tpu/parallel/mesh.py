@@ -159,9 +159,38 @@ MOE_LM_PARTITION_RULES = (
     (r"mtp/eh$", P(None, MODEL_AXIS)),
 )
 
+# A decoder of Mamba-1, differential attention and gated memory units
+# (models/sambay_lm.py).  Its fused projections are column-parallel
+# (``in_proj``, ``qkv``, and the cross layers' ``q``, which the rule for
+# ``attn/(q|k|v)`` above already names), closed by the row-parallel
+# ``out_proj`` / ``attn/o`` above; Mamba-1's channels, their conv taps,
+# ``dt_proj``'s columns, ``A_log``'s rows, ``dt_bias`` and ``D`` go by
+# channel (the last three by the Mamba-2 rule above, which names them);
+# ``x_proj`` contracts the channels into Δ's rank, B and C, which every
+# channel reads (row-parallel, one all-reduce); a gated
+# memory unit is a column- then row-parallel pair whose gate product is by
+# channel, like the memory it multiplies.  The differential λ vectors and
+# the norm over a head pair's values are a head wide and replicate, as do
+# the LayerNorms' biases.
+SAMBAY_LM_PARTITION_RULES = (
+    (r"mamba/in_proj$", P(None, MODEL_AXIS)),
+    (r"mamba/conv_kernel$", P(None, None, MODEL_AXIS)),
+    (r"mamba/conv_bias$", P(MODEL_AXIS)),
+    (r"mamba/x_proj$", P(MODEL_AXIS, None)),
+    (r"mamba/dt_proj$", P(None, MODEL_AXIS)),
+    (r"attn/qkv$", P(None, MODEL_AXIS)),
+    (r"attn/(qkv_bias|q_bias)$", P(MODEL_AXIS)),
+    (r"attn/(o_bias|subln|lambda_[qk][12])$", P()),
+    (r"gmu/gmu_in$", P(None, MODEL_AXIS)),
+    (r"gmu/gmu_out$", P(MODEL_AXIS, None)),
+    (r"(norm[1-4]|final_norm)/bias$", P()),
+)
+
 CATCH_ALL = r".*"
 
-DEFAULT_PARTITION_RULES = HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES + (
+DEFAULT_PARTITION_RULES = (
+        HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
+        + SAMBAY_LM_PARTITION_RULES) + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),

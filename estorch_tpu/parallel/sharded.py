@@ -78,7 +78,7 @@ from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
                            lowrank_tree_noise, lowrank_tree_weighted_sum)
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
-from ..ops.pallas_attention import attention_form, kernel_scope
+from ..ops.pallas_attention import attention_form_why, kernel_scope
 from ..ops.pallas_head import head_form
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
@@ -167,6 +167,8 @@ class ShardedESEngine:
         leaf_rows_per_token: dict[str, float] | None = None,
         float32_leaves=(),
         expert_load: bool = False,
+        attention_window: int | None = None,
+        dense_noise_leaves=(),
     ):
         if config.obs_norm:
             raise ValueError(
@@ -235,9 +237,17 @@ class ShardedESEngine:
         # None for a policy that has none.  Resolved once, here, from the
         # mesh, the sequence length and the widths the policy states (run
         # manifest + telemetry gauge)
+        # the band of the policy's windowed layers (None: it has none)
+        self._attention_window = attention_window
         self.attention_form = (
             None if attention_widths is None
             else self._resolve_attention_form(attention_widths))
+        # which condition of the rule decided (the head's kernel is taken
+        # inside the attention kernel's scope alone, so this is the head
+        # form's reason too wherever the attention is "xla")
+        self.attention_form_why = (
+            None if attention_widths is None
+            else self._attention_rule(attention_widths)[1])
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
         # "kernel" | "xla": which form the policy's next-token head takes
@@ -248,6 +258,12 @@ class ShardedESEngine:
             None if head_width is None
             else head_form(self.attention_form, head_width, config.horizon,
                            jnp.dtype(self._dtype).itemsize))
+        if self.attention_form is not None:
+            import logging
+
+            logging.getLogger(__name__).info(
+                "attention_form %s (%s); head_form %s", self.attention_form,
+                self.attention_form_why, self.head_form)
         self.n_devices = int(mesh.devices.size)
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.pop_shards = int(axis_sizes[POP_AXIS])
@@ -283,8 +299,11 @@ class ShardedESEngine:
         self._factored: dict[int, tuple[int, int]] = {}
         if config.low_rank:
             r = int(config.low_rank)
+            # but for the 2-D leaves no matmul reads, which the model names
+            dense = set(dense_noise_leaves)
             for i, shape in enumerate(self.leaf_shapes):
-                if len(shape) == 2 and r * (shape[0] + shape[1]) < shape[0] * shape[1]:
+                if (len(shape) == 2 and self.leaf_paths[i] not in dense
+                        and r * (shape[0] + shape[1]) < shape[0] * shape[1]):
                     self._factored[i] = (shape[0], shape[1])
 
         # ---- partition rules → shardings (params + optax state) ----
@@ -400,11 +419,14 @@ class ShardedESEngine:
             out_shardings=self.param_shardings)
         self._copy_into_compiled = None
 
-    def _resolve_attention_form(self, widths) -> str:
-        """``"kernel"`` or ``"xla"``: see ``attention_form``."""
-        return attention_form(
+    def _attention_rule(self, widths) -> tuple[str, str]:
+        """``("kernel" | "xla", why)``: see ``attention_form_why``."""
+        return attention_form_why(
             self.mesh.devices.flat[0].platform, int(self.mesh.devices.size),
-            widths, self.config.horizon)
+            widths, self.config.horizon, self._attention_window)
+
+    def _resolve_attention_form(self, widths) -> str:
+        return self._attention_rule(widths)[0]
 
     def _in_attention_form(self, rollout):
         """``rollout`` traced in this engine's attention form: the policy's
@@ -531,20 +553,29 @@ class ShardedESEngine:
         otherwise as many as keep a device's share of the chunk's widest
         activation (:meth:`_widest_activation`, float32) under
         ``ACTIVATION_BUDGET_BYTES``.  A chunk holds whole pairs and a
-        multiple of ``pop_shards`` rows."""
+        multiple of ``pop_shards`` rows, with one exception: where ONE
+        member's widest activation is over the budget by itself, a chunk is
+        one pair whose two signs are evaluated in turn (``signs_in_turn``:
+        half the activations of a pair at once, the pair still reads one
+        factor row)."""
         cfg = self.config
         per_row = 2 if cfg.mirrored else 1
         rows_per_shard = self.rows_padded // self.pop_shards
+        self.signs_in_turn = False
         if cfg.eval_chunk > 0:
             req = max(1, cfg.eval_chunk // (per_row * self.pop_shards))
         else:
             per_member = 4 * self._widest_activation()
             req = max(1, ACTIVATION_BUDGET_BYTES // (per_member * per_row))
+            self.signs_in_turn = (cfg.mirrored
+                                  and per_member > ACTIVATION_BUDGET_BYTES)
         rows_chunk_per_shard = _choose_eval_chunk(req, rows_per_shard)
         self.pair_chunk = rows_chunk_per_shard * self.pop_shards
         self.n_pair_chunks = self.rows_padded // self.pair_chunk
-        self.eval_chunk = self.pair_chunk * per_row
-        self.n_eval_chunks = self.n_pair_chunks
+        # members evaluated at once, and how often
+        in_turn = 2 if self.signs_in_turn else 1
+        self.eval_chunk = self.pair_chunk * per_row // in_turn
+        self.n_eval_chunks = self.n_pair_chunks * in_turn
         self.members_padded = self.rows_padded * per_row
 
     def _eval_all_perturbed(self, state, center, noise_rows, rkey):
@@ -572,6 +603,8 @@ class ShardedESEngine:
                         c = state.sigma * sign
                     return self._rollout((center, noise_p, c), key)
 
+                if self.signs_in_turn:
+                    return jax.lax.map(sign_eval, signs)
                 return jax.vmap(sign_eval)(signs)
 
             res = jax.vmap(pair_eval, spmd_axis_name=POP_AXIS)(

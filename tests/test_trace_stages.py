@@ -17,17 +17,19 @@ import pytest
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
 from estorch_tpu.obs.spans import Telemetry
-from estorch_tpu.obs.trace import (ATTN, DENSE, DISPATCH, ENV, EXIT, EXPERT,
-                                   GATHER, GRAD, HEAD, NOISE, PERTURB, POLICY,
-                                   PART_PREFIX, RANK, ROPE, ROUTE, SAMPLE,
-                                   SCOPE_PREFIX, SSM, STAGES, UPDATE,
-                                   annotate, part, stage, trace)
+from estorch_tpu.obs.trace import (ATTN, DENSE, DIFF, DISPATCH, ENV, EXIT,
+                                   EXPERT, GATHER, GMU, GRAD, HEAD, NOISE,
+                                   PERTURB, POLICY, PART_PREFIX, RANK, ROPE,
+                                   ROUTE, SAMPLE, SCOPE_PREFIX, SSM, STAGES,
+                                   UPDATE, annotate, part, stage, trace)
 
 # the stages of every generation program; a sequence model nests more
 # inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT; a
-# sparse-expert one ROPE, ROUTE, DISPATCH and EXPERT)
+# sparse-expert one ROPE, ROUTE, DISPATCH and EXPERT; one with gated memory
+# units and differential attention GMU and DIFF)
 GENERATION_STAGES = STAGES[:9]
 EXPERT_STAGES = {ROUTE, DISPATCH, EXPERT}
+SAMBAY_STAGES = {GMU, DIFF}
 
 SCOPE = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(SCOPE_PREFIX)
                    + r"([a-z_]+)")
@@ -99,19 +101,26 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source,
     assert any(POLICY in stack for stack in matmuls)
 
 
-# the three sequence models on the sharded engine's perturbed form: what
-# each is built from, the stages its forward does NOT name, and the layers
-# it nests inside es.policy
+# the four sequence models on the sharded engine's perturbed form: what
+# each is built from, the stages its forward does NOT name, the layers it
+# nests inside es.policy, and the parts it names that are no leaf's
 SEQUENCE_MODELS = {
     "sequence": dict(policy="HybridLM", tiny="lm_tiny", devices=4,
-                     model_shards=2, absent={ROPE, EXIT} | EXPERT_STAGES,
+                     model_shards=2,
+                     absent={ROPE, EXIT} | EXPERT_STAGES | SAMBAY_STAGES,
                      inner=(DENSE, SSM, ATTN, HEAD)),
     "looped": dict(policy="LoopedLM", tiny="loop_tiny", devices=1,
-                   model_shards=1, absent={SSM} | EXPERT_STAGES,
+                   model_shards=1,
+                   absent={SSM} | EXPERT_STAGES | SAMBAY_STAGES,
                    inner=(DENSE, ATTN, HEAD, ROPE, EXIT)),
     "expert": dict(policy="MoELM", tiny="moe_tiny", devices=1,
-                   model_shards=1, absent={SSM, EXIT},
+                   model_shards=1, absent={SSM, EXIT} | SAMBAY_STAGES,
                    inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT)),
+    # its three kinds of attention say which they are: parts of es.attn
+    "sambay": dict(policy="SambaYLM", tiny="sambay_tiny", devices=1,
+                   model_shards=1, absent={ROPE, EXIT} | EXPERT_STAGES,
+                   inner=(DENSE, SSM, ATTN, HEAD, GMU, DIFF),
+                   more_parts={"window": ATTN, "full": ATTN, "cross": ATTN}),
 }
 PART = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(PART_PREFIX)
                   + r"([A-Za-z0-9_.]+)")
@@ -146,7 +155,8 @@ def _multiplied_leaves(module) -> dict:
     parts = {}
     for path, leaf in leaves:
         keys = [str(k.key) for k in path]
-        if len(leaf.shape) < 2 or keys[-1].startswith("conv_"):
+        if (len(leaf.shape) < 2 or keys[-1].startswith("conv_")
+                or keys[-1] == "A_log"):        # a table of decay rates
             continue
         if keys[-1] in ("kernel", "embedding"):
             keys = keys[:-1]
@@ -219,7 +229,7 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
     # ---- the parts: every multiplied leaf, beneath its stage
     parted = [(".".join(PART.findall(n)), n) for n in names
               if PART.search(n)]
-    leaves = _multiplied_leaves(es.module)
+    leaves = {**_multiplied_leaves(es.module), **case.get("more_parts", {})}
     assert {p for p, _ in parted} == set(leaves), (
         set(leaves) - {p for p, _ in parted},
         {p for p, _ in parted} - set(leaves))
@@ -227,7 +237,14 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         last = PART_PREFIX + part_name.split(".")[-1]
         assert any(beneath in SCOPE.findall(n[:n.rindex(last)])
                    for p, n in parted if p == part_name), (part_name, beneath)
-    if model == "sequence":     # the tied head reads the embedding, too
+    if model == "sambay":
+        # the scan is a loop under es.ssm; the two maps are subtracted under
+        # es.diff and the memory multiplied under es.gmu
+        assert any(st[-1] == SSM and "while" in n for st, n in stacks)
+        assert any(st[-1] == DIFF and n.endswith("sub") for st, n in stacks)
+        assert any(st[-1] == GMU and n.endswith("mul") for st, n in stacks)
+        assert es.obs.counters.get("kv_shared_by") == 1
+    if model in ("sequence", "sambay"):     # the tied head reads the embedding, too
         assert any(p == "embed" and HEAD in SCOPE.findall(n)
                    for p, n in parted)
     # an unfused correction keeps es.perturb INSIDE its part
@@ -249,7 +266,7 @@ def test_parts_are_metadata_only(model, monkeypatch):
 
     from estorch_tpu import models
     from estorch_tpu.models import (hybrid_lm, lm_blocks, looped_lm, moe_lm,
-                                    perturbed)
+                                    perturbed, sambay_lm)
 
     case = SEQUENCE_MODELS[model]
     tiny = importlib.import_module(case["tiny"])
@@ -269,7 +286,8 @@ def test_parts_are_metadata_only(model, monkeypatch):
 
     with_parts = lowered()
     assert PART_PREFIX in with_parts.as_text(debug_info=True)
-    for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm):
+    for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm,
+                sambay_lm):
         monkeypatch.setattr(mod, "part",
                             lambda name: contextlib.nullcontext())
     without = lowered()
@@ -631,10 +649,10 @@ def test_kernel_form_books_its_kernel_to_attn(latent, v5e_chip):
 
 @pytest.mark.parametrize("use", ["context", "decorator"])
 def test_stage_scopes_a_name_stack(use):
-    assert len(set(STAGES)) == len(STAGES) == 18
+    assert len(set(STAGES)) == len(STAGES) == 20
     assert (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD,
             UPDATE, DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH,
-            EXPERT) == STAGES
+            EXPERT, GMU, DIFF) == STAGES
     if use == "context":
         def f(x):
             with stage(NOISE):
