@@ -343,10 +343,13 @@ class ES:
                     self.module, "leaf_rows_per_token", None),
                 float32_leaves=getattr(self.module, "float32_leaves", ()),
                 expert_load=hasattr(self.module, "stacked_leaves"),
-                # a model with windowed layers and with 2-D leaves no
+                # a model with attention layers of several kinds, some
+                # windowed, whose key heads pair, and with 2-D leaves no
                 # matmul reads (models/sambay_lm.py)
-                attention_window=getattr(self.module, "attention_window",
-                                         None),
+                attention_windows=getattr(self.module, "attention_windows",
+                                          None),
+                attention_kv_heads=getattr(self.module,
+                                           "num_key_value_heads", None),
                 dense_noise_leaves=getattr(
                     self.module, "dense_noise_leaves", ()),
             )
@@ -558,6 +561,9 @@ class ES:
             # "kernel" says ops/pallas_attention.py engaged
             self.obs.counters.gauge("attention_form",
                                     self.engine.attention_form)
+            # and where: the form each kind of attention layer took
+            self.obs.counters.gauge("attention_form_by_kind",
+                                    self.engine.attention_form_by_kind)
         if getattr(self.engine, "head_form", None) is not None:
             # "kernel" says ops/pallas_head.py engaged
             self.obs.counters.gauge("head_form", self.engine.head_form)
@@ -1205,6 +1211,10 @@ class ES:
             # which form the policy's causal attention takes ("kernel" |
             # "xla"; None: a policy without one, or the replicated engine)
             "attention_form": getattr(self.engine, "attention_form", None),
+            # "<kind>:<form>,…" of the policy's attention layer kinds (a
+            # kind with a window is "xla" in a "kernel" program too)
+            "attention_form_by_kind": getattr(
+                self.engine, "attention_form_by_kind", None),
             # which form the policy's next-token head takes ("kernel" |
             # "xla"; None: a policy without one, or the replicated engine)
             "head_form": getattr(self.engine, "head_form", None),

@@ -23,9 +23,13 @@ parts (:func:`causal_attention`
 is the plain q/k/v/o form two of them share).  With a ``window`` a query
 sees the keys ``(t - window, t]`` and no others: the XLA form skips the key
 blocks wholly outside that band as it skips those above the diagonal; the
-kernel has no band yet, and the rule below turns a windowed model away.
+kernel has no band, so a CALL with a window is the XLA form wherever it is
+traced, and the model's other calls follow the rule below.
 Differential attention (arXiv 2410.05258) is ONE call of the core with both
-softmax maps as heads of it, then :func:`differential_combine`.  The core
+softmax maps as heads of it (``paired``: the heads handed in as the PAIRS
+the projections write, two score heads of half a lane block side by side
+over ONE value block, which the kernel reads where they lie and the XLA
+form orders itself), then :func:`differential_combine`.  The core
 has TWO forms of one algorithm, same mathematics, same tiles, same
 precision:
 
@@ -41,16 +45,20 @@ Which one a program takes is not an option of a model or of ``ES``: the
 ENGINE resolves it once at build from what it observes
 (``ShardedESEngine.attention_form``, by the one rule
 ``ops.pallas_attention.attention_form``: TPU devices; ONE device on the
-mesh so the operands are whole on it; a head's own query/key part and its
-values whole numbers of 128-lane column blocks; a shared part of 64 or a
-multiple of 128; the sequence a whole number of the kernel's blocks) and
-opens ``pallas_attention.kernel_scope`` around its trace of the policy.
-:func:`attention_core` takes the kernel inside that scope and the XLA
-form everywhere else, so ``apply`` outside an engine is the XLA form.
+mesh so the operands are whole on it; a head's values whole numbers of
+128-lane column blocks, and its own query/key part too, or half of one
+with values of ONE block and an even number of key heads (a pair); a
+shared part of 64 or a multiple of 128; the sequence a whole number of the
+kernel's blocks) and opens ``pallas_attention.kernel_scope`` around its
+trace of the policy.  :func:`attention_core` takes the kernel inside that
+scope for a call without a ``window``, and the XLA form for a call with
+one and everywhere else, so ``apply`` outside an engine is the XLA form.
 ``ES`` hands the engine the model's ``attention_widths``, as it hands it
 ``leaf_rows``: the widths the kernel's column blocks are cut by, one
 ``int`` for heads of one width or ``(a head's own part, the shared part,
-the value width)``.
+the value width)``; its key heads; and, where its attention layers are of
+several kinds, each kind's band (``attention_windows``), from which the
+engine says which form each kind took (``attention_form_by_kind``).
 
 The next-token scorer (:func:`score_next_tokens`: hidden states and the
 head's leaf -> each next token's log-probability) takes the leaf itself,
@@ -238,7 +246,7 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
 
 def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
                    scale: float, block: int, q_shared=None, k_shared=None,
-                   window: int | None = None):
+                   window: int | None = None, paired: bool = False):
     """``context [T, heads · value width]`` of causal attention with
     grouped heads: ``q [T, heads(, ·) qk width]``, ``k [T, kv heads(, ·)
     qk width]`` and ``v [T, kv heads(, ·) value width]`` in the compute
@@ -259,10 +267,23 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     two key blocks a query block however long the sequence, and masked
     scores exist in the first and the last of them.
 
-    Inside an engine's ``pallas_attention.kernel_scope`` the core is the
-    Pallas kernel (its own blocks, scores in VMEM, the shared part a
-    second contraction in the tile: the key part is never broadcast);
-    anywhere else the XLA form below, in blocks of ``block``, which
+    ``paired``: the heads come in PAIRS, as differential attention
+    publishes them: ``q [T, heads/2, 2, qk width]`` a diff-head's two maps
+    side by side, ``k [T, kv heads/2, 2, qk width]`` a key pair's two
+    heads, ``v [T, kv heads/2, value width]`` ONE value block a key pair,
+    read by both of its heads; diff-head ``j`` reads key pair ``j //
+    (heads / kv heads)``, its map ``m`` key head ``m`` of it.  The context
+    is that of the ``heads`` score heads ordered (key pair, map, group)
+    over the ``kv heads`` key heads (pair, map), each with its pair's
+    values: what :func:`differential_combine` reads.  The kernel reads the
+    pairs where they lie (a pair is one of its column blocks); the XLA
+    form makes that order of q and a copy of the values a map first.
+
+    Inside an engine's ``pallas_attention.kernel_scope`` a call WITHOUT a
+    ``window`` is the Pallas kernel (its own blocks, scores in VMEM, the
+    shared part a second contraction in the tile: the key part is never
+    broadcast); a call with one (the kernel has no band), and any call
+    anywhere else, is the XLA form below, in blocks of ``block``, which
     concatenates the shared parts onto q and k, the key's broadcast to
     every head (the module's text has the rule).  The XLA form's loop over
     blocks is unrolled: the program grows with ``T / block``, so a much
@@ -270,26 +291,33 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     dtype, t = q.dtype, q.shape[0]
     nq, nkv = num_heads, num_kv_heads
     hd = q.size // (t * nq)
-    interpret = pallas_attention.scoped_interpret()
-    if window is not None and interpret is not None:
-        raise NotImplementedError(
-            "the attention kernel has no band: a model with a window is "
-            "turned away by ops.pallas_attention.attention_form, and no "
-            "kernel scope should be open around it")
+    # the kernel has no band: a call with a window is the XLA form
+    interpret = (pallas_attention.scoped_interpret() if window is None
+                 else None)
+    value_heads = nkv
+    if paired and interpret is None:
+        # the score heads of one key head (pair, map) side by side, and a
+        # pair's values once a map
+        q = q.reshape(t, nkv // 2, nq // nkv, 2, hd).transpose(0, 1, 3, 2, 4)
+        v = jnp.broadcast_to(v.reshape(t, nkv // 2, 1, -1),
+                             (t, nkv // 2, 2, v.size // (t * (nkv // 2))))
+    elif paired:
+        value_heads = nkv // 2      # the kernel reads ONE block a key pair
     if v is None and (interpret is None or k.size != 2 * t * nkv * hd):
         # cut the values from beside the keys: the XLA form's einsums
         # read them apart (and the kernel's column blocks are one width)
         kv = k.reshape(t, nkv, -1)
         k, v = kv[..., :hd], kv[..., hd:]
-    vd = hd if v is None else v.size // (t * nkv)
+    vd = hd if v is None else v.size // (t * value_heads)
     if interpret is not None:
         with stage(ATTN):
             return pallas_attention.causal_attention(
                 q.reshape(t, nq * hd), k.reshape(t, -1),
-                None if v is None else v.reshape(t, nkv * vd),
+                None if v is None else v.reshape(t, value_heads * vd),
                 None if q_shared is None else q_shared.reshape(t, -1),
                 k_shared, num_heads=nq, num_kv_heads=nkv, head_dim=hd,
-                value_dim=vd, scale=scale, interpret=interpret)
+                value_dim=vd, scale=scale, interpret=interpret,
+                paired=paired)
     if q_shared is not None:
         with stage(ROPE):
             dr = k_shared.shape[-1]

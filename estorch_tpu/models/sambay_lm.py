@@ -38,13 +38,18 @@ pair's two value heads side by side); diff-head ``j`` reads key/value pair
 the layer's mask; ``o = (A₁ - λA₂) v``; ``λ = exp(λ_q1·λ_k1) - exp(λ_q2·λ_k2)
 + λ_init``, ``λ_init = 0.8 - 0.6·exp(-0.3·i)`` with ``i`` the published
 index; ``o <- RMSNorm(o; γ [2d], ε) · (1 - λ_init)``; out ``concat(o) W_o +
-b_o``.  Through ``lm_blocks.attention_core`` this is ONE call: ``H`` score
-heads of ``d`` (``[q₁; q₂]``, ordered by the key head they read) over ``G``
-key heads (``[k₁; k₂]``) with values ``2d`` wide (each value pair read by
-both maps), then ``lm_blocks.differential_combine``.
+b_o``.  Through ``lm_blocks.attention_core`` this is ONE call, handed the
+pairs as published (``paired``): ``H`` score heads of ``d`` over ``G`` key
+heads with ONE value block ``2d`` wide a key pair, read by both maps; its
+context is ordered (key pair, map, group), where
+``lm_blocks.differential_combine`` reads it.  On one TPU device the two
+full-causal kinds of layer take the attention kernel, which reads a pair as
+the one 128-lane block it is (``d`` 64); the windowed kind stays in the XLA
+form (the kernel has no band).
 
 Two values are carried ACROSS layers inside one member's forward: ``m [T,
-d_inner]`` float32 and ``(K, V)`` in the compute dtype.  Both are functions
+d_inner]`` float32 and ``(K, V)`` in the compute dtype, as the ``qkv``
+projection wrote them.  Both are functions
 of perturbed leaves, so under the engine's ``vmap``s they are per member.
 
 ``layer_indices`` picks WHICH published layers this program holds (a
@@ -199,15 +204,22 @@ class SambaYLM:
     def attention_widths(self) -> tuple:
         """``(a score head's width, no shared part, the value width)``: a
         map's heads are ``head_dim`` wide where they are scored and a PAIR's
-        values, twice that, where they are summed (the attention form's
-        rule reads it, ops/pallas_attention.py)."""
+        values, twice that, where they are summed.  The attention form's
+        rule reads it (ops/pallas_attention.py): at the published
+        ``head_dim`` of 64 a pair is one 128-lane block, two score heads
+        side by side over one value block, which the kernel takes as it
+        lies."""
         return (self.head_dim, 0, 2 * self.head_dim)
 
     @property
-    def attention_window(self) -> int | None:
-        """The band of the windowed layers, where one is held (the
-        attention form's rule reads it: the kernel has no band)."""
-        return self.sliding_window if WINDOW in self.layer_types else None
+    def attention_windows(self) -> dict:
+        """``{attention layer kind held: the band of its calls of the core
+        | None}``, in layer order.  The engine reads it to say which form
+        each kind takes: the kernel has no band, so a call with a window is
+        the XLA form inside the kernel's scope too."""
+        return {kind: self.sliding_window if kind == WINDOW else None
+                for kind in dict.fromkeys(self.layer_types)
+                if kind in ATTENTION_PARTS}
 
     @property
     def head_width(self) -> int:
@@ -440,25 +452,26 @@ class SambaYLM:
         if kind == CROSS:
             q = self._dense(p, noise, c, "q", u, bias="q_bias")
             k, v = carried["kv"]
+            # whoever hands on a copy of a pair's values a map ([T, pairs,
+            # 2, 2·hd], the layout before the core took pairs: the
+            # benchmark's degraded forms do): one of them
+            v = v.reshape(t, pairs, -1, 2 * hd)[:, :, 0]
         else:
             qkv = self._dense(p, noise, c, "qkv", u, bias="qkv_bias")
             q = qkv[:, :nq * hd]
             k = qkv[:, nq * hd:(nq + nkv) * hd].astype(dtype)
             # a pair's values, read by both of its maps
-            v = jnp.broadcast_to(
-                qkv[:, (nq + nkv) * hd:].astype(dtype).reshape(
-                    t, pairs, 1, 2 * hd), (t, pairs, 2, 2 * hd))
+            v = qkv[:, (nq + nkv) * hd:].astype(dtype)
             if kind == FULL_KV:
                 carried["kv"] = (k, v)
-        # diff-head j = pair · group + g holds maps (q₁, q₂); the core wants
-        # the score heads of one key head (pair, map) side by side
-        q = q.astype(dtype).reshape(t, pairs, group, 2, hd).transpose(
-            0, 1, 3, 2, 4)
+        # as published: diff-head j = pair · group + g holds its two maps
+        # (q₁, q₂) side by side, a key pair (k₁, k₂), and ONE value block
         with stage(ATTN), part(ATTENTION_PARTS[kind]):
             ctx = lm_blocks.attention_core(
-                q, k, v, num_heads=nq, num_kv_heads=nkv,
+                q.astype(dtype), k, v, num_heads=nq, num_kv_heads=nkv,
                 scale=1.0 / math.sqrt(hd), block=self.attention_block,
-                window=self.sliding_window if kind == WINDOW else None)
+                window=self.sliding_window if kind == WINDOW else None,
+                paired=True)
 
         def leaf(name):
             return perturbed_leaf(p[name], subtree(noise, name), c)

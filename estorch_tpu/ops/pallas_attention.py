@@ -23,6 +23,21 @@ out of that array, key column block ``2g`` and values ``2g + 1``
 (``v=None``): no k and no v is cut from it, and XLA then has the
 projection write the kernel's row-major tiling itself.
 
+Heads HALF a lane block wide come two a block (``paired``): a
+differential PAIR as arXiv 2410.05258 publishes it, ``q [T, H/2, 2, 64]``
+two query parts side by side, ``k [T, G/2, 2, 64]`` two key heads, ONE
+value block of 128 a key pair.  Score head ``h = (pair p, map m, g)`` of
+the grid reads q's column block ``p · group + g`` as the projection wrote
+it, both maps of it, against key column block ``2p + m`` of the layout
+``[T, pairs, 2, 128] = [k₁, 0 | 0, k₂]``, and the pair's ONE value block;
+its context goes to column block ``h`` of ``[T, pairs · 2 · group · 128]``,
+map 1's ``group`` heads and then map 2's, where
+``lm_blocks.differential_combine`` reads it.  Exact (the other map's lanes
+meet zeros; the MXU contracts 128 deep whatever the width), no transposing
+copy of q, no copy of the values a map, and the body below is the
+single-term one unchanged: only index maps differ.  The padded key costs 42
+MB a member and layer at 8,192 positions x 20 key heads in bfloat16.
+
 The score may have a SECOND term: ``s = q kᵀ + q_shared k_sharedᵀ``, each
 head's second query part against ONE key part ``[T, width]`` that every
 head reads (latent attention's rotated 64, which the XLA form broadcasts
@@ -57,9 +72,12 @@ the un-normalised probabilities are what is rounded to bfloat16.
 
 What Mosaic dictated (learned by compiling for the v5e, not by reading):
 a block's last two dimensions must be divisible by 8 and 128 or span the
-array, so a head is a column block only where ``head_dim % 128 == 0``
-(granite's heads of 64 are refused at lowering: two heads a block, split in
-VMEM, is ROADMAP R4's); the float32 score tile, its exponential and the
+array, so a head is a column block only where ``head_dim % 128 == 0``;
+heads of 64 come two a block, which this kernel takes where the pair's
+values are ONE block of 128 (``paired`` above, and the shared 64 below:
+the split is the key's zeros, in HBM), and refuses where each head of 64
+has values of 64 of its own, half a block of context (granite's grouped
+heads: split in VMEM, ROADMAP R4's); the float32 score tile, its exponential and the
 bfloat16 probabilities of a block pair live on the kernel's stack in scoped
 VMEM, 16 MiB by default: blocks of 1024 x 1024 fit, 2048 x 2048 ask for
 24.6 MiB and are refused.  The second term changes none of that: Mosaic
@@ -84,7 +102,11 @@ mesh), tests pass ``True``.  Nothing here consults
 Which form a program takes is the ENGINE's decision, made once at build
 from what it observes (:func:`attention_form`), and told to the model
 function while the engine traces it (:func:`kernel_scope`): a call outside
-an engine's trace takes the XLA form.
+an engine's trace takes the XLA form.  The kernel has no band: inside the
+scope a CALL of the core with a ``window`` takes the XLA form too
+(:func:`call_form`; ``lm_blocks.attention_core`` reads its own argument),
+and the engine says which form each kind of layer took
+(``attention_form_by_kind``).
 """
 
 from __future__ import annotations
@@ -134,14 +156,17 @@ def kernel_block(length: int) -> int | None:
 
 
 def attention_form(platform: str, n_devices: int, widths, length: int,
-                   window: int | None = None) -> str:
+                   window: int | None = None,
+                   kv_heads: int | None = None) -> str:
     """``"kernel"`` or ``"xla"``: :func:`attention_form_why` without its
     reason."""
-    return attention_form_why(platform, n_devices, widths, length, window)[0]
+    return attention_form_why(platform, n_devices, widths, length, window,
+                              kv_heads)[0]
 
 
 def attention_form_why(platform: str, n_devices: int, widths, length: int,
-                       window: int | None = None) -> tuple[str, str]:
+                       window: int | None = None,
+                       kv_heads: int | None = None) -> tuple[str, str]:
     """``("kernel" | "xla", why)`` for a program on a mesh of ``n_devices``
     devices of ``platform`` that runs attention over ``length`` positions
     with heads of ``widths``, as the model states them: one width (an
@@ -152,32 +177,51 @@ def attention_form_why(platform: str, n_devices: int, widths, length: int,
     taken when, and only when, ALL hold: the devices are TPUs; there is
     one of them, so the attention's operands are whole on it (under GSPMD
     an unwrapped ``pallas_call`` would be replicated, not partitioned); a
-    head's own part and its values are whole numbers of 128-lane column
-    blocks; the shared part is a whole number of them or half of one (two
+    head's values are whole numbers of 128-lane column blocks, and its own
+    part is too, OR is half of one with values of ONE block and an even
+    number of key heads ``kv_heads``: two score heads a block that read
+    one value block, which is what a differential PAIR is and how such a
+    model hands its heads to the core (``attention_core(paired=True)``);
+    the shared part is a whole number of blocks or half of one (two
     heads a block); the sequence is a whole number of the kernel's blocks
-    (:func:`kernel_block`); no layer has a ``window`` (the kernel has no
-    band).  ``why`` names the first of these that fails (the engine logs
-    it and the run manifest carries it)."""
+    (:func:`kernel_block`).  ``why`` names the first of these that fails
+    (the engine logs it and the run manifest carries it).
+
+    ``window``: the band of the model's windowed layers, if it has any.
+    It decides nothing here: the kernel has no band, so inside the
+    program's scope a CALL with a window takes the XLA form
+    (:func:`call_form`) and every other call the kernel; ``why`` says so."""
     head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
                            else widths)
+    pair = (2 * head == value == LANES and not shared
+            and kv_heads is not None and kv_heads % 2 == 0)
     failed = [why for ok, why in (
         (platform == "tpu", f"the devices are {platform!r}, not TPUs"),
         (n_devices == 1, f"{n_devices} devices on the mesh: the operands "
                          "are not whole on one"),
-        (head % LANES == 0 and value % LANES == 0,
-         f"a head's own part is {head} wide and its values {value}: not "
-         f"whole {LANES}-lane column blocks"),
+        (value % LANES == 0 and (head % LANES == 0 or pair),
+         f"a head's own part is {head} wide and its values {value}, over "
+         f"{kv_heads} key heads: not whole {LANES}-lane column blocks, nor "
+         "pairs of half a block that read one value block"),
         (shared % LANES == 0 or shared == LANES // 2,
          f"the shared part is {shared} wide: neither whole {LANES}-lane "
          "blocks nor half of one"),
         (kernel_block(length) is not None,
          f"no block of {BLOCKS} divides {length} positions"),
-        (window is None, f"a layer has a window of {window}: the kernel "
-                         "has no band"),
     ) if not ok]
     if failed:
         return "xla", failed[0]
-    return "kernel", "one TPU device, whole column blocks, whole row blocks"
+    return "kernel", "one TPU device, {}, whole row blocks{}".format(
+        "two score heads a column block" if pair else "whole column blocks",
+        "" if window is None else
+        f"; layers with a window of {window} in the XLA form")
+
+
+def call_form(form: str, window: int | None) -> str:
+    """The form ONE call of the core takes in a program whose form is
+    ``form``: the kernel has no band, so a call with a ``window`` is the
+    XLA form inside the kernel's scope too."""
+    return "kernel" if form == "kernel" and window is None else "xla"
 
 
 _SCOPE: contextvars.ContextVar = contextvars.ContextVar(
@@ -217,8 +261,8 @@ def _last_visible(i, block_q: int, block_k: int):
 
 def attention_cost(length: int, num_heads: int, num_kv_heads: int,
                    head_dim: int, value_dim: int, shared_dim: int,
-                   block_q: int, block_k: int,
-                   itemsize: int) -> pl.CostEstimate:
+                   block_q: int, block_k: int, itemsize: int,
+                   paired: bool = False) -> pl.CostEstimate:
     """What ONE call of the kernel does, from its grid and blocks: the
     declaration ``pallas_call`` hands XLA (the scheduler reads it, and a
     profiler's trace carries it as the custom call's ``flops`` and
@@ -227,17 +271,21 @@ def attention_cost(length: int, num_heads: int, num_kv_heads: int,
     row; the grid steps beyond are skipped and not counted), each ``2 ·
     block_q · block_k · (head_dim + shared_dim + value_dim)``: the widths
     the model states, although the MXU contracts a shared 64 as 128 deep
-    and multiplies the masked half of a diagonal tile too.
+    and multiplies the masked half of a diagonal tile too (and a pair's
+    64-wide score heads as 128 deep: two heads a block).
     Transcendentals: a tile's exponentials, one a score and one a row for
     the rescaling.  Bytes: q, k, v, the shared parts and the context ONCE
     each (the algorithm's least; k and v are fetched again for every query
-    block that sees them, 2.5 times at four blocks).  ``vmap`` scales all
-    three by the members in front of the grid."""
+    block that sees them, 2.5 times at four blocks; ``paired``: ONE value
+    block a pair of key heads).  ``vmap`` scales all three by the members
+    in front of the grid."""
     tiles = sum(_last_visible(i, block_q, block_k) + 1
                 for i in range(length // block_q))
+    value_heads = num_kv_heads // 2 if paired else num_kv_heads
     elements = length * (
         num_heads * (head_dim + shared_dim + value_dim)        # q, q_shared, out
-        + num_kv_heads * (head_dim + value_dim) + shared_dim)  # k, v, k_shared
+        + num_kv_heads * head_dim + value_heads * value_dim    # k, v
+        + shared_dim)                                          # k_shared
     return pl.CostEstimate(
         flops=2 * num_heads * tiles * block_q * block_k
         * (head_dim + shared_dim + value_dim),
@@ -306,7 +354,7 @@ def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "num_heads", "num_kv_heads", "head_dim", "value_dim", "scale", "block_q",
-    "block_k", "interpret"))
+    "block_k", "interpret", "paired"))
 def causal_attention(
     q: jax.Array,  # [T, num_heads · head_dim], rotated, compute dtype
     k: jax.Array,  # [T, num_kv_heads · head_dim], rotated, compute dtype
@@ -322,6 +370,7 @@ def causal_attention(
     value_dim: int | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
+    paired: bool = False,
 ) -> jax.Array:
     """The context ``softmax(scale · s + causal mask) v`` per head, ``[T,
     num_heads · value_dim]`` in q's dtype, with grouped heads (query head
@@ -338,6 +387,25 @@ def causal_attention(
     wrote them (latent attention's ``kv_b``); the kernel reads both out of
     that array, so no k and no v is cut from it first.  The two widths
     must be equal (the key is column block ``2g``, the values ``2g + 1``).
+
+    ``paired``: the heads come in PAIRS that share a column block, as
+    differential attention publishes them: ``q [T, num_heads/2 · 2 ·
+    head_dim]`` holds query head pair ``j``'s two heads side by side (its
+    two softmax maps), ``k [T, num_kv_heads/2 · 2 · head_dim]`` key pair
+    ``p``'s two heads, and ``v [T, num_kv_heads/2 · value_dim]`` ONE value
+    block a key pair, read by both of its heads; query pair ``j`` reads
+    key pair ``j // (num_heads / num_kv_heads)``, its head ``m`` key head
+    ``m`` of it.  The context is ``[T, num_kv_heads/2 · 2 · group ·
+    value_dim]``: per key pair, head 0 of its ``group`` query pairs and
+    then head 1 of them (where ``lm_blocks.differential_combine`` reads
+    it).  Grid head ``h = (pair p, map m, g)`` reads q column block ``p ·
+    group + g`` as it lies, both heads of it, against key column block
+    ``2p + m`` of the layout ``[T, pairs, 2, 2 · head_dim] = [k₀, 0 | 0,
+    k₁]``, built here: the other head's lanes meet zeros, so the one
+    contraction over the block IS head ``m``'s score, exactly, and the
+    kernel's body is the single-term one (as the shared 64 below).  On
+    the chip a pair is one 128-lane block: ``head_dim`` 64, ``value_dim``
+    a multiple of 128.
 
     ``block_q``, ``block_k``: rows of a query and of a key block;
     :func:`kernel_block` of ``T`` where not given (the whole sequence where
@@ -360,14 +428,24 @@ def causal_attention(
         raise ValueError(
             f"values beside their keys are column blocks of one width; "
             f"got {head_dim} and {value_dim}")
+    shared = q_shared is not None
+    if shared != (k_shared is not None):
+        raise ValueError("a shared score term needs q_shared AND k_shared")
+    if paired and (beside or shared or num_kv_heads % 2):
+        raise ValueError(
+            "heads in pairs are an even number of key heads with their "
+            "values apart and no shared part; got "
+            f"{num_kv_heads} key heads, v {None if beside else v.shape}, "
+            f"q_shared {q_shared.shape if shared else None}")
     k_width = head_dim + value_dim if beside else head_dim
+    value_heads = num_kv_heads // 2 if paired else num_kv_heads
     if (q.shape != (t, num_heads * head_dim)
             or k.shape != (t, num_kv_heads * k_width)
-            or not (beside or v.shape == (t, num_kv_heads * value_dim))):
+            or not (beside or v.shape == (t, value_heads * value_dim))):
         raise ValueError(
             f"q {q.shape}, k {k.shape}, v {None if beside else v.shape} are "
-            f"not [T, heads · {head_dim}], [T, heads · {k_width}] and [T, "
-            f"heads · {value_dim}] of {num_heads} and {num_kv_heads} heads")
+            f"not [T, {num_heads} · {head_dim}], [T, {num_kv_heads} · "
+            f"{k_width}] and [T, {value_heads} · {value_dim}]")
     group = num_heads // num_kv_heads
 
     def kv_row(i, j):
@@ -383,21 +461,36 @@ def causal_attention(
     def values_beside(h, i, j):
         return kv_row(i, j), 2 * (h // group) + 1
 
+    # the column block of q and of v that grid head h reads
+    q_width, own_q, own_v = head_dim, (lambda h: h), (lambda h: h // group)
+    if paired:
+        # two heads a column block: [k₀, 0 | 0, k₁] a key pair, whose
+        # column block 2p + m picks head m's half of q's block by its
+        # zeros; h = (pair p, map m, g) reads q's block p · group + g as it
+        # lies and the pair's ONE value block.  The layout is a select over
+        # whole blocks of lanes (reshaped to [.., 2, head_dim], XLA
+        # transposes k to cut it at the 64-wide heads, and back)
+        mine = (jnp.arange(2 * head_dim) // head_dim
+                == jnp.arange(2)[:, None])                  # [map, lane]
+        k = jnp.where(mine, k.reshape(t, value_heads, 1, 2 * head_dim),
+                      0).reshape(t, num_kv_heads * 2 * head_dim)
+        q_width, own_q, own_v = 2 * head_dim, (
+            lambda h: h // (2 * group) * group + h % group), (
+            lambda h: h // (2 * group))
+
     operands = [q, k, k if beside else v]
     in_specs = [
-        pl.BlockSpec((block_q, head_dim), lambda h, i, j: (i, h)),
-        pl.BlockSpec((block_k, head_dim), key_beside if beside else kv_block),
+        pl.BlockSpec((block_q, q_width), lambda h, i, j: (i, own_q(h))),
+        pl.BlockSpec((block_k, q_width), key_beside if beside else kv_block),
         pl.BlockSpec((block_k, value_dim),
-                     values_beside if beside else kv_block),
+                     values_beside if beside
+                     else lambda h, i, j: (kv_row(i, j), own_v(h))),
     ]
-    shared = q_shared is not None
-    if shared != (k_shared is not None):
-        raise ValueError("a shared score term needs q_shared AND k_shared")
     # from the widths the model states, before a shared 64 is packed below
     cost = attention_cost(
         t, num_heads, num_kv_heads, head_dim, value_dim,
         k_shared.shape[-1] if shared else 0, block_q, block_k,
-        q.dtype.itemsize)
+        q.dtype.itemsize, paired)
     if shared:
         width = k_shared.shape[-1]
         if (q_shared.shape != (t, num_heads * width)

@@ -78,7 +78,8 @@ from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
                            lowrank_tree_noise, lowrank_tree_weighted_sum)
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
-from ..ops.pallas_attention import attention_form_why, kernel_scope
+from ..ops.pallas_attention import (attention_form_why, call_form,
+                                    kernel_scope)
 from ..ops.pallas_head import head_form
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
@@ -167,7 +168,8 @@ class ShardedESEngine:
         leaf_rows_per_token: dict[str, float] | None = None,
         float32_leaves=(),
         expert_load: bool = False,
-        attention_window: int | None = None,
+        attention_windows: dict | None = None,
+        attention_kv_heads: int | None = None,
         dense_noise_leaves=(),
     ):
         if config.obs_norm:
@@ -237,8 +239,11 @@ class ShardedESEngine:
         # None for a policy that has none.  Resolved once, here, from the
         # mesh, the sequence length and the widths the policy states (run
         # manifest + telemetry gauge)
-        # the band of the policy's windowed layers (None: it has none)
-        self._attention_window = attention_window
+        # {attention layer kind: the band of its calls | None}, as the
+        # policy states them (one kind, no band, where it states none), and
+        # its key heads: a pair of them may share a column block
+        self._attention_windows = dict(attention_windows or {"causal": None})
+        self._attention_kv_heads = attention_kv_heads
         self.attention_form = (
             None if attention_widths is None
             else self._resolve_attention_form(attention_widths))
@@ -248,6 +253,15 @@ class ShardedESEngine:
         self.attention_form_why = (
             None if attention_widths is None
             else self._attention_rule(attention_widths)[1])
+        # "<kind>:<form>,…": the form the calls of each attention layer
+        # kind take in this engine's programs (the kernel has no band: a
+        # kind with a window stays in the XLA form inside the kernel's
+        # scope)
+        self.attention_form_by_kind = (
+            None if attention_widths is None
+            else ",".join(
+                f"{kind}:{call_form(self.attention_form, window)}"
+                for kind, window in self._attention_windows.items()))
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
         # "kernel" | "xla": which form the policy's next-token head takes
@@ -262,8 +276,9 @@ class ShardedESEngine:
             import logging
 
             logging.getLogger(__name__).info(
-                "attention_form %s (%s); head_form %s", self.attention_form,
-                self.attention_form_why, self.head_form)
+                "attention_form %s (%s; %s); head_form %s",
+                self.attention_form, self.attention_form_why,
+                self.attention_form_by_kind, self.head_form)
         self.n_devices = int(mesh.devices.size)
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.pop_shards = int(axis_sizes[POP_AXIS])
@@ -423,7 +438,10 @@ class ShardedESEngine:
         """``("kernel" | "xla", why)``: see ``attention_form_why``."""
         return attention_form_why(
             self.mesh.devices.flat[0].platform, int(self.mesh.devices.size),
-            widths, self.config.horizon, self._attention_window)
+            widths, self.config.horizon,
+            next((w for w in self._attention_windows.values()
+                  if w is not None), None),
+            self._attention_kv_heads)
 
     def _resolve_attention_form(self, widths) -> str:
         return self._attention_rule(widths)[0]

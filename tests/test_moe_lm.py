@@ -896,6 +896,7 @@ class TestThroughTheShardedEngine:
         assert gauges.get("experts_per_token") == 3
         assert gauges.get("mtp_depth") == 1
         assert gauges.get("attention_form") == "xla"
+        assert gauges.get("attention_form_by_kind") == "causal:xla"
         assert gauges.get("loop_steps", None) is None
         cfg = es.run_manifest()["config"]
         assert (cfg["experts_held"], cfg["experts_total"],

@@ -66,8 +66,29 @@ def _parts(t, nh, head, shared, value, dtype, seed=0):
     return (*parts[:3], *(parts[3:] if shared else (None, None)))
 
 
-def _plain(q, k, v, nq, nkv, scale):
+def _plain_pairs(q, k, v, nq, nkv, scale):
+    """Differential attention's TWO masked softmaxes a diff-head, one at a
+    time in float32 ``highest``, from the published layouts (``q [T, nq/2,
+    2, HD]``, ``k [T, nkv/2, 2, HD]``, ONE value block ``[T, nkv/2, 2·HD]``
+    a key pair): the context ``[T, pairs, map, group, 2·HD]`` that
+    ``lm_blocks.differential_combine`` reads."""
+    t, f32, hi = q.shape[0], jnp.float32, "highest"
+    pairs, group = nkv // 2, nq // nkv
+    qh = q.astype(f32).reshape(t, pairs, group, 2, HD)
+    kh = k.astype(f32).reshape(t, pairs, 2, HD)
+    vh = v.astype(f32).reshape(t, pairs, 2 * HD)
+    seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    ctx = [[[jnp.dot(jax.nn.softmax(jnp.where(
+        seen, jnp.dot(qh[:, p, g, m], kh[:, p, m].T, precision=hi) * scale,
+        -jnp.inf), -1), vh[:, p], precision=hi)
+        for g in range(group)] for m in range(2)] for p in range(pairs)]
+    return jnp.transpose(jnp.asarray(ctx), (3, 0, 1, 2, 4)).reshape(t, -1)
+
+
+def _plain(q, k, v, nq, nkv, scale, paired=False):
     """Full masked softmax per head, float32 ``highest``."""
+    if paired:
+        return _plain_pairs(q, k, v, nq, nkv, scale)
     t, f32, hi = q.shape[0], jnp.float32, "highest"
     qh = q.astype(f32).reshape(t, nq, HD)
     kh = jnp.repeat(k.astype(f32).reshape(t, nkv, HD), nq // nkv, axis=1)
@@ -92,10 +113,18 @@ def _through_lm_blocks(q, k, v, nq, nkv, scale, block):
         head_dim=HD, scale=scale, block=block)
 
 
-def _kernel(q, k, v, nq, nkv, scale, block_q, block_k=None):
+def _kernel(q, k, v, nq, nkv, scale, block_q, block_k=None, paired=False):
+    """``paired``: q, k and v as ``_qkv`` makes them, READ as differential
+    pairs (``v [T, nkv · HD]`` is then ``nkv/2`` value blocks of 2·HD)."""
     return causal_attention(
         q, k, v, num_heads=nq, num_kv_heads=nkv, head_dim=HD, scale=scale,
+        value_dim=2 * HD if paired else None, paired=paired,
         block_q=block_q, block_k=block_k or block_q, interpret=True)
+
+
+# (query heads, key heads, in pairs): grouped and not; a differential pair
+# of key heads with one and with two diff-heads a pair (group 1 and 2)
+HEADS = [(4, 4, False), (4, 1, False), (4, 4, True), (8, 4, True)]
 
 
 def _f32(x):
@@ -105,43 +134,107 @@ def _f32(x):
 class TestKernelAgainstBothForms:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("blocks", [1, 2, 4])
-    @pytest.mark.parametrize("nq, nkv", [(4, 4), (4, 1)])
-    def test_kernel_is_the_masked_softmax(self, nq, nkv, blocks, dtype):
+    @pytest.mark.parametrize("nq, nkv, paired", HEADS)
+    def test_kernel_is_the_masked_softmax(self, nq, nkv, paired, blocks,
+                                          dtype):
+        """Against a plain softmax a head; heads in pairs against the two
+        softmaxes a diff-head of differential attention."""
         block, scale = 8, HD ** -0.5
         q, k, v = _qkv(block * blocks, nq, nkv, dtype, seed=blocks)
-        got = _kernel(q, k, v, nq, nkv, scale, block)
-        assert got.shape == q.shape and got.dtype == q.dtype
+        got = _kernel(q, k, v, nq, nkv, scale, block, paired=paired)
+        # a pair's heads are summed over the pair's 2·HD values
+        assert got.shape == (q.shape[0], nq * HD * (2 if paired else 1))
+        assert got.dtype == q.dtype
         tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
         np.testing.assert_allclose(
-            _f32(got), _f32(_plain(q, k, v, nq, nkv, scale)), atol=tol)
+            _f32(got), _f32(_plain(q, k, v, nq, nkv, scale, paired)),
+            atol=tol)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("blocks", [1, 2, 4])
-    @pytest.mark.parametrize("nq, nkv", [(4, 4), (4, 1)])
-    def test_kernel_is_the_xla_block_causal_form(self, nq, nkv, blocks,
-                                                 dtype):
+    @pytest.mark.parametrize("nq, nkv, paired", HEADS)
+    def test_kernel_is_the_xla_block_causal_form(self, nq, nkv, paired,
+                                                 blocks, dtype):
         """The two forms ``lm_blocks.causal_attention`` dispatches between:
-        outside a scope the XLA form, inside one the kernel."""
+        outside a scope the XLA form, inside one the kernel (heads in
+        pairs: ``attention_core(paired=True)``, whose XLA form orders the
+        score heads and copies a pair's values a map itself)."""
         block, scale = 8, 0.3
         q, k, v = _qkv(block * blocks, nq, nkv, dtype, seed=10 + blocks)
-        xla = _through_lm_blocks(q, k, v, nq, nkv, scale, block)
+
+        def through_the_core():
+            if not paired:
+                return _through_lm_blocks(q, k, v, nq, nkv, scale, block)
+            return lm_blocks.attention_core(
+                q, k, v, num_heads=nq, num_kv_heads=nkv, scale=scale,
+                block=block, paired=True)
+
+        xla = through_the_core()
         tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
         np.testing.assert_allclose(
-            _f32(_kernel(q, k, v, nq, nkv, scale, block)), _f32(xla),
-            atol=tol)
+            _f32(_kernel(q, k, v, nq, nkv, scale, block, paired=paired)),
+            _f32(xla), atol=tol)
         with kernel_scope(interpret=True):
-            scoped = _through_lm_blocks(q, k, v, nq, nkv, scale, block)
+            scoped = through_the_core()
         np.testing.assert_allclose(_f32(scoped), _f32(xla), atol=tol)
 
     @pytest.mark.parametrize("block_q, block_k", [
         (16, 8), (8, 16), (32, 8), (8, 32), (32, 32)])
-    def test_unequal_blocks(self, block_q, block_k):
+    @pytest.mark.parametrize("paired", [False, True])
+    def test_unequal_blocks(self, block_q, block_k, paired):
         """Key blocks the diagonal crosses part-way, rows that a visible
         block masks whole, and the clamp of the index map."""
         q, k, v = _qkv(32, 4, 2, jnp.float32, seed=3)
         np.testing.assert_allclose(
-            _f32(_kernel(q, k, v, 4, 2, 0.25, block_q, block_k)),
-            _f32(_plain(q, k, v, 4, 2, 0.25)), atol=F32_TOL)
+            _f32(_kernel(q, k, v, 4, 2, 0.25, block_q, block_k,
+                         paired=paired)),
+            _f32(_plain(q, k, v, 4, 2, 0.25, paired)), atol=F32_TOL)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("group", [1, 2])
+    def test_a_pairs_maps_given_equal_heads_give_equal_contexts(self, group,
+                                                                dtype):
+        """Exactness of the split: map ``m`` contracts the whole column
+        block of q against ``[k₀, 0]`` or ``[0, k₁]``, and the other map's
+        lanes meet zeros.  With both heads of every query pair and of every
+        key pair equal, the two maps' contexts are the same numbers; and
+        they are the single-term kernel's on one head of each, bit for
+        bit."""
+        t, pairs = 32, 2
+        q1, k1, v = _qkv(t, pairs * group, pairs, dtype, seed=6)
+        v = jnp.concatenate([v, -v], axis=1)    # [T, pairs · 2·HD]
+
+        def twice(x):
+            heads = x.reshape(t, -1, 1, HD)
+            return jnp.concatenate([heads, heads], axis=2).reshape(t, -1)
+
+        got = causal_attention(
+            twice(q1), twice(k1), v, num_heads=2 * pairs * group,
+            num_kv_heads=2 * pairs, head_dim=HD, value_dim=2 * HD, scale=0.3,
+            block_q=16, block_k=8, interpret=True, paired=True)
+        maps = _f32(got).reshape(t, pairs, 2, group, 2 * HD)
+        np.testing.assert_array_equal(maps[:, :, 0], maps[:, :, 1])
+        single = causal_attention(
+            q1, k1, v, num_heads=pairs * group, num_kv_heads=pairs,
+            head_dim=HD, value_dim=2 * HD, scale=0.3, block_q=16, block_k=8,
+            interpret=True)
+        np.testing.assert_array_equal(
+            maps[:, :, 0], _f32(single).reshape(t, pairs, group, 2 * HD))
+
+    def test_a_pair_is_read_where_it_lies(self):
+        """No transposing copy of q and no copy of the values a map: the
+        program around the ``pallas_call`` holds the ``[k₀, 0 | 0, k₁]``
+        layout of the key (a select) and nothing that touches q or v."""
+        q, k, v = _qkv(32, 8, 4, jnp.float32, seed=8)
+        jaxpr = jax.make_jaxpr(lambda q, k, v: _kernel(
+            q, k, v, 8, 4, 0.3, 8, paired=True))(q, k, v)
+        inner, = [eq.params["jaxpr"] for eq in jaxpr.jaxpr.eqns
+                  if eq.primitive.name in ("pjit", "jit")]
+        call, = [eq for eq in inner.jaxpr.eqns
+                 if eq.primitive.name == "pallas_call"]
+        assert call.invars[0] is inner.jaxpr.invars[0]     # q as handed in
+        assert call.invars[2] is inner.jaxpr.invars[2]     # v as handed in
+        assert "transpose" not in str(inner) and "select_n" in str(inner)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("head, shared, value, block_q, block_k", [
@@ -336,7 +429,8 @@ class TestKernelAgainstBothForms:
 
     @pytest.mark.parametrize("case, match", [
         ("ragged", "whole number"), ("heads", "multiple of key/value"),
-        ("shape", "are not")])
+        ("shape", "are not"), ("odd pairs", "even number of key heads"),
+        ("pairs beside", "values apart"), ("a pair's values", "are not")])
     def test_sizes_are_validated(self, case, match):
         q, k, v = _qkv(24, 4, 2, jnp.float32)
         kw = dict(num_heads=4, num_kv_heads=2, head_dim=HD, scale=1.0,
@@ -345,6 +439,17 @@ class TestKernelAgainstBothForms:
             kw["block_q"] = 16
         elif case == "heads":
             kw["num_kv_heads"] = 3
+        elif case == "odd pairs":
+            q, k, v = q[:, :3 * HD], k[:, :3 * HD], v[:, :2 * HD]
+            kw.update(num_heads=3, num_kv_heads=3, value_dim=2 * HD,
+                      paired=True)
+        elif case == "pairs beside":
+            k, v = jnp.concatenate([k, v], axis=1), None
+            kw["paired"] = True
+        elif case == "a pair's values":
+            # a copy of the pair's values a map: the unpaired contract
+            v = jnp.concatenate([v, v], axis=1)
+            kw.update(value_dim=2 * HD, paired=True)
         else:
             k = k[:, :HD]
         with pytest.raises(ValueError, match=match):
@@ -378,6 +483,26 @@ class TestTheRule:
     def test_form_from_what_the_engine_observes(self, platform, devices,
                                                 widths, length, form):
         assert attention_form(platform, devices, widths, length) == form
+
+    @pytest.mark.parametrize("devices, widths, kv_heads, form", [
+        (1, (64, 0, 128), 20, "kernel"),   # phi4-flash-es-8k-1chip: a pair
+        (4, (64, 0, 128), 20, "xla"),
+        (1, (64, 0, 128), 5, "xla"),       # odd: the last head has no pair
+        (1, (64, 0, 128), None, "xla"),    # the model states no key heads
+        (1, 64, 4, "xla"),      # granite's on one chip: half a block of values
+        (1, (64, 0, 64), 4, "xla"),
+        (1, (64, 64, 128), 20, "xla"),     # pairs with a shared part: not written
+        (1, (32, 0, 64), 20, "xla"),       # a quarter of a block
+        (1, (128, 0, 128), 5, "kernel"),   # whole blocks need no pair
+    ])
+    def test_half_a_block_passes_as_a_pair(self, devices, widths, kv_heads,
+                                           form):
+        """Two score heads of 64 a column block over ONE value block of
+        128 and an even number of key heads: differential attention's
+        pair.  The band of a windowed layer decides nothing."""
+        for window in (None, 512):
+            assert attention_form("tpu", devices, widths, 8192, window,
+                                  kv_heads) == form
 
     @pytest.mark.parametrize("length, block", [
         (4096, 1024), (1024, 1024), (1536, 512), (768, 256), (384, 128),
@@ -443,6 +568,11 @@ class TestThroughTheShardedEngine:
         assert es.engine.attention_form == "xla"
         assert es.run_manifest()["config"]["attention_form"] == "xla"
         assert es.obs.counters.snapshot()["attention_form"] == "xla"
+        # one kind of attention layer, which states no band
+        assert es.run_manifest()["config"]["attention_form_by_kind"] == (
+            "causal:xla")
+        assert es.obs.counters.snapshot()["attention_form_by_kind"] == (
+            "causal:xla")
 
     def test_a_policy_without_attention_has_no_form(self, devices8):
         from estorch_tpu import ES, JaxAgent, MLPPolicy
@@ -457,6 +587,8 @@ class TestThroughTheShardedEngine:
         assert es.engine.attention_form is None
         assert es.run_manifest()["config"]["attention_form"] is None
         assert "attention_form" not in es.obs.counters.snapshot()
+        assert es.run_manifest()["config"]["attention_form_by_kind"] is None
+        assert "attention_form_by_kind" not in es.obs.counters.snapshot()
 
     @pytest.mark.parametrize("policy", [LoopedLM, HybridLM, MoELM])
     def test_forced_kernel_runs_the_generation_the_xla_form_runs(
@@ -471,6 +603,8 @@ class TestThroughTheShardedEngine:
             == ("xla", "kernel")
         assert kern.run_manifest()["config"]["attention_form"] == "kernel"
         assert kern.obs.counters.snapshot()["attention_form"] == "kernel"
+        assert kern.obs.counters.snapshot()["attention_form_by_kind"] == (
+            "causal:kernel")
         programs = [str(jax.make_jaxpr(es.engine._generation_step)(
             es.state, es.table.data)) for es in (ref, kern)]
         assert ["pallas_call" in text for text in programs] == [False, True]
@@ -502,9 +636,10 @@ class TestTheDeclaredCost:
 
     @pytest.mark.parametrize("length, block_q, block_k", [
         (64, 16, 16), (64, 32, 16), (64, 16, 32), (96, 32, 32), (32, 32, 32)])
-    @pytest.mark.parametrize("shared", [0, 4])
+    @pytest.mark.parametrize("shared, paired", [(0, False), (4, False),
+                                                (0, True)])
     def test_against_a_count_tile_by_tile(self, length, block_q, block_k,
-                                          shared):
+                                          shared, paired):
         heads, kv_heads, hd, vd, itemsize = 4, 2, 8, 16, 2
         flops = exps = 0
         for _ in range(heads):
@@ -515,11 +650,13 @@ class TestTheDeclaredCost:
                     if j * block_k <= (i + 1) * block_q - 1:
                         flops += 2 * block_q * block_k * (hd + shared + vd)
                         exps += block_q * block_k + block_q
-        elements = length * (heads * (hd + shared + vd)
-                             + kv_heads * (hd + vd) + shared)
+        # q, its shared part and the context a head; k a key head; v a key
+        # head, or ONE block a pair of them; the one shared key
+        elements = length * (heads * (hd + shared + vd) + kv_heads * hd
+                             + kv_heads // (2 if paired else 1) * vd + shared)
         cost = pallas_attention.attention_cost(
             length, heads, kv_heads, hd, vd, shared, block_q, block_k,
-            itemsize)
+            itemsize, paired)
         assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
             flops, exps, elements * itemsize)
 
@@ -530,22 +667,26 @@ class TestTheDeclaredCost:
         exact = 16 * 2 * (128 + 128) * 4096 * 4097 // 2
         assert cost.flops / exact == pytest.approx(1.25, abs=1e-3)
 
-    @pytest.mark.parametrize("shared", [False, True])
-    def test_the_call_declares_it_and_vmap_scales_it(self, shared):
+    @pytest.mark.parametrize("shared, paired", [
+        (False, False), (True, False), (False, True)])
+    def test_the_call_declares_it_and_vmap_scales_it(self, shared, paired):
         from pallas_costs import declared_costs
 
         t, heads, kv_heads, dr = 32, 4, 4, 4
         q, k, v, qs, ks = _parts(t, heads, HD, dr, 16, jnp.float32, seed=1)
+        if paired:
+            v = v[:, :kv_heads // 2 * 16]   # ONE value block a key pair
         args = (q, k, v) + ((qs, ks) if shared else ())
 
         def call(*a):
             return causal_attention(*a, num_heads=heads,
                                     num_kv_heads=kv_heads, head_dim=HD,
                                     value_dim=16, scale=0.25, interpret=True,
-                                    block_q=16, block_k=8)
+                                    block_q=16, block_k=8, paired=paired)
 
         want = pallas_attention.attention_cost(
-            t, heads, kv_heads, HD, 16, dr if shared else 0, 16, 8, 4)
+            t, heads, kv_heads, HD, 16, dr if shared else 0, 16, 8, 4,
+            paired)
         one, = declared_costs(call, *args)
         assert one == want
         members = 3

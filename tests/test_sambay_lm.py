@@ -21,7 +21,7 @@ from estorch_tpu.ops.lowrank import (lowrank_tree_noise,
                                      lowrank_tree_weighted_sum,
                                      make_lowrank_tree_spec)
 from estorch_tpu.ops.pallas_attention import (attention_form,
-                                              attention_form_why,
+                                              attention_form_why, call_form,
                                               kernel_scope)
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
                                        unmatched_leaves)
@@ -132,11 +132,10 @@ def test_what_the_boundary_layers_hand_on_matches_the_reference(ref, tiny,
     np.testing.assert_allclose(carried["memory"], want_m, atol=TOL, rtol=0)
     k, v = carried["kv"]
     np.testing.assert_allclose(k, want_k, atol=TOL, rtol=0)
-    # each of the two value pairs [T, 2 · 4] is read by both of its maps
-    assert v.shape == (21, 2, 2, 8)
-    np.testing.assert_array_equal(v[:, :, 0], v[:, :, 1])
-    np.testing.assert_allclose(v[:, :, 0].reshape(21, -1), want_v, atol=TOL,
-                               rtol=0)
+    # ONE block [T, 2 · 4] a value pair, as published: both of its maps
+    # read it where it lies (no copy a map is carried)
+    assert v.shape == (21, 2 * 8)
+    np.testing.assert_allclose(v, want_v, atol=TOL, rtol=0)
 
 
 @pytest.mark.parametrize("unroll", [1, 4, 21, 64])
@@ -180,7 +179,10 @@ def test_the_six_layer_cut_keeps_the_published_indices():
     assert published["published_layer_types"] == list(layer_kinds(32))
     assert published["layers_held"] == list(lm.layer_indices)
     assert (lm.kv_shared_by, lm.memory_shared_by) == (1, 1)
-    assert lm.attention_window == 512
+    assert lm.attention_windows == {"window": 512, "full_kv": None,
+                                    "cross": None}
+    assert SambaYLM(**{**kwargs, "layer_indices": (16, 17)}
+                    ).attention_windows == {"full_kv": None}
     for index, want in [(1, 0.3555), (17, 0.7963), (19, 0.7980)]:
         assert lambda_init(index) == pytest.approx(
             0.8 - 0.6 * math.exp(-0.3 * index))
@@ -316,30 +318,66 @@ def test_no_window_is_the_program_it_was():
     assert [eq.primitive.name for eq in huge.jaxpr.eqns].count("and") == 4
 
 
-def test_the_kernel_has_no_band():
+def test_a_call_with_a_window_is_the_xla_form_inside_a_kernel_scope():
+    """The kernel has no band: inside an engine's scope the call with a
+    ``window`` traces to the XLA form's equations (and so gives its
+    result), the call without one to the kernel."""
     q, k, v = _core_case(16)
-    with kernel_scope(True), pytest.raises(NotImplementedError,
-                                           match="no band"):
-        lm_blocks.attention_core(q, k, v, num_heads=4, num_kv_heads=2,
-                                 scale=0.5, block=8, window=4)
+
+    def traced(window, scoped):
+        def core(q, k, v):     # a new closure a trace: jit caches by function
+            return lm_blocks.attention_core(
+                q, k, v, num_heads=4, num_kv_heads=2, scale=0.5, block=8,
+                window=window)
+
+        if not scoped:
+            return jax.make_jaxpr(core)(q, k, v), core(q, k, v)
+        with kernel_scope(True):
+            return jax.make_jaxpr(core)(q, k, v), core(q, k, v)
+
+    (banded, got), (outside, want) = traced(4, True), traced(4, False)
+    assert "pallas_call" not in str(banded)
+    assert str(banded) == str(outside)
+    np.testing.assert_array_equal(got, want)
+    full, _ = traced(None, True)
+    assert "pallas_call" in str(full)
 
 
-@pytest.mark.parametrize("widths, length, window, form, why", [
-    ((64, 0, 128), 8192, 512, "xla", "64 wide"),
-    ((64, 0, 128), 8192, None, "xla", "64 wide"),
-    ((128, 0, 128), 8192, 512, "xla", "window of 512"),
-    ((128, 0, 128), 8192, None, "kernel", "one TPU device"),
-    (128, 200, None, "xla", "divides 200"),
+@pytest.mark.parametrize("widths, length, window, kv_heads, form, why", [
+    # the published model: a pair is one lane block; the band is the call's
+    ((64, 0, 128), 8192, 512, 20, "kernel",
+     "one TPU device, two score heads a column block, whole row blocks; "
+     "layers with a window of 512 in the XLA form"),
+    ((64, 0, 128), 8192, None, 20, "kernel",
+     "one TPU device, two score heads a column block, whole row blocks"),
+    ((64, 0, 128), 8192, 512, 5, "xla", "over 5 key heads"),
+    ((64, 0, 128), 8192, 512, None, "xla", "64 wide and its values 128"),
+    # grouped heads of 64 with 64-wide values: half a block of context
+    ((64, 0, 64), 8192, None, 20, "xla", "64 wide and its values 64"),
+    # values of two blocks are no pair's ONE block
+    ((64, 0, 256), 8192, None, 20, "xla", "64 wide and its values 256"),
+    ((128, 0, 128), 8192, 512, 8, "kernel",
+     "whole column blocks, whole row blocks; layers with a window of 512"),
+    ((128, 0, 128), 8192, None, 8, "kernel",
+     "one TPU device, whole column blocks, whole row blocks"),
+    ((64, 0, 128), 8000, 512, 20, "xla", "divides 8000"),
+    (128, 200, None, None, "xla", "divides 200"),
 ])
-def test_the_attention_rule_turns_this_model_away_and_says_why(
-        widths, length, window, form, why):
-    assert attention_form("tpu", 1, widths, length, window) == form
-    got, reason = attention_form_why("tpu", 1, widths, length, window)
+def test_the_attention_rule_takes_pairs_and_leaves_the_band_to_the_call(
+        widths, length, window, kv_heads, form, why):
+    assert attention_form("tpu", 1, widths, length, window,
+                          kv_heads) == form
+    got, reason = attention_form_why("tpu", 1, widths, length, window,
+                                     kv_heads)
     assert got == form and why in reason
-    assert attention_form_why("cpu", 1, widths, length, window)[1].startswith(
-        "the devices are 'cpu'")
+    assert attention_form_why("cpu", 1, widths, length, window,
+                              kv_heads)[1].startswith("the devices are 'cpu'")
     assert "4 devices" in attention_form_why("tpu", 4, widths, length,
-                                             window)[1]
+                                             window, kv_heads)[1]
+    # which form a CALL takes in a program of that form
+    assert call_form(form, window) == (
+        "kernel" if form == "kernel" and window is None else "xla")
+    assert call_form(form, None) == form
 
 
 # ------------------------------------------- (d) differential attention
@@ -359,11 +397,13 @@ def test_the_differential_combine_against_its_formula():
 
 def test_both_maps_go_through_one_call_of_the_core(tiny):
     """Each attention layer is ONE call of the shared core (8 score heads
-    over 4 key heads, values 8 wide), not two attentions."""
+    over 4 key heads in pairs, ONE block of 8 values a key pair), not two
+    attentions."""
     calls = []
     honest = lm_blocks.attention_core
 
     def counting(q, k, v, **kw):
+        assert kw["paired"]
         calls.append((q.size // 21, k.size // 21, v.size // 21,
                       kw["num_heads"], kw["num_kv_heads"], kw["window"]))
         return honest(q, k, v, **kw)
@@ -373,8 +413,70 @@ def test_both_maps_go_through_one_call_of_the_core(tiny):
         tiny["lm"].hidden(tiny["params"], None, 0.0, _tokens(21))
     finally:
         lm_blocks.attention_core = honest
-    assert calls == [(32, 16, 32, 8, 4, 5), (32, 16, 32, 8, 4, None),
-                     (32, 16, 32, 8, 4, None)]
+    assert calls == [(32, 16, 16, 8, 4, 5), (32, 16, 16, 8, 4, None),
+                     (32, 16, 16, 8, 4, None)]
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, TOL),
+                                        (jnp.bfloat16, 0.1)])
+@pytest.mark.parametrize("length", [21, 16])
+def test_forced_through_the_interpreted_kernel_it_agrees(tiny, dtype, tol,
+                                                         length):
+    """The whole model inside a ``kernel_scope``, the windowed layer
+    present: the two full-causal differential layers run the Pallas kernel
+    on their pairs where they lie (interpreted here), the call with the
+    window takes the XLA form and raises nothing; scores and logits agree
+    with the XLA form's to the order of float32 sums, or to bfloat16's
+    rounding of the probabilities."""
+    tokens = _tokens(length, 4)
+    params = jax.tree_util.tree_map(
+        lambda x: x.astype(dtype) if x.ndim == 2 else x, tiny["params"])
+    factors = tiny["spec"].unpack(tiny["noise"])
+    lm = tiny["lm"]
+    want = lm.perturbed_apply(params, factors, 0.05, tokens)
+    with kernel_scope(interpret=True):
+        program = str(jax.make_jaxpr(
+            lambda p, f: lm.perturbed_apply(p, f, 0.05, tokens))(
+                params, factors))
+        got = lm.perturbed_apply(params, factors, 0.05, tokens)
+    # full_kv and cross; the window's call is not among them
+    assert program.count("jaxpr=causal_attention") == 2
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.float32 and bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, atol=tol, rtol=0)
+    assert float(jnp.abs(got[0] - want[0]).max()) > 0.0  # another program
+
+
+def test_the_heads_kernel_is_reached_through_the_same_scope(ref):
+    """A hidden width of one 128-lane block over 512 positions fits the
+    head's rule (ops/pallas_head.py): inside a ``kernel_scope`` the tied
+    head scores its next tokens in the head's kernel (interpreted here)
+    beside the attention's two, and scores and last logits agree to the
+    order of float32 sums."""
+    lm = SambaYLM(**{**sambay_tiny.TINY, "hidden_size": 128,
+                     "intermediate_size": 64, "attention_block": 128,
+                     "head_block": 96, "sliding_window": 100,
+                     "scan_chunk": 16})
+    tokens = _tokens(512, 4)
+    params = jax.tree_util.tree_map(
+        lambda x: 3.0 * x if x.ndim == 2 and x.shape != (256, 4) else x,
+        lm.init(jax.random.PRNGKey(2))["params"])
+    spec = make_lowrank_tree_spec(lm.param_shapes(), 1,
+                                  dense=lm.dense_noise_leaves)
+    factors = spec.unpack(
+        jax.random.normal(jax.random.PRNGKey(5), (spec.noise_dim,)))
+    want = lm.perturbed_apply(params, factors, 0.05, tokens)
+    with kernel_scope(interpret=True):
+        program = str(jax.make_jaxpr(
+            lambda p, f: lm.perturbed_apply(p, f, 0.05, tokens))(
+                params, factors))
+        got = lm.perturbed_apply(params, factors, 0.05, tokens)
+    assert "next_token_scores" in program
+    assert program.count("jaxpr=causal_attention") == 2
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, w, atol=TOL, rtol=0)
+    assert float(jnp.abs(got[0] - want[0]).max()) > 0.0  # another program
 
 
 # ------------------------------------- (e) members under the engine's vmaps
@@ -395,7 +497,7 @@ def test_what_is_handed_on_is_per_member_under_vmap(tiny):
     h, m, k, v = jax.vmap(lambda row: jax.vmap(
         lambda c: member(row, c))(signs))(rows)
     assert m.shape == (3, 2, 21, 64) and k.shape == (3, 2, 21, 16)
-    assert v.shape == (3, 2, 21, 2, 2, 8)
+    assert v.shape == (3, 2, 21, 2 * 8)
     for i in range(3):
         for j in range(2):
             own = member(rows[i], signs[j])
@@ -575,6 +677,10 @@ class TestThroughTheShardedEngine:
                 gauges.get("kv_shared_by"),
                 gauges.get("memory_shared_by")) == (5, 4, 1, 1)
         assert gauges.get("attention_form") == "xla"
+        # a CPU mesh: every kind of attention layer in the XLA form
+        by_kind = "window:xla,full_kv:xla,cross:xla"
+        assert es.engine.attention_form_by_kind == by_kind
+        assert gauges.get("attention_form_by_kind") == by_kind
         assert gauges.get("head_form") == "xla"
         assert gauges.get("experts_held", None) is None
         cfg = es.run_manifest()["config"]
@@ -582,6 +688,36 @@ class TestThroughTheShardedEngine:
         assert (cfg["window"], cfg["kv_shared_by"],
                 cfg["memory_shared_by"]) == (5, 1, 1)
         assert cfg["attention_form_why"].startswith("the devices are 'cpu'")
+        assert cfg["attention_form_by_kind"] == by_kind
+
+    @pytest.mark.parametrize("dtype, tol", [("float32", 1e-4),
+                                            ("bfloat16", 2e-2)])
+    def test_forced_kernel_runs_the_generation_the_xla_form_runs(
+            self, devices8, kernel_attention, dtype, tol):
+        """The generation program on one device, the engine's scope open
+        around its trace: the two full-causal kinds of attention layer take
+        the kernel (two ``pallas_call``s a forward), the windowed kind the
+        XLA form, the gauge says which, and the members' fitness is the XLA
+        form's to the order of float32 sums (bfloat16: of its rounding of
+        the probabilities; fitness is a mean log p of about -4.2)."""
+        ref_es = _sambay_es(devices8[:1], 1, compute_dtype=dtype)
+        with kernel_attention():
+            kern = _sambay_es(devices8[:1], 1, compute_dtype=dtype)
+        assert (ref_es.engine.attention_form,
+                kern.engine.attention_form) == ("xla", "kernel")
+        by_kind = "window:xla,full_kv:kernel,cross:kernel"
+        assert kern.engine.attention_form_by_kind == by_kind
+        assert kern.obs.counters.get("attention_form_by_kind") == by_kind
+        assert kern.run_manifest()["config"][
+            "attention_form_by_kind"] == by_kind
+        programs = [str(jax.make_jaxpr(es.engine._generation_step)(
+            es.state, es.table.data)) for es in (ref_es, kern)]
+        assert [text.count("jaxpr=causal_attention")
+                for text in programs] == [0, 2]
+        ref_es.state, want = ref_es.engine.generation_step(ref_es.state)
+        kern.state, got = kern.engine.generation_step(kern.state)
+        np.testing.assert_allclose(got["fitness"], want["fitness"], atol=tol)
+        assert np.isfinite(np.asarray(got["fitness"])).all()
 
     def test_another_model_states_none_of_it(self, devices8):
         import loop_tiny
@@ -593,7 +729,8 @@ class TestThroughTheShardedEngine:
                         agent_kwargs={"env": TokenScoreEnv(**loop_tiny.ENV)})
         assert es.obs.counters.get("layer_kinds", None) is None
         assert "kv_shared_by" not in es.run_manifest()["config"]
-        assert es.engine._attention_window is None
+        assert es.engine._attention_windows == {"causal": None}
+        assert es.engine.attention_form_by_kind == "causal:xla"
 
     def test_the_reference_scores_the_engines_members(self, ref, devices8):
         """Generation 0 of the engine against the reference through the

@@ -467,6 +467,42 @@ def test_attention_kernel_compiles_for_the_v5e_at_the_looped_cells_shapes(
     assert shared or " copy(" not in text.split("ENTRY")[1]
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_attention_kernel_compiles_for_the_v5e_at_a_differential_pairs_shapes(
+        dtype, v5e_chip):
+    """Mosaic accepts the attention kernel at ``phi4-flash-es-8k-1chip``'s
+    shapes: 40 score heads of 64 over 20 key heads and ten value blocks of
+    128, two heads a column block, one member of 8,192 positions in blocks
+    of 1,024 (the cell evaluates a pair's signs in turn).  q and v reach the
+    call as they were handed in (a bitcast, a prefetch: no transposing copy,
+    no copy of the values a map); the key is laid out ``[k₁, 0 | 0, k₂]``
+    before it."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import causal_attention
+
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (1, 8192, width), dtype, sharding=SingleDeviceSharding(v5e_chip))
+
+    text = jax.jit(jax.vmap(lambda q, k, v: causal_attention(
+        q, k, v, num_heads=40, num_kv_heads=20, head_dim=64, value_dim=128,
+        scale=0.125, interpret=False, paired=True))).lower(
+            operand(2560), operand(1280), operand(1280)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    entry = text.split("ENTRY")[1]
+    call, = [line for line in entry.splitlines()
+             if "tpu_custom_call" in line]
+    q_operand, _, v_operand = re.search(
+        r"custom-call\(([^)]*)\)", call).group(1).split(", ")
+    by_name = {line.split(" = ")[0].strip(): line
+               for line in entry.splitlines() if " = " in line}
+    assert " bitcast(%q" in by_name[q_operand]
+    assert re.search(r" (bitcast|copy-done)\(", by_name[v_operand])
+    assert "select" in entry     # the padded key
+
+
 @pytest.mark.parametrize("hidden, vocab, tied, dtype", [
     (2048, 49152, False, jnp.bfloat16), (2048, 49152, False, jnp.float32),
     (2048, 16160, False, jnp.bfloat16), (2048, 100352, True, jnp.bfloat16),
@@ -645,6 +681,75 @@ def test_kernel_form_books_its_kernel_to_attn(latent, v5e_chip):
     assert kernels and all(
         stack[-2:] == [POLICY, ATTN] and "causal_attention" in name
         for name, stack in kernels), kernels
+
+
+def test_kernel_form_books_a_differential_pairs_kernel_by_its_kind(v5e_chip):
+    """A small SambaY decoder with differential heads of 64 on a
+    one-device TPU mesh: the rule takes its pairs (two score heads a column
+    block over ONE value block of 128), the engine says which kind of
+    attention layer took which form, and the compiled generation program
+    holds the Mosaic call under es.attn inside es.policy in the parts of
+    the two full-causal kinds, ``of.full`` and ``of.cross``, and none in
+    ``of.window``, whose calls stay in the XLA form: the device trace books
+    each to ``sambay.full_attn_share`` / ``sambay.window_attn_share`` as
+    before."""
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import SambaYLM
+    from estorch_tpu.parallel.mesh import hyperscale_mesh
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    es = _es(
+        policy=SambaYLM, population_size=4, sigma=0.02,
+        policy_kwargs=dict(
+            vocab_size=256, hidden_size=256, intermediate_size=256,
+            num_attention_heads=4, num_key_value_heads=2, published_layers=8,
+            layer_indices=(0, 1, 4, 5, 6, 7), sliding_window=64,
+            mamba_d_state=4, mamba_dt_rank=8, scan_chunk=16,
+            attention_block=128, head_block=128),
+        agent_kwargs={"env": TokenScoreEnv(
+            vocab_size=256, seq_len=256, corpus_sequences=4)},
+        shard_params=True, low_rank=1, noise_mode="table",
+        compute_dtype="bfloat16", table_size=1 << 18,
+        device=jax.devices()[:1])
+    assert es.module.attention_widths == (64, 0, 128)
+    assert es.engine.attention_form_by_kind == (
+        "window:xla,full_kv:xla,cross:xla")        # a CPU mesh
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    engine = ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=1, devices=[v5e_chip]),
+        partition_rules=es._partition_rules, noise_mode="table",
+        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        attention_widths=es.module.attention_widths,
+        head_width=es.module.head_width,
+        float32_leaves=es.module.float32_leaves,
+        attention_windows=es.module.attention_windows,
+        attention_kv_heads=es.module.num_key_value_heads,
+        dense_noise_leaves=es.module.dense_noise_leaves)
+    assert engine.attention_form == "kernel"
+    assert engine.attention_form_why == (
+        "one TPU device, two score heads a column block, whole row blocks; "
+        "layers with a window of 64 in the XLA form")
+    assert engine.attention_form_by_kind == (
+        "window:xla,full_kv:kernel,cross:kernel")
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        es.state, engine.state_shardings)
+    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
+                                 sharding=engine._repl)
+    text = engine._generation_step.lower(state, table).compile().as_text()
+    kernels = [name for line in text.splitlines()
+               if "tpu_custom_call" in line and "causal_attention" in line
+               for name in re.findall(r'op_name="([^"]*)"', line)]
+    assert sorted(PART.findall(name)[0] for name in kernels) == [
+        "cross", "full"], kernels
+    for name in kernels:
+        assert SCOPE.findall(name)[-2:] == [ATTN, ATTN], name
+        assert SCOPE.findall(name)[0] == POLICY, name
+    # the windowed layer's scores are XLA's: float32, under its own part
+    assert any(PART_PREFIX + "window" in line and "f32[" in line
+               and "exponential" in line for line in text.splitlines())
 
 
 @pytest.mark.parametrize("use", ["context", "decorator"])
