@@ -352,6 +352,11 @@ class ES:
                                            "num_key_value_heads", None),
                 dense_noise_leaves=getattr(
                     self.module, "dense_noise_leaves", ()),
+                # a model whose attention reads a learned selection of keys
+                # (models/indexed_moe_lm.py): what the selection holds of a
+                # member, for the chunk rule
+                selection_bytes=getattr(self.module, "selection_bytes",
+                                        None),
             )
             # the whole flat vector leaves the device before the sharded
             # state is placed from it, a leaf at a time: a tree this
@@ -427,6 +432,14 @@ class ES:
                 experts_total=int(self.module.experts_total),
                 experts_per_token=int(self.module.num_experts_per_tok),
                 mtp_depth=int(self.module.num_nextn_predict_layers))
+        if hasattr(self.module, "selection_bytes"):
+            # attention over a learned selection of keys
+            # (models/indexed_moe_lm.py)
+            facts.update(
+                sparse_topk=int(self.module.topk),
+                index_heads=int(self.module.indexer_num_heads),
+                index_head_dim=int(self.module.indexer_head_dim),
+                position_streams=len(self.module.mrope_section))
         if hasattr(self.module, "kv_shared_by"):
             # layers of several kinds, two of which hand state to the layers
             # above them (models/sambay_lm.py)
@@ -848,6 +861,11 @@ class ES:
                 record["routed_pairs"] = int(load.sum())
                 record["expert_load_max_over_mean"] = float(
                     load.max() / max(load.mean(), 1e-12))
+            if "selected_pairs" in metrics:
+                # (query, key) pairs the members' indexers selected over
+                # their layers (models/indexed_moe_lm.py)
+                record["selected_pairs"] = int(np.asarray(
+                    metrics["selected_pairs"], np.int64).sum())
             self._emit_record(record, log_fn, verbose)
             done += 1
             # the sharded program's best member is param-sized: let go of

@@ -1,4 +1,5 @@
 from .hybrid_lm import HybridLM
+from .indexed_moe_lm import IndexedMoELM
 from .looped_lm import LoopedLM
 from .moe_lm import MoELM
 from .sambay_lm import SambaYLM
@@ -21,6 +22,7 @@ def __getattr__(name):
 
 __all__ = [
     "HybridLM",
+    "IndexedMoELM",
     "LoopedLM",
     "MLPPolicy",
     "MoELM",

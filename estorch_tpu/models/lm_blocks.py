@@ -1,8 +1,10 @@
-"""The pieces the four sequence models are built from
+"""The pieces the FIVE sequence models are built from
 (models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py,
-models/sambay_lm.py): ONE RMSNorm, ONE LayerNorm, ONE gated SiLU FFN, ONE
-causal attention core (full or banded), ONE differential combine, ONE
-depthwise causal conv, ONE expert layer and ONE
+models/sambay_lm.py, models/indexed_moe_lm.py): ONE RMSNorm, ONE LayerNorm,
+ONE gated SiLU FFN, ONE causal attention core (full, banded or over a
+learned SELECTION of keys), ONE indexer that makes such a selection, ONE
+differential combine, ONE depthwise causal conv, ONE expert layer (routed
+by sigmoid scores with a selection bias, or by a softmax) and ONE
 next-token scorer, each on the perturbed-dense primitive
 (models/perturbed.py), so that an optimisation of one is measured on every
 model that calls it.  The attention core and the scorer have TWO forms each
@@ -25,6 +27,12 @@ sees the keys ``(t - window, t]`` and no others: the XLA form skips the key
 blocks wholly outside that band as it skips those above the diagonal; the
 kernel has no band, so a CALL with a window is the XLA form wherever it is
 traced, and the model's other calls follow the rule below.
+With ``selected`` (``[T, T]`` int8, :func:`select_keys`) a query sees the
+keys its row of the selection marks and no others: a mask made from the
+DATA, different for every member, layer and sequence, which BOTH forms
+read: the XLA form beside its causal mask, the kernel as one more operand
+of a tile.  A selection marks no future key, and ``topk >= T`` marks every
+visible one: full causal attention, bit for bit.
 Differential attention (arXiv 2410.05258) is ONE call of the core with both
 softmax maps as heads of it (``paired``: the heads handed in as the PAIRS
 the projections write, two score heads of half a lane block side by side
@@ -45,7 +53,8 @@ Which one a program takes is not an option of a model or of ``ES``: the
 ENGINE resolves it once at build from what it observes
 (``ShardedESEngine.attention_form``, by the one rule
 ``ops.pallas_attention.attention_form``: TPU devices; ONE device on the
-mesh so the operands are whole on it; a head's values whole numbers of
+mesh so the operands are whole on it (a selection is one of them); a
+head's values whole numbers of
 128-lane column blocks, and its own query/key part too, or half of one
 with values of ONE block and an even number of key heads (a pair); a
 shared part of 64 or a multiple of 128; the sequence a whole number of the
@@ -84,8 +93,18 @@ and the noise is factored or none.  The engine says which at build
 ``head_width`` the model states).  The last position's logits (the
 behaviour) are the one-row XLA matmul in both forms.
 
+The indexer (:func:`select_keys`: DeepSeek-V3.2's sparse attention, whose
+``sa_config`` keys a configuration carries) scores every visible key of a
+query with a few narrow heads against ONE key head, ``I[t, s] = Σ_j w[t, j]
+· relu(qI[t, j] · kI[s])`` (:func:`index_scores`, ``es.index``), and keeps
+the ``min(t + 1, topk)`` largest, ties to the lower index
+(:func:`choose_keys`, ``es.select``): a block of queries at a time against
+the keys up to the block's end, the k-th largest score of a row found by
+bisection on the float's bits (32 counting passes, no sort), exactly.
+
 The expert layer (:func:`routed_experts`) is told which experts it holds:
-it routes over all of them (:func:`route`), computes what its own experts
+it routes over all of them (:func:`route`: sigmoid scores and a selection
+bias, or a softmax over all experts), computes what its own experts
 give for the (token, k) pairs routed to them and leaves the rest out.  One
 implementation: the pairs of ALL members under the ``vmap``s around it are
 sorted by expert together, so that the centre's stacked ``[E, m, n]``
@@ -112,8 +131,10 @@ import jax.numpy as jnp
 
 import functools
 
-from ..obs.trace import (ATTN, DENSE, DIFF, DISPATCH, EXPERT, HEAD, PERTURB,
-                         ROPE, ROUTE, part, stage)
+import numpy as np
+
+from ..obs.trace import (ATTN, DENSE, DIFF, DISPATCH, EXPERT, HEAD, INDEX,
+                         PERTURB, ROPE, ROUTE, SELECT, part, stage)
 from ..ops import pallas_attention, pallas_head
 from .perturbed import (F32, is_factored, perturbed_dense,
                         perturbed_grouped_dense, perturbed_leaf)
@@ -125,6 +146,16 @@ EXPERT_CAPACITY_MARGIN = 1.25
 
 def layer_name(i: int) -> str:
     return f"layer_{i:02d}"
+
+
+def refuse_unwritten(model, only: dict) -> None:
+    """A model's ``__post_init__``: ``only`` maps a published key that has
+    ONE form written here to that form's value; any other value raises,
+    naming the key and the form."""
+    for name, value in only.items():
+        if getattr(model, name) != value:
+            raise ValueError(f"{name} = {getattr(model, name)!r} is not "
+                             f"written: the one form is {value!r}")
 
 
 def draw_tree(shapes, key, value_of):
@@ -162,12 +193,15 @@ def causal_conv(x, taps, bias):
     return y
 
 
-def dense(p, noise, c, name, x, bias: str | None = None):
+def dense(p, noise, c, name, x, bias: str | None = None,
+          under: str = DENSE):
     """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``, the
-    part ``of.<name>``: every projection of the four models says here
+    part ``of.<name>``: every projection of the five models says here
     which leaf it multiplies (obs/trace.py).  ``bias``: the key of a bias
-    leaf of ``p`` (perturbed like any small leaf), added to the product."""
-    with stage(DENSE), part(name):
+    leaf of ``p`` (perturbed like any small leaf), added to the product.
+    ``under``: the stage of a projection that belongs to another one (the
+    indexer's, ``es.index``)."""
+    with stage(under), part(name):
         y = perturbed_dense(
             x, p[name], None if noise is None else noise[name], c)
         if bias is None:
@@ -195,13 +229,30 @@ def gated_mlp(dense, p, noise, c, u):
     return dense(p, noise, c, "down", act)
 
 
-def rotary_tables(length: int, head_dim: int, theta: float):
+def rotary_tables(length: int, head_dim: int, theta: float,
+                  positions=None, sections=None):
     """``(cos, sin) [T, head_dim/2]`` float32 of positions ``0 … T-1``:
-    ``inv_freq_i = theta^(-2i/head_dim)``."""
+    ``inv_freq_i = theta^(-2i/head_dim)``.  ``positions [streams, T]`` with
+    ``sections`` (M-RoPE, arXiv 2409.12191): frequency pair ``i`` turns by
+    the position stream its section names, the first ``sections[0]`` pairs
+    by stream 0, the next ``sections[1]`` by stream 1, …; the sections add
+    up to ``head_dim/2``.  Streams that all hold ``0 … T-1`` give the
+    tables of no ``positions``, bit for bit."""
     with stage(ROPE):
         inv_freq = 1.0 / (theta ** (
             jnp.arange(0, head_dim, 2, dtype=F32) / head_dim))
-        angle = jnp.arange(length, dtype=F32)[:, None] * inv_freq[None, :]
+        if positions is None:
+            at = jnp.arange(length, dtype=F32)[:, None]
+        else:
+            if (sum(sections) != head_dim // 2
+                    or positions.shape != (len(sections), length)):
+                raise ValueError(
+                    f"sections {tuple(sections)} over positions "
+                    f"{positions.shape} are not {head_dim // 2} frequency "
+                    f"pairs over [streams, {length}]")
+            stream = np.repeat(np.arange(len(sections)), sections)
+            at = positions.astype(F32)[stream].T        # [T, head_dim/2]
+        angle = at * inv_freq[None, :]
         return jnp.cos(angle), jnp.sin(angle)
 
 
@@ -246,7 +297,8 @@ def causal_attention(dense, p, noise, c, u, *, num_heads: int,
 
 def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
                    scale: float, block: int, q_shared=None, k_shared=None,
-                   window: int | None = None, paired: bool = False):
+                   window: int | None = None, paired: bool = False,
+                   selected=None):
     """``context [T, heads · value width]`` of causal attention with
     grouped heads: ``q [T, heads(, ·) qk width]``, ``k [T, kv heads(, ·)
     qk width]`` and ``v [T, kv heads(, ·) value width]`` in the compute
@@ -265,7 +317,10 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     is then scored against the key blocks that hold ``(start of block i -
     window, end of block i)`` and no others, so a window of one block costs
     two key blocks a query block however long the sequence, and masked
-    scores exist in the first and the last of them.
+    scores exist in the first and the last of them.  ``selected [T, T]``
+    int8 (:func:`select_keys`): query ``t`` sees the keys ``s`` with
+    ``selected[t, s] != 0`` alone, beside the causal mask (a selection
+    marks no future key; heads of one width, values apart, no window).
 
     ``paired``: the heads come in PAIRS, as differential attention
     publishes them: ``q [T, heads/2, 2, qk width]`` a diff-head's two maps
@@ -291,6 +346,12 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     dtype, t = q.dtype, q.shape[0]
     nq, nkv = num_heads, num_kv_heads
     hd = q.size // (t * nq)
+    if selected is not None and (
+            v is None or q_shared is not None or window is not None
+            or paired or selected.shape != (t, t)):
+        raise ValueError(
+            "a selection of keys is [T, T] over heads of one width with "
+            "their values apart, no shared part, no window and no pairs")
     # the kernel has no band: a call with a window is the XLA form
     interpret = (pallas_attention.scoped_interpret() if window is None
                  else None)
@@ -317,7 +378,7 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
                 None if q_shared is None else q_shared.reshape(t, -1),
                 k_shared, num_heads=nq, num_kv_heads=nkv, head_dim=hd,
                 value_dim=vd, scale=scale, interpret=interpret,
-                paired=paired)
+                paired=paired, selected=selected)
     if q_shared is not None:
         with stage(ROPE):
             dr = k_shared.shape[-1]
@@ -352,12 +413,117 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
             mask = keys <= queries
             if window is not None:
                 mask = mask & (keys > queries - window)
+            if selected is not None:
+                mask = mask & (selected[start:stop, first:stop] != 0)
             s = jnp.where(mask, s, -jnp.inf)
             prob = jax.nn.softmax(s, axis=-1).astype(dtype)
             ctx.append(jnp.einsum(
                 "kgqs,skd->qkgd", prob, vh[first:stop],
                 preferred_element_type=F32).astype(dtype))
     return jnp.concatenate(ctx).reshape(t, nq * vd)
+
+
+# ---------------------------------------------------- the learned selection
+
+def index_scores(q_i, k_i, w, first_query: int):
+    """``I [rows, keys]`` float32 of a block of queries against the keys up
+    to the block's end: ``I[t, s] = Σ_j w[t, j] · relu(q_i[t, j] ·
+    k_i[s])`` over the indexer's heads ``j``, ``-inf`` where ``s > t``.
+    ``q_i [rows, heads, width]`` and the ONE key head ``k_i [keys, width]``
+    in the compute dtype (float32 accumulation), ``w [rows, heads]``
+    float32; the block's first query is position ``first_query``, key ``s``
+    position ``s``.  A positive scale of a row of ``w`` changes no order."""
+    with stage(INDEX):
+        dots = jnp.einsum("qhd,sd->qhs", q_i, k_i,
+                          preferred_element_type=F32)
+        scores = jnp.sum(jax.nn.relu(dots) * w[:, :, None], axis=1)
+        queries = first_query + jnp.arange(q_i.shape[0])[:, None]
+        return jnp.where(jnp.arange(k_i.shape[0])[None, :] <= queries,
+                         scores, -jnp.inf)
+
+
+def _ordered_bits(x):
+    """int32 whose signed order is the float32 order of ``x`` (``-0.0``
+    read as ``0.0``, as a comparison of floats reads it)."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(x == 0.0, 0.0, x).astype(F32), jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _prefix_count(flags):
+    """Inclusive count of the set ``flags [rows, n]`` along a row, int32:
+    lanes of 128 against a triangle on the MXU (exact: small whole numbers
+    in float32), the blocks' totals by a short cumulative sum."""
+    rows, n = flags.shape
+    lane = 128 if n % 128 == 0 else n
+    f = flags.reshape(rows, n // lane, lane)
+    upto = jnp.arange(lane)[:, None] <= jnp.arange(lane)[None, :]
+    inside = jnp.einsum("rbl,lm->rbm", f.astype(jnp.bfloat16),
+                        upto.astype(jnp.bfloat16),
+                        preferred_element_type=F32).astype(jnp.int32)
+    before = jnp.cumsum(inside[..., -1], axis=1) - inside[..., -1]
+    return (inside + before[..., None]).reshape(rows, n)
+
+
+def choose_keys(scores, topk: int, first_query: int):
+    """``(selected [rows, keys] int8, how many int32)``: per row of
+    ``scores`` (:func:`index_scores`: ``-inf`` at the future keys) its
+    ``min(t + 1, topk)`` largest, ``t = first_query + row``; among equal
+    scores the lower index.  Exact and without a sort: the k-th largest
+    value of a row is found bit by bit (32 passes that count the scores at
+    or above a candidate, on an int32 whose order is the float's), the
+    scores above it are taken, and of those equal to it the first few
+    that fill the row."""
+    with stage(SELECT):
+        rows = scores.shape[0]
+        want = jnp.minimum(first_query + jnp.arange(rows) + 1,
+                           topk).astype(jnp.int32)[:, None]
+        key = _ordered_bits(scores)
+        low = jnp.int32(-2**31)
+
+        def one_bit(i, kth):
+            # ``kth`` holds the bits found so far of the k-th largest key,
+            # as an unsigned pattern (sign bit flipped)
+            trial = kth | jnp.left_shift(jnp.int32(1), 31 - i)
+            enough = jnp.sum(key >= (trial ^ low), axis=1, keepdims=True,
+                             dtype=jnp.int32) >= want
+            return jnp.where(enough, trial, kth)
+
+        kth = jax.lax.fori_loop(
+            0, 32, one_bit, jnp.zeros((rows, 1), jnp.int32)) ^ low
+        above, equal = key > kth, key == kth
+        room = want - jnp.sum(above, axis=1, keepdims=True, dtype=jnp.int32)
+        chosen = above | (equal & (_prefix_count(equal) <= room))
+        return chosen.astype(jnp.int8), jnp.sum(chosen, dtype=jnp.int32)
+
+
+def select_keys(q_i, k_i, w, *, topk: int, block: int):
+    """``(selected [T, T] int8, selected pairs int32)``: the keys each
+    query of a sequence attends to, ``selected[t, s] = 1`` for the ``min(t
+    + 1, topk)`` keys ``s <= t`` of largest index score (the same for every
+    attention head), 0 elsewhere; what both forms of
+    :func:`attention_core` read as ``selected``.  ``q_i [T, heads,
+    width]``, ``k_i [T, width]`` (rotated, compute dtype), ``w [T, heads]``
+    float32.  ``block`` queries at a time against the keys up to the
+    block's end (:func:`index_scores`, :func:`choose_keys`), one block
+    after the other: the float32 scores of a block are ``[heads, block,
+    T]`` at the end of the sequence."""
+    t = q_i.shape[0]
+    block = min(block, t)
+    rows, count = [], jnp.int32(0)
+    for start in range(0, t, block):
+        stop = min(start + block, t)
+        q_b = q_i[start:stop]
+        if rows:
+            # one block at a time, as the attention's XLA form
+            q_b, _ = jax.lax.optimization_barrier((q_b, rows[-1]))
+        chosen, n = choose_keys(
+            index_scores(q_b, k_i[:stop], w[start:stop], start), topk, start)
+        with stage(SELECT):
+            rows.append(jnp.pad(chosen, ((0, 0), (0, t - stop))))
+            count = count + n
+    with stage(SELECT):
+        return jnp.concatenate(rows), count
 
 
 def differential_combine(ctx, lam, gamma, *, pairs: int, group: int,
@@ -378,26 +544,51 @@ def differential_combine(ctx, lam, gamma, *, pairs: int, group: int,
 
 # ------------------------------------------------------- the expert layer
 
-def route(p, noise, c, u, *, top_k: int, scaling: float):
+def route(p, noise, c, u, *, top_k: int, scaling: float,
+          scoring: str = "sigmoid"):
     """``(experts [T, top_k] int32, weights [T, top_k] float32)`` of the
     tokens ``u [T, hidden]`` float32 over ALL the experts the router
-    ``p["router"] [hidden, experts]`` scores, held here or not: ``s =
-    sigmoid(u W_r)``, the ``top_k`` of ``s + bias`` (ties to the lower
-    index), weights ``s`` at the chosen (the selection bias enters the
-    choice only), renormalised to sum ``scaling``.  All in float32, the
-    matmul at ``highest`` precision: a rounding of the scores picks another
-    expert."""
+    ``p["router"] [hidden, experts]`` scores, held here or not.
+    ``scoring`` ``"sigmoid"`` (DeepSeek-V3): ``s = sigmoid(u W_r)``, the
+    ``top_k`` of ``s + bias`` (ties to the lower index), weights ``s`` at
+    the chosen (the selection bias ``p["router_bias"]`` enters the choice
+    only).  ``"softmax"`` (Qwen3-MoE): ``s = softmax(u W_r)`` over all
+    experts, the ``top_k`` of ``s``, no bias leaf.  Both renormalised to
+    sum ``scaling``.  All in float32, the matmul at ``highest`` precision:
+    a rounding of the scores picks another expert."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring {scoring!r}: 'sigmoid' or 'softmax'")
     with stage(ROUTE), jax.default_matmul_precision("highest"):
         with part("router"):
-            s = jax.nn.sigmoid(perturbed_dense(
+            s = perturbed_dense(
                 u.astype(F32), p["router"].astype(F32),
-                None if noise is None else noise["router"], c))
-        bias = perturbed_leaf(
-            p["router_bias"],
-            None if noise is None else noise["router_bias"], c)
-        _, experts = jax.lax.top_k(s + bias, top_k)
+                None if noise is None else noise["router"], c)
+            s = (jax.nn.sigmoid(s) if scoring == "sigmoid"
+                 else jax.nn.softmax(s, axis=-1))
+        picked_by = s
+        if scoring == "sigmoid":
+            picked_by = s + perturbed_leaf(
+                p["router_bias"],
+                None if noise is None else noise["router_bias"], c)
+        _, experts = jax.lax.top_k(picked_by, top_k)
         w = jnp.take_along_axis(s, experts, axis=-1)
         return experts, scaling * w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+
+
+def routed_ffn(moe, noise, c, u, dtype, *, top_k: int, scaling: float,
+               first_held: int, total: int, scoring: str = "sigmoid"):
+    """An expert layer's routed part of the float32 tokens ``u``:
+    :func:`route` over all experts, then :func:`routed_experts` on the
+    tokens in the compute ``dtype`` for the experts ``moe["experts"]``
+    holds; ``(the held experts' sum [T, hidden], pairs per held expert)``."""
+    # (the older form is asked for as it always was: what stands in for
+    # ``route`` in a rehearsal takes the arguments it had then)
+    experts, weights = route(
+        moe, noise, c, u, top_k=top_k, scaling=scaling,
+        **({} if scoring == "sigmoid" else {"scoring": scoring}))
+    return routed_experts(
+        moe["experts"], subtree(noise, "experts"), c, u.astype(dtype),
+        experts, weights, first_held=first_held, total=total)
 
 
 def expert_capacity(pairs: int, held: int, total: int) -> int:

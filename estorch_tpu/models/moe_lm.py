@@ -123,14 +123,11 @@ class MoELM:
         if bad or not self.layer_types:
             raise ValueError(f"layer_types holds {sorted(bad)}; the kinds "
                              f"are {DENSE_LAYER!r} and {MOE_LAYER!r}")
-        only = {"n_group": 1, "topk_group": 1, "norm_topk_prob": True,
-                "scoring_func": "sigmoid", "topk_method": "noaux_tc",
-                "rope_interleave": True, "n_shared_experts": 1,
-                "num_nextn_predict_layers": 1, "tie_word_embeddings": False}
-        for name, value in only.items():
-            if getattr(self, name) != value:
-                raise ValueError(f"{name} = {getattr(self, name)!r} is not "
-                                 f"written: the one form is {value!r}")
+        lm_blocks.refuse_unwritten(self, {
+            "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+            "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+            "rope_interleave": True, "n_shared_experts": 1,
+            "num_nextn_predict_layers": 1, "tie_word_embeddings": False})
         if self.qk_rope_head_dim % 2:
             raise ValueError(f"qk_rope_head_dim {self.qk_rope_head_dim} must "
                              "be even: the rotation turns pairs")
@@ -373,13 +370,10 @@ class MoELM:
                 u.astype(dtype)), jnp.zeros((self.n_routed_experts,),
                                             jnp.int32)
         moe, m_noise = p["moe"], subtree(noise, "moe")
-        experts, weights = lm_blocks.route(
-            moe, m_noise, c, u, top_k=self.num_experts_per_tok,
-            scaling=self.routed_scaling_factor)
-        routed, load = lm_blocks.routed_experts(
-            moe["experts"], subtree(m_noise, "experts"), c, u.astype(dtype),
-            experts, weights, first_held=self.first_expert_held,
-            total=self.experts_total)
+        routed, load = lm_blocks.routed_ffn(
+            moe, m_noise, c, u, dtype, top_k=self.num_experts_per_tok,
+            scaling=self.routed_scaling_factor,
+            first_held=self.first_expert_held, total=self.experts_total)
         u = u.astype(dtype)
         with part("shared"):    # its leaves read ``shared.gate`` … in a trace
             shared = lm_blocks.gated_mlp(

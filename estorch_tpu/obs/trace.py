@@ -52,7 +52,7 @@ _PART_NAME = re.compile(r"[A-Za-z0-9_]+(?:\.[A-Za-z0-9_]+)*")
 # the stages of one generation, in program order (docs/observability.md)
 STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
           DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH, EXPERT, GMU,
-          DIFF) = (
+          DIFF, INDEX, SELECT) = (
     "sample",    # offsets, signs, member keys
     "noise",     # reading eps: the table gather and the slab it builds
     "perturb",   # theta + sigma * sign * eps, unravel, cast; the rank-r
@@ -64,13 +64,14 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "grad",      # the second pass over the noise, the weighted sum
     "update",    # weight decay, optax step, sigma decay, obs-norm probe
     # nested inside es.policy by a sequence model (models/hybrid_lm.py,
-    # models/looped_lm.py, models/moe_lm.py, models/sambay_lm.py on the
-    # pieces of models/lm_blocks.py)
+    # models/looped_lm.py, models/moe_lm.py, models/sambay_lm.py,
+    # models/indexed_moe_lm.py on the pieces of models/lm_blocks.py)
     "dense",     # the shared x@W projections and the gated FFN
     "ssm",       # conv1d, dt and decay, the scan (Mamba-2's chunked form,
                  # Mamba-1's selective one), the gate
     "attn",      # scores, softmax, P.V (a model with several kinds of
-                 # attention names each a part: of.window, of.full, of.cross)
+                 # attention names each a part: of.window, of.full, of.cross;
+                 # attention over a selection of keys: of.selected)
     "head",      # the logits (tied or not), log-softmax, the score
     "rope",      # rotary positions: cos/sin, rotating queries and keys
     "exit",      # a looped model's exit gate, the exit distribution and
@@ -86,6 +87,12 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
                  # projections are es.dense parts gmu_in, gmu_out)
     "diff",      # differential attention's combine: lambda, A1 v - lambda
                  # A2 v, the norm over a head pair's values, the scale
+    "index",     # a sparse-attention indexer: its three projections (parts
+                 # index_q, index_k, index_w), the key's norm, the score
+                 # product sum_j w_j relu(q_j . k) and its causal mask
+    "select",    # the choice of the topk largest index scores a query (the
+                 # bisection on the k-th largest, the ties) and the write of
+                 # the [T, T] selection the attention reads
 )
 
 

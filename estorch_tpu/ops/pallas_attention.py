@@ -54,6 +54,20 @@ layer), and the v5e's MXU contracts 128 deep whatever the width.  The
 term is a branch at TRACE time: without a shared part the traced kernel is
 the single-term one, operand for operand.
 
+The tile may be masked by a SELECTION of keys (``selected [T, T]`` int8,
+``lm_blocks.select_keys``: a learned sparse attention's choice, the same
+for every head): one more operand, read a ``(block_q, block_k)`` tile at a
+time beside q and k, and ``s = where(selected, s, -inf)`` in EVERY visible
+tile, beside the causal mask in the tiles the diagonal crosses.  A row may
+then have selected nothing in the first key blocks it sees: its running
+max is still ``-inf`` when the next block arrives, and the body keeps max
+``-inf``, sum 0 and accumulator 0 for it (the exponentials are taken
+against 0 where the max is ``-inf``) instead of ``exp(-inf - -inf)``.  A
+branch at TRACE time, as the shared term and the pair are: without a
+selection the traced kernel is the one above, operand for operand.  The
+kernel multiplies every visible pair and masks; skipping the key blocks no
+row of a query block selected is ROADMAP R13's.
+
 Grid ``(heads, query blocks, key blocks)``, the key axis innermost and
 sequential; running max, sum and a float32 accumulator in VMEM scratch.
 Causal by construction: a key block beyond the query block's last row is
@@ -262,7 +276,8 @@ def _last_visible(i, block_q: int, block_k: int):
 def attention_cost(length: int, num_heads: int, num_kv_heads: int,
                    head_dim: int, value_dim: int, shared_dim: int,
                    block_q: int, block_k: int, itemsize: int,
-                   paired: bool = False) -> pl.CostEstimate:
+                   paired: bool = False,
+                   selected: bool = False) -> pl.CostEstimate:
     """What ONE call of the kernel does, from its grid and blocks: the
     declaration ``pallas_call`` hands XLA (the scheduler reads it, and a
     profiler's trace carries it as the custom call's ``flops`` and
@@ -277,8 +292,10 @@ def attention_cost(length: int, num_heads: int, num_kv_heads: int,
     the rescaling.  Bytes: q, k, v, the shared parts and the context ONCE
     each (the algorithm's least; k and v are fetched again for every query
     block that sees them, 2.5 times at four blocks; ``paired``: ONE value
-    block a pair of key heads).  ``vmap`` scales all three by the members
-    in front of the grid."""
+    block a pair of key heads; ``selected``: the int8 tiles of the
+    selection the kernel computes under, once, although every head fetches
+    them again).  ``vmap`` scales all three by the members in front of the
+    grid."""
     tiles = sum(_last_visible(i, block_q, block_k) + 1
                 for i in range(length // block_q))
     value_heads = num_kv_heads // 2 if paired else num_kv_heads
@@ -290,13 +307,16 @@ def attention_cost(length: int, num_heads: int, num_kv_heads: int,
         flops=2 * num_heads * tiles * block_q * block_k
         * (head_dim + shared_dim + value_dim),
         transcendentals=num_heads * tiles * block_q * (block_k + 1),
-        bytes_accessed=elements * itemsize)
+        bytes_accessed=elements * itemsize
+        + (tiles * block_q * block_k if selected else 0))
 
 
 def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
-                      block_k: int):
-    # with a shared score term: each head's second query part, the one key
+                      block_k: int, selected: bool = False):
+    # with a shared score term: each head's second query part, the one key;
+    # with a selection: its tile, last of the operands
     *shared, o_ref, m_ref, l_ref, acc_ref = refs
+    sel_ref = shared.pop() if selected else None
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_visible(i, block_q, block_k)
 
@@ -324,12 +344,19 @@ def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
             cols = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             s = jnp.where(cols <= rows, s, -jnp.inf)
+        if selected:
+            s = jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, -jnp.inf)
         # every row sees key 0, which block 0 holds: after the first block
         # the running max is finite, so exp(-inf - max) is 0, never NaN
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next[:, :1])
+        m_base = m_next
+        if selected:
+            # a row that has selected no key yet: max -inf, and its
+            # exponentials are taken against 0 (all of them 0)
+            m_base = jnp.where(m_next == -jnp.inf, 0.0, m_next)
+        alpha = jnp.exp(m_prev - m_base)
+        p = jnp.exp(s - m_base[:, :1])
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_next
         acc_ref[...] = alpha[:, :1] * acc_ref[...] + jnp.dot(
@@ -361,6 +388,7 @@ def causal_attention(
     v: jax.Array | None,  # [T, num_kv_heads · value_dim]; None: beside k
     q_shared: jax.Array | None = None,  # [T, num_heads · shared width]
     k_shared: jax.Array | None = None,  # [T, shared width]: ONE key part
+    selected: jax.Array | None = None,  # [T, T] int8: the keys a query sees
     *,
     num_heads: int,
     num_kv_heads: int,
@@ -407,6 +435,13 @@ def causal_attention(
     the chip a pair is one 128-lane block: ``head_dim`` 64, ``value_dim``
     a multiple of 128.
 
+    ``selected [T, T]`` int8: query ``t`` sees the keys ``s <= t`` with
+    ``selected[t, s] != 0`` and no others (heads of one width, values
+    apart, no shared part, no pairs); every head reads the one selection.
+    A row whose selection is empty gives NaN, as a softmax over nothing
+    does (``lm_blocks.select_keys`` always selects a query's own key or an
+    earlier one).
+
     ``block_q``, ``block_k``: rows of a query and of a key block;
     :func:`kernel_block` of ``T`` where not given (the whole sequence where
     it has none, which only the interpreter runs).  On the chip the
@@ -437,6 +472,12 @@ def causal_attention(
             "values apart and no shared part; got "
             f"{num_kv_heads} key heads, v {None if beside else v.shape}, "
             f"q_shared {q_shared.shape if shared else None}")
+    choose = selected is not None
+    if choose and (beside or shared or paired or selected.shape != (t, t)):
+        raise ValueError(
+            "a selection is [T, T] over heads of one width with their "
+            f"values apart, no shared part and no pairs; got "
+            f"{selected.shape} over {t} positions")
     k_width = head_dim + value_dim if beside else head_dim
     value_heads = num_kv_heads // 2 if paired else num_kv_heads
     if (q.shape != (t, num_heads * head_dim)
@@ -490,7 +531,7 @@ def causal_attention(
     cost = attention_cost(
         t, num_heads, num_kv_heads, head_dim, value_dim,
         k_shared.shape[-1] if shared else 0, block_q, block_k,
-        q.dtype.itemsize, paired)
+        q.dtype.itemsize, paired, choose)
     if shared:
         width = k_shared.shape[-1]
         if (q_shared.shape != (t, num_heads * width)
@@ -524,6 +565,13 @@ def causal_attention(
                          lambda h, i, j: (kv_row(i, j), k_col(h))),
         ]
 
+    if choose:
+        # the tile of the selection this step scores under; beyond the
+        # diagonal the tile already there, as for k and v
+        operands.append(selected)
+        in_specs.append(pl.BlockSpec(
+            (block_q, block_k), lambda h, i, j: (i, kv_row(i, j))))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(num_heads, t // block_q, t // block_k),
@@ -537,7 +585,7 @@ def causal_attention(
     )
     return pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k),
+                          block_k=block_k, selected=choose),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, num_heads * value_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -186,11 +186,24 @@ SAMBAY_LM_PARTITION_RULES = (
     (r"(norm[1-4]|final_norm)/bias$", P()),
 )
 
+# A sparse-expert decoder whose attention reads a learned selection of keys
+# (models/indexed_moe_lm.py).  Its q, k, v, o, router and stacked experts go
+# by the rules above that name them.  The indexer's query projection goes by
+# index head (column-parallel); its ONE key head, that key's LayerNorm and
+# the per-head weights ``index_w`` (16 columns) are read whole by every
+# index head and replicate, as the per-head norms of q and k do.
+INDEXED_MOE_LM_PARTITION_RULES = (
+    (r"indexer/index_q$", P(None, MODEL_AXIS)),
+    (r"indexer/(index_k|index_w)$", P()),
+    (r"indexer/index_norm/(scale|bias)$", P()),
+    (r"k_norm/scale$", P()),
+)
+
 CATCH_ALL = r".*"
 
 DEFAULT_PARTITION_RULES = (
         HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
-        + SAMBAY_LM_PARTITION_RULES) + (
+        + SAMBAY_LM_PARTITION_RULES + INDEXED_MOE_LM_PARTITION_RULES) + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),

@@ -171,6 +171,7 @@ class ShardedESEngine:
         attention_windows: dict | None = None,
         attention_kv_heads: int | None = None,
         dense_noise_leaves=(),
+        selection_bytes: Callable[[int], int] | None = None,
     ):
         if config.obs_norm:
             raise ValueError(
@@ -231,6 +232,15 @@ class ShardedESEngine:
         # the policy returns its experts' load after what the env scores
         # (models/moe_lm.py); the perturbed form sums it into the metrics
         self._expert_load = bool(expert_load)
+        # a model whose attention reads a learned selection of keys
+        # (models/indexed_moe_lm.py): the bytes of the selection's
+        # temporaries ONE member holds over the horizon, which the chunk
+        # rule counts; such a policy returns, after its experts' load, the
+        # (query, key) pairs it selected
+        self._selection_bytes = (
+            0 if selection_bytes is None
+            else int(selection_bytes(config.horizon)))
+        self._selected_pairs = selection_bytes is not None
         # the Pallas kernels compile through Mosaic on the chip this mesh
         # is made of; anywhere else only the interpreter can run them
         self._pallas_interpret = mesh.devices.flat[0].platform != "tpu"
@@ -406,6 +416,8 @@ class ShardedESEngine:
         }
         if self._expert_load and self.forward_form == "perturbed":
             metrics_shardings["expert_load"] = self._repl
+        if self._selected_pairs and self.forward_form == "perturbed":
+            metrics_shardings["selected_pairs"] = self._repl
         # table mode threads the table as a replicated OPERAND, not a
         # closure: a closed-over array lowers as an embedded HLO constant
         # — at table size that bloats the module past the persistent
@@ -569,7 +581,10 @@ class ShardedESEngine:
         """Perturbed form: antithetic pairs (unmirrored: members) per
         evaluation chunk.  ``eval_chunk`` members when the caller set it;
         otherwise as many as keep a device's share of the chunk's widest
-        activation (:meth:`_widest_activation`, float32) under
+        activation (:meth:`_widest_activation`, float32; or, where they are
+        more, the bytes of a learned selection of keys and of the index
+        scores it is chosen from, as the model states them:
+        ``selection_bytes``) under
         ``ACTIVATION_BUDGET_BYTES``.  A chunk holds whole pairs and a
         multiple of ``pop_shards`` rows, with one exception: where ONE
         member's widest activation is over the budget by itself, a chunk is
@@ -583,7 +598,8 @@ class ShardedESEngine:
         if cfg.eval_chunk > 0:
             req = max(1, cfg.eval_chunk // (per_row * self.pop_shards))
         else:
-            per_member = 4 * self._widest_activation()
+            per_member = max(4 * self._widest_activation(),
+                             self._selection_bytes)
             req = max(1, ACTIVATION_BUDGET_BYTES // (per_member * per_row))
             self.signs_in_turn = (cfg.mirrored
                                   and per_member > ACTIVATION_BUDGET_BYTES)
@@ -628,13 +644,14 @@ class ShardedESEngine:
             res = jax.vmap(pair_eval, spmd_axis_name=POP_AXIS)(
                 noise_tree, keys_c)
             load = res.extras[0] if self._expert_load else None
-            return res.total_reward, res.bc, res.steps, load
+            chosen = res.extras[1] if self._selected_pairs else None
+            return res.total_reward, res.bc, res.steps, load, chosen
 
         if self.n_pair_chunks == 1:
-            f, bc, st, load = chunk_body(noise_rows, keys)
+            f, bc, st, load, chosen = chunk_body(noise_rows, keys)
         else:
             n, k = self.n_pair_chunks, self.pair_chunk
-            _, (f, bc, st, load) = jax.lax.scan(
+            _, (f, bc, st, load, chosen) = jax.lax.scan(
                 lambda _, xs: (0, chunk_body(*xs)), 0,
                 (noise_rows.reshape(n, k, self.noise_dim),
                  keys.reshape((n, k) + keys.shape[1:])))
@@ -650,8 +667,13 @@ class ShardedESEngine:
                 # over the real members
                 load = load.reshape(self.members_padded, -1)
                 load = jnp.where(alive[:, None], load, 0).sum(axis=0)
+            if chosen is not None:
+                # a member's count, summed over its layers, fits int32; the
+                # population's need not: the host adds the members up
+                chosen = chosen.reshape(
+                    self.members_padded)[: cfg.population_size]
             return (f[: cfg.population_size], bc[: cfg.population_size],
-                    steps, load)
+                    steps, load, chosen)
 
     def _noise_rows(self, offsets, table_data):
         """Perturbed form: every pair's ``noise_dim`` floats, sliced from
@@ -854,8 +876,8 @@ class ShardedESEngine:
                     for x, dtype, sh in zip(
                         jax.tree_util.tree_leaves(state.params),
                         self._leaf_dtypes, self._param_sharding_leaves)])
-            fitness, bc, steps, expert_load = self._eval_all_perturbed(
-                state, center, noise_rows, rkey)
+            fitness, bc, steps, expert_load, selected_pairs = (
+                self._eval_all_perturbed(state, center, noise_rows, rkey))
         else:
             fitness, bc, steps = self._eval_all(
                 state, offsets, leaf_keys, rkey, table_data)
@@ -905,6 +927,8 @@ class ShardedESEngine:
         }
         if perturbed and expert_load is not None:
             metrics["expert_load"] = expert_load
+        if perturbed and selected_pairs is not None:
+            metrics["selected_pairs"] = selected_pairs
         return new_state, metrics
 
     # ------------------------------------------------------------- public

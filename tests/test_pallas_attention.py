@@ -85,8 +85,22 @@ def _plain_pairs(q, k, v, nq, nkv, scale):
     return jnp.transpose(jnp.asarray(ctx), (3, 0, 1, 2, 4)).reshape(t, -1)
 
 
-def _plain(q, k, v, nq, nkv, scale, paired=False):
-    """Full masked softmax per head, float32 ``highest``."""
+def _selection(t, topk, seed=0):
+    """``[T, T]`` int8 of ``lm_blocks.select_keys`` over random index
+    scores: ``min(t + 1, topk)`` keys a query, none in the future; ``None``
+    for no ``topk``."""
+    if topk is None:
+        return None
+    ks = jax.random.split(jax.random.PRNGKey(100 + seed), 3)
+    selected, _ = lm_blocks.select_keys(
+        jax.random.normal(ks[0], (t, 2, 4)), jax.random.normal(ks[1], (t, 4)),
+        jax.random.normal(ks[2], (t, 2)), topk=topk, block=8)
+    return selected
+
+
+def _plain(q, k, v, nq, nkv, scale, paired=False, selected=None):
+    """Full masked softmax per head, float32 ``highest``; under a
+    ``selected [T, T]`` the keys it marks alone."""
     if paired:
         return _plain_pairs(q, k, v, nq, nkv, scale)
     t, f32, hi = q.shape[0], jnp.float32, "highest"
@@ -94,8 +108,10 @@ def _plain(q, k, v, nq, nkv, scale, paired=False):
     kh = jnp.repeat(k.astype(f32).reshape(t, nkv, HD), nq // nkv, axis=1)
     vh = jnp.repeat(v.astype(f32).reshape(t, nkv, HD), nq // nkv, axis=1)
     s = jnp.einsum("qhd,khd->hqk", qh, kh, precision=hi) * scale
-    s = jnp.where(jnp.arange(t)[None, :] <= jnp.arange(t)[:, None], s,
-                  -jnp.inf)
+    seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    if selected is not None:
+        seen = seen & (selected != 0)
+    s = jnp.where(seen, s, -jnp.inf)
     return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), vh,
                       precision=hi).reshape(t, nq * HD)
 
@@ -113,18 +129,24 @@ def _through_lm_blocks(q, k, v, nq, nkv, scale, block):
         head_dim=HD, scale=scale, block=block)
 
 
-def _kernel(q, k, v, nq, nkv, scale, block_q, block_k=None, paired=False):
+def _kernel(q, k, v, nq, nkv, scale, block_q, block_k=None, paired=False,
+            selected=None):
     """``paired``: q, k and v as ``_qkv`` makes them, READ as differential
     pairs (``v [T, nkv · HD]`` is then ``nkv/2`` value blocks of 2·HD)."""
     return causal_attention(
-        q, k, v, num_heads=nq, num_kv_heads=nkv, head_dim=HD, scale=scale,
-        value_dim=2 * HD if paired else None, paired=paired,
-        block_q=block_q, block_k=block_k or block_q, interpret=True)
+        q, k, v, selected=selected, num_heads=nq, num_kv_heads=nkv,
+        head_dim=HD, scale=scale, value_dim=2 * HD if paired else None,
+        paired=paired, block_q=block_q, block_k=block_k or block_q,
+        interpret=True)
 
 
-# (query heads, key heads, in pairs): grouped and not; a differential pair
-# of key heads with one and with two diff-heads a pair (group 1 and 2)
-HEADS = [(4, 4, False), (4, 1, False), (4, 4, True), (8, 4, True)]
+# (query heads, key heads, in pairs, keys a query selects): grouped and not;
+# a differential pair of key heads with one and with two diff-heads a pair
+# (group 1 and 2); a learned selection of 5 or 3 keys a query, under which a
+# row of a later query block has often selected nothing in the first key
+# blocks it sees
+HEADS = [(4, 4, False, None), (4, 1, False, None), (4, 4, True, None),
+         (8, 4, True, None), (4, 4, False, 5), (4, 2, False, 3)]
 
 
 def _f32(x):
@@ -134,35 +156,47 @@ def _f32(x):
 class TestKernelAgainstBothForms:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("blocks", [1, 2, 4])
-    @pytest.mark.parametrize("nq, nkv, paired", HEADS)
-    def test_kernel_is_the_masked_softmax(self, nq, nkv, paired, blocks,
-                                          dtype):
+    @pytest.mark.parametrize("nq, nkv, paired, topk", HEADS)
+    def test_kernel_is_the_masked_softmax(self, nq, nkv, paired, topk,
+                                          blocks, dtype):
         """Against a plain softmax a head; heads in pairs against the two
-        softmaxes a diff-head of differential attention."""
+        softmaxes a diff-head of differential attention; under a selection
+        against the softmax over the selected keys."""
         block, scale = 8, HD ** -0.5
         q, k, v = _qkv(block * blocks, nq, nkv, dtype, seed=blocks)
-        got = _kernel(q, k, v, nq, nkv, scale, block, paired=paired)
+        selected = _selection(block * blocks, topk, seed=blocks)
+        got = _kernel(q, k, v, nq, nkv, scale, block, paired=paired,
+                      selected=selected)
         # a pair's heads are summed over the pair's 2·HD values
         assert got.shape == (q.shape[0], nq * HD * (2 if paired else 1))
         assert got.dtype == q.dtype
         tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
         np.testing.assert_allclose(
-            _f32(got), _f32(_plain(q, k, v, nq, nkv, scale, paired)),
+            _f32(got),
+            _f32(_plain(q, k, v, nq, nkv, scale, paired, selected)),
             atol=tol)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("blocks", [1, 2, 4])
-    @pytest.mark.parametrize("nq, nkv, paired", HEADS)
-    def test_kernel_is_the_xla_block_causal_form(self, nq, nkv, paired,
+    @pytest.mark.parametrize("nq, nkv, paired, topk", HEADS)
+    def test_kernel_is_the_xla_block_causal_form(self, nq, nkv, paired, topk,
                                                  blocks, dtype):
         """The two forms ``lm_blocks.causal_attention`` dispatches between:
         outside a scope the XLA form, inside one the kernel (heads in
         pairs: ``attention_core(paired=True)``, whose XLA form orders the
-        score heads and copies a pair's values a map itself)."""
+        score heads and copies a pair's values a map itself; under a
+        selection: ``attention_core(selected=)``, both forms of which read
+        the one ``[T, T]``)."""
         block, scale = 8, 0.3
         q, k, v = _qkv(block * blocks, nq, nkv, dtype, seed=10 + blocks)
+        selected = _selection(block * blocks, topk, seed=10 + blocks)
 
         def through_the_core():
+            if selected is not None:
+                return lm_blocks.attention_core(
+                    q.reshape(-1, nq, HD), k.reshape(-1, nkv, HD), v,
+                    num_heads=nq, num_kv_heads=nkv, scale=scale, block=block,
+                    selected=selected)
             if not paired:
                 return _through_lm_blocks(q, k, v, nq, nkv, scale, block)
             return lm_blocks.attention_core(
@@ -172,7 +206,8 @@ class TestKernelAgainstBothForms:
         xla = through_the_core()
         tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
         np.testing.assert_allclose(
-            _f32(_kernel(q, k, v, nq, nkv, scale, block, paired=paired)),
+            _f32(_kernel(q, k, v, nq, nkv, scale, block, paired=paired,
+                         selected=selected)),
             _f32(xla), atol=tol)
         with kernel_scope(interpret=True):
             scoped = through_the_core()
@@ -180,15 +215,45 @@ class TestKernelAgainstBothForms:
 
     @pytest.mark.parametrize("block_q, block_k", [
         (16, 8), (8, 16), (32, 8), (8, 32), (32, 32)])
-    @pytest.mark.parametrize("paired", [False, True])
-    def test_unequal_blocks(self, block_q, block_k, paired):
+    @pytest.mark.parametrize("paired, topk", [(False, None), (True, None),
+                                              (False, 4)])
+    def test_unequal_blocks(self, block_q, block_k, paired, topk):
         """Key blocks the diagonal crosses part-way, rows that a visible
-        block masks whole, and the clamp of the index map."""
+        block masks whole, and the clamp of the index map (the selection's
+        tile follows it)."""
         q, k, v = _qkv(32, 4, 2, jnp.float32, seed=3)
+        selected = _selection(32, topk, seed=3)
         np.testing.assert_allclose(
             _f32(_kernel(q, k, v, 4, 2, 0.25, block_q, block_k,
-                         paired=paired)),
-            _f32(_plain(q, k, v, 4, 2, 0.25, paired)), atol=F32_TOL)
+                         paired=paired, selected=selected)),
+            _f32(_plain(q, k, v, 4, 2, 0.25, paired, selected)),
+            atol=F32_TOL)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("block_q, block_k", [(8, 8), (16, 8), (8, 16)])
+    def test_a_row_that_selects_nothing_in_the_first_key_blocks(
+            self, block_q, block_k, dtype):
+        """A selection of the last three keys of every query: the rows of
+        every later query block select NOTHING in the first key blocks they
+        see, their running max is still ``-inf`` when the next block
+        arrives, and the kernel keeps max ``-inf``, sum 0 and accumulator 0
+        for them with no NaN; it is then the XLA form under a WINDOW of
+        three, whose mask the selection spells."""
+        t, nq, nkv = 32, 4, 2
+        q, k, v = _qkv(t, nq, nkv, dtype, seed=21)
+        rows, cols = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+        selected = ((cols <= rows) & (cols > rows - 3)).astype(jnp.int8)
+        got = _f32(_kernel(q, k, v, nq, nkv, 0.3, block_q, block_k,
+                           selected=selected))
+        assert np.isfinite(got).all()
+        window = lm_blocks.attention_core(
+            q.reshape(t, nq, HD), k.reshape(t, nkv, HD), v, num_heads=nq,
+            num_kv_heads=nkv, scale=0.3, block=8, window=3)
+        tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
+        np.testing.assert_allclose(got, _f32(window), atol=tol)
+        np.testing.assert_allclose(
+            got, _f32(_plain(q, k, v, nq, nkv, 0.3, selected=selected)),
+            atol=tol)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("group", [1, 2])
@@ -636,13 +701,14 @@ class TestTheDeclaredCost:
 
     @pytest.mark.parametrize("length, block_q, block_k", [
         (64, 16, 16), (64, 32, 16), (64, 16, 32), (96, 32, 32), (32, 32, 32)])
-    @pytest.mark.parametrize("shared, paired", [(0, False), (4, False),
-                                                (0, True)])
+    @pytest.mark.parametrize("shared, paired, chosen", [
+        (0, False, False), (4, False, False), (0, True, False),
+        (0, False, True)])
     def test_against_a_count_tile_by_tile(self, length, block_q, block_k,
-                                          shared, paired):
+                                          shared, paired, chosen):
         heads, kv_heads, hd, vd, itemsize = 4, 2, 8, 16, 2
-        flops = exps = 0
-        for _ in range(heads):
+        flops = exps = tiles = 0
+        for head in range(heads):
             for i in range(length // block_q):
                 for j in range(length // block_k):
                     # a tile is computed where its first key is no later
@@ -650,15 +716,18 @@ class TestTheDeclaredCost:
                     if j * block_k <= (i + 1) * block_q - 1:
                         flops += 2 * block_q * block_k * (hd + shared + vd)
                         exps += block_q * block_k + block_q
+                        tiles += head == 0
         # q, its shared part and the context a head; k a key head; v a key
         # head, or ONE block a pair of them; the one shared key
         elements = length * (heads * (hd + shared + vd) + kv_heads * hd
                              + kv_heads // (2 if paired else 1) * vd + shared)
         cost = pallas_attention.attention_cost(
             length, heads, kv_heads, hd, vd, shared, block_q, block_k,
-            itemsize, paired)
+            itemsize, paired, chosen)
+        # a selection: its int8 tiles, the visible ones, once
         assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
-            flops, exps, elements * itemsize)
+            flops, exps, elements * itemsize
+            + (tiles * block_q * block_k if chosen else 0))
 
     def test_ten_tiles_of_sixteen_over_the_exact_triangle(self):
         # the cells' geometry: 4,096 positions in blocks of 1,024

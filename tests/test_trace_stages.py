@@ -18,18 +18,21 @@ from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
 from estorch_tpu.obs.spans import Telemetry
 from estorch_tpu.obs.trace import (ATTN, DENSE, DIFF, DISPATCH, ENV, EXIT,
-                                   EXPERT, GATHER, GMU, GRAD, HEAD, NOISE,
-                                   PERTURB, POLICY, PART_PREFIX, RANK, ROPE,
-                                   ROUTE, SAMPLE, SCOPE_PREFIX, SSM, STAGES,
-                                   UPDATE, annotate, part, stage, trace)
+                                   EXPERT, GATHER, GMU, GRAD, HEAD, INDEX,
+                                   NOISE, PERTURB, POLICY, PART_PREFIX, RANK,
+                                   ROPE, ROUTE, SAMPLE, SCOPE_PREFIX, SELECT,
+                                   SSM, STAGES, UPDATE, annotate, part, stage,
+                                   trace)
 
 # the stages of every generation program; a sequence model nests more
 # inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT; a
 # sparse-expert one ROPE, ROUTE, DISPATCH and EXPERT; one with gated memory
-# units and differential attention GMU and DIFF)
+# units and differential attention GMU and DIFF; one whose attention reads a
+# learned selection of keys INDEX and SELECT)
 GENERATION_STAGES = STAGES[:9]
 EXPERT_STAGES = {ROUTE, DISPATCH, EXPERT}
 SAMBAY_STAGES = {GMU, DIFF}
+INDEXED_STAGES = {INDEX, SELECT}
 
 SCOPE = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(SCOPE_PREFIX)
                    + r"([a-z_]+)")
@@ -101,26 +104,38 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source,
     assert any(POLICY in stack for stack in matmuls)
 
 
-# the four sequence models on the sharded engine's perturbed form: what
+# the five sequence models on the sharded engine's perturbed form: what
 # each is built from, the stages its forward does NOT name, the layers it
 # nests inside es.policy, and the parts it names that are no leaf's
 SEQUENCE_MODELS = {
     "sequence": dict(policy="HybridLM", tiny="lm_tiny", devices=4,
                      model_shards=2,
-                     absent={ROPE, EXIT} | EXPERT_STAGES | SAMBAY_STAGES,
+                     absent=({ROPE, EXIT} | EXPERT_STAGES | SAMBAY_STAGES
+                             | INDEXED_STAGES),
                      inner=(DENSE, SSM, ATTN, HEAD)),
     "looped": dict(policy="LoopedLM", tiny="loop_tiny", devices=1,
                    model_shards=1,
-                   absent={SSM} | EXPERT_STAGES | SAMBAY_STAGES,
+                   absent=({SSM} | EXPERT_STAGES | SAMBAY_STAGES
+                           | INDEXED_STAGES),
                    inner=(DENSE, ATTN, HEAD, ROPE, EXIT)),
     "expert": dict(policy="MoELM", tiny="moe_tiny", devices=1,
-                   model_shards=1, absent={SSM, EXIT} | SAMBAY_STAGES,
+                   model_shards=1,
+                   absent={SSM, EXIT} | SAMBAY_STAGES | INDEXED_STAGES,
                    inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT)),
     # its three kinds of attention say which they are: parts of es.attn
     "sambay": dict(policy="SambaYLM", tiny="sambay_tiny", devices=1,
-                   model_shards=1, absent={ROPE, EXIT} | EXPERT_STAGES,
+                   model_shards=1,
+                   absent={ROPE, EXIT} | EXPERT_STAGES | INDEXED_STAGES,
                    inner=(DENSE, SSM, ATTN, HEAD, GMU, DIFF),
                    more_parts={"window": ATTN, "full": ATTN, "cross": ATTN}),
+    # its indexer's three projections are parts of es.index; its one kind
+    # of attention, over the selection, says which it is
+    "indexed": dict(policy="IndexedMoELM", tiny="indexed_moe_tiny",
+                    devices=1, model_shards=1,
+                    absent={SSM, EXIT} | SAMBAY_STAGES,
+                    inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT,
+                           INDEX, SELECT),
+                    more_parts={"selected": ATTN}),
 }
 PART = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(PART_PREFIX)
                   + r"([A-Za-z0-9_.]+)")
@@ -151,7 +166,8 @@ def _multiplied_leaves(module) -> dict:
     expert's with that path element in front."""
     leaves = jax.tree_util.tree_flatten_with_path(module.param_shapes())[0]
     own_stage = {"router": ROUTE, "exit_gate": EXIT, "head": HEAD,
-                 "embed": POLICY}
+                 "embed": POLICY, "index_q": INDEX, "index_k": INDEX,
+                 "index_w": INDEX}
     parts = {}
     for path, leaf in leaves:
         keys = [str(k.key) for k in path]
@@ -181,7 +197,7 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
     es = _sequence_es(case)
     engine = es.engine
     lowered = engine._generation_step.lower(es.state, engine.table.data)
-    if model == "expert":
+    if model in ("expert", "indexed"):
         text = lowered.as_text(debug_info=True)
         names = re.findall(r'loc\("(jit\([^"]*)"', text)
     else:
@@ -198,10 +214,11 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         # lowered text of the expert model shortens none)
         nested = [st for st in stacks if POLICY in st]
         assert stacks and len(nested) > len(stacks) // 2, inner
-        assert model != "expert" or len(nested) == len(stacks), inner
+        assert (model not in ("expert", "indexed")
+                or len(nested) == len(stacks)), inner
         assert all(st.index(POLICY) < st.index(inner) for st in nested), inner
     stacks = [(tuple(SCOPE.findall(n)), n) for n in names if SCOPE.findall(n)]
-    if model == "expert":
+    if model in ("expert", "indexed"):
         # the grouped matmuls sit under es.expert, their per-(member,
         # expert) corrections one deeper, the sort under es.dispatch
         assert any(st[-1] == EXPERT and "ragged_dot" in n for st, n in stacks)
@@ -210,7 +227,16 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         assert any(st[-1] == DISPATCH and "scatter" in n for st, n in stacks)
         assert any(st[-1] == ROUTE and "top_k" in n for st, n in stacks)
         assert es.obs.counters.get("experts_held") == 4
-    else:
+    if model == "indexed":
+        # the score product of every index head against the ONE key head
+        # under es.index, the bisection's loop and the prefix count under
+        # es.select, and the selection the attention reads
+        assert any(st[-1] == INDEX and "dot_general" in n for st, n in stacks)
+        assert any(st[-1] == SELECT and "while" in n for st, n in stacks)
+        assert any(st[-1] == SELECT and "cumsum" in n for st, n in stacks)
+        assert any(st[-2:] == (INDEX, PERTURB) for st, _ in stacks)
+        assert es.obs.counters.get("sparse_topk") == 6
+    if model not in ("expert", "indexed"):
         # the projections are matmuls under es.dense or es.head; the
         # corrections are nested one deeper, under es.perturb
         matmuls = [SCOPE.findall(m) for line in text.splitlines()
@@ -265,8 +291,8 @@ def test_parts_are_metadata_only(model, monkeypatch):
     import importlib
 
     from estorch_tpu import models
-    from estorch_tpu.models import (hybrid_lm, lm_blocks, looped_lm, moe_lm,
-                                    perturbed, sambay_lm)
+    from estorch_tpu.models import (hybrid_lm, indexed_moe_lm, lm_blocks,
+                                    looped_lm, moe_lm, perturbed, sambay_lm)
 
     case = SEQUENCE_MODELS[model]
     tiny = importlib.import_module(case["tiny"])
@@ -287,7 +313,7 @@ def test_parts_are_metadata_only(model, monkeypatch):
     with_parts = lowered()
     assert PART_PREFIX in with_parts.as_text(debug_info=True)
     for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm,
-                sambay_lm):
+                sambay_lm, indexed_moe_lm):
         monkeypatch.setattr(mod, "part",
                             lambda name: contextlib.nullcontext())
     without = lowered()
@@ -752,12 +778,106 @@ def test_kernel_form_books_a_differential_pairs_kernel_by_its_kind(v5e_chip):
                and "exponential" in line for line in text.splitlines())
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_attention_kernel_compiles_for_the_v5e_under_a_selection(dtype,
+                                                                  v5e_chip):
+    """Mosaic accepts the attention kernel at ``keye-vl2-es-16k-1chip``'s
+    shapes: 32 query heads of 128 over 4 key heads, one member of 16,384
+    positions in blocks of 1,024 (the cell evaluates a pair's signs in
+    turn), the ``[T, T]`` int8 selection one more operand read a tile at a
+    time; nothing else in the program, no copy of the selection."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import causal_attention
+
+    def operand(width, kind=dtype):
+        return jax.ShapeDtypeStruct(
+            (1, 16384, width), kind, sharding=SingleDeviceSharding(v5e_chip))
+
+    text = jax.jit(jax.vmap(lambda q, k, v, chosen: causal_attention(
+        q, k, v, selected=chosen, num_heads=32, num_kv_heads=4, head_dim=128,
+        scale=128 ** -0.5, interpret=False))).lower(
+            operand(4096), operand(512), operand(512),
+            operand(16384, jnp.int8)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    entry = text.split("ENTRY")[1]
+    assert " copy(" not in entry and "s8[1,16384,16384]" in entry
+
+
+def test_kernel_form_books_the_selected_attention_by_its_kind(v5e_chip):
+    """A small decoder with a learned selection of keys on a one-device TPU
+    mesh: the rule takes its heads of 128, the engine says that the selected
+    attention took the kernel, and the compiled generation program holds
+    the Mosaic call under es.attn inside es.policy in the part
+    ``of.selected``, one a layer, beside the head's: the device trace books
+    it to ``dsa.attn_share``."""
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import IndexedMoELM
+    from estorch_tpu.parallel.mesh import hyperscale_mesh
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    es = _es(
+        policy=IndexedMoELM, population_size=4, sigma=0.02,
+        policy_kwargs=dict(
+            layer_types=("moe", "moe"), vocab_size=256, hidden_size=128,
+            moe_intermediate_size=64, num_attention_heads=2,
+            num_key_value_heads=1, head_dim=128, num_experts=4,
+            num_experts_per_tok=2, indexer_num_heads=2, indexer_head_dim=64,
+            topk=64, mrope_section=(16, 24, 24), attention_block=128,
+            index_block=128, head_block=128),
+        agent_kwargs={"env": TokenScoreEnv(
+            vocab_size=256, seq_len=512, corpus_sequences=4)},
+        shard_params=True, low_rank=1, noise_mode="table",
+        compute_dtype="bfloat16", table_size=1 << 18,
+        device=jax.devices()[:1])
+    assert es.module.attention_widths == 128
+    assert es.engine.attention_form_by_kind == "selected:xla"   # a CPU mesh
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    engine = ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=1, devices=[v5e_chip]),
+        partition_rules=es._partition_rules, noise_mode="table",
+        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        leaf_rows=es.module.leaf_rows,
+        attention_widths=es.module.attention_widths,
+        head_width=es.module.head_width,
+        leaf_rows_per_token=es.module.leaf_rows_per_token,
+        float32_leaves=es.module.float32_leaves, expert_load=True,
+        attention_windows=es.module.attention_windows,
+        attention_kv_heads=es.module.num_key_value_heads,
+        selection_bytes=es.module.selection_bytes)
+    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
+    assert engine.attention_form_why == (
+        "one TPU device, whole column blocks, whole row blocks")
+    assert engine.attention_form_by_kind == "selected:kernel"
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        es.state, engine.state_shardings)
+    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
+                                 sharding=engine._repl)
+    text = engine._generation_step.lower(state, table).compile().as_text()
+    kernels = [name for line in text.splitlines()
+               if "tpu_custom_call" in line and "causal_attention" in line
+               for name in re.findall(r'op_name="([^"]*)"', line)]
+    assert len(kernels) == 2, kernels
+    for name in kernels:
+        assert PART.findall(name) == ["selected"], name
+        assert SCOPE.findall(name)[0] == POLICY, name
+        assert SCOPE.findall(name)[-1] == ATTN, name
+    # the index scores and the choice are XLA's, under their own stages
+    assert any(SCOPE_PREFIX + INDEX in line and "f32[" in line
+               for line in text.splitlines())
+    assert any(SCOPE_PREFIX + SELECT in line for line in text.splitlines())
+
+
 @pytest.mark.parametrize("use", ["context", "decorator"])
 def test_stage_scopes_a_name_stack(use):
-    assert len(set(STAGES)) == len(STAGES) == 20
+    assert len(set(STAGES)) == len(STAGES) == 22
     assert (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD,
             UPDATE, DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH,
-            EXPERT, GMU, DIFF) == STAGES
+            EXPERT, GMU, DIFF, INDEX, SELECT) == STAGES
     if use == "context":
         def f(x):
             with stage(NOISE):
