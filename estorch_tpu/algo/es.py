@@ -337,6 +337,9 @@ class ES:
                 # the width its next-token head contracts, for the head
                 # form's rule; None for a policy without one
                 head_width=getattr(self.module, "head_width", None),
+                # (d_inner, d_state) of its selective scans, for the scan
+                # form's rule; None for a policy without one
+                scan_widths=getattr(self.module, "scan_widths", None),
                 # a sparse-expert model (models/moe_lm.py): what its stacked
                 # leaves see of a sequence, what stays float32, its load
                 leaf_rows_per_token=getattr(
@@ -580,6 +583,9 @@ class ES:
         if getattr(self.engine, "head_form", None) is not None:
             # "kernel" says ops/pallas_head.py engaged
             self.obs.counters.gauge("head_form", self.engine.head_form)
+        if getattr(self.engine, "scan_form", None) is not None:
+            # "kernel" says ops/pallas_scan.py engaged
+            self.obs.counters.gauge("scan_form", self.engine.scan_form)
         if self._shard_params:
             self.obs.counters.gauge("mesh_shape", "x".join(
                 str(n) for n in self.mesh.devices.shape))
@@ -1236,6 +1242,9 @@ class ES:
             # which form the policy's next-token head takes ("kernel" |
             # "xla"; None: a policy without one, or the replicated engine)
             "head_form": getattr(self.engine, "head_form", None),
+            # which form the policy's selective scans take ("kernel" |
+            # "xla"; None: a policy without one, or the replicated engine)
+            "scan_form": getattr(self.engine, "scan_form", None),
             # which condition of the attention form's rule decided (and,
             # where that is "xla", the head form with it)
             "attention_form_why": getattr(

@@ -93,6 +93,11 @@ and the noise is factored or none.  The engine says which at build
 ``head_width`` the model states).  The last position's logits (the
 behaviour) are the one-row XLA matmul in both forms.
 
+A third kernel is taken inside that scope, by a model and not by this
+module: Mamba-1's selective scan (``sambay_lm.selective_scan`` asks
+``pallas_attention.scoped_interpret()`` as the two dispatches here do;
+ops/pallas_scan.py, ``ShardedESEngine.scan_form``).
+
 The indexer (:func:`select_keys`: DeepSeek-V3.2's sparse attention, whose
 ``sa_config`` keys a configuration carries) scores every visible key of a
 query with a few narrow heads against ONE key head, ``I[t, s] = Σ_j w[t, j]

@@ -71,9 +71,20 @@ parameters handed in, float32 accumulation; residual stream, norms, conv,
 ``Δ``, decay, state, ``y`` and ``m``, softmax, the differential combine and
 the log-softmax in float32.
 
-The scan is a ``lax.scan`` over time with the ``[d_state, d_inner]`` state
-as its carry, ``scan_chunk`` steps unrolled an iteration; Mamba-1's decay
-is per (channel, state), so there is no matmul form of it as Mamba-2's.
+The scan (:func:`selective_scan`) has two forms, as the attention and the
+head have.  ``"xla"``: a ``lax.scan`` over time with the ``[d_state,
+d_inner]`` state as its carry, ``scan_chunk`` steps unrolled an iteration;
+the state and the decay's operand cross HBM at every step.  ``"kernel"``:
+ops/pallas_scan.py, the same recurrence with the state in vector registers
+and VMEM, ``Δ``, ``x``, ``B``, ``C`` streamed a time chunk at a time and
+``y`` written once.  It takes the kernel inside an engine's
+``pallas_attention.kernel_scope`` (so: ONE TPU device, an attention whose
+form is the kernel) where its own shapes fit (``pallas_scan.fits``:
+``d_inner`` whole 128-lane blocks, at most 16 states, the sequence whole
+time chunks of 256) and the ``lax.scan`` anywhere else; the engine says
+which at build (``ShardedESEngine.scan_form`` from the ``scan_widths`` the
+model states).  Mamba-1's decay is per (channel, state), so there is no
+matmul form of it as Mamba-2's.
 
 As an ES policy the module maps ``tokens [T]`` to ``(log p of each next
 token [T-1], the last position's logits [vocab])``, as ``HybridLM`` does.
@@ -89,6 +100,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import ATTN, DIFF, GMU, HEAD, SSM, part, stage
+from ..ops import pallas_attention, pallas_scan
 from . import lm_blocks
 from .lm_blocks import layer_name, subtree
 from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
@@ -226,6 +238,15 @@ class SambaYLM:
         """The width the next-token head contracts (the head form's rule
         reads it, ops/pallas_head.py)."""
         return self.hidden_size
+
+    @property
+    def scan_widths(self) -> tuple | None:
+        """``(d_inner, d_state)`` of the selective scans (the scan form's
+        rule reads it, ops/pallas_scan.py); ``None`` where the layers held
+        have no Mamba layer among them."""
+        if not {MAMBA, MAMBA_MEM} & set(self.layer_types):
+            return None
+        return (self.d_inner, self.mamba_d_state)
 
     @property
     def kv_shared_by(self) -> int:
@@ -491,8 +512,17 @@ def selective_scan(x, delta, a, b, c, unroll: int = 1):
     """Mamba-1's recurrence, float32: ``h_t = exp(Δ_t ⊙ A) ⊙ h_{t-1} + (Δ_t
     ⊙ x_t) ⊗ B_t``, ``y_t = h_t C_t``, from ``x, delta [T, d_inner]``, ``a
     [d_inner, d_state]`` (negative), ``b, c [T, d_state]``; ``y [T,
-    d_inner]``.  A ``lax.scan`` over time, ``unroll`` steps an iteration;
-    the state is carried ``[d_state, d_inner]``, channels in the lanes."""
+    d_inner]``.  Inside an engine's ``pallas_attention.kernel_scope``,
+    where the shapes fit (``pallas_scan.fits``), the Pallas kernel of
+    ops/pallas_scan.py, whose state never leaves the chip's registers and
+    VMEM; anywhere else a ``lax.scan`` over time, ``unroll`` steps an
+    iteration, the state carried ``[d_state, d_inner]``, channels in the
+    lanes."""
+    interpret = pallas_attention.scoped_interpret()
+    if interpret is not None and pallas_scan.fits(
+            x.shape[1], a.shape[1], x.shape[0]):
+        return pallas_scan.selective_scan(x, delta, a, b, c,
+                                          interpret=interpret)
     a = a.astype(F32).T                                 # [N, D]
 
     def step(h, xs):

@@ -81,6 +81,7 @@ from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
 from ..ops.pallas_attention import (attention_form_why, call_form,
                                     kernel_scope)
 from ..ops.pallas_head import head_form
+from ..ops.pallas_scan import scan_form
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
 from .engine import (EngineConfig, _bf16_io_apply, _bf16_obs,
@@ -165,6 +166,7 @@ class ShardedESEngine:
         leaf_rows: dict[str, int] | None = None,
         attention_widths: int | tuple | None = None,
         head_width: int | None = None,
+        scan_widths: tuple | None = None,
         leaf_rows_per_token: dict[str, float] | None = None,
         float32_leaves=(),
         expert_load: bool = False,
@@ -282,13 +284,21 @@ class ShardedESEngine:
             None if head_width is None
             else head_form(self.attention_form, head_width, config.horizon,
                            jnp.dtype(self._dtype).itemsize))
+        # "kernel" | "xla": which form the policy's selective scans take
+        # (models/sambay_lm.py::selective_scan); None for a policy that
+        # states no scan.  The kernel is taken inside the same scope, where
+        # the scan's own shapes fit
+        self.scan_form = (
+            None if scan_widths is None
+            else scan_form(self.attention_form, *scan_widths,
+                           config.horizon))
         if self.attention_form is not None:
             import logging
 
             logging.getLogger(__name__).info(
-                "attention_form %s (%s; %s); head_form %s",
+                "attention_form %s (%s; %s); head_form %s; scan_form %s",
                 self.attention_form, self.attention_form_why,
-                self.attention_form_by_kind, self.head_form)
+                self.attention_form_by_kind, self.head_form, self.scan_form)
         self.n_devices = int(mesh.devices.size)
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.pop_shards = int(axis_sizes[POP_AXIS])

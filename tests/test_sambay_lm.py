@@ -14,6 +14,7 @@ import pytest
 from jax.flatten_util import ravel_pytree
 
 import sambay_tiny
+from estorch_tpu.envs import TokenScoreEnv
 from estorch_tpu.models import SambaYLM, lm_blocks
 from estorch_tpu.models.sambay_lm import (lambda_init, layer_kinds,
                                           selective_scan)
@@ -617,7 +618,6 @@ def test_every_leaf_has_a_partition_rule(tiny):
 
 def _sambay_es(devices, model_shards, **over):
     from estorch_tpu import ES, JaxAgent
-    from estorch_tpu.envs import TokenScoreEnv
 
     kw = dict(
         policy=SambaYLM, agent=JaxAgent, optimizer=optax.adam,
@@ -682,8 +682,11 @@ class TestThroughTheShardedEngine:
         assert es.engine.attention_form_by_kind == by_kind
         assert gauges.get("attention_form_by_kind") == by_kind
         assert gauges.get("head_form") == "xla"
+        # the scans too: no scope on a CPU mesh, whatever their shapes
+        assert es.engine.scan_form == gauges.get("scan_form") == "xla"
         assert gauges.get("experts_held", None) is None
         cfg = es.run_manifest()["config"]
+        assert cfg["scan_form"] == "xla"
         assert cfg["layer_kinds"] == gauges.get("layer_kinds")
         assert (cfg["window"], cfg["kv_shared_by"],
                 cfg["memory_shared_by"]) == (5, 1, 1)
@@ -719,9 +722,51 @@ class TestThroughTheShardedEngine:
         np.testing.assert_allclose(got["fitness"], want["fitness"], atol=tol)
         assert np.isfinite(np.asarray(got["fitness"])).all()
 
+    def test_forced_kernel_scans_both_mamba_layers_in_the_kernel(
+            self, devices8, kernel_attention):
+        """Shapes the scan's rule takes (``d_inner`` one 128-lane block,
+        one time chunk of 256 steps): inside the engine's scope BOTH Mamba
+        layers run the scan kernel (interpreted here) beside the
+        attention's two, the engine says so where it says ``head_form``,
+        and the members' fitness is the XLA form's to float32 rounding."""
+        from pallas_costs import pallas_calls
+
+        over = dict(
+            policy_kwargs={**sambay_tiny.TINY, "hidden_size": 64},
+            agent_kwargs={"env": TokenScoreEnv(
+                **{**sambay_tiny.ENV, "seq_len": 256})})
+        ref_es = _sambay_es(devices8[:1], 1, **over)
+        with kernel_attention():
+            kern = _sambay_es(devices8[:1], 1, **over)
+        assert (ref_es.engine.scan_form, kern.engine.scan_form) == (
+            "xla", "kernel")
+        assert kern.obs.counters.get("scan_form") == "kernel"
+        assert kern.run_manifest()["config"]["scan_form"] == "kernel"
+        names = [[call.params["name"] for call in pallas_calls(
+            es.engine._generation_step, es.state, es.table.data)]
+            for es in (ref_es, kern)]
+        assert names[0] == []
+        assert names[1].count("selective_scan") == 2
+        assert names[1].count("causal_attention") == 2
+        ref_es.state, want = ref_es.engine.generation_step(ref_es.state)
+        kern.state, got = kern.engine.generation_step(kern.state)
+        np.testing.assert_allclose(got["fitness"], want["fitness"],
+                                   atol=1e-4)
+        assert np.isfinite(np.asarray(got["fitness"])).all()
+
+    def test_shapes_the_scans_rule_refuses_keep_the_xla_form_in_the_scope(
+            self, devices8, kernel_attention):
+        """The tiny model (``d_inner`` 64 over 21 positions) inside the
+        forced scope: attention in the kernel, the scans in the
+        ``lax.scan``, and the engine says which."""
+        with kernel_attention():
+            es = _sambay_es(devices8[:1], 1)
+        assert (es.engine.attention_form, es.engine.scan_form) == (
+            "kernel", "xla")
+        assert es.run_manifest()["config"]["scan_form"] == "xla"
+
     def test_another_model_states_none_of_it(self, devices8):
         import loop_tiny
-        from estorch_tpu.envs import TokenScoreEnv
         from estorch_tpu.models import LoopedLM
 
         es = _sambay_es(devices8[:1], 1, policy=LoopedLM,
@@ -731,6 +776,10 @@ class TestThroughTheShardedEngine:
         assert "kv_shared_by" not in es.run_manifest()["config"]
         assert es.engine._attention_windows == {"causal": None}
         assert es.engine.attention_form_by_kind == "causal:xla"
+        # no scan stated: no form, no gauge, null in the manifest
+        assert es.engine.scan_form is None
+        assert es.obs.counters.get("scan_form", None) is None
+        assert es.run_manifest()["config"]["scan_form"] is None
 
     def test_the_reference_scores_the_engines_members(self, ref, devices8):
         """Generation 0 of the engine against the reference through the

@@ -709,6 +709,53 @@ def test_kernel_form_books_its_kernel_to_attn(latent, v5e_chip):
         for name, stack in kernels), kernels
 
 
+def _sambay_engine_on(chip, **policy_over):
+    """A small SambaY decoder's ES on the CPU and its sharded engine on the
+    one-device mesh of ``chip``, built as ``algo/es.py`` builds it."""
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import SambaYLM
+    from estorch_tpu.parallel.mesh import hyperscale_mesh
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    es = _es(
+        policy=SambaYLM, population_size=4, sigma=0.02,
+        policy_kwargs={**dict(
+            vocab_size=256, hidden_size=256, intermediate_size=256,
+            num_attention_heads=4, num_key_value_heads=2, published_layers=8,
+            layer_indices=(0, 1, 4, 5, 6, 7), sliding_window=64,
+            mamba_d_state=4, mamba_dt_rank=8, scan_chunk=16,
+            attention_block=128, head_block=128), **policy_over},
+        agent_kwargs={"env": TokenScoreEnv(
+            vocab_size=256, seq_len=256, corpus_sequences=4)},
+        shard_params=True, low_rank=1, noise_mode="table",
+        compute_dtype="bfloat16", table_size=1 << 18,
+        device=jax.devices()[:1])
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    engine = ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=1, devices=[chip]),
+        partition_rules=es._partition_rules, noise_mode="table",
+        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        attention_widths=es.module.attention_widths,
+        head_width=es.module.head_width,
+        scan_widths=es.module.scan_widths,
+        float32_leaves=es.module.float32_leaves,
+        attention_windows=es.module.attention_windows,
+        attention_kv_heads=es.module.num_key_value_heads,
+        dense_noise_leaves=es.module.dense_noise_leaves)
+    return es, engine
+
+
+def _compiled_generation(es, engine) -> str:
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        es.state, engine.state_shardings)
+    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
+                                 sharding=engine._repl)
+    return engine._generation_step.lower(state, table).compile().as_text()
+
+
 def test_kernel_form_books_a_differential_pairs_kernel_by_its_kind(v5e_chip):
     """A small SambaY decoder with differential heads of 64 on a
     one-device TPU mesh: the rule takes its pairs (two score heads a column
@@ -719,52 +766,17 @@ def test_kernel_form_books_a_differential_pairs_kernel_by_its_kind(v5e_chip):
     ``of.window``, whose calls stay in the XLA form: the device trace books
     each to ``sambay.full_attn_share`` / ``sambay.window_attn_share`` as
     before."""
-    from estorch_tpu.envs import TokenScoreEnv
-    from estorch_tpu.models import SambaYLM
-    from estorch_tpu.parallel.mesh import hyperscale_mesh
-    from estorch_tpu.parallel.sharded import ShardedESEngine
-
-    es = _es(
-        policy=SambaYLM, population_size=4, sigma=0.02,
-        policy_kwargs=dict(
-            vocab_size=256, hidden_size=256, intermediate_size=256,
-            num_attention_heads=4, num_key_value_heads=2, published_layers=8,
-            layer_indices=(0, 1, 4, 5, 6, 7), sliding_window=64,
-            mamba_d_state=4, mamba_dt_rank=8, scan_chunk=16,
-            attention_block=128, head_block=128),
-        agent_kwargs={"env": TokenScoreEnv(
-            vocab_size=256, seq_len=256, corpus_sequences=4)},
-        shard_params=True, low_rank=1, noise_mode="table",
-        compute_dtype="bfloat16", table_size=1 << 18,
-        device=jax.devices()[:1])
+    es, engine = _sambay_engine_on(v5e_chip)
     assert es.module.attention_widths == (64, 0, 128)
     assert es.engine.attention_form_by_kind == (
         "window:xla,full_kv:xla,cross:xla")        # a CPU mesh
-    lr_apply, lr_spec = es._perturbed_form(
-        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
-    engine = ShardedESEngine(
-        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
-        es.config, hyperscale_mesh(model_shards=1, devices=[v5e_chip]),
-        partition_rules=es._partition_rules, noise_mode="table",
-        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
-        attention_widths=es.module.attention_widths,
-        head_width=es.module.head_width,
-        float32_leaves=es.module.float32_leaves,
-        attention_windows=es.module.attention_windows,
-        attention_kv_heads=es.module.num_key_value_heads,
-        dense_noise_leaves=es.module.dense_noise_leaves)
     assert engine.attention_form == "kernel"
     assert engine.attention_form_why == (
         "one TPU device, two score heads a column block, whole row blocks; "
         "layers with a window of 64 in the XLA form")
     assert engine.attention_form_by_kind == (
         "window:xla,full_kv:kernel,cross:kernel")
-    state = jax.tree_util.tree_map(
-        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
-        es.state, engine.state_shardings)
-    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
-                                 sharding=engine._repl)
-    text = engine._generation_step.lower(state, table).compile().as_text()
+    text = _compiled_generation(es, engine)
     kernels = [name for line in text.splitlines()
                if "tpu_custom_call" in line and "causal_attention" in line
                for name in re.findall(r'op_name="([^"]*)"', line)]
@@ -776,6 +788,88 @@ def test_kernel_form_books_a_differential_pairs_kernel_by_its_kind(v5e_chip):
     # the windowed layer's scores are XLA's: float32, under its own part
     assert any(PART_PREFIX + "window" in line and "f32[" in line
                and "exponential" in line for line in text.splitlines())
+
+
+@pytest.mark.parametrize("d_inner, d_state, length, a_batched", [
+    (5120, 16, 8192, True), (5120, 16, 8192, False), (640, 8, 512, True),
+], ids=["sambay_cell", "centre", "narrow_blocks"])
+def test_scan_kernel_compiles_for_the_v5e_at_the_cells_shapes(
+        d_inner, d_state, length, a_batched, v5e_chip):
+    """Mosaic accepts the selective scan's kernel at
+    ``phi4-flash-es-8k-1chip``'s shapes (one member of 8,192 steps x 5,120
+    channels x 16 states in float32 through the engine's pair x sign
+    ``vmap``s, ``A`` a perturbed leaf and so batched, or not) and at a width
+    only the narrowest channel block divides, eight states.  ONE custom
+    call; ``x``, ``Δ`` and ``y`` reach and leave it as they are (no padded
+    or transposed copy of a ``[T, d_inner]`` operand anywhere in the
+    program)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops import pallas_scan
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct((1, 1) + shape, jnp.float32,
+                                    sharding=SingleDeviceSharding(v5e_chip))
+
+    def scan(*operands):
+        return pallas_scan.selective_scan(*operands, interpret=False)
+
+    axes = (0, 0, 0 if a_batched else None, 0, 0)
+    a = (on_chip(d_inner, d_state) if a_batched else jax.ShapeDtypeStruct(
+        (d_inner, d_state), jnp.float32,
+        sharding=SingleDeviceSharding(v5e_chip)))
+    compiled = jax.jit(jax.vmap(jax.vmap(scan, in_axes=axes),
+                                in_axes=axes)).lower(
+        on_chip(length, d_inner), on_chip(length, d_inner), a,
+        on_chip(length, d_state), on_chip(length, d_state)).compile()
+    text = compiled.as_text()
+    entry = text.split("ENTRY")[1]
+    calls = [line for line in entry.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "selective_scan" in calls[0]
+    wide = [line for line in entry.splitlines()
+            if re.search(rf"= f32\[(1,1,)?{length},{d_inner}\]\S* "
+                         r"(copy|transpose|pad|fusion)\(", line)]
+    assert wide == [], wide
+    # the program holds its operands and y, and nothing as large beside them
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 4 * length * d_inner // 8
+
+
+def test_kernel_form_books_the_scans_kernel_to_ssm(v5e_chip):
+    """A small SambaY decoder (``d_inner`` 512 over 256 steps: one channel
+    block, one time chunk) on a one-device TPU mesh: the engine resolves
+    ``scan_form`` to "kernel" by itself, and the compiled generation
+    program holds the Mosaic call ``selective_scan`` once a Mamba layer
+    under es.ssm inside es.policy, where the device trace books it
+    (``sambay.ssm_share``, ``sambay.ssm_hbm_util``); no ``while`` loop is
+    left under es.ssm."""
+    es, engine = _sambay_engine_on(v5e_chip)
+    assert es.engine.scan_form == "xla"            # a CPU mesh
+    assert (engine.attention_form, engine.scan_form) == ("kernel", "kernel")
+    text = _compiled_generation(es, engine)
+    kernels = [name for line in text.splitlines()
+               if "tpu_custom_call" in line and "selective_scan" in line
+               for name in re.findall(r'op_name="([^"]*)"', line)]
+    assert len(kernels) == 2, kernels
+    for name in kernels:
+        assert SCOPE.findall(name)[0] == POLICY, name
+        assert SCOPE.findall(name)[-1] == SSM, name
+    assert not [line for line in text.splitlines()
+                if " while(" in line and SCOPE.findall(line)[-1:] == [SSM]]
+
+
+def test_a_scan_the_rule_refuses_stays_a_loop_on_the_chip(v5e_chip):
+    """The same decoder with 24 states a channel: attention and head in
+    their kernels, the scans in the ``lax.scan`` (a ``while`` under
+    es.ssm), and the engine says so."""
+    es, engine = _sambay_engine_on(v5e_chip, mamba_d_state=24)
+    assert (engine.attention_form, engine.scan_form) == ("kernel", "xla")
+    text = _compiled_generation(es, engine)
+    assert not [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "selective_scan" in line]
+    assert [line for line in text.splitlines()
+            if " while(" in line and SCOPE.findall(line)[-1:] == [SSM]]
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
