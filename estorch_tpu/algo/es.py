@@ -591,6 +591,14 @@ class ES:
                 str(n) for n in self.mesh.devices.shape))
             self.obs.counters.gauge("param_bytes_per_chip",
                                     self.engine.param_bytes_per_chip)
+            # "gathered": every chip reads the whole compute-dtype centre
+            # and evaluates whole members; "split": the centre stays
+            # sharded like the state (parallel/sharded.py::centre_form_why)
+            self.obs.counters.gauge("centre_form", self.engine.centre_form)
+            self.obs.counters.gauge("centre_form_why",
+                                    self.engine.centre_form_why)
+            self.obs.counters.gauge("centre_bytes_per_chip",
+                                    self.engine.centre_bytes_per_chip)
         for name, value in self._sequence_facts().items():
             self.obs.counters.gauge(name, value)
         # analytic FLOPs/bytes model of this configuration (obs/profile/):
@@ -1266,6 +1274,12 @@ class ES:
                 [int(s) for s in self.mesh.devices.shape]))
             cfg["partition_rules"] = partition_rules_to_json(
                 self.engine.partition_rules)
+            # how the perturbed form's forward holds the centre ("gathered":
+            # whole on every chip, whole members a chip | "split": sharded
+            # like the state), the condition that decided, and its bytes
+            cfg["centre_form"] = self.engine.centre_form
+            cfg["centre_form_why"] = self.engine.centre_form_why
+            cfg["centre_bytes_per_chip"] = self.engine.centre_bytes_per_chip
         mesh = getattr(self, "mesh", None)
         devices = list(mesh.devices.flat) if mesh is not None else None
         return collect_manifest(config=cfg, devices=devices, extra=extra)

@@ -1,4 +1,6 @@
-"""Param-sharded hyperscale ES engine — no tree ever whole on one device.
+"""Param-sharded hyperscale ES engine — no tree of the STATE ever whole on
+one device (the forward's compute-dtype copy of the centre is, where it
+fits: ``centre_form`` below).
 
 The fused engine (parallel/engine.py) replicates the full param tree on
 every device, so the largest trainable policy is capped by one chip's HBM
@@ -42,13 +44,28 @@ Two evaluation bodies, chosen by the engine from what it observes
 - ``perturbed``: low-rank table noise on a policy with a perturbed forward
   (models/perturbed.py).  No member's weights are ever built: the centre
   enters the member ``vmap`` un-batched (cast to the compute dtype once a
-  generation) and sharded over ``model``, the factor rows of each
-  antithetic pair are sliced from the table at the pair's offset, batched
-  over pairs and sharded over ``pop``, and both signs of a pair read one
-  factor row.  Every projection is one population-wide matmul plus a
-  rank-r correction; the update is one contraction per leaf over the
-  pairs' factors, sharded like the leaf.  This is the form a model too wide
-  for a perturbed copy per member needs.
+  generation), the factor rows of each antithetic pair are sliced from the
+  table at the pair's offset and batched over pairs, and both signs of a
+  pair read one factor row.  Every projection is one population-wide
+  matmul plus a rank-r correction; the update is one contraction per leaf
+  over the pairs' factors, sharded like the leaf.  This is the form a model
+  too wide for a perturbed copy per member needs.  Where its centre lies
+  is ``centre_form`` (:func:`centre_form_why`, decided at build from what
+  a chip holds, no option):
+
+  - ``gathered``, where the whole compute-dtype centre fits a chip beside
+    the chip's share of the state: one all-gather a leaf a generation, of
+    the leaf already cast; the pairs split over EVERY chip of the mesh;
+    each chip evaluates whole members, so no collective runs between two
+    projections and no float32 sum is reassociated across chips.
+  - ``split``, where it does not (and on a ``model`` axis of 1, where
+    there is nothing to gather): the centre stays sharded over ``model``
+    like the state, the pairs over ``pop``, and GSPMD runs every
+    projection tensor-parallel (all-gathers of split inputs, combines of
+    row-split projections' partial products).
+
+  The state, the optimizer, the update and the emitted best member are
+  sharded over ``model`` in both.
 - ``materialised``: ``leaf[None] + σ·s·ε`` per member of a chunk, for
   full-rank noise and in-program low-rank noise on small trees.
 
@@ -95,6 +112,68 @@ NOISE_MODES = ("program", "table")
 # leaf is applied to at once, the leaf's output width], a device's share)
 # is held under this many bytes
 ACTIVATION_BUDGET_BYTES = 256 * 2**20
+# a chip's memory where the platform does not say (the CPU's virtual
+# devices, a described TPU): a v5e's 16 GB, which the centre form's rule
+# holds the gathered centre against
+CHIP_MEMORY_BYTES = 16 * 10**9
+
+
+def centre_form_why(forward_form: str, model_shards: int, centre_bytes: int,
+                    held_bytes: int, chip_bytes: int) -> tuple[str, str]:
+    """``("gathered" | "split", why)``: the layout of the centre the
+    perturbed form's forward reads, on a ``(pop, model)`` mesh whose
+    ``model`` axis is ``model_shards`` wide.  ``gathered``: every chip holds
+    the WHOLE centre in its compute dtypes (one all-gather a leaf a
+    generation) and evaluates whole members, the pairs split over every
+    chip of the mesh, so that no collective runs between two projections.
+    It is taken when, and only when, ALL hold: the form is ``perturbed``
+    (the materialised form builds members' weights, a shard a chip); the
+    ``model`` axis is wider than 1 (else every leaf is whole already and
+    there is nothing to gather); ``centre_bytes``, the whole centre in its
+    compute dtypes, fits a chip of ``chip_bytes`` beside the ``held_bytes``
+    it holds anyway (its share of the float32 state and of the best member,
+    a chunk's activation budget).  Otherwise ``split``: the centre stays
+    sharded over ``model`` like the state and GSPMD runs every projection
+    tensor-parallel, the form for a centre one chip cannot hold.  ``why``
+    names the first condition that fails (the engine logs it; the run
+    manifest and a gauge carry it)."""
+    failed = [why for ok, why in (
+        (forward_form == "perturbed",
+         f"the {forward_form} form builds members' weights, a shard a chip"),
+        (model_shards > 1,
+         "the mesh's model axis is 1: every leaf is whole on its chip"),
+        (centre_bytes + held_bytes <= chip_bytes,
+         f"the centre in its compute dtypes, {centre_bytes} bytes, does not "
+         f"fit a chip of {chip_bytes} bytes beside the {held_bytes} it "
+         "holds (state, best member, a chunk's activations)"),
+    ) if not ok]
+    if failed:
+        return "split", failed[0]
+    return "gathered", (
+        f"the centre in its compute dtypes, {centre_bytes} bytes, fits a "
+        f"chip of {chip_bytes} bytes beside the {held_bytes} it holds: "
+        f"whole members on each of the mesh's chips, no {MODEL_AXIS!r} axis "
+        "in the forward")
+
+
+def _chip_memory_bytes(device) -> int:
+    """What the runtime lets a program use of ``device``'s memory, where
+    the platform reports it; ``CHIP_MEMORY_BYTES`` where it does not."""
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError:  # a described device, not attached
+        stats = None
+    return int((stats or {}).get("bytes_limit", CHIP_MEMORY_BYTES))
+
+
+def _bytes_per_chip(shapes, shardings) -> int:
+    """Bytes ONE device holds of a tree of ``shapes`` under ``shardings``."""
+    return sum(
+        math.prod(sh.shard_shape(tuple(x.shape))) * jnp.dtype(x.dtype).itemsize
+        for x, sh in zip(
+            jax.tree_util.tree_leaves(shapes),
+            jax.tree_util.tree_leaves(
+                shardings, is_leaf=lambda s: isinstance(s, NamedSharding))))
 
 
 def _rng_scope(partitionable: bool):
@@ -368,6 +447,35 @@ class ShardedESEngine:
             for sh in self._param_sharding_leaves
         ]
 
+        # ---- the layout of the centre the perturbed form's forward reads:
+        # "gathered" | "split", resolved once, here, from the form, the mesh
+        # and what a chip holds (run manifest + telemetry gauges)
+        self.centre_bytes = sum(
+            size * jnp.dtype(dtype).itemsize
+            for size, dtype in zip(self.leaf_sizes, self._leaf_dtypes))
+        self.centre_form, self.centre_form_why = centre_form_why(
+            self.forward_form, self.model_shards, self.centre_bytes,
+            # the float32 state's share, the best member the program emits
+            # and the one the host holds, a chunk's widest activations
+            _bytes_per_chip(opt_shape, self.opt_shardings)
+            + 3 * self.param_bytes_per_chip + ACTIVATION_BUDGET_BYTES,
+            _chip_memory_bytes(mesh.devices.flat[0]))
+        gathered = self.centre_form == "gathered"
+        # what the pairs of a chunk are split over, and the shardings of
+        # the compute-dtype centre: every chip of the mesh and whole leaves
+        # where the centre is gathered; ``pop`` and the state's own where
+        # it stays split
+        self._pair_axes = (POP_AXIS, MODEL_AXIS) if gathered else POP_AXIS
+        self._pair_shards = self.n_devices if gathered else self.pop_shards
+        self._centre_shardings = (
+            [self._repl] * len(self.leaf_shapes) if gathered
+            else self._param_sharding_leaves)
+        if self.forward_form == "perturbed":
+            import logging
+
+            logging.getLogger(__name__).info(
+                "centre_form %s (%s)", self.centre_form, self.centre_form_why)
+
         # ---- population layout (ghost-padded like the replicated path) --
         cfg = config
         if cfg.mirrored:
@@ -384,8 +492,9 @@ class ShardedESEngine:
         chunk_per_shard = _choose_eval_chunk(req, per_shard)
         self.eval_chunk = chunk_per_shard * self.pop_shards
         self.n_eval_chunks = self.members_padded // self.eval_chunk
-        # update reduction chunking over noise rows
-        self.rows_padded = padded_count(self.rows_global, self.pop_shards)
+        # update reduction chunking over noise rows (the rows are padded to
+        # what the perturbed form splits its pairs over)
+        self.rows_padded = padded_count(self.rows_global, self._pair_shards)
         rows_per_shard = self.rows_padded // self.pop_shards
         greq = max(1, cfg.grad_chunk // self.pop_shards) if cfg.grad_chunk > 0 else 0
         gchunk_per_shard = _choose_eval_chunk(greq, rows_per_shard)
@@ -572,16 +681,19 @@ class ShardedESEngine:
     def _widest_activation(self) -> int:
         """Floats of the widest activation ONE member holds on a device:
         over the factored leaves, the positions the leaf is applied to at
-        once times its output width over ``model``.  A leaf sees the whole
+        once times its output width, over ``model`` where the centre stays
+        split (a member is whole on its chip where it is gathered).  A leaf
+        sees the whole
         horizon unless the policy runs it in blocks of positions
         (``leaf_rows``: an untied head is ``[head_block, vocab]``, never
         ``[horizon, vocab]``).  A stacked expert leaf sees the rows routed
         to its experts (``leaf_rows_per_token`` a position), whole on every
         device: its expert axis is what ``model`` divides."""
         horizon = self.config.horizon
+        across = 1 if self.centre_form == "gathered" else self.model_shards
         return max([
             min(horizon, self._leaf_rows.get(self.leaf_paths[i], horizon))
-            * -(-n // self.model_shards)
+            * -(-n // across)
             for i, _, n, _, _ in self.lr_spec.lr_leaves] + [
             math.ceil(horizon * self._leaf_rows_per_token.get(
                 self.leaf_paths[i], 1.0)) * n
@@ -596,17 +708,18 @@ class ShardedESEngine:
         scores it is chosen from, as the model states them:
         ``selection_bytes``) under
         ``ACTIVATION_BUDGET_BYTES``.  A chunk holds whole pairs and a
-        multiple of ``pop_shards`` rows, with one exception: where ONE
+        multiple of the rows' shards (``pop_shards``; every device of the
+        mesh where the centre is gathered), with one exception: where ONE
         member's widest activation is over the budget by itself, a chunk is
         one pair whose two signs are evaluated in turn (``signs_in_turn``:
         half the activations of a pair at once, the pair still reads one
         factor row)."""
         cfg = self.config
         per_row = 2 if cfg.mirrored else 1
-        rows_per_shard = self.rows_padded // self.pop_shards
+        rows_per_shard = self.rows_padded // self._pair_shards
         self.signs_in_turn = False
         if cfg.eval_chunk > 0:
-            req = max(1, cfg.eval_chunk // (per_row * self.pop_shards))
+            req = max(1, cfg.eval_chunk // (per_row * self._pair_shards))
         else:
             per_member = max(4 * self._widest_activation(),
                              self._selection_bytes)
@@ -614,7 +727,7 @@ class ShardedESEngine:
             self.signs_in_turn = (cfg.mirrored
                                   and per_member > ACTIVATION_BUDGET_BYTES)
         rows_chunk_per_shard = _choose_eval_chunk(req, rows_per_shard)
-        self.pair_chunk = rows_chunk_per_shard * self.pop_shards
+        self.pair_chunk = rows_chunk_per_shard * self._pair_shards
         self.n_pair_chunks = self.rows_padded // self.pair_chunk
         # members evaluated at once, and how often
         in_turn = 2 if self.signs_in_turn else 1
@@ -624,17 +737,19 @@ class ShardedESEngine:
 
     def _eval_all_perturbed(self, state, center, noise_rows, rkey):
         """Evaluate every member without building any member's weights:
-        ``center`` (the compute-dtype copy of the params, sharded like them)
+        ``center`` (the compute-dtype copy of the params: sharded like them
+        in the ``split`` centre form, whole on every chip in ``gathered``)
         is closed over un-batched, ``noise_rows [rows_padded, noise_dim]``
-        are unpacked per chunk into factor trees batched over pairs, and
-        the two signs of a pair read the one tree."""
+        are unpacked per chunk into factor trees batched over pairs (split
+        over ``pop``; over every chip of the mesh in ``gathered``), and the
+        two signs of a pair read the one tree."""
         cfg = self.config
         with stage(SAMPLE):
             member_keys = jax.random.split(rkey, self.rows_global)
             keys = jnp.take(member_keys, self._padded_rows(), axis=0)
             signs = (jnp.asarray([1.0, -1.0], jnp.float32) if cfg.mirrored
                      else jnp.ones((1,), jnp.float32))
-        pair_rows = NamedSharding(self.mesh, P(POP_AXIS, None))
+        pair_rows = NamedSharding(self.mesh, P(self._pair_axes, None))
 
         def chunk_body(noise_c, keys_c):
             with stage(NOISE):
@@ -651,7 +766,7 @@ class ShardedESEngine:
                     return jax.lax.map(sign_eval, signs)
                 return jax.vmap(sign_eval)(signs)
 
-            res = jax.vmap(pair_eval, spmd_axis_name=POP_AXIS)(
+            res = jax.vmap(pair_eval, spmd_axis_name=self._pair_axes)(
                 noise_tree, keys_c)
             load = res.extras[0] if self._expert_load else None
             chosen = res.extras[1] if self._selected_pairs else None
@@ -691,9 +806,14 @@ class ShardedESEngine:
         one read of the noise, shared by evaluation, update and best-member
         reconstruction.  Small (rows × noise_dim), so it is replicated."""
         with stage(NOISE):
-            return jax.vmap(lambda o: jax.lax.dynamic_slice(
+            rows = jax.vmap(lambda o: jax.lax.dynamic_slice(
                 table_data, (o,), (self.noise_dim,)))(
                     offsets[self._padded_rows()])
+            if self.centre_form == "gathered":
+                # said, not left to propagation: the chunks' rows are then
+                # slices a chip already has, and the update reads the same
+                rows = jax.lax.with_sharding_constraint(rows, self._repl)
+            return rows
 
     def _padded_rows(self):
         """Row index per padded row: ghost rows repeat the last real one."""
@@ -880,12 +1000,14 @@ class ShardedESEngine:
         if perturbed:
             # the pairs' rows, read once for evaluation, update and best
             noise_rows = self._noise_rows(offsets, table_data)
+            # cast first, then laid out as the centre form says: a gathered
+            # leaf crosses the chips in its compute dtype
             with stage(PERTURB):
                 center = jax.tree_util.tree_unflatten(self._treedef, [
                     jax.lax.with_sharding_constraint(x.astype(dtype), sh)
                     for x, dtype, sh in zip(
                         jax.tree_util.tree_leaves(state.params),
-                        self._leaf_dtypes, self._param_sharding_leaves)])
+                        self._leaf_dtypes, self._centre_shardings)])
             fitness, bc, steps, expert_load, selected_pairs = (
                 self._eval_all_perturbed(state, center, noise_rows, rkey))
         else:
@@ -1018,13 +1140,19 @@ class ShardedESEngine:
     def param_bytes_per_chip(self) -> int:
         """Float32 bytes of the centre one device holds, from the resolved
         shardings (optimizer moments are param-shaped multiples of it)."""
-        total = 0
-        for shape, sh in zip(self.leaf_shapes, self._param_sharding_leaves):
-            n = 4
-            for d in sh.shard_shape(shape):
-                n *= d
-            total += n
-        return total
+        return _bytes_per_chip(
+            [jax.ShapeDtypeStruct(shape, jnp.float32)
+             for shape in self.leaf_shapes], self._param_sharding_leaves)
+
+    @property
+    def centre_bytes_per_chip(self) -> int:
+        """Bytes of the compute-dtype centre one device holds while the
+        perturbed form's forward runs: the whole of it where the centre is
+        gathered, the device's shard where it stays split."""
+        return _bytes_per_chip(
+            [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in
+             zip(self.leaf_shapes, self._leaf_dtypes)],
+            self._centre_shardings)
 
     def memory_facts(self) -> dict:
         """XLA per-device byte facts of the compiled generation program
@@ -1079,8 +1207,13 @@ class ShardedESEngine:
 
     def sharding_report(self) -> dict[str, str]:
         """{leaf path: resolved spec} — what the rules did, incl. any
-        divisibility fallbacks (manifests, tests, docs examples)."""
+        divisibility fallbacks (manifests, tests, docs examples) — and,
+        under ``centre_form``, how the forward's copy of the centre lies."""
         params_shape = jax.eval_shape(
             self.spec.unravel, jax.ShapeDtypeStruct((self.spec.dim,), jnp.float32))
-        return sharding_summary(params_shape, self.param_shardings,
-                                self.partition_rules)
+        report = sharding_summary(params_shape, self.param_shardings,
+                                  self.partition_rules)
+        report["centre_form"] = (
+            f"{self.centre_form}: {self.centre_form_why}; "
+            f"{self.centre_bytes_per_chip} bytes of it a chip")
+        return report

@@ -53,6 +53,34 @@ def devices8():
     return devs
 
 
+@pytest.fixture(params=["gathered", "split"])
+def centre_form(request, monkeypatch):
+    """Both layouts of the centre the sharded engine's perturbed form
+    reads, for engines built on a mesh whose ``model`` axis is wider than
+    1.  The rule (``parallel/sharded.py::centre_form_why``) reads a chip's
+    memory from ``CHIP_MEMORY_BYTES`` wherever the platform reports none,
+    as on the suite's CPU devices: a chip with no room keeps the centre
+    split; the small trees of the suite fit any other."""
+    from estorch_tpu.parallel import sharded
+
+    if request.param == "split":
+        monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 0)
+    return request.param
+
+
+def collectives(compiled_text) -> list:
+    """``[(kind, dtype, shape)]`` of every collective in the text of a
+    compiled program or of one of its computations (the first array of a
+    collective that moves a tuple)."""
+    import re
+
+    return [(kind, dtype, tuple(int(d) for d in dims.split(",") if d))
+            for dtype, dims, kind in re.findall(
+                r"= \(?(\w+)\[([\d,]*)\]\S* (all-gather|all-reduce|"
+                r"reduce-scatter|collective-permute|all-to-all)"
+                r"(?:-start)?\(", compiled_text)]
+
+
 def materialised(es):
     """``es`` with its engine rebuilt WITHOUT a decomposed_apply: the
     materialized-weights path, which no public option selects for a mirrored

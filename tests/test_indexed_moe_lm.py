@@ -807,12 +807,15 @@ class TestThroughTheShardedEngine:
 
     @pytest.mark.parametrize("pop, model", [(2, 4), (1, 2)])
     def test_mesh_shapes_match_one_device(self, one_device, devices8, pop,
-                                          model):
+                                          model, centre_form):
         """The same fitness, parameters and counts on (2, 4) and (1, 2)
         virtual meshes as on one device, in the XLA form."""
         es = _es(devices8[:pop * model], model)
         assert es.engine.forward_form == "perturbed"
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
+        # both layouts of the centre; nothing to gather on a model axis of 1
+        assert es.engine.centre_form == (
+            centre_form if model > 1 else "split")
         assert es.engine.attention_form == "xla"
         report = es.engine.sharding_report()
         assert report["layer_01/moe/experts/gate"].startswith(

@@ -512,10 +512,13 @@ class TestThroughTheShardedEngine:
 
     @pytest.mark.parametrize("pop, model", [(2, 2), (1, 4), (4, 1)])
     def test_mesh_shapes_match_one_device(self, one_device, devices8, pop,
-                                          model):
+                                          model, centre_form):
         es = _loop_es(devices8[:4], model)
         assert es.engine.forward_form == "perturbed"
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
+        # both layouts of the centre; nothing to gather on a model axis of 1
+        assert es.engine.centre_form == (
+            centre_form if model > 1 else "split")
         np.testing.assert_array_equal(
             es.engine.all_pair_offsets(es.state), one_device["offsets"])
         es.train(2, verbose=False)
@@ -639,15 +642,30 @@ class TestChunkRule:
         assert (es.engine.pair_chunk, es.engine.n_pair_chunks) == (1, 4)
         es.train(1, verbose=False)
         assert np.isfinite(es.history[0]["reward_mean"])
+        # a member is whole on its chip where the centre is gathered, and
+        # ``model`` divides its widths where the centre stays split (a
+        # chip with no room for it)
+        whole = _loop_es(devices8[:4], 2)
+        assert whole.engine.centre_form == "gathered"
+        assert whole.engine._widest_activation() == 21 * 48
+        monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 0)
         split = _loop_es(devices8[:4], 2)
+        assert split.engine.centre_form == "split"
         assert split.engine._widest_activation() == 21 * 24
 
-    def test_a_tied_head_reads_the_embedding_as_before(self, devices8):
+    @pytest.mark.parametrize("centre_form, across", [("gathered", 1),
+                                                     ("split", 2)])
+    def test_a_tied_head_reads_the_embedding_as_before(
+            self, devices8, monkeypatch, centre_form, across):
         import lm_tiny
         from estorch_tpu.envs import TokenScoreEnv
+        from estorch_tpu.parallel import sharded
 
+        if centre_form == "split":
+            monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 0)
         es = _loop_es(devices8[:4], 2, policy=HybridLM,
                       policy_kwargs=lm_tiny.TINY,
                       agent_kwargs={"env": TokenScoreEnv(**lm_tiny.ENV)})
+        assert es.engine.centre_form == centre_form
         assert es.engine._leaf_rows == {}
-        assert es.engine._widest_activation() == 21 * 64 // 2
+        assert es.engine._widest_activation() == 21 * 64 // across

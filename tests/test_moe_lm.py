@@ -860,12 +860,15 @@ class TestThroughTheShardedEngine:
 
     @pytest.mark.parametrize("pop, model", [(2, 4), (1, 4), (2, 2)])
     def test_mesh_shapes_match_one_device(self, one_device, devices8, pop,
-                                          model):
+                                          model, centre_form):
         """The stacked leaves' expert axis over ``model`` (4 experts over 4
         or 2 devices), the same fitness and parameters as on one device."""
         es = _moe_es(devices8[:pop * model], model)
         assert es.engine.forward_form == "perturbed"
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
+        # both layouts of the centre; nothing to gather on a model axis of 1
+        assert es.engine.centre_form == (
+            centre_form if model > 1 else "split")
         report = es.engine.sharding_report()
         assert report["layer_01/moe/experts/gate"].startswith(
             "PartitionSpec('model'")
@@ -952,7 +955,8 @@ class TestThroughTheShardedEngine:
 
 
 class TestChunkRule:
-    def test_a_stacked_leaf_counts_the_rows_routed_to_it(self, devices8):
+    def test_a_stacked_leaf_counts_the_rows_routed_to_it(self, devices8,
+                                                         monkeypatch):
         """The widest activation: a stacked expert leaf sees ``top_k x
         held / total`` of the positions (with the layer's margin), not
         every position, and its width is whole on every device."""
@@ -969,7 +973,17 @@ class TestChunkRule:
             "expert_group_rank": 0})
         # every position, top_k times over: ceil(21 x 3.75) x 32 = 2528
         assert uncut.engine._widest_activation() == 79 * 32
+        # the centre gathered: whole members, as on one device; the centre
+        # split (a chip with no room for it): ``model`` divides kv_b's
+        # width and the stacked leaves' rows are then the most
+        from estorch_tpu.parallel import sharded
+
+        whole = _moe_es(devices8[:4], 4)
+        assert whole.engine.centre_form == "gathered"
+        assert whole.engine._widest_activation() == 21 * 56
+        monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 0)
         split = _moe_es(devices8[:4], 4)
+        assert split.engine.centre_form == "split"
         assert split.engine._widest_activation() == 20 * 32
 
     def test_a_name_that_is_no_leaf_is_refused(self, devices8):

@@ -641,10 +641,13 @@ class TestThroughTheShardedEngine:
 
     @pytest.mark.parametrize("pop, model", [(2, 4), (1, 2)])
     def test_mesh_shapes_match_one_device(self, one_device, devices8, pop,
-                                          model):
+                                          model, centre_form):
         es = _sambay_es(devices8[:pop * model], model)
         assert es.engine.forward_form == "perturbed"
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
+        # both layouts of the centre; nothing to gather on a model axis of 1
+        assert es.engine.centre_form == (
+            centre_form if model > 1 else "split")
         report = es.engine.sharding_report()
         assert report["layer_00/mamba/in_proj"] == (
             "PartitionSpec(None, 'model')")

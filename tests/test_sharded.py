@@ -39,6 +39,8 @@ from estorch_tpu.parallel import (
 )
 from estorch_tpu.parallel.mesh import sharding_summary
 
+from conftest import collectives
+
 
 def _mlp_setup():
     def init_params(key):
@@ -630,6 +632,63 @@ def _lm_es(devices, model_shards, **over):
     return ES(**kw)
 
 
+class TestCentreFormRule:
+    """``centre_form_why``: the layout of the centre the perturbed form's
+    forward reads, from what the engine observes."""
+
+    @pytest.mark.parametrize(
+        "form, model_shards, centre, held, chip, want, reason", [
+            ("perturbed", 2, 1_900, 9_800, 16_000, "gathered",
+             "fits a chip of 16000 bytes beside the 9800"),
+            ("perturbed", 2, 1_900, 14_200, 16_000, "split",
+             "1900 bytes, does not fit a chip of 16000 bytes beside the "
+             "14200"),
+            ("perturbed", 1, 1_900, 9_800, 16_000, "split",
+             "model axis is 1"),
+            ("materialised", 2, 1_900, 9_800, 16_000, "split",
+             "the materialised form builds members' weights"),
+            ("perturbed", 4, 10, 90, 100, "gathered", "10 bytes, fits"),
+            ("perturbed", 4, 11, 90, 100, "split", "11 bytes, does not fit"),
+        ], ids=["fits", "does-not-fit", "model-axis-1", "materialised",
+                "to-the-byte", "a-byte-over"])
+    def test_the_rule(self, form, model_shards, centre, held, chip, want,
+                      reason):
+        from estorch_tpu.parallel.sharded import centre_form_why
+
+        got, why = centre_form_why(form, model_shards, centre, held, chip)
+        assert got == want
+        assert reason in why
+
+    def test_what_the_engine_hands_the_rule(self, devices8, monkeypatch):
+        """The whole centre in its compute dtypes against a chip's share of
+        the float32 state (params and Adam's two moments), two best members
+        and the activation budget."""
+        from estorch_tpu.parallel import sharded
+
+        es = _lm_es(devices8[:4], 2, compute_dtype="bfloat16")
+        eng = es.engine
+        assert eng.centre_form == "gathered"
+        assert eng.centre_bytes == 2 * es._spec.dim
+        assert eng.centre_bytes_per_chip == eng.centre_bytes
+        held = 5 * eng.param_bytes_per_chip + 4  # Adam's count: one int32
+        assert f"{eng.centre_bytes} bytes, fits" in eng.centre_form_why
+        assert (f"beside the {held + sharded.ACTIVATION_BUDGET_BYTES} it "
+                "holds") in eng.centre_form_why
+        # one byte short of all of it: split, with the same numbers
+        monkeypatch.setattr(
+            sharded, "CHIP_MEMORY_BYTES",
+            eng.centre_bytes + held + sharded.ACTIVATION_BUDGET_BYTES - 1)
+        tight = _lm_es(devices8[:4], 2, compute_dtype="bfloat16").engine
+        assert tight.centre_form == "split"
+        assert "does not fit" in tight.centre_form_why
+        assert tight.centre_bytes_per_chip < tight.centre_bytes
+        # the materialised form has no centre to gather
+        plain = _lm_es(devices8[:4], 2, low_rank=0, noise_mode="program")
+        assert plain.engine.forward_form == "materialised"
+        assert plain.engine.centre_form == "split"
+        assert "materialised" in plain.engine.centre_form_why
+
+
 class TestPerturbedForm:
     @pytest.fixture(scope="class")
     def one_device(self, devices8):
@@ -641,11 +700,15 @@ class TestPerturbedForm:
 
     @pytest.mark.parametrize("pop, model", [(2, 2), (1, 4), (4, 1)])
     def test_mesh_shapes_match_one_device(self, one_device, devices8, pop,
-                                          model):
+                                          model, centre_form):
         """Fitness and updated parameters, allclose at f32: GSPMD's
-        all-reduces reassociate float32 sums, nothing else differs."""
+        all-reduces reassociate float32 sums, nothing else differs (and in
+        the gathered form only the update's)."""
         es = _lm_es(devices8[:4], model)
         assert es.engine.forward_form == "perturbed"
+        # nothing to gather on a model axis of 1
+        want_form = centre_form if model > 1 else "split"
+        assert es.engine.centre_form == want_form
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
         np.testing.assert_array_equal(
             es.engine.all_pair_offsets(es.state), one_device["offsets"])
@@ -663,10 +726,25 @@ class TestPerturbedForm:
         assert gauges.get("noise_rows_per_generation") == 4
         assert gauges.get("tokens_per_generation") == 8 * 21
         assert 0 < gauges.get("param_bytes_per_chip") <= 4 * es._spec.dim
+        # which layout ran, why, and the centre's bytes a chip
+        assert cfg["centre_form"] == gauges.get("centre_form") == want_form
+        assert (cfg["centre_form_why"] == gauges.get("centre_form_why")
+                == es.engine.centre_form_why)
+        whole = 4 * es._spec.dim
+        assert (cfg["centre_bytes_per_chip"]
+                == gauges.get("centre_bytes_per_chip")
+                == es.engine.centre_bytes_per_chip)
+        if want_form == "gathered" or model == 1:
+            assert cfg["centre_bytes_per_chip"] == whole
+        else:
+            assert whole / model <= cfg["centre_bytes_per_chip"] < whole
+        assert es.engine.sharding_report()["centre_form"].startswith(
+            f"{want_form}: ")
 
     def test_bfloat16_forward_over_a_float32_centre(self, one_device,
-                                                    devices8):
+                                                    devices8, centre_form):
         es = _lm_es(devices8[:4], 2, compute_dtype="bfloat16")
+        assert es.engine.centre_form == centre_form
         es.train(2, verbose=False)
         params = np.asarray(es.state.params_flat)
         assert params.dtype == np.float32 and np.isfinite(params).all()
@@ -680,14 +758,20 @@ class TestPerturbedForm:
                    jax.tree_util.tree_leaves(es.state.opt_state)
                    if jnp.issubdtype(leaf.dtype, jnp.floating))
 
-    def test_no_member_weights_and_no_whole_tree_gather(self, devices8):
+    def test_no_member_weights_and_no_whole_tree_gather(self, devices8,
+                                                        centre_form):
         """The lowered program holds no ``[members | pairs, m, n]`` array
-        for any factored leaf, and the compiled one gathers no whole
-        leaf."""
-        import re
+        for any factored leaf.  ``split``: the compiled one gathers no whole
+        leaf.  ``gathered``: it gathers every factored leaf the rules split,
+        once and in the compute dtype, and moves NO activation (an array
+        with the sequence's positions in it) between chips."""
+        import lm_tiny
 
-        es = _lm_es(devices8[:4], 2)
+        es = _lm_es(devices8[:4], 2, **(
+            {"compute_dtype": "bfloat16"} if centre_form == "gathered"
+            else {}))
         eng = es.engine
+        assert eng.centre_form == centre_form
         lowered = eng._generation_step.lower(es.state, eng.table.data)
         text = lowered.as_text()
         batch = {eng.pair_chunk, eng.eval_chunk, eng.rows_padded,
@@ -696,12 +780,95 @@ class TestPerturbedForm:
             for lead in batch:
                 for dims in (f"{lead}x{m}x{n}x", f"{lead}x2x{m}x{n}x"):
                     assert f"tensor<{dims}" not in text, dims
-        compiled = lowered.compile().as_text()
-        gathered = re.findall(r"= (\w+)\[([\d,]*)\]\S* all-gather", compiled)
+        moved = collectives(lowered.compile().as_text())
         whole = {tuple(s) for s in eng.leaf_shapes if len(s) == 2}
-        for _, dims in gathered:
-            shape = tuple(int(d) for d in dims.split(",") if d)
-            assert shape not in whole or shape[0] * shape[1] <= 4096, shape
+        gathered = [(dtype, shape) for kind, dtype, shape in moved
+                    if kind == "all-gather"]
+        if centre_form == "split":
+            for _, shape in gathered:
+                assert (shape not in whole
+                        or shape[0] * shape[1] <= 4096), shape
+            return
+        # every leaf is cast to the compute dtype FIRST and constrained whole
+        # after (XLA:CPU widens bfloat16 collectives, so the dtype a gather
+        # carries is read off the TPU's compiler: test_trace_stages.py)
+        import re
+
+        whole_bf16 = re.findall(
+            r"sdy\.sharding_constraint %\d+ <@mesh, \[(?:\{\}(?:, )?)+\]> : "
+            r"tensor<([\dx]+)xbf16>", text)
+        assert sorted(whole_bf16) == sorted(
+            "x".join(str(d) for d in shape) for shape in eng.leaf_shapes)
+        # no collective of any kind carries the sequence's positions
+        positions = lm_tiny.ENV["seq_len"]
+        assert positions not in {m for _, m, n, _, _ in eng.lr_spec.lr_leaves}
+        carried = [(kind, shape) for kind, _, shape in moved
+                   if positions in shape or positions - 1 in shape]
+        assert not carried, carried
+
+    @pytest.mark.parametrize("population", [6, 10])
+    def test_ghost_rows_where_pairs_do_not_divide_the_devices(
+            self, devices8, population):
+        """3 and 5 pairs on four chips, the centre gathered: the rows are
+        padded to the DEVICES (4 and 8), ghost rows repeat the last pair
+        and weigh nothing, so the run is the one-device run."""
+        one = _lm_es(devices8[:1], 1, population_size=population)
+        es = _lm_es(devices8[:4], 2, population_size=population)
+        eng = es.engine
+        assert eng.centre_form == "gathered"
+        pairs = population // 2
+        assert eng.rows_global == pairs
+        assert eng.rows_padded == -(-pairs // 4) * 4
+        assert eng.pair_chunk % 4 == 0
+        assert eng.members_padded == 2 * eng.rows_padded
+        one.train(2, verbose=False)
+        es.train(2, verbose=False)
+        np.testing.assert_allclose(
+            [r["reward_mean"] for r in es.history],
+            [r["reward_mean"] for r in one.history], rtol=2e-6)
+        np.testing.assert_allclose(np.asarray(es.state.params_flat),
+                                   np.asarray(one.state.params_flat),
+                                   atol=1e-5, rtol=0)
+
+    def test_chunks_are_whole_rounds_of_the_devices(self, devices8,
+                                                    monkeypatch):
+        """Gathered: a member's activations are whole on its chip (no
+        division by ``model``) and a chunk is a multiple of the devices."""
+        from estorch_tpu.parallel import sharded
+
+        gathered = _lm_es(devices8[:4], 2, population_size=16).engine
+        monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 0)
+        split = _lm_es(devices8[:4], 2, population_size=16).engine
+        assert (gathered.centre_form, split.centre_form) == (
+            "gathered", "split")
+        assert gathered._widest_activation() > split._widest_activation()
+        one = _lm_es(devices8[:1], 1, population_size=16).engine
+        assert gathered._widest_activation() == one._widest_activation()
+        # a budget of one pair's widest activations: a pair a chip a chunk
+        monkeypatch.setattr(sharded, "ACTIVATION_BUDGET_BYTES",
+                            2 * 4 * gathered._widest_activation())
+        monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 16 * 10**9)
+        tight = _lm_es(devices8[:4], 2, population_size=16).engine
+        assert tight.centre_form == "gathered"
+        assert (tight.pair_chunk, tight.n_pair_chunks, tight.eval_chunk) == (
+            4, 2, 8)
+
+    def test_a_one_by_one_mesh_traces_one_program(self, devices8,
+                                                  monkeypatch):
+        """Nothing to gather on one device: whatever the rule reads of the
+        chip's memory, the lowered program is the same text."""
+        def lowered():
+            es = _lm_es(devices8[:1], 1, compute_dtype="bfloat16")
+            assert es.engine.centre_form == "split"
+            assert "model axis is 1" in es.engine.centre_form_why
+            return es.engine._generation_step.lower(
+                es.state, es.engine.table.data).as_text()
+
+        from estorch_tpu.parallel import sharded
+
+        roomy = lowered()
+        monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 0)
+        assert lowered() == roomy
 
     def test_best_member_stays_sharded_until_it_is_read(self, devices8):
         es = _lm_es(devices8[:4], 2)
@@ -714,8 +881,10 @@ class TestPerturbedForm:
         assert isinstance(flat, np.ndarray) and flat.shape == (es._spec.dim,)
         assert isinstance(es._best, np.ndarray)
 
-    def test_member_params_match_the_emitted_best_member(self, devices8):
+    def test_member_params_match_the_emitted_best_member(self, devices8,
+                                                         centre_form):
         es = _lm_es(devices8[:4], 2)
+        assert es.engine.centre_form == centre_form
         state0_params = np.asarray(es.state.params_flat)
         want = {i: np.asarray(es.engine.member_params(es.state, i))
                 for i in range(8)}

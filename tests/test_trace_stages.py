@@ -582,7 +582,8 @@ def test_head_kernel_compiles_for_the_v5e_at_the_cells_shapes(
     assert not re.search(rf"\[\d+,{w_shape[0]},{w_shape[1]}\]", text)
 
 
-def _looped_engine_on(devices, model_shards, head_dim, length, latent=False):
+def _looped_engine_on(devices, model_shards, head_dim, length, latent=False,
+                      population_size=4):
     """A small looped model's sharded engine on a mesh of described TPU
     ``devices``: its pieces from an ES built on the CPU, as the engine of
     a chip run would get them.  ``latent``: a small sparse-expert model
@@ -595,7 +596,8 @@ def _looped_engine_on(devices, model_shards, head_dim, length, latent=False):
     sizes = dict(vocab_size=256, hidden_size=128, intermediate_size=256,
                  num_attention_heads=2, attention_block=128, head_block=128)
     es = _es(
-        policy=MoELM if latent else LoopedLM, population_size=4, sigma=0.02,
+        policy=MoELM if latent else LoopedLM,
+        population_size=population_size, sigma=0.02,
         policy_kwargs=dict(
             layer_types=("moe",), moe_intermediate_size=64, q_lora_rank=32,
             kv_lora_rank=32, qk_nope_head_dim=head_dim, qk_rope_head_dim=64,
@@ -745,6 +747,79 @@ def _sambay_engine_on(chip, **policy_over):
         attention_kv_heads=es.module.num_key_value_heads,
         dense_noise_leaves=es.module.dense_noise_leaves)
     return es, engine
+
+
+def _collectives_by_computation(compiled_text) -> dict:
+    """``{computation name: [(kind, dtype, shape)]}`` of a compiled program,
+    and under ``"in a loop"`` those of every computation a ``while`` body
+    reaches."""
+    import re
+
+    from conftest import collectives
+
+    blocks = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> )",
+                      compiled_text)
+    found, calls = {}, {}
+    for block in blocks:
+        head = block.split("(", 1)[0].split()
+        name = head[-1].lstrip("%") if head else ""
+        found[name] = collectives(block)
+        calls[name] = set(re.findall(
+            r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", block))
+    reach = set(re.findall(r"body=%([\w.\-]+)", compiled_text))
+    todo = list(reach)
+    while todo:
+        for callee in calls.get(todo.pop(), ()):
+            if callee not in reach:
+                reach.add(callee)
+                todo.append(callee)
+    assert reach, "the program has no loop"
+    found["in a loop"] = [c for name in reach for c in found.get(name, [])]
+    return found
+
+
+def test_a_gathered_centre_crosses_the_chips_once_in_the_compute_dtype(
+        centre_form, v5e_2x2, monkeypatch):
+    """The generation program of a small looped model compiled for a
+    described v5e 2x2, ``(pop 2, model 2)``, eight pairs in two chunks.
+    ``gathered``: every factored leaf the rules split is all-gathered ONCE,
+    whole, in bfloat16 (the cast stays in front of the gather), no float32
+    gather of a whole leaf, and NOTHING crosses the chips inside a loop:
+    the chunk scan evaluates whole members.  ``split``, the same engine
+    with no room on the chip: the loop holds the tensor-parallel forward's
+    collectives over activations."""
+    from estorch_tpu.parallel import sharded
+
+    length = 384  # no leaf has a side of it
+    one = _looped_engine_on(v5e_2x2[:1], 1, 128, length,
+                            population_size=16)[1]
+    # a budget of one pair's widest activations: a pair a chip a chunk
+    monkeypatch.setattr(sharded, "ACTIVATION_BUDGET_BYTES",
+                        2 * 4 * one._widest_activation())
+    es, engine = _looped_engine_on(v5e_2x2, 2, 128, length,
+                                   population_size=16)
+    assert engine.centre_form == centre_form
+    # a pair a chip at full width, or two a ``pop`` shard at half of it
+    assert (engine.pair_chunk, engine.n_pair_chunks) == (4, 2)
+    moved = _collectives_by_computation(_compiled_generation(es, engine))
+    everywhere = [c for name, cs in moved.items() if name != "in a loop"
+                  for c in cs]
+    whole = {shape for shape in engine.leaf_shapes if len(shape) == 2}
+    split_leaves = sorted(
+        engine.leaf_shapes[i] for i, _, _, _, _ in engine.lr_spec.lr_leaves
+        if not engine._param_sharding_leaves[i].is_fully_replicated)
+    assert len(split_leaves) >= 6
+    gathers = [(dtype, shape) for kind, dtype, shape in everywhere
+               if kind == "all-gather" and shape in whole]
+    if centre_form == "split":
+        assert not gathers
+        assert any(length in shape for _, _, shape in moved["in a loop"])
+        return
+    assert sorted(shape for dtype, shape in gathers
+                  if dtype == "bf16") == split_leaves
+    assert not [g for g in gathers if g[0] != "bf16"]
+    assert not moved["in a loop"], moved["in a loop"]
+    assert not [c for c in everywhere if length in c[2]]
 
 
 def _compiled_generation(es, engine) -> str:
