@@ -35,11 +35,13 @@ import numpy as np
 import optax
 
 from ..envs.agent import JaxAgent, collect_reference_batch
+from ..models.perturbed import declaration_of
 from ..models.vbn import capture_reference_stats
 from ..obs.spans import resolve_telemetry
 from ..ops.noise import DEFAULT_TABLE_SIZE, make_noise_table
 from ..ops.params import make_param_spec
-from ..parallel.engine import EngineConfig, ESEngine
+from ..parallel.engine import (EngineConfig, ESEngine, build_fact_gauges,
+                               build_fact_manifest)
 from ..parallel.mesh import population_mesh
 
 
@@ -328,38 +330,7 @@ class ES:
                 partition_rules=self._partition_rules,
                 noise_mode=self._noise_mode,
                 perturbed_apply=lr_apply, lowrank_spec=lr_spec,
-                leaf_rows=getattr(self.module, "leaf_rows", None),
-                # the widths a sequence model's attention is cut by, which
-                # the attention form's rule reads (models/lm_blocks.py);
-                # None for a policy without attention
-                attention_widths=getattr(self.module, "attention_widths",
-                                         None),
-                # the width its next-token head contracts, for the head
-                # form's rule; None for a policy without one
-                head_width=getattr(self.module, "head_width", None),
-                # (d_inner, d_state) of its selective scans, for the scan
-                # form's rule; None for a policy without one
-                scan_widths=getattr(self.module, "scan_widths", None),
-                # a sparse-expert model (models/moe_lm.py): what its stacked
-                # leaves see of a sequence, what stays float32, its load
-                leaf_rows_per_token=getattr(
-                    self.module, "leaf_rows_per_token", None),
-                float32_leaves=getattr(self.module, "float32_leaves", ()),
-                expert_load=hasattr(self.module, "stacked_leaves"),
-                # a model with attention layers of several kinds, some
-                # windowed, whose key heads pair, and with 2-D leaves no
-                # matmul reads (models/sambay_lm.py)
-                attention_windows=getattr(self.module, "attention_windows",
-                                          None),
-                attention_kv_heads=getattr(self.module,
-                                           "num_key_value_heads", None),
-                dense_noise_leaves=getattr(
-                    self.module, "dense_noise_leaves", ()),
-                # a model whose attention reads a learned selection of keys
-                # (models/indexed_moe_lm.py): what the selection holds of a
-                # member, for the chunk rule
-                selection_bytes=getattr(self.module, "selection_bytes",
-                                        None),
+                policy=declaration_of(self.module),
             )
             # the whole flat vector leaves the device before the sharded
             # state is placed from it, a leaf at a time: a tree this
@@ -416,43 +387,14 @@ class ES:
 
     def _sequence_facts(self) -> dict:
         """What a whole-episode (token sequence) run works through a
-        generation: gauges and ``run_manifest()["config"]``; a looped model
-        (models/looped_lm.py) adds its passes and the layer-applications a
-        token goes through."""
+        generation, and what its model states of itself
+        (``PolicyDeclaration.facts``): gauges and
+        ``run_manifest()["config"]``."""
         if not getattr(getattr(self, "env", None), "whole_episode", False):
             return {}
-        facts = {"tokens_per_generation":
-                 self.population_size * self.config.horizon}
-        steps = getattr(self.module, "total_ut_steps", None)
-        if steps is not None:
-            facts["loop_steps"] = int(steps)
-            facts["layer_applications_per_token"] = (
-                int(steps) * len(self.module.layer_types))
-        if hasattr(self.module, "experts_total"):
-            # a sparse-expert model (models/moe_lm.py)
-            facts.update(
-                experts_held=int(self.module.n_routed_experts),
-                experts_total=int(self.module.experts_total),
-                experts_per_token=int(self.module.num_experts_per_tok),
-                mtp_depth=int(self.module.num_nextn_predict_layers))
-        if hasattr(self.module, "selection_bytes"):
-            # attention over a learned selection of keys
-            # (models/indexed_moe_lm.py)
-            facts.update(
-                sparse_topk=int(self.module.topk),
-                index_heads=int(self.module.indexer_num_heads),
-                index_head_dim=int(self.module.indexer_head_dim),
-                position_streams=len(self.module.mrope_section))
-        if hasattr(self.module, "kv_shared_by"):
-            # layers of several kinds, two of which hand state to the layers
-            # above them (models/sambay_lm.py)
-            facts.update(
-                layer_kinds=",".join(self.module.layer_types),
-                window=int(self.module.sliding_window),
-                scan_chunk=int(self.module.scan_chunk),
-                kv_shared_by=int(self.module.kv_shared_by),
-                memory_shared_by=int(self.module.memory_shared_by))
-        return facts
+        return {"tokens_per_generation":
+                self.population_size * self.config.horizon,
+                **declaration_of(self.module).facts}
 
     def _perturbed_form(self, flat):
         """``(perturbed apply, noise layout)`` of the module for
@@ -563,42 +505,11 @@ class ES:
         # (host sample/eval/update, pooled obsnorm merge, engine compile
         # events) land in the same per-generation accumulator
         self.engine.telemetry = self.obs
-        if hasattr(self.engine, "forward_form"):
-            # so a record's counters and a flight-recorder dump say which
-            # forward ran (the string is skipped by the numeric exporters)
-            self.obs.counters.gauge("forward_form", self.engine.forward_form)
-            self.obs.counters.gauge("noise_rows_per_generation",
-                                    self.engine.noise_rows_per_generation)
-        if hasattr(self.engine, "noise_gather_form"):
-            # "dma" says the row kernels of ops/pallas_noise.py engaged
-            self.obs.counters.gauge("noise_gather_form",
-                                    self.engine.noise_gather_form)
-        if getattr(self.engine, "attention_form", None) is not None:
-            # "kernel" says ops/pallas_attention.py engaged
-            self.obs.counters.gauge("attention_form",
-                                    self.engine.attention_form)
-            # and where: the form each kind of attention layer took
-            self.obs.counters.gauge("attention_form_by_kind",
-                                    self.engine.attention_form_by_kind)
-        if getattr(self.engine, "head_form", None) is not None:
-            # "kernel" says ops/pallas_head.py engaged
-            self.obs.counters.gauge("head_form", self.engine.head_form)
-        if getattr(self.engine, "scan_form", None) is not None:
-            # "kernel" says ops/pallas_scan.py engaged
-            self.obs.counters.gauge("scan_form", self.engine.scan_form)
-        if self._shard_params:
-            self.obs.counters.gauge("mesh_shape", "x".join(
-                str(n) for n in self.mesh.devices.shape))
-            self.obs.counters.gauge("param_bytes_per_chip",
-                                    self.engine.param_bytes_per_chip)
-            # "gathered": every chip reads the whole compute-dtype centre
-            # and evaluates whole members; "split": the centre stays
-            # sharded like the state (parallel/sharded.py::centre_form_why)
-            self.obs.counters.gauge("centre_form", self.engine.centre_form)
-            self.obs.counters.gauge("centre_form_why",
-                                    self.engine.centre_form_why)
-            self.obs.counters.gauge("centre_bytes_per_chip",
-                                    self.engine.centre_bytes_per_chip)
+        # what the engine resolved at build, so that a record's counters
+        # and a flight-recorder dump say which forms ran (the strings are
+        # skipped by the numeric exporters), then what the model states
+        for name, value in build_fact_gauges(self.engine).items():
+            self.obs.counters.gauge(name, value)
         for name, value in self._sequence_facts().items():
             self.obs.counters.gauge(name, value)
         # analytic FLOPs/bytes model of this configuration (obs/profile/):
@@ -1230,33 +1141,9 @@ class ES:
             "mirrored": self._mirrored,
             "obs_norm": self._obs_norm,
             "low_rank": self._low_rank,
-            # which forward the engine resolved at build, and how many
-            # noise-table rows it gathers per generation (None: an engine
-            # that does not evaluate on the device path)
-            "forward_form": getattr(self.engine, "forward_form", None),
-            "noise_rows_per_generation": getattr(
-                self.engine, "noise_rows_per_generation", None),
-            # how whole rows leave the noise table ("dma" | "slice"; None:
-            # an engine with no replicated-table gather of its own)
-            "noise_gather_form": getattr(
-                self.engine, "noise_gather_form", None),
-            # which form the policy's causal attention takes ("kernel" |
-            # "xla"; None: a policy without one, or the replicated engine)
-            "attention_form": getattr(self.engine, "attention_form", None),
-            # "<kind>:<form>,…" of the policy's attention layer kinds (a
-            # kind with a window is "xla" in a "kernel" program too)
-            "attention_form_by_kind": getattr(
-                self.engine, "attention_form_by_kind", None),
-            # which form the policy's next-token head takes ("kernel" |
-            # "xla"; None: a policy without one, or the replicated engine)
-            "head_form": getattr(self.engine, "head_form", None),
-            # which form the policy's selective scans take ("kernel" |
-            # "xla"; None: a policy without one, or the replicated engine)
-            "scan_form": getattr(self.engine, "scan_form", None),
-            # which condition of the attention form's rule decided (and,
-            # where that is "xla", the head form with it)
-            "attention_form_why": getattr(
-                self.engine, "attention_form_why", None),
+            # what the engine resolved at build (None: a form this engine
+            # does not resolve)
+            **build_fact_manifest(self.engine),
             "shard_params": self._shard_params,
             **self._sequence_facts(),
         }
@@ -1274,12 +1161,6 @@ class ES:
                 [int(s) for s in self.mesh.devices.shape]))
             cfg["partition_rules"] = partition_rules_to_json(
                 self.engine.partition_rules)
-            # how the perturbed form's forward holds the centre ("gathered":
-            # whole on every chip, whole members a chip | "split": sharded
-            # like the state), the condition that decided, and its bytes
-            cfg["centre_form"] = self.engine.centre_form
-            cfg["centre_form_why"] = self.engine.centre_form_why
-            cfg["centre_bytes_per_chip"] = self.engine.centre_bytes_per_chip
         mesh = getattr(self, "mesh", None)
         devices = list(mesh.devices.flat) if mesh is not None else None
         return collect_manifest(config=cfg, devices=devices, extra=extra)

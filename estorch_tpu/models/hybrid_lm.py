@@ -58,7 +58,8 @@ from ..obs.trace import HEAD, SSM, part, stage
 from . import lm_blocks
 from .lm_blocks import (causal_conv as _causal_conv, layer_name,
                         rmsnorm as _rmsnorm, subtree)
-from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
+from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+                        perturbed_embed, perturbed_leaf)
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -113,17 +114,15 @@ class HybridLM:
         return (self.attention_head_dim
                 or self.hidden_size // self.num_attention_heads)
 
-    @property
-    def attention_widths(self) -> int:
-        """Heads of ONE width, scored and summed (the attention form's
-        rule reads it, ops/pallas_attention.py)."""
-        return self.head_dim
-
-    @property
-    def head_width(self) -> int:
-        """The width the next-token head contracts (the head form's rule
-        reads it, ops/pallas_head.py)."""
-        return self.hidden_size
+    def declaration(self) -> PolicyDeclaration:
+        """What the engine that runs this model and the run's records read
+        of it, stated once (models/perturbed.py::PolicyDeclaration)."""
+        return PolicyDeclaration(
+            # heads of ONE width, scored and summed
+            attention_widths=self.head_dim,
+            attention_kv_heads=self.num_key_value_heads,
+            # the width the next-token head contracts
+            head_width=self.hidden_size)
 
     def param_shapes(self) -> dict:
         """The parameter tree as shapes (float32)."""

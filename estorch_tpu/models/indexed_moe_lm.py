@@ -74,7 +74,8 @@ import jax.numpy as jnp
 from ..obs.trace import ATTN, HEAD, INDEX, ROPE, part, stage
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
-from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
+from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+                        perturbed_embed, perturbed_leaf)
 
 MOE_LAYER = "moe"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -156,28 +157,6 @@ class IndexedMoELM:
     def first_expert_held(self) -> int:
         return self.num_experts * self.expert_group_rank
 
-    # what ``ES`` reads its sparse-expert gauges from, under MoELM's names
-    @property
-    def n_routed_experts(self) -> int:
-        return self.num_experts
-
-    num_nextn_predict_layers = 0
-
-    @property
-    def attention_widths(self) -> int:
-        """Heads scored and summed at one width (the attention form's rule
-        reads it, ops/pallas_attention.py)."""
-        return self.head_dim
-
-    @property
-    def attention_windows(self) -> dict:
-        """One kind of attention layer, over a selection of keys, no band."""
-        return {"selected": None}
-
-    @property
-    def head_width(self) -> int:
-        return self.hidden_size
-
     def selection_bytes(self, length: int) -> int:
         """Bytes of the selection's temporaries ONE member holds over a
         sequence of ``length`` positions (parallel/sharded.py's chunk rule
@@ -235,19 +214,38 @@ class IndexedMoELM:
             "moe/router", "indexer/index_norm/scale",
             "indexer/index_norm/bias", "indexer/index_w"))
 
-    @property
-    def leaf_rows(self) -> dict:
-        """The head runs in blocks of ``head_block`` positions."""
-        return {"head/kernel": self.head_block}
-
-    @property
-    def leaf_rows_per_token(self) -> dict:
-        """Rows a stacked expert leaf is applied to per position: the
-        (token, k) pairs routed to the held experts, with the layer's
-        margin."""
+    def declaration(self) -> PolicyDeclaration:
+        """What the engine that runs this model and the run's records read
+        of it, stated once (models/perturbed.py::PolicyDeclaration)."""
+        # rows a stacked expert leaf is applied to per position: the (token,
+        # k) pairs routed to the held experts, with the layer's margin
         rows = (self.num_experts_per_tok * lm_blocks.EXPERT_CAPACITY_MARGIN
                 / self.expert_group_size)
-        return dict.fromkeys(self.stacked_leaves, rows)
+        return PolicyDeclaration(
+            # the head runs in blocks of ``head_block`` positions
+            leaf_rows={"head/kernel": self.head_block},
+            leaf_rows_per_token=dict.fromkeys(self.stacked_leaves, rows),
+            stacked_leaves=self.stacked_leaves,
+            float32_leaves=self.float32_leaves,
+            # heads scored and summed at one width; one kind of attention
+            # layer, over a selection of keys, no band
+            attention_widths=self.head_dim,
+            attention_windows={"selected": None},
+            attention_kv_heads=self.num_key_value_heads,
+            head_width=self.hidden_size,
+            selection_bytes=self.selection_bytes,
+            # after what the env scores: the pairs per held expert, then
+            # the (query, key) pairs selected
+            outputs=("expert_load", "selected_pairs"),
+            # the sparse-expert facts under MoELM's names (no MTP module)
+            facts={"experts_held": self.num_experts,
+                   "experts_total": self.experts_total,
+                   "experts_per_token": self.num_experts_per_tok,
+                   "mtp_depth": 0,
+                   "sparse_topk": self.topk,
+                   "index_heads": self.indexer_num_heads,
+                   "index_head_dim": self.indexer_head_dim,
+                   "position_streams": len(self.mrope_section)})
 
     # ------------------------------------------------------------- init
 

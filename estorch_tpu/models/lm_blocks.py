@@ -62,8 +62,9 @@ kernel's blocks) and opens ``pallas_attention.kernel_scope`` around its
 trace of the policy.  :func:`attention_core` takes the kernel inside that
 scope for a call without a ``window``, and the XLA form for a call with
 one and everywhere else, so ``apply`` outside an engine is the XLA form.
-``ES`` hands the engine the model's ``attention_widths``, as it hands it
-``leaf_rows``: the widths the kernel's column blocks are cut by, one
+The engine reads what its rule needs from the model's declaration
+(``perturbed.PolicyDeclaration``, the model's ``declaration()``):
+``attention_widths``, the widths the kernel's column blocks are cut by, one
 ``int`` for heads of one width or ``(a head's own part, the shared part,
 the value width)``; its key heads; and, where its attention layers are of
 several kinds, each kind's band (``attention_windows``), from which the

@@ -54,7 +54,8 @@ import jax.numpy as jnp
 from ..obs.trace import EXIT, part, stage
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
-from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
+from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+                        perturbed_embed, perturbed_leaf)
 
 FULL_ATTENTION = "full_attention"
 NORMS = ("norm1", "norm2", "norm3", "norm4")
@@ -126,26 +127,22 @@ class LoopedLM:
             lambda s: jax.ShapeDtypeStruct(s, F32), tree,
             is_leaf=lambda s: isinstance(s, tuple))
 
-    @property
-    def attention_widths(self) -> int:
-        """Heads of ONE width, scored and summed (the attention form's
-        rule reads it, ops/pallas_attention.py)."""
-        return self.head_dim
-
-    @property
-    def head_width(self) -> int:
-        """The width the next-token head contracts (the head form's rule
-        reads it, ops/pallas_head.py)."""
-        return self.hidden_size
-
-    @property
-    def leaf_rows(self) -> dict:
-        """Leaves a token sequence does NOT pass whole: ``{leaf path:
-        positions per application}``.  The head runs in blocks of
-        ``head_block`` positions, so its widest activation is
-        ``[head_block, vocab]``, not ``[T, vocab]`` (parallel/sharded.py
-        sizes its evaluation chunks from this)."""
-        return {"head/kernel": self.head_block}
+    def declaration(self) -> PolicyDeclaration:
+        """What the engine that runs this model and the run's records read
+        of it, stated once (models/perturbed.py::PolicyDeclaration)."""
+        return PolicyDeclaration(
+            # the head runs in blocks of ``head_block`` positions: its
+            # widest activation is ``[head_block, vocab]``, not ``[T, vocab]``
+            leaf_rows={"head/kernel": self.head_block},
+            # heads of ONE width, scored and summed
+            attention_widths=self.head_dim,
+            attention_kv_heads=self.num_key_value_heads,
+            # the width the next-token head contracts
+            head_width=self.hidden_size,
+            # its passes, and the layer-applications a token goes through
+            facts={"loop_steps": self.total_ut_steps,
+                   "layer_applications_per_token":
+                       self.total_ut_steps * len(self.layer_types)})
 
     # ------------------------------------------------------------- init
 

@@ -72,8 +72,8 @@ import jax.numpy as jnp
 from ..obs.trace import DENSE, HEAD, ROPE, part, stage
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
-from .perturbed import (F32, leaf_columns, perturbed_dense, perturbed_embed,
-                        perturbed_leaf)
+from .perturbed import (F32, PolicyDeclaration, leaf_columns, perturbed_dense,
+                        perturbed_embed, perturbed_leaf)
 
 DENSE_LAYER, MOE_LAYER = "dense", "moe"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -157,21 +157,6 @@ class MoELM:
         """A head's width where it is scored."""
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
-    @property
-    def attention_widths(self) -> tuple:
-        """``(a head's own query/key part, the rotated part whose key all
-        heads share, the value width)``: what the attention core's parts
-        are cut by (the attention form's rule reads it,
-        ops/pallas_attention.py)."""
-        return (self.qk_nope_head_dim, self.qk_rope_head_dim,
-                self.v_head_dim)
-
-    @property
-    def head_width(self) -> int:
-        """The width the next-token head contracts (the head form's rule
-        reads it, ops/pallas_head.py)."""
-        return self.hidden_size
-
     def _layer_shapes(self, kind: str) -> dict:
         h, nh = self.hidden_size, self.num_attention_heads
         tree: dict[str, Any] = {
@@ -237,20 +222,31 @@ class MoELM:
         return tuple(f"{p}/{n}" for p in self._moe_paths()
                      for n in ("router", "router_bias"))
 
-    @property
-    def leaf_rows(self) -> dict:
-        """The head runs in blocks of ``head_block`` positions
-        (parallel/sharded.py sizes its evaluation chunks from this)."""
-        return {"head/kernel": self.head_block}
-
-    @property
-    def leaf_rows_per_token(self) -> dict:
-        """Rows a stacked expert leaf is applied to per position of a
-        sequence: the (token, k) pairs routed to the held experts, with the
-        expert layer's margin; not every position."""
+    def declaration(self) -> PolicyDeclaration:
+        """What the engine that runs this model and the run's records read
+        of it, stated once (models/perturbed.py::PolicyDeclaration)."""
+        # rows a stacked expert leaf is applied to per position: the (token,
+        # k) pairs routed to the held experts, with the expert layer's margin
         rows = (self.num_experts_per_tok * lm_blocks.EXPERT_CAPACITY_MARGIN
                 / self.expert_group_size)
-        return dict.fromkeys(self.stacked_leaves, rows)
+        return PolicyDeclaration(
+            # the head runs in blocks of ``head_block`` positions
+            leaf_rows={"head/kernel": self.head_block},
+            leaf_rows_per_token=dict.fromkeys(self.stacked_leaves, rows),
+            stacked_leaves=self.stacked_leaves,
+            float32_leaves=self.float32_leaves,
+            # (a head's own query/key part, the rotated part whose key all
+            # heads share, the value width)
+            attention_widths=(self.qk_nope_head_dim, self.qk_rope_head_dim,
+                              self.v_head_dim),
+            # the width the next-token head contracts
+            head_width=self.hidden_size,
+            # after what the env scores: the pairs per held expert
+            outputs=("expert_load",),
+            facts={"experts_held": self.n_routed_experts,
+                   "experts_total": self.experts_total,
+                   "experts_per_token": self.num_experts_per_tok,
+                   "mtp_depth": self.num_nextn_predict_layers})
 
     # ------------------------------------------------------------- init
 

@@ -27,6 +27,9 @@ lookup, which has one name everywhere and says ``of.embed`` itself.
 
 from __future__ import annotations
 
+import dataclasses
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
 
@@ -150,6 +153,62 @@ def perturbed_leaf(w, noise, c):
         return w + c * noise.astype(F32)
 
 
+@dataclasses.dataclass(frozen=True)
+class PolicyDeclaration:
+    """What a policy states of itself, once, for the engine that runs it and
+    for the run's records (:func:`declaration_of`).  Every field has the
+    value of a policy that states nothing: an MLP, a conv or recurrent
+    policy.  A sequence model builds its own in ONE place, its
+    ``declaration()``, from its sizes; the param-sharded engine reads the
+    fields where it sizes chunks and resolves forms (parallel/sharded.py),
+    ``lowrank_spec_for`` where it lays out the noise, ``ES`` merges
+    ``facts`` into the gauges and ``run_manifest()["config"]``.
+
+    - ``leaf_rows``: ``{leaf path: positions per application}`` of the
+      leaves a sequence does NOT pass whole (an untied head run in blocks);
+    - ``leaf_rows_per_token``: ``{stacked leaf path: rows of the leaf's
+      input per position}`` (an expert sees the pairs routed to it);
+    - ``stacked_leaves``: leaves whose leading axis indexes experts, one
+      factor pair an expert; ``dense_noise_leaves``: 2-D leaves no matmul
+      reads, dense noise whatever the factoring rule says of their shape;
+    - ``float32_leaves``: leaves the forward reads in float32 whatever the
+      compute dtype;
+    - ``attention_widths`` (one ``int``, or ``(a head's own part, the part
+      scored against one shared key, the value width)``),
+      ``attention_windows`` (``{attention layer kind: its band | None}``;
+      ``None``: one kind, no band) and ``attention_kv_heads``: what the
+      attention form's rule reads (ops/pallas_attention.py);
+      ``head_width``: the head form's (ops/pallas_head.py);
+      ``scan_widths`` ``(d_inner, d_state)``: the scan form's
+      (ops/pallas_scan.py).  ``None``: the policy has no such layer;
+    - ``selection_bytes``: ``horizon -> bytes`` of the temporaries ONE
+      member's learned selection of keys holds, for the chunk rule;
+    - ``outputs``: the names, in order, of what the policy returns after
+      ``(score, behaviour)``; the engine reduces each by its name;
+    - ``facts``: what the model itself adds to the gauges and the manifest.
+    """
+
+    leaf_rows: dict = dataclasses.field(default_factory=dict)
+    leaf_rows_per_token: dict = dataclasses.field(default_factory=dict)
+    stacked_leaves: tuple = ()
+    dense_noise_leaves: tuple = ()
+    float32_leaves: tuple = ()
+    attention_widths: int | tuple | None = None
+    attention_windows: dict | None = None
+    attention_kv_heads: int | None = None
+    head_width: int | None = None
+    scan_widths: tuple | None = None
+    selection_bytes: Callable[[int], int] | None = None
+    outputs: tuple = ()
+    facts: dict = dataclasses.field(default_factory=dict)
+
+
+def declaration_of(module) -> PolicyDeclaration:
+    """The module's ``declaration()``; the defaults for one that has none."""
+    own = getattr(module, "declaration", None)
+    return PolicyDeclaration() if own is None else own()
+
+
 def perturbed_forward(module):
     """``(params, noise, c, obs) -> policy output`` of ``params + c·noise``
     for a module that has such a form (``noise`` as
@@ -177,8 +236,7 @@ def lowrank_spec_for(module, params, rank: int):
 
     if supports_decomposed(module):
         return make_lowrank_spec(params, rank)
-    # a model names the leaves whose leading axis indexes experts, and
-    # the 2-D leaves no matmul reads
+    policy = declaration_of(module)
     return make_lowrank_tree_spec(
-        params, rank, stacked=getattr(module, "stacked_leaves", ()),
-        dense=getattr(module, "dense_noise_leaves", ()))
+        params, rank, stacked=policy.stacked_leaves,
+        dense=policy.dense_noise_leaves)

@@ -103,7 +103,8 @@ from ..obs.trace import ATTN, DIFF, GMU, HEAD, SSM, part, stage
 from ..ops import pallas_attention, pallas_scan
 from . import lm_blocks
 from .lm_blocks import layer_name, subtree
-from .perturbed import F32, perturbed_dense, perturbed_embed, perturbed_leaf
+from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+                        perturbed_embed, perturbed_leaf)
 
 MAMBA, WINDOW, MAMBA_MEM, FULL_KV, GMU_LAYER, CROSS = (
     "mamba", "window", "mamba_mem", "full_kv", "gmu", "cross")
@@ -213,42 +214,6 @@ class SambaYLM:
         return self.hidden_size // self.num_attention_heads
 
     @property
-    def attention_widths(self) -> tuple:
-        """``(a score head's width, no shared part, the value width)``: a
-        map's heads are ``head_dim`` wide where they are scored and a PAIR's
-        values, twice that, where they are summed.  The attention form's
-        rule reads it (ops/pallas_attention.py): at the published
-        ``head_dim`` of 64 a pair is one 128-lane block, two score heads
-        side by side over one value block, which the kernel takes as it
-        lies."""
-        return (self.head_dim, 0, 2 * self.head_dim)
-
-    @property
-    def attention_windows(self) -> dict:
-        """``{attention layer kind held: the band of its calls of the core
-        | None}``, in layer order.  The engine reads it to say which form
-        each kind takes: the kernel has no band, so a call with a window is
-        the XLA form inside the kernel's scope too."""
-        return {kind: self.sliding_window if kind == WINDOW else None
-                for kind in dict.fromkeys(self.layer_types)
-                if kind in ATTENTION_PARTS}
-
-    @property
-    def head_width(self) -> int:
-        """The width the next-token head contracts (the head form's rule
-        reads it, ops/pallas_head.py)."""
-        return self.hidden_size
-
-    @property
-    def scan_widths(self) -> tuple | None:
-        """``(d_inner, d_state)`` of the selective scans (the scan form's
-        rule reads it, ops/pallas_scan.py); ``None`` where the layers held
-        have no Mamba layer among them."""
-        if not {MAMBA, MAMBA_MEM} & set(self.layer_types):
-            return None
-        return (self.d_inner, self.mamba_d_state)
-
-    @property
     def kv_shared_by(self) -> int:
         """Layers that read the ``full_kv`` layer's keys and values."""
         return self.layer_types.count(CROSS)
@@ -314,6 +279,40 @@ class SambaYLM:
         what the scan's decay and the differential ``λ`` are made of."""
         return self._small_leaves(
             ("A_log", "D", "dt_bias", "conv_kernel", "conv_bias") + LAMBDAS)
+
+    def declaration(self) -> PolicyDeclaration:
+        """What the engine that runs this model and the run's records read
+        of it, stated once (models/perturbed.py::PolicyDeclaration)."""
+        mamba = {MAMBA, MAMBA_MEM} & set(self.layer_types)
+        return PolicyDeclaration(
+            dense_noise_leaves=self.dense_noise_leaves,
+            float32_leaves=self.float32_leaves,
+            # (a score head's width, no shared part, the value width): a
+            # map's heads are ``head_dim`` wide where they are scored and a
+            # PAIR's values, twice that, where they are summed; at the
+            # published 64 a pair is one 128-lane block, two score heads
+            # side by side over one value block, which the kernel takes as
+            # it lies
+            attention_widths=(self.head_dim, 0, 2 * self.head_dim),
+            # {attention layer kind held: the band of its calls of the core
+            # | None}, in layer order: the kernel has no band, so a call
+            # with a window is the XLA form inside the kernel's scope too
+            attention_windows={
+                kind: self.sliding_window if kind == WINDOW else None
+                for kind in dict.fromkeys(self.layer_types)
+                if kind in ATTENTION_PARTS},
+            attention_kv_heads=self.num_key_value_heads,
+            # the width the next-token head contracts
+            head_width=self.hidden_size,
+            # of the selective scans, where a Mamba layer is held
+            scan_widths=(self.d_inner, self.mamba_d_state) if mamba else None,
+            # layers of several kinds, two of which hand state to the
+            # layers above them
+            facts={"layer_kinds": ",".join(self.layer_types),
+                   "window": self.sliding_window,
+                   "scan_chunk": self.scan_chunk,
+                   "kv_shared_by": self.kv_shared_by,
+                   "memory_shared_by": self.memory_shared_by})
 
     # ------------------------------------------------------------- init
 

@@ -287,6 +287,36 @@ NOISE_KERNEL_MAX_DIM = 1_000_000  # the row kernels hold a few windows of
 # it the chunked pure-JAX forms ("slice") handle any dim
 
 
+# ---- what an engine resolved at build, as ``ES`` publishes it.  A device
+# engine's ``build_facts()`` names each fact as its gauge and
+# ``run_manifest()["config"]`` do; an engine without one (pooled, host)
+# resolves none.  The manifest names these for EVERY engine (``None``: not
+# resolved by this one), and the rest where an engine reports them
+MANIFEST_BUILD_FACTS = (
+    "forward_form", "noise_rows_per_generation", "noise_gather_form",
+    "attention_form", "attention_form_by_kind", "head_form", "scan_form",
+    "attention_form_why")
+_NO_GAUGE = frozenset({"attention_form_why"})  # a sentence
+# the manifest has the mesh as ``mesh_axes``
+_NOT_IN_MANIFEST = frozenset({"mesh_shape", "param_bytes_per_chip"})
+
+
+def build_fact_gauges(engine) -> dict:
+    """The gauges of what ``engine`` resolved at build: a fact it did not
+    resolve (``None``) sets none."""
+    facts = getattr(engine, "build_facts", dict)()
+    return {name: value for name, value in facts.items()
+            if value is not None and name not in _NO_GAUGE}
+
+
+def build_fact_manifest(engine) -> dict:
+    """What ``run_manifest()["config"]`` says of ``engine``'s build."""
+    facts = getattr(engine, "build_facts", dict)()
+    return {**dict.fromkeys(MANIFEST_BUILD_FACTS),
+            **{name: value for name, value in facts.items()
+               if name not in _NOT_IN_MANIFEST}}
+
+
 class ESEngine:
     """Compiles and caches the per-generation XLA programs for one setup."""
 
@@ -1058,6 +1088,14 @@ class ESEngine:
             sigma=jnp.float32(self.config.sigma),
             obs_stats=obs_stats,
         ), self.mesh)
+
+    # what this engine resolves at build, by attribute: the names its
+    # gauges and ``run_manifest()["config"]`` carry
+    BUILD_FACTS = ("forward_form", "noise_rows_per_generation",
+                   "noise_gather_form")
+
+    def build_facts(self) -> dict:
+        return {name: getattr(self, name) for name in self.BUILD_FACTS}
 
     def compile(self, state: ESState) -> float:
         """AOT-compile the fused generation program; returns seconds spent.

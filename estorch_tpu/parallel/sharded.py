@@ -77,16 +77,22 @@ the ctor rejects them loudly.
 
 from __future__ import annotations
 
+import contextlib
+import logging
 import math
+import time
 from typing import Any, Callable, NamedTuple
 
+import chex
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..envs.rollout import make_rollout
+from ..models.perturbed import PolicyDeclaration
 from ..obs.spans import NULL_TELEMETRY
 from ..obs.trace import (GATHER, GRAD, NOISE, PERTURB, RANK, SAMPLE, UPDATE,
                          stage)
@@ -176,6 +182,28 @@ def _bytes_per_chip(shapes, shardings) -> int:
                 shardings, is_leaf=lambda s: isinstance(s, NamedSharding))))
 
 
+def _held_expert_load(engine, load, alive):
+    load = load.reshape(engine.members_padded, -1)
+    return jnp.where(alive[:, None], load, 0).sum(axis=0)
+
+
+def _member_selected_pairs(engine, chosen, alive):
+    return chosen.reshape(
+        engine.members_padded)[: engine.config.population_size]
+
+
+# What a policy may return after ``(score, behaviour)``, by the name it
+# declares (``PolicyDeclaration.outputs``): how the perturbed form reduces a
+# value ``[(chunks,) pairs, signs, ...]`` over the members (``alive``: the
+# real ones, the first) into ``metrics[name]``
+OUTPUT_REDUCTIONS = {
+    # pairs per held expert, over the real members
+    "expert_load": _held_expert_load,
+    # a member's count, summed over its layers, fits int32; the
+    # population's need not: the host adds the members up
+    "selected_pairs": _member_selected_pairs}
+
+
 def _rng_scope(partitionable: bool):
     """Program-mode dispatch/trace scope: the partitionable threefry
     implementation, without which GSPMD cannot shard in-program normal()
@@ -190,8 +218,6 @@ def _rng_scope(partitionable: bool):
     computation must re-enter this scope."""
     if partitionable:
         return jax.threefry_partitionable(True)
-    import contextlib
-
     return contextlib.nullcontext()
 
 
@@ -242,17 +268,7 @@ class ShardedESEngine:
         noise_mode: str = "program",
         perturbed_apply: Callable[..., Any] | None = None,
         lowrank_spec=None,
-        leaf_rows: dict[str, int] | None = None,
-        attention_widths: int | tuple | None = None,
-        head_width: int | None = None,
-        scan_widths: tuple | None = None,
-        leaf_rows_per_token: dict[str, float] | None = None,
-        float32_leaves=(),
-        expert_load: bool = False,
-        attention_windows: dict | None = None,
-        attention_kv_heads: int | None = None,
-        dense_noise_leaves=(),
-        selection_bytes: Callable[[int], int] | None = None,
+        policy: PolicyDeclaration = PolicyDeclaration(),
     ):
         if config.obs_norm:
             raise ValueError(
@@ -303,56 +319,48 @@ class ShardedESEngine:
             "perturbed" if noise_mode == "table" and config.low_rank
             else "materialised")
         self.lr_spec = lowrank_spec if self.forward_form == "perturbed" else None
-        self._perturbed_apply = perturbed_apply
-        # {leaf path: positions per application} of the leaves the policy
-        # runs in blocks of positions (a sequence model's untied head)
-        self._leaf_rows = dict(leaf_rows or {})
-        # {stacked leaf path: rows of the leaf's input per position}: an
-        # expert layer applies a held expert to the pairs routed to it
-        self._leaf_rows_per_token = dict(leaf_rows_per_token or {})
-        # the policy returns its experts' load after what the env scores
-        # (models/moe_lm.py); the perturbed form sums it into the metrics
-        self._expert_load = bool(expert_load)
+        # what the policy states of itself, each field read where it counts
+        self.policy = policy
+        # what it returns after what the env scores it names; the perturbed
+        # form reduces each into the metrics by that name
+        if set(policy.outputs) - set(OUTPUT_REDUCTIONS):
+            raise ValueError(
+                f"the policy declares the outputs {policy.outputs}; this "
+                f"engine can reduce {sorted(OUTPUT_REDUCTIONS)} over members")
         # a model whose attention reads a learned selection of keys
         # (models/indexed_moe_lm.py): the bytes of the selection's
         # temporaries ONE member holds over the horizon, which the chunk
-        # rule counts; such a policy returns, after its experts' load, the
-        # (query, key) pairs it selected
+        # rule counts
         self._selection_bytes = (
-            0 if selection_bytes is None
-            else int(selection_bytes(config.horizon)))
-        self._selected_pairs = selection_bytes is not None
+            0 if policy.selection_bytes is None
+            else int(policy.selection_bytes(config.horizon)))
         # the Pallas kernels compile through Mosaic on the chip this mesh
         # is made of; anywhere else only the interpreter can run them
         self._pallas_interpret = mesh.devices.flat[0].platform != "tpu"
+        # {attention layer kind: the band of its calls | None}, as the
+        # policy states them (one kind, no band, where it states none)
+        self._attention_windows = policy.attention_windows or {"causal": None}
         # "kernel" | "xla": which form the policy's causal attention takes
         # in this engine's programs (models/lm_blocks.py has the two forms);
         # None for a policy that has none.  Resolved once, here, from the
-        # mesh, the sequence length and the widths the policy states (run
-        # manifest + telemetry gauge)
-        # {attention layer kind: the band of its calls | None}, as the
-        # policy states them (one kind, no band, where it states none), and
-        # its key heads: a pair of them may share a column block
-        self._attention_windows = dict(attention_windows or {"causal": None})
-        self._attention_kv_heads = attention_kv_heads
-        self.attention_form = (
-            None if attention_widths is None
-            else self._resolve_attention_form(attention_widths))
-        # which condition of the rule decided (the head's kernel is taken
-        # inside the attention kernel's scope alone, so this is the head
-        # form's reason too wherever the attention is "xla")
-        self.attention_form_why = (
-            None if attention_widths is None
-            else self._attention_rule(attention_widths)[1])
-        # "<kind>:<form>,…": the form the calls of each attention layer
-        # kind take in this engine's programs (the kernel has no band: a
-        # kind with a window stays in the XLA form inside the kernel's
-        # scope)
-        self.attention_form_by_kind = (
-            None if attention_widths is None
-            else ",".join(
+        # mesh, the sequence length and what the policy states: the widths,
+        # the bands and the key heads, a pair of which may share a column
+        # block (run manifest + telemetry gauge)
+        widths = policy.attention_widths
+        self.attention_form = self.attention_form_why = None
+        self.attention_form_by_kind = None
+        if widths is not None:
+            self.attention_form = self._resolve_attention_form(widths)
+            # which condition of the rule decided (the head's kernel is
+            # taken inside the attention kernel's scope alone, so this is
+            # the head form's reason too wherever the attention is "xla")
+            self.attention_form_why = self._attention_rule(widths)[1]
+            # "<kind>:<form>,…": the form the calls of each attention layer
+            # kind take in this engine's programs (the kernel has no band:
+            # a kind with a window stays in the XLA form inside its scope)
+            self.attention_form_by_kind = ",".join(
                 f"{kind}:{call_form(self.attention_form, window)}"
-                for kind, window in self._attention_windows.items()))
+                for kind, window in self._attention_windows.items())
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
         # "kernel" | "xla": which form the policy's next-token head takes
@@ -360,25 +368,24 @@ class ShardedESEngine:
         # states no head.  The kernel is taken inside the scope the
         # attention form opens, where the head's own shapes fit
         self.head_form = (
-            None if head_width is None
-            else head_form(self.attention_form, head_width, config.horizon,
-                           jnp.dtype(self._dtype).itemsize))
+            None if policy.head_width is None
+            else head_form(self.attention_form, policy.head_width,
+                           config.horizon, jnp.dtype(self._dtype).itemsize))
         # "kernel" | "xla": which form the policy's selective scans take
         # (models/sambay_lm.py::selective_scan); None for a policy that
         # states no scan.  The kernel is taken inside the same scope, where
         # the scan's own shapes fit
         self.scan_form = (
-            None if scan_widths is None
-            else scan_form(self.attention_form, *scan_widths,
+            None if policy.scan_widths is None
+            else scan_form(self.attention_form, *policy.scan_widths,
                            config.horizon))
         if self.attention_form is not None:
-            import logging
-
             logging.getLogger(__name__).info(
                 "attention_form %s (%s; %s); head_form %s; scan_form %s",
                 self.attention_form, self.attention_form_why,
                 self.attention_form_by_kind, self.head_form, self.scan_form)
         self.n_devices = int(mesh.devices.size)
+        self.mesh_shape = "x".join(str(n) for n in mesh.devices.shape)
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.pop_shards = int(axis_sizes[POP_AXIS])
         self.model_shards = int(axis_sizes[MODEL_AXIS])
@@ -387,6 +394,7 @@ class ShardedESEngine:
         # ---- param-tree layout (tree_flatten order == ravel order) ----
         params_shape = jax.eval_shape(
             spec.unravel, jax.ShapeDtypeStruct((spec.dim,), jnp.float32))
+        self._params_shape = params_shape
         leaves, self._treedef = jax.tree_util.tree_flatten(params_shape)
         self.leaf_paths = [
             _leaf_path_name(path) for path, _ in
@@ -401,7 +409,7 @@ class ShardedESEngine:
         # the dtype each leaf has in the copy the forward reads: the
         # compute dtype, but float32 for the leaves the model names (a
         # router: a rounding of its scores picks another expert)
-        keep = set(float32_leaves)
+        keep = set(policy.float32_leaves)
         if keep - set(self.leaf_paths):
             raise ValueError("float32_leaves names no leaf: "
                              f"{sorted(keep - set(self.leaf_paths))}")
@@ -414,7 +422,7 @@ class ShardedESEngine:
         if config.low_rank:
             r = int(config.low_rank)
             # but for the 2-D leaves no matmul reads, which the model names
-            dense = set(dense_noise_leaves)
+            dense = set(policy.dense_noise_leaves)
             for i, shape in enumerate(self.leaf_shapes):
                 if (len(shape) == 2 and self.leaf_paths[i] not in dense
                         and r * (shape[0] + shape[1]) < shape[0] * shape[1]):
@@ -471,8 +479,6 @@ class ShardedESEngine:
             [self._repl] * len(self.leaf_shapes) if gathered
             else self._param_sharding_leaves)
         if self.forward_form == "perturbed":
-            import logging
-
             logging.getLogger(__name__).info(
                 "centre_form %s (%s)", self.centre_form, self.centre_form_why)
 
@@ -533,10 +539,9 @@ class ShardedESEngine:
             "update_finite": self._repl, "sigma": self._repl,
             "best_theta": self.param_shardings,
         }
-        if self._expert_load and self.forward_form == "perturbed":
-            metrics_shardings["expert_load"] = self._repl
-        if self._selected_pairs and self.forward_form == "perturbed":
-            metrics_shardings["selected_pairs"] = self._repl
+        if self.forward_form == "perturbed":
+            metrics_shardings.update(
+                dict.fromkeys(policy.outputs, self._repl))
         # table mode threads the table as a replicated OPERAND, not a
         # closure: a closed-over array lowers as an embedded HLO constant
         # — at table size that bloats the module past the persistent
@@ -572,7 +577,7 @@ class ShardedESEngine:
             widths, self.config.horizon,
             next((w for w in self._attention_windows.values()
                   if w is not None), None),
-            self._attention_kv_heads)
+            self.policy.attention_kv_heads)
 
     def _resolve_attention_form(self, widths) -> str:
         return self._attention_rule(widths)[0]
@@ -691,11 +696,12 @@ class ShardedESEngine:
         device: its expert axis is what ``model`` divides."""
         horizon = self.config.horizon
         across = 1 if self.centre_form == "gathered" else self.model_shards
+        policy = self.policy
         return max([
-            min(horizon, self._leaf_rows.get(self.leaf_paths[i], horizon))
+            min(horizon, policy.leaf_rows.get(self.leaf_paths[i], horizon))
             * -(-n // across)
             for i, _, n, _, _ in self.lr_spec.lr_leaves] + [
-            math.ceil(horizon * self._leaf_rows_per_token.get(
+            math.ceil(horizon * policy.leaf_rows_per_token.get(
                 self.leaf_paths[i], 1.0)) * n
             for i, _, _, n, _, _ in self.lr_spec.stacked_leaves] or [1])
 
@@ -768,15 +774,16 @@ class ShardedESEngine:
 
             res = jax.vmap(pair_eval, spmd_axis_name=self._pair_axes)(
                 noise_tree, keys_c)
-            load = res.extras[0] if self._expert_load else None
-            chosen = res.extras[1] if self._selected_pairs else None
-            return res.total_reward, res.bc, res.steps, load, chosen
+            # strict: a policy that returns more or fewer things than it
+            # declares is refused here, while the program is traced
+            return (res.total_reward, res.bc, res.steps, dict(zip(
+                self.policy.outputs, res.extras or (), strict=True)))
 
         if self.n_pair_chunks == 1:
-            f, bc, st, load, chosen = chunk_body(noise_rows, keys)
+            f, bc, st, outputs = chunk_body(noise_rows, keys)
         else:
             n, k = self.n_pair_chunks, self.pair_chunk
-            _, (f, bc, st, load, chosen) = jax.lax.scan(
+            _, (f, bc, st, outputs) = jax.lax.scan(
                 lambda _, xs: (0, chunk_body(*xs)), 0,
                 (noise_rows.reshape(n, k, self.noise_dim),
                  keys.reshape((n, k) + keys.shape[1:])))
@@ -787,18 +794,10 @@ class ShardedESEngine:
         with stage(GATHER):
             alive = jnp.arange(self.members_padded) < cfg.population_size
             steps = jnp.where(alive, st, 0).sum()
-            if load is not None:
-                # [(chunks,) pairs, signs, held] -> pairs per held expert
-                # over the real members
-                load = load.reshape(self.members_padded, -1)
-                load = jnp.where(alive[:, None], load, 0).sum(axis=0)
-            if chosen is not None:
-                # a member's count, summed over its layers, fits int32; the
-                # population's need not: the host adds the members up
-                chosen = chosen.reshape(
-                    self.members_padded)[: cfg.population_size]
+            out = {name: OUTPUT_REDUCTIONS[name](self, outputs[name], alive)
+                   for name in self.policy.outputs}
             return (f[: cfg.population_size], bc[: cfg.population_size],
-                    steps, load, chosen)
+                    steps, out)
 
     def _noise_rows(self, offsets, table_data):
         """Perturbed form: every pair's ``noise_dim`` floats, sliced from
@@ -1008,9 +1007,10 @@ class ShardedESEngine:
                     for x, dtype, sh in zip(
                         jax.tree_util.tree_leaves(state.params),
                         self._leaf_dtypes, self._centre_shardings)])
-            fitness, bc, steps, expert_load, selected_pairs = (
-                self._eval_all_perturbed(state, center, noise_rows, rkey))
+            fitness, bc, steps, outputs = self._eval_all_perturbed(
+                state, center, noise_rows, rkey)
         else:
+            outputs = {}
             fitness, bc, steps = self._eval_all(
                 state, offsets, leaf_keys, rkey, table_data)
         with stage(RANK):
@@ -1057,24 +1057,17 @@ class ShardedESEngine:
             "best_theta": jax.tree_util.tree_unflatten(
                 self._treedef, best_leaves),
         }
-        if perturbed and expert_load is not None:
-            metrics["expert_load"] = expert_load
-        if perturbed and selected_pairs is not None:
-            metrics["selected_pairs"] = selected_pairs
+        metrics.update(outputs)
         return new_state, metrics
 
     # ------------------------------------------------------------- public
 
     def init_state(self, params_flat: jax.Array, key: jax.Array) -> ShardedESState:
-        import chex
-
         chex.assert_shape(params_flat, (self.spec.dim,))
         chex.assert_tree_all_finite(params_flat)
         # place leaf by leaf, each slice straight onto its shards: the flat
         # vector is never replicated over the mesh and no second whole
         # tree exists anywhere (a host vector is read where it lies)
-        import numpy as np
-
         on_host = isinstance(params_flat, np.ndarray)
         flat = params_flat if on_host else jnp.asarray(params_flat)
         leaves, at = [], 0
@@ -1105,13 +1098,11 @@ class ShardedESEngine:
         output/temp byte sizes (``memory_analysis``) — with sharded
         inputs those ARE shard sizes, which is how the bench A/B and the
         acceptance test state per-device peak bytes."""
-        import time as _time
-
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         args = (state, self.table.data) if self.noise_mode == "table" else (state,)
         with _rng_scope(self.noise_mode == "program"):
             compiled = self._generation_step.lower(*args).compile()
-        dt = _time.perf_counter() - t0
+        dt = time.perf_counter() - t0
         from ..obs.profile.costmodel import compiled_cost_facts
 
         self._compiled_facts = compiled_cost_facts(compiled)
@@ -1153,6 +1144,18 @@ class ShardedESEngine:
             [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in
              zip(self.leaf_shapes, self._leaf_dtypes)],
             self._centre_shardings)
+
+    # what this engine resolves at build, by attribute, under the names of
+    # its gauges and manifest entries (engine.py::build_fact_gauges): a
+    # form the next PR resolves is named HERE, and nowhere in ``ES``
+    BUILD_FACTS = (
+        "forward_form", "noise_rows_per_generation", "attention_form",
+        "attention_form_why", "attention_form_by_kind", "head_form",
+        "scan_form", "mesh_shape", "param_bytes_per_chip", "centre_form",
+        "centre_form_why", "centre_bytes_per_chip")
+
+    def build_facts(self) -> dict:
+        return {name: getattr(self, name) for name in self.BUILD_FACTS}
 
     def memory_facts(self) -> dict:
         """XLA per-device byte facts of the compiled generation program
@@ -1201,17 +1204,13 @@ class ShardedESEngine:
             flats.append(
                 (jax.device_get(leaf) + jax.device_get(
                     state.sigma * sign * eps)).reshape(-1))
-        import numpy as np
-
         return jnp.asarray(np.concatenate(flats))
 
     def sharding_report(self) -> dict[str, str]:
         """{leaf path: resolved spec} — what the rules did, incl. any
         divisibility fallbacks (manifests, tests, docs examples) — and,
         under ``centre_form``, how the forward's copy of the centre lies."""
-        params_shape = jax.eval_shape(
-            self.spec.unravel, jax.ShapeDtypeStruct((self.spec.dim,), jnp.float32))
-        report = sharding_summary(params_shape, self.param_shardings,
+        report = sharding_summary(self._params_shape, self.param_shardings,
                                   self.partition_rules)
         report["centre_form"] = (
             f"{self.centre_form}: {self.centre_form_why}; "

@@ -1081,6 +1081,9 @@ class _RouterHttpd(ThreadingHTTPServer):
 def _make_handler(router: Router):
     class RouterHandler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
+        # two sends a reply (headers, body): no Nagle stall between them
+        # (serve/server.py)
+        disable_nagle_algorithm = True
 
         def log_message(self, *args):
             pass

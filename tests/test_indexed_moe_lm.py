@@ -704,10 +704,12 @@ def test_published_sizes_and_layouts(ref):
                 "indexer_num_kv_heads", "topk"):
         assert fields[key] == cfg["sa_config"][key], key
     assert list(lm.mrope_section) == cfg["rope_scaling"]["mrope_section"]
-    assert (lm.attention_widths, lm.head_width, lm.attention_windows) == (
-        128, 2048, {"selected": None})
-    assert attention_form_why("tpu", 1, lm.attention_widths, cfg["horizon"],
-                              None, lm.num_key_value_heads)[0] == "kernel"
+    stated = lm.declaration()
+    assert (stated.attention_widths, stated.head_width,
+            stated.attention_windows) == (128, 2048, {"selected": None})
+    assert attention_form_why("tpu", 1, stated.attention_widths,
+                              cfg["horizon"], None,
+                              stated.attention_kv_heads)[0] == "kernel"
     # 16,384 positions: the selection and the index scores of one member
     assert lm.selection_bytes(16384) == 16384 ** 2 + 4 * 16 * 512 * 16384
     shapes = lm.param_shapes()

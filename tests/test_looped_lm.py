@@ -622,7 +622,7 @@ class TestChunkRule:
         ``head_block x vocab`` for it, ``horizon x n`` for the others."""
         es = _loop_es(devices8[:1], 1)
         eng = es.engine
-        assert eng._leaf_rows == {"head/kernel": 8}
+        assert eng.policy.leaf_rows == {"head/kernel": 8}
         # horizon 21: gate/up 21 x 48 = 1008 floats; the head 8 x 64 = 512
         assert eng._widest_activation() == 21 * 48
         wide = _loop_es(devices8[:1], 1, policy_kwargs={
@@ -667,5 +667,5 @@ class TestChunkRule:
                       policy_kwargs=lm_tiny.TINY,
                       agent_kwargs={"env": TokenScoreEnv(**lm_tiny.ENV)})
         assert es.engine.centre_form == centre_form
-        assert es.engine._leaf_rows == {}
+        assert es.engine.policy.leaf_rows == {}
         assert es.engine._widest_activation() == 21 * 64 // across

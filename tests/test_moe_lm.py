@@ -462,14 +462,14 @@ def test_the_models_say_the_width_their_heads_are_scored_at(tiny):
     from estorch_tpu.models import HybridLM
 
     assert tiny["lm"].qk_head_dim == 8 + 4
-    assert tiny["lm"].attention_widths == (8, 4, 6)
-    assert LoopedLM(**loop_tiny.TINY).attention_widths == 8
+    assert tiny["lm"].declaration().attention_widths == (8, 4, 6)
+    assert LoopedLM(**loop_tiny.TINY).declaration().attention_widths == 8
     hybrid = HybridLM(**lm_tiny.TINY)
-    assert hybrid.attention_widths == hybrid.head_dim
+    assert hybrid.declaration().attention_widths == hybrid.head_dim
     published = MoELM(**moe_tiny.published()["build"]["kwargs"][
         "policy_kwargs"])
     assert published.qk_head_dim == 192
-    assert published.attention_widths == (128, 64, 128)
+    assert published.declaration().attention_widths == (128, 64, 128)
 
 
 @pytest.mark.parametrize("noise_form", ["factored", "dense", "none"])
@@ -963,9 +963,9 @@ class TestChunkRule:
         es = _moe_es(devices8[:1], 1)
         eng = es.engine
         per_token = 3 * lm_blocks.EXPERT_CAPACITY_MARGIN / 4
-        assert eng._leaf_rows_per_token == dict.fromkeys(
+        assert eng.policy.leaf_rows_per_token == dict.fromkeys(
             MoELM(**moe_tiny.TINY).stacked_leaves, per_token)
-        assert eng._leaf_rows == {"head/kernel": 8}
+        assert eng.policy.leaf_rows == {"head/kernel": 8}
         # horizon 21: kv_b 21 x 56 = 1176; experts/down ceil(21 x .9375) x 32
         assert eng._widest_activation() == 21 * 56
         uncut = _moe_es(devices8[:1], 1, policy_kwargs={

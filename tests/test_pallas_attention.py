@@ -599,9 +599,9 @@ class TestTheRule:
         assert "pallas_call" in inside
 
     def test_models_have_the_head_size_es_hands_the_engine(self):
-        assert LoopedLM(**loop_tiny.TINY).attention_widths == 8
-        assert HybridLM(**lm_tiny.TINY).attention_widths == 8
-        assert MoELM(**moe_tiny.TINY).attention_widths == (8, 4, 6)
+        assert LoopedLM(**loop_tiny.TINY).declaration().attention_widths == 8
+        assert HybridLM(**lm_tiny.TINY).declaration().attention_widths == 8
+        assert MoELM(**moe_tiny.TINY).declaration().attention_widths == (8, 4, 6)
 
 
 # ----------------------------------------------------- through the engine

@@ -253,9 +253,9 @@ class TestTheRule:
         import lm_tiny
         import moe_tiny
 
-        assert LoopedLM(**loop_tiny.TINY).head_width == 32
-        assert HybridLM(**lm_tiny.TINY).head_width == 32
-        assert MoELM(**moe_tiny.TINY).head_width == 32
+        assert LoopedLM(**loop_tiny.TINY).declaration().head_width == 32
+        assert HybridLM(**lm_tiny.TINY).declaration().head_width == 32
+        assert MoELM(**moe_tiny.TINY).declaration().head_width == 32
 
 
 class TestTheDeclaredCost:

@@ -1,0 +1,224 @@
+"""The contract between a policy and the engine that runs it (PR 43).
+
+A model states what it is ONCE (``models/perturbed.py::PolicyDeclaration``,
+its ``declaration()``), an engine reports what it resolved at build ONCE
+(``build_facts()``), and ``ES`` carries both to the gauges and to
+``run_manifest()["config"]`` without naming a field of either.  Moving the
+seam moved nothing: the literals of ``policy_contract_parent.py`` were read
+at the parent commit.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+import indexed_moe_tiny
+import lm_tiny
+import loop_tiny
+import moe_tiny
+import sambay_tiny
+from policy_contract_parent import PARENT
+
+from estorch_tpu import ES, JaxAgent, MLPPolicy
+from estorch_tpu.envs import CartPole, TokenScoreEnv
+from estorch_tpu.models import (HybridLM, IndexedMoELM, LoopedLM, MoELM,
+                                SambaYLM)
+from estorch_tpu.models.perturbed import PolicyDeclaration, declaration_of
+from estorch_tpu.parallel.engine import MANIFEST_BUILD_FACTS
+from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
+                                       hyperscale_mesh,
+                                       partition_rules_to_json)
+from estorch_tpu.parallel.sharded import OUTPUT_REDUCTIONS, ShardedESEngine
+
+# name -> (model, its tiny sizes, devices of the mesh, width of ``model``)
+SEQUENCE_MODELS = {
+    "hybrid": (HybridLM, lm_tiny, 4, 2),
+    "looped": (LoopedLM, loop_tiny, 1, 1),
+    "moe": (MoELM, moe_tiny, 1, 1),
+    "sambay": (SambaYLM, sambay_tiny, 1, 1),
+    "indexed_moe": (IndexedMoELM, indexed_moe_tiny, 1, 1),
+}
+
+
+def build(name):
+    """One of the seven builds the parent's literals were read from."""
+    devices = jax.devices()
+    if name in SEQUENCE_MODELS:
+        policy, tiny, n_devices, shards = SEQUENCE_MODELS[name]
+        return ES(policy=policy, agent=JaxAgent, optimizer=optax.adam,
+                  population_size=8, sigma=0.02, policy_kwargs=tiny.TINY,
+                  agent_kwargs={"env": TokenScoreEnv(**tiny.ENV)},
+                  optimizer_kwargs={"learning_rate": 1e-2},
+                  shard_params=True, model_shards=shards, low_rank=1,
+                  noise_mode="table", table_size=1 << 18,
+                  compute_dtype="bfloat16", device=devices[:n_devices])
+    sharded = {"mlp_sharded": dict(shard_params=True, model_shards=2),
+               "mlp_replicated": {}}[name]
+    return ES(policy=MLPPolicy, agent=JaxAgent, optimizer=optax.adam,
+              population_size=8, sigma=0.1,
+              policy_kwargs={"action_dim": 2, "hidden": (16, 16)},
+              agent_kwargs={"env": CartPole(), "horizon": 20},
+              optimizer_kwargs={"learning_rate": 1e-2},
+              device=devices[:4], **sharded)
+
+
+def sized(engine) -> dict:
+    """What the engine sized from the policy's statements at build."""
+    out = {k: getattr(engine, k) for k in (
+        "pair_chunk", "eval_chunk", "n_eval_chunks", "signs_in_turn")
+        if hasattr(engine, k)}
+    if isinstance(engine, ShardedESEngine):
+        out.update(
+            float32_leaves_kept=sum(
+                d == jnp.float32 for d in engine._leaf_dtypes),
+            factored_leaves=len(engine._factored),
+            selection_bytes=engine._selection_bytes)
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_manifest_gauges_and_sizes_are_the_parents(name, devices8):
+    """(i) Every key and value of ``run_manifest()["config"]``, every gauge
+    and every chunk size, for the five sequence models through the sharded
+    engine's perturbed form and the MLP through both device engines."""
+    es = build(name)
+    want = PARENT[name]
+    config = es.run_manifest()["config"]
+    rules = config.pop("partition_rules", None)
+    assert sorted(config) == sorted(want["config"])
+    assert config == want["config"]
+    assert rules == (partition_rules_to_json(DEFAULT_PARTITION_RULES)
+                     if es._shard_params else None)
+    assert set(MANIFEST_BUILD_FACTS) <= set(config)
+    gauges = es.obs.counters.snapshot()
+    assert sorted(gauges) == sorted(want["gauges"])
+    assert gauges == want["gauges"]
+    assert sized(es.engine) == want["sized"]
+
+
+# ------------------------------------------------- a model declares itself
+
+# what each model states at its tiny sizes: the fields the engine's rules
+# read, as the parent's ``ES`` read them off the module's attributes
+STATED = {
+    "hybrid": dict(attention_widths=8, attention_kv_heads=2, head_width=32),
+    "looped": dict(
+        leaf_rows={"head/kernel": 8}, attention_widths=8,
+        attention_kv_heads=2, head_width=32,
+        facts={"loop_steps": 4, "layer_applications_per_token": 8}),
+    "moe": dict(
+        leaf_rows={"head/kernel": 8}, attention_widths=(8, 4, 6),
+        head_width=32, outputs=("expert_load",),
+        facts={"experts_held": 4, "experts_total": 16,
+               "experts_per_token": 3, "mtp_depth": 1}),
+    "sambay": dict(
+        attention_widths=(4, 0, 8), attention_kv_heads=4, head_width=32,
+        attention_windows={"window": 5, "full_kv": None, "cross": None},
+        scan_widths=(64, 4),
+        facts={"layer_kinds": "mamba,window,mamba_mem,full_kv,gmu,cross",
+               "window": 5, "scan_chunk": 4, "kv_shared_by": 1,
+               "memory_shared_by": 1}),
+    "indexed_moe": dict(
+        leaf_rows={"head/kernel": 8}, attention_widths=8,
+        attention_kv_heads=2, head_width=32,
+        attention_windows={"selected": None},
+        outputs=("expert_load", "selected_pairs"),
+        facts={"experts_held": 4, "experts_total": 16,
+               "experts_per_token": 3, "mtp_depth": 0, "sparse_topk": 6,
+               "index_heads": 2, "index_head_dim": 8,
+               "position_streams": 3}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SEQUENCE_MODELS))
+def test_a_model_declares_itself_once(name):
+    policy, tiny, _, _ = SEQUENCE_MODELS[name]
+    lm = policy(**tiny.TINY)
+    stated = declaration_of(lm)
+    assert stated == lm.declaration()
+    # the leaves it names are the model's own lists (the benchmark's
+    # tolerance scripts read the same properties)
+    leaves = {field: tuple(getattr(lm, field, ())) for field in (
+        "stacked_leaves", "dense_noise_leaves", "float32_leaves")}
+    per_token = dict.fromkeys(leaves["stacked_leaves"], 3 * 1.25 / 4)
+    selection = getattr(lm, "selection_bytes", None)
+    assert stated == PolicyDeclaration(
+        **leaves, leaf_rows_per_token=per_token, selection_bytes=selection,
+        **STATED[name])
+    assert set(stated.outputs) <= set(OUTPUT_REDUCTIONS)
+    if selection is not None:
+        assert stated.selection_bytes(21) == 21 * 21 + 4 * 2 * 8 * 21
+
+
+def test_a_module_that_states_nothing_gets_the_defaults(devices8):
+    """(ii) The MLP declares nothing: the default declaration, and an
+    engine that resolves no form of a sequence model."""
+    assert declaration_of(MLPPolicy(action_dim=2, hidden=(16, 16))) == (
+        PolicyDeclaration())
+    assert declaration_of(object()) == PolicyDeclaration()
+    engine = build("mlp_sharded").engine
+    assert engine.policy == PolicyDeclaration()
+    assert (engine.attention_form, engine.head_form, engine.scan_form) == (
+        None, None, None)
+    facts = engine.build_facts()
+    assert [facts[k] for k in ("attention_form", "attention_form_by_kind",
+                               "attention_form_why", "head_form",
+                               "scan_form")] == [None] * 5
+
+
+# ------------------------------------------- what the policy returns, named
+
+def _engine_with(es, policy):
+    """``es``'s sharded engine rebuilt from another declaration."""
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    return ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=1,
+                                   devices=jax.devices()[:1]),
+        noise_mode="table", perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        policy=policy)
+
+
+@pytest.fixture(scope="module")
+def moe_es():
+    return build("moe")
+
+
+def test_an_output_without_a_reduction_is_refused_at_build(moe_es):
+    """(iii) The engine reduces a policy's outputs by NAME: one it has no
+    reduction for stops the build, and the message lists the names it
+    knows."""
+    stated = declaration_of(moe_es.module)
+    with pytest.raises(ValueError) as refused:
+        _engine_with(moe_es, dataclasses.replace(
+            stated, outputs=("expert_load", "router_entropy")))
+    assert "('expert_load', 'router_entropy')" in str(refused.value)
+    assert "['expert_load', 'selected_pairs']" in str(refused.value)
+
+
+@pytest.mark.parametrize("outputs, returned", [
+    ((), "argument 2 is longer"),
+    (("expert_load", "selected_pairs"), "argument 2 is shorter"),
+])
+def test_a_tuple_of_another_length_is_refused_at_trace_time(
+        moe_es, outputs, returned):
+    """(iv) ``MoELM`` returns (score, behaviour, expert load): declared
+    with fewer or more outputs, the generation program does not trace;
+    nothing is read by position and found to be something else."""
+    engine = _engine_with(moe_es, dataclasses.replace(
+        declaration_of(moe_es.module), outputs=outputs))
+    with pytest.raises(ValueError, match=returned):
+        engine._generation_step.lower(moe_es.state, moe_es.table.data)
+
+
+def test_the_declared_outputs_reach_the_metrics_by_name(moe_es):
+    engine = _engine_with(moe_es, declaration_of(moe_es.module))
+    _, metrics = jax.eval_shape(
+        engine._generation_step, moe_es.state, moe_es.table.data)
+    assert metrics["expert_load"].shape == (
+        moe_es.module.n_routed_experts,)
+    assert "selected_pairs" not in metrics

@@ -180,10 +180,10 @@ def test_the_six_layer_cut_keeps_the_published_indices():
     assert published["published_layer_types"] == list(layer_kinds(32))
     assert published["layers_held"] == list(lm.layer_indices)
     assert (lm.kv_shared_by, lm.memory_shared_by) == (1, 1)
-    assert lm.attention_windows == {"window": 512, "full_kv": None,
-                                    "cross": None}
+    assert lm.declaration().attention_windows == {
+        "window": 512, "full_kv": None, "cross": None}
     assert SambaYLM(**{**kwargs, "layer_indices": (16, 17)}
-                    ).attention_windows == {"full_kv": None}
+                    ).declaration().attention_windows == {"full_kv": None}
     for index, want in [(1, 0.3555), (17, 0.7963), (19, 0.7980)]:
         assert lambda_init(index) == pytest.approx(
             0.8 - 0.6 * math.exp(-0.3 * index))

@@ -614,7 +614,12 @@ class TestQuantileHonesty:
         server's histogram-derived quantiles for the same run must agree
         within the bucket ladder's documented error bound, plus a small
         absolute allowance for what the client clock sees and the
-        batcher's cannot (HTTP parse + event-wakeup, loopback-scale)."""
+        batcher's cannot (HTTP parse + event-wakeup, loopback-scale).
+        The lower bound is the one that finds a stall between the server's
+        spans and the wire: until PR 43 a reply's body waited ~40 ms for
+        the ACK of its headers (two sends under Nagle's algorithm; client
+        p50 48 ms against 3 ms here), which no server span saw; the
+        handlers set ``disable_nagle_algorithm`` since."""
         from estorch_tpu.serve import PolicyServer
         from estorch_tpu.serve.loadgen import _percentile, run_load
 

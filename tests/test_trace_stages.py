@@ -16,6 +16,7 @@ import pytest
 
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole
+from estorch_tpu.models.perturbed import declaration_of
 from estorch_tpu.obs.spans import Telemetry
 from estorch_tpu.obs.trace import (ATTN, DENSE, DIFF, DISPATCH, ENV, EXIT,
                                    EXPERT, GATHER, GMU, GRAD, HEAD, INDEX,
@@ -618,12 +619,7 @@ def _looped_engine_on(devices, model_shards, head_dim, length, latent=False,
         es.config, hyperscale_mesh(model_shards=model_shards, devices=devices),
         partition_rules=es._partition_rules, noise_mode="table",
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
-        leaf_rows=es.module.leaf_rows,
-        attention_widths=es.module.attention_widths,
-        head_width=es.module.head_width,
-        leaf_rows_per_token=getattr(es.module, "leaf_rows_per_token", None),
-        float32_leaves=getattr(es.module, "float32_leaves", ()),
-        expert_load=latent)
+        policy=declaration_of(es.module))
     return es, engine
 
 
@@ -739,13 +735,7 @@ def _sambay_engine_on(chip, **policy_over):
         es.config, hyperscale_mesh(model_shards=1, devices=[chip]),
         partition_rules=es._partition_rules, noise_mode="table",
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
-        attention_widths=es.module.attention_widths,
-        head_width=es.module.head_width,
-        scan_widths=es.module.scan_widths,
-        float32_leaves=es.module.float32_leaves,
-        attention_windows=es.module.attention_windows,
-        attention_kv_heads=es.module.num_key_value_heads,
-        dense_noise_leaves=es.module.dense_noise_leaves)
+        policy=declaration_of(es.module))
     return es, engine
 
 
@@ -842,7 +832,7 @@ def test_kernel_form_books_a_differential_pairs_kernel_by_its_kind(v5e_chip):
     each to ``sambay.full_attn_share`` / ``sambay.window_attn_share`` as
     before."""
     es, engine = _sambay_engine_on(v5e_chip)
-    assert es.module.attention_widths == (64, 0, 128)
+    assert es.module.declaration().attention_widths == (64, 0, 128)
     assert es.engine.attention_form_by_kind == (
         "window:xla,full_kv:xla,cross:xla")        # a CPU mesh
     assert engine.attention_form == "kernel"
@@ -1000,7 +990,7 @@ def test_kernel_form_books_the_selected_attention_by_its_kind(v5e_chip):
         shard_params=True, low_rank=1, noise_mode="table",
         compute_dtype="bfloat16", table_size=1 << 18,
         device=jax.devices()[:1])
-    assert es.module.attention_widths == 128
+    assert es.module.declaration().attention_widths == 128
     assert es.engine.attention_form_by_kind == "selected:xla"   # a CPU mesh
     lr_apply, lr_spec = es._perturbed_form(
         jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
@@ -1009,14 +999,7 @@ def test_kernel_form_books_the_selected_attention_by_its_kind(v5e_chip):
         es.config, hyperscale_mesh(model_shards=1, devices=[v5e_chip]),
         partition_rules=es._partition_rules, noise_mode="table",
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
-        leaf_rows=es.module.leaf_rows,
-        attention_widths=es.module.attention_widths,
-        head_width=es.module.head_width,
-        leaf_rows_per_token=es.module.leaf_rows_per_token,
-        float32_leaves=es.module.float32_leaves, expert_load=True,
-        attention_windows=es.module.attention_windows,
-        attention_kv_heads=es.module.num_key_value_heads,
-        selection_bytes=es.module.selection_bytes)
+        policy=declaration_of(es.module))
     assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
     assert engine.attention_form_why == (
         "one TPU device, whole column blocks, whole row blocks")
