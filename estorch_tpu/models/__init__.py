@@ -1,3 +1,4 @@
+from .cca_moe_lm import CCAMoELM
 from .hybrid_lm import HybridLM
 from .indexed_moe_lm import IndexedMoELM
 from .looped_lm import LoopedLM
@@ -21,6 +22,7 @@ def __getattr__(name):
 
 
 __all__ = [
+    "CCAMoELM",
     "HybridLM",
     "IndexedMoELM",
     "LoopedLM",

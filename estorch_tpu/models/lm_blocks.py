@@ -1,10 +1,13 @@
-"""The pieces the FIVE sequence models are built from
+"""The pieces the SIX sequence models are built from
 (models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py,
-models/sambay_lm.py, models/indexed_moe_lm.py): ONE RMSNorm, ONE LayerNorm,
-ONE gated SiLU FFN, ONE causal attention core (full, banded or over a
-learned SELECTION of keys), ONE indexer that makes such a selection, ONE
-differential combine, ONE depthwise causal conv, ONE expert layer (routed
-by sigmoid scores with a selection bias, or by a softmax) and ONE
+models/sambay_lm.py, models/indexed_moe_lm.py, models/cca_moe_lm.py): ONE
+RMSNorm, ONE LayerNorm, ONE gated SiLU FFN, ONE causal attention core (full,
+banded or over a learned SELECTION of keys), ONE indexer that makes such a
+selection, ONE differential combine, ONE depthwise causal conv, the mixing
+of q, k and v inside a compressed latent (a per-head convolution, the q-k
+mean, the value shift, the L2 scale), ONE expert layer (routed by sigmoid
+scores or by a softmax, of ONE matrix or of an MLP over a carried state,
+with or without a selection bias, renormalised or not) and ONE
 next-token scorer, each on the perturbed-dense primitive
 (models/perturbed.py), so that an optimisation of one is measured on every
 model that calls it.  The attention core and the scorer have TWO forms each
@@ -140,10 +143,11 @@ import functools
 import numpy as np
 
 from ..obs.trace import (ATTN, DENSE, DIFF, DISPATCH, EXPERT, HEAD, INDEX,
-                         PERTURB, ROPE, ROUTE, SELECT, part, stage)
+                         MIX, PERTURB, ROPE, ROUTE, SELECT, part, stage)
 from ..ops import pallas_attention, pallas_head
 from .perturbed import (F32, is_factored, perturbed_dense,
-                        perturbed_grouped_dense, perturbed_leaf)
+                        perturbed_grouped_dense, perturbed_headwise_dense,
+                        perturbed_leaf)
 
 # rows the expert layer takes at a time, over what a uniform router sends
 # its held experts: one pass nearly always, and the loop takes the rest
@@ -202,7 +206,7 @@ def causal_conv(x, taps, bias):
 def dense(p, noise, c, name, x, bias: str | None = None,
           under: str = DENSE):
     """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``, the
-    part ``of.<name>``: every projection of the five models says here
+    part ``of.<name>``: every projection of the six models says here
     which leaf it multiplies (obs/trace.py).  ``bias``: the key of a bias
     leaf of ``p`` (perturbed like any small leaf), added to the product.
     ``under``: the stage of a projection that belongs to another one (the
@@ -262,11 +266,20 @@ def rotary_tables(length: int, head_dim: int, theta: float,
         return jnp.cos(angle), jnp.sin(angle)
 
 
-def rotate(x, cos, sin, interleaved: bool = False):
+def rotate(x, cos, sin, interleaved: bool = False,
+           rotary_dim: int | None = None):
     """Rotary embedding of ``x [T, heads, head_dim]`` float32.  Frequency
     ``i`` turns the pair ``(x_i, x_{i+d/2})`` in the halves convention
     (``x·cos + rotate_half(x)·sin``) and the pair ``(x_{2i}, x_{2i+1})``
-    when ``interleaved``; each pair stays where it was."""
+    when ``interleaved``; each pair stays where it was.  ``rotary_dim``
+    (a ``partial_rotary_factor``): the LEADING ``rotary_dim`` of each head
+    turn, by the ``rotary_dim / 2`` frequency pairs of ``cos`` and ``sin``,
+    the convention inside that slice; the rest of the head stays as it is.
+    ``None``, or the whole head: the program of no ``rotary_dim``."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [rotate(x[..., :rotary_dim], cos, sin, interleaved),
+             x[..., rotary_dim:]], axis=-1)
     half = x.shape[-1] // 2
     x1, x2 = ((x[..., 0::2], x[..., 1::2]) if interleaved
               else (x[..., :half], x[..., half:]))
@@ -309,7 +322,9 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     grouped heads: ``q [T, heads(, ·) qk width]``, ``k [T, kv heads(, ·)
     qk width]`` and ``v [T, kv heads(, ·) value width]`` in the compute
     dtype, heads split or not.  The value width may differ from the
-    query/key width.  ``v=None``: ``k [T, kv heads(, ·) qk width + value
+    query/key width.  ``scale`` is ONE number of the program, the same for
+    every head; a scale that is learned, or a head's own (a temperature),
+    arrives folded into ``k`` (:func:`l2_scale`), the one way.  ``v=None``: ``k [T, kv heads(, ·) qk width + value
     width]`` holds each head's key with its values beside it, as one
     projection wrote them.  ``q_shared [T, heads(, ·) shared width]`` and
     ``k_shared [T, shared width]``: a second part of every head's query
@@ -427,6 +442,76 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
                 "kgqs,skd->qkgd", prob, vh[first:stop],
                 preferred_element_type=F32).astype(dtype))
     return jnp.concatenate(ctx).reshape(t, nq * vd)
+
+
+# ------------------------------------------- mixing inside a compressed latent
+# (CCA, arXiv 2510.04476: attention whose q, k and v live in a latent
+# narrower than the residual and are mixed there, over time and across
+# each other, before the scores; each piece alone, under es.mix and a part)
+
+def head_conv(x, w, noise, c, bias, taps: int):
+    """Causal convolution over time that MIXES the channels of each head,
+    float32 ``[T, heads, width]``: ``y_t[h] = Σ_j x_{t-(taps-1-j)}[h] ·
+    (C_j[h] + c·E_j[h]) + bias[h]``, zero before the sequence, the last tap
+    the current position's (as :func:`causal_conv`).  ``x [T, heads,
+    width]`` in the compute dtype; ``w [taps · heads, width, width]`` the
+    (tap, head) matrices stacked tap-major (``w[j · heads + h] = C_j[h]``),
+    a stacked leaf whose ``noise`` is one factor pair a matrix
+    (ops/lowrank.py) or a dense array; ``bias [heads · width]`` float32, as
+    a member reads it.  Written as ``taps`` head-wise matmuls on shifted
+    inputs (``perturbed.perturbed_headwise_dense``): the centre's product
+    is shared by the members and the correction books to ``es.perturb``."""
+    t, heads, width = x.shape
+    with stage(MIX), part("conv_head"):
+        # head-major while the taps add up, as the batched products are
+        y = bias.reshape(heads, 1, width)
+        for j in range(taps):
+            back = taps - 1 - j
+            x_j = x if back == 0 else jnp.pad(
+                x, ((back, 0), (0, 0), (0, 0)))[:t]
+            of_tap = slice(j * heads, (j + 1) * heads)
+            y = y + perturbed_headwise_dense(
+                x_j, w[of_tap],
+                None if noise is None else (
+                    tuple(f[of_tap] for f in noise) if is_factored(noise)
+                    else noise[of_tap]), c)
+        return y.transpose(1, 0, 2)
+
+
+def qk_mean(q, k, q_before, k_before):
+    """``(q + ½(q̃ + repeat(k̃)), k + ½(mean over its group of q̃ + k̃))``
+    float32: what the convolutions made of q ``[T, heads, width]`` and k
+    ``[T, kv heads, width]``, each with the mean of BOTH projections'
+    values from before the convolutions (``q̃``, ``k̃``) across the
+    query-group boundary: query head ``i`` pairs with key head ``i //
+    (heads / kv heads)``, as it does in the scores."""
+    t, nq, width = q_before.shape
+    nkv = k_before.shape[1]
+    with stage(MIX), part("qk_mean"):
+        of_group = q_before.reshape(t, nkv, nq // nkv, width).mean(axis=2)
+        return (q + 0.5 * (q_before + jnp.repeat(k_before, nq // nkv, axis=1)),
+                k + 0.5 * (of_group + k_before))
+
+
+def value_shift(v):
+    """``v [T, kv heads, width]`` with the LAST half of its heads read from
+    the position before (zeros at position 0): the first half of the value
+    heads are projections of ``u_t``, the others of ``u_{t-1}``."""
+    t, nkv = v.shape[:2]
+    with stage(MIX), part("value_shift"):
+        before = jnp.pad(v[:, nkv // 2:], ((1, 0), (0, 0), (0, 0)))[:t]
+        return jnp.concatenate([v[:, :nkv // 2], before], axis=1)
+
+
+def l2_scale(x, scale, eps: float):
+    """``√width · scale · x / ‖x‖₂`` over the last axis, float32: ``x /
+    √(mean x² + eps) · scale``, an RMSNorm whose weight is ONE number a
+    head.  ``scale`` broadcasts against ``x [T, heads, width]`` (``[heads,
+    1]``: a learned temperature a head; ``1.0``: none).  Scores of two such
+    vectors are ``width · scale_q · scale_k · cos``: bounded, whatever the
+    projections' norms."""
+    with stage(MIX), part("qk_norm"):
+        return rmsnorm(x, scale, eps)
 
 
 # ---------------------------------------------------- the learned selection
@@ -551,7 +636,7 @@ def differential_combine(ctx, lam, gamma, *, pairs: int, group: int,
 # ------------------------------------------------------- the expert layer
 
 def route(p, noise, c, u, *, top_k: int, scaling: float,
-          scoring: str = "sigmoid"):
+          scoring: str = "sigmoid", logits=None, renormalise: bool = True):
     """``(experts [T, top_k] int32, weights [T, top_k] float32)`` of the
     tokens ``u [T, hidden]`` float32 over ALL the experts the router
     ``p["router"] [hidden, experts]`` scores, held here or not.
@@ -559,26 +644,77 @@ def route(p, noise, c, u, *, top_k: int, scaling: float,
     ``top_k`` of ``s + bias`` (ties to the lower index), weights ``s`` at
     the chosen (the selection bias ``p["router_bias"]`` enters the choice
     only).  ``"softmax"`` (Qwen3-MoE): ``s = softmax(u W_r)`` over all
-    experts, the ``top_k`` of ``s``, no bias leaf.  Both renormalised to
-    sum ``scaling``.  All in float32, the matmul at ``highest`` precision:
-    a rounding of the scores picks another expert."""
+    experts, the ``top_k`` of ``s``; where ``p`` holds a ``router_bias``
+    it enters the choice as under ``"sigmoid"``.  ``logits [T, experts]``
+    float32: the scores of a router that is more than one matrix
+    (:func:`state_router`), in place of ``u W_r``.  Renormalised to sum
+    ``scaling``, or with ``renormalise=False`` ``scaling · s`` at the
+    chosen as they are (at ``top_k`` 1 a renormalised weight is 1 whatever
+    the router says).  All in float32, the matmul at ``highest``
+    precision: a rounding of the scores picks another expert."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"scoring {scoring!r}: 'sigmoid' or 'softmax'")
+    def scored(z):
+        return (jax.nn.sigmoid(z) if scoring == "sigmoid"
+                else jax.nn.softmax(z, axis=-1))
+
     with stage(ROUTE), jax.default_matmul_precision("highest"):
-        with part("router"):
-            s = perturbed_dense(
-                u.astype(F32), p["router"].astype(F32),
-                None if noise is None else noise["router"], c)
-            s = (jax.nn.sigmoid(s) if scoring == "sigmoid"
-                 else jax.nn.softmax(s, axis=-1))
+        if logits is None:
+            with part("router"):
+                s = scored(perturbed_dense(
+                    u.astype(F32), p["router"].astype(F32),
+                    None if noise is None else noise["router"], c))
+        else:
+            s = scored(logits)
         picked_by = s
-        if scoring == "sigmoid":
+        if scoring == "sigmoid" or "router_bias" in p:
             picked_by = s + perturbed_leaf(
                 p["router_bias"],
                 None if noise is None else noise["router_bias"], c)
         _, experts = jax.lax.top_k(picked_by, top_k)
         w = jnp.take_along_axis(s, experts, axis=-1)
+        if not renormalise:
+            return experts, scaling * w
         return experts, scaling * w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+
+
+def state_router(p, noise, c, u, below, eps: float):
+    """``(logits [T, experts], state [T, router width])`` float32 of a
+    router that is an MLP over a state carried from layer to layer (ZAYA1,
+    arXiv 2511.17127): ``r = u W_dn + b_dn + γ ⊙ below``; ``logits = W₃
+    gelu(W₂ gelu(W₁ rmsnorm(r) + b₁) + b₂) + b₃`` (the exact GELU, by
+    ``erf``); ``r`` is handed to the layer above AFTER γ's term is added.
+    ``p``: ``router_down``, ``router_down_bias``, ``router_state`` (γ),
+    ``router_norm/scale`` and ``router_mlp/{w1, b1, w2, b2, w3, b3}``;
+    ``below [T, router width]`` float32, zeros under the first layer.  All
+    in float32 at ``highest`` precision, as :func:`route`, which takes the
+    logits."""
+    def leaf(*path):
+        w = p
+        for k in path:
+            w = w[k]
+        return w, subtree(noise, *path)
+
+    def affine(x, weight, bias):
+        w, w_noise = leaf(*weight)
+        b, b_noise = leaf(*bias)
+        return (perturbed_dense(x, w.astype(F32), w_noise, c)
+                + perturbed_leaf(b, b_noise, c))
+
+    with stage(ROUTE), jax.default_matmul_precision("highest"):
+        with part("router_down"):
+            r = affine(u.astype(F32), ("router_down",),
+                       ("router_down_bias",))
+        with part("router_state"):
+            r = r + perturbed_leaf(*leaf("router_state"), c) * below
+        with part("router_mlp"):
+            x = rmsnorm(r, perturbed_leaf(*leaf("router_norm", "scale"), c),
+                        eps)
+            for i in (1, 2):
+                x = jax.nn.gelu(affine(x, ("router_mlp", f"w{i}"),
+                                       ("router_mlp", f"b{i}")),
+                                approximate=False)
+            return affine(x, ("router_mlp", "w3"), ("router_mlp", "b3")), r
 
 
 def routed_ffn(moe, noise, c, u, dtype, *, top_k: int, scaling: float,

@@ -14,7 +14,9 @@ ONE population-wide matmul, and only the correction is per member:
 A stack of experts ``W [E, m, n]`` takes the grouped form
 (:func:`perturbed_grouped_dense`): rows sorted by expert go through ONE
 grouped matmul against the stack, whichever members they belong to, and
-the correction is per row from its (member, expert)'s factor pair.
+the correction is per row from its (member, expert)'s factor pair.  A
+stack of one matrix a HEAD that every row passes (a per-head convolution's
+taps) is a batched matmul (:func:`perturbed_headwise_dense`).
 
 The embedding lookup and the tied head take the same factors:
 ``E[tok] + c·A[tok]·Bᵀ/√r`` and ``h@Eᵀ + c·(h@B)@Aᵀ/√r``.  Both dots
@@ -127,6 +129,30 @@ def perturbed_grouped_dense(x, w, group_sizes, noise, c, row_expert,
                               b[row_member, row_expert].astype(F32))
 
 
+def perturbed_headwise_dense(x, w, noise, c):
+    """float32 ``x[:, h] @ (W[h] + c·E[h])`` HEAD-MAJOR, ``[H, T, n]``, for
+    ``x [T, H, m]`` against a stack ``W [H, m, n]`` of one matrix a head:
+    every row passes EVERY matrix of the stack with its own slice (a
+    batched matmul; nothing is routed, so no sort and no groups).  The
+    output keeps the batched product's own order, heads first: the caller
+    sums its terms and turns the sum once.  ``noise``: ``None``, ``(A [H,
+    m, r], B [H, n, r])`` one factor pair a matrix (a stacked leaf of
+    ops/lowrank.py), or a dense ``[H, m, n]`` array; ``c`` a scalar."""
+    y = jnp.einsum("thm,hmn->htn", x, w, preferred_element_type=F32)
+    if noise is None:
+        return y
+    with stage(PERTURB):
+        if not is_factored(noise):
+            return y + c * jnp.einsum("thm,hmn->htn", x,
+                                      noise.astype(x.dtype),
+                                      preferred_element_type=F32)
+        a, b = noise
+        xa = jnp.einsum("thm,hmr->htr", x, a.astype(x.dtype),
+                        preferred_element_type=F32)
+        scale = c / jnp.sqrt(jnp.asarray(a.shape[-1], F32))
+        return y + scale * jnp.einsum("htr,hnr->htn", xa, b.astype(F32))
+
+
 def perturbed_embed(tokens, table, noise, c):
     """float32 rows ``(E + c·A·Bᵀ/√r)[tokens]``: the lookup reads the
     centre's rows and the factor ``A``'s rows, never a perturbed table."""
@@ -168,8 +194,9 @@ class PolicyDeclaration:
       leaves a sequence does NOT pass whole (an untied head run in blocks);
     - ``leaf_rows_per_token``: ``{stacked leaf path: rows of the leaf's
       input per position}`` (an expert sees the pairs routed to it);
-    - ``stacked_leaves``: leaves whose leading axis indexes experts, one
-      factor pair an expert; ``dense_noise_leaves``: 2-D leaves no matmul
+    - ``stacked_leaves``: leaves whose leading axis indexes experts (or a
+      per-head convolution's (tap, head) matrices), one factor pair a
+      matrix of the stack; ``dense_noise_leaves``: 2-D leaves no matmul
       reads, dense noise whatever the factoring rule says of their shape;
     - ``float32_leaves``: leaves the forward reads in float32 whatever the
       compute dtype;

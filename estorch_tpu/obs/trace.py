@@ -52,7 +52,7 @@ _PART_NAME = re.compile(r"[A-Za-z0-9_]+(?:\.[A-Za-z0-9_]+)*")
 # the stages of one generation, in program order (docs/observability.md)
 STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
           DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH, EXPERT, GMU,
-          DIFF, INDEX, SELECT) = (
+          DIFF, INDEX, SELECT, MIX) = (
     "sample",    # offsets, signs, member keys
     "noise",     # reading eps: the table gather and the slab it builds
     "perturb",   # theta + sigma * sign * eps, unravel, cast; the rank-r
@@ -65,7 +65,8 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "update",    # weight decay, optax step, sigma decay, obs-norm probe
     # nested inside es.policy by a sequence model (models/hybrid_lm.py,
     # models/looped_lm.py, models/moe_lm.py, models/sambay_lm.py,
-    # models/indexed_moe_lm.py on the pieces of models/lm_blocks.py)
+    # models/indexed_moe_lm.py, models/cca_moe_lm.py on the pieces of
+    # models/lm_blocks.py)
     "dense",     # the shared x@W projections and the gated FFN
     "ssm",       # conv1d, dt and decay, the scan (Mamba-2's chunked form,
                  # Mamba-1's selective one), the gate
@@ -77,7 +78,9 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "exit",      # a looped model's exit gate, the exit distribution and
                  # the weighting of the per-pass scores
     "route",     # an expert layer's router: its matmul, sigmoid, selection
-                 # bias, top-k and the renormalised weights
+                 # bias, top-k and the renormalised weights (a router that
+                 # is an MLP over a carried state names its parts:
+                 # of.router_down, of.router_state, of.router_mlp)
     "dispatch",  # sorting (token, k) pairs by held expert, the gather into
                  # expert order and the weighted combine back
     "expert",    # the grouped matmuls over the routed rows and the
@@ -93,6 +96,12 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "select",    # the choice of the topk largest index scores a query (the
                  # bisection on the k-th largest, the ties) and the write of
                  # the [T, T] selection the attention reads
+    "mix",       # what attention in a compressed latent does to q, k and v
+                 # between their projections and the scores (parts
+                 # conv_time, conv_head, qk_mean, value_shift, qk_norm): the
+                 # two causal convolutions over q and k, the q-k mean, the
+                 # values' shift by one position, the L2 scale under the
+                 # learned temperature
 )
 
 

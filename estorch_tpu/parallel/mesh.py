@@ -199,11 +199,30 @@ INDEXED_MOE_LM_PARTITION_RULES = (
     (r"k_norm/scale$", P()),
 )
 
+# A sparse-expert decoder whose attention is computed inside a compressed
+# latent (models/cca_moe_lm.py).  Its q, k, v, o, embedding, stacked experts
+# and selection bias go by the rules above that name them.  The head-mixing
+# convolution's STACKED ``[taps · heads, d, d]`` matrices shard their
+# (tap, head) axis, as the experts shard theirs; the depthwise taps, both
+# convolutions' biases and the temperatures are a few thousand values that
+# every head's slice reads and replicate.  The router (a down-projection
+# into a norm over its whole width, a state's scale, a three-matrix MLP 256
+# wide) replicates, as the one-matrix routers do: every device routes every
+# token.
+CCA_MOE_LM_PARTITION_RULES = (
+    (r"attn/conv_head$", P(MODEL_AXIS, None, None)),
+    (r"attn/(conv_time|conv_time_bias|conv_head_bias|temperature)$", P()),
+    (r"moe/(router_down|router_down_bias|router_state)$", P()),
+    (r"moe/router_norm/scale$", P()),
+    (r"moe/router_mlp/[wb][123]$", P()),
+)
+
 CATCH_ALL = r".*"
 
 DEFAULT_PARTITION_RULES = (
         HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
-        + SAMBAY_LM_PARTITION_RULES + INDEXED_MOE_LM_PARTITION_RULES) + (
+        + SAMBAY_LM_PARTITION_RULES + INDEXED_MOE_LM_PARTITION_RULES
+        + CCA_MOE_LM_PARTITION_RULES) + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),

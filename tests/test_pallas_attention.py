@@ -523,6 +523,61 @@ class TestKernelAgainstBothForms:
 
 # --------------------------------------------------------------- the rule
 
+class TestTheCallersScale:
+    """A model whose scores are cosines under a learned temperature
+    (models/cca_moe_lm.py) hands the core L2-scaled q and k, the
+    temperature folded into k, and the one scale ``1/√d``: no operand of
+    the kernel is new."""
+
+    @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                            (jnp.bfloat16, BF16_TOL)],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("tau", [(1.0, 1.0), (0.5, 2.0)],
+                             ids=["unit", "two_temperatures"])
+    def test_eight_over_two_heads_of_128_under_a_temperature(self, tau,
+                                                             dtype, tol):
+        """8 query heads over 2 key-value heads of 128, 256 positions in
+        the kernel's blocks of 128: the kernel (called directly and through
+        the scope) against the XLA form of ``attention_core`` and against a
+        plain softmax of ``√d · τ · cos``."""
+        t, nq, nkv, d = 256, 8, 2, 128
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = lm_blocks.l2_scale(
+            jax.random.normal(ks[0], (t, nq, d)), 1.0, 0.0).astype(dtype)
+        k = lm_blocks.l2_scale(
+            jax.random.normal(ks[1], (t, nkv, d)),
+            jnp.asarray(tau)[:, None], 0.0).astype(dtype)
+        v = jax.random.normal(ks[2], (t, nkv, d)).astype(dtype)
+        scale = d ** -0.5
+
+        def through_the_core():
+            return lm_blocks.attention_core(
+                q, k, v, num_heads=nq, num_kv_heads=nkv, scale=scale,
+                block=128)
+
+        xla = through_the_core()
+        got = causal_attention(
+            q.reshape(t, -1), k.reshape(t, -1), v.reshape(t, -1),
+            num_heads=nq, num_kv_heads=nkv, head_dim=d, scale=scale,
+            block_q=128, block_k=128, interpret=True)
+        np.testing.assert_allclose(_f32(got), _f32(xla), atol=tol)
+        with kernel_scope(interpret=True):
+            scoped = through_the_core()
+        np.testing.assert_allclose(_f32(scoped), _f32(xla), atol=tol)
+        # the formula: scores are sqrt(d) x tau x cos, bounded
+        qf, kf, vf = (_f32(x) for x in (q, k, v))
+        mask = np.tril(np.ones((t, t), bool))
+        for h in (0, 3, 4, 7):
+            g = h // (nq // nkv)
+            scores = qf[:, h] @ kf[:, g].T * scale
+            assert np.abs(scores).max() <= np.sqrt(d) * tau[g] * 1.01
+            scores = np.where(mask, scores, -np.inf)
+            prob = np.exp(scores - scores.max(axis=-1, keepdims=True))
+            prob /= prob.sum(axis=-1, keepdims=True)
+            np.testing.assert_allclose(
+                _f32(xla)[:, h * d:(h + 1) * d], prob @ vf[:, g], atol=tol)
+
+
 class TestTheRule:
     @pytest.mark.parametrize("platform, devices, widths, length, form", [
         ("tpu", 1, 128, 4096, "kernel"),   # ouro-2.6b-es-4k-1chip

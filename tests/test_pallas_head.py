@@ -150,6 +150,38 @@ class TestKernelAgainstTheXlaForm:
         assert got.dtype == jnp.float32
         np.testing.assert_allclose(got, want, atol=1e-4, rtol=0)
 
+    @pytest.mark.parametrize("dtype, tol", [(jnp.float32, F32_TOL),
+                                            (jnp.bfloat16, 2e-4)],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("vocab", [4096, 4112], ids=["whole", "tail"])
+    def test_the_tied_layout_at_the_first_cells_width_that_runs_it(
+            self, vocab, dtype, tol):
+        """``zaya1-es-8k-1chip`` is the first cell whose head is the TIED
+        layout in the kernel: a ``[vocabulary, 2048]`` table read transposed
+        under rank-1 factors whose ``A`` is the table's rows' and ``B`` the
+        hidden width's.  Hidden 2,048 as in the cell, a vocabulary of whole
+        4,096-row tiles and one with a short last tile (the cell's 32,784
+        rows end in one), one pair's two signs over 512 positions: the XLA
+        form's scores."""
+        h, w, noise, tokens = _head(vocab, True, 1, pairs=1, hidden=2048,
+                                    dtype=dtype, seed=3)
+        w = (w.astype(jnp.float32) / 8).astype(dtype)   # logits of spread 4
+
+        def score():    # a new function a trace: jit caches by function
+            return _nested(_through_blocks(True, None), w, noise)
+
+        want = jax.jit(score())(h, tokens)
+        with kernel_scope(interpret=True):
+            assert len(pallas_calls(score(), h, tokens)) == 1
+            got = jax.jit(score())(h, tokens)
+        assert got.shape == want.shape == (1, 2, LENGTH - 1)
+        assert got.dtype == jnp.float32
+        # (scores down to -190 under the rank-1 correction: the float32
+        # sums' order shows in the seventh digit)
+        np.testing.assert_allclose(got, want, atol=tol, rtol=1e-6)
+        # the two signs differ by the correction alone
+        assert float(jnp.abs(got[:, 0] - got[:, 1]).max()) > 1e-2
+
     @pytest.mark.parametrize("rank", [1, 6], ids=["r1", "r6"])
     @pytest.mark.parametrize("block_rows, block_vocab", [(16, 32), (32, 128)])
     def test_several_row_tiles_a_member_read_that_members_factor(

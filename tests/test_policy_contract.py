@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import optax
 import pytest
 
+import cca_moe_tiny
 import indexed_moe_tiny
 import lm_tiny
 import loop_tiny
@@ -24,8 +25,8 @@ from policy_contract_parent import PARENT
 
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole, TokenScoreEnv
-from estorch_tpu.models import (HybridLM, IndexedMoELM, LoopedLM, MoELM,
-                                SambaYLM)
+from estorch_tpu.models import (CCAMoELM, HybridLM, IndexedMoELM, LoopedLM,
+                                MoELM, SambaYLM)
 from estorch_tpu.models.perturbed import PolicyDeclaration, declaration_of
 from estorch_tpu.parallel.engine import MANIFEST_BUILD_FACTS
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
@@ -40,6 +41,8 @@ SEQUENCE_MODELS = {
     "moe": (MoELM, moe_tiny, 1, 1),
     "sambay": (SambaYLM, sambay_tiny, 1, 1),
     "indexed_moe": (IndexedMoELM, indexed_moe_tiny, 1, 1),
+    # added after the seam moved: no literal of the parent's to hold it to
+    "cca_moe": (CCAMoELM, cca_moe_tiny, 1, 1),
 }
 
 
@@ -130,6 +133,16 @@ STATED = {
                "experts_per_token": 3, "mtp_depth": 0, "sparse_topk": 6,
                "index_heads": 2, "index_head_dim": 8,
                "position_streams": 3}),
+    # ONE expert a token over two shares reaches the EXPERTS' stacked
+    # leaves; the head-mixing convolution's stack sees every position
+    "cca_moe": dict(
+        attention_widths=8, attention_kv_heads=2, head_width=32,
+        outputs=("expert_load",),
+        leaf_rows_per_token=lambda lm: dict.fromkeys(lm.expert_leaves,
+                                                     1.25 / 2),
+        facts={"experts_held": 2, "experts_total": 4,
+               "experts_per_token": 1, "mtp_depth": 0, "latent_q_width": 64,
+               "latent_kv_width": 16, "conv_taps": 4, "router_hidden": 16}),
 }
 
 
@@ -143,11 +156,13 @@ def test_a_model_declares_itself_once(name):
     # tolerance scripts read the same properties)
     leaves = {field: tuple(getattr(lm, field, ())) for field in (
         "stacked_leaves", "dense_noise_leaves", "float32_leaves")}
-    per_token = dict.fromkeys(leaves["stacked_leaves"], 3 * 1.25 / 4)
+    fields = dict(STATED[name])
+    per_token = fields.pop("leaf_rows_per_token", lambda lm: dict.fromkeys(
+        leaves["stacked_leaves"], 3 * 1.25 / 4))(lm)
     selection = getattr(lm, "selection_bytes", None)
     assert stated == PolicyDeclaration(
         **leaves, leaf_rows_per_token=per_token, selection_bytes=selection,
-        **STATED[name])
+        **fields)
     assert set(stated.outputs) <= set(OUTPUT_REDUCTIONS)
     if selection is not None:
         assert stated.selection_bytes(21) == 21 * 21 + 4 * 2 * 8 * 21

@@ -20,20 +20,22 @@ from estorch_tpu.models.perturbed import declaration_of
 from estorch_tpu.obs.spans import Telemetry
 from estorch_tpu.obs.trace import (ATTN, DENSE, DIFF, DISPATCH, ENV, EXIT,
                                    EXPERT, GATHER, GMU, GRAD, HEAD, INDEX,
-                                   NOISE, PERTURB, POLICY, PART_PREFIX, RANK,
-                                   ROPE, ROUTE, SAMPLE, SCOPE_PREFIX, SELECT,
-                                   SSM, STAGES, UPDATE, annotate, part, stage,
-                                   trace)
+                                   MIX, NOISE, PERTURB, POLICY, PART_PREFIX,
+                                   RANK, ROPE, ROUTE, SAMPLE, SCOPE_PREFIX,
+                                   SELECT, SSM, STAGES, UPDATE, annotate,
+                                   part, stage, trace)
 
 # the stages of every generation program; a sequence model nests more
 # inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT; a
 # sparse-expert one ROPE, ROUTE, DISPATCH and EXPERT; one with gated memory
 # units and differential attention GMU and DIFF; one whose attention reads a
-# learned selection of keys INDEX and SELECT)
+# learned selection of keys INDEX and SELECT; one whose attention is computed
+# inside a compressed latent MIX)
 GENERATION_STAGES = STAGES[:9]
 EXPERT_STAGES = {ROUTE, DISPATCH, EXPERT}
 SAMBAY_STAGES = {GMU, DIFF}
 INDEXED_STAGES = {INDEX, SELECT}
+LATENT_STAGES = {MIX}
 
 SCOPE = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(SCOPE_PREFIX)
                    + r"([a-z_]+)")
@@ -105,39 +107,56 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source,
     assert any(POLICY in stack for stack in matmuls)
 
 
-# the five sequence models on the sharded engine's perturbed form: what
+# the six sequence models on the sharded engine's perturbed form: what
 # each is built from, the stages its forward does NOT name, the layers it
 # nests inside es.policy, and the parts it names that are no leaf's
 SEQUENCE_MODELS = {
     "sequence": dict(policy="HybridLM", tiny="lm_tiny", devices=4,
                      model_shards=2,
                      absent=({ROPE, EXIT} | EXPERT_STAGES | SAMBAY_STAGES
-                             | INDEXED_STAGES),
+                             | INDEXED_STAGES | LATENT_STAGES),
                      inner=(DENSE, SSM, ATTN, HEAD)),
     "looped": dict(policy="LoopedLM", tiny="loop_tiny", devices=1,
                    model_shards=1,
                    absent=({SSM} | EXPERT_STAGES | SAMBAY_STAGES
-                           | INDEXED_STAGES),
+                           | INDEXED_STAGES | LATENT_STAGES),
                    inner=(DENSE, ATTN, HEAD, ROPE, EXIT)),
     "expert": dict(policy="MoELM", tiny="moe_tiny", devices=1,
                    model_shards=1,
-                   absent={SSM, EXIT} | SAMBAY_STAGES | INDEXED_STAGES,
+                   absent=({SSM, EXIT} | SAMBAY_STAGES | INDEXED_STAGES
+                           | LATENT_STAGES),
                    inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT)),
     # its three kinds of attention say which they are: parts of es.attn
     "sambay": dict(policy="SambaYLM", tiny="sambay_tiny", devices=1,
                    model_shards=1,
-                   absent={ROPE, EXIT} | EXPERT_STAGES | INDEXED_STAGES,
+                   absent=({ROPE, EXIT} | EXPERT_STAGES | INDEXED_STAGES
+                           | LATENT_STAGES),
                    inner=(DENSE, SSM, ATTN, HEAD, GMU, DIFF),
                    more_parts={"window": ATTN, "full": ATTN, "cross": ATTN}),
     # its indexer's three projections are parts of es.index; its one kind
     # of attention, over the selection, says which it is
     "indexed": dict(policy="IndexedMoELM", tiny="indexed_moe_tiny",
                     devices=1, model_shards=1,
-                    absent={SSM, EXIT} | SAMBAY_STAGES,
+                    absent={SSM, EXIT} | SAMBAY_STAGES | LATENT_STAGES,
                     inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT,
                            INDEX, SELECT),
                     more_parts={"selected": ATTN}),
+    # what it does to q, k and v inside the latent are parts of es.mix (the
+    # convolutions' leaves among them, which are no matrices of a tree); its
+    # router's state scale is a vector, a part of es.route beside the
+    # router's matrices
+    "latent": dict(policy="CCAMoELM", tiny="cca_moe_tiny", devices=1,
+                   model_shards=1, held=2,
+                   absent={SSM, EXIT} | SAMBAY_STAGES | INDEXED_STAGES,
+                   inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT,
+                          MIX),
+                   more_parts={"conv_time": MIX, "conv_head": MIX,
+                               "qk_mean": MIX, "value_shift": MIX,
+                               "qk_norm": MIX, "router_state": ROUTE}),
 }
+# the models whose lowered text is read (an expert layer's grouped matmul
+# keeps its name there and not in the compiled program's)
+ROUTED = ("expert", "indexed", "latent")
 PART = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(PART_PREFIX)
                   + r"([A-Za-z0-9_.]+)")
 
@@ -168,12 +187,15 @@ def _multiplied_leaves(module) -> dict:
     leaves = jax.tree_util.tree_flatten_with_path(module.param_shapes())[0]
     own_stage = {"router": ROUTE, "exit_gate": EXIT, "head": HEAD,
                  "embed": POLICY, "index_q": INDEX, "index_k": INDEX,
-                 "index_w": INDEX}
+                 "index_w": INDEX, "router_down": ROUTE}
     parts = {}
     for path, leaf in leaves:
         keys = [str(k.key) for k in path]
         if (len(leaf.shape) < 2 or keys[-1].startswith("conv_")
                 or keys[-1] == "A_log"):        # a table of decay rates
+            continue
+        if "router_mlp" in keys:        # a router's MLP is one part
+            parts["router_mlp"] = ROUTE
             continue
         if keys[-1] in ("kernel", "embedding"):
             keys = keys[:-1]
@@ -198,7 +220,7 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
     es = _sequence_es(case)
     engine = es.engine
     lowered = engine._generation_step.lower(es.state, engine.table.data)
-    if model in ("expert", "indexed"):
+    if model in ROUTED:
         text = lowered.as_text(debug_info=True)
         names = re.findall(r'loc\("(jit\([^"]*)"', text)
     else:
@@ -215,11 +237,10 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         # lowered text of the expert model shortens none)
         nested = [st for st in stacks if POLICY in st]
         assert stacks and len(nested) > len(stacks) // 2, inner
-        assert (model not in ("expert", "indexed")
-                or len(nested) == len(stacks)), inner
+        assert model not in ROUTED or len(nested) == len(stacks), inner
         assert all(st.index(POLICY) < st.index(inner) for st in nested), inner
     stacks = [(tuple(SCOPE.findall(n)), n) for n in names if SCOPE.findall(n)]
-    if model in ("expert", "indexed"):
+    if model in ROUTED:
         # the grouped matmuls sit under es.expert, their per-(member,
         # expert) corrections one deeper, the sort under es.dispatch
         assert any(st[-1] == EXPERT and "ragged_dot" in n for st, n in stacks)
@@ -227,7 +248,19 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         assert any(st[-1] == DISPATCH and "sort" in n for st, n in stacks)
         assert any(st[-1] == DISPATCH and "scatter" in n for st, n in stacks)
         assert any(st[-1] == ROUTE and "top_k" in n for st, n in stacks)
-        assert es.obs.counters.get("experts_held") == 4
+        assert es.obs.counters.get("experts_held") == case.get("held", 4)
+    if model == "latent":
+        # the head-mixing convolution's batched products under es.mix, their
+        # per-(tap, head) corrections one deeper; the router's MLP and the
+        # choice of ONE expert under es.route; the tied table read at the
+        # head, transposed, in its own part
+        assert any(st[-1] == MIX and "dot_general" in n for st, n in stacks)
+        assert any(st[-2:] == (MIX, PERTURB) for st, _ in stacks)
+        assert any(st[-1] == ROUTE and "erf" in n for st, n in stacks)
+        assert any(st[-2:] == (ROUTE, PERTURB) for st, _ in stacks)
+        assert any(st[-1] == HEAD and PART.findall(n) == ["embed"]
+                   for st, n in stacks)
+        assert es.obs.counters.get("conv_taps") == 4
     if model == "indexed":
         # the score product of every index head against the ONE key head
         # under es.index, the bisection's loop and the prefix count under
@@ -237,7 +270,7 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         assert any(st[-1] == SELECT and "cumsum" in n for st, n in stacks)
         assert any(st[-2:] == (INDEX, PERTURB) for st, _ in stacks)
         assert es.obs.counters.get("sparse_topk") == 6
-    if model not in ("expert", "indexed"):
+    if model not in ROUTED:
         # the projections are matmuls under es.dense or es.head; the
         # corrections are nested one deeper, under es.perturb
         matmuls = [SCOPE.findall(m) for line in text.splitlines()
@@ -292,8 +325,9 @@ def test_parts_are_metadata_only(model, monkeypatch):
     import importlib
 
     from estorch_tpu import models
-    from estorch_tpu.models import (hybrid_lm, indexed_moe_lm, lm_blocks,
-                                    looped_lm, moe_lm, perturbed, sambay_lm)
+    from estorch_tpu.models import (cca_moe_lm, hybrid_lm, indexed_moe_lm,
+                                    lm_blocks, looped_lm, moe_lm, perturbed,
+                                    sambay_lm)
 
     case = SEQUENCE_MODELS[model]
     tiny = importlib.import_module(case["tiny"])
@@ -314,7 +348,7 @@ def test_parts_are_metadata_only(model, monkeypatch):
     with_parts = lowered()
     assert PART_PREFIX in with_parts.as_text(debug_info=True)
     for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm,
-                sambay_lm, indexed_moe_lm):
+                sambay_lm, indexed_moe_lm, cca_moe_lm):
         monkeypatch.setattr(mod, "part",
                             lambda name: contextlib.nullcontext())
     without = lowered()
@@ -534,7 +568,11 @@ def test_attention_kernel_compiles_for_the_v5e_at_a_differential_pairs_shapes(
     (2048, 49152, False, jnp.bfloat16), (2048, 49152, False, jnp.float32),
     (2048, 16160, False, jnp.bfloat16), (2048, 100352, True, jnp.bfloat16),
     (8192, 32768, False, jnp.bfloat16), (4096, 32768, True, jnp.float32),
-], ids=["looped", "looped_f32", "tail", "tied", "widest_bf16", "widest_f32"])
+    # zaya1-es-8k-1chip: the first cell that RUNS the tied layout, its
+    # 32,784 rows a short last vocabulary tile
+    (2048, 32784, True, jnp.bfloat16),
+], ids=["looped", "looped_f32", "tail", "tied", "widest_bf16", "widest_f32",
+        "tied_tail"])
 def test_head_kernel_compiles_for_the_v5e_at_the_cells_shapes(
         hidden, vocab, tied, dtype, v5e_chip):
     """Mosaic accepts the head's kernel at the one-chip sequence cells'
@@ -1024,12 +1062,101 @@ def test_kernel_form_books_the_selected_attention_by_its_kind(v5e_chip):
     assert any(SCOPE_PREFIX + SELECT in line for line in text.splitlines())
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_attention_kernel_compiles_for_the_v5e_at_the_latents_shapes(
+        dtype, v5e_chip):
+    """Mosaic accepts the attention kernel at ``zaya1-es-8k-1chip``'s shapes:
+    8 query heads over 2 key-value heads of 128 (the latent: q ``[8192,
+    1024]``, k and v ``[8192, 256]``), 8,192 positions in blocks of 1,024,
+    two pairs' two signs through the engine's ``vmap``s, under the caller's
+    scale; nothing else in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import causal_attention
+
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (2, 2, 8192, width), dtype,
+            sharding=SingleDeviceSharding(v5e_chip))
+
+    text = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
+        q, k, v, num_heads=8, num_kv_heads=2, head_dim=128,
+        scale=128 ** -0.5, interpret=False)))).lower(
+            operand(1024), operand(256), operand(256)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " copy(" not in text.split("ENTRY")[1]
+
+
+def test_kernel_form_books_the_latents_attention_and_the_tied_head(v5e_chip):
+    """A small decoder with attention inside a latent on a one-device TPU
+    mesh: the rule takes its 8 / 2 heads of 128 and the tied head's hidden
+    128 over 512 positions, and the compiled generation program holds one
+    Mosaic call ``causal_attention`` a layer under es.attn inside es.policy
+    and ONE ``next_token_scores`` under es.head in the part ``of.embed``
+    (the tied layout): the device trace books them to ``cca.attn_share``
+    and ``cca.head_share``.  The latent's mixing stays XLA's, under
+    es.mix."""
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import CCAMoELM
+    from estorch_tpu.parallel.mesh import hyperscale_mesh
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    es = _es(
+        policy=CCAMoELM, population_size=4, sigma=0.02,
+        policy_kwargs=dict(
+            layer_types=("hybrid", "hybrid"), vocab_size=256,
+            hidden_size=128, moe_intermediate_size=64, num_attention_heads=8,
+            num_key_value_heads=2, head_dim=128, router_hidden_size=16,
+            num_experts=2, expert_group_size=2, behaviour_positions=64,
+            attention_block=128, head_block=128),
+        agent_kwargs={"env": TokenScoreEnv(
+            vocab_size=256, seq_len=512, corpus_sequences=4)},
+        shard_params=True, low_rank=1, noise_mode="table",
+        compute_dtype="bfloat16", table_size=1 << 18,
+        device=jax.devices()[:1])
+    assert (es.engine.attention_form, es.engine.head_form) == ("xla", "xla")
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    engine = ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=1, devices=[v5e_chip]),
+        partition_rules=es._partition_rules, noise_mode="table",
+        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        policy=declaration_of(es.module))
+    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
+    assert engine.attention_form_by_kind == "causal:kernel"
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        es.state, engine.state_shardings)
+    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
+                                 sharding=engine._repl)
+    text = engine._generation_step.lower(state, table).compile().as_text()
+
+    def calls(kernel):
+        return [name for line in text.splitlines()
+                if "tpu_custom_call" in line and kernel in line
+                for name in re.findall(r'op_name="([^"]*)"', line)]
+
+    kernels = calls("causal_attention")
+    assert len(kernels) == 2, kernels
+    for name in kernels:
+        assert SCOPE.findall(name)[0] == POLICY, name
+        assert SCOPE.findall(name)[-1] == ATTN, name
+    heads = calls("next_token_scores")
+    assert len(heads) == 1, heads
+    assert SCOPE.findall(heads[0])[-2:] == [POLICY, HEAD], heads
+    assert PART.findall(heads[0]) == ["embed"], heads
+    assert any(SCOPE_PREFIX + MIX in line and "f32[" in line
+               for line in text.splitlines())
+
+
 @pytest.mark.parametrize("use", ["context", "decorator"])
 def test_stage_scopes_a_name_stack(use):
-    assert len(set(STAGES)) == len(STAGES) == 22
+    assert len(set(STAGES)) == len(STAGES) == 23
     assert (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD,
             UPDATE, DENSE, SSM, ATTN, HEAD, ROPE, EXIT, ROUTE, DISPATCH,
-            EXPERT, GMU, DIFF, INDEX, SELECT) == STAGES
+            EXPERT, GMU, DIFF, INDEX, SELECT, MIX) == STAGES
     if use == "context":
         def f(x):
             with stage(NOISE):
