@@ -13,10 +13,13 @@ ONE population-wide matmul, and only the correction is per member:
 
 A stack of experts ``W [E, m, n]`` takes the grouped form
 (:func:`perturbed_grouped_dense`): rows sorted by expert go through ONE
-grouped matmul against the stack, whichever members they belong to, and
-the correction is per row from its (member, expert)'s factor pair.  A
-stack of one matrix a HEAD that every row passes (a per-head convolution's
-taps) is a batched matmul (:func:`perturbed_headwise_dense`).
+grouped matmul against the stack, whichever members they belong to.  The
+correction is two dense products against the factors of every (member,
+expert) laid side by side, ``x @ A_all`` and ``· @ B_allᵀ``, between which
+each row keeps the columns of its own pair (a one-hot choice): no row
+fetches a copy of its factors.  A stack of one matrix a HEAD that every
+row passes (a per-head convolution's taps) is a batched matmul
+(:func:`perturbed_headwise_dense`).
 
 The embedding lookup and the tied head take the same factors:
 ``E[tok] + c·A[tok]·Bᵀ/√r`` and ``h@Eᵀ + c·(h@B)@Aᵀ/√r``.  Both dots
@@ -114,19 +117,34 @@ def perturbed_grouped_dense(x, w, group_sizes, noise, c, row_expert,
     shared by every member whose rows are among ``x``.  ``noise``: ``None``
     or ``(A [M, E, m, r], B [M, E, n, r])``, one factor pair per (member,
     expert); ``c [M]``; ``row_expert``, ``row_member`` ``[R]`` say whose
-    pair corrects each row."""
+    pair corrects each row.
+
+    The correction gathers no factor row by row (``A[m_i, e_i]`` would be
+    a ``[R, m, r]`` copy out of ``M·E`` distinct pairs, read with the MXU
+    idle: PERF.md, PR 45).  ``x @ A`` is ONE product against all ``M·E·r``
+    columns (operands in ``x``'s dtype, float32 sums, as
+    :func:`perturbed_dense`); a row keeps its own pair's ``r`` of them
+    times ``c[m_i]/√r`` and zeros elsewhere; that ``[R, M·E·r]`` goes
+    against all of ``B`` in float32 at ``HIGHEST``: a sum with ``r``
+    non-zero terms a row, neither side rounded.  Both are dense products
+    under ``es.perturb`` and the caller's part, not grouped matmuls."""
     y = jax.lax.ragged_dot(x, w, group_sizes, preferred_element_type=F32)
     if noise is None:
         return y
     with stage(PERTURB):
         a, b = noise
-        r = a.shape[-1]
-        xa = jnp.einsum("im,imr->ir", x,
-                        a[row_member, row_expert].astype(x.dtype),
+        members, held, _, r = a.shape
+        pairs = members * held
+        xa = jnp.einsum("im,jmr->ijr", x,
+                        a.reshape(pairs, -1, r).astype(x.dtype),
                         preferred_element_type=F32)
-        scale = jnp.take(c, row_member) / jnp.sqrt(jnp.asarray(r, F32))
-        return y + jnp.einsum("ir,inr->in", xa * scale[:, None],
-                              b[row_member, row_expert].astype(F32))
+        own = (row_member * held + row_expert)[:, None] == jnp.arange(
+            pairs, dtype=row_expert.dtype)
+        scale = jnp.repeat(c, held) / jnp.sqrt(jnp.asarray(r, F32))
+        xa = jnp.where(own[..., None], xa * scale[:, None], 0.0)
+        return y + jnp.einsum("ijr,jnr->in", xa,
+                              b.reshape(pairs, -1, r).astype(F32),
+                              precision=jax.lax.Precision.HIGHEST)
 
 
 def perturbed_headwise_dense(x, w, noise, c):

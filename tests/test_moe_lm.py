@@ -151,6 +151,37 @@ def test_members_under_vmap_share_one_sort_and_one_grouped_matmul(tiny):
     assert len(re.findall(r"= argsort\b|name=argsort\b", text)) == layers
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for held in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(held)
+
+
+def test_no_row_fetches_its_own_copy_of_a_factor(tiny):
+    """The routed rows' corrections are products against the stacked
+    factors: under the engine's nesting no ``gather`` of the expert layers
+    reads a factor stack ``[members, held, m | n, r]`` (one that did would
+    write a ``[rows, m | n, r]`` copy of it)."""
+    lm, spec, tokens = tiny["lm"], tiny["spec"], _tokens(21, 9)
+    rows = jax.random.normal(jax.random.PRNGKey(7), (3, spec.noise_dim))
+    signs = jnp.asarray([0.05, -0.05])
+
+    def all_members(rows):
+        return jax.vmap(lambda row: jax.vmap(lambda c: lm.perturbed_apply(
+            tiny["params"], spec.unpack(row), c, tokens))(signs))(rows)
+
+    # a stacked leaf's factors [held, m | n, r]: 4 experts held, rank 2
+    factors = {(4, moe_tiny.TINY[width], 2)
+               for width in ("hidden_size", "moe_intermediate_size")}
+    gathers = [e for e in _equations(jax.make_jaxpr(all_members)(rows).jaxpr)
+               if e.primitive.name == "gather"]
+    assert gathers                  # the tokens' rows are still gathered
+    assert not [e for e in gathers
+                if e.invars[0].aval.shape[-3:] in factors]
+
+
 def test_each_member_its_own_weights_goes_member_by_member(tiny):
     """The materialised form hands every member its own tree: the expert
     layer then has no centre to share and evaluates the members one by
@@ -682,6 +713,42 @@ def test_the_grouped_form_is_the_materialised_expert_of_each_row():
     centre = perturbed_grouped_dense(x, w, jnp.asarray([3, 1, 4]), None, c,
                                      row_expert, row_member)
     np.testing.assert_allclose(centre[3], x[3] @ w[1], atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("members, experts, rank", [
+    (1, 16, 1), (4, 8, 1), (2, 4, 2), (3, 5, 8)])
+def test_the_grouped_form_row_by_row(members, experts, rank, dtype):
+    """Each row against ``x_i @ (W[e_i] + c[m_i]·A[m_i, e_i]·B[m_i, e_i]ᵀ/√r)``
+    materialised in float32 (``A`` as the product reads it: in ``x``'s
+    dtype), with an empty group, members mixed inside a group, and rows
+    past ``sum(group_sizes)``, which get their correction alone."""
+    m, n, past = 24, 20, 5
+    keys = jax.random.split(jax.random.PRNGKey(experts * rank), 6)
+    sizes = np.array(jax.random.randint(keys[0], (experts,), 1, 6))
+    sizes[experts // 2] = 0
+    held = int(sizes.sum())
+    rows = held + past
+    w = jax.random.normal(keys[1], (experts, m, n)).astype(dtype)
+    a = jax.random.normal(keys[2], (members, experts, m, rank))
+    b = jax.random.normal(keys[3], (members, experts, n, rank))
+    x = jax.random.normal(keys[4], (rows, m)).astype(dtype)
+    c = jnp.linspace(-0.3, 0.4, members)
+    row_expert = np.concatenate([np.repeat(np.arange(experts), sizes),
+                                 np.full(past, experts - 1)])
+    row_member = np.asarray(jax.random.randint(keys[5], (rows,), 0, members))
+    got = jax.jit(perturbed_grouped_dense)(
+        x, w, jnp.asarray(sizes, jnp.int32), (a, b), c,
+        jnp.asarray(row_expert, jnp.int32), jnp.asarray(row_member, jnp.int32))
+    assert got.shape == (rows, n) and got.dtype == jnp.float32
+    x32, w32 = np.asarray(x, np.float32), np.asarray(w, np.float32)
+    a32 = np.asarray(a.astype(dtype), np.float32)
+    for i in range(rows):
+        k, j = row_expert[i], row_member[i]
+        full = float(c[j]) * a32[j, k] @ np.asarray(b[j, k]).T / math.sqrt(rank)
+        if i < held:
+            full = full + w32[k]
+        np.testing.assert_allclose(got[i], x32[i] @ full, atol=1e-5, rtol=0)
 
 
 # -------------------------------------------- (h) sizes, layouts, rules
