@@ -47,24 +47,28 @@ precision:
 - ``"xla"``: block-causal einsums and a softmax, whose float32 score
   tiles XLA holds in HBM; a shared part is concatenated onto q and k, the
   key's broadcast to every head.  It runs anywhere: the CPU path, every
-  test's oracle, and what a mesh of several devices takes.
+  test's oracle, and what a mesh takes whose chips hold no whole member.
 - ``"kernel"``: ops/pallas_attention.py, an online softmax whose scores
   never leave VMEM; the shared part is a second contraction inside the
   tile, its key read by every head from the one ``[T, width]`` array.
 
-Which one a program takes is not an option of a model or of ``ES``: the
-ENGINE resolves it once at build from what it observes
-(``ShardedESEngine.attention_form``, by the one rule
-``ops.pallas_attention.attention_form``: TPU devices; ONE device on the
-mesh so the operands are whole on it (a selection is one of them); a
-head's values whole numbers of
-128-lane column blocks, and its own query/key part too, or half of one
-with values of ONE block and an even number of key heads (a pair); a
-shared part of 64 or a multiple of 128; the sequence a whole number of the
-kernel's blocks) and opens ``pallas_attention.kernel_scope`` around its
-trace of the policy.  :func:`attention_core` takes the kernel inside that
-scope for a call without a ``window``, and the XLA form for a call with
-one and everywhere else, so ``apply`` outside an engine is the XLA form.
+Which one a program takes is not an option of a model or of ``ES``.  The
+ENGINE resolves once at build, from what it observes, where Mosaic kernels
+may be traced at all (``ops.pallas_attention.traced_why``, the one place
+that says it: TPU devices and a member WHOLE on its chip, which is one
+device on the mesh, or several with the centre gathered and the members
+partitioned over them by hand) and opens ``pallas_attention.kernel_scope``
+around its trace of the policy there.  Inside that scope
+:func:`attention_core` takes the kernel for a call without a ``window``
+whose own shapes fit (``pallas_attention.fits``: a head's values whole
+numbers of 128-lane column blocks, and its own query/key part too, or half
+of one with values of ONE block and an even number of key heads (a pair);
+a shared part of 64 or a multiple of 128; the sequence a whole number of
+the kernel's blocks), and the XLA form for every other call and everywhere
+else, so ``apply`` outside an engine is the XLA form.  The engine says at
+build which form that is (``ShardedESEngine.attention_form``, by
+``ops.pallas_attention.attention_form``: the same conditions on the widths
+the model states).
 The engine reads what its rule needs from the model's declaration
 (``perturbed.PolicyDeclaration``, the model's ``declaration()``):
 ``attention_widths``, the widths the kernel's column blocks are cut by, one
@@ -88,14 +92,16 @@ a closure, because its two forms multiply it differently:
   members under the ``vmap``s around it become rows of ONE call, so ``W``
   is never copied a member; any vocabulary (a short last tile is masked).
 
-It takes the kernel inside the SAME ``kernel_scope`` (so: TPU devices, ONE
-on the mesh, an attention whose form is the kernel) where its own shapes
+It takes the kernel inside the SAME ``kernel_scope`` where its own shapes
 fit (``pallas_head.fits``: a hidden width of whole 128-lane blocks and at
 most 16 KiB a row, the sequence a whole number of the kernel's row tiles)
-and the noise is factored or none.  The engine says which at build
-(``ShardedESEngine.head_form`` by ``pallas_head.head_form``, from the
-``head_width`` the model states).  The last position's logits (the
-behaviour) are the one-row XLA matmul in both forms.
+and the noise is factored or none, whatever form the attention beside it
+takes: a model whose heads the attention's kernel turns away (64 wide with
+values of 64) scores in the head's kernel all the same.  The engine says
+which at build, and why (``ShardedESEngine.head_form`` and
+``head_form_why`` by ``pallas_head.head_form_why``, from the ``head_width``
+the model states).  The last position's logits (the behaviour) are the
+one-row XLA matmul in both forms.
 
 A third kernel is taken inside that scope, by a model and not by this
 module: Mamba-1's selective scan (``sambay_lm.selective_scan`` asks
@@ -356,10 +362,11 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     form makes that order of q and a copy of the values a map first.
 
     Inside an engine's ``pallas_attention.kernel_scope`` a call WITHOUT a
-    ``window`` is the Pallas kernel (its own blocks, scores in VMEM, the
-    shared part a second contraction in the tile: the key part is never
-    broadcast); a call with one (the kernel has no band), and any call
-    anywhere else, is the XLA form below, in blocks of ``block``, which
+    ``window`` whose widths and length fit (``pallas_attention.fits``) is
+    the Pallas kernel (its own blocks, scores in VMEM, the shared part a
+    second contraction in the tile: the key part is never broadcast); a
+    call with a window (the kernel has no band) or of other shapes, and any
+    call anywhere else, is the XLA form below, in blocks of ``block``, which
     concatenates the shared parts onto q and k, the key's broadcast to
     every head (the module's text has the rule).  The XLA form's loop over
     blocks is unrolled: the program grows with ``T / block``, so a much
@@ -373,16 +380,26 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
         raise ValueError(
             "a selection of keys is [T, T] over heads of one width with "
             "their values apart, no shared part, no window and no pairs")
-    # the kernel has no band: a call with a window is the XLA form
-    interpret = (pallas_attention.scoped_interpret() if window is None
-                 else None)
+    # the call's own widths: a head's values (ONE block a key pair where
+    # the heads come in pairs; beside the keys where none are handed in)
+    # and the part scored against the shared key
+    vd = (k.size // (t * nkv) - hd if v is None
+          else v.size // (t * (nkv // 2 if paired else nkv)))
+    shared = 0 if q_shared is None else k_shared.shape[-1]
+    # inside a scope, the kernel where these fit; it has no band: a call
+    # with a window is the XLA form
+    interpret = (
+        pallas_attention.scoped_interpret()
+        if window is None and pallas_attention.fits(
+            hd, shared, vd, nkv if paired else None, t)
+        else None)
     value_heads = nkv
     if paired and interpret is None:
         # the score heads of one key head (pair, map) side by side, and a
         # pair's values once a map
         q = q.reshape(t, nkv // 2, nq // nkv, 2, hd).transpose(0, 1, 3, 2, 4)
-        v = jnp.broadcast_to(v.reshape(t, nkv // 2, 1, -1),
-                             (t, nkv // 2, 2, v.size // (t * (nkv // 2))))
+        v = jnp.broadcast_to(v.reshape(t, nkv // 2, 1, vd),
+                             (t, nkv // 2, 2, vd))
     elif paired:
         value_heads = nkv // 2      # the kernel reads ONE block a key pair
     if v is None and (interpret is None or k.size != 2 * t * nkv * hd):
@@ -390,7 +407,6 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
         # read them apart (and the kernel's column blocks are one width)
         kv = k.reshape(t, nkv, -1)
         k, v = kv[..., :hd], kv[..., hd:]
-    vd = hd if v is None else v.size // (t * value_heads)
     if interpret is not None:
         with stage(ATTN):
             return pallas_attention.causal_attention(

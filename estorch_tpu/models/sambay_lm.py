@@ -42,7 +42,8 @@ b_o``.  Through ``lm_blocks.attention_core`` this is ONE call, handed the
 pairs as published (``paired``): ``H`` score heads of ``d`` over ``G`` key
 heads with ONE value block ``2d`` wide a key pair, read by both maps; its
 context is ordered (key pair, map, group), where
-``lm_blocks.differential_combine`` reads it.  On one TPU device the two
+``lm_blocks.differential_combine`` reads it.  Where Mosaic kernels may be
+traced (``pallas_attention.traced_why``) the two
 full-causal kinds of layer take the attention kernel, which reads a pair as
 the one 128-lane block it is (``d`` 64); the windowed kind stays in the XLA
 form (the kernel has no band).
@@ -78,10 +79,11 @@ the state and the decay's operand cross HBM at every step.  ``"kernel"``:
 ops/pallas_scan.py, the same recurrence with the state in vector registers
 and VMEM, ``Δ``, ``x``, ``B``, ``C`` streamed a time chunk at a time and
 ``y`` written once.  It takes the kernel inside an engine's
-``pallas_attention.kernel_scope`` (so: ONE TPU device, an attention whose
-form is the kernel) where its own shapes fit (``pallas_scan.fits``:
-``d_inner`` whole 128-lane blocks, at most 16 states, the sequence whole
-time chunks of 256) and the ``lax.scan`` anywhere else; the engine says
+``pallas_attention.kernel_scope`` (so: TPU devices and whole members on a
+chip, ``pallas_attention.traced_why``) where its own shapes fit
+(``pallas_scan.fits``: ``d_inner`` whole 128-lane blocks, at most 16
+states, the sequence whole time chunks of 256), whatever form the attention
+takes, and the ``lax.scan`` anywhere else; the engine says
 which at build (``ShardedESEngine.scan_form`` from the ``scan_widths`` the
 model states).  Mamba-1's decay is per (channel, state), so there is no
 matmul form of it as Mamba-2's.

@@ -113,14 +113,18 @@ derives it from the platform of the mesh it runs on (never true on a TPU
 mesh), tests pass ``True``.  Nothing here consults
 ``jax.default_backend()``.
 
-Which form a program takes is the ENGINE's decision, made once at build
-from what it observes (:func:`attention_form`), and told to the model
-function while the engine traces it (:func:`kernel_scope`): a call outside
-an engine's trace takes the XLA form.  The kernel has no band: inside the
-scope a CALL of the core with a ``window`` takes the XLA form too
-(:func:`call_form`; ``lm_blocks.attention_core`` reads its own argument),
-and the engine says which form each kind of layer took
-(``attention_form_by_kind``).
+Where a kernel may be traced at all is the ENGINE's decision, made once at
+build from what it observes (:func:`traced_why`: TPU devices and whole
+members on a chip) and told to the model function while the engine traces
+it (:func:`kernel_scope`): a call outside an engine's trace takes the XLA
+form.  Inside the scope a CALL of the core decides by its own shapes
+(:func:`fits`; the head and the scan by theirs, so an attention the shapes
+turn away stays in the XLA form beside a head in its kernel), and the
+kernel has no band: a call with a ``window`` takes the XLA form too
+(:func:`call_form`; ``lm_blocks.attention_core`` reads its own argument).
+The engine says at build which form the model's attention takes
+(:func:`attention_form`, from the widths the model states) and which each
+kind of layer took (``attention_form_by_kind``).
 """
 
 from __future__ import annotations
@@ -169,51 +173,50 @@ def kernel_block(length: int) -> int | None:
     return next((b for b in BLOCKS if length % b == 0), None)
 
 
-def attention_form(platform: str, n_devices: int, widths, length: int,
-                   window: int | None = None,
-                   kv_heads: int | None = None) -> str:
-    """``"kernel"`` or ``"xla"``: :func:`attention_form_why` without its
-    reason."""
-    return attention_form_why(platform, n_devices, widths, length, window,
-                              kv_heads)[0]
+def traced_why(platform: str, n_devices: int,
+               centre_form: str | None = None) -> tuple[bool, str]:
+    """``(may Mosaic kernels be traced here?, why)`` for the policy's
+    forward in a program on a mesh of ``n_devices`` devices of
+    ``platform`` whose perturbed form reads its centre as ``centre_form``
+    says (``parallel/sharded.py::centre_form_why``).  THE rule of the three
+    kernels' scope (:func:`kernel_scope`), said once, here: the devices are
+    TPUs, and a member is WHOLE on its chip, which it is on a mesh of one
+    device and, on a mesh of several, where the centre is ``"gathered"``:
+    every chip then holds the whole compute-dtype centre and the engine
+    partitions the members over the chips by hand (a ``shard_map`` over the
+    pairs), so a ``pallas_call`` inside runs a chip's own members.  Where
+    the centre stays ``"split"`` over the mesh's ``model`` axis the
+    operands are not whole on a chip, and under GSPMD an unwrapped
+    ``pallas_call`` would be replicated, not partitioned: no kernel there.
+    The answer says nothing of any one kernel: each call site takes its
+    kernel inside the scope where its own shapes fit (:func:`fits` here,
+    ``pallas_head.fits``, ``pallas_scan.fits``)."""
+    if platform != "tpu":
+        return False, f"the devices are {platform!r}, not TPUs"
+    if n_devices == 1:
+        return True, "one TPU device"
+    if centre_form != "gathered":
+        return False, (f"{n_devices} devices on the mesh and the centre "
+                       f"{centre_form}: a member's operands are not whole "
+                       "on a chip")
+    return True, (f"{n_devices} TPU devices, whole members on each (the "
+                  "centre gathered, the pairs partitioned by hand)")
 
 
-def attention_form_why(platform: str, n_devices: int, widths, length: int,
-                       window: int | None = None,
-                       kv_heads: int | None = None) -> tuple[str, str]:
-    """``("kernel" | "xla", why)`` for a program on a mesh of ``n_devices``
-    devices of ``platform`` that runs attention over ``length`` positions
-    with heads of ``widths``, as the model states them: one width (an
-    ``int``) for heads scored and summed at it, or ``(a head's own
-    query/key part, a shared part, the value width)`` where every head
-    also scores a second part against ONE key all heads read (0: none).
-    These are what the kernel's column blocks are cut by.  The kernel is
-    taken when, and only when, ALL hold: the devices are TPUs; there is
-    one of them, so the attention's operands are whole on it (under GSPMD
-    an unwrapped ``pallas_call`` would be replicated, not partitioned); a
-    head's values are whole numbers of 128-lane column blocks, and its own
-    part is too, OR is half of one with values of ONE block and an even
-    number of key heads ``kv_heads``: two score heads a block that read
-    one value block, which is what a differential PAIR is and how such a
-    model hands its heads to the core (``attention_core(paired=True)``);
-    the shared part is a whole number of blocks or half of one (two
-    heads a block); the sequence is a whole number of the kernel's blocks
-    (:func:`kernel_block`).  ``why`` names the first of these that fails
-    (the engine logs it and the run manifest carries it).
-
-    ``window``: the band of the model's windowed layers, if it has any.
-    It decides nothing here: the kernel has no band, so inside the
-    program's scope a CALL with a window takes the XLA form
-    (:func:`call_form`) and every other call the kernel; ``why`` says so."""
-    head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
-                           else widths)
-    pair = (2 * head == value == LANES and not shared
+def _pair(head: int, shared: int, value: int, kv_heads: int | None) -> bool:
+    """Two score heads of half a lane block side by side over ONE value
+    block, an even number of key heads: differential attention's pair."""
+    return (2 * head == value == LANES and not shared
             and kv_heads is not None and kv_heads % 2 == 0)
-    failed = [why for ok, why in (
-        (platform == "tpu", f"the devices are {platform!r}, not TPUs"),
-        (n_devices == 1, f"{n_devices} devices on the mesh: the operands "
-                         "are not whole on one"),
-        (value % LANES == 0 and (head % LANES == 0 or pair),
+
+
+def _shape_failures(head: int, shared: int, value: int,
+                    kv_heads: int | None, length: int) -> list[str]:
+    """The conditions on an attention's shapes that fail, each as a
+    sentence (:func:`attention_form_why` has them in words)."""
+    return [why for ok, why in (
+        (value % LANES == 0
+         and (head % LANES == 0 or _pair(head, shared, value, kv_heads)),
          f"a head's own part is {head} wide and its values {value}, over "
          f"{kv_heads} key heads: not whole {LANES}-lane column blocks, nor "
          "pairs of half a block that read one value block"),
@@ -223,10 +226,69 @@ def attention_form_why(platform: str, n_devices: int, widths, length: int,
         (kernel_block(length) is not None,
          f"no block of {BLOCKS} divides {length} positions"),
     ) if not ok]
+
+
+def fits(head: int, shared: int, value: int, kv_heads: int | None,
+         length: int) -> bool:
+    """The shapes ONE call of the core takes the kernel at, inside a
+    scope: the width and block conditions of :func:`attention_form_why`,
+    read off the call's own operands (``kv_heads``: the key heads of a call
+    whose heads come in pairs, ``None`` for any other)."""
+    return not _shape_failures(head, shared, value, kv_heads, length)
+
+
+def attention_form(platform: str, n_devices: int, widths, length: int,
+                   window: int | None = None,
+                   kv_heads: int | None = None,
+                   centre_form: str | None = None) -> str:
+    """``"kernel"`` or ``"xla"``: :func:`attention_form_why` without its
+    reason."""
+    return attention_form_why(platform, n_devices, widths, length, window,
+                              kv_heads, centre_form)[0]
+
+
+def attention_form_why(platform: str, n_devices: int, widths, length: int,
+                       window: int | None = None,
+                       kv_heads: int | None = None,
+                       centre_form: str | None = None) -> tuple[str, str]:
+    """``("kernel" | "xla", why)`` for a program on a mesh of ``n_devices``
+    devices of ``platform`` that runs attention over ``length`` positions
+    with heads of ``widths``, as the model states them: one width (an
+    ``int``) for heads scored and summed at it, or ``(a head's own
+    query/key part, a shared part, the value width)`` where every head
+    also scores a second part against ONE key all heads read (0: none).
+    These are what the kernel's column blocks are cut by.  The kernel is
+    taken when, and only when, ALL hold: Mosaic kernels may be traced in
+    the program (:func:`traced_why`: TPU devices and whole members on a
+    chip, by ``centre_form`` on a mesh of several); a head's values are
+    whole numbers of 128-lane column blocks, and its own part is too, OR
+    is half of one with values of ONE block and an even number of key
+    heads ``kv_heads``: two score heads a block that read one value block,
+    which is what a differential PAIR is and how such a model hands its
+    heads to the core (``attention_core(paired=True)``); the shared part
+    is a whole number of blocks or half of one (two heads a block); the
+    sequence is a whole number of the kernel's blocks
+    (:func:`kernel_block`).  ``why`` names the first of these that fails
+    (the engine logs it and the run manifest carries it).  The shape
+    conditions are the ones a CALL of the core decides by inside the scope
+    (:func:`fits`): an attention they turn away stays in the XLA form
+    beside a head or a scan in its kernel.
+
+    ``window``: the band of the model's windowed layers, if it has any.
+    It decides nothing here: the kernel has no band, so inside the
+    program's scope a CALL with a window takes the XLA form
+    (:func:`call_form`) and every other call the kernel; ``why`` says so."""
+    head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
+                           else widths)
+    traced, where = traced_why(platform, n_devices, centre_form)
+    failed = ([] if traced else [where]) + _shape_failures(
+        head, shared, value, kv_heads, length)
     if failed:
         return "xla", failed[0]
-    return "kernel", "one TPU device, {}, whole row blocks{}".format(
-        "two score heads a column block" if pair else "whole column blocks",
+    return "kernel", "{}, {}, whole row blocks{}".format(
+        where,
+        "two score heads a column block"
+        if _pair(head, shared, value, kv_heads) else "whole column blocks",
         "" if window is None else
         f"; layers with a window of {window} in the XLA form")
 
@@ -244,10 +306,14 @@ _SCOPE: contextvars.ContextVar = contextvars.ContextVar(
 
 @contextlib.contextmanager
 def kernel_scope(interpret: bool):
-    """While a policy is traced inside, ``lm_blocks.attention_core`` takes
-    the kernel (under the Pallas interpreter where ``interpret``).  The
-    engine that resolved ``attention_form == "kernel"`` opens it around its
-    own trace of the policy; nothing else does.  The scope acts at TRACE
+    """While a policy is traced inside, Mosaic kernels may be traced
+    (under the Pallas interpreter where ``interpret``), and that is all it
+    says: ``lm_blocks.attention_core``, ``lm_blocks.score_next_tokens`` and
+    ``sambay_lm.selective_scan`` each take their kernel where the call's
+    own shapes fit (:func:`fits`, ``pallas_head.fits``,
+    ``pallas_scan.fits``) and their XLA form where they do not.  The
+    engine opens it around its own trace of the policy where
+    :func:`traced_why` says so; nothing else does.  The scope acts at TRACE
     time and is no part of a ``jax.jit`` cache key: a jitted function
     traced outside it keeps the XLA form if called inside it later."""
     token = _SCOPE.set(bool(interpret))

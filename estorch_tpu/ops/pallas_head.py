@@ -50,9 +50,14 @@ the same operands the kernel is off by 8e-6, the XLA form by 2e-3; PERF.md,
 PR 36).
 
 ``interpret`` is a required argument, as in ops/pallas_attention.py.  Which
-form a program takes is observed, not configured (:func:`head_form`):
+form a program takes is observed, not configured (:func:`head_form_why`):
 ``lm_blocks.score_next_tokens`` takes the kernel inside an engine's
-``pallas_attention.kernel_scope`` where its shapes fit (:func:`fits`).
+``pallas_attention.kernel_scope`` where its shapes fit (:func:`fits`),
+whatever form the attention beside it takes.  The engine opens the scope
+where a member is whole on its chip (``pallas_attention.traced_why`` has the
+rule): one TPU device, or several with the centre gathered, where it
+partitions the members over the chips itself, so that a chip's call holds
+its own members' rows.
 """
 
 from __future__ import annotations
@@ -115,21 +120,29 @@ def fits(hidden: int, rows: int, itemsize: int) -> bool:
             and rows % ROW_TILE == 0)
 
 
-def head_form(attention: str | None, hidden: int, length: int,
-              itemsize: int) -> str:
-    """``"kernel"`` or ``"xla"`` for the next-token head of a program whose
-    attention takes the form ``attention``, scoring sequences of ``length``
-    positions from hidden states ``hidden`` wide in ``itemsize``-byte
-    operands.  The kernel when, and only when, the engine opens its
-    ``kernel_scope`` around the policy, which it does where
-    ``pallas_attention.attention_form`` says ``"kernel"`` (TPU devices, ONE
-    of them on the mesh, so ``W`` and the hidden states are whole on it:
-    under GSPMD an unwrapped ``pallas_call`` would be replicated, not
-    partitioned), and the head's own shapes fit (:func:`fits`).  What
+def head_form_why(traced: tuple[bool, str], hidden: int, length: int,
+                  itemsize: int) -> tuple[str, str]:
+    """``("kernel" | "xla", why)`` for the next-token head of a program,
+    scoring sequences of ``length`` positions from hidden states ``hidden``
+    wide in ``itemsize``-byte operands.  The head's OWN rule, whatever form
+    the model's attention takes: the kernel when, and only when, Mosaic
+    kernels may be traced in the program (``traced``, the answer of
+    ``pallas_attention.traced_why``: TPU devices and whole members on a
+    chip, so ``W`` and a member's hidden states are whole where the call
+    runs) and the head's shapes fit (:func:`fits`).  What
     ``lm_blocks.score_next_tokens`` does while it is traced, said once at
-    build."""
-    return ("kernel" if attention == "kernel"
-            and fits(hidden, length, itemsize) else "xla")
+    build; ``why`` names what decided (the engine logs it and the run
+    manifest carries it)."""
+    may, where = traced
+    if not may:
+        return "xla", where
+    if not fits(hidden, length, itemsize):
+        return "xla", (
+            f"hidden states {hidden} wide in {itemsize}-byte operands over "
+            f"{length} positions: not whole {LANES}-lane blocks of at most "
+            f"{ROW_BYTES_MAX} bytes a row over whole row tiles of {ROW_TILE}")
+    return "kernel", (f"{where}; a hidden width of whole {LANES}-lane "
+                      f"blocks, whole row tiles of {ROW_TILE}")
 
 
 # --------------------------------------------------------------------------

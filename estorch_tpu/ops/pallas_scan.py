@@ -96,19 +96,16 @@ def fits(d_inner: int, d_state: int, length: int) -> bool:
             and length > 0 and length % TIME_CHUNK == 0)
 
 
-def scan_form(attention: str | None, d_inner: int, d_state: int,
-              length: int) -> str:
-    """``"kernel"`` or ``"xla"`` for the selective scans of a program whose
-    attention takes the form ``attention``, over sequences of ``length``
-    steps with ``d_inner`` channels of ``d_state`` states.  The kernel
-    when, and only when, the engine opens its ``kernel_scope`` around the
-    policy, which it does where ``pallas_attention.attention_form`` says
-    ``"kernel"`` (TPU devices, ONE of them on the mesh, so the scan's
-    operands are whole on it), and the scan's own shapes fit
-    (:func:`fits`).  What ``sambay_lm.selective_scan`` does while it is
+def scan_form(traced: bool, d_inner: int, d_state: int, length: int) -> str:
+    """``"kernel"`` or ``"xla"`` for the selective scans of a program, over
+    sequences of ``length`` steps with ``d_inner`` channels of ``d_state``
+    states.  The scan's OWN rule, whatever form the model's attention
+    takes: the kernel when, and only when, Mosaic kernels may be
+    ``traced`` in the program (``pallas_attention.traced_why`` has that
+    rule: TPU devices and whole members on a chip) and the scan's shapes
+    fit (:func:`fits`).  What ``sambay_lm.selective_scan`` does while it is
     traced, said once at build."""
-    return ("kernel" if attention == "kernel"
-            and fits(d_inner, d_state, length) else "xla")
+    return "kernel" if traced and fits(d_inner, d_state, length) else "xla"
 
 
 # --------------------------------------------------------------------------

@@ -30,13 +30,14 @@ at the Hyperscale" recipe (PAPERS.md, arxiv 2511.16652) on a 2-D
   only param-sized traffic per generation is the psum'd update GSPMD
   inserts for the weighted-noise contraction — never a replicated tree.
 
-Everything global-view (``jit`` + ``NamedSharding`` constraints, not
-``shard_map``): the program is written against full logical shapes and
-GSPMD partitions it, which is what makes the numerics mesh-shape
-invariant (values identical on (1, N), (N, 1), or (a, b) meshes up to
-f32 reduction order — the forward's contractions over model-sharded
-dims and the update psum may reassociate, so cross-path comparisons are
-``allclose`` at f32, not bit-equal; docs/sharding.md).
+Global-view but for ONE region (``jit`` + ``NamedSharding`` constraints;
+the exception, a ``shard_map`` around the evaluation of a chunk's pairs in
+the ``gathered`` centre form, is below): the program is written against
+full logical shapes and GSPMD partitions it, which is what makes the
+numerics mesh-shape invariant (values identical on (1, N), (N, 1), or
+(a, b) meshes up to f32 reduction order — the forward's contractions over
+model-sharded dims and the update psum may reassociate, so cross-path
+comparisons are ``allclose`` at f32, not bit-equal; docs/sharding.md).
 
 Two evaluation bodies, chosen by the engine from what it observes
 (``forward_form``; no option):
@@ -57,7 +58,13 @@ Two evaluation bodies, chosen by the engine from what it observes
     the chip's share of the state: one all-gather a leaf a generation, of
     the leaf already cast; the pairs split over EVERY chip of the mesh;
     each chip evaluates whole members, so no collective runs between two
-    projections and no float32 sum is reassociated across chips.
+    projections and no float32 sum is reassociated across chips.  The
+    split is made by hand: a chunk's pairs are evaluated under a
+    ``shard_map`` over the pair axes, the gathered centre whole inside it,
+    so that what is traced there sees a chip's own pairs and nothing names
+    a mesh axis.  That is what a hand-written kernel needs (left to GSPMD
+    a ``pallas_call`` is replicated, every chip running every member), and
+    why the policy's Mosaic kernels may be traced on such a mesh.
   - ``split``, where it does not (and on a ``model`` axis of 1, where
     there is nothing to gather): the centre stays sharded over ``model``
     like the state, the pairs over ``pop``, and GSPMD runs every
@@ -65,7 +72,13 @@ Two evaluation bodies, chosen by the engine from what it observes
     row-split projections' partial products).
 
   The state, the optimizer, the update and the emitted best member are
-  sharded over ``model`` in both.
+  sharded over ``model`` in both.  Where the policy's three Pallas kernels
+  (attention, next-token head, selective scan) may be traced follows from
+  the same observations: TPU devices and whole members on a chip, which is
+  a mesh of one device or the ``gathered`` form
+  (``ops.pallas_attention.traced_why`` has the rule;
+  :meth:`ShardedESEngine._resolve_kernel_forms` what each kernel's own
+  shapes then decide).
 - ``materialised``: ``leaf[None] + σ·s·ε`` per member of a chunk, for
   full-rank noise and in-program low-rank noise on small trees.
 
@@ -102,8 +115,8 @@ from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
 from ..ops.pallas_attention import (attention_form_why, call_form,
-                                    kernel_scope)
-from ..ops.pallas_head import head_form
+                                    kernel_scope, traced_why)
+from ..ops.pallas_head import head_form_why
 from ..ops.pallas_scan import scan_form
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
@@ -337,53 +350,8 @@ class ShardedESEngine:
         # the Pallas kernels compile through Mosaic on the chip this mesh
         # is made of; anywhere else only the interpreter can run them
         self._pallas_interpret = mesh.devices.flat[0].platform != "tpu"
-        # {attention layer kind: the band of its calls | None}, as the
-        # policy states them (one kind, no band, where it states none)
-        self._attention_windows = policy.attention_windows or {"causal": None}
-        # "kernel" | "xla": which form the policy's causal attention takes
-        # in this engine's programs (models/lm_blocks.py has the two forms);
-        # None for a policy that has none.  Resolved once, here, from the
-        # mesh, the sequence length and what the policy states: the widths,
-        # the bands and the key heads, a pair of which may share a column
-        # block (run manifest + telemetry gauge)
-        widths = policy.attention_widths
-        self.attention_form = self.attention_form_why = None
-        self.attention_form_by_kind = None
-        if widths is not None:
-            self.attention_form = self._resolve_attention_form(widths)
-            # which condition of the rule decided (the head's kernel is
-            # taken inside the attention kernel's scope alone, so this is
-            # the head form's reason too wherever the attention is "xla")
-            self.attention_form_why = self._attention_rule(widths)[1]
-            # "<kind>:<form>,…": the form the calls of each attention layer
-            # kind take in this engine's programs (the kernel has no band:
-            # a kind with a window stays in the XLA form inside its scope)
-            self.attention_form_by_kind = ",".join(
-                f"{kind}:{call_form(self.attention_form, window)}"
-                for kind, window in self._attention_windows.items())
         self._dtype = (jnp.bfloat16 if config.compute_dtype == "bfloat16"
                        else jnp.float32)
-        # "kernel" | "xla": which form the policy's next-token head takes
-        # (models/lm_blocks.py::score_next_tokens); None for a policy that
-        # states no head.  The kernel is taken inside the scope the
-        # attention form opens, where the head's own shapes fit
-        self.head_form = (
-            None if policy.head_width is None
-            else head_form(self.attention_form, policy.head_width,
-                           config.horizon, jnp.dtype(self._dtype).itemsize))
-        # "kernel" | "xla": which form the policy's selective scans take
-        # (models/sambay_lm.py::selective_scan); None for a policy that
-        # states no scan.  The kernel is taken inside the same scope, where
-        # the scan's own shapes fit
-        self.scan_form = (
-            None if policy.scan_widths is None
-            else scan_form(self.attention_form, *policy.scan_widths,
-                           config.horizon))
-        if self.attention_form is not None:
-            logging.getLogger(__name__).info(
-                "attention_form %s (%s; %s); head_form %s; scan_form %s",
-                self.attention_form, self.attention_form_why,
-                self.attention_form_by_kind, self.head_form, self.scan_form)
         self.n_devices = int(mesh.devices.size)
         self.mesh_shape = "x".join(str(n) for n in mesh.devices.shape)
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -481,6 +449,7 @@ class ShardedESEngine:
         if self.forward_form == "perturbed":
             logging.getLogger(__name__).info(
                 "centre_form %s (%s)", self.centre_form, self.centre_form_why)
+        self._resolve_kernel_forms()
 
         # ---- population layout (ghost-padded like the replicated path) --
         cfg = config
@@ -524,10 +493,10 @@ class ShardedESEngine:
                 return jax.tree_util.tree_map(
                     lambda o: o.astype(jnp.float32), out)
 
-            self._rollout = self._in_attention_form(
+            self._rollout = self._in_kernel_scope(
                 make_rollout(env, packed_apply, cfg.horizon))
         else:
-            self._rollout = self._in_attention_form(make_rollout(
+            self._rollout = self._in_kernel_scope(make_rollout(
                 env, _bf16_io_apply(policy_apply) if bf16 else policy_apply,
                 cfg.horizon))
 
@@ -570,23 +539,80 @@ class ShardedESEngine:
             out_shardings=self.param_shardings)
         self._copy_into_compiled = None
 
+    def _resolve_kernel_forms(self):
+        """Which form each of the policy's three hand-written kernels takes
+        in this engine's programs (models/lm_blocks.py and
+        models/sambay_lm.py have the forms), resolved once, at build, from
+        the mesh, the centre's form, the sequence length and what the
+        policy states (run manifest + telemetry gauges).  ONE question is
+        the engine's, whether Mosaic kernels may be traced at all
+        (``pallas_attention.traced_why``: TPU devices and whole members on
+        a chip); the scope it opens around the policy's trace says that and
+        nothing more, and each kernel's form then follows from its own
+        shapes, as the call sites decide while they are traced."""
+        policy, horizon = self.policy, self.config.horizon
+        self.kernels_traced, self.kernels_traced_why = self._traced_rule()
+        # {attention layer kind: the band of its calls | None}, as the
+        # policy states them (one kind, no band, where it states none)
+        self._attention_windows = policy.attention_windows or {"causal": None}
+        # "kernel" | "xla": the form of the policy's causal attention, from
+        # the widths, the bands and the key heads it states, a pair of
+        # which may share a column block; None for a policy that has none
+        widths = policy.attention_widths
+        self.attention_form = self.attention_form_why = None
+        self.attention_form_by_kind = None
+        if widths is not None:
+            # the form, and which condition of the rule decided
+            self.attention_form, self.attention_form_why = (
+                self._attention_rule(widths))
+            # "<kind>:<form>,…": the form the calls of each attention layer
+            # kind take in this engine's programs (the kernel has no band:
+            # a kind with a window stays in the XLA form inside its scope)
+            self.attention_form_by_kind = ",".join(
+                f"{kind}:{call_form(self.attention_form, window)}"
+                for kind, window in self._attention_windows.items())
+        # "kernel" | "xla", and what decided: the form of the policy's
+        # next-token head (lm_blocks.score_next_tokens), by the head's own
+        # rule; None for a policy that states no head
+        self.head_form, self.head_form_why = (
+            (None, None) if policy.head_width is None
+            else head_form_why(
+                (self.kernels_traced, self.kernels_traced_why),
+                policy.head_width, horizon, jnp.dtype(self._dtype).itemsize))
+        # "kernel" | "xla": the form of the policy's selective scans
+        # (sambay_lm.selective_scan), by the scan's own rule; None for a
+        # policy that states no scan
+        self.scan_form = (
+            None if policy.scan_widths is None
+            else scan_form(self.kernels_traced, *policy.scan_widths, horizon))
+        if {self.attention_form, self.head_form, self.scan_form} != {None}:
+            logging.getLogger(__name__).info(
+                "attention_form %s (%s; %s); head_form %s (%s); scan_form %s",
+                self.attention_form, self.attention_form_why,
+                self.attention_form_by_kind, self.head_form,
+                self.head_form_why, self.scan_form)
+
+    def _traced_rule(self) -> tuple[bool, str]:
+        """``(may Mosaic kernels be traced in the policy's forward?,
+        why)``: see ``pallas_attention.traced_why``."""
+        return traced_why(self.mesh.devices.flat[0].platform,
+                          self.n_devices, self.centre_form)
+
     def _attention_rule(self, widths) -> tuple[str, str]:
         """``("kernel" | "xla", why)``: see ``attention_form_why``."""
         return attention_form_why(
-            self.mesh.devices.flat[0].platform, int(self.mesh.devices.size),
+            self.mesh.devices.flat[0].platform, self.n_devices,
             widths, self.config.horizon,
             next((w for w in self._attention_windows.values()
                   if w is not None), None),
-            self.policy.attention_kv_heads)
+            self.policy.attention_kv_heads, self.centre_form)
 
-    def _resolve_attention_form(self, widths) -> str:
-        return self._attention_rule(widths)[0]
-
-    def _in_attention_form(self, rollout):
-        """``rollout`` traced in this engine's attention form: the policy's
-        ``causal_attention`` learns of the kernel by the scope that is open
-        while it is traced, and takes the XLA form with none."""
-        if self.attention_form != "kernel":
+    def _in_kernel_scope(self, rollout):
+        """``rollout`` traced where this engine may trace Mosaic kernels:
+        the policy's ``attention_core``, ``score_next_tokens`` and
+        ``selective_scan`` learn of it by the scope that is open while
+        they are traced, and take their XLA forms with none."""
+        if not self.kernels_traced:
             return rollout
 
         def scoped(*args):
@@ -745,46 +771,85 @@ class ShardedESEngine:
         """Evaluate every member without building any member's weights:
         ``center`` (the compute-dtype copy of the params: sharded like them
         in the ``split`` centre form, whole on every chip in ``gathered``)
-        is closed over un-batched, ``noise_rows [rows_padded, noise_dim]``
-        are unpacked per chunk into factor trees batched over pairs (split
-        over ``pop``; over every chip of the mesh in ``gathered``), and the
-        two signs of a pair read the one tree."""
+        enters un-batched, ``noise_rows [rows_padded, noise_dim]`` are
+        unpacked per chunk into factor trees batched over pairs, and the two
+        signs of a pair read the one tree.  The pairs of a chunk are split
+        over ``pop`` and left to GSPMD in the ``split`` form
+        (``vmap(spmd_axis_name=)``); in ``gathered`` they are partitioned
+        over every chip of the mesh by hand (a ``shard_map`` over the pair
+        axes, the centre whole inside it), which is what lets a Mosaic
+        kernel in the policy run a chip's own members."""
         cfg = self.config
         with stage(SAMPLE):
             member_keys = jax.random.split(rkey, self.rows_global)
             keys = jnp.take(member_keys, self._padded_rows(), axis=0)
             signs = (jnp.asarray([1.0, -1.0], jnp.float32) if cfg.mirrored
                      else jnp.ones((1,), jnp.float32))
-        pair_rows = NamedSharding(self.mesh, P(self._pair_axes, None))
 
-        def chunk_body(noise_c, keys_c):
+        def eval_pairs(center, sigma, noise_c, keys_c, spmd_axis_name=None):
+            """The pairs whose rows are ``noise_c``, every member of them
+            whole where this is traced."""
             with stage(NOISE):
-                noise_c = jax.lax.with_sharding_constraint(noise_c, pair_rows)
                 noise_tree = self.lr_spec.unpack(noise_c)
 
             def pair_eval(noise_p, key):
                 def sign_eval(sign):
                     with stage(PERTURB):
-                        c = state.sigma * sign
+                        c = sigma * sign
                     return self._rollout((center, noise_p, c), key)
 
                 if self.signs_in_turn:
                     return jax.lax.map(sign_eval, signs)
                 return jax.vmap(sign_eval)(signs)
 
-            res = jax.vmap(pair_eval, spmd_axis_name=self._pair_axes)(
-                noise_tree, keys_c)
+            if spmd_axis_name is None and keys_c.shape[0] == 1:
+                # ONE pair on this chip: evaluated as it is, not under a
+                # ``vmap`` of one.  XLA drops an axis of one from some
+                # operations and keeps it on others, and the mixed shapes
+                # cost the published four-chip program 0.63 GiB of
+                # temporaries a chip and 23 s of compile (PERF.md, PR 46)
+                res = jax.tree_util.tree_map(
+                    lambda x: x[None], pair_eval(jax.tree_util.tree_map(
+                        lambda x: x[0], noise_tree), keys_c[0]))
+            else:
+                res = jax.vmap(pair_eval, spmd_axis_name=spmd_axis_name)(
+                    noise_tree, keys_c)
             # strict: a policy that returns more or fewer things than it
             # declares is refused here, while the program is traced
             return (res.total_reward, res.bc, res.steps, dict(zip(
                 self.policy.outputs, res.extras or (), strict=True)))
 
+        if self.centre_form == "gathered":
+            # whole members on every chip: the chunk's pairs are
+            # partitioned over the chips by hand, so that what is traced
+            # inside sees a chip's OWN pairs and a hand-written kernel runs
+            # on them (left to GSPMD a ``pallas_call`` would be replicated:
+            # every chip scoring every member).  The centre and sigma enter
+            # whole, as they lie; nothing inside names a mesh axis, so no
+            # collective is added
+            pairs = P(self._pair_axes)
+            chunk_body = jax.shard_map(
+                lambda noise_c, keys_c, center, sigma: eval_pairs(
+                    center, sigma, noise_c, keys_c),
+                mesh=self.mesh, in_specs=(pairs, pairs, P(), P()),
+                out_specs=pairs, check_vma=False)
+        else:
+            pair_rows = NamedSharding(self.mesh, P(self._pair_axes, None))
+
+            def chunk_body(noise_c, keys_c, center, sigma):
+                with stage(NOISE):
+                    noise_c = jax.lax.with_sharding_constraint(
+                        noise_c, pair_rows)
+                return eval_pairs(center, sigma, noise_c, keys_c,
+                                  spmd_axis_name=self._pair_axes)
+
         if self.n_pair_chunks == 1:
-            f, bc, st, outputs = chunk_body(noise_rows, keys)
+            f, bc, st, outputs = chunk_body(noise_rows, keys, center,
+                                            state.sigma)
         else:
             n, k = self.n_pair_chunks, self.pair_chunk
             _, (f, bc, st, outputs) = jax.lax.scan(
-                lambda _, xs: (0, chunk_body(*xs)), 0,
+                lambda _, xs: (0, chunk_body(*xs, center, state.sigma)), 0,
                 (noise_rows.reshape(n, k, self.noise_dim),
                  keys.reshape((n, k) + keys.shape[1:])))
         # (chunks, pairs, signs) is member order: member 2k+s is pair k
@@ -1151,8 +1216,8 @@ class ShardedESEngine:
     BUILD_FACTS = (
         "forward_form", "noise_rows_per_generation", "attention_form",
         "attention_form_why", "attention_form_by_kind", "head_form",
-        "scan_form", "mesh_shape", "param_bytes_per_chip", "centre_form",
-        "centre_form_why", "centre_bytes_per_chip")
+        "head_form_why", "scan_form", "mesh_shape", "param_bytes_per_chip",
+        "centre_form", "centre_form_why", "centre_bytes_per_chip")
 
     def build_facts(self) -> dict:
         return {name: getattr(self, name) for name in self.BUILD_FACTS}

@@ -114,21 +114,66 @@ def dma_gather(monkeypatch):
 
 
 @pytest.fixture
-def kernel_attention(monkeypatch):
-    """``with kernel_attention():`` — sharded engines built inside resolve
-    ``attention_form == "kernel"`` on the suite's CPU mesh, where the rule
-    says "xla", and open their ``kernel_scope``; ``_pallas_interpret``
-    comes from the mesh, so the kernels run under the Pallas interpreter.
-    The next-token head's form then follows from its own shapes
-    (ops/pallas_head.py).  A fake substituted by the test: nothing in the
+def tiny_widths(monkeypatch):
+    """Inside a ``kernel_scope`` a call of ``lm_blocks.attention_core``
+    takes the kernel at ANY widths: the suite's tiny models have heads of 8
+    and sequences of 16, which the call's own rule
+    (``pallas_attention.fits``: Mosaic's 128-lane column blocks) turns
+    away and the Pallas interpreter runs.  The engine-level rule
+    (``attention_form_why``) is not touched.  A fake substituted by the
+    test: nothing in the package reads it."""
+    from estorch_tpu.ops import pallas_attention
+
+    monkeypatch.setattr(pallas_attention, "fits", lambda *shapes: True)
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """``with as_tpu():`` — sharded engines built inside resolve their
+    kernels' rules as on a mesh of TPU devices (where Mosaic kernels may be
+    traced, ``ShardedESEngine._traced_rule``, and the attention's form)
+    from the suite's CPU mesh as it is: its size, the centre's form, the
+    shapes.  ``_pallas_interpret`` still comes from the mesh, so what the
+    rules admit runs under the Pallas interpreter.  A fake substituted by
+    the test: nothing in the package reads it."""
+    from estorch_tpu.ops import pallas_attention
+    from estorch_tpu.parallel import sharded
+
+    def on_tpu(rule):
+        return lambda platform, *observed: rule("tpu", *observed)
+
+    @contextlib.contextmanager
+    def forced():
+        with monkeypatch.context() as m:
+            m.setattr(sharded, "traced_why",
+                      on_tpu(pallas_attention.traced_why))
+            m.setattr(sharded, "attention_form_why",
+                      on_tpu(pallas_attention.attention_form_why))
+            yield
+
+    return forced
+
+
+@pytest.fixture
+def kernel_attention(monkeypatch, tiny_widths):
+    """``with kernel_attention():`` — sharded engines built inside open
+    their ``kernel_scope`` on the suite's CPU mesh, where the rule says no
+    kernel may be traced, and resolve ``attention_form == "kernel"`` at the
+    tiny widths of the suite's models, whose calls then take the kernel
+    (``tiny_widths``); ``_pallas_interpret`` comes from the mesh, so the
+    kernels run under the Pallas interpreter.  The next-token head's and
+    the scan's forms follow from their own shapes (ops/pallas_head.py,
+    ops/pallas_scan.py).  A fake substituted by the test: nothing in the
     package reads it."""
     from estorch_tpu.parallel.sharded import ShardedESEngine
 
     @contextlib.contextmanager
     def forced():
         with monkeypatch.context() as m:
-            m.setattr(ShardedESEngine, "_resolve_attention_form",
-                      lambda self, widths: "kernel")
+            m.setattr(ShardedESEngine, "_traced_rule",
+                      lambda self: (True, "forced by the test"))
+            m.setattr(ShardedESEngine, "_attention_rule",
+                      lambda self, widths: ("kernel", "forced by the test"))
             yield
 
     return forced

@@ -17,6 +17,12 @@ from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
                                        unmatched_leaves)
 
+# the models here are tiny (heads of 8, sequences of 16): inside a
+# ``kernel_scope`` their attention calls take the kernel all the same
+# (conftest.py::tiny_widths fakes the call's own rule,
+# ``pallas_attention.fits``, which the interpreter does not need)
+pytestmark = pytest.mark.usefixtures("tiny_widths")
+
 # float32 on both sides; what differs is the ORDER of float32 sums (chunked
 # scan against sequential recurrence, blocked softmax against whole, split
 # against fused projections) on logits of magnitude 0.1: a few ulps of the

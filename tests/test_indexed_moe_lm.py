@@ -28,6 +28,12 @@ from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
+# the models here are tiny (heads of 8, sequences of 16): inside a
+# ``kernel_scope`` their attention calls take the kernel all the same
+# (conftest.py::tiny_widths fakes the call's own rule,
+# ``pallas_attention.fits``, which the interpreter does not need)
+pytestmark = pytest.mark.usefixtures("tiny_widths")
+
 # float32 on both sides; what differs is the ORDER of float32 sums (blocked
 # softmax against whole, grouped matmul against a masked loop) on values of
 # magnitude 1: measured 1e-6.  1e-4 would still catch bfloat16 anywhere

@@ -23,6 +23,12 @@ from estorch_tpu.ops.pallas_attention import (attention_form,
                                               causal_attention, kernel_block,
                                               kernel_scope, scoped_interpret)
 
+# the models here are tiny (heads of 8, sequences of 16): inside a
+# ``kernel_scope`` their attention calls take the kernel all the same
+# (conftest.py::tiny_widths fakes the call's own rule,
+# ``pallas_attention.fits``, which the interpreter does not need)
+pytestmark = pytest.mark.usefixtures("tiny_widths")
+
 HD = 8  # a tiny head: only Mosaic needs 128 lanes, the interpreter none
 # float32 on both sides, sums in another order: measured up to 5e-7
 F32_TOL = 1e-5

@@ -17,12 +17,13 @@ import numpy as np
 import optax
 import pytest
 
+import lm_tiny
 import loop_tiny
 from pallas_costs import declared_costs, pallas_calls
 from estorch_tpu.models import HybridLM, LoopedLM, MoELM, lm_blocks
 from estorch_tpu.models.perturbed import perturbed_dense
 from estorch_tpu.ops.pallas_attention import kernel_scope
-from estorch_tpu.ops.pallas_head import (fits, head_cost, head_form,
+from estorch_tpu.ops.pallas_head import (fits, head_cost, head_form_why,
                                          score_rows)
 
 # float32 on both sides, sums in another order: measured up to 2e-6 on
@@ -241,25 +242,43 @@ class TestKernelAgainstTheXlaForm:
 
 
 class TestTheRule:
-    @pytest.mark.parametrize("attention, hidden, length, itemsize, form", [
-        ("kernel", 2048, 4096, 2, "kernel"),
-        ("kernel", 128, 512, 4, "kernel"),
-        ("kernel", 8192, 4096, 2, "kernel"),
-        ("xla", 2048, 4096, 2, "xla"),  # no scope is opened: any other mesh
-        (None, 2048, 4096, 2, "xla"),
-        ("kernel", 2000, 4096, 2, "xla"),   # not whole 128-lane blocks
-        ("kernel", 32, 512, 4, "xla"),
-        ("kernel", 8192, 4096, 4, "xla"),   # too wide to contract whole
-        ("kernel", 16384, 4096, 2, "xla"),
-        ("kernel", 2048, 4000, 2, "xla"),   # not whole row tiles of 512
-        ("kernel", 2048, 1536, 2, "kernel"),
-        ("kernel", 2048, 256, 2, "xla"),
+    @pytest.mark.parametrize("traced, hidden, length, itemsize, form", [
+        (True, 2048, 4096, 2, "kernel"),
+        (True, 128, 512, 4, "kernel"),
+        (True, 8192, 4096, 2, "kernel"),
+        (False, 2048, 4096, 2, "xla"),  # no scope is opened: any other mesh
+        (False, 128, 512, 4, "xla"),
+        (True, 2000, 4096, 2, "xla"),   # not whole 128-lane blocks
+        (True, 32, 512, 4, "xla"),
+        (True, 8192, 4096, 4, "xla"),   # too wide to contract whole
+        (True, 16384, 4096, 2, "xla"),
+        (True, 2048, 4000, 2, "xla"),   # not whole row tiles of 512
+        (True, 2048, 1536, 2, "kernel"),
+        (True, 2048, 256, 2, "xla"),
     ])
-    def test_form_from_what_the_engine_observes(self, attention, hidden,
+    def test_form_from_what_the_engine_observes(self, traced, hidden,
                                                 length, itemsize, form):
-        assert head_form(attention, hidden, length, itemsize) == form
+        """The head's own rule: whether Mosaic kernels may be traced, and
+        the head's shapes; the attention's form is no part of it."""
+        assert head_form_why((traced, "where"), hidden, length,
+                             itemsize)[0] == form
         assert fits(hidden, length, itemsize) == (
-            form == "kernel" or attention != "kernel")
+            form == "kernel" or not traced)
+
+    @pytest.mark.parametrize("traced, shapes, form, says", [
+        ((True, "one TPU device"), (2048, 4096, 2), "kernel",
+         "one TPU device; a hidden width of whole 128-lane blocks"),
+        ((False, "the devices are 'cpu', not TPUs"), (2048, 4096, 2), "xla",
+         "the devices are 'cpu', not TPUs"),
+        ((True, "one TPU device"), (2000, 4096, 2), "xla",
+         "hidden states 2000 wide in 2-byte operands over 4096 positions"),
+        ((True, "one TPU device"), (2048, 4000, 2), "xla",
+         "over whole row tiles of 512"),
+    ])
+    def test_the_rule_names_what_decided(self, traced, shapes, form, says):
+        got, why = head_form_why(traced, *shapes)
+        assert got == form and says in why
+
 
     def test_outside_a_scope_or_past_the_shapes_the_xla_form(self):
         """``score_next_tokens`` itself: no scope, a hidden width that is
@@ -377,22 +396,46 @@ class TestWhatTheProgramHolds:
 
 # ----------------------------------------------------- through the engine
 
-def _lm_es(devices, model_shards=1, **policy):
+# the smallest models whose head fits the rule, by the head's layout:
+# (model, its tiny sizes, what is changed of them)
+HEADS = {
+    # an untied [hidden, vocab] kernel, no scaling
+    "untied": (LoopedLM, loop_tiny, {
+        "layer_types": ("full_attention",), "total_ut_steps": 2}),
+    # granite's: the embedding read transposed, the logits divided by 8,
+    # and heads of 64 with values of 64, which the attention's kernel
+    # turns away
+    "tied_scaled": (HybridLM, lm_tiny, {
+        "layer_types": ("attention", "mamba"), "mamba_chunk_size": 64,
+        "num_attention_heads": 2, "num_key_value_heads": 1}),
+    "tied": (HybridLM, lm_tiny, {
+        "layer_types": ("attention", "mamba"), "mamba_chunk_size": 64,
+        "num_attention_heads": 2, "num_key_value_heads": 1,
+        "logits_scaling": 1.0}),
+}
+
+
+def _lm_es(devices, model_shards=1, head="untied", population_size=4,
+           **policy):
     from estorch_tpu import ES, JaxAgent
     from estorch_tpu.envs import TokenScoreEnv
 
+    model, tiny, sizes = HEADS[head]
     return ES(
-        policy=LoopedLM, agent=JaxAgent, optimizer=optax.adam,
-        population_size=4, sigma=0.02,
-        policy_kwargs={**loop_tiny.TINY, "hidden_size": HIDDEN,
-                       "layer_types": ("full_attention",),
-                       "total_ut_steps": 2, "attention_block": 128,
-                       "head_block": 96, **policy},
+        policy=model, agent=JaxAgent, optimizer=optax.adam,
+        population_size=population_size, sigma=0.02,
+        policy_kwargs={**tiny.TINY, "hidden_size": HIDDEN,
+                       "attention_block": 128, "head_block": 96, **sizes,
+                       **policy},
         agent_kwargs={"env": TokenScoreEnv(
-            **{**loop_tiny.ENV, "seq_len": LENGTH})},
+            **{**tiny.ENV, "seq_len": LENGTH})},
         optimizer_kwargs={"learning_rate": 1e-2}, shard_params=True,
         model_shards=model_shards, low_rank=1, noise_mode="table",
         table_size=1 << 18, device=list(devices))
+
+
+def _program(es):
+    return lambda: es.engine._generation_step(es.state, es.table.data)
 
 
 class TestThroughTheShardedEngine:
@@ -456,3 +499,106 @@ class TestThroughTheShardedEngine:
         np.testing.assert_allclose(np.asarray(kern.state.params_flat),
                                    np.asarray(ref.state.params_flat),
                                    atol=1e-4, rtol=0)
+
+
+class TestOnAMeshOfSeveralDevices:
+    """The rule "TPU devices and whole members on a chip", and the
+    partition that makes it true: on a (2, 2) mesh whose centre is
+    gathered the engine evaluates a chunk's pairs under a ``shard_map``, so
+    the head's kernel runs a chip's OWN members.  ``as_tpu`` lets the
+    suite's CPU mesh resolve the rules as TPU devices would; the kernels
+    run under the interpreter."""
+
+    @pytest.mark.parametrize("head", sorted(HEADS))
+    def test_every_members_fitness_is_the_xla_heads(self, devices8, as_tpu,
+                                                    head):
+        """(2, 2), gathered, one pair a chip: the fitness of all 8 members
+        and the update, head in the kernel against head in the XLA form,
+        to the order of float32 sums."""
+        ref = _lm_es(devices8[:4], 2, head, population_size=8)
+        with as_tpu():
+            kern = _lm_es(devices8[:4], 2, head, population_size=8)
+        assert (ref.engine.centre_form, kern.engine.centre_form) == (
+            "gathered", "gathered")
+        assert (ref.engine.head_form, kern.engine.head_form) == (
+            "xla", "kernel")
+        assert [len(pallas_calls(_program(es))) for es in (ref, kern)] == [
+            0, 1]
+        ref.state, want = ref.engine.generation_step(ref.state)
+        kern.state, got = kern.engine.generation_step(kern.state)
+        assert np.isfinite(np.asarray(got["fitness"])).all()
+        # a fitness is a mean log p of about -log(64) = -4.16
+        np.testing.assert_allclose(got["fitness"], want["fitness"],
+                                   atol=F32_TOL, rtol=0)
+        np.testing.assert_allclose(np.asarray(kern.state.params_flat),
+                                   np.asarray(ref.state.params_flat),
+                                   atol=1e-4, rtol=0)
+
+    @pytest.mark.parametrize("population_size, pairs_a_chip", [(8, 1),
+                                                               (16, 2)])
+    def test_a_chips_call_holds_its_own_members_rows(
+            self, devices8, as_tpu, population_size, pairs_a_chip):
+        """The call is partitioned, not replicated: its rows are the pairs
+        of ONE chip x 2 signs x T, whatever the population, and ``W``
+        enters it whole (a replicated call would hold every pair's rows)."""
+        with as_tpu():
+            es = _lm_es(devices8[:4], 2, "tied_scaled",
+                        population_size=population_size)
+        assert es.engine.pair_chunk == population_size // 2
+        call, = pallas_calls(_program(es))
+        h, w, *_ = call.invars
+        assert h.aval.shape == (pairs_a_chip * 2 * LENGTH, HIDDEN)
+        assert w.aval.shape == (64, HIDDEN)
+        assert [v.aval.shape for v in call.outvars] == [
+            (pairs_a_chip * 2 * LENGTH, 1)]
+
+    @pytest.mark.parametrize(
+        "n_devices, model_shards, centre, head, attention, form, says", [
+            (1, 1, "gathered", "untied", "kernel", "kernel",
+             "one TPU device"),
+            (4, 2, "gathered", "untied", "kernel", "kernel",
+             "4 TPU devices, whole members on each"),
+            (4, 2, "split", "untied", "xla", "xla",
+             "a member's operands are not whole on a chip"),
+            # a model axis of 1 gathers nothing: the pairs over ``pop``
+            # alone, left to GSPMD
+            (4, 1, "gathered", "untied", "xla", "xla",
+             "4 devices on the mesh and the centre split"),
+            # heads of 64 with values of 64: the attention in the XLA
+            # form, the head beside it in its kernel, in one program
+            (1, 1, "gathered", "tied_scaled", "xla", "kernel",
+             "one TPU device"),
+            (4, 2, "gathered", "tied_scaled", "xla", "kernel",
+             "4 TPU devices, whole members on each"),
+        ])
+    def test_the_rule_as_the_engine_resolves_it(
+            self, devices8, as_tpu, monkeypatch, n_devices, model_shards,
+            centre, head, attention, form, says):
+        from estorch_tpu.parallel import sharded
+
+        if centre == "split":   # a chip with no room for the centre
+            monkeypatch.setattr(sharded, "CHIP_MEMORY_BYTES", 0)
+        with as_tpu():
+            # heads of 128 where the attention's kernel is to fit
+            es = _lm_es(devices8[:n_devices], model_shards, head,
+                        **({"head_dim": 128, "num_key_value_heads": 1}
+                           if attention == "kernel" or head == "untied"
+                           else {}))
+        engine = es.engine
+        assert (engine.attention_form, engine.head_form) == (attention, form)
+        assert says in engine.head_form_why
+        assert es.run_manifest()["config"]["head_form_why"] == (
+            engine.head_form_why)
+        assert "head_form_why" not in es.obs.counters.snapshot()
+        names = " ".join(eqn.params["name"]
+                         for eqn in pallas_calls(_program(es)))
+        assert ("causal_attention" in names) == (attention == "kernel")
+        assert ("next_token_scores" in names) == (form == "kernel")
+
+    def test_a_cpu_mesh_without_the_fixture_traces_no_kernel(self, devices8):
+        es = _lm_es(devices8[:4], 2, "tied_scaled", population_size=8)
+        assert es.engine.centre_form == "gathered"
+        assert (es.engine.kernels_traced, es.engine.head_form) == (
+            False, "xla")
+        assert es.engine.head_form_why == "the devices are 'cpu', not TPUs"
+        assert pallas_calls(_program(es)) == []

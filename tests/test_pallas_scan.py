@@ -153,15 +153,17 @@ class TestTheRule:
     def test_the_channel_block(self, d_inner, want):
         assert channel_block(d_inner) == want
 
-    @pytest.mark.parametrize("attention, shapes, want", [
-        ("kernel", (5120, 16, 8192), "kernel"),
-        ("xla", (5120, 16, 8192), "xla"),     # no scope: another mesh
-        (None, (5120, 16, 8192), "xla"),      # a policy without attention
-        ("kernel", (5120, 16, 8191), "xla"),
-        ("kernel", (5121, 16, 8192), "xla"),
+    @pytest.mark.parametrize("traced, shapes, want", [
+        (True, (5120, 16, 8192), "kernel"),
+        (False, (5120, 16, 8192), "xla"),     # no scope: another mesh
+        (False, (128, 4, 256), "xla"),
+        (True, (5120, 16, 8191), "xla"),
+        (True, (5121, 16, 8192), "xla"),
     ])
-    def test_the_form(self, attention, shapes, want):
-        assert scan_form(attention, *shapes) == want
+    def test_the_form(self, traced, shapes, want):
+        """The scan's own rule: whether Mosaic kernels may be traced, and
+        the scan's shapes; the attention's form is no part of it."""
+        assert scan_form(traced, *shapes) == want
 
     def test_nothing_reads_the_backend(self):
         source = open(pallas_scan.__file__).read()

@@ -27,6 +27,12 @@ from estorch_tpu.ops.pallas_attention import (attention_form,
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
                                        unmatched_leaves)
 
+# the models here are tiny (heads of 8, sequences of 16): inside a
+# ``kernel_scope`` their attention calls take the kernel all the same
+# (conftest.py::tiny_widths fakes the call's own rule,
+# ``pallas_attention.fits``, which the interpreter does not need)
+pytestmark = pytest.mark.usefixtures("tiny_widths")
+
 # float32 on both sides; what differs is the ORDER of float32 sums (blocked
 # softmax against whole, one call of the core against two softmaxes a head)
 # on values of magnitude 1: measured 2e-6 to 6e-6.  1e-4 would still catch

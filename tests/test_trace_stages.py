@@ -665,14 +665,17 @@ def _looped_engine_on(devices, model_shards, head_dim, length, latent=False,
     (1, 1, 128, 256, "kernel"),
     (1, 1, 64, 256, "xla"),     # granite's head: half a lane tile
     (1, 1, 128, 200, "xla"),    # no block of the kernel divides it
-    (4, 2, 128, 256, "xla"),    # granite's mesh: operands not whole
-    (4, 1, 128, 256, "xla"),
+    # granite's mesh: the centre gathered, whole members on each chip
+    (4, 2, 128, 256, "kernel"),
+    (4, 2, 64, 256, "xla"),     # and granite's heads on it
+    (4, 1, 128, 256, "xla"),    # nothing to gather: the centre split
 ])
 def test_attention_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim,
                                       length, form, v5e_2x2):
     """``ShardedESEngine.attention_form`` on meshes of TPU devices, each
-    arm of the rule (devices on the mesh, ``head_dim``, the sequence) —
-    what a chip run resolves, with nothing compiled."""
+    arm of the rule (whole members on a chip: one device, or several with
+    the centre gathered; ``head_dim``; the sequence) — what a chip run
+    resolves, with nothing compiled."""
     _, engine = _looped_engine_on(v5e_2x2[:n_devices], model_shards,
                                   head_dim, length)
     assert engine.attention_form == form
@@ -681,17 +684,53 @@ def test_attention_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim,
 @pytest.mark.parametrize("n_devices, model_shards, head_dim, length, form", [
     (1, 1, 128, 512, "kernel"),
     (1, 1, 128, 256, "xla"),    # no row tile of the head's divides it
-    (1, 1, 64, 512, "xla"),     # the attention's form opens no scope
-    (4, 2, 128, 512, "xla"),    # granite's mesh
+    # the head's rule is its own: heads the attention's kernel turns away
+    (1, 1, 64, 512, "kernel"),
+    (4, 2, 128, 512, "kernel"),  # granite's mesh: the centre gathered
+    (4, 2, 64, 512, "kernel"),   # granite's mesh and granite's heads
+    (4, 2, 64, 256, "xla"),
+    (4, 1, 128, 512, "xla"),     # the centre split: no whole member a chip
 ])
 def test_head_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim, length,
                                  form, v5e_2x2):
     """``ShardedESEngine.head_form`` on meshes of TPU devices: the kernel
-    inside the scope a one-device mesh opens where the head's own shapes
-    fit (hidden 128 here), the XLA form on every other."""
+    wherever Mosaic kernels may be traced (one device, or several with the
+    centre gathered) and the head's own shapes fit (hidden 128 here),
+    whatever form the attention takes; the XLA form on every other."""
     _, engine = _looped_engine_on(v5e_2x2[:n_devices], model_shards,
                                   head_dim, length)
     assert engine.head_form == form
+    assert engine.kernels_traced == (n_devices == 1 or model_shards == 2)
+
+
+def test_on_a_2x2_mesh_a_chips_head_call_holds_its_own_members(v5e_2x2):
+    """The generation program of a small looped model with granite's heads
+    (64 wide, values of 64) compiled for a described v5e 2x2, ``(pop 2,
+    model 2)``, the centre gathered, eight pairs: Mosaic compiles the
+    head's kernel inside the engine's ``shard_map`` over the pairs; ONE
+    call, under es.head inside es.policy, whose rows are the two pairs of
+    ONE chip x 2 signs x 512 positions (a replicated call would hold all
+    eight pairs' 8,192) and whose ``W`` is the whole gathered leaf; the
+    attention beside it stays in the XLA form; and the partition adds no
+    collective: what crosses the chips before the evaluation is the
+    centre's gather, as without a kernel."""
+    es, engine = _looped_engine_on(v5e_2x2, 2, 64, 512, population_size=16)
+    assert (engine.centre_form, engine.attention_form, engine.head_form) == (
+        "gathered", "xla", "kernel")
+    assert "4 TPU devices, whole members on each" in engine.head_form_why
+    assert (engine.pair_chunk, engine.n_pair_chunks) == (8, 1)
+    text = _compiled_generation(es, engine)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    # the one Mosaic call of the program: no attention kernel beside it
+    assert len(calls) == 1 and "next_token_scores" in calls[0]
+    assert "bf16[2048,128]" in calls[0] and "bf16[128,256]" in calls[0]
+    assert "8192" not in calls[0]
+    name, = re.findall(r'op_name="([^"]*)"', calls[0])
+    assert SCOPE.findall(name)[-2:] == [POLICY, HEAD], name
+    assert PART.findall(name) == ["head"], name
+    moved = _collectives_by_computation(text)
+    assert not moved["in a loop"], moved["in a loop"]
+    assert not [c for cs in moved.values() for c in cs if 512 in c[2]]
 
 
 @pytest.mark.parametrize("latent", [False, True], ids=["looped", "latent"])
