@@ -5,6 +5,7 @@ from .looped_lm import LoopedLM
 from .moe_lm import MoELM
 from .sambay_lm import SambaYLM
 from .policies import MLPPolicy, NatureCNN, RecurrentNatureCNN, RecurrentPolicy
+from .window_moe_lm import WindowMoELM
 from .vbn import VirtualBatchNorm, capture_reference_stats
 
 
@@ -34,6 +35,7 @@ __all__ = [
     "RecurrentPolicy",
     "SambaYLM",
     "VirtualBatchNorm",
+    "WindowMoELM",
     "TorchVirtualBatchNorm",
     "capture_reference_stats",
 ]

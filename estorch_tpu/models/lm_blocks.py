@@ -1,7 +1,9 @@
-"""The pieces the SIX sequence models are built from
+"""The pieces the SEVEN sequence models are built from
 (models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py,
-models/sambay_lm.py, models/indexed_moe_lm.py, models/cca_moe_lm.py): ONE
-RMSNorm, ONE LayerNorm, ONE gated SiLU FFN, ONE causal attention core (full,
+models/sambay_lm.py, models/indexed_moe_lm.py, models/cca_moe_lm.py,
+models/window_moe_lm.py): ONE RMSNorm, ONE LayerNorm, ONE gated FFN whose
+gate's activation is the MODEL's (SiLU where it states none, ReLU for a
+ReGLU model), ONE causal attention core (full,
 banded or over a learned SELECTION of keys), ONE indexer that makes such a
 selection, ONE differential combine, ONE depthwise causal conv, the mixing
 of q, k and v inside a compressed latent (a per-head convolution, the q-k
@@ -120,7 +122,11 @@ bisection on the float's bits (32 counting passes, no sort), exactly.
 The expert layer (:func:`routed_experts`) is told which experts it holds:
 it routes over all of them (:func:`route`: sigmoid scores and a selection
 bias, or a softmax over all experts), computes what its own experts
-give for the (token, k) pairs routed to them and leaves the rest out.  One
+give for the (token, k) pairs routed to them and leaves the rest out.
+The two are separate functions: a model routes from the state its experts
+read (:func:`routed_ffn`) or from another one, the layer's input ahead of
+attention (models/window_moe_lm.py), and the gate's activation is the
+model's, as the dense FFN's.  One
 implementation: the pairs of ALL members under the ``vmap``s around it are
 sorted by expert together, so that the centre's stacked ``[E, m, n]``
 leaves go through one grouped matmul whose work follows the rows routed,
@@ -212,7 +218,7 @@ def causal_conv(x, taps, bias):
 def dense(p, noise, c, name, x, bias: str | None = None,
           under: str = DENSE):
     """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``, the
-    part ``of.<name>``: every projection of the six models says here
+    part ``of.<name>``: every projection of the seven models says here
     which leaf it multiplies (obs/trace.py).  ``bias``: the key of a bias
     leaf of ``p`` (perturbed like any small leaf), added to the product.
     ``under``: the stage of a projection that belongs to another one (the
@@ -235,13 +241,14 @@ def subtree(noise, *path):
     return noise
 
 
-def gated_mlp(dense, p, noise, c, u):
-    """``down(silu(gate u) ⊙ up u)``, float32 out."""
+def gated_mlp(dense, p, noise, c, u, activation=jax.nn.silu):
+    """``down(activation(gate u) ⊙ up u)``, float32 out; the gate's
+    ``activation`` is the model's (SiLU; ``jax.nn.relu``: ReGLU)."""
     dtype = u.dtype
     gate = dense(p, noise, c, "gate", u)
     up = dense(p, noise, c, "up", u)
     with stage(DENSE), part("down"):    # the operand ``down`` multiplies
-        act = (jax.nn.silu(gate) * up).astype(dtype)
+        act = (activation(gate) * up).astype(dtype)
     return dense(p, noise, c, "down", act)
 
 
@@ -734,11 +741,14 @@ def state_router(p, noise, c, u, below, eps: float):
 
 
 def routed_ffn(moe, noise, c, u, dtype, *, top_k: int, scaling: float,
-               first_held: int, total: int, scoring: str = "sigmoid"):
+               first_held: int, total: int, scoring: str = "sigmoid",
+               activation=jax.nn.silu):
     """An expert layer's routed part of the float32 tokens ``u``:
     :func:`route` over all experts, then :func:`routed_experts` on the
     tokens in the compute ``dtype`` for the experts ``moe["experts"]``
-    holds; ``(the held experts' sum [T, hidden], pairs per held expert)``."""
+    holds; ``(the held experts' sum [T, hidden], pairs per held expert)``.
+    A model whose router reads ANOTHER state than its experts (the layer's
+    input, ahead of attention) calls the two functions itself."""
     # (the older form is asked for as it always was: what stands in for
     # ``route`` in a rehearsal takes the arguments it had then)
     experts, weights = route(
@@ -746,7 +756,8 @@ def routed_ffn(moe, noise, c, u, dtype, *, top_k: int, scaling: float,
         **({} if scoring == "sigmoid" else {"scoring": scoring}))
     return routed_experts(
         moe["experts"], subtree(noise, "experts"), c, u.astype(dtype),
-        experts, weights, first_held=first_held, total=total)
+        experts, weights, first_held=first_held, total=total,
+        activation=activation)
 
 
 def expert_capacity(pairs: int, held: int, total: int) -> int:
@@ -761,19 +772,22 @@ def expert_capacity(pairs: int, held: int, total: int) -> int:
 
 
 def routed_experts(p, noise, c, u, experts, weights, *, first_held: int,
-                   total: int):
+                   total: int, activation=jax.nn.silu):
     """``(Σ_k weights_k · expert_{experts_k}(u) over the pairs whose expert
     is HELD here [T, hidden] float32, pairs per held expert [held]
     int32)``: the held experts are ``first_held … first_held + held - 1``
     of ``total``, ``held`` the leading axis of the stacked ``p["gate"]``,
     ``p["up"] [held, hidden, width]`` and ``p["down"] [held, width,
-    hidden]`` (gated SiLU).  What the other experts would have added is
+    hidden]`` (gated: ``down(activation(gate u) ⊙ up u)``, the gate's
+    ``activation`` the model's, SiLU where it states none).  ``experts``
+    and ``weights`` are the routes of :func:`route`, from whichever state
+    the model's router reads.  What the other experts would have added is
     left out.  ``held == total`` is the uncut layer.
 
     Under the engine's ``vmap``s over members the centre ``p`` is not
     batched, and the pairs of every member are handled together (the
     module's text): one sort, one grouped matmul a leaf and pass."""
-    core = _expert_core(int(first_held), int(total))
+    core = _expert_core(int(first_held), int(total), activation)
     c = jnp.asarray(c, F32).reshape(1)
     noise = None if noise is None else {
         n: tuple(f[None] for f in noise[n]) for n in ("gate", "up", "down")}
@@ -783,17 +797,17 @@ def routed_experts(p, noise, c, u, experts, weights, *, first_held: int,
 
 
 @functools.lru_cache(maxsize=None)
-def _expert_core(first_held: int, total: int):
+def _expert_core(first_held: int, total: int, activation):
     """The expert layer over a set of members, ``custom_vmap``'d so that a
     ``vmap`` over more members (pairs, signs) GROWS the set instead of
     batching the sort and the grouped matmul: ``(u [M, T, hidden], experts
     [M, T, K], weights [M, T, K], c [M], centre {gate, up, down}, noise
     {name: (A [M, E, m, r], B [M, E, n, r])} | None) -> (y [M, T, hidden],
-    load [M, E])``."""
+    load [M, E])``; ``activation``: the experts' gate's."""
 
     def impl(u, experts, weights, c, centre, noise):
         return _experts_of_members(u, experts, weights, c, centre, noise,
-                                   first_held, total)
+                                   first_held, total, activation)
 
     core = jax.custom_batching.custom_vmap(impl)
 
@@ -824,7 +838,7 @@ def _expert_core(first_held: int, total: int):
 
 
 def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
-                        total):
+                        total, activation):
     """:func:`_expert_core` written out: sort the pairs by held expert
     (the others last), then ``capacity`` sorted rows at a time: gather the
     tokens, the gated FFN as grouped matmuls with each row's (member,
@@ -876,7 +890,7 @@ def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
             gate = grouped("gate", x, sizes, row_expert, row_member)
             up = grouped("up", x, sizes, row_expert, row_member)
             with part("down"):
-                act = (jax.nn.silu(gate) * up).astype(x.dtype)
+                act = (activation(gate) * up).astype(x.dtype)
             out = grouped("down", act, sizes, row_expert, row_member)
         with stage(DISPATCH):
             # the rows past the routed ones land nowhere

@@ -65,14 +65,17 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "update",    # weight decay, optax step, sigma decay, obs-norm probe
     # nested inside es.policy by a sequence model (models/hybrid_lm.py,
     # models/looped_lm.py, models/moe_lm.py, models/sambay_lm.py,
-    # models/indexed_moe_lm.py, models/cca_moe_lm.py on the pieces of
+    # models/indexed_moe_lm.py, models/cca_moe_lm.py,
+    # models/window_moe_lm.py on the pieces of
     # models/lm_blocks.py)
     "dense",     # the shared x@W projections and the gated FFN
     "ssm",       # conv1d, dt and decay, the scan (Mamba-2's chunked form,
                  # Mamba-1's selective one), the gate
     "attn",      # scores, softmax, P.V (a model with several kinds of
                  # attention names each a part: of.window, of.full, of.cross;
-                 # attention over a selection of keys: of.selected)
+                 # attention over a selection of keys: of.selected;
+                 # rotary banded layers beside position-free full ones:
+                 # of.window, of.global)
     "head",      # the logits (tied or not), log-softmax, the score
     "rope",      # rotary positions: cos/sin, rotating queries and keys
     "exit",      # a looped model's exit gate, the exit distribution and
@@ -80,7 +83,8 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     "route",     # an expert layer's router: its matmul, sigmoid, selection
                  # bias, top-k and the renormalised weights (a router that
                  # is an MLP over a carried state names its parts:
-                 # of.router_down, of.router_state, of.router_mlp)
+                 # of.router_down, of.router_state, of.router_mlp; a router
+                 # that reads the layer's input sits AHEAD of es.attn)
     "dispatch",  # sorting (token, k) pairs by held expert, the gather into
                  # expert order and the weighted combine back
     "expert",    # the grouped matmuls over the routed rows and the

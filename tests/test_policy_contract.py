@@ -21,12 +21,13 @@ import lm_tiny
 import loop_tiny
 import moe_tiny
 import sambay_tiny
+import window_moe_tiny
 from policy_contract_parent import PARENT
 
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole, TokenScoreEnv
 from estorch_tpu.models import (CCAMoELM, HybridLM, IndexedMoELM, LoopedLM,
-                                MoELM, SambaYLM)
+                                MoELM, SambaYLM, WindowMoELM)
 from estorch_tpu.models.perturbed import PolicyDeclaration, declaration_of
 from estorch_tpu.parallel.engine import MANIFEST_BUILD_FACTS
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
@@ -43,6 +44,7 @@ SEQUENCE_MODELS = {
     "indexed_moe": (IndexedMoELM, indexed_moe_tiny, 1, 1),
     # added after the seam moved: no literal of the parent's to hold it to
     "cca_moe": (CCAMoELM, cca_moe_tiny, 1, 1),
+    "window_moe": (WindowMoELM, window_moe_tiny, 1, 1),
 }
 
 
@@ -148,6 +150,16 @@ STATED = {
         facts={"experts_held": 2, "experts_total": 4,
                "experts_per_token": 1, "mtp_depth": 0, "latent_q_width": 64,
                "latent_kv_width": 16, "conv_taps": 4, "router_hidden": 16}),
+    # two kinds of attention layer, each with its band; the routes are
+    # taken ahead of attention, which the declaration need not say
+    "window_moe": dict(
+        leaf_rows={"head/kernel": 8}, attention_widths=8,
+        attention_kv_heads=2, head_width=32,
+        attention_windows={"window": 6, "global": None},
+        outputs=("expert_load",),
+        facts={"experts_held": 4, "experts_total": 16,
+               "experts_per_token": 3, "mtp_depth": 0, "sliding_window": 6,
+               "window_layers": 2, "global_layers": 1}),
 }
 
 

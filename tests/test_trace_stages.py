@@ -107,7 +107,7 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source,
     assert any(POLICY in stack for stack in matmuls)
 
 
-# the six sequence models on the sharded engine's perturbed form: what
+# the seven sequence models on the sharded engine's perturbed form: what
 # each is built from, the stages its forward does NOT name, the layers it
 # nests inside es.policy, and the parts it names that are no leaf's
 SEQUENCE_MODELS = {
@@ -153,10 +153,18 @@ SEQUENCE_MODELS = {
                    more_parts={"conv_time": MIX, "conv_head": MIX,
                                "qk_mean": MIX, "value_shift": MIX,
                                "qk_norm": MIX, "router_state": ROUTE}),
+    # its two kinds of attention say which they are; its router sits AHEAD
+    # of the attention, under the same es.route
+    "windowed": dict(policy="WindowMoELM", tiny="window_moe_tiny", devices=1,
+                     model_shards=1,
+                     absent=({SSM, EXIT} | SAMBAY_STAGES | INDEXED_STAGES
+                             | LATENT_STAGES),
+                     inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT),
+                     more_parts={"window": ATTN, "global": ATTN}),
 }
 # the models whose lowered text is read (an expert layer's grouped matmul
 # keeps its name there and not in the compiled program's)
-ROUTED = ("expert", "indexed", "latent")
+ROUTED = ("expert", "indexed", "latent", "windowed")
 PART = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(PART_PREFIX)
                   + r"([A-Za-z0-9_.]+)")
 
@@ -261,6 +269,21 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         assert any(st[-1] == HEAD and PART.findall(n) == ["embed"]
                    for st, n in stacks)
         assert es.obs.counters.get("conv_taps") == 4
+    if model == "windowed":
+        # the router's choice is made BEFORE the attention it sits beside:
+        # the first top_k of the program comes ahead of the first softmax
+        # of an attention part (the lowered text is in program order)
+        first = {key: next(i for i, n in enumerate(names) if hit(n))
+                 for key, hit in (
+                     ("route", lambda n: SCOPE.findall(n)[-1:] == [ROUTE]
+                      and "top_k" in n),
+                     ("attn", lambda n: ATTN in SCOPE.findall(n)
+                      and PART.findall(n) == ["global"]))}
+        assert first["route"] < first["attn"], first
+        # only the window layers turn: es.rope under no part of.global
+        assert any(st[-1] == ROPE and ("sin" in n or "cos" in n)
+                   for st, n in stacks)
+        assert es.obs.counters.get("sliding_window") == 6
     if model == "indexed":
         # the score product of every index head against the ONE key head
         # under es.index, the bisection's loop and the prefix count under
@@ -327,7 +350,7 @@ def test_parts_are_metadata_only(model, monkeypatch):
     from estorch_tpu import models
     from estorch_tpu.models import (cca_moe_lm, hybrid_lm, indexed_moe_lm,
                                     lm_blocks, looped_lm, moe_lm, perturbed,
-                                    sambay_lm)
+                                    sambay_lm, window_moe_lm)
 
     case = SEQUENCE_MODELS[model]
     tiny = importlib.import_module(case["tiny"])
@@ -348,7 +371,7 @@ def test_parts_are_metadata_only(model, monkeypatch):
     with_parts = lowered()
     assert PART_PREFIX in with_parts.as_text(debug_info=True)
     for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm,
-                sambay_lm, indexed_moe_lm, cca_moe_lm):
+                sambay_lm, indexed_moe_lm, cca_moe_lm, window_moe_lm):
         monkeypatch.setattr(mod, "part",
                             lambda name: contextlib.nullcontext())
     without = lowered()
@@ -1188,6 +1211,100 @@ def test_kernel_form_books_the_latents_attention_and_the_tied_head(v5e_chip):
     assert PART.findall(heads[0]) == ["embed"], heads
     assert any(SCOPE_PREFIX + MIX in line and "f32[" in line
                for line in text.splitlines())
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_attention_kernel_compiles_for_the_v5e_in_groups_of_seven(
+        dtype, v5e_chip):
+    """Mosaic accepts the attention kernel at ``smallthinker-es-16k-1chip``'s
+    shapes, a grouping no other cell has: 28 query heads over 4 key-value
+    heads of 128 (groups of SEVEN: q ``[16384, 3584]``, k and v ``[16384,
+    512]``), 16,384 positions in blocks of 1,024, one member at a time as the
+    cell's chunk of one pair with its signs in turn evaluates them; nothing
+    else in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import causal_attention
+
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (1, 1, 16384, width), dtype,
+            sharding=SingleDeviceSharding(v5e_chip))
+
+    text = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
+        q, k, v, num_heads=28, num_kv_heads=4, head_dim=128,
+        scale=128 ** -0.5, interpret=False)))).lower(
+            operand(3584), operand(512), operand(512)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " copy(" not in text.split("ENTRY")[1]
+
+
+def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip):
+    """A small decoder with a global and two window layers on a one-device
+    TPU mesh: the rule takes its 14 / 2 heads of 128 (groups of seven) over
+    512 positions; the compiled generation program holds ONE Mosaic call
+    ``causal_attention``, the global layer's, under es.attn inside es.policy
+    in the part ``of.global``, and none in ``of.window``, whose band of 128
+    stays in the XLA form (the kernel has no band): the device trace books
+    them to ``swa.global_attn_share`` and ``swa.window_attn_share``.  The
+    head takes its kernel beside them."""
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import WindowMoELM
+    from estorch_tpu.parallel.mesh import hyperscale_mesh
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    es = _es(
+        policy=WindowMoELM, population_size=4, sigma=0.02,
+        policy_kwargs=dict(
+            layer_types=("global", "window", "window"), vocab_size=256,
+            hidden_size=128, moe_ffn_hidden_size=64, sliding_window_size=128,
+            num_attention_heads=14, num_key_value_heads=2, head_dim=128,
+            moe_num_primary_experts=2, expert_group_size=2,
+            moe_num_active_primary_experts=2, behaviour_positions=64,
+            attention_block=128, head_block=128),
+        agent_kwargs={"env": TokenScoreEnv(
+            vocab_size=256, seq_len=512, corpus_sequences=4)},
+        shard_params=True, low_rank=1, noise_mode="table",
+        compute_dtype="bfloat16", table_size=1 << 18,
+        device=jax.devices()[:1])
+    assert es.engine.attention_form_by_kind == "window:xla,global:xla"
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    engine = ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=1, devices=[v5e_chip]),
+        partition_rules=es._partition_rules, noise_mode="table",
+        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        policy=declaration_of(es.module))
+    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
+    assert engine.attention_form_by_kind == "window:xla,global:kernel"
+    assert engine.attention_form_why.endswith(
+        "layers with a window of 128 in the XLA form")
+    state = jax.tree_util.tree_map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        es.state, engine.state_shardings)
+    table = jax.ShapeDtypeStruct(es.table.data.shape, es.table.data.dtype,
+                                 sharding=engine._repl)
+    text = engine._generation_step.lower(state, table).compile().as_text()
+
+    def calls(kernel):
+        return [name for line in text.splitlines()
+                if "tpu_custom_call" in line and kernel in line
+                for name in re.findall(r'op_name="([^"]*)"', line)]
+
+    kernels = calls("causal_attention")
+    assert len(kernels) == 1, kernels
+    assert SCOPE.findall(kernels[0])[0] == POLICY, kernels
+    assert SCOPE.findall(kernels[0])[-1] == ATTN, kernels
+    assert PART.findall(kernels[0]) == ["global"], kernels
+    heads = calls("next_token_scores")
+    assert len(heads) == 1 and PART.findall(heads[0]) == ["head"], heads
+    # the window layers' scores are XLA's, under es.attn in their own part
+    banded = [n for line in text.splitlines() if "f32[" in line
+              for n in re.findall(r'op_name="([^"]*)"', line)
+              if PART.findall(n) == ["window"]]
+    assert banded and all(ATTN in SCOPE.findall(n) for n in banded)
 
 
 @pytest.mark.parametrize("use", ["context", "decorator"])
