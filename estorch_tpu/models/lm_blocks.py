@@ -28,10 +28,12 @@ latent attention's ``kv_b`` writes them (``v=None``): the kernel reads
 both where they lie, the XLA form cuts them apart.  A model makes its own
 parts (:func:`causal_attention`
 is the plain q/k/v/o form two of them share).  With a ``window`` a query
-sees the keys ``(t - window, t]`` and no others: the XLA form skips the key
-blocks wholly outside that band as it skips those above the diagonal; the
-kernel has no band, so a CALL with a window is the XLA form wherever it is
-traced, and the model's other calls follow the rule below.
+sees the keys ``(t - window, t]`` and no others: both forms skip the key
+blocks wholly outside that band as they skip those above the diagonal.  A
+CALL with a window takes the kernel where the band spans at least one of the
+kernel's blocks and the XLA form under a narrower one
+(``pallas_attention.call_form`` has the rule), and the model's other calls
+follow the rule below.
 With ``selected`` (``[T, T]`` int8, :func:`select_keys`) a query sees the
 keys its row of the selection marks and no others: a mask made from the
 DATA, different for every member, layer and sequence, which BOTH forms
@@ -61,13 +63,14 @@ that says it: TPU devices and a member WHOLE on its chip, which is one
 device on the mesh, or several with the centre gathered and the members
 partitioned over them by hand) and opens ``pallas_attention.kernel_scope``
 around its trace of the policy there.  Inside that scope
-:func:`attention_core` takes the kernel for a call without a ``window``
-whose own shapes fit (``pallas_attention.fits``: a head's values whole
-numbers of 128-lane column blocks, and its own query/key part too, or half
-of one with values of ONE block and an even number of key heads (a pair);
-a shared part of 64 or a multiple of 128; the sequence a whole number of
-the kernel's blocks), and the XLA form for every other call and everywhere
-else, so ``apply`` outside an engine is the XLA form.  The engine says at
+:func:`attention_core` takes the kernel for a call whose own shapes fit
+(``pallas_attention.fits``: a head's values whole numbers of 128-lane
+column blocks, and its own query/key part too, or half of one with values
+of ONE block and an even number of key heads (a pair); a shared part of 64
+or a multiple of 128; the sequence a whole number of the kernel's blocks;
+and, of a call with a ``window``, a band of at least one of those blocks:
+``pallas_attention.call_form``), and the XLA form for every other call and
+everywhere else, so ``apply`` outside an engine is the XLA form.  The engine says at
 build which form that is (``ShardedESEngine.attention_form``, by
 ``ops.pallas_attention.attention_form``: the same conditions on the widths
 the model states).
@@ -368,12 +371,14 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     pairs where they lie (a pair is one of its column blocks); the XLA
     form makes that order of q and a copy of the values a map first.
 
-    Inside an engine's ``pallas_attention.kernel_scope`` a call WITHOUT a
-    ``window`` whose widths and length fit (``pallas_attention.fits``) is
-    the Pallas kernel (its own blocks, scores in VMEM, the shared part a
-    second contraction in the tile: the key part is never broadcast); a
-    call with a window (the kernel has no band) or of other shapes, and any
-    call anywhere else, is the XLA form below, in blocks of ``block``, which
+    Inside an engine's ``pallas_attention.kernel_scope`` a call whose
+    widths and length fit (``pallas_attention.fits``), and whose band, if
+    it has a ``window``, spans at least one of the kernel's blocks
+    (``pallas_attention.call_form``), is the Pallas kernel (its own blocks,
+    scores in VMEM, the shared part a second contraction in the tile: the
+    key part is never broadcast, the band a key axis as long as itself); a
+    call of other shapes or under a narrower band, and any call anywhere
+    else, is the XLA form below, in blocks of ``block``, which
     concatenates the shared parts onto q and k, the key's broadcast to
     every head (the module's text has the rule).  The XLA form's loop over
     blocks is unrolled: the program grows with ``T / block``, so a much
@@ -393,13 +398,14 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
     vd = (k.size // (t * nkv) - hd if v is None
           else v.size // (t * (nkv // 2 if paired else nkv)))
     shared = 0 if q_shared is None else k_shared.shape[-1]
-    # inside a scope, the kernel where these fit; it has no band: a call
-    # with a window is the XLA form
-    interpret = (
-        pallas_attention.scoped_interpret()
-        if window is None and pallas_attention.fits(
-            hd, shared, vd, nkv if paired else None, t)
-        else None)
+    # inside a scope, the kernel where these fit and a band, if there is
+    # one, spans a block of it
+    form = pallas_attention.call_form(
+        "kernel" if pallas_attention.fits(
+            hd, shared, vd, nkv if paired else None, t) else "xla",
+        window, t)
+    interpret = (pallas_attention.scoped_interpret() if form == "kernel"
+                 else None)
     value_heads = nkv
     if paired and interpret is None:
         # the score heads of one key head (pair, map) side by side, and a
@@ -422,7 +428,7 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
                 None if q_shared is None else q_shared.reshape(t, -1),
                 k_shared, num_heads=nq, num_kv_heads=nkv, head_dim=hd,
                 value_dim=vd, scale=scale, interpret=interpret,
-                paired=paired, selected=selected)
+                paired=paired, selected=selected, window=window)
     if q_shared is not None:
         with stage(ROPE):
             dr = k_shared.shape[-1]

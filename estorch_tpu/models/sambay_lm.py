@@ -45,8 +45,10 @@ context is ordered (key pair, map, group), where
 ``lm_blocks.differential_combine`` reads it.  Where Mosaic kernels may be
 traced (``pallas_attention.traced_why``) the two
 full-causal kinds of layer take the attention kernel, which reads a pair as
-the one 128-lane block it is (``d`` 64); the windowed kind stays in the XLA
-form (the kernel has no band).
+the one 128-lane block it is (``d`` 64); the windowed kind takes it only
+where its band spans at least one of the kernel's blocks
+(``pallas_attention.call_form``: the published 512 keys over 8,192 positions
+do not, and stay in the XLA form).
 
 Two values are carried ACROSS layers inside one member's forward: ``m [T,
 d_inner]`` float32 and ``(K, V)`` in the compute dtype, as the ``qkv``
@@ -297,8 +299,8 @@ class SambaYLM:
             # it lies
             attention_widths=(self.head_dim, 0, 2 * self.head_dim),
             # {attention layer kind held: the band of its calls of the core
-            # | None}, in layer order: the kernel has no band, so a call
-            # with a window is the XLA form inside the kernel's scope too
+            # | None}, in layer order: a call with a window takes the kernel
+            # or the XLA form by its band (pallas_attention.call_form)
             attention_windows={
                 kind: self.sliding_window if kind == WINDOW else None
                 for kind in dict.fromkeys(self.layer_types)

@@ -68,6 +68,19 @@ selection the traced kernel is the one above, operand for operand.  The
 kernel multiplies every visible pair and masks; skipping the key blocks no
 row of a query block selected is ROADMAP R13's.
 
+The visible keys may be a BAND (``window``: query ``t`` sees ``(t - window,
+t]``, a sliding-window layer).  The grid's key axis is then only as long as
+the band, the most key blocks a query block sees (five of 1,024 under a
+band of 4,096 keys, whatever the sequence), and its step ``j`` is key block
+``first visible + j``: no grid step is spent on the blocks the band has
+left behind.  The band's mask, ``cols > rows - window``, is applied in the
+tiles its edge crosses (the first of a query block from the fifth block on,
+there), beside the diagonal's in the last.  With an aligned band a query
+block's LAST rows see nothing in the first block it is handed, so a banded
+call takes the selection's guard against ``exp(-inf - -inf)``.  A branch at
+TRACE time: without a window the traced kernel is the one above, operand
+for operand.  Which calls take it is :func:`call_form`'s rule.
+
 Grid ``(heads, query blocks, key blocks)``, the key axis innermost and
 sequential; running max, sum and a float32 accumulator in VMEM scratch.
 Causal by construction: a key block beyond the query block's last row is
@@ -119,10 +132,11 @@ members on a chip) and told to the model function while the engine traces
 it (:func:`kernel_scope`): a call outside an engine's trace takes the XLA
 form.  Inside the scope a CALL of the core decides by its own shapes
 (:func:`fits`; the head and the scan by theirs, so an attention the shapes
-turn away stays in the XLA form beside a head in its kernel), and the
-kernel has no band: a call with a ``window`` takes the XLA form too
-(:func:`call_form`; ``lm_blocks.attention_core`` reads its own argument).
-The engine says at build which form the model's attention takes
+turn away stays in the XLA form beside a head in its kernel), and a call
+with a ``window`` by its band too: the kernel where the band spans at least
+one of its blocks, the XLA form under a narrower one (:func:`call_form` has
+the rule and why; ``lm_blocks.attention_core`` and the engine both read
+it).  The engine says at build which form the model's attention takes
 (:func:`attention_form`, from the widths the model states) and which each
 kind of layer took (``attention_form_by_kind``).
 """
@@ -275,9 +289,10 @@ def attention_form_why(platform: str, n_devices: int, widths, length: int,
     beside a head or a scan in its kernel.
 
     ``window``: the band of the model's windowed layers, if it has any.
-    It decides nothing here: the kernel has no band, so inside the
-    program's scope a CALL with a window takes the XLA form
-    (:func:`call_form`) and every other call the kernel; ``why`` says so."""
+    It decides nothing here: inside the program's scope a CALL with a
+    window takes the kernel or the XLA form by the band against the
+    kernel's block (:func:`call_form`) and every other call the kernel;
+    ``why`` says which."""
     head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
                            else widths)
     traced, where = traced_why(platform, n_devices, centre_form)
@@ -290,14 +305,30 @@ def attention_form_why(platform: str, n_devices: int, widths, length: int,
         "two score heads a column block"
         if _pair(head, shared, value, kv_heads) else "whole column blocks",
         "" if window is None else
-        f"; layers with a window of {window} in the XLA form")
+        f"; layers with a window of {window} in the " + (
+            "kernel" if call_form("kernel", window, length) == "kernel"
+            else "XLA form"))
 
 
-def call_form(form: str, window: int | None) -> str:
-    """The form ONE call of the core takes in a program whose form is
-    ``form``: the kernel has no band, so a call with a ``window`` is the
-    XLA form inside the kernel's scope too."""
-    return "kernel" if form == "kernel" and window is None else "xla"
+def call_form(form: str, window: int | None, length: int) -> str:
+    """The form ONE call of the core over ``length`` positions takes where
+    the kernel may be traced and the call's shapes fit it (``form`` is
+    ``"kernel"``; anything else stays what it is, the XLA form).  THE rule
+    of a call with a ``window``, said once, here
+    (``lm_blocks.attention_core`` and the engine's
+    ``attention_form_by_kind`` both read it): the kernel multiplies whole
+    tiles, so its band is as wide as the key blocks a query block's keys
+    touch, and a call takes it where the band spans at least ONE of the
+    kernel's blocks (``window >= kernel_block(length)``; a window of the
+    whole sequence or more is plain causal attention).  A narrower band
+    keeps the XLA form, whose blocks are the model's own: under a band of
+    512 keys in blocks of 1,024 the kernel would multiply two tiles a query
+    block for half a tile of visible pairs (the SambaY cell's windowed
+    layer: 0.022 s by the full layers' rate against the XLA form's 0.0117 s,
+    PERF.md §6, PR 49); at 4,096 keys it multiplies five for four (0.80 of
+    the multiplied pairs visible, where the global layer has 0.94)."""
+    spans = window is None or window >= (kernel_block(length) or length)
+    return "kernel" if form == "kernel" and spans else "xla"
 
 
 _SCOPE: contextvars.ContextVar = contextvars.ContextVar(
@@ -339,11 +370,34 @@ def _last_visible(i, block_q: int, block_k: int):
     return ((i + 1) * block_q - 1) // block_k
 
 
+def _first_visible(i, block_q: int, block_k: int, window: int | None):
+    """Index of the first key block the rows of query block ``i`` see
+    under a band of ``window`` keys: the block that holds the oldest key of
+    the block's first row, ``i · block_q - window + 1``; block 0 without a
+    band.  ``i``: a Python ``int`` or a traced grid index."""
+    if window is None:
+        return 0
+    oldest = i * block_q - window + 1
+    return (max(oldest, 0) if isinstance(i, int)
+            else jnp.maximum(oldest, 0)) // block_k
+
+
+def _band_blocks(length: int, block_q: int, block_k: int,
+                 window: int | None) -> list[int]:
+    """How many key blocks each query block sees, first visible to last:
+    the tiles the kernel computes (:func:`attention_cost`); the most of
+    them is the length of the grid's key axis."""
+    return [_last_visible(i, block_q, block_k) + 1
+            - _first_visible(i, block_q, block_k, window)
+            for i in range(length // block_q)]
+
+
 def attention_cost(length: int, num_heads: int, num_kv_heads: int,
                    head_dim: int, value_dim: int, shared_dim: int,
                    block_q: int, block_k: int, itemsize: int,
                    paired: bool = False,
-                   selected: bool = False) -> pl.CostEstimate:
+                   selected: bool = False,
+                   window: int | None = None) -> pl.CostEstimate:
     """What ONE call of the kernel does, from its grid and blocks: the
     declaration ``pallas_call`` hands XLA (the scheduler reads it, and a
     profiler's trace carries it as the custom call's ``flops`` and
@@ -362,8 +416,7 @@ def attention_cost(length: int, num_heads: int, num_kv_heads: int,
     selection the kernel computes under, once, although every head fetches
     them again).  ``vmap`` scales all three by the members in front of the
     grid."""
-    tiles = sum(_last_visible(i, block_q, block_k) + 1
-                for i in range(length // block_q))
+    tiles = sum(_band_blocks(length, block_q, block_k, window))
     value_heads = num_kv_heads // 2 if paired else num_kv_heads
     elements = length * (
         num_heads * (head_dim + shared_dim + value_dim)        # q, q_shared, out
@@ -378,21 +431,27 @@ def attention_cost(length: int, num_heads: int, num_kv_heads: int,
 
 
 def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
-                      block_k: int, selected: bool = False):
+                      block_k: int, selected: bool = False,
+                      window: int | None = None):
     # with a shared score term: each head's second query part, the one key;
     # with a selection: its tile, last of the operands
     *shared, o_ref, m_ref, l_ref, acc_ref = refs
     sel_ref = shared.pop() if selected else None
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_visible(i, block_q, block_k)
+    first_step = j == 0
+    if window is not None:
+        # the key axis is as long as the band: its steps are the key
+        # blocks from the first one the query block sees
+        j = _first_visible(i, block_q, block_k, window) + j
 
-    @pl.when(j == 0)
+    @pl.when(first_step)
     def _init():
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def fold(masked: bool):
+    def fold(masked: bool, edge: bool = False):
         """This key block into the running max, sum and accumulator."""
         v = v_ref[...]
         q, k = q_ref[...], k_ref[...]
@@ -409,17 +468,22 @@ def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
                 jnp.int32, s.shape, 0)
             cols = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= rows, s, -jnp.inf)
+            seen = cols <= rows
+            if edge:
+                seen = jnp.logical_and(seen, cols > rows - window)
+            s = jnp.where(seen, s, -jnp.inf)
         if selected:
             s = jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, -jnp.inf)
-        # every row sees key 0, which block 0 holds: after the first block
-        # the running max is finite, so exp(-inf - max) is 0, never NaN
+        # without a band or a selection every row sees key 0, which block 0
+        # holds: after the first block the running max is finite, so
+        # exp(-inf - max) is 0, never NaN
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         m_base = m_next
-        if selected:
-            # a row that has selected no key yet: max -inf, and its
-            # exponentials are taken against 0 (all of them 0)
+        if selected or window is not None:
+            # a row that has seen no key yet (it selected none so far; the
+            # band's first block holds none of a late row's keys): max
+            # -inf, and its exponentials are taken against 0 (all of them 0)
             m_base = jnp.where(m_next == -jnp.inf, 0.0, m_next)
         alpha = jnp.exp(m_prev - m_base)
         p = jnp.exp(s - m_base[:, :1])
@@ -431,6 +495,16 @@ def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
     # a tile needs the mask where its last key lies beyond its first row
     crosses = (j + 1) * block_k - 1 > i * block_q
     visible = j <= last
+    if window is not None:
+        # and the band's where its first key is too old for its last row
+        # (both masks there: the diagonal may cross the same tile)
+        edge = j * block_k <= (i + 1) * block_q - 1 - window
+
+        @pl.when(jnp.logical_and(visible, edge))
+        def _edge():
+            fold(masked=True, edge=True)
+
+        visible = jnp.logical_and(visible, jnp.logical_not(edge))
 
     @pl.when(jnp.logical_and(visible, crosses))
     def _diagonal():
@@ -447,7 +521,7 @@ def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "num_heads", "num_kv_heads", "head_dim", "value_dim", "scale", "block_q",
-    "block_k", "interpret", "paired"))
+    "block_k", "interpret", "paired", "window"))
 def causal_attention(
     q: jax.Array,  # [T, num_heads · head_dim], rotated, compute dtype
     k: jax.Array,  # [T, num_kv_heads · head_dim], rotated, compute dtype
@@ -465,6 +539,7 @@ def causal_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     paired: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """The context ``softmax(scale · s + causal mask) v`` per head, ``[T,
     num_heads · value_dim]`` in q's dtype, with grouped heads (query head
@@ -508,6 +583,14 @@ def causal_attention(
     does (``lm_blocks.select_keys`` always selects a query's own key or an
     earlier one).
 
+    ``window``: query ``t`` sees the keys ``(t - window, t]`` and no
+    others, a band beside the diagonal (no selection with it).  The key
+    axis of the grid is then as long as the band, the most key blocks a
+    query block sees (five of 1,024 under a band of 4,096), and starts at
+    the query block's first visible one; the band's mask is applied in the
+    tiles its edge crosses, as the diagonal's is.  ``window >= T`` is plain
+    causal attention and traces as a call without one.
+
     ``block_q``, ``block_k``: rows of a query and of a key block;
     :func:`kernel_block` of ``T`` where not given (the whole sequence where
     it has none, which only the interpreter runs).  On the chip the
@@ -538,11 +621,17 @@ def causal_attention(
             "values apart and no shared part; got "
             f"{num_kv_heads} key heads, v {None if beside else v.shape}, "
             f"q_shared {q_shared.shape if shared else None}")
+    if window is not None and window < 1:
+        raise ValueError(f"a band holds at least a query's own key; got "
+                         f"a window of {window}")
+    if window is not None and window >= t:
+        window = None
     choose = selected is not None
-    if choose and (beside or shared or paired or selected.shape != (t, t)):
+    if choose and (beside or shared or paired or window is not None
+                   or selected.shape != (t, t)):
         raise ValueError(
             "a selection is [T, T] over heads of one width with their "
-            f"values apart, no shared part and no pairs; got "
+            f"values apart, no shared part, no pairs and no window; got "
             f"{selected.shape} over {t} positions")
     k_width = head_dim + value_dim if beside else head_dim
     value_heads = num_kv_heads // 2 if paired else num_kv_heads
@@ -556,7 +645,11 @@ def causal_attention(
     group = num_heads // num_kv_heads
 
     def kv_row(i, j):
-        # beyond the diagonal: the block already there, so nothing moves
+        # step j of a query block's key axis: its first visible key block
+        # (block 0 without a band) and the ones after it; beyond the
+        # diagonal: the block already there, so nothing moves
+        if window is not None:
+            j = _first_visible(i, block_q, block_k, window) + j
         return jnp.minimum(j, _last_visible(i, block_q, block_k))
 
     def kv_block(h, i, j):
@@ -597,7 +690,7 @@ def causal_attention(
     cost = attention_cost(
         t, num_heads, num_kv_heads, head_dim, value_dim,
         k_shared.shape[-1] if shared else 0, block_q, block_k,
-        q.dtype.itemsize, paired, choose)
+        q.dtype.itemsize, paired, choose, window)
     if shared:
         width = k_shared.shape[-1]
         if (q_shared.shape != (t, num_heads * width)
@@ -640,7 +733,10 @@ def causal_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        grid=(num_heads, t // block_q, t // block_k),
+        # the key axis: the most key blocks a query block sees (all of them
+        # without a band: its last query block sees every one)
+        grid=(num_heads, t // block_q,
+              max(_band_blocks(t, block_q, block_k, window))),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_q, value_dim), lambda h, i, j: (i, h)),
         scratch_shapes=[
@@ -651,7 +747,7 @@ def causal_attention(
     )
     return pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, selected=choose),
+                          block_k=block_k, selected=choose, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, num_heads * value_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -566,10 +566,10 @@ class ShardedESEngine:
             self.attention_form, self.attention_form_why = (
                 self._attention_rule(widths))
             # "<kind>:<form>,…": the form the calls of each attention layer
-            # kind take in this engine's programs (the kernel has no band:
-            # a kind with a window stays in the XLA form inside its scope)
+            # kind take in this engine's programs (a kind with a window by
+            # its band against the kernel's block: call_form has the rule)
             self.attention_form_by_kind = ",".join(
-                f"{kind}:{call_form(self.attention_form, window)}"
+                f"{kind}:{call_form(self.attention_form, window, horizon)}"
                 for kind, window in self._attention_windows.items())
         # "kernel" | "xla", and what decided: the form of the policy's
         # next-token head (lm_blocks.score_next_tokens), by the head's own

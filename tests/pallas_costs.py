@@ -25,6 +25,22 @@ def pallas_calls(f, *args) -> list:
     return found
 
 
+def primitive_names(jaxpr) -> list:
+    """The primitives of ``jaxpr``'s equations in program order, those of
+    every jaxpr an equation carries (a ``cond``'s branches, a ``jit``'s
+    body) after its own."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (
+                    value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    names += primitive_names(inner)
+    return names
+
+
 def declared_costs(f, *args) -> list:
     """The ``CostEstimate`` of every ``pallas_call`` in ``f(*args)``'s
     jaxpr."""

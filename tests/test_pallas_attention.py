@@ -20,6 +20,7 @@ import moe_tiny
 from estorch_tpu.models import HybridLM, LoopedLM, MoELM, lm_blocks
 from estorch_tpu.ops import pallas_attention
 from estorch_tpu.ops.pallas_attention import (attention_form,
+                                              attention_form_why, call_form,
                                               causal_attention, kernel_block,
                                               kernel_scope, scoped_interpret)
 
@@ -104,9 +105,11 @@ def _selection(t, topk, seed=0):
     return selected
 
 
-def _plain(q, k, v, nq, nkv, scale, paired=False, selected=None):
+def _plain(q, k, v, nq, nkv, scale, paired=False, selected=None,
+           window=None):
     """Full masked softmax per head, float32 ``highest``; under a
-    ``selected [T, T]`` the keys it marks alone."""
+    ``selected [T, T]`` the keys it marks alone, under a ``window`` the
+    keys ``(t - window, t]``."""
     if paired:
         return _plain_pairs(q, k, v, nq, nkv, scale)
     t, f32, hi = q.shape[0], jnp.float32, "highest"
@@ -117,6 +120,9 @@ def _plain(q, k, v, nq, nkv, scale, paired=False, selected=None):
     seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
     if selected is not None:
         seen = seen & (selected != 0)
+    if window is not None:
+        seen = seen & (jnp.arange(t)[None, :] > jnp.arange(t)[:, None]
+                       - window)
     s = jnp.where(seen, s, -jnp.inf)
     return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), vh,
                       precision=hi).reshape(t, nq * HD)
@@ -136,14 +142,14 @@ def _through_lm_blocks(q, k, v, nq, nkv, scale, block):
 
 
 def _kernel(q, k, v, nq, nkv, scale, block_q, block_k=None, paired=False,
-            selected=None):
+            selected=None, window=None):
     """``paired``: q, k and v as ``_qkv`` makes them, READ as differential
     pairs (``v [T, nkv · HD]`` is then ``nkv/2`` value blocks of 2·HD)."""
     return causal_attention(
         q, k, v, selected=selected, num_heads=nq, num_kv_heads=nkv,
         head_dim=HD, scale=scale, value_dim=2 * HD if paired else None,
         paired=paired, block_q=block_q, block_k=block_k or block_q,
-        interpret=True)
+        interpret=True, window=window)
 
 
 # (query heads, key heads, in pairs, keys a query selects): grouped and not;
@@ -529,6 +535,187 @@ class TestKernelAgainstBothForms:
 
 # --------------------------------------------------------------- the rule
 
+# (positions, window, block_q, block_k): an aligned band of several blocks
+# (the cell's geometry: 4 blocks of band, five key blocks a query block);
+# a band that is no multiple of the block; narrower than a block; ONE key;
+# the whole sequence and more (plain causal); unequal blocks either way; a
+# band of one block and a half over blocks of two widths
+BANDS = [(64, 32, 8, 8), (64, 20, 8, 8), (64, 3, 8, 8), (64, 1, 8, 8),
+         (64, 64, 8, 8), (64, 100, 8, 8), (64, 24, 16, 8), (64, 24, 8, 16),
+         (64, 17, 16, 16), (96, 40, 32, 16)]
+
+
+def _core_in_blocks(q, k, v, nq, nkv, scale, block, window, paired=False):
+    """``lm_blocks.attention_core`` handed q, k, v as they are: the XLA
+    form outside a scope."""
+    return lm_blocks.attention_core(
+        q if paired else q.reshape(-1, nq, HD),
+        k if paired else k.reshape(-1, nkv, HD), v, num_heads=nq,
+        num_kv_heads=nkv, scale=scale, block=block, window=window,
+        paired=paired)
+
+
+class TestTheBand:
+    """``causal_attention(window=)``: a key axis as long as the band that
+    starts at a query block's first visible key block, the band's mask in
+    the tiles its edge crosses, and rows that see nothing yet."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("nq, nkv", [(4, 4), (4, 2), (6, 1)])
+    @pytest.mark.parametrize("t, window, block_q, block_k", BANDS)
+    def test_banded_kernel_is_the_banded_softmax_and_the_xla_form(
+            self, t, window, block_q, block_k, nq, nkv, dtype):
+        """Against the dense float32 softmax over ``(t - window, t]`` and
+        against the XLA form of ``attention_core(window=)``, grouped heads
+        and not; a window of the whole sequence or more is the call
+        without one, bit for bit."""
+        q, k, v = _qkv(t, nq, nkv, dtype, seed=t + window)
+        got = _kernel(q, k, v, nq, nkv, 0.3, block_q, block_k, window=window)
+        assert got.shape == (t, nq * HD) and got.dtype == q.dtype
+        assert np.isfinite(_f32(got)).all()
+        tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
+        np.testing.assert_allclose(
+            _f32(got), _f32(_plain(q, k, v, nq, nkv, 0.3, window=window)),
+            atol=tol)
+        np.testing.assert_allclose(
+            _f32(got),
+            _f32(_core_in_blocks(q, k, v, nq, nkv, 0.3, 8, window)),
+            atol=tol)
+        full = _kernel(q, k, v, nq, nkv, 0.3, block_q, block_k)
+        if window >= t:
+            np.testing.assert_array_equal(_f32(got), _f32(full))
+        else:
+            assert np.abs(_f32(got) - _f32(full)).max() > 1e-2
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("window, block_q, block_k", [
+        (16, 8, 8), (32, 8, 8), (16, 16, 8), (32, 8, 16)])
+    def test_rows_that_see_nothing_in_the_first_block_fetched(
+            self, window, block_q, block_k, dtype):
+        """An ALIGNED band: the first key block a query block is handed
+        holds the oldest key of its FIRST row, and nothing its last rows
+        see (the last row's oldest key opens the next block).  Their
+        running max is still ``-inf`` when that block has been folded, and
+        ``exp(-inf - -inf)`` would be NaN: the kernel keeps max ``-inf``,
+        sum 0 and accumulator 0 for them.  Large scores, so that a wrong
+        base of the exponentials would show (and values of magnitude 10:
+        float32 sums in another order differ by 5e-5 there)."""
+        t, nq, nkv = 64, 4, 2
+        first_of_last_block = (t - block_q - window + 1) // block_k
+        oldest_of_last_row = t - window
+        assert oldest_of_last_row >= (first_of_last_block + 1) * block_k
+        q, k, v = _qkv(t, nq, nkv, dtype, seed=5, spread=4.0)
+        got = _f32(_kernel(q, k, v, nq, nkv, 1.0, block_q, block_k,
+                           window=window))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, _f32(_plain(q, k, v, nq, nkv, 1.0, window=window)),
+            atol=2e-4 if dtype == jnp.float32 else 0.1)
+
+    @pytest.mark.parametrize("window", [5, 8, 20, 32])
+    @pytest.mark.parametrize("group", [1, 2])
+    def test_a_band_over_heads_in_pairs(self, group, window):
+        """Differential pairs under a band: only index maps differ from
+        the plain heads', so the band's are the same; against the XLA form
+        of ``attention_core(paired=True, window=)``."""
+        t, nkv = 32, 4
+        nq = nkv * group
+        q, k, v = _qkv(t, nq, nkv, jnp.float32, seed=window)
+        got = _kernel(q, k, v, nq, nkv, 0.3, 8, paired=True, window=window)
+        want = _core_in_blocks(q, k, v, nq, nkv, 0.3, 8, window, paired=True)
+        np.testing.assert_allclose(_f32(got), _f32(want), atol=F32_TOL)
+
+    @pytest.mark.parametrize("window", [3, 16, 24])
+    def test_a_band_under_a_shared_score_term(self, window):
+        t, nh = 32, 4
+        q, k, v, qs, ks = _parts(t, nh, 16, 8, 16, jnp.float32, seed=6)
+        got = causal_attention(
+            q, k, v, qs, ks, num_heads=nh, num_kv_heads=nh, head_dim=16,
+            scale=0.25, block_q=8, block_k=8, interpret=True, window=window)
+        want = lm_blocks.attention_core(
+            q, k, v, num_heads=nh, num_kv_heads=nh, scale=0.25, block=8,
+            q_shared=qs, k_shared=ks, window=window)
+        np.testing.assert_allclose(_f32(got), _f32(want), atol=F32_TOL)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_a_band_under_the_engines_nesting(self, dtype):
+        """Members in front of the grid, twice, as the engine's pair x
+        sign ``vmap``s put them."""
+        t, nq, nkv, window = 32, 4, 2, 12
+        members = [[_qkv(t, nq, nkv, dtype, seed=10 * a + b)
+                    for b in range(2)] for a in range(2)]
+        stacked = [jnp.stack([jnp.stack([m[x] for m in row])
+                              for row in members]) for x in range(3)]
+        got = jax.vmap(jax.vmap(lambda q, k, v: _kernel(
+            q, k, v, nq, nkv, 0.3, 8, window=window)))(*stacked)
+        for a in range(2):
+            for b in range(2):
+                np.testing.assert_allclose(
+                    _f32(got[a, b]), _f32(_plain(
+                        *members[a][b], nq, nkv, 0.3, window=window)),
+                    atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
+
+    @pytest.mark.parametrize("case, match", [
+        ("selection", "no window"), ("zero", "at least a query's own key")])
+    def test_a_band_is_validated(self, case, match):
+        q, k, v = _qkv(16, 4, 2, jnp.float32)
+        with pytest.raises(ValueError, match=match):
+            _kernel(q, k, v, 4, 2, 0.3, 8,
+                    selected=(jnp.ones((16, 16), jnp.int8)
+                              if case == "selection" else None),
+                    window=4 if case == "selection" else 0)
+
+    def test_the_key_axis_is_as_long_as_the_band(self):
+        """The grid of a banded call: the most key blocks a query block
+        sees, not the sequence's; without a window the sequence's."""
+        from pallas_costs import pallas_calls
+
+        q, k, v = _qkv(64, 4, 2, jnp.float32)
+
+        def grid(window, block_q=8, block_k=8):
+            call, = pallas_calls(lambda q, k, v: _kernel(
+                q, k, v, 4, 2, 0.3, block_q, block_k, window=window), q, k, v)
+            return call.params["grid_mapping"].grid
+
+        assert grid(None) == (4, 8, 8)
+        assert grid(32) == (4, 8, 5)      # the cell's: a band of 4 blocks
+        assert grid(20) == (4, 8, 4)
+        assert grid(3) == grid(8) == (4, 8, 2)
+        assert grid(1) == (4, 8, 1)       # a query's own key: the diagonal
+        assert grid(64) == grid(1000) == (4, 8, 8)
+        assert grid(24, 16, 8) == (4, 4, 5)
+        assert grid(24, 8, 16) == (4, 8, 3)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_the_core_takes_the_kernel_where_the_band_spans_a_block(
+            self, dtype):
+        """384 positions are three of the kernel's blocks of 128: inside a
+        scope a call under a band of 200 keys is ONE ``pallas_call`` and
+        gives what the XLA form gives; under 100 keys it traces the XLA
+        form's equations; a band of the whole sequence traces the call
+        without one."""
+        t, nq, nkv = 384, 4, 2
+        q, k, v = _qkv(t, nq, nkv, dtype, seed=9)
+
+        def traced(window, scoped):
+            def core(q, k, v):   # a new closure a trace: jit caches by it
+                return _core_in_blocks(q, k, v, nq, nkv, 0.3, 64, window)
+
+            if not scoped:
+                return str(jax.make_jaxpr(core)(q, k, v)), core(q, k, v)
+            with kernel_scope(interpret=True):
+                return str(jax.make_jaxpr(core)(q, k, v)), core(q, k, v)
+
+        (inside, got), (outside, want) = traced(200, True), traced(200, False)
+        assert (inside.count("pallas_call["),
+                outside.count("pallas_call[")) == (1, 0)
+        np.testing.assert_allclose(
+            _f32(got), _f32(want),
+            atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
+        assert traced(100, True)[0] == traced(100, False)[0]
+        assert traced(384, True)[0] == traced(None, True)[0]
+
+
 class TestTheCallersScale:
     """A model whose scores are cosines under a learned temperature
     (models/cca_moe_lm.py) hands the core L2-scaled q and k, the
@@ -635,6 +822,61 @@ class TestTheRule:
         (128, 128), (4000, None), (64, None), (21, None)])
     def test_the_kernels_block(self, length, block):
         assert kernel_block(length) == block
+
+    @pytest.mark.parametrize("form, window, length, took", [
+        # smallthinker-es-16k-1chip: a band of four of the kernel's blocks
+        ("kernel", 4096, 16384, "kernel"), ("kernel", None, 16384, "kernel"),
+        ("xla", 4096, 16384, "xla"), ("xla", None, 16384, "xla"),
+        # phi4-flash-es-8k-1chip: half a block of band
+        ("kernel", 512, 8192, "xla"), ("kernel", None, 8192, "kernel"),
+        # at one block it turns; by the block of THIS length
+        ("kernel", 1024, 8192, "kernel"), ("kernel", 1023, 8192, "xla"),
+        ("kernel", 128, 384, "kernel"), ("kernel", 127, 384, "xla"),
+        ("kernel", 512, 1536, "kernel"), ("kernel", 511, 1536, "xla"),
+        # the whole sequence or more is plain causal attention
+        ("kernel", 16384, 16384, "kernel"), ("kernel", 10 ** 6, 4096,
+                                             "kernel"),
+        # a length the kernel has no block for (the interpreter's): the
+        # sequence is the block
+        ("kernel", 6, 32, "xla"), ("kernel", 32, 32, "kernel"),
+    ])
+    def test_a_call_with_a_window_by_its_band_against_the_block(
+            self, form, window, length, took):
+        """THE rule of a banded call: the kernel where it may be traced,
+        the shapes fit and the band spans at least one of its blocks."""
+        assert call_form(form, window, length) == took
+
+    @pytest.mark.parametrize(
+        "platform, widths, kv_heads, length, windows, by_kind, why", [
+            # smallthinker-es-16k-1chip
+            ("tpu", 128, 4, 16384, {"window": 4096, "global": None},
+             "window:kernel,global:kernel",
+             "layers with a window of 4096 in the kernel"),
+            ("cpu", 128, 4, 16384, {"window": 4096, "global": None},
+             "window:xla,global:xla", "the devices are 'cpu', not TPUs"),
+            # phi4-flash-es-8k-1chip, as it was
+            ("tpu", (64, 0, 128), 20, 8192,
+             {"window": 512, "full_kv": None, "cross": None},
+             "window:xla,full_kv:kernel,cross:kernel",
+             "layers with a window of 512 in the XLA form"),
+            ("cpu", (64, 0, 128), 20, 8192,
+             {"window": 512, "full_kv": None, "cross": None},
+             "window:xla,full_kv:xla,cross:xla",
+             "the devices are 'cpu', not TPUs"),
+        ])
+    def test_each_kind_of_layer_at_the_two_banded_cells_shapes(
+            self, platform, widths, kv_heads, length, windows, by_kind, why):
+        """What the engine's ``attention_form_by_kind`` is made of
+        (``_resolve_kernel_forms``: the program's form, then
+        ``call_form`` a kind), at the published shapes of the two cells
+        whose models have a banded layer, on one TPU device and on a CPU
+        mesh."""
+        band = next(w for w in windows.values() if w is not None)
+        form, reason = attention_form_why(platform, 1, widths, length, band,
+                                          kv_heads)
+        assert reason.endswith(why)
+        assert ",".join(f"{kind}:{call_form(form, window, length)}"
+                        for kind, window in windows.items()) == by_kind
 
     def test_published_shapes_are_what_the_rows_say(self):
         ouro, granite = loop_tiny.published(), lm_tiny.published()
@@ -789,6 +1031,104 @@ class TestTheDeclaredCost:
         assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
             flops, exps, elements * itemsize
             + (tiles * block_q * block_k if chosen else 0))
+
+    @pytest.mark.parametrize("length, block_q, block_k, window", [
+        (64, 16, 16, 32), (64, 16, 16, 20), (64, 16, 16, 3), (64, 16, 16, 1),
+        (64, 32, 16, 24), (64, 16, 32, 24), (96, 32, 32, 33),
+        (64, 16, 16, 64), (64, 16, 16, None)])
+    def test_a_bands_tiles_counted_tile_by_tile(self, length, block_q,
+                                                block_k, window):
+        """A tile is computed where it holds a key some row of the query
+        block sees: its first key no later than the block's last row, its
+        last key no older than the first row's oldest."""
+        heads, kv_heads, hd, vd, itemsize = 4, 2, 8, 16, 2
+        tiles = sum(
+            j * block_k <= (i + 1) * block_q - 1
+            and (window is None
+                 or (j + 1) * block_k - 1 >= i * block_q - window + 1)
+            for i in range(length // block_q)
+            for j in range(length // block_k))
+        cost = pallas_attention.attention_cost(
+            length, heads, kv_heads, hd, vd, 0, block_q, block_k, itemsize,
+            window=window)
+        plain = pallas_attention.attention_cost(
+            length, heads, kv_heads, hd, vd, 0, block_q, block_k, itemsize)
+        assert cost.flops == heads * tiles * 2 * block_q * block_k * (hd + vd)
+        assert cost.transcendentals == heads * tiles * block_q * (block_k + 1)
+        # the bytes are the call's operands, whatever the band
+        assert cost.bytes_accessed == plain.bytes_accessed
+        assert cost.flops <= plain.flops
+
+    def test_seventy_tiles_of_136_under_the_cells_band(self):
+        """smallthinker-es-16k-1chip: 16 blocks of 1,024, a band of four:
+        five key blocks a query block from the fifth on, 70 tiles a head
+        where full causal has 136; 73.4 M pairs multiplied a head for the
+        58.7 M the band holds (``benchmark/costs_swa.visible_pairs``)."""
+        def tiles(cost):
+            return cost.flops / (28 * 2 * 1024 * 1024 * 256)
+
+        banded = pallas_attention.attention_cost(
+            16384, 28, 4, 128, 128, 0, 1024, 1024, 2, window=4096)
+        full = pallas_attention.attention_cost(
+            16384, 28, 4, 128, 128, 0, 1024, 1024, 2)
+        assert (tiles(banded), tiles(full)) == (70, 136)
+        visible = sum(min(t + 1, 4096) for t in range(16384))
+        assert visible == 58_722_304
+        assert visible / (70 * 1024 * 1024) == pytest.approx(0.80, abs=5e-3)
+        # what the call without a window declared at PR 48
+        assert (full.flops, full.transcendentals, full.bytes_accessed) == (
+            2044404432896, 3996876800, 268435456)
+
+    def test_a_banded_call_declares_its_tiles(self):
+        from pallas_costs import declared_costs
+
+        q, k, v = _qkv(64, 4, 2, jnp.float32)
+
+        def call(q, k, v):
+            return _kernel(q, k, v, 4, 2, 0.3, 8, window=32)
+
+        one, = declared_costs(call, q, k, v)
+        assert one == pallas_attention.attention_cost(
+            64, 4, 2, HD, HD, 0, 8, 8, 4, window=32)
+        # 8 blocks, a band of 4: 1 + 2 + 3 + 4 + 4 x 5 = 30 tiles of 36
+        assert one.flops == 4 * 30 * 2 * 8 * 8 * (HD + HD)
+
+    def test_a_call_without_a_window_traces_the_kernel_it_traced(self):
+        """The band is a branch at TRACE time: without a window (and under
+        one of the whole sequence) the ``pallas_call`` has the operands,
+        the grid, the index maps, the body and the declared cost it had
+        before the kernel learned a band (the literals are PR 48's tree's,
+        for these operands); a banded call's body differs."""
+        import hashlib
+
+        from pallas_costs import pallas_calls, primitive_names
+
+        q, k, v = jnp.zeros((32, 32)), jnp.zeros((32, 16)), jnp.zeros((32, 16))
+
+        def facts(window):
+            call, = pallas_calls(lambda q, k, v: causal_attention(
+                q, k, v, num_heads=4, num_kv_heads=2, head_dim=8, scale=0.25,
+                block_q=16, block_k=8, interpret=True, window=window),
+                q, k, v)
+            mapping, cost = call.params["grid_mapping"], call.params[
+                "cost_estimate"]
+            body = primitive_names(call.params["jaxpr"])
+            return ([str(x.aval) for x in call.invars], mapping.grid,
+                    [len(primitive_names(m.index_map_jaxpr.jaxpr))
+                     for m in mapping.block_mappings],
+                    len(body),
+                    hashlib.sha256(",".join(body).encode()).hexdigest()[:16],
+                    (cost.flops, cost.transcendentals, cost.bytes_accessed))
+
+        parents = (["float32[32,32]", "float32[32,16]", "float32[32,16]"],
+                   (4, 2, 4), [0, 28, 28, 0], 112, "ec3f9130b925a7d5",
+                   (98304, 3456, 12288))
+        assert facts(None) == parents
+        assert facts(32) == facts(40) == parents
+        banded = facts(12)
+        assert banded[:2] == (parents[0], (4, 2, 4))
+        # a third fold, for the tiles the band's edge crosses
+        assert banded[3] > parents[3] and banded[4] != parents[4]
 
     def test_ten_tiles_of_sixteen_over_the_exact_triangle(self):
         # the cells' geometry: 4,096 positions in blocks of 1,024

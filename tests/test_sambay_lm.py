@@ -326,9 +326,11 @@ def test_no_window_is_the_program_it_was():
 
 
 def test_a_call_with_a_window_is_the_xla_form_inside_a_kernel_scope():
-    """The kernel has no band: inside an engine's scope the call with a
-    ``window`` traces to the XLA form's equations (and so gives its
-    result), the call without one to the kernel."""
+    """A band narrower than the kernel's block (4 keys of 16 positions;
+    the published 512 of 8,192): inside an engine's scope the call with
+    that ``window`` traces to the XLA form's equations (and so gives its
+    result), the call without one to the kernel
+    (``pallas_attention.call_form``)."""
     q, k, v = _core_case(16)
 
     def traced(window, scoped):
@@ -382,9 +384,9 @@ def test_the_attention_rule_takes_pairs_and_leaves_the_band_to_the_call(
     assert "4 devices" in attention_form_why("tpu", 4, widths, length,
                                              window, kv_heads)[1]
     # which form a CALL takes in a program of that form
-    assert call_form(form, window) == (
+    assert call_form(form, window, length) == (
         "kernel" if form == "kernel" and window is None else "xla")
-    assert call_form(form, None) == form
+    assert call_form(form, None, length) == form
 
 
 # ------------------------------------------- (d) differential attention

@@ -1213,16 +1213,19 @@ def test_kernel_form_books_the_latents_attention_and_the_tied_head(v5e_chip):
                for line in text.splitlines())
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["global", "band4096"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_attention_kernel_compiles_for_the_v5e_in_groups_of_seven(
-        dtype, v5e_chip):
+        dtype, window, v5e_chip):
     """Mosaic accepts the attention kernel at ``smallthinker-es-16k-1chip``'s
     shapes, a grouping no other cell has: 28 query heads over 4 key-value
     heads of 128 (groups of SEVEN: q ``[16384, 3584]``, k and v ``[16384,
     512]``), 16,384 positions in blocks of 1,024, one member at a time as the
     cell's chunk of one pair with its signs in turn evaluates them; nothing
-    else in the program."""
+    else in the program.  The global layer's call, and the window layers'
+    under their band of 4,096 keys (a key axis of five steps, three folds
+    in the body)."""
     from jax.sharding import SingleDeviceSharding
 
     from estorch_tpu.ops.pallas_attention import causal_attention
@@ -1234,21 +1237,28 @@ def test_attention_kernel_compiles_for_the_v5e_in_groups_of_seven(
 
     text = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
         q, k, v, num_heads=28, num_kv_heads=4, head_dim=128,
-        scale=128 ** -0.5, interpret=False)))).lower(
+        scale=128 ** -0.5, interpret=False, window=window)))).lower(
             operand(3584), operand(512), operand(512)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert " copy(" not in text.split("ENTRY")[1]
 
 
-def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip):
+@pytest.mark.parametrize("window, by_kind, parts", [
+    (512, "window:kernel,global:kernel", ["global", "window", "window"]),
+    (128, "window:xla,global:kernel", ["global"])])
+def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
+                                                          by_kind, parts):
     """A small decoder with a global and two window layers on a one-device
     TPU mesh: the rule takes its 14 / 2 heads of 128 (groups of seven) over
-    512 positions; the compiled generation program holds ONE Mosaic call
-    ``causal_attention``, the global layer's, under es.attn inside es.policy
-    in the part ``of.global``, and none in ``of.window``, whose band of 128
-    stays in the XLA form (the kernel has no band): the device trace books
-    them to ``swa.global_attn_share`` and ``swa.window_attn_share``.  The
-    head takes its kernel beside them."""
+    1,536 positions, three of the kernel's blocks of 512.  Under a band of
+    512 keys (one block: the band's rule, ``pallas_attention.call_form``)
+    the compiled generation program holds THREE Mosaic calls
+    ``causal_attention`` under es.attn inside es.policy, the global
+    layer's in the part ``of.global`` and the window layers' in
+    ``of.window``; under 128 keys only the global layer's, and the window
+    layers' float32 scores are XLA's: the device trace books them to
+    ``swa.global_attn_share`` and ``swa.window_attn_share`` either way.
+    The head takes its kernel beside them."""
     from estorch_tpu.envs import TokenScoreEnv
     from estorch_tpu.models import WindowMoELM
     from estorch_tpu.parallel.mesh import hyperscale_mesh
@@ -1258,13 +1268,14 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip):
         policy=WindowMoELM, population_size=4, sigma=0.02,
         policy_kwargs=dict(
             layer_types=("global", "window", "window"), vocab_size=256,
-            hidden_size=128, moe_ffn_hidden_size=64, sliding_window_size=128,
+            hidden_size=128, moe_ffn_hidden_size=64,
+            sliding_window_size=window,
             num_attention_heads=14, num_key_value_heads=2, head_dim=128,
             moe_num_primary_experts=2, expert_group_size=2,
             moe_num_active_primary_experts=2, behaviour_positions=64,
             attention_block=128, head_block=128),
         agent_kwargs={"env": TokenScoreEnv(
-            vocab_size=256, seq_len=512, corpus_sequences=4)},
+            vocab_size=256, seq_len=1536, corpus_sequences=4)},
         shard_params=True, low_rank=1, noise_mode="table",
         compute_dtype="bfloat16", table_size=1 << 18,
         device=jax.devices()[:1])
@@ -1278,9 +1289,10 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip):
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
         policy=declaration_of(es.module))
     assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
-    assert engine.attention_form_by_kind == "window:xla,global:kernel"
+    assert engine.attention_form_by_kind == by_kind
     assert engine.attention_form_why.endswith(
-        "layers with a window of 128 in the XLA form")
+        f"layers with a window of {window} in the "
+        + ("kernel" if window == 512 else "XLA form"))
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -1294,17 +1306,19 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip):
                 for name in re.findall(r'op_name="([^"]*)"', line)]
 
     kernels = calls("causal_attention")
-    assert len(kernels) == 1, kernels
-    assert SCOPE.findall(kernels[0])[0] == POLICY, kernels
-    assert SCOPE.findall(kernels[0])[-1] == ATTN, kernels
-    assert PART.findall(kernels[0]) == ["global"], kernels
+    assert sorted(PART.findall(name)[0] for name in kernels) == parts, kernels
+    for name in kernels:
+        assert SCOPE.findall(name)[0] == POLICY, kernels
+        assert SCOPE.findall(name)[-1] == ATTN, kernels
     heads = calls("next_token_scores")
     assert len(heads) == 1 and PART.findall(heads[0]) == ["head"], heads
-    # the window layers' scores are XLA's, under es.attn in their own part
+    # where the window layers stay in the XLA form their scores are XLA's,
+    # under es.attn in their own part; in the kernel no float32 [.., T]
+    # score array of theirs is left
     banded = [n for line in text.splitlines() if "f32[" in line
               for n in re.findall(r'op_name="([^"]*)"', line)
-              if PART.findall(n) == ["window"]]
-    assert banded and all(ATTN in SCOPE.findall(n) for n in banded)
+              if PART.findall(n) == ["window"] and ATTN in SCOPE.findall(n)]
+    assert bool(banded) == (window == 128)
 
 
 @pytest.mark.parametrize("use", ["context", "decorator"])

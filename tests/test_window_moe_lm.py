@@ -18,6 +18,7 @@ import pytest
 from jax.flatten_util import ravel_pytree
 
 import window_moe_tiny as tiny_model
+from pallas_costs import pallas_calls
 from estorch_tpu.models import (CCAMoELM, HybridLM, IndexedMoELM, LoopedLM,
                                 MoELM, SambaYLM, WindowMoELM, lm_blocks)
 from estorch_tpu.ops import pallas_attention
@@ -175,7 +176,8 @@ def test_both_forms_and_both_dtypes_match_the_reference(ref, tiny, form,
     forward reads: routers float32): the reference's scores and behaviour
     to the dtype's rounding.  32 positions in blocks of 8; inside the scope
     the ONE global layer takes the kernel and the two window layers stay in
-    the XLA form (the kernel has no band)."""
+    the XLA form (a band of 6 keys spans no block of the kernel's:
+    ``pallas_attention.call_form``)."""
     lm, tokens, c = tiny["lm"], _tokens(32, 7), 0.05
     keep = set(lm.float32_leaves)
     paths = ["/".join(str(k.key) for k in p) for p, _ in
@@ -205,6 +207,31 @@ def test_both_forms_and_both_dtypes_match_the_reference(ref, tiny, form,
         else:
             assert float(jnp.mean(jnp.abs(g - w))) < tol
             assert float(jnp.std(w)) > 0.3
+
+
+@pytest.mark.parametrize("window, kernels", [(200, 3), (128, 3), (100, 1)])
+def test_a_band_of_a_kernel_block_takes_the_kernel_and_matches_the_reference(
+        ref, window, kernels, tiny_widths):
+    """384 positions are three of the kernel's blocks of 128: inside a scope
+    the two window layers take the kernel beside the global one where their
+    band spans a block (200 keys: no multiple of it; 128: exactly one) and
+    stay in the XLA form under 100 keys; either way a perturbed member's
+    scores and behaviour are the float32 reference's."""
+    built = _built(ref, sliding_window_size=window, attention_block=64)
+    lm, tokens, c = built["lm"], _tokens(384, 11), 0.05
+    want = ref.forward(built["s"], ref.Member(
+        built["s"], built["theta"], built["noise"], c), tokens, head_block=8)
+    factors = built["spec"].unpack(built["noise"])
+
+    def forward(p, f):
+        return lm.perturbed_apply(p, f, c, tokens)
+
+    with kernel_scope(interpret=True):
+        calls = pallas_calls(forward, built["params"], factors)
+        got = forward(built["params"], factors)
+    assert len(calls) == kernels
+    for g, w in zip(got[:2], want):
+        np.testing.assert_allclose(g, w, atol=TOL, rtol=0)
 
 
 def test_apply_is_the_centre_alone(tiny):
@@ -755,16 +782,17 @@ def test_published_sizes_and_layouts(ref):
     assert stated.leaf_rows_per_token == dict.fromkeys(
         lm.stacked_leaves, 6 * 1.25 / 4)
     # 28 query heads over 4 key heads of 128 at 16,384: whole column
-    # blocks, so the global layer takes the kernel on one chip and the
-    # window layers, which the kernel has no band for, the XLA form
+    # blocks, so the global layer takes the kernel on one chip, and the
+    # window layers too: their band is four of its blocks of 1,024
     form, why = attention_form_why("tpu", 1, stated.attention_widths,
                                    cfg["horizon"], 4096,
                                    stated.attention_kv_heads)
     assert form == "kernel" and why.endswith(
-        "layers with a window of 4096 in the XLA form")
+        "layers with a window of 4096 in the kernel")
     assert pallas_attention.fits(128, 0, 128, None, 16384)
-    assert [call_form(form, w) for w in stated.attention_windows.values()
-            ] == ["xla", "kernel"]
+    assert [call_form(form, w, cfg["horizon"])
+            for w in stated.attention_windows.values()] == ["kernel",
+                                                            "kernel"]
     shapes = lm.param_shapes()
     paths = ["/".join(str(k.key) for k in p) for p, _ in
              jax.tree_util.tree_flatten_with_path(shapes)[0]]
@@ -941,17 +969,27 @@ class TestThroughTheShardedEngine:
 
     @pytest.mark.parametrize("dtype, tol", [("float32", 1e-4),
                                             ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("positions, window, by_kind, kernels", [
+        (32, 6, "window:xla,global:kernel", 1),
+        (384, 200, "window:kernel,global:kernel", 3)])
     def test_forced_kernel_runs_the_generation_the_xla_form_runs(
-            self, devices8, kernel_attention, dtype, tol):
+            self, devices8, kernel_attention, positions, window, by_kind,
+            kernels, dtype, tol):
         """The generation program on one device, the engine's scope open
-        around its trace: the ONE global layer takes the kernel, the two
-        window layers stay in the XLA form (the gauge and the manifest say
-        which kind took which), and the members' fitness is the XLA form's
-        to the order of float32 sums."""
+        around its trace: the ONE global layer takes the kernel; the two
+        window layers stay in the XLA form under a band of 6 keys over 32
+        positions and take the kernel under 200 keys over 384 (three of
+        its blocks of 128: the band spans one); the gauge and the manifest
+        say which kind took which, and the members' fitness is the XLA
+        form's to the order of float32 sums (in bfloat16 over 384
+        positions the rounding moves a route in a thousand of the layers
+        above)."""
         from estorch_tpu.envs import TokenScoreEnv
 
-        wide = {**TINY, "attention_block": 16}
-        env = {"env": TokenScoreEnv(**{**tiny_model.ENV, "seq_len": 32})}
+        wide = {**TINY, "attention_block": 16,
+                "sliding_window_size": window}
+        env = {"env": TokenScoreEnv(**{**tiny_model.ENV,
+                                       "seq_len": positions})}
         ref_es = _es(devices8[:1], 1, compute_dtype=dtype,
                      policy_kwargs=wide, agent_kwargs=env)
         with kernel_attention():
@@ -959,18 +997,21 @@ class TestThroughTheShardedEngine:
                        policy_kwargs=wide, agent_kwargs=env)
         assert (ref_es.engine.attention_form,
                 kern.engine.attention_form) == ("xla", "kernel")
-        assert kern.engine.attention_form_by_kind == (
-            "window:xla,global:kernel")
+        assert ref_es.engine.attention_form_by_kind == (
+            "window:xla,global:xla")
+        assert kern.engine.attention_form_by_kind == by_kind
         assert kern.run_manifest()["config"][
-            "attention_form_by_kind"] == "window:xla,global:kernel"
-        programs = [str(jax.make_jaxpr(es.engine._generation_step)(
-            es.state, es.table.data)) for es in (ref_es, kern)]
-        assert [text.count("pallas_call[") for text in programs] == [0, 1]
+            "attention_form_by_kind"] == by_kind
+        assert [len(pallas_calls(es.engine._generation_step, es.state,
+                                 es.table.data))
+                for es in (ref_es, kern)] == [0, kernels]
         ref_es.state, want = ref_es.engine.generation_step(ref_es.state)
         kern.state, got = kern.engine.generation_step(kern.state)
         np.testing.assert_allclose(got["fitness"], want["fitness"], atol=tol)
-        np.testing.assert_array_equal(got["expert_load"],
-                                      want["expert_load"])
+        moved = np.abs(np.asarray(got["expert_load"])
+                       - np.asarray(want["expert_load"])).sum()
+        assert moved <= (0 if (dtype, positions) != ("bfloat16", 384)
+                         else 0.005 * np.asarray(want["expert_load"]).sum())
         assert np.isfinite(np.asarray(got["fitness"])).all()
 
 
