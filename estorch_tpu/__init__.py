@@ -46,3 +46,7 @@ __all__ = [
     "utils",
     "__version__",
 ]
+
+# the last line of the import: "before the program" ends here on the
+# process's set-up timeline (obs/spans.py)
+obs.spans.TIMELINE.mark_imported()

@@ -156,7 +156,6 @@ class IW_ES(ES):
         obs = self.obs
         obs.discard_phases()  # drop partial spans from an aborted generation
         if self.compile_time_s is None:
-            obs.note("compile")
             self.compile_time_s = self.engine.compile_split(self.state)
             self.compile_time_s += self._warm_reuse_programs()
         n = self.population_size

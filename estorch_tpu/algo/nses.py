@@ -150,7 +150,6 @@ class NS_ES(ES):
         if self.compile_time_s is None:
             # AOT-compile the split-path programs outside the timed loop,
             # same invariant as ES.train for the primary metric
-            obs.note("compile")
             self.compile_time_s = self.engine.compile_split(self.meta_states[0])
         for _ in range(n_steps):
             t0 = time.perf_counter()

@@ -458,7 +458,6 @@ class GenerationScheduler:
     def _ensure_compiled(self) -> None:
         es = self.es
         if es.compile_time_s is None:
-            self.obs.note("compile")
             es.compile_time_s = self.engine.compile(es.state)
 
     def _make_source(self, events: "queue.Queue"):
@@ -1217,7 +1216,6 @@ class ElasticScheduler(GenerationScheduler):
         es = self.es
         if es.compile_time_s is not None:
             return
-        self.obs.note("compile")
         es.compile_time_s = self.engine.compile_split(es.state)
         # warm the single-source-group reuse shape (the host-granular
         # common case: one whole stale population per update) outside
@@ -1418,7 +1416,6 @@ def train_overlap(es, n_steps: int, log_fn=None, verbose: bool = True,
     obs = es.obs
     obs.discard_phases()
     if es.compile_time_s is None:
-        obs.note("compile")
         es.compile_time_s = es.engine.compile(es.state)
     ex = cf.ThreadPoolExecutor(max_workers=1,
                                thread_name_prefix="estorch-overlap")

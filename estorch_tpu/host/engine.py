@@ -78,7 +78,8 @@ class HostEngine:
     Appendix A).
     """
 
-    # span telemetry hub; ES replaces this with its own (obs/spans.py).
+    # span telemetry hub; ES hands over its own at construction
+    # (obs/spans.py).
     # Class-level null default so instrumented paths never branch on None.
     telemetry = NULL_TELEMETRY
 
@@ -101,9 +102,12 @@ class HostEngine:
         sigma_decay: float = 1.0,
         sigma_min: float = 0.0,
         mirrored: bool = True,
+        telemetry=None,
     ):
         import torch
 
+        if telemetry is not None:
+            self.telemetry = telemetry
         self.torch = torch
         self.mirrored = bool(mirrored)
         if mirrored and population_size % 2 != 0:
@@ -243,17 +247,22 @@ class HostEngine:
             )
 
     def init_state(self, params_flat=None, key: int | None = None) -> HostState:
-        flat = self._flat() if params_flat is None else np.asarray(params_flat, np.float32)
-        return HostState(
-            params_flat=flat,
-            opt_state=None,
-            key=self.seed if key is None else int(key),
-            generation=0,
-            sigma=self.sigma,
-        )
+        with self.telemetry.phase("setup/init_state"):
+            flat = (self._flat() if params_flat is None
+                    else np.asarray(params_flat, np.float32))
+            return HostState(
+                params_flat=flat,
+                opt_state=None,
+                key=self.seed if key is None else int(key),
+                generation=0,
+                sigma=self.sigma,
+            )
 
     def compile(self, state: HostState) -> float:
-        return 0.0  # nothing to compile on the host path
+        # nothing to compile on the host path; the span's entry is the
+        # heartbeat's last-known phase, as on the other engines
+        with self.telemetry.phase("setup/compile"):
+            return 0.0
 
     compile_split = compile
 

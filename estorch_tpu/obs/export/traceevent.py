@@ -30,6 +30,12 @@ at the carrying generation's start — compile seconds and XLA cost facts
 in the args, so "why is this generation wide" and "what did that
 program cost to build" are answered on the same timeline.
 
+The run's first record carries the process's start-up (``"setup"``,
+obs/spans.py): its set-up spans render on the ``phases`` lane at their
+REAL offsets from the process's start, with the first generation laid
+where it really ended, and the acquisition summary as one marker on the
+``compiles`` lane.
+
 Async runs get a causal ``async`` lane (docs/observability.md "Tails &
 traces"): each record's ``async`` block names the dispatches it
 snapshotted and the ``[dispatch, members]`` pairs it folded or
@@ -170,6 +176,32 @@ def export_trace(records: list[dict],
             })
         gen = rec.get("generation", i)
         wall = max(0.0, float(rec.get("wall_time_s", 0.0) or 0.0))
+        setup = rec.get("setup")
+        if isinstance(setup, dict) and isinstance(setup.get("spans"), list):
+            # the process's start-up (obs/spans.py): real offsets from the
+            # process's start, so the run's first generation is laid where
+            # it really ended and the set-up spans before it
+            done = float(setup.get("first_generation_done_s") or 0.0)
+            if i == 0:
+                cursor = max(0.0, done - wall)
+            base = cursor + wall - done
+            for span in setup["spans"]:
+                if not isinstance(span, dict) or "name" not in span:
+                    continue
+                a = float(span.get("start_s", 0.0))
+                b = float(span.get("end_s", a))
+                trace_events.append({
+                    "ph": "X", "name": str(span["name"]), "cat": "setup",
+                    "ts": _us(max(0.0, base + a)),
+                    "dur": _us(max(0.0, b - a)), "pid": pid, "tid": 2,
+                })
+            acquired = setup.get("acquisitions")
+            if isinstance(acquired, dict) and acquired:
+                trace_events.append({
+                    "ph": "i", "s": "t", "name": "acquisitions in set-up",
+                    "cat": "compile", "ts": _us(cursor), "pid": pid,
+                    "tid": 3, "args": acquired,
+                })
         trace_events.append({
             "ph": "X", "name": f"gen {gen}", "cat": "generation",
             "ts": _us(cursor), "dur": _us(wall), "pid": pid, "tid": 1,

@@ -24,13 +24,32 @@ its own materialization (``np.asarray`` of an output) or passes
 ``fence=`` — a callable run before the clock stops, typically
 ``jax.block_until_ready`` on the program's outputs.
 
+Set-up spans: a span whose name starts ``setup/`` (``SETUP_SPANS``) is
+the process's and not one generation's.  It is kept WHOLE (name, start and
+end on ``time.perf_counter``, thread, parent) in the one bounded
+:data:`TIMELINE` of the process, beside the process's own start and the
+moment ``estorch_tpu`` finished importing, and stays out of the
+per-generation accumulator: ``take_phases`` / ``discard_phases`` neither
+drop nor double it.  The first generation's record carries the timeline
+under ``"setup"`` (:func:`setup_summary`); the benchmark's ``boot.*``
+metrics read it (docs/observability.md "Set-up spans").  A set-up span
+never fences: where the host did not wait, it does not wait under a span.
+
 Overhead budget: a disabled Telemetry's ``phase()`` yields a cached
 no-op context manager (two attribute loads); an enabled one costs two
 ``perf_counter`` calls, a trace annotation + dict update per span.  Heartbeat/file work only
 happens when a heartbeat path is configured (supervisors opt in via the
 ``ESTORCH_OBS_HEARTBEAT`` env var).  The budget for default-on spans is
-<2% of generation wall time (``bench.py --obs-ab`` is the gate; not
-measured on the chip yet).
+<2% of generation wall time (``bench.py --obs-ab`` is the gate).  On the
+chip (v5e, ``synth376-train-1chip``, a generation of 0.198 s, two pairs a
+seed a pair, the default ``Telemetry`` against ``ESTORCH_OBS=0``; chip
+runs, PR 50): ``steps_per_s_per_chip`` 4,072,953 against 4,072,722
+(+0.006%) and 4,070,604 against 4,073,563 (-0.073%), ``setup_s`` 16.69
+against 16.69 s and 16.58 against 16.95 s: inside the runs' own spread,
+a fortieth of the budget.  One set-up span costs 6.8 us on that host, a
+generation phase 6.1 us, a listener callback that keeps its event 1.0 to
+1.4 us; a process leaves 13 to 22 set-up spans and 2,400 to 6,300 kept
+events before its first measured generation: under 10 ms.
 """
 
 from __future__ import annotations
@@ -51,6 +70,150 @@ OBS_DISABLE_ENV = "ESTORCH_OBS"  # "0" disables default-on telemetry
 # shared stateless no-op context manager: the disabled path costs one
 # attribute check + one return, no generator construction per span
 _NULL_CM = contextlib.nullcontext()
+
+# ------------------------------------------------------------ set-up spans
+#
+# the vocabulary of the program's start-up (docs/observability.md "Set-up
+# spans").  ``Telemetry.phase`` is how each is opened: a name that starts
+# ``setup/`` nests under an open set-up span by its leaf (an engine's
+# ``init_state`` asks for ``setup/init_state`` and is
+# ``setup/init/init_state`` inside ``ES.__init__``) and stands at the top
+# anywhere else.
+SETUP_PREFIX = "setup/"
+SETUP_SPANS = (
+    "setup/init",                # all of ES.__init__
+    "setup/init/module_init",    # flax init from the real observation
+    "setup/init/param_spec",     # ravel and spec
+    "setup/init/noise_table",
+    "setup/init/mesh",
+    "setup/init/engine_build",   # the engine's constructor
+    "setup/init/init_state",
+    "setup/init/cost_model",     # _post_engine_init
+    "setup/init_state",          # an engine's init_state outside ES.__init__
+    "setup/compile",             # an engine's compile
+    "setup/compile/lower",       # .lower(...): trace and MLIR
+    "setup/compile/acquire",     # .compile(): retrieval or build, the load
+    "setup/compile/facts",       # compiled_cost_facts / memory_analysis
+    "setup/compile/copy_into",   # the sharded engine's second program
+)
+
+
+def _process_age_s() -> float:
+    """Seconds since the kernel started this process (0 where ``/proc``
+    is absent), as ``benchmark/run.py::process_age_s`` reads it."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return max(0.0, uptime - start_ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return 0.0
+
+
+class Timeline:
+    """The process's start-up on ONE clock (``time.perf_counter``, the
+    clock of the benchmark's fences): when the process started, when
+    ``estorch_tpu`` finished importing, every set-up span whole, and the
+    first generations' phases whole (a reader places the warm-up by them).
+    Set-up is the process's, not one ES's: a run that builds two ES
+    objects, or a reader that is handed no ES, sees one timeline.
+    Bounded: past ``SPAN_CAP`` a set-up span is counted in ``dropped`` and
+    not kept; past ``PHASE_CAP`` a phase is not kept (every record's
+    ``phases`` has its seconds anyway)."""
+
+    SPAN_CAP = 4096
+    PHASE_CAP = 512
+
+    def __init__(self):
+        now = time.perf_counter()
+        self.process_start = now - _process_age_s()
+        self.imported: float | None = None
+        self.backend_up_at_import: bool | None = None
+        # (name, start, end, thread id, parent's name or None)
+        self.spans: list[tuple] = []
+        # (name, start, end, thread id, generation)
+        self.phases: list[tuple] = []
+        self.dropped = 0
+
+    def mark_imported(self) -> None:
+        """Called once, by the last line of ``estorch_tpu/__init__.py``.
+        Also notes whether a jax backend was live by then: a caller that
+        asked for the devices BEFORE it imported the package paid the
+        runtime's bring-up before this stamp, one that asks later pays it
+        after (a reader that takes the bring-up out has to know which)."""
+        if self.imported is None:
+            self.imported = time.perf_counter()
+            try:
+                from jax._src import xla_bridge
+
+                self.backend_up_at_import = bool(xla_bridge._backends)
+            except (ImportError, AttributeError):
+                self.backend_up_at_import = None
+
+    def add_span(self, name, start, end, parent) -> None:
+        if len(self.spans) < self.SPAN_CAP:
+            self.spans.append(
+                (name, start, end, threading.get_ident(), parent))
+        else:
+            self.dropped += 1
+
+    def add_phase(self, name, start, end, generation) -> None:
+        if len(self.phases) < self.PHASE_CAP:
+            self.phases.append(
+                (name, start, end, threading.get_ident(), generation))
+
+
+TIMELINE = Timeline()
+
+
+def setup_summary() -> dict:
+    """The timeline as a generation record carries it (``"setup"``), made
+    the moment the first generation is complete: seconds since the
+    process's start throughout.  The acquisition summary is
+    ``utils.backend.acquisition_summary`` 's and is empty where no listener
+    was installed."""
+    from ..utils.backend import acquisition_summary
+
+    t0 = TIMELINE.process_start
+    out = {
+        "schema": 1,
+        "first_generation_done_s": round(time.perf_counter() - t0, 6),
+        "spans": [
+            {"name": name, "start_s": round(a - t0, 6),
+             "end_s": round(b - t0, 6), "thread": thread,
+             **({"parent": parent} if parent else {})}
+            for name, a, b, thread, parent in list(TIMELINE.spans)],
+        "acquisitions": acquisition_summary(),
+    }
+    if TIMELINE.imported is not None:
+        out["imported_s"] = round(TIMELINE.imported - t0, 6)
+    if TIMELINE.dropped:
+        out["spans_dropped"] = TIMELINE.dropped
+    return out
+
+
+def format_setup(setup: dict) -> str:
+    """One line: time to the first generation and its parts (the import,
+    the top-level set-up spans summed by name, the acquisitions)."""
+    total = setup["first_generation_done_s"]
+    tops: dict[str, float] = {}
+    for s in setup["spans"]:
+        if "parent" not in s:
+            tops[s["name"]] = tops.get(s["name"], 0.0) + (
+                s["end_s"] - s["start_s"])
+    parts = [f"before the package {setup['imported_s']:.2f} s"] \
+        if "imported_s" in setup else []
+    parts += [f"{name} {dur:.2f} s" for name, dur in tops.items()]
+    acq = setup["acquisitions"]
+    if acq.get("programs"):
+        parts.append(
+            f"{acq['programs']} executables acquired "
+            f"({acq['cache_hits']} from the cache) in {acq['backend_s']:.2f} "
+            f"s, traced and lowered in "
+            f"{acq['trace_s'] + acq['lower_s']:.2f} s")
+    return (f"first generation complete {total:.2f} s after the process "
+            f"started: " + ", ".join(parts))
 
 
 class Telemetry:
@@ -124,7 +287,17 @@ class Telemetry:
     @contextlib.contextmanager
     def _phase_cm(self, name: str, fence):
         stack = self._stack
-        full = f"{stack[-1]}/{name}" if stack else name
+        parent = stack[-1] if stack else None
+        if name.startswith(SETUP_PREFIX):
+            # set-up is the process's: under an open set-up span the leaf
+            # nests, anywhere else the span stands at the top
+            if parent is not None and parent.startswith(SETUP_PREFIX):
+                full = f"{parent}/{name[len(SETUP_PREFIX):]}"
+            else:
+                parent, full = None, name
+        else:
+            full = f"{parent}/{name}" if parent else name
+        setup = full.startswith(SETUP_PREFIX)
         stack.append(full)
         if self.heartbeat is not None:
             # beat on ENTRY: a wedge inside this phase leaves its name —
@@ -146,10 +319,17 @@ class Telemetry:
                 if fence is not None:
                     fence()
         finally:
-            dt = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            dt = t1 - t0
             stack.pop()
-            with self._acc_lock:
-                self._acc[full] = self._acc.get(full, 0.0) + dt
+            if setup:
+                # kept whole in the process's timeline, and out of the
+                # generation's accumulator
+                TIMELINE.add_span(full, t0, t1, parent)
+            else:
+                with self._acc_lock:
+                    self._acc[full] = self._acc.get(full, 0.0) + dt
+                TIMELINE.add_phase(full, t0, t1, self.generation)
             # per-phase duration DISTRIBUTION, not just the sum: the
             # accumulator's per-generation total is what records carry,
             # the histogram is what `obs regress --tail` gates on
@@ -239,9 +419,16 @@ class Telemetry:
         batcher records from its worker thread)."""
         if not self.enabled:
             return None
+        from ..utils.backend import last_acquisition
         from .profile.costmodel import compiled_cost_facts
 
         facts = compiled_cost_facts(compiled) if compiled is not None else {}
+        # the listener's record of the same acquisition, where it kept one
+        # inside the caller's interval (utils/backend.py): the program's
+        # name in jax, the seconds of the acquisition alone, and whether
+        # the persistent cache served it
+        facts.update(last_acquisition(
+            since=time.perf_counter() - float(dur_s) - 0.5))
         entry = self.compile_ledger.record(
             program, dur_s, generation=self.generation, **facts, **extra)
         if count_recompiles:

@@ -44,6 +44,9 @@ RECORD_SCHEMA: dict[str, tuple[tuple[type, ...], bool]] = {
     # rides the run's first record only
     "compile_events": ((list,), False),
     "cost_model": ((dict,), False),
+    # the process's start-up (obs/spans.py::setup_summary): set-up spans
+    # whole and the acquisition summary, on the run's first record only
+    "setup": ((dict,), False),
     # async scheduler accounting (algo/scheduler.py, docs/async.md):
     # consumed/fresh/folded/stale_discarded per update + overlap facts
     "async": ((dict,), False),
@@ -119,6 +122,24 @@ def validate_record(rec: dict) -> list[str]:
                   or isinstance(dur, bool) or dur < 0):
                 problems.append(f"phase {name!r} duration {dur!r} is not a "
                                 "non-negative number")
+    setup = rec.get("setup")
+    if isinstance(setup, dict):
+        spans = setup.get("spans")
+        if not isinstance(spans, list):
+            problems.append("setup.spans is not a list")
+        else:
+            for span in spans:
+                ok = (isinstance(span, dict)
+                      and isinstance(span.get("name"), str)
+                      and all(isinstance(span.get(k), (int, float))
+                              and not isinstance(span.get(k), bool)
+                              for k in ("start_s", "end_s"))
+                      and span["start_s"] <= span["end_s"])
+                if not ok:
+                    problems.append(f"setup span {span!r} is not a name "
+                                    "with start_s <= end_s")
+        if not isinstance(setup.get("acquisitions"), dict):
+            problems.append("setup.acquisitions is not an object")
     a = rec.get("async")
     if isinstance(a, dict):
         for key in ASYNC_REQUIRED_KEYS:

@@ -320,9 +320,11 @@ def build_fact_manifest(engine) -> dict:
 class ESEngine:
     """Compiles and caches the per-generation XLA programs for one setup."""
 
-    # span telemetry hub; ES replaces this with its own (obs/spans.py).
+    # span telemetry hub; ES hands over its own AT construction
+    # (``telemetry=``), so the set-up spans of the constructor's callees,
+    # of ``init_state`` and of ``compile`` land in it (obs/spans.py).
     # The fused generation program cannot be phase-split host-side — the
-    # engine's contributions are compile events + recompile counters
+    # engine's other contributions are compile events + recompile counters
     telemetry = NULL_TELEMETRY
 
     def __init__(
@@ -341,7 +343,10 @@ class ESEngine:
         lowrank_apply=None,
         lowrank_spec=None,
         carry_init=None,
+        telemetry=None,
     ):
+        if telemetry is not None:
+            self.telemetry = telemetry
         self.env = env
         if config.obs_norm:
             if env is None:
@@ -1047,6 +1052,10 @@ class ESEngine:
     # ---- public API ----
 
     def init_state(self, params_flat: jax.Array, key: jax.Array) -> ESState:
+        with self.telemetry.phase("setup/init_state"):
+            return self._init_state(params_flat, key)
+
+    def _init_state(self, params_flat, key) -> ESState:
         import chex
 
         chex.assert_shape(params_flat, (self.spec.dim,))
@@ -1105,15 +1114,21 @@ class ESEngine:
         """
         import time as _time
 
-        t0 = _time.perf_counter()
-        compiled = self._generation_step.lower(state).compile()
-        dt = _time.perf_counter() - t0
-        # ledger entry + recompiles counter + per-program gauges + ring
-        # event in one call; `compiled` contributes XLA's own FLOPs/bytes/
-        # peak-memory estimates where this jax version exposes them
-        # (obs/profile/ledger.py)
-        self.telemetry.compile_event("generation_step", dt,
-                                     compiled=compiled, first_call=True)
+        obs = self.telemetry
+        with obs.phase("setup/compile"):
+            t0 = _time.perf_counter()
+            with obs.phase("lower"):
+                lowered = self._generation_step.lower(state)
+            with obs.phase("acquire"):
+                compiled = lowered.compile()
+            dt = _time.perf_counter() - t0
+            # ledger entry + recompiles counter + per-program gauges + ring
+            # event in one call; `compiled` contributes XLA's own FLOPs/
+            # bytes/peak-memory estimates where this jax version exposes
+            # them (obs/profile/ledger.py)
+            with obs.phase("facts"):
+                obs.compile_event("generation_step", dt,
+                                  compiled=compiled, first_call=True)
         return dt
 
     def compile_split(self, state: ESState) -> float:
@@ -1121,23 +1136,30 @@ class ESEngine:
         center eval) used by the novelty family; returns seconds spent."""
         import time as _time
 
+        obs = self.telemetry
         total = 0.0
         dummy_w = jnp.zeros((self.config.population_size,), jnp.float32)
-        for program, lowered in (
-            ("evaluate", lambda: self._evaluate.lower(state)),
-            ("apply_weights", lambda: self._apply_weights.lower(state,
-                                                               dummy_w)),
-            ("center_eval", lambda: self._center_eval.lower(state)),
-        ):
-            t0 = _time.perf_counter()
-            compiled = lowered().compile()
-            dt = _time.perf_counter() - t0
-            # per-program ledger entries: the split path's three programs
-            # have very different costs, and the ledger is what tells
-            # them apart (one blended "split_path" entry could not)
-            self.telemetry.compile_event(program, dt, compiled=compiled,
-                                         first_call=True)
-            total += dt
+        with obs.phase("setup/compile"):
+            for program, lower in (
+                ("evaluate", lambda: self._evaluate.lower(state)),
+                ("apply_weights", lambda: self._apply_weights.lower(
+                    state, dummy_w)),
+                ("center_eval", lambda: self._center_eval.lower(state)),
+            ):
+                t0 = _time.perf_counter()
+                with obs.phase("lower"):
+                    lowered = lower()
+                with obs.phase("acquire"):
+                    compiled = lowered.compile()
+                dt = _time.perf_counter() - t0
+                # per-program ledger entries: the split path's three
+                # programs have very different costs, and the ledger is
+                # what tells them apart (one blended "split_path" entry
+                # could not)
+                with obs.phase("facts"):
+                    obs.compile_event(program, dt, compiled=compiled,
+                                      first_call=True)
+                total += dt
         return total
 
     def generation_step(self, state: ESState):

@@ -41,7 +41,8 @@ class PooledEvalResult:
 class PooledEngine:
     """Same engine interface as ESEngine/HostEngine, pooled evaluation."""
 
-    # span telemetry hub; ES replaces this with its own (obs/spans.py)
+    # span telemetry hub; ES hands over its own at construction
+    # (obs/spans.py)
     telemetry = NULL_TELEMETRY
 
     def __init__(
@@ -60,7 +61,10 @@ class PooledEngine:
         carry_init=None,
         env_kwargs: dict | None = None,
         bc_indices=None,
+        telemetry=None,
     ):
+        if telemetry is not None:
+            self.telemetry = telemetry
         self.env_name = env_name
         self.env_kwargs = dict(env_kwargs) if env_kwargs else None
         self.prep = dict(prep) if prep else None
@@ -240,6 +244,10 @@ class PooledEngine:
     # ------------------------------------------------------------ interface
 
     def init_state(self, params_flat, key) -> ESState:
+        with self.telemetry.phase("setup/init_state"):
+            return self._init_state(params_flat, key)
+
+    def _init_state(self, params_flat, key) -> ESState:
         state = self.core.init_state(params_flat, key)
         if self.obs_norm:
             # same init as the device path: count=1, mean=0, m2=1 → var 1
@@ -266,6 +274,10 @@ class PooledEngine:
                        self._obs_clip).astype(np.float32)
 
     def compile(self, state: ESState) -> float:
+        with self.telemetry.phase("setup/compile"):
+            return self._compile(state)
+
+    def _compile(self, state: ESState) -> float:
         import time as _time
 
         t0 = _time.perf_counter()

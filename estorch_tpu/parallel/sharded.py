@@ -282,7 +282,12 @@ class ShardedESEngine:
         perturbed_apply: Callable[..., Any] | None = None,
         lowrank_spec=None,
         policy: PolicyDeclaration = PolicyDeclaration(),
+        telemetry=None,
     ):
+        # the ES's hub from the first line on: what this constructor, then
+        # ``init_state`` and ``compile`` span lands in it (obs/spans.py)
+        if telemetry is not None:
+            self.telemetry = telemetry
         if config.obs_norm:
             raise ValueError(
                 "obs_norm is a replicated-engine option; the sharded "
@@ -1128,6 +1133,10 @@ class ShardedESEngine:
     # ------------------------------------------------------------- public
 
     def init_state(self, params_flat: jax.Array, key: jax.Array) -> ShardedESState:
+        with self.telemetry.phase("setup/init_state"):
+            return self._init_state(params_flat, key)
+
+    def _init_state(self, params_flat, key) -> ShardedESState:
         chex.assert_shape(params_flat, (self.spec.dim,))
         chex.assert_tree_all_finite(params_flat)
         # place leaf by leaf, each slice straight onto its shards: the flat
@@ -1163,20 +1172,29 @@ class ShardedESEngine:
         output/temp byte sizes (``memory_analysis``) — with sharded
         inputs those ARE shard sizes, which is how the bench A/B and the
         acceptance test state per-device peak bytes."""
-        t0 = time.perf_counter()
-        args = (state, self.table.data) if self.noise_mode == "table" else (state,)
-        with _rng_scope(self.noise_mode == "program"):
-            compiled = self._generation_step.lower(*args).compile()
-        dt = time.perf_counter() - t0
         from ..obs.profile.costmodel import compiled_cost_facts
 
-        self._compiled_facts = compiled_cost_facts(compiled)
-        self.telemetry.compile_event("generation_step_sharded", dt,
-                                     compiled=compiled, first_call=True)
-        # built here, called as built: the first best member that replaces
-        # another may come generations later, and nothing is to compile then
-        self._copy_into_compiled = self._copy_into.lower(
-            state.params, state.params).compile()
+        obs = self.telemetry
+        with obs.phase("setup/compile"):
+            t0 = time.perf_counter()
+            args = ((state, self.table.data) if self.noise_mode == "table"
+                    else (state,))
+            with _rng_scope(self.noise_mode == "program"):
+                with obs.phase("lower"):
+                    lowered = self._generation_step.lower(*args)
+                with obs.phase("acquire"):
+                    compiled = lowered.compile()
+            dt = time.perf_counter() - t0
+            with obs.phase("facts"):
+                self._compiled_facts = compiled_cost_facts(compiled)
+                obs.compile_event("generation_step_sharded", dt,
+                                  compiled=compiled, first_call=True)
+            # built here, called as built: the first best member that
+            # replaces another may come generations later, and nothing is
+            # to compile then
+            with obs.phase("copy_into"):
+                self._copy_into_compiled = self._copy_into.lower(
+                    state.params, state.params).compile()
         return dt
 
     def keep_best(self, best_theta, held=None):

@@ -164,23 +164,62 @@ def scoped_compilation_cache(cache_dir: str, min_compile_time_s: float = 0.0):
 _COMPILE_EVENT_COUNTS = {"programs": 0, "cache_hits": 0, "build_s": 0.0}
 _COMPILE_COUNTERS_INSTALLED = False
 
+# Besides the counts, every event of an executable's acquisition with its
+# name and its interval: (kind, fun_name, end on ``time.perf_counter``
+# stamped in the callback, duration, cache hit or None).  ``trace`` and
+# ``lower`` are jax's tracing of a function to a jaxpr and the jaxpr's
+# lowering to an MLIR module; ``backend`` the acquisition itself (a
+# retrieval from the persistent cache or a build, and the load), with
+# whether a cache hit came with it; ``retrieval`` the cache's own read
+# inside a hit, which carries no name.  Bounded: past
+# ``ACQUISITION_LOG_CAP`` entries an event is counted and not kept.
+ACQUISITION_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval",
+}
+ACQUISITION_LOG_CAP = 32768
+_ACQUISITION_LOG: list[tuple] = []
+_ACQUISITION_DROPPED = [0]
+
 
 def install_compile_event_counters() -> None:
     """Idempotently register jax monitoring listeners feeding
-    :func:`compile_event_counts`."""
+    :func:`compile_event_counts` and :func:`acquisition_log`."""
     global _COMPILE_COUNTERS_INSTALLED
     if _COMPILE_COUNTERS_INSTALLED:
         return
+    import threading
+    import time
+
     from jax._src import monitoring
+
+    # a hit is announced before the acquisition that it belongs to ends,
+    # on the thread that acquires
+    pending = threading.local()
 
     def _on_event(event, **kw):
         if event == "/jax/compilation_cache/cache_hits":
             _COMPILE_EVENT_COUNTS["cache_hits"] += 1
+            pending.hit = True
 
     def _on_duration(event, duration, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
+        kind = ACQUISITION_KINDS.get(event)
+        if kind is None:
+            return
+        end = time.perf_counter()
+        hit = None
+        if kind == "backend":
             _COMPILE_EVENT_COUNTS["programs"] += 1
             _COMPILE_EVENT_COUNTS["build_s"] += float(duration)
+            hit = getattr(pending, "hit", False)
+            pending.hit = False
+        if len(_ACQUISITION_LOG) < ACQUISITION_LOG_CAP:
+            _ACQUISITION_LOG.append(
+                (kind, kw.get("fun_name"), end, float(duration), hit))
+        else:
+            _ACQUISITION_DROPPED[0] += 1
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
@@ -195,6 +234,52 @@ def compile_event_counts() -> dict:
     load's fresh-build count: ``(programs - cache_hits)`` after minus
     before."""
     return dict(_COMPILE_EVENT_COUNTS)
+
+
+def acquisition_log() -> list[tuple]:
+    """Point-in-time copy of the acquisition events the listener kept:
+    ``(kind, fun_name, end, duration, cache_hit)``, ``end`` on
+    ``time.perf_counter``, in the order they ended."""
+    return list(_ACQUISITION_LOG)
+
+
+def last_acquisition(since: float = 0.0) -> dict:
+    """The newest ``backend`` event that ended after ``since`` (perf
+    counter), as the facts a compile-ledger entry carries; ``{}`` where
+    the listener kept none."""
+    for kind, fun_name, end, duration, hit in reversed(_ACQUISITION_LOG):
+        if end < since:
+            break
+        if kind == "backend":
+            return {"fun_name": fun_name, "acquire_s": round(duration, 6),
+                    "cache_hit": bool(hit)}
+    return {}
+
+
+def acquisition_summary() -> dict:
+    """What the acquisitions so far came to: how many executables, how
+    many of them cache hits, the seconds by kind, and the costliest five
+    by name (a generation record's ``"setup"["acquisitions"]``)."""
+    log = list(_ACQUISITION_LOG)
+    if not log:
+        return {}
+    by_kind = {kind: 0.0 for kind in ACQUISITION_KINDS.values()}
+    for kind, _, _, duration, _ in log:
+        by_kind[kind] += duration
+    backend = [e for e in log if e[0] == "backend"]
+    named = sorted((e for e in log if e[0] != "retrieval"),
+                   key=lambda e: -e[3])[:5]
+    out = {
+        "programs": len(backend),
+        "cache_hits": sum(1 for e in backend if e[4]),
+        **{f"{kind}_s": round(s, 6) for kind, s in by_kind.items()},
+        "costliest": [{"kind": kind, "fun_name": fun_name,
+                       "dur_s": round(duration, 6)}
+                      for kind, fun_name, _, duration, _ in named],
+    }
+    if _ACQUISITION_DROPPED[0]:
+        out["events_dropped"] = _ACQUISITION_DROPPED[0]
+    return out
 
 
 def enable_cpu_gloo_collectives() -> None:
