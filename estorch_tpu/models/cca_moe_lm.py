@@ -251,6 +251,8 @@ class CCAMoELM:
             attention_widths=self.head_dim,
             attention_kv_heads=self.num_key_value_heads,
             head_width=self.hidden_size,
+            # the token rows the expert layer's combine adds into
+            combine_width=self.hidden_size,
             # after what the env scores: the pairs per held expert
             outputs=("expert_load",),
             facts={"experts_held": self.num_experts,

@@ -233,6 +233,8 @@ class IndexedMoELM:
             attention_windows={"selected": None},
             attention_kv_heads=self.num_key_value_heads,
             head_width=self.hidden_size,
+            # the token rows the expert layer's combine adds into
+            combine_width=self.hidden_size,
             selection_bytes=self.selection_bytes,
             # after what the env scores: the pairs per held expert, then
             # the (query, key) pairs selected

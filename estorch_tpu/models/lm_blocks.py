@@ -12,8 +12,9 @@ scores or by a softmax, of ONE matrix or of an MLP over a carried state,
 with or without a selection bias, renormalised or not) and ONE
 next-token scorer, each on the perturbed-dense primitive
 (models/perturbed.py), so that an optimisation of one is measured on every
-model that calls it.  The attention core and the scorer have TWO forms each
-of one algorithm, and the rule that picks is below.
+model that calls it.  The attention core, the scorer and the expert layer's
+combine have TWO forms each of one algorithm, and the rule that picks is
+below.
 
 The attention's core (:func:`attention_core`: rotated parts -> context)
 takes its operands in PARTS: per head a query and a key of one width and
@@ -108,9 +109,9 @@ which at build, and why (``ShardedESEngine.head_form`` and
 the model states).  The last position's logits (the behaviour) are the
 one-row XLA matmul in both forms.
 
-A third kernel is taken inside that scope, by a model and not by this
+One more kernel is taken inside that scope, by a model and not by this
 module: Mamba-1's selective scan (``sambay_lm.selective_scan`` asks
-``pallas_attention.scoped_interpret()`` as the two dispatches here do;
+``pallas_attention.scoped_interpret()`` as the dispatches here do;
 ops/pallas_scan.py, ``ShardedESEngine.scan_form``).
 
 The indexer (:func:`select_keys`: DeepSeek-V3.2's sparse attention, whose
@@ -135,7 +136,26 @@ sorted by expert together, so that the centre's stacked ``[E, m, n]``
 leaves go through one grouped matmul whose work follows the rows routed,
 and only the rank-r correction is per (member, expert).  Static shapes and
 no drop: the sorted rows are taken ``capacity`` at a time, as many times as
-there are rows.
+there are rows.  What closes a pass, the COMBINE (each routed row times its
+route's weight, added into its token's row: float32 rows, weights and sums,
+every routed pair, in the pass's row order), has TWO forms:
+
+- ``"xla"``: ``y.at[token].add(out · w)``, a scatter-add, which XLA:TPU runs
+  as one read-modify-write a row, in turn.  It runs anywhere, as the
+  attention's XLA form does, and is what the materialised form (each member
+  its own weights, a ``vmap`` of the layer) always takes.
+- ``"kernel"``: ops/pallas_combine.py, over tiles of consecutive tokens.  A
+  pass's rows ascend by ``held expert · tokens + token`` (the stable sort,
+  and no expert twice a token), so what one expert sends a tile is ONE
+  contiguous run of the pass's rows: a tile copies its ``held`` runs, adds
+  them in the same order, and is written once, in place of ``y``.
+
+It takes the kernel inside the SAME ``kernel_scope`` where its own shapes fit
+(``pallas_combine.fits``: token rows of whole 128-lane blocks, a member's
+tokens a whole number of the kernel's tiles), whatever forms the kernels
+beside it take.  The engine says which at build
+(``ShardedESEngine.combine_form`` by ``pallas_combine.combine_form``, from
+the ``combine_width`` the model states).
 
 Functions, not a base class: a model hands in its own ``dense`` (the
 ``(p, noise, c, name, x) -> x @ (p[name] + c·noise[name])`` of the class,
@@ -159,7 +179,7 @@ import numpy as np
 
 from ..obs.trace import (ATTN, DENSE, DIFF, DISPATCH, EXPERT, HEAD, INDEX,
                          MIX, PERTURB, ROPE, ROUTE, SELECT, part, stage)
-from ..ops import pallas_attention, pallas_head
+from ..ops import pallas_attention, pallas_combine, pallas_head
 from .perturbed import (F32, is_factored, perturbed_dense,
                         perturbed_grouped_dense, perturbed_headwise_dense,
                         perturbed_leaf)
@@ -792,8 +812,13 @@ def routed_experts(p, noise, c, u, experts, weights, *, first_held: int,
 
     Under the engine's ``vmap``s over members the centre ``p`` is not
     batched, and the pairs of every member are handled together (the
-    module's text): one sort, one grouped matmul a leaf and pass."""
-    core = _expert_core(int(first_held), int(total), activation)
+    module's text): one sort, one grouped matmul a leaf and pass, and a
+    combine whose form is picked here, by the module's rule."""
+    interpret = pallas_attention.scoped_interpret()
+    if interpret is not None and not pallas_combine.fits(u.shape[-1],
+                                                         u.shape[0]):
+        interpret = None
+    core = _expert_core(int(first_held), int(total), activation, interpret)
     c = jnp.asarray(c, F32).reshape(1)
     noise = None if noise is None else {
         n: tuple(f[None] for f in noise[n]) for n in ("gate", "up", "down")}
@@ -803,28 +828,34 @@ def routed_experts(p, noise, c, u, experts, weights, *, first_held: int,
 
 
 @functools.lru_cache(maxsize=None)
-def _expert_core(first_held: int, total: int, activation):
+def _expert_core(first_held: int, total: int, activation,
+                 combine: bool | None = None):
     """The expert layer over a set of members, ``custom_vmap``'d so that a
     ``vmap`` over more members (pairs, signs) GROWS the set instead of
     batching the sort and the grouped matmul: ``(u [M, T, hidden], experts
     [M, T, K], weights [M, T, K], c [M], centre {gate, up, down}, noise
     {name: (A [M, E, m, r], B [M, E, n, r])} | None) -> (y [M, T, hidden],
-    load [M, E])``; ``activation``: the experts' gate's."""
+    load [M, E])``; ``activation``: the experts' gate's; ``combine``: the
+    form of the combine, ``None`` the scatter-add, else the kernel (under
+    the Pallas interpreter where true)."""
 
-    def impl(u, experts, weights, c, centre, noise):
-        return _experts_of_members(u, experts, weights, c, centre, noise,
-                                   first_held, total, activation)
+    def members(combine):
+        def impl(u, experts, weights, c, centre, noise):
+            return _experts_of_members(u, experts, weights, c, centre, noise,
+                                       first_held, total, activation, combine)
+        return impl
 
-    core = jax.custom_batching.custom_vmap(impl)
+    core = jax.custom_batching.custom_vmap(members(combine))
 
     @core.def_vmap
     def rule(axis_size, in_batched, u, experts, weights, c, centre, noise):
         if any(jax.tree_util.tree_leaves(in_batched[4])):
             # each member its own weights (the materialised form): no
-            # centre to share, the members go one by one
+            # centre to share, the members go one by one, and a batched
+            # combine would be a kernel call a member: the scatter-add
             axes = jax.tree_util.tree_map(lambda b: 0 if b else None,
                                           in_batched)
-            return jax.vmap(impl, in_axes=axes)(
+            return jax.vmap(members(None), in_axes=axes)(
                 u, experts, weights, c, centre, noise), (True, True)
 
         def merged(x, batched):
@@ -844,11 +875,19 @@ def _expert_core(first_held: int, total: int, activation):
 
 
 def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
-                        total, activation):
+                        total, activation, combine: bool | None = None):
     """:func:`_expert_core` written out: sort the pairs by held expert
     (the others last), then ``capacity`` sorted rows at a time: gather the
     tokens, the gated FFN as grouped matmuls with each row's (member,
-    expert) correction, scatter-add the weighted rows back."""
+    expert) correction, add the weighted rows back into their tokens' rows
+    (the combine).  The combine has TWO forms of one sum, float32 rows,
+    weights and adds, every routed pair, in the pass's row order:
+    ``combine`` ``None`` is the scatter-add, which runs anywhere; else the
+    kernel of ops/pallas_combine.py (``combine``: its ``interpret``), which
+    reads what the sort already gives (a pass's rows ascend by ``held
+    expert · tokens + token``, so the rows an expert sends a tile of
+    consecutive tokens are one contiguous run) and writes each token's row
+    once, in place.  :func:`routed_experts` picks, by the module's rule."""
     n_members, t, hidden = u.shape
     k, held = experts.shape[-1], centre["gate"].shape[0]
     pairs, tokens = n_members * t * k, n_members * t
@@ -900,8 +939,15 @@ def _experts_of_members(u, experts, weights, c, centre, noise, first_held,
             out = grouped("down", act, sizes, row_expert, row_member)
         with stage(DISPATCH):
             # the rows past the routed ones land nowhere
-            y = y.at[jnp.where(valid, token, tokens)].add(
-                out * w[:, None], mode="drop")
+            if combine is None:
+                y = y.at[jnp.where(valid, token, tokens)].add(
+                    out * w[:, None], mode="drop")
+            else:
+                key = jnp.where(valid, row_expert * tokens + token,
+                                held * tokens)
+                y = pallas_combine.combine_rows(
+                    y, out, w, token, key, start == 0, held=held,
+                    interpret=combine)
         return start + cap, y
 
     _, y = jax.lax.while_loop(

@@ -241,6 +241,8 @@ class MoELM:
                               self.v_head_dim),
             # the width the next-token head contracts
             head_width=self.hidden_size,
+            # the token rows the expert layer's combine adds into
+            combine_width=self.hidden_size,
             # after what the env scores: the pairs per held expert
             outputs=("expert_load",),
             facts={"experts_held": self.n_routed_experts,

@@ -225,7 +225,10 @@ class PolicyDeclaration:
       attention form's rule reads (ops/pallas_attention.py);
       ``head_width``: the head form's (ops/pallas_head.py);
       ``scan_widths`` ``(d_inner, d_state)``: the scan form's
-      (ops/pallas_scan.py).  ``None``: the policy has no such layer;
+      (ops/pallas_scan.py); ``combine_width``: the floats of a token's row
+      that an expert layer's combine adds the routed rows into, which the
+      combine form's rule reads (ops/pallas_combine.py).  ``None``: the
+      policy has no such layer;
     - ``selection_bytes``: ``horizon -> bytes`` of the temporaries ONE
       member's learned selection of keys holds, for the chunk rule;
     - ``outputs``: the names, in order, of what the policy returns after
@@ -243,6 +246,7 @@ class PolicyDeclaration:
     attention_kv_heads: int | None = None
     head_width: int | None = None
     scan_widths: tuple | None = None
+    combine_width: int | None = None
     selection_bytes: Callable[[int], int] | None = None
     outputs: tuple = ()
     facts: dict = dataclasses.field(default_factory=dict)

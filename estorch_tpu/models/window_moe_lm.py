@@ -205,6 +205,8 @@ class WindowMoELM:
                                if kind in self.layer_types},
             attention_kv_heads=self.num_key_value_heads,
             head_width=self.hidden_size,
+            # the token rows the expert layer's combine adds into
+            combine_width=self.hidden_size,
             # after what the env scores: the pairs per held expert
             outputs=("expert_load",),
             # the sparse-expert facts under MoELM's names (no MTP module),

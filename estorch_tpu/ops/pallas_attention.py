@@ -192,7 +192,7 @@ def traced_why(platform: str, n_devices: int,
     """``(may Mosaic kernels be traced here?, why)`` for the policy's
     forward in a program on a mesh of ``n_devices`` devices of
     ``platform`` whose perturbed form reads its centre as ``centre_form``
-    says (``parallel/sharded.py::centre_form_why``).  THE rule of the three
+    says (``parallel/sharded.py::centre_form_why``).  THE rule of the four
     kernels' scope (:func:`kernel_scope`), said once, here: the devices are
     TPUs, and a member is WHOLE on its chip, which it is on a mesh of one
     device and, on a mesh of several, where the centre is ``"gathered"``:
@@ -339,10 +339,10 @@ _SCOPE: contextvars.ContextVar = contextvars.ContextVar(
 def kernel_scope(interpret: bool):
     """While a policy is traced inside, Mosaic kernels may be traced
     (under the Pallas interpreter where ``interpret``), and that is all it
-    says: ``lm_blocks.attention_core``, ``lm_blocks.score_next_tokens`` and
-    ``sambay_lm.selective_scan`` each take their kernel where the call's
-    own shapes fit (:func:`fits`, ``pallas_head.fits``,
-    ``pallas_scan.fits``) and their XLA form where they do not.  The
+    says: ``lm_blocks``' ``attention_core``, ``score_next_tokens`` and
+    ``routed_experts`` and ``sambay_lm.selective_scan`` each take their
+    kernel where the call's own shapes fit (:func:`fits`, and ``fits`` of
+    ``pallas_head``, ``pallas_combine``, ``pallas_scan``), else XLA.  The
     engine opens it around its own trace of the policy where
     :func:`traced_why` says so; nothing else does.  The scope acts at TRACE
     time and is no part of a ``jax.jit`` cache key: a jitted function

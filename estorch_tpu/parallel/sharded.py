@@ -116,6 +116,7 @@ from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
 from ..ops.pallas_attention import (attention_form_why, call_form,
                                     kernel_scope, traced_why)
+from ..ops.pallas_combine import combine_form
 from ..ops.pallas_head import head_form_why
 from ..ops.pallas_scan import scan_form
 from ..ops.params import ParamSpec
@@ -545,7 +546,7 @@ class ShardedESEngine:
         self._copy_into_compiled = None
 
     def _resolve_kernel_forms(self):
-        """Which form each of the policy's three hand-written kernels takes
+        """Which form each of the policy's four hand-written kernels takes
         in this engine's programs (models/lm_blocks.py and
         models/sambay_lm.py have the forms), resolved once, at build, from
         the mesh, the centre's form, the sequence length and what the
@@ -590,12 +591,21 @@ class ShardedESEngine:
         self.scan_form = (
             None if policy.scan_widths is None
             else scan_form(self.kernels_traced, *policy.scan_widths, horizon))
-        if {self.attention_form, self.head_form, self.scan_form} != {None}:
+        # "kernel" | "xla": the form of the combine that closes a pass of
+        # the policy's expert layers (lm_blocks.routed_experts), by the
+        # combine's own rule; None for a policy that states no expert layer
+        self.combine_form = (
+            None if policy.combine_width is None
+            else combine_form(self.kernels_traced, policy.combine_width,
+                              horizon))
+        if {self.attention_form, self.head_form, self.scan_form,
+                self.combine_form} != {None}:
             logging.getLogger(__name__).info(
-                "attention_form %s (%s; %s); head_form %s (%s); scan_form %s",
+                "attention_form %s (%s; %s); head_form %s (%s); scan_form "
+                "%s; combine_form %s",
                 self.attention_form, self.attention_form_why,
                 self.attention_form_by_kind, self.head_form,
-                self.head_form_why, self.scan_form)
+                self.head_form_why, self.scan_form, self.combine_form)
 
     def _traced_rule(self) -> tuple[bool, str]:
         """``(may Mosaic kernels be traced in the policy's forward?,
@@ -614,9 +624,9 @@ class ShardedESEngine:
 
     def _in_kernel_scope(self, rollout):
         """``rollout`` traced where this engine may trace Mosaic kernels:
-        the policy's ``attention_core``, ``score_next_tokens`` and
-        ``selective_scan`` learn of it by the scope that is open while
-        they are traced, and take their XLA forms with none."""
+        the policy's ``attention_core``, ``score_next_tokens``,
+        ``selective_scan`` and ``routed_experts`` learn of it by the scope
+        open while they are traced, and take their XLA forms with none."""
         if not self.kernels_traced:
             return rollout
 
@@ -1234,8 +1244,9 @@ class ShardedESEngine:
     BUILD_FACTS = (
         "forward_form", "noise_rows_per_generation", "attention_form",
         "attention_form_why", "attention_form_by_kind", "head_form",
-        "head_form_why", "scan_form", "mesh_shape", "param_bytes_per_chip",
-        "centre_form", "centre_form_why", "centre_bytes_per_chip")
+        "head_form_why", "scan_form", "combine_form", "mesh_shape",
+        "param_bytes_per_chip", "centre_form", "centre_form_why",
+        "centre_bytes_per_chip")
 
     def build_facts(self) -> dict:
         return {name: getattr(self, name) for name in self.BUILD_FACTS}

@@ -99,11 +99,17 @@ def test_manifest_gauges_and_sizes_are_the_parents(name, devices8):
     assert config.pop("head_form_why") == (
         None if config["head_form"] is None
         else "the devices are 'cpu', not TPUs")
+    # stated since PR 51, when the expert layer's combine got its two
+    # forms: the scatter-add on these CPU meshes, nothing without experts
+    combine = (None if declaration_of(es.module).combine_width is None
+               else "xla")
+    assert config.pop("combine_form") == combine
     assert sorted(config) == sorted(want["config"])
     assert config == want["config"]
     assert rules == (partition_rules_to_json(DEFAULT_PARTITION_RULES)
                      if es._shard_params else None)
     gauges = es.obs.counters.snapshot()
+    assert gauges.pop("combine_form", None) == combine
     assert sorted(gauges) == sorted(want["gauges"])
     assert gauges == want["gauges"]
     assert sized(es.engine) == want["sized"]
@@ -121,7 +127,7 @@ STATED = {
         facts={"loop_steps": 4, "layer_applications_per_token": 8}),
     "moe": dict(
         leaf_rows={"head/kernel": 8}, attention_widths=(8, 4, 6),
-        head_width=32, outputs=("expert_load",),
+        head_width=32, combine_width=32, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 1}),
     "sambay": dict(
@@ -133,7 +139,7 @@ STATED = {
                "memory_shared_by": 1}),
     "indexed_moe": dict(
         leaf_rows={"head/kernel": 8}, attention_widths=8,
-        attention_kv_heads=2, head_width=32,
+        attention_kv_heads=2, head_width=32, combine_width=32,
         attention_windows={"selected": None},
         outputs=("expert_load", "selected_pairs"),
         facts={"experts_held": 4, "experts_total": 16,
@@ -144,7 +150,7 @@ STATED = {
     # leaves; the head-mixing convolution's stack sees every position
     "cca_moe": dict(
         attention_widths=8, attention_kv_heads=2, head_width=32,
-        outputs=("expert_load",),
+        combine_width=32, outputs=("expert_load",),
         leaf_rows_per_token=lambda lm: dict.fromkeys(lm.expert_leaves,
                                                      1.25 / 2),
         facts={"experts_held": 2, "experts_total": 4,
@@ -154,7 +160,7 @@ STATED = {
     # taken ahead of attention, which the declaration need not say
     "window_moe": dict(
         leaf_rows={"head/kernel": 8}, attention_widths=8,
-        attention_kv_heads=2, head_width=32,
+        attention_kv_heads=2, head_width=32, combine_width=32,
         attention_windows={"window": 6, "global": None},
         outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
@@ -193,12 +199,12 @@ def test_a_module_that_states_nothing_gets_the_defaults(devices8):
     assert declaration_of(object()) == PolicyDeclaration()
     engine = build("mlp_sharded").engine
     assert engine.policy == PolicyDeclaration()
-    assert (engine.attention_form, engine.head_form, engine.scan_form) == (
-        None, None, None)
+    assert (engine.attention_form, engine.head_form, engine.scan_form,
+            engine.combine_form) == (None, None, None, None)
     facts = engine.build_facts()
     assert [facts[k] for k in ("attention_form", "attention_form_by_kind",
                                "attention_form_why", "head_form",
-                               "scan_form")] == [None] * 5
+                               "scan_form", "combine_form")] == [None] * 6
 
 
 # ------------------------------------------- what the policy returns, named

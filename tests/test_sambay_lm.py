@@ -695,9 +695,13 @@ class TestThroughTheShardedEngine:
         assert gauges.get("head_form") == "xla"
         # the scans too: no scope on a CPU mesh, whatever their shapes
         assert es.engine.scan_form == gauges.get("scan_form") == "xla"
+        # no expert layer: no combine, no form of it
+        assert es.engine.combine_form is None
+        assert gauges.get("combine_form", None) is None
         assert gauges.get("experts_held", None) is None
         cfg = es.run_manifest()["config"]
         assert cfg["scan_form"] == "xla"
+        assert cfg["combine_form"] is None
         assert cfg["layer_kinds"] == gauges.get("layer_kinds")
         assert (cfg["window"], cfg["kv_shared_by"],
                 cfg["memory_shared_by"]) == (5, 1, 1)

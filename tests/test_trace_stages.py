@@ -1001,6 +1001,43 @@ def test_scan_kernel_compiles_for_the_v5e_at_the_cells_shapes(
     assert memory.temp_size_in_bytes < 4 * length * d_inner // 8
 
 
+@pytest.mark.parametrize("tokens, hidden, held, rows", [
+    (16384, 2560, 16, 30720), (32768, 2048, 8, 20480),
+    (16384, 2048, 16, 20480), (65536, 2048, 16, 40960),
+], ids=["smallthinker", "zaya1", "keye", "joyai"])
+def test_combine_kernel_compiles_for_the_v5e_at_the_cells_shapes(
+        tokens, hidden, held, rows, v5e_chip):
+    """Mosaic accepts the combine's kernel at the four expert cells' shapes
+    (the merged members' float32 token rows, a pass of ``rows`` rows over
+    ``held`` experts; the tokens and the weights of a pass as scalars: 327
+    KB of SMEM at the widest).  ONE custom call, ``y`` aliased in and out:
+    the program holds no second ``[tokens, hidden]`` buffer, and nothing
+    beside its operands but the run bounds."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops import pallas_combine
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=SingleDeviceSharding(v5e_chip))
+
+    def combine(y, out, w, token, key, first):
+        return pallas_combine.combine_rows(y, out, w, token, key, first,
+                                           held=held, interpret=False)
+
+    compiled = jax.jit(combine, donate_argnums=0).lower(
+        on_chip((tokens, hidden)), on_chip((rows, hidden)), on_chip((rows,)),
+        on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
+        on_chip((), jnp.bool_)).compile()
+    entry = compiled.as_text().split("ENTRY")[1]
+    calls = [line for line in entry.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "combine_rows" in calls[0]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 4 * tokens * hidden
+    assert memory.temp_size_in_bytes < 4 * tokens * hidden // 64
+
+
 def test_kernel_form_books_the_scans_kernel_to_ssm(v5e_chip):
     """A small SambaY decoder (``d_inner`` 512 over 256 steps: one channel
     block, one time chunk) on a one-device TPU mesh: the engine resolves
@@ -1188,6 +1225,7 @@ def test_kernel_form_books_the_latents_attention_and_the_tied_head(v5e_chip):
         policy=declaration_of(es.module))
     assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
     assert engine.attention_form_by_kind == "causal:kernel"
+    assert (es.engine.combine_form, engine.combine_form) == ("xla", "kernel")
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -1211,6 +1249,16 @@ def test_kernel_form_books_the_latents_attention_and_the_tied_head(v5e_chip):
     assert PART.findall(heads[0]) == ["embed"], heads
     assert any(SCOPE_PREFIX + MIX in line and "f32[" in line
                for line in text.splitlines())
+    # the expert layers' combine in its kernel, one call a layer (the
+    # loop's passes all go through it), where the scatter-add was: the
+    # device trace books it to ``cca.dispatch_share``
+    combines = calls("combine_rows")
+    assert len(combines) == 2, combines
+    for name in combines:
+        assert SCOPE.findall(name)[0] == POLICY, name
+        assert SCOPE.findall(name)[-1] == DISPATCH, name
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line and "f32[2048,128]" in line]
 
 
 @pytest.mark.parametrize("window", [None, 4096], ids=["global", "band4096"])

@@ -916,6 +916,9 @@ class TestThroughTheShardedEngine:
         assert es.engine.forward_form == "perturbed"
         assert (es.engine.attention_form, es.engine.head_form) == ("xla",
                                                                    "xla")
+        # the expert layers' combine too: the scatter-add on a CPU mesh
+        assert (es.engine.combine_form, es.obs.counters.get("combine_form"),
+                es.run_manifest()["config"]["combine_form"]) == ("xla",) * 3
         assert [r["env_steps"] for r in es.history] == [8 * 21] * 2
         assert -4.6 < es.history[0]["reward_mean"] < -3.9   # about -log 64
         gauges = es.obs.counters
