@@ -1,4 +1,5 @@
 from .cca_moe_lm import CCAMoELM
+from .delta_moe_lm import DeltaMoELM
 from .hybrid_lm import HybridLM
 from .indexed_moe_lm import IndexedMoELM
 from .looped_lm import LoopedLM
@@ -24,6 +25,7 @@ def __getattr__(name):
 
 __all__ = [
     "CCAMoELM",
+    "DeltaMoELM",
     "HybridLM",
     "IndexedMoELM",
     "LoopedLM",

@@ -1,7 +1,8 @@
-"""The pieces the SEVEN sequence models are built from
+"""The pieces the EIGHT sequence models are built from
 (models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py,
 models/sambay_lm.py, models/indexed_moe_lm.py, models/cca_moe_lm.py,
-models/window_moe_lm.py): ONE RMSNorm, ONE LayerNorm, ONE gated FFN whose
+models/window_moe_lm.py, models/delta_moe_lm.py): ONE RMSNorm (and its
+zero-centred reading, ``1 + w``), ONE LayerNorm, ONE gated FFN whose
 gate's activation is the MODEL's (SiLU where it states none, ReLU for a
 ReGLU model), ONE causal attention core (full,
 banded or over a learned SELECTION of keys), ONE indexer that makes such a
@@ -160,7 +161,9 @@ the ``combine_width`` the model states).
 Functions, not a base class: a model hands in its own ``dense`` (the
 ``(p, noise, c, name, x) -> x @ (p[name] + c·noise[name])`` of the class,
 which a subclass may replace) and its sizes.  What only one model has stays
-with it: the Mamba-2 mixer there, the pass loop and the exit gate here.
+with it: the Mamba-2 mixer there, the pass loop and the exit gate here,
+the gated delta rule, its gated norm and the attention's output gate in
+models/delta_moe_lm.py.
 Rotary positions are an optional argument of the one attention.
 
 Precision as in the models' own text: matmul operands in the dtype of the
@@ -218,6 +221,13 @@ def rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
+def zero_centred_rmsnorm(x, w, eps):
+    """:func:`rmsnorm` whose weight is stored about ZERO: ``x · rsqrt(mean
+    x² + eps) · (1 + w)`` (Qwen3-Next's and Gemma's convention; ``w = 0`` is
+    the bare normalisation)."""
+    return rmsnorm(x, 1.0 + w, eps)
+
+
 def layernorm(x, scale, bias, eps):
     """float32 LayerNorm with bias over the last axis."""
     x = x.astype(F32)
@@ -241,7 +251,7 @@ def causal_conv(x, taps, bias):
 def dense(p, noise, c, name, x, bias: str | None = None,
           under: str = DENSE):
     """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``, the
-    part ``of.<name>``: every projection of the seven models says here
+    part ``of.<name>``: every projection of the eight models says here
     which leaf it multiplies (obs/trace.py).  ``bias``: the key of a bias
     leaf of ``p`` (perturbed like any small leaf), added to the product.
     ``under``: the stage of a projection that belongs to another one (the

@@ -66,11 +66,15 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     # nested inside es.policy by a sequence model (models/hybrid_lm.py,
     # models/looped_lm.py, models/moe_lm.py, models/sambay_lm.py,
     # models/indexed_moe_lm.py, models/cca_moe_lm.py,
-    # models/window_moe_lm.py on the pieces of
+    # models/window_moe_lm.py, models/delta_moe_lm.py on the pieces of
     # models/lm_blocks.py)
     "dense",     # the shared x@W projections and the gated FFN
     "ssm",       # conv1d, dt and decay, the scan (Mamba-2's chunked form,
-                 # Mamba-1's selective one), the gate
+                 # Mamba-1's selective one), the gate; the gated delta rule
+                 # names its parts: of.conv, of.decay (beta, g, the L2
+                 # norms), of.solve (K Kt, the triangular inverse, W, U),
+                 # of.carry (the chain over chunks: V', O, S'), of.gate (the
+                 # norm gated by silu(z))
     "attn",      # scores, softmax, P.V (a model with several kinds of
                  # attention names each a part: of.window, of.full, of.cross;
                  # attention over a selection of keys: of.selected;

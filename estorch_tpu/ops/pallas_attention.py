@@ -187,6 +187,18 @@ def kernel_block(length: int) -> int | None:
     return next((b for b in BLOCKS if length % b == 0), None)
 
 
+def key_block(own: int, width: int, itemsize: int) -> int:
+    """The key block of a call whose sequence takes blocks of ``own`` and
+    whose heads (or values) are ``width`` wide in operands of ``itemsize``
+    bytes: ``own``, but 512 for float32 heads of two lane blocks and more
+    under blocks of 1,024: beside the score tile the pipelined copies of a
+    ``(1024, 256)`` float32 key and value block pass the 16 MiB of scoped
+    VMEM by 76 KiB (compiled for the v5e), key blocks of 512 fit.  No
+    bfloat16 call and no narrower head is changed by it."""
+    narrow = itemsize == 4 and width >= 2 * LANES and own > 512
+    return 512 if narrow else own
+
+
 def traced_why(platform: str, n_devices: int,
                centre_form: str | None = None) -> tuple[bool, str]:
     """``(may Mosaic kernels be traced here?, why)`` for the policy's
@@ -600,7 +612,9 @@ def causal_attention(
     t = q.shape[0]
     value_dim = value_dim or head_dim
     own = kernel_block(t) or t
-    block_q, block_k = min(block_q or own, t), min(block_k or own, t)
+    block_q = min(block_q or own, t)
+    block_k = min(block_k or key_block(
+        own, max(head_dim, value_dim), q.dtype.itemsize), t)
     if t % block_q or t % block_k:
         raise ValueError(
             f"sequence of {t} positions is not a whole number of "

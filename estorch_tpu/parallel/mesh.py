@@ -217,12 +217,31 @@ CCA_MOE_LM_PARTITION_RULES = (
     (r"moe/router_mlp/[wb][123]$", P()),
 )
 
+# A sparse-expert decoder of gated-delta-rule and gated full-attention
+# layers (models/delta_moe_lm.py).  Its k, v, o, norms, router, shared and
+# stacked experts go by the rules above that name them; the full layers'
+# ``q`` (each head's query and gate side by side) by the rule for
+# ``attn/(q|k|v)``.  The linear mixer's fused ``[q | k | v | z]`` projection
+# and the conv over ``[q | k | v]`` go by column (a layout, not a cut by
+# head: GSPMD moves what the split into parts needs), closed by the
+# row-parallel ``out_proj``; ``A_log`` and ``dt_bias`` by value head; the
+# narrow ``[b | a]`` projection, the gated norm's one head of weights and
+# the shared expert's one-column gate replicate.
+DELTA_MOE_LM_PARTITION_RULES = (
+    (r"delta/in_proj_qkvz$", P(None, MODEL_AXIS)),
+    (r"delta/conv$", P(None, None, MODEL_AXIS)),
+    (r"delta/(A_log|dt_bias)$", P(MODEL_AXIS)),
+    (r"delta/(in_proj_ba|norm_scale)$", P()),
+    (r"delta/out_proj$", P(MODEL_AXIS, None)),
+    (r"moe/shared_gate$", P()),
+)
+
 CATCH_ALL = r".*"
 
 DEFAULT_PARTITION_RULES = (
         HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
         + SAMBAY_LM_PARTITION_RULES + INDEXED_MOE_LM_PARTITION_RULES
-        + CCA_MOE_LM_PARTITION_RULES) + (
+        + CCA_MOE_LM_PARTITION_RULES + DELTA_MOE_LM_PARTITION_RULES) + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),

@@ -16,6 +16,7 @@ import optax
 import pytest
 
 import cca_moe_tiny
+import delta_moe_tiny
 import indexed_moe_tiny
 import lm_tiny
 import loop_tiny
@@ -26,8 +27,8 @@ from policy_contract_parent import PARENT
 
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole, TokenScoreEnv
-from estorch_tpu.models import (CCAMoELM, HybridLM, IndexedMoELM, LoopedLM,
-                                MoELM, SambaYLM, WindowMoELM)
+from estorch_tpu.models import (CCAMoELM, DeltaMoELM, HybridLM, IndexedMoELM,
+                                LoopedLM, MoELM, SambaYLM, WindowMoELM)
 from estorch_tpu.models.perturbed import PolicyDeclaration, declaration_of
 from estorch_tpu.parallel.engine import MANIFEST_BUILD_FACTS
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
@@ -45,6 +46,7 @@ SEQUENCE_MODELS = {
     # added after the seam moved: no literal of the parent's to hold it to
     "cca_moe": (CCAMoELM, cca_moe_tiny, 1, 1),
     "window_moe": (WindowMoELM, window_moe_tiny, 1, 1),
+    "delta_moe": (DeltaMoELM, delta_moe_tiny, 1, 1),
 }
 
 
@@ -166,6 +168,18 @@ STATED = {
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 0, "sliding_window": 6,
                "window_layers": 2, "global_layers": 1}),
+    # two kinds of MIXER and one kind of attention layer: no band to state;
+    # the delta rule's chunk and the form its inverse takes are facts
+    "delta_moe": dict(
+        leaf_rows={"head/kernel": 8}, attention_widths=16,
+        attention_kv_heads=2, head_width=32, combine_width=32,
+        outputs=("expert_load",),
+        facts={"experts_held": 4, "experts_total": 16,
+               "experts_per_token": 3, "mtp_depth": 0, "linear_layers": 3,
+               "full_layers": 1, "delta_chunk": 8,
+               "delta_inverse": "blocks of 8 by the finite product "
+                                "(I - A)(I + A^2)(I + A^4)..., merged in "
+                                "pairs"}),
 }
 
 

@@ -107,7 +107,7 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source,
     assert any(POLICY in stack for stack in matmuls)
 
 
-# the seven sequence models on the sharded engine's perturbed form: what
+# the eight sequence models on the sharded engine's perturbed form: what
 # each is built from, the stages its forward does NOT name, the layers it
 # nests inside es.policy, and the parts it names that are no leaf's
 SEQUENCE_MODELS = {
@@ -161,10 +161,22 @@ SEQUENCE_MODELS = {
                              | LATENT_STAGES),
                      inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT),
                      more_parts={"window": ATTN, "global": ATTN}),
+    # the gated delta rule names its parts beneath es.ssm (the conv's taps,
+    # the decay's leaves and the gated norm's weights are no matrices of a
+    # tree; the triangular solve and the chain over chunks multiply no leaf
+    # at all); the attention of its ONE kind of full layer names none
+    "delta": dict(policy="DeltaMoELM", tiny="delta_moe_tiny", devices=1,
+                  model_shards=1,
+                  absent=({EXIT} | SAMBAY_STAGES | INDEXED_STAGES
+                          | LATENT_STAGES),
+                  inner=(DENSE, SSM, ATTN, HEAD, ROPE, ROUTE, DISPATCH,
+                         EXPERT),
+                  more_parts={"conv": SSM, "decay": SSM, "solve": SSM,
+                              "carry": SSM, "gate": SSM}),
 }
 # the models whose lowered text is read (an expert layer's grouped matmul
 # keeps its name there and not in the compiled program's)
-ROUTED = ("expert", "indexed", "latent", "windowed")
+ROUTED = ("expert", "indexed", "latent", "windowed", "delta")
 PART = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(PART_PREFIX)
                   + r"([A-Za-z0-9_.]+)")
 
@@ -199,7 +211,7 @@ def _multiplied_leaves(module) -> dict:
     parts = {}
     for path, leaf in leaves:
         keys = [str(k.key) for k in path]
-        if (len(leaf.shape) < 2 or keys[-1].startswith("conv_")
+        if (len(leaf.shape) < 2 or keys[-1].startswith("conv")
                 or keys[-1] == "A_log"):        # a table of decay rates
             continue
         if "router_mlp" in keys:        # a router's MLP is one part
@@ -284,6 +296,17 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         assert any(st[-1] == ROPE and ("sin" in n or "cos" in n)
                    for st, n in stacks)
         assert es.obs.counters.get("sliding_window") == 6
+    if model == "delta":
+        # the chain over the chunks is a loop under es.ssm in the part
+        # of.carry, the triangular system's products in of.solve; the
+        # shared expert's one-column gate is a part of es.dense
+        assert any(st[-1] == SSM and "while" in n
+                   and PART.findall(n) == ["carry"] for st, n in stacks)
+        assert any(st[-1] == SSM and "dot_general" in n
+                   and PART.findall(n) == ["solve"] for st, n in stacks)
+        assert any(st[-1] == DENSE and PART.findall(n) == ["shared_gate"]
+                   for st, n in stacks)
+        assert es.obs.counters.get("delta_chunk") == 8
     if model == "indexed":
         # the score product of every index head against the ONE key head
         # under es.index, the bisection's loop and the prefix count under
@@ -348,9 +371,10 @@ def test_parts_are_metadata_only(model, monkeypatch):
     import importlib
 
     from estorch_tpu import models
-    from estorch_tpu.models import (cca_moe_lm, hybrid_lm, indexed_moe_lm,
-                                    lm_blocks, looped_lm, moe_lm, perturbed,
-                                    sambay_lm, window_moe_lm)
+    from estorch_tpu.models import (cca_moe_lm, delta_moe_lm, hybrid_lm,
+                                    indexed_moe_lm, lm_blocks, looped_lm,
+                                    moe_lm, perturbed, sambay_lm,
+                                    window_moe_lm)
 
     case = SEQUENCE_MODELS[model]
     tiny = importlib.import_module(case["tiny"])
@@ -371,7 +395,8 @@ def test_parts_are_metadata_only(model, monkeypatch):
     with_parts = lowered()
     assert PART_PREFIX in with_parts.as_text(debug_info=True)
     for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm,
-                sambay_lm, indexed_moe_lm, cca_moe_lm, window_moe_lm):
+                sambay_lm, indexed_moe_lm, cca_moe_lm, window_moe_lm,
+                delta_moe_lm):
         monkeypatch.setattr(mod, "part",
                             lambda name: contextlib.nullcontext())
     without = lowered()
@@ -1287,6 +1312,35 @@ def test_attention_kernel_compiles_for_the_v5e_in_groups_of_seven(
         q, k, v, num_heads=28, num_kv_heads=4, head_dim=128,
         scale=128 ** -0.5, interpret=False, window=window)))).lower(
             operand(3584), operand(512), operand(512)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " copy(" not in text.split("ENTRY")[1]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_attention_kernel_compiles_for_the_v5e_at_heads_of_256(dtype,
+                                                               v5e_chip):
+    """Mosaic accepts the attention kernel at ``qwen3next-es-16k-1chip``'s
+    shapes, which no other cell has: 16 query heads over 2 key-value heads
+    of 256 (TWO lane blocks a head, groups of EIGHT: q ``[16384, 4096]``, k
+    and v ``[16384, 512]``), 16,384 positions, one member at a time as the
+    cell's chunk of one pair with its signs in turn evaluates them; nothing
+    else in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import causal_attention, fits
+
+    assert fits(256, 0, 256, None, 16384)
+
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (1, 1, 16384, width), dtype,
+            sharding=SingleDeviceSharding(v5e_chip))
+
+    text = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
+        q, k, v, num_heads=16, num_kv_heads=2, head_dim=256,
+        scale=256 ** -0.5, interpret=False)))).lower(
+            operand(4096), operand(512), operand(512)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert " copy(" not in text.split("ENTRY")[1]
 

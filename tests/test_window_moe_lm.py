@@ -1024,7 +1024,20 @@ class TestThroughTheShardedEngine:
 
 import test_swa_cell as _cell  # noqa: E402
 
-test_cell__is_added_by_files_alone = _cell.test_the_cell_is_added_by_files_alone
+
+def test_cell__is_added_by_files_alone(monkeypatch):
+    """The rehearsal also asserts that the cell and its configuration are
+    the LAST of their lists; cells that later PRs append follow them (PR
+    52's did), so it is handed the lists up to its own entries."""
+    bench = _cell._bench()
+
+    def upto(entries, name):
+        return entries[:[e["name"] for e in entries].index(name) + 1]
+
+    monkeypatch.setattr(_cell, "_bench", lambda: {
+        **bench, "workloads": upto(bench["workloads"], _cell.CELL),
+        "configs": upto(bench["configs"], _cell.CONFIG)})
+    _cell.test_the_cell_is_added_by_files_alone()
 
 
 def test_cell__metrics_name_this_cell_and_only_it(monkeypatch):
