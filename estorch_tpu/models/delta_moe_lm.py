@@ -51,9 +51,16 @@ other through a unit lower-triangular system.  Inside a chunk of
 across chunks, with the incoming state ``S``: ``V' = U - W S``, ``O = (Q ∘
 e^γ) S + (Q Kᵀ ∘ e^{γ_i - γ_j} ∘ [j ≤ i]) V'``, ``S' = e^{γ_C} S + (K ∘
 e^{γ_C - γ})ᵀ V'``.  All of it float32, its products at ``HIGHEST``
-precision: an XLA form (``lax.scan`` over the chunks); nothing of
-``HybridLM._ssd`` is shared but the idea of a chunk, whose ``C Bᵀ ∘ decay``
-product has no counterpart here.
+precision, in two forms of the same terms: the kernels of
+ops/pallas_delta.py (a chunk's system and the carried state in VMEM, ``q``,
+``k``, ``v``, ``o`` left ``[T, heads · dim]``) inside an engine's
+``pallas_attention.kernel_scope`` where the shapes fit (``pallas_delta.fits``:
+heads of whole 128-lane blocks, a chunk of 16 to 128; which at build,
+``ShardedESEngine.delta_form`` from the ``delta_widths`` the model states),
+and an XLA form (batched products over head-major chunks, ``lax.scan`` over
+the chunks) everywhere else: the CPU, a call outside a scope, other shapes.
+Nothing of ``HybridLM._ssd`` is shared but the idea of a chunk, whose ``C Bᵀ
+∘ decay`` product has no counterpart here.
 
 The expert layer is told which experts it holds, as ``MoELM``'s: the router
 scores ``num_experts · expert_group_size`` experts, this program holds the
@@ -85,6 +92,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import ATTN, DENSE, HEAD, ROPE, SSM, part, stage
+from ..ops import pallas_attention, pallas_delta
 from . import lm_blocks
 from .lm_blocks import layer_name, subtree, zero_centred_rmsnorm
 from .perturbed import (F32, PolicyDeclaration, leaf_columns, perturbed_dense,
@@ -93,11 +101,9 @@ from .perturbed import (F32, PolicyDeclaration, leaf_columns, perturbed_dense,
 LINEAR_LAYER, FULL_LAYER = "linear", "full"
 EXPERT_LEAVES = ("gate", "up", "down")
 HIGHEST = jax.lax.Precision.HIGHEST
-# the diagonal blocks of a chunk's triangular system that are inverted by
-# the finite product (I - A)(I + A²)(I + A⁴)…; larger ones are assembled
-# from their halves.  8: the product's terms grow at most C(6, 3) = 20-fold
-# before they cancel, whatever the keys (at 64 they reach 1e17)
-INVERSE_BASE = 8
+# the diagonal blocks of a chunk's triangular system that the finite
+# product inverts, in both forms of the rule (ops/pallas_delta.py has why 8)
+INVERSE_BASE = pallas_delta.INVERSE_BASE
 
 
 def _mm(a, b):
@@ -168,9 +174,14 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int):
     ``g = 0`` and ``k = 0``: it writes nothing and the state passes
     through.  Parts of ``es.ssm``: ``of.solve`` (``K Kᵀ``, the triangular
     inverse, ``W``, ``U``) and ``of.carry`` (the chain over the chunks:
-    ``V'``, ``O``, ``S'``)."""
+    ``V'``, ``O``, ``S'``).  Inside a kernel scope, where the shapes fit,
+    the kernels of ops/pallas_delta.py compute the same terms
+    (:func:`_rule_in_kernels`); this XLA form anywhere else."""
     t, nk, dk = q.shape
     nv, dv = v.shape[1:]
+    interpret = pallas_attention.scoped_interpret()
+    if interpret is not None and pallas_delta.fits(dk, dv, chunk, t):
+        return _rule_in_kernels(q, k, v, g, beta, chunk, interpret)
     rep = nv // nk
     length = min(chunk, t)
     n = -(-t // length)
@@ -220,6 +231,23 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int):
         out = out + _mm(inside, fresh)                  # [n, nk, rep, L, dv]
     out = jnp.moveaxis(out, 3, 1).reshape(n * length, nv, dv)
     return out[:t]
+
+
+def _rule_in_kernels(q, k, v, g, beta, chunk: int, interpret: bool):
+    """:func:`gated_delta_rule` in the kernels of ops/pallas_delta.py: the
+    same terms a tile at a time, ``K Kᵀ``, the decay tile, ``A``, the
+    inverse and the state in VMEM; ``q``, ``k``, ``v`` and ``o`` stay
+    ``[T, heads · dim]`` (no head-major copy).  Every operation is in one of
+    the rule's two parts."""
+    t, nv, dv = v.shape
+    with part("solve"):
+        rows = pallas_delta.decay_rows(g, beta, q.shape[1], chunk)
+        w, u = pallas_delta.solve_chunks(k, v, rows, chunk=chunk,
+                                         interpret=interpret)
+    with part("carry"):
+        out = pallas_delta.chain_chunks(q, k, w, u, rows, chunk=chunk,
+                                        interpret=interpret)
+        return out[:t].reshape(t, nv, dv)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -393,6 +421,9 @@ class DeltaMoELM:
             attention_kv_heads=self.num_key_value_heads if full else None,
             head_width=self.hidden_size,
             combine_width=self.hidden_size,
+            delta_widths=(self.linear_key_head_dim,
+                          self.linear_value_head_dim, self.delta_chunk)
+            if LINEAR_LAYER in self.layer_types else None,
             outputs=("expert_load",),
             facts={"experts_held": self.num_experts,
                    "experts_total": self.experts_total,

@@ -227,7 +227,9 @@ class PolicyDeclaration:
       ``scan_widths`` ``(d_inner, d_state)``: the scan form's
       (ops/pallas_scan.py); ``combine_width``: the floats of a token's row
       that an expert layer's combine adds the routed rows into, which the
-      combine form's rule reads (ops/pallas_combine.py).  ``None``: the
+      combine form's rule reads (ops/pallas_combine.py); ``delta_widths``
+      ``(a key head's width, a value head's, the chunk)`` of the gated
+      delta rule: the delta form's (ops/pallas_delta.py).  ``None``: the
       policy has no such layer;
     - ``selection_bytes``: ``horizon -> bytes`` of the temporaries ONE
       member's learned selection of keys holds, for the chunk rule;
@@ -247,6 +249,7 @@ class PolicyDeclaration:
     head_width: int | None = None
     scan_widths: tuple | None = None
     combine_width: int | None = None
+    delta_widths: tuple | None = None
     selection_bytes: Callable[[int], int] | None = None
     outputs: tuple = ()
     facts: dict = dataclasses.field(default_factory=dict)

@@ -295,7 +295,7 @@ NOISE_KERNEL_MAX_DIM = 1_000_000  # the row kernels hold a few windows of
 MANIFEST_BUILD_FACTS = (
     "forward_form", "noise_rows_per_generation", "noise_gather_form",
     "attention_form", "attention_form_by_kind", "head_form", "scan_form",
-    "combine_form", "attention_form_why", "head_form_why")
+    "combine_form", "delta_form", "attention_form_why", "head_form_why")
 _NO_GAUGE = frozenset({"attention_form_why", "head_form_why"})  # sentences
 # the manifest has the mesh as ``mesh_axes``
 _NOT_IN_MANIFEST = frozenset({"mesh_shape", "param_bytes_per_chip"})

@@ -117,6 +117,7 @@ from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
 from ..ops.pallas_attention import (attention_form_why, call_form,
                                     kernel_scope, traced_why)
 from ..ops.pallas_combine import combine_form
+from ..ops.pallas_delta import delta_form
 from ..ops.pallas_head import head_form_why
 from ..ops.pallas_scan import scan_form
 from ..ops.params import ParamSpec
@@ -546,7 +547,7 @@ class ShardedESEngine:
         self._copy_into_compiled = None
 
     def _resolve_kernel_forms(self):
-        """Which form each of the policy's four hand-written kernels takes
+        """Which form each of the policy's five hand-written kernels takes
         in this engine's programs (models/lm_blocks.py and
         models/sambay_lm.py have the forms), resolved once, at build, from
         the mesh, the centre's form, the sequence length and what the
@@ -598,14 +599,22 @@ class ShardedESEngine:
             None if policy.combine_width is None
             else combine_form(self.kernels_traced, policy.combine_width,
                               horizon))
+        # "kernel" | "xla": the form of the policy's gated delta rule
+        # (delta_moe_lm.gated_delta_rule), by the rule's own; None for a
+        # policy that states no such layer
+        self.delta_form = (
+            None if policy.delta_widths is None
+            else delta_form(self.kernels_traced, *policy.delta_widths,
+                            horizon))
         if {self.attention_form, self.head_form, self.scan_form,
-                self.combine_form} != {None}:
+                self.combine_form, self.delta_form} != {None}:
             logging.getLogger(__name__).info(
                 "attention_form %s (%s; %s); head_form %s (%s); scan_form "
-                "%s; combine_form %s",
+                "%s; combine_form %s; delta_form %s",
                 self.attention_form, self.attention_form_why,
                 self.attention_form_by_kind, self.head_form,
-                self.head_form_why, self.scan_form, self.combine_form)
+                self.head_form_why, self.scan_form, self.combine_form,
+                self.delta_form)
 
     def _traced_rule(self) -> tuple[bool, str]:
         """``(may Mosaic kernels be traced in the policy's forward?,
@@ -1244,7 +1253,8 @@ class ShardedESEngine:
     BUILD_FACTS = (
         "forward_form", "noise_rows_per_generation", "attention_form",
         "attention_form_why", "attention_form_by_kind", "head_form",
-        "head_form_why", "scan_form", "combine_form", "mesh_shape",
+        "head_form_why", "scan_form", "combine_form", "delta_form",
+        "mesh_shape",
         "param_bytes_per_chip", "centre_form", "centre_form_why",
         "centre_bytes_per_chip")
 

@@ -106,12 +106,18 @@ def test_manifest_gauges_and_sizes_are_the_parents(name, devices8):
     combine = (None if declaration_of(es.module).combine_width is None
                else "xla")
     assert config.pop("combine_form") == combine
+    # stated since PR 53, when the gated delta rule got its two forms: the
+    # XLA form on these CPU meshes, nothing without a linear layer
+    delta = (None if declaration_of(es.module).delta_widths is None
+             else "xla")
+    assert config.pop("delta_form") == delta
     assert sorted(config) == sorted(want["config"])
     assert config == want["config"]
     assert rules == (partition_rules_to_json(DEFAULT_PARTITION_RULES)
                      if es._shard_params else None)
     gauges = es.obs.counters.snapshot()
     assert gauges.pop("combine_form", None) == combine
+    assert gauges.pop("delta_form", None) == delta
     assert sorted(gauges) == sorted(want["gauges"])
     assert gauges == want["gauges"]
     assert sized(es.engine) == want["sized"]
@@ -173,7 +179,7 @@ STATED = {
     "delta_moe": dict(
         leaf_rows={"head/kernel": 8}, attention_widths=16,
         attention_kv_heads=2, head_width=32, combine_width=32,
-        outputs=("expert_load",),
+        delta_widths=(8, 8, 8), outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 0, "linear_layers": 3,
                "full_layers": 1, "delta_chunk": 8,
@@ -214,11 +220,12 @@ def test_a_module_that_states_nothing_gets_the_defaults(devices8):
     engine = build("mlp_sharded").engine
     assert engine.policy == PolicyDeclaration()
     assert (engine.attention_form, engine.head_form, engine.scan_form,
-            engine.combine_form) == (None, None, None, None)
+            engine.combine_form, engine.delta_form) == (None,) * 5
     facts = engine.build_facts()
     assert [facts[k] for k in ("attention_form", "attention_form_by_kind",
                                "attention_form_why", "head_form",
-                               "scan_form", "combine_form")] == [None] * 6
+                               "scan_form", "combine_form",
+                               "delta_form")] == [None] * 7
 
 
 # ------------------------------------------- what the policy returns, named

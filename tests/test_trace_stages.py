@@ -1099,6 +1099,190 @@ def test_a_scan_the_rule_refuses_stays_a_loop_on_the_chip(v5e_chip):
             if " while(" in line and SCOPE.findall(line)[-1:] == [SSM]]
 
 
+# ------------------------------------------ the gated delta rule's kernels
+
+# the delta rule's own parts of es.ssm, and every part of it
+RULE_PARTS = ("solve", "carry")
+SSM_PARTS = ("conv", "decay", "gate") + RULE_PARTS
+
+
+def _delta_es(**policy_over):
+    """A linear and a full layer with linear heads of 128 over 128
+    positions in chunks of 64 (the widths the delta rule's kernels take:
+    one tile of two chunks), as ``algo/es.py`` builds it on the CPU."""
+    from estorch_tpu.envs import TokenScoreEnv
+    from estorch_tpu.models import DeltaMoELM
+
+    return _es(
+        policy=DeltaMoELM, population_size=4, sigma=0.02,
+        policy_kwargs={**dict(
+            layer_types=("linear", "full"), vocab_size=256, hidden_size=128,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            linear_num_key_heads=1, linear_num_value_heads=2,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+            num_experts=4, num_experts_per_tok=2, behaviour_positions=16,
+            attention_block=128, head_block=128, delta_chunk=64),
+            **policy_over},
+        agent_kwargs={"env": TokenScoreEnv(
+            vocab_size=256, seq_len=128, corpus_sequences=4)},
+        shard_params=True, low_rank=1, noise_mode="table",
+        compute_dtype="bfloat16", table_size=1 << 18,
+        device=jax.devices()[:1])
+
+
+def _delta_engine_on(chip, **policy_over):
+    """:func:`_delta_es` and its sharded engine on the one-device mesh of
+    ``chip``, built as ``algo/es.py`` builds it."""
+    from estorch_tpu.parallel.mesh import hyperscale_mesh
+    from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    es = _delta_es(**policy_over)
+    lr_apply, lr_spec = es._perturbed_form(
+        jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
+    engine = ShardedESEngine(
+        es.env, es._policy_apply, es._spec, es.table, es.optimizer,
+        es.config, hyperscale_mesh(model_shards=1, devices=[chip]),
+        partition_rules=es._partition_rules, noise_mode="table",
+        perturbed_apply=lr_apply, lowrank_spec=lr_spec,
+        policy=declaration_of(es.module))
+    return es, engine
+
+
+def _ssm_parts(names) -> list:
+    """The parts, ``[]`` where it names none, of every operation whose
+    innermost stage is es.ssm."""
+    return [PART.findall(n) for n in names
+            if SCOPE.findall(n)[-1:] == [SSM]]
+
+
+def test_kernel_form_books_the_delta_rule_to_its_two_parts(
+        kernel_attention, keyed_by_source):
+    """The delta case at widths that fit, in a kernel scope under the
+    interpreter: the engine says ``delta_form`` "kernel" (gauge and
+    manifest too), BOTH ``of.solve`` and ``of.carry`` hold operations
+    beneath es.ssm (``benchmark/layers/gdn.py`` returns nothing for a
+    program without ``of.solve``, and divides the rule's two rooflines by
+    the seconds of the two), no operation of es.ssm is without a part, and
+    no ``while`` of a ``lax.scan`` over the chunks is left in ``of.carry``
+    but the interpreter's grid loop."""
+    with kernel_attention():
+        es = _delta_es()
+    engine = es.engine
+    assert (engine.delta_form, es.obs.counters.get("delta_form"),
+            es.run_manifest()["config"]["delta_form"]) == ("kernel",) * 3
+    text = engine._generation_step.lower(
+        es.state, engine.table.data).as_text(debug_info=True)
+    names = re.findall(r'loc\("(jit\([^"]*)"', text)
+    parts = _ssm_parts(names)
+    assert parts and all(len(p) == 1 and p[0] in SSM_PARTS for p in parts), (
+        sorted({tuple(p) for p in parts}))
+    for part in RULE_PARTS:
+        # (the lowered text names a jitted call by its function)
+        kernel = {"solve": "solve_chunks", "carry": "chain_chunks"}[part]
+        assert any(p == [part] and kernel in n for p, n in zip(
+            parts, (n for n in names if SCOPE.findall(n)[-1:] == [SSM])))
+    # the pad and the head-major moveaxis of the XLA form's ``chunked()``
+    # are not run: no transpose of es.ssm outside the decay rows' (solve)
+    assert not [n for n in names if SCOPE.findall(n)[-1:] == [SSM]
+                and "transpose" in n and PART.findall(n) != ["solve"]]
+
+
+def test_the_delta_case_at_tiny_widths_says_xla():
+    """The suite's tiny model (heads of 8 in chunks of 8) builds the XLA
+    form whatever the scope, and says so."""
+    es = _sequence_es(SEQUENCE_MODELS["delta"])
+    assert (es.engine.delta_form, es.obs.counters.get("delta_form"),
+            es.run_manifest()["config"]["delta_form"]) == ("xla",) * 3
+
+
+@pytest.mark.parametrize("length, nk, nv, dk, dv, chunk", [
+    (16384, 16, 32, 128, 128, 64), (4096, 4, 4, 256, 128, 128),
+    (4096, 2, 8, 128, 256, 16)], ids=["qwen3next", "wide_keys",
+                                      "wide_values"])
+def test_delta_kernels_compile_for_the_v5e_at_the_cells_shapes(
+        length, nk, nv, dk, dv, chunk, v5e_chip):
+    """Mosaic accepts the two kernels at the Qwen3-Next cell's shapes
+    (16,384 positions, 16 key and 32 value heads of 128 x 128, chunks of
+    64) and at the rule's edges (heads of two lane blocks, one and four
+    value heads a key head, the widest and the narrowest chunk) under the
+    engine's ``vmap`` of one member: TWO custom calls, no ``[T,
+    nv·dv]``-sized copy, pad or transpose beside them (q, k, v stay ``[T,
+    heads · dim]``), and nothing held but ``W``, ``U`` and the decay
+    rows."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops import pallas_delta
+
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct((1,) + shape, jnp.float32,
+                                    sharding=SingleDeviceSharding(v5e_chip))
+
+    def rule(q, k, v, g, beta):
+        rows = pallas_delta.decay_rows(g, beta, nk, chunk)
+        w, u = pallas_delta.solve_chunks(
+            k.reshape(length, nk, dk), v.reshape(length, nv, dv), rows,
+            chunk=chunk, interpret=False)
+        return pallas_delta.chain_chunks(
+            q.reshape(length, nk, dk), k.reshape(length, nk, dk), w, u,
+            rows, chunk=chunk, interpret=False)
+
+    compiled = jax.jit(jax.vmap(rule)).lower(
+        on_chip(length, nk * dk), on_chip(length, nk * dk),
+        on_chip(length, nv * dv), on_chip(length, nv),
+        on_chip(length, nv)).compile()
+    entry = compiled.as_text().split("ENTRY")[1]
+    calls = [line for line in entry.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 2
+    assert "delta_solve" in calls[0] and "delta_chain" in calls[1]
+    wide = [line for line in entry.splitlines()
+            if re.search(rf"= f32\[(1,)?{length},({nk * dk}|{nv * dv})\]\S* "
+                         r"(copy|transpose|pad|fusion)\(", line)]
+    assert wide == [], wide
+    # W and U, and the decay rows in their tiles: nothing else is held
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.2 * 4 * length * nv * max(dk, dv)
+
+
+def test_kernel_form_books_the_delta_rules_kernels_on_the_chip(v5e_chip):
+    """The same small decoder on a one-device TPU mesh: the engine resolves
+    ``delta_form`` to "kernel" by itself, and the compiled generation
+    program holds the Mosaic calls ``delta_solve`` under es.ssm in the part
+    ``of.solve`` and ``delta_chain`` in ``of.carry``, where the device
+    trace books them (``gdn.solve_share``, ``gdn.carry_share``, the rule's
+    two rooflines); no ``while`` of the XLA form's chain is left under
+    es.ssm."""
+    es, engine = _delta_engine_on(v5e_chip)
+    assert es.engine.delta_form == "xla"           # a CPU mesh
+    assert engine.delta_form == "kernel"
+    text = _compiled_generation(es, engine)
+    for kernel, part in (("delta_solve", "solve"), ("delta_chain", "carry")):
+        names = [name for line in text.splitlines()
+                 if "tpu_custom_call" in line and kernel in line
+                 for name in re.findall(r'op_name="([^"]*)"', line)]
+        assert len(names) == 1, (kernel, names)
+        assert SCOPE.findall(names[0])[0] == POLICY, names
+        assert SCOPE.findall(names[0])[-1] == SSM, names
+        assert PART.findall(names[0]) == [part], names
+    assert not [line for line in text.splitlines()
+                if " while(" in line and SCOPE.findall(line)[-1:] == [SSM]]
+
+
+def test_a_chunk_the_rule_refuses_stays_in_xla_on_the_chip(v5e_chip):
+    """The same decoder in chunks of 8 (the inverse's base alone): the
+    rule's XLA form, a ``while`` under es.ssm, and the engine says so; its
+    attention and head keep their kernels."""
+    es, engine = _delta_engine_on(v5e_chip, delta_chunk=8)
+    assert (engine.attention_form, engine.delta_form) == ("kernel", "xla")
+    text = _compiled_generation(es, engine)
+    assert not [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "delta_" in line]
+    assert [line for line in text.splitlines()
+            if " while(" in line and SCOPE.findall(line)[-1:] == [SSM]]
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_attention_kernel_compiles_for_the_v5e_under_a_selection(dtype,
