@@ -1,5 +1,6 @@
 from .cca_moe_lm import CCAMoELM
 from .delta_moe_lm import DeltaMoELM
+from .gated_window_moe_lm import GatedWindowMoELM
 from .hybrid_lm import HybridLM
 from .indexed_moe_lm import IndexedMoELM
 from .looped_lm import LoopedLM
@@ -26,6 +27,7 @@ def __getattr__(name):
 __all__ = [
     "CCAMoELM",
     "DeltaMoELM",
+    "GatedWindowMoELM",
     "HybridLM",
     "IndexedMoELM",
     "LoopedLM",

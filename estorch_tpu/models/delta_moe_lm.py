@@ -78,7 +78,8 @@ decay, state and gated norm in float32.
 As an ES policy the module maps ``tokens [T]`` to ``(score [T-1], the head's
 logits averaged over the last ``behaviour_positions`` positions [vocab],
 (token, k) pairs per held expert summed over the layers [held])``.  Left out:
-the MTP module, biases, a rope scaling (``rope_scaling`` is null), an
+the MTP module, biases, a rope scaling (``rope_scaling`` is null and anything
+else is refused; ``lm_blocks.rotary_tables(scaling=)`` is where one lives), an
 un-normalised top-k.
 """
 

@@ -1,7 +1,8 @@
-"""The pieces the EIGHT sequence models are built from
+"""The pieces the NINE sequence models are built from
 (models/hybrid_lm.py, models/looped_lm.py, models/moe_lm.py,
 models/sambay_lm.py, models/indexed_moe_lm.py, models/cca_moe_lm.py,
-models/window_moe_lm.py, models/delta_moe_lm.py): ONE RMSNorm (and its
+models/window_moe_lm.py, models/delta_moe_lm.py,
+models/gated_window_moe_lm.py): ONE RMSNorm (and its
 zero-centred reading, ``1 + w``), ONE LayerNorm, ONE gated FFN whose
 gate's activation is the MODEL's (SiLU where it states none, ReLU for a
 ReGLU model), ONE causal attention core (full,
@@ -251,7 +252,7 @@ def causal_conv(x, taps, bias):
 def dense(p, noise, c, name, x, bias: str | None = None,
           under: str = DENSE):
     """float32 ``x @ (p[name] + c·noise[name])`` under ``es.dense``, the
-    part ``of.<name>``: every projection of the eight models says here
+    part ``of.<name>``: every projection of the nine models says here
     which leaf it multiplies (obs/trace.py).  ``bias``: the key of a bias
     leaf of ``p`` (perturbed like any small leaf), added to the product.
     ``under``: the stage of a projection that belongs to another one (the
@@ -285,18 +286,72 @@ def gated_mlp(dense, p, noise, c, u, activation=jax.nn.silu):
     return dense(p, noise, c, "down", act)
 
 
+def yarn_inv_freq(head_dim: int, theta: float, scaling: dict):
+    """YaRN's ``inv_freq [head_dim/2]`` float32 and the factor its tables
+    are multiplied by (arXiv 2309.00071, the ``rope_type`` ``"yarn"`` of a
+    published ``rope_scaling`` / ``rope_parameters`` group).  With ``D =
+    head_dim`` (the ROTATED width), ``f_i = theta^(-2i/D)`` and ``L =
+    original_max_position_embeddings``:
+
+        low  = floor(D ln(L / (beta_fast · 2π)) / (2 ln theta))   (>= 0)
+        high = ceil (D ln(L / (beta_slow · 2π)) / (2 ln theta))   (<= D - 1)
+        r_i  = clip((i - low) / max(high - low, 0.001), 0, 1)
+        inv_freq_i = (f_i / factor) · r_i + f_i · (1 - r_i)
+
+    so the pairs that turn more than ``beta_fast`` times inside ``L`` keep
+    their frequency, those that turn less than ``beta_slow`` times are
+    slowed ``factor`` times, and the ones between are blended.  The second
+    value is ``attention_factor``, ``0.1 ln(factor) + 1`` where the group
+    gives none: cos and sin are both multiplied by it, so a score of two
+    rotated parts grows by its square.  Computed on the host from the
+    group's numbers (``beta_fast`` 32 and ``beta_slow`` 1 where absent)."""
+    d, factor = head_dim, float(scaling["factor"])
+    span = scaling["original_max_position_embeddings"]
+
+    def turns_at(rotations):
+        return d * np.log(span / (rotations * 2 * np.pi)) / (
+            2 * np.log(theta))
+
+    low = max(np.floor(turns_at(scaling.get("beta_fast") or 32)), 0)
+    high = min(np.ceil(turns_at(scaling.get("beta_slow") or 1)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 0.001), 0, 1)
+    freq = float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    attention_factor = scaling.get("attention_factor")
+    if attention_factor is None:
+        attention_factor = 0.1 * np.log(factor) + 1.0
+    return (jnp.asarray(freq / factor * ramp + freq * (1 - ramp), F32),
+            float(attention_factor))
+
+
 def rotary_tables(length: int, head_dim: int, theta: float,
-                  positions=None, sections=None):
+                  positions=None, sections=None, scaling=None):
     """``(cos, sin) [T, head_dim/2]`` float32 of positions ``0 … T-1``:
     ``inv_freq_i = theta^(-2i/head_dim)``.  ``positions [streams, T]`` with
     ``sections`` (M-RoPE, arXiv 2409.12191): frequency pair ``i`` turns by
     the position stream its section names, the first ``sections[0]`` pairs
     by stream 0, the next ``sections[1]`` by stream 1, …; the sections add
     up to ``head_dim/2``.  Streams that all hold ``0 … T-1`` give the
-    tables of no ``positions``, bit for bit."""
+    tables of no ``positions``, bit for bit.
+
+    ``scaling``: a published rope-scaling group (``rope_type`` and its
+    numbers).  ``None`` and ``rope_type`` ``"default"`` are the path above,
+    bit for bit: the program of a model that states no scaling is what it
+    was.  ``"yarn"``: the frequencies of :func:`yarn_inv_freq` (its text has
+    the formula: a per-frequency blend of ``f_i`` and ``f_i / factor``) and
+    BOTH tables times its ``attention_factor``.  Any other type raises.
+    One table a KIND of layer: a model whose kinds differ in ``theta``, in
+    the rotated width or in the scaling calls this once a kind."""
+    kind = (scaling or {}).get("rope_type", "default")
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"rope_type {kind!r} is not written: 'default' "
+                         "(no scaling) or 'yarn'")
     with stage(ROPE):
-        inv_freq = 1.0 / (theta ** (
-            jnp.arange(0, head_dim, 2, dtype=F32) / head_dim))
+        if kind == "yarn":
+            inv_freq, factor = yarn_inv_freq(head_dim, theta, scaling)
+        else:
+            factor = None
+            inv_freq = 1.0 / (theta ** (
+                jnp.arange(0, head_dim, 2, dtype=F32) / head_dim))
         if positions is None:
             at = jnp.arange(length, dtype=F32)[:, None]
         else:
@@ -309,7 +364,8 @@ def rotary_tables(length: int, head_dim: int, theta: float,
             stream = np.repeat(np.arange(len(sections)), sections)
             at = positions.astype(F32)[stream].T        # [T, head_dim/2]
         angle = at * inv_freq[None, :]
-        return jnp.cos(angle), jnp.sin(angle)
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        return (cos, sin) if factor is None else (cos * factor, sin * factor)
 
 
 def rotate(x, cos, sin, interleaved: bool = False,
@@ -703,8 +759,9 @@ def route(p, noise, c, u, *, top_k: int, scaling: float,
     ``top_k`` of ``s + bias`` (ties to the lower index), weights ``s`` at
     the chosen (the selection bias ``p["router_bias"]`` enters the choice
     only).  ``"softmax"`` (Qwen3-MoE): ``s = softmax(u W_r)`` over all
-    experts, the ``top_k`` of ``s``; where ``p`` holds a ``router_bias``
-    it enters the choice as under ``"sigmoid"``.  ``logits [T, experts]``
+    experts, the ``top_k`` of ``s``.  Under either, the bias is read where
+    ``p`` holds a ``router_bias`` and the choice is by ``s`` alone where a
+    model builds none.  ``logits [T, experts]``
     float32: the scores of a router that is more than one matrix
     (:func:`state_router`), in place of ``u W_r``.  Renormalised to sum
     ``scaling``, or with ``renormalise=False`` ``scaling · s`` at the
@@ -726,7 +783,7 @@ def route(p, noise, c, u, *, top_k: int, scaling: float,
         else:
             s = scored(logits)
         picked_by = s
-        if scoring == "sigmoid" or "router_bias" in p:
+        if "router_bias" in p:
             picked_by = s + perturbed_leaf(
                 p["router_bias"],
                 None if noise is None else noise["router_bias"], c)

@@ -56,8 +56,10 @@ rounding of each other moves its own logits by a third of their spread when
 it picks the other (measured on the chip: one member in 25, PERF.md §6); a
 mean over hundreds of positions does not jump, so whoever compares
 behaviours compares the weights and not one coin.  Left out: group-limited routing (``n_group`` and ``topk_group``
-must be 1), the rotary scale correction (``rope_scaling`` null), more than
-one MTP module.
+must be 1), the rotary scale correction (``rope_scaling`` null: the class has
+no argument for one; the scaling the tree has is
+``lm_blocks.rotary_tables(scaling=)``, read by
+models/gated_window_moe_lm.py), more than one MTP module.
 """
 
 from __future__ import annotations

@@ -53,7 +53,9 @@ logits averaged over the last ``behaviour_positions`` positions [vocab],
 (token, k) pairs per held expert summed over the layers [held])``;
 ``TokenScoreEnv`` scores the first two, the engine sums the third into its
 records.  Left out: q/k norms, biases, secondary experts, a shared expert,
-un-normalised routing weights, a rope scaling (``rope_scaling`` is null).
+un-normalised routing weights, a rope scaling (``rope_scaling`` is null and
+anything else is refused; the scaling the tree has is
+``lm_blocks.rotary_tables(scaling=)``, read by models/gated_window_moe_lm.py).
 """
 
 from __future__ import annotations

@@ -66,9 +66,11 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
     # nested inside es.policy by a sequence model (models/hybrid_lm.py,
     # models/looped_lm.py, models/moe_lm.py, models/sambay_lm.py,
     # models/indexed_moe_lm.py, models/cca_moe_lm.py,
-    # models/window_moe_lm.py, models/delta_moe_lm.py on the pieces of
-    # models/lm_blocks.py)
-    "dense",     # the shared x@W projections and the gated FFN
+    # models/window_moe_lm.py, models/delta_moe_lm.py,
+    # models/gated_window_moe_lm.py on the pieces of models/lm_blocks.py)
+    "dense",     # the shared x@W projections and the gated FFN (a gate a
+                 # head on the attention's context is the part of.head_gate:
+                 # its projection, the sigmoid and the product)
     "ssm",       # conv1d, dt and decay, the scan (Mamba-2's chunked form,
                  # Mamba-1's selective one), the gate; the gated delta rule
                  # names its parts: of.conv, of.decay (beta, g, the L2
@@ -79,9 +81,12 @@ STAGES = (SAMPLE, NOISE, PERTURB, POLICY, ENV, GATHER, RANK, GRAD, UPDATE,
                  # attention names each a part: of.window, of.full, of.cross;
                  # attention over a selection of keys: of.selected;
                  # rotary banded layers beside position-free full ones:
-                 # of.window, of.global)
+                 # of.window, of.global; banded and full layers of different
+                 # head counts: of.sliding, of.full)
     "head",      # the logits (tied or not), log-softmax, the score
-    "rope",      # rotary positions: cos/sin, rotating queries and keys
+    "rope",      # rotary positions: cos/sin (one table a kind of layer
+                 # where the kinds differ in theta, width or scaling),
+                 # rotating queries and keys
     "exit",      # a looped model's exit gate, the exit distribution and
                  # the weighting of the per-pass scores
     "route",     # an expert layer's router: its matmul, sigmoid, selection

@@ -236,12 +236,23 @@ DELTA_MOE_LM_PARTITION_RULES = (
     (r"moe/shared_gate$", P()),
 )
 
+# A sparse-expert decoder whose two kinds of attention layer differ in
+# their head count, with a gate a head (models/gated_window_moe_lm.py).  Its
+# q, k, v, o (as wide as the layer's own heads), norms, dense FFN, router,
+# shared and stacked experts go by the rules above that name them.  The
+# gate's projection is one column a head, 48 or 64 of them: it replicates,
+# as the other narrow projections do.
+GATED_WINDOW_MOE_LM_PARTITION_RULES = (
+    (r"attn/head_gate$", P()),
+)
+
 CATCH_ALL = r".*"
 
 DEFAULT_PARTITION_RULES = (
         HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
         + SAMBAY_LM_PARTITION_RULES + INDEXED_MOE_LM_PARTITION_RULES
-        + CCA_MOE_LM_PARTITION_RULES + DELTA_MOE_LM_PARTITION_RULES) + (
+        + CCA_MOE_LM_PARTITION_RULES + DELTA_MOE_LM_PARTITION_RULES
+        + GATED_WINDOW_MOE_LM_PARTITION_RULES) + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),

@@ -17,6 +17,7 @@ import pytest
 
 import cca_moe_tiny
 import delta_moe_tiny
+import gated_window_moe_tiny
 import indexed_moe_tiny
 import lm_tiny
 import loop_tiny
@@ -27,8 +28,9 @@ from policy_contract_parent import PARENT
 
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole, TokenScoreEnv
-from estorch_tpu.models import (CCAMoELM, DeltaMoELM, HybridLM, IndexedMoELM,
-                                LoopedLM, MoELM, SambaYLM, WindowMoELM)
+from estorch_tpu.models import (CCAMoELM, DeltaMoELM, GatedWindowMoELM,
+                                HybridLM, IndexedMoELM, LoopedLM, MoELM,
+                                SambaYLM, WindowMoELM)
 from estorch_tpu.models.perturbed import PolicyDeclaration, declaration_of
 from estorch_tpu.parallel.engine import MANIFEST_BUILD_FACTS
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
@@ -47,6 +49,7 @@ SEQUENCE_MODELS = {
     "cca_moe": (CCAMoELM, cca_moe_tiny, 1, 1),
     "window_moe": (WindowMoELM, window_moe_tiny, 1, 1),
     "delta_moe": (DeltaMoELM, delta_moe_tiny, 1, 1),
+    "gated_window_moe": (GatedWindowMoELM, gated_window_moe_tiny, 1, 1),
 }
 
 
@@ -186,6 +189,18 @@ STATED = {
                "delta_inverse": "blocks of 8 by the finite product "
                                 "(I - A)(I + A^2)(I + A^4)..., merged in "
                                 "pairs"}),
+    # two kinds of attention layer that differ in their HEAD COUNT too: the
+    # engine's rule reads the key heads and the widths, which the kinds
+    # share; each kind's heads are facts
+    "gated_window_moe": dict(
+        leaf_rows={"head/kernel": 8}, attention_widths=8,
+        attention_kv_heads=2, head_width=32, combine_width=32,
+        attention_windows={"sliding": 6, "full": None},
+        outputs=("expert_load",),
+        facts={"experts_held": 4, "experts_total": 16,
+               "experts_per_token": 3, "mtp_depth": 0, "sliding_window": 6,
+               "dense_layers": 1, "sliding_layers": 2, "full_layers": 2,
+               "sliding_heads": 6, "full_heads": 4}),
 }
 
 

@@ -107,7 +107,7 @@ def test_compiled_generation_names_every_stage(form, keyed_by_source,
     assert any(POLICY in stack for stack in matmuls)
 
 
-# the eight sequence models on the sharded engine's perturbed form: what
+# the nine sequence models on the sharded engine's perturbed form: what
 # each is built from, the stages its forward does NOT name, the layers it
 # nests inside es.policy, and the parts it names that are no leaf's
 SEQUENCE_MODELS = {
@@ -173,10 +173,19 @@ SEQUENCE_MODELS = {
                          EXPERT),
                   more_parts={"conv": SSM, "decay": SSM, "solve": SSM,
                               "carry": SSM, "gate": SSM}),
+    # its two kinds of attention (of different head counts) say which they
+    # are; the gate a head is a part of es.dense by its leaf's key, which
+    # the sigmoid and the product on the context carry too
+    "gated": dict(policy="GatedWindowMoELM", tiny="gated_window_moe_tiny",
+                  devices=1, model_shards=1,
+                  absent=({SSM, EXIT} | SAMBAY_STAGES | INDEXED_STAGES
+                          | LATENT_STAGES),
+                  inner=(DENSE, ATTN, HEAD, ROPE, ROUTE, DISPATCH, EXPERT),
+                  more_parts={"sliding": ATTN, "full": ATTN}),
 }
 # the models whose lowered text is read (an expert layer's grouped matmul
 # keeps its name there and not in the compiled program's)
-ROUTED = ("expert", "indexed", "latent", "windowed", "delta")
+ROUTED = ("expert", "indexed", "latent", "windowed", "delta", "gated")
 PART = re.compile(r"(?<![A-Za-z0-9_])" + re.escape(PART_PREFIX)
                   + r"([A-Za-z0-9_.]+)")
 
@@ -296,6 +305,19 @@ def test_model_names_its_layers_inside_the_policy_stage(model,
         assert any(st[-1] == ROPE and ("sin" in n or "cos" in n)
                    for st, n in stacks)
         assert es.obs.counters.get("sliding_window") == 6
+    if model == "gated":
+        # the gate a head: its projection's matmul under es.dense in the
+        # part of its leaf, and the sigmoid on the context in the same
+        # part; the kinds' tables and rotations under es.rope (a partial
+        # rotation puts the turned half back beside the rest of the head)
+        assert any(st[-1] == DENSE and "dot_general" in n
+                   and PART.findall(n) == ["head_gate"] for st, n in stacks)
+        assert any(st[-1] == DENSE and "logistic" in n
+                   and PART.findall(n) == ["head_gate"] for st, n in stacks)
+        assert any(st[-1] == ROPE and "cos" in n for st, n in stacks)
+        assert any(st[-1] == ROPE and "concatenate" in n for st, n in stacks)
+        assert es.obs.counters.get("sliding_heads") == 6
+        assert es.obs.counters.get("full_heads") == 4
     if model == "delta":
         # the chain over the chunks is a loop under es.ssm in the part
         # of.carry, the triangular system's products in of.solve; the
@@ -371,7 +393,8 @@ def test_parts_are_metadata_only(model, monkeypatch):
     import importlib
 
     from estorch_tpu import models
-    from estorch_tpu.models import (cca_moe_lm, delta_moe_lm, hybrid_lm,
+    from estorch_tpu.models import (cca_moe_lm, delta_moe_lm,
+                                    gated_window_moe_lm, hybrid_lm,
                                     indexed_moe_lm, lm_blocks, looped_lm,
                                     moe_lm, perturbed, sambay_lm,
                                     window_moe_lm)
@@ -396,7 +419,7 @@ def test_parts_are_metadata_only(model, monkeypatch):
     assert PART_PREFIX in with_parts.as_text(debug_info=True)
     for mod in (lm_blocks, perturbed, hybrid_lm, looped_lm, moe_lm,
                 sambay_lm, indexed_moe_lm, cca_moe_lm, window_moe_lm,
-                delta_moe_lm):
+                delta_moe_lm, gated_window_moe_lm):
         monkeypatch.setattr(mod, "part",
                             lambda name: contextlib.nullcontext())
     without = lowered()
