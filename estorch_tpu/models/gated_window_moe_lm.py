@@ -43,8 +43,8 @@ layer's own head count, so the tree is not a stack of one layer's shapes.
 One table of rotations a KIND (two ``rope_theta``, two rotated widths, one
 of them scaled) is made once a sequence.  The engine learns each kind's band
 from ``declaration()`` (``attention_windows``) and says which form each took
-(``attention_form_by_kind``: a band narrower than one of the kernel's blocks
-keeps the XLA form, ``pallas_attention.call_form``).  The gate is the
+(``attention_form_by_kind``: the published band, half of the kernel's block,
+is the block itself there, ``pallas_attention.call_form``).  The gate is the
 head-wise one of arXiv 2505.06708: ``W_g`` is ``n_l`` columns, not a second
 query-sized projection.  The router scores by sigmoid and builds NO selection
 bias (``lm_blocks.route`` chooses by the scores alone where the tree holds no

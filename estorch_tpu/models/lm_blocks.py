@@ -34,9 +34,9 @@ is the plain q/k/v/o form two of them share).  With a ``window`` a query
 sees the keys ``(t - window, t]`` and no others: both forms skip the key
 blocks wholly outside that band as they skip those above the diagonal.  A
 CALL with a window takes the kernel where the band spans at least one of the
-kernel's blocks and the XLA form under a narrower one
-(``pallas_attention.call_form`` has the rule), and the model's other calls
-follow the rule below.
+kernel's blocks or, narrower, can be the block itself, and the XLA form under
+any other (``pallas_attention.call_form`` has the rule), and the model's other
+calls follow the rule below.
 With ``selected`` (``[T, T]`` int8, :func:`select_keys`) a query sees the
 keys its row of the selection marks and no others: a mask made from the
 DATA, different for every member, layer and sequence, which BOTH forms
@@ -71,8 +71,8 @@ around its trace of the policy there.  Inside that scope
 column blocks, and its own query/key part too, or half of one with values
 of ONE block and an even number of key heads (a pair); a shared part of 64
 or a multiple of 128; the sequence a whole number of the kernel's blocks;
-and, of a call with a ``window``, a band of at least one of those blocks:
-``pallas_attention.call_form``), and the XLA form for every other call and
+and, of a call with a ``window``, a band of at least one of those blocks
+or one that can be the block: ``call_form``), and the XLA form otherwise and
 everywhere else, so ``apply`` outside an engine is the XLA form.  The engine says at
 build which form that is (``ShardedESEngine.attention_form``, by
 ``ops.pallas_attention.attention_form``: the same conditions on the widths
@@ -459,11 +459,11 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
 
     Inside an engine's ``pallas_attention.kernel_scope`` a call whose
     widths and length fit (``pallas_attention.fits``), and whose band, if
-    it has a ``window``, spans at least one of the kernel's blocks
-    (``pallas_attention.call_form``), is the Pallas kernel (its own blocks,
-    scores in VMEM, the shared part a second contraction in the tile: the
+    it has a ``window``, spans at least one of the kernel's blocks or can
+    be the block (``pallas_attention.call_form``), is the Pallas kernel
+    (scores in VMEM, the shared part a second contraction in the tile: the
     key part is never broadcast, the band a key axis as long as itself); a
-    call of other shapes or under a narrower band, and any call anywhere
+    call of other shapes or under any other band, and any call anywhere
     else, is the XLA form below, in blocks of ``block``, which
     concatenates the shared parts onto q and k, the key's broadcast to
     every head (the module's text has the rule).  The XLA form's loop over
@@ -485,11 +485,11 @@ def attention_core(q, k, v, *, num_heads: int, num_kv_heads: int,
           else v.size // (t * (nkv // 2 if paired else nkv)))
     shared = 0 if q_shared is None else k_shared.shape[-1]
     # inside a scope, the kernel where these fit and a band, if there is
-    # one, spans a block of it
+    # one, spans a block of it or can be the block
     form = pallas_attention.call_form(
         "kernel" if pallas_attention.fits(
             hd, shared, vd, nkv if paired else None, t) else "xla",
-        window, t)
+        window, t, paired)
     interpret = (pallas_attention.scoped_interpret() if form == "kernel"
                  else None)
     value_heads = nkv

@@ -48,7 +48,7 @@ full-causal kinds of layer take the attention kernel, which reads a pair as
 the one 128-lane block it is (``d`` 64); the windowed kind takes it only
 where its band spans at least one of the kernel's blocks
 (``pallas_attention.call_form``: the published 512 keys over 8,192 positions
-do not, and stay in the XLA form).
+do not, nor may PAIRS take a narrower band as the block: the XLA form).
 
 Two values are carried ACROSS layers inside one member's forward: ``m [T,
 d_inner]`` float32 and ``(K, V)`` in the compute dtype, as the ``qkv``

@@ -34,8 +34,8 @@ apart; every other expert model routes and computes from ONE state through
 position: the engine learns each kind's band from ``declaration()``
 (``attention_windows``) and says which form each took
 (``attention_form_by_kind``: where the kernel is traced a ``window`` layer
-takes it too if its band spans at least one of the kernel's blocks,
-``pallas_attention.call_form``).  The expert layer is told which experts
+takes it too if its band spans at least one of the kernel's blocks or can
+be the block, ``pallas_attention.call_form``).  The expert layer is told which experts
 it holds, as ``MoELM``'s: the router scores ``moe_num_primary_experts ·
 expert_group_size`` experts, this program holds the
 ``moe_num_primary_experts`` of share ``expert_group_rank`` and leaves out

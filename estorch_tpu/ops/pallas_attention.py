@@ -70,14 +70,14 @@ row of a query block selected is ROADMAP R13's.
 
 The visible keys may be a BAND (``window``: query ``t`` sees ``(t - window,
 t]``, a sliding-window layer).  The grid's key axis is then only as long as
-the band, the most key blocks a query block sees (five of 1,024 under a
-band of 4,096 keys, whatever the sequence), and its step ``j`` is key block
-``first visible + j``: no grid step is spent on the blocks the band has
-left behind.  The band's mask, ``cols > rows - window``, is applied in the
-tiles its edge crosses (the first of a query block from the fifth block on,
-there), beside the diagonal's in the last.  With an aligned band a query
-block's LAST rows see nothing in the first block it is handed, so a banded
-call takes the selection's guard against ``exp(-inf - -inf)``.  A branch at
+the band, the most key blocks a query block sees (five of 1,024 under 4,096
+keys, whatever the sequence), its step ``j`` key block ``first visible +
+j``; the band's mask, ``cols > rows - window``, is applied in the tiles its
+edge crosses, beside the diagonal's in the last.  With an aligned band a
+query block's LAST rows see nothing in the first block it is handed, so a
+banded call takes the selection's guard against ``exp(-inf - -inf)``.  A
+band NARROWER than a block is the block itself, its two key blocks folded in
+ONE grid step with no key axis (the last section of this file).  A branch at
 TRACE time: without a window the traced kernel is the one above, operand
 for operand.  Which calls take it is :func:`call_form`'s rule.
 
@@ -134,9 +134,9 @@ form.  Inside the scope a CALL of the core decides by its own shapes
 (:func:`fits`; the head and the scan by theirs, so an attention the shapes
 turn away stays in the XLA form beside a head in its kernel), and a call
 with a ``window`` by its band too: the kernel where the band spans at least
-one of its blocks, the XLA form under a narrower one (:func:`call_form` has
-the rule and why; ``lm_blocks.attention_core`` and the engine both read
-it).  The engine says at build which form the model's attention takes
+one of its blocks or can be the block itself, the XLA form under any other
+(:func:`call_form` has the rule; ``lm_blocks.attention_core`` and the engine
+both read it).  The engine says at build which form the model's attention takes
 (:func:`attention_form`, from the widths the model states) and which each
 kind of layer took (``attention_form_by_kind``).
 """
@@ -318,28 +318,28 @@ def attention_form_why(platform: str, n_devices: int, widths, length: int,
         if _pair(head, shared, value, kv_heads) else "whole column blocks",
         "" if window is None else
         f"; layers with a window of {window} in the " + (
-            "kernel" if call_form("kernel", window, length) == "kernel"
-            else "XLA form"))
+            "kernel" if call_form("kernel", window, length, _pair(
+                head, shared, value, kv_heads)) == "kernel" else "XLA form"))
 
 
-def call_form(form: str, window: int | None, length: int) -> str:
+def call_form(form: str, window: int | None, length: int,
+              paired: bool = False) -> str:
     """The form ONE call of the core over ``length`` positions takes where
     the kernel may be traced and the call's shapes fit it (``form`` is
     ``"kernel"``; anything else stays what it is, the XLA form).  THE rule
-    of a call with a ``window``, said once, here
-    (``lm_blocks.attention_core`` and the engine's
-    ``attention_form_by_kind`` both read it): the kernel multiplies whole
-    tiles, so its band is as wide as the key blocks a query block's keys
-    touch, and a call takes it where the band spans at least ONE of the
-    kernel's blocks (``window >= kernel_block(length)``; a window of the
-    whole sequence or more is plain causal attention).  A narrower band
-    keeps the XLA form, whose blocks are the model's own: under a band of
-    512 keys in blocks of 1,024 the kernel would multiply two tiles a query
-    block for half a tile of visible pairs (the SambaY cell's windowed
-    layer: 0.022 s by the full layers' rate against the XLA form's 0.0117 s,
-    PERF.md §6, PR 49); at 4,096 keys it multiplies five for four (0.80 of
-    the multiplied pairs visible, where the global layer has 0.94)."""
-    spans = window is None or window >= (kernel_block(length) or length)
+    of a call with a ``window``, said once, here (``attention_core`` and
+    the engine's ``attention_form_by_kind`` both read it), a rule of
+    shapes: the kernel where the band spans at least ONE of the kernel's
+    blocks (``window >= kernel_block(length)``: the grid's key axis follows
+    the band; a window of the sequence or more is plain causal attention),
+    AND where a narrower band can be the block itself (:func:`band_block`:
+    whole 128-lane rows that divide the sequence, heads not ``paired``),
+    both of its key blocks folded in one grid step (the module's text has
+    the measured times).  Any other band keeps the XLA form: the kernel
+    multiplies whole tiles, two of 1,024 for half a tile of visible pairs
+    under the SambaY cell's paired band of 512 keys."""
+    spans = (window is None or window >= (kernel_block(length) or length)
+             or band_block(window, length, paired) is not None)
     return "kernel" if form == "kernel" and spans else "xla"
 
 
@@ -589,31 +589,28 @@ def causal_attention(
     a multiple of 128.
 
     ``selected [T, T]`` int8: query ``t`` sees the keys ``s <= t`` with
-    ``selected[t, s] != 0`` and no others (heads of one width, values
-    apart, no shared part, no pairs); every head reads the one selection.
-    A row whose selection is empty gives NaN, as a softmax over nothing
-    does (``lm_blocks.select_keys`` always selects a query's own key or an
-    earlier one).
+    ``selected[t, s] != 0`` alone (plain heads); every head reads the one
+    selection.  An empty row gives NaN, as a softmax over nothing does
+    (``lm_blocks.select_keys`` always selects a query's own key).
 
-    ``window``: query ``t`` sees the keys ``(t - window, t]`` and no
-    others, a band beside the diagonal (no selection with it).  The key
-    axis of the grid is then as long as the band, the most key blocks a
-    query block sees (five of 1,024 under a band of 4,096), and starts at
-    the query block's first visible one; the band's mask is applied in the
-    tiles its edge crosses, as the diagonal's is.  ``window >= T`` is plain
-    causal attention and traces as a call without one.
+    ``window``: query ``t`` sees the keys ``(t - window, t]`` alone (no
+    selection with it).  The grid's key axis is as long as the band (five
+    blocks of 1,024 under 4,096 keys) from the query block's first visible
+    one, the band's mask in the tiles its edge crosses.  A band NARROWER
+    than the block that :func:`band_block` takes is the block itself, both
+    key blocks in one grid step (:func:`_band_in_one_step`; plain heads,
+    no blocks given).  ``window >= T`` traces as a call without one.
 
-    ``block_q``, ``block_k``: rows of a query and of a key block;
-    :func:`kernel_block` of ``T`` where not given (the whole sequence where
-    it has none, which only the interpreter runs).  On the chip the
-    widths and the blocks must be multiples of 128, the shared width 64
-    or a multiple of 128 (:func:`attention_form` is where an engine asks);
-    under the interpreter any sizes with ``T % block == 0`` run."""
+    ``block_q``, ``block_k``: rows of a query and of a key block; where
+    none is given :func:`kernel_block` of ``T`` (all of it where it has
+    none: the interpreter's).  On the chip widths and blocks are multiples
+    of 128, the shared width 64 too (:func:`attention_form`)."""
     t = q.shape[0]
     value_dim = value_dim or head_dim
     own = kernel_block(t) or t
-    block_q = min(block_q or own, t)
-    block_k = min(block_k or key_block(
+    fold = None if block_q or block_k else band_block(window, t, paired)
+    block_q = min(block_q or fold or own, t)
+    block_k = min(block_k or fold or key_block(
         own, max(head_dim, value_dim), q.dtype.itemsize), t)
     if t % block_q or t % block_k:
         raise ValueError(
@@ -657,6 +654,9 @@ def causal_attention(
             f"not [T, {num_heads} · {head_dim}], [T, {num_kv_heads} · "
             f"{k_width}] and [T, {value_heads} · {value_dim}]")
     group = num_heads // num_kv_heads
+    if fold and not (beside or shared):
+        return _band_in_one_step(q, k, v, num_heads, num_kv_heads, head_dim,
+                                 value_dim, scale, fold, interpret)
 
     def kv_row(i, j):
         # step j of a query block's key axis: its first visible key block
@@ -770,3 +770,198 @@ def causal_attention(
         name="causal_attention",
         interpret=interpret,
     )(*operands)
+
+
+# --------------------------------------------------------------------------
+# a band narrower than the kernel's block: both key blocks in ONE grid step
+# --------------------------------------------------------------------------
+# Under a band of w keys in blocks of w a query block sees exactly two key
+# blocks, so the key axis leaves the grid.  Measured on the v5e at the
+# sliding layers of laguna-xs2-es-16k-1chip, ONE member and layer (16,384
+# positions x 64 heads x 128 over 8 key heads, band 512, bfloat16; PERF.md
+# §6, PR 55): the XLA form 17.4 ms (20.5 in the cell); the grid above called
+# with blocks of 512: 7.94 ms (512 x 1,024: 8.04; 1,024 x 1,024: 8.44), a
+# grid step a tile with the running max, sum and accumulator carried; the
+# band as the block, ONE head a step: two masked folds 3.55 ms, the two
+# products selected into the one visible tile 3.50, only the visible
+# 128-wide sub-tiles multiplied 3.40 (3.69 by query sub-blocks: short
+# products); a key-value group's EIGHT heads a step: 3.12 (the selected tile,
+# a loop over the heads), 3.04 (unrolled), and 2.20 ms with the visible
+# sub-tiles alone and the heads unrolled, which is what is below: 0.62 of the
+# MXU's peak by the visible pairs.  (The rule's helpers live down here so
+# that no line of the kernel above moves: a Mosaic call's cache key carries
+# its callers' lines.)
+
+
+def heads_in_pairs(widths, kv_heads: int | None) -> bool:
+    """Whether heads of ``widths`` (as a model states them, see
+    :func:`attention_form_why`) over ``kv_heads`` key heads reach the core
+    two a column block (``attention_core(paired=True)``): what
+    :func:`call_form` asks of a call, said of a model."""
+    head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
+                           else widths)
+    return _pair(head, shared, value, kv_heads)
+
+
+def band_block(window: int | None, length: int,
+               paired: bool = False) -> int | None:
+    """The block of a call over ``length`` positions whose band of
+    ``window`` keys is NARROWER than the kernel's block
+    (:func:`kernel_block`) and can be the block itself: a whole number of
+    128-lane rows that divides the sequence, over heads that are whole
+    column blocks (not ``paired``).  ``None`` for every other call: no
+    band, a band of a block or more (the grid's key axis follows it), a
+    band that is no block (the XLA form, :func:`call_form`)."""
+    own = kernel_block(length) or length
+    if (window is None or paired or window >= own or window % LANES
+            or length % window):
+        return None
+    return window
+
+
+# rows of a query sub-block and keys of a key sub-block inside a band's
+# block: the MXU's own width, so a sub-tile is one pass of one array
+SUB = LANES
+# the most bytes of q a grid step of a narrow band holds: a key-value group's
+# heads share the step (k and v fetched once for them) up to this much
+BAND_Q_BYTES = 1 << 20
+
+
+def band_heads(group: int, block: int, head_dim: int, itemsize: int) -> int:
+    """How many query heads of a key-value group of ``group`` ONE grid step
+    of a narrow band holds: the largest divisor of the group whose q block
+    ``[block, heads · head_dim]`` is within :data:`BAND_Q_BYTES` (all 8 of
+    the cell's bfloat16 group at a band of 512; 4 in float32)."""
+    most = max(1, BAND_Q_BYTES // (block * head_dim * itemsize))
+    return max(d for d in range(1, group + 1) if group % d == 0 and d <= most)
+
+
+def _band_kernel(q_ref, k_prev_ref, k_ref, v_prev_ref, v_ref, o_ref, *,
+                 scale: float, heads: int):
+    """Query block ``i`` of ``heads`` query heads of ONE key-value head
+    under a band as wide as the block: it sees key blocks ``i - 1`` and
+    ``i`` and no others, so both are folded HERE and nothing is carried
+    between grid steps.  In the tiles' own indices the two masks are
+    complements, the same in every step (own block: ``cols <= rows``, the
+    diagonal; previous block: ``cols > rows``, the band's edge), so a row's
+    visible scores are exactly ONE tile's worth: column sub-block ``c`` of
+    that tile is the PREVIOUS block's product for the query rows before
+    sub-block ``c``, the OWN block's for the rows after it, and the select
+    of the two inside it.  Only those rows are multiplied (five of eight
+    sub-tiles at four sub-blocks), each key sub-block one pass of the MXU
+    against all the rows that see it; max, exp and sum are taken once over
+    the visible tile (every row sees its own key: no ``-inf`` max); P·V
+    goes the same way, a key sub-block's values against the rows that see
+    it.  The heads of the step are unrolled: the scheduler overlaps one
+    head's products with another's exponentials."""
+    block, hd, vd = k_ref.shape[0], k_ref.shape[1], v_ref.shape[1]
+    n = block // SUB
+    rows = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)
+    own = cols <= rows
+    zero = jnp.zeros((SUB, SUB), jnp.float32)
+
+    def stack(*parts):
+        # the parts that hold rows, one under another
+        parts = [x for x in parts if x is not None]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+
+    def head(h, banded):
+        q = q_ref[:, h * hd:(h + 1) * hd]
+        tile = []
+        for c in range(n):
+            lo, hi = c * SUB, (c + 1) * SUB
+            # own keys c against the rows from lo on, the first SUB of them
+            # under the diagonal
+            s_own = jax.lax.dot_general(q[lo:], k_ref[lo:hi], _QK_DIMS,
+                                        preferred_element_type=jnp.float32)
+            below = s_own[SUB:] if hi < block else None
+            if banded:
+                # previous keys c against the rows before hi, the last SUB
+                # of them past the band's edge
+                s_prev = jax.lax.dot_general(
+                    q[:hi], k_prev_ref[lo:hi], _QK_DIMS,
+                    preferred_element_type=jnp.float32)
+                above, edge = s_prev[:lo] if lo else None, s_prev[lo:]
+            else:
+                above = jnp.full((lo, SUB), -jnp.inf) if lo else None
+                edge = -jnp.inf
+            tile.append(stack(above, jnp.where(own, s_own[:SUB], edge),
+                              below))
+        s = jnp.concatenate(tile, axis=1) * scale
+        p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+        total = jnp.sum(p, axis=1, keepdims=True)
+        context = [[] for _ in range(n)]    # a query sub-block's terms
+        for c in range(n):
+            lo, hi = c * SUB, (c + 1) * SUB
+            diagonal = p[lo:hi, lo:hi]
+            seen = stack(jnp.where(own, diagonal, zero),
+                         p[hi:, lo:hi] if hi < block else None)
+            product = jnp.dot(seen.astype(v_ref.dtype), v_ref[lo:hi],
+                              preferred_element_type=jnp.float32)
+            for a in range(c, n):
+                context[a].append(product[(a - c) * SUB:(a - c + 1) * SUB])
+            if banded:
+                seen = stack(p[:lo, lo:hi] if lo else None,
+                             jnp.where(own, zero, diagonal))
+                product = jnp.dot(seen.astype(v_ref.dtype), v_prev_ref[lo:hi],
+                                  preferred_element_type=jnp.float32)
+                for a in range(c + 1):
+                    context[a].append(product[a * SUB:(a + 1) * SUB])
+        o_ref[:, h * vd:(h + 1) * vd] = (
+            stack(*(sum(terms[1:], terms[0]) for terms in context))
+            / total).astype(o_ref.dtype)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        # no previous block: the own block's triangle alone
+        for h in range(heads):
+            head(h, banded=False)
+
+    @pl.when(pl.program_id(1) > 0)
+    def _banded():
+        for h in range(heads):
+            head(h, banded=True)
+
+
+def _band_in_one_step(q, k, v, num_heads: int, num_kv_heads: int,
+                      head_dim: int, value_dim: int, scale: float, block: int,
+                      interpret: bool) -> jax.Array:
+    """:func:`causal_attention` under a band of ``block`` keys in blocks of
+    ``block`` (:func:`band_block`): grid ``(heads / heads a step, query
+    blocks)``, no key axis; a step holds :func:`band_heads` query heads of
+    one key-value head, contiguous columns of q and of the context.  k and
+    v are each handed in TWICE, as the previous and as the own block (two
+    index maps over one array; block 0's previous clamps to itself and is
+    not read)."""
+    t, group = q.shape[0], num_heads // num_kv_heads
+    heads = band_heads(group, block, head_dim, q.dtype.itemsize)
+    steps = group // heads      # grid steps a key-value head
+
+    def previous(g, i):
+        return jnp.maximum(i - 1, 0), g // steps
+
+    def own(g, i):
+        return i, g // steps
+
+    return pl.pallas_call(
+        functools.partial(_band_kernel, scale=scale, heads=heads),
+        grid=(num_heads // heads, t // block),
+        in_specs=[
+            pl.BlockSpec((block, heads * head_dim), lambda g, i: (i, g)),
+            pl.BlockSpec((block, head_dim), previous),
+            pl.BlockSpec((block, head_dim), own),
+            pl.BlockSpec((block, value_dim), previous),
+            pl.BlockSpec((block, value_dim), own),
+        ],
+        out_specs=pl.BlockSpec((block, heads * value_dim),
+                               lambda g, i: (i, g)),
+        out_shape=jax.ShapeDtypeStruct((t, num_heads * value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        cost_estimate=attention_cost(
+            t, num_heads, num_kv_heads, head_dim, value_dim, 0, block, block,
+            q.dtype.itemsize, window=block),
+        name="causal_attention",
+        interpret=interpret,
+    )(q, k, k, v, v)

@@ -115,7 +115,7 @@ from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
 from ..ops.pallas_attention import (attention_form_why, call_form,
-                                    kernel_scope, traced_why)
+                                    heads_in_pairs, kernel_scope, traced_why)
 from ..ops.pallas_combine import combine_form
 from ..ops.pallas_delta import delta_form
 from ..ops.pallas_head import head_form_why
@@ -573,10 +573,10 @@ class ShardedESEngine:
             self.attention_form, self.attention_form_why = (
                 self._attention_rule(widths))
             # "<kind>:<form>,…": the form the calls of each attention layer
-            # kind take in this engine's programs (a kind with a window by
-            # its band against the kernel's block: call_form has the rule)
-            self.attention_form_by_kind = ",".join(
-                f"{kind}:{call_form(self.attention_form, window, horizon)}"
+            # kind take here (a kind with a window by call_form's rule)
+            paired = heads_in_pairs(widths, policy.attention_kv_heads)
+            self.attention_form_by_kind = ",".join(f"{kind}:" + call_form(
+                self.attention_form, window, horizon, paired)
                 for kind, window in self._attention_windows.items())
         # "kernel" | "xla", and what decided: the form of the policy's
         # next-token head (lm_blocks.score_next_tokens), by the head's own

@@ -209,21 +209,25 @@ def test_both_forms_and_both_dtypes_match_the_reference(ref, tiny, form,
             assert float(jnp.std(w)) > 0.3
 
 
-@pytest.mark.parametrize("kind, heads, window, kernels", [
-    (FULL, 4, 100, 1), (SLIDING, 6, 200, 1), (SLIDING, 6, 128, 1),
-    (SLIDING, 6, 100, 0)])
-def test_the_two_forms_agree_a_kind(ref, kind, heads, window, kernels,
-                                    tiny_widths):
+@pytest.mark.parametrize("kind, heads, window, length, kernels", [
+    (FULL, 4, 100, 384, 1), (SLIDING, 6, 200, 384, 1),
+    (SLIDING, 6, 128, 384, 1), (SLIDING, 6, 100, 384, 0),
+    (SLIDING, 6, 128, 512, 1), (SLIDING, 4, 256, 512, 1),
+    (SLIDING, 6, 192, 512, 0)])
+def test_the_two_forms_agree_a_kind(ref, kind, heads, window, length,
+                                    kernels, tiny_widths):
     """ONE layer of a kind over 384 positions (three of the kernel's blocks
     of 128), in the XLA form and inside a scope under the interpreter: a
     full layer takes the kernel whatever the band beside it, a sliding one
-    where its band spans a block (200 keys, 128) and not under 100; either
-    way the two forms' scores and behaviour agree and are the float32
-    reference's."""
+    where its band spans a block (200 keys, 128) and not under 100; over
+    512 positions (ONE block) a band of 128 or 256 keys is the block itself,
+    both key blocks in one grid step, and one of 192 stays in the XLA form;
+    either way the two forms' scores and behaviour agree and are the
+    float32 reference's."""
     built = _built(ref, layer_types=(kind,), mlp_layer_types=("sparse",),
                    num_attention_heads_per_layer=(heads,),
                    sliding_window=window, attention_block=64)
-    lm, tokens, c = built["lm"], _tokens(384, 11), 0.05
+    lm, tokens, c = built["lm"], _tokens(length, 11), 0.05
     want = ref.forward(built["s"], ref.Member(
         built["s"], built["theta"], built["noise"], c), tokens, head_block=8)
     factors = built["spec"].unpack(built["noise"])
@@ -236,8 +240,11 @@ def test_the_two_forms_agree_a_kind(ref, kind, heads, window, kernels,
         calls = pallas_calls(forward, built["params"], factors)
         got = forward(built["params"], factors)
     assert len(calls) == kernels
-    assert call_form("kernel", lm._band(kind), 384) == (
+    assert call_form("kernel", lm._band(kind), length) == (
         "kernel" if kernels else "xla")
+    # the band as the block: no key axis on the grid
+    assert [len(call.params["grid_mapping"].grid) for call in calls] == (
+        [2 if kind == SLIDING and length == 512 else 3] * kernels)
     for g, x, w in zip(got[:2], xla[:2], want):
         np.testing.assert_allclose(g, x, atol=TOL, rtol=0)
         np.testing.assert_allclose(g, w, atol=TOL, rtol=0)
@@ -954,18 +961,22 @@ class TestThroughTheShardedEngine:
 
     @pytest.mark.parametrize("dtype, tol", [("float32", 1e-4),
                                             ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("length, band, sliding, kernels", [
+        (32, 6, "xla", 2), (512, 128, "kernel", 4)])
     def test_forced_kernel_runs_the_generation_the_xla_form_runs(
-            self, devices8, kernel_attention, dtype, tol):
+            self, devices8, kernel_attention, dtype, tol, length, band,
+            sliding, kernels):
         """The generation program on one device, the engine's scope open
         around its trace: the TWO full layers take the kernel, the two
         sliding layers stay in the XLA form under a band of 6 keys over 32
-        positions; the gauge and the manifest say which kind took which,
-        and the members' fitness is the XLA form's to the order of float32
-        sums."""
+        positions and take it, the band as the block, under 128 keys over
+        512; the gauge and the manifest say which kind took which, and the
+        members' fitness is the XLA form's to the order of float32 sums."""
         from estorch_tpu.envs import TokenScoreEnv
 
-        wide = {**TINY, "attention_block": 16}
-        env = {"env": TokenScoreEnv(**{**tiny_model.ENV, "seq_len": 32})}
+        wide = {**TINY, "attention_block": min(length // 2, 64),
+                "sliding_window": band}
+        env = {"env": TokenScoreEnv(**{**tiny_model.ENV, "seq_len": length})}
         ref_es = _es(devices8[:1], 1, compute_dtype=dtype,
                      policy_kwargs=wide, agent_kwargs=env)
         with kernel_attention():
@@ -975,12 +986,12 @@ class TestThroughTheShardedEngine:
                 kern.engine.attention_form) == ("xla", "kernel")
         assert ref_es.engine.attention_form_by_kind == "sliding:xla,full:xla"
         assert kern.engine.attention_form_by_kind == (
-            "sliding:xla,full:kernel")
+            f"sliding:{sliding},full:kernel")
         assert kern.run_manifest()["config"][
-            "attention_form_by_kind"] == "sliding:xla,full:kernel"
+            "attention_form_by_kind"] == f"sliding:{sliding},full:kernel"
         assert [len(pallas_calls(es.engine._generation_step, es.state,
                                  es.table.data))
-                for es in (ref_es, kern)] == [0, 2]
+                for es in (ref_es, kern)] == [0, kernels]
         ref_es.state, want = ref_es.engine.generation_step(ref_es.state)
         kern.state, got = kern.engine.generation_step(kern.state)
         np.testing.assert_allclose(got["fitness"], want["fitness"], atol=tol)

@@ -20,8 +20,9 @@ import moe_tiny
 from estorch_tpu.models import HybridLM, LoopedLM, MoELM, lm_blocks
 from estorch_tpu.ops import pallas_attention
 from estorch_tpu.ops.pallas_attention import (attention_form,
-                                              attention_form_why, call_form,
-                                              causal_attention, kernel_block,
+                                              attention_form_why, band_block,
+                                              call_form, causal_attention,
+                                              heads_in_pairs, kernel_block,
                                               kernel_scope, scoped_interpret)
 
 # the models here are tiny (heads of 8, sequences of 16): inside a
@@ -106,16 +107,16 @@ def _selection(t, topk, seed=0):
 
 
 def _plain(q, k, v, nq, nkv, scale, paired=False, selected=None,
-           window=None):
+           window=None, value=HD):
     """Full masked softmax per head, float32 ``highest``; under a
     ``selected [T, T]`` the keys it marks alone, under a ``window`` the
-    keys ``(t - window, t]``."""
+    keys ``(t - window, t]``; values ``value`` wide."""
     if paired:
         return _plain_pairs(q, k, v, nq, nkv, scale)
     t, f32, hi = q.shape[0], jnp.float32, "highest"
     qh = q.astype(f32).reshape(t, nq, HD)
     kh = jnp.repeat(k.astype(f32).reshape(t, nkv, HD), nq // nkv, axis=1)
-    vh = jnp.repeat(v.astype(f32).reshape(t, nkv, HD), nq // nkv, axis=1)
+    vh = jnp.repeat(v.astype(f32).reshape(t, nkv, value), nq // nkv, axis=1)
     s = jnp.einsum("qhd,khd->hqk", qh, kh, precision=hi) * scale
     seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
     if selected is not None:
@@ -125,7 +126,7 @@ def _plain(q, k, v, nq, nkv, scale, paired=False, selected=None,
                        - window)
     s = jnp.where(seen, s, -jnp.inf)
     return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), vh,
-                      precision=hi).reshape(t, nq * HD)
+                      precision=hi).reshape(t, nq * value)
 
 
 def _through_lm_blocks(q, k, v, nq, nkv, scale, block):
@@ -539,10 +540,15 @@ class TestKernelAgainstBothForms:
 # (the cell's geometry: 4 blocks of band, five key blocks a query block);
 # a band that is no multiple of the block; narrower than a block; ONE key;
 # the whole sequence and more (plain causal); unequal blocks either way; a
-# band of one block and a half over blocks of two widths
+# band of one block and a half over blocks of two widths; and, with NO
+# blocks given, a band narrower than the kernel's block that becomes the
+# block itself, both key blocks in one grid step (``band_block``): half and
+# a quarter of a block of 512 (the second a band of ONE 128-row block), a
+# quarter of 1,024
 BANDS = [(64, 32, 8, 8), (64, 20, 8, 8), (64, 3, 8, 8), (64, 1, 8, 8),
          (64, 64, 8, 8), (64, 100, 8, 8), (64, 24, 16, 8), (64, 24, 8, 16),
-         (64, 17, 16, 16), (96, 40, 32, 16)]
+         (64, 17, 16, 16), (96, 40, 32, 16), (512, 256, None, None),
+         (512, 128, None, None), (1024, 256, None, None)]
 
 
 def _core_in_blocks(q, k, v, nq, nkv, scale, block, window, paired=False):
@@ -573,13 +579,15 @@ class TestTheBand:
         got = _kernel(q, k, v, nq, nkv, 0.3, block_q, block_k, window=window)
         assert got.shape == (t, nq * HD) and got.dtype == q.dtype
         assert np.isfinite(_f32(got)).all()
+        assert (band_block(window, t) == window) == (block_q is None)
         tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
         np.testing.assert_allclose(
             _f32(got), _f32(_plain(q, k, v, nq, nkv, 0.3, window=window)),
             atol=tol)
         np.testing.assert_allclose(
             _f32(got),
-            _f32(_core_in_blocks(q, k, v, nq, nkv, 0.3, 8, window)),
+            _f32(_core_in_blocks(q, k, v, nq, nkv, 0.3, 8 if t < 128 else 64,
+                                 window)),
             atol=tol)
         full = _kernel(q, k, v, nq, nkv, 0.3, block_q, block_k)
         if window >= t:
@@ -611,6 +619,116 @@ class TestTheBand:
         np.testing.assert_allclose(
             got, _f32(_plain(q, k, v, nq, nkv, 1.0, window=window)),
             atol=2e-4 if dtype == jnp.float32 else 0.1)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("nq, nkv, value", [(8, 1, HD), (6, 1, HD),
+                                                (8, 2, 2 * HD), (4, 4, 24)])
+    @pytest.mark.parametrize("t, window", [(512, 256), (256, 128),
+                                           (1024, 256)])
+    def test_a_band_narrower_than_the_block_is_the_block_in_one_step(
+            self, t, window, nq, nkv, value, dtype):
+        """The band as the block, the previous and the own key block folded
+        in ONE grid step (no key axis, nothing carried): groups of eight and
+        of six query heads a key head, values wider than the heads; query
+        block 0 has no previous block, its rows the plain causal ones;
+        against the dense float32 softmax, the XLA form of the core and the
+        shipped grid called with the band as its blocks."""
+        from pallas_costs import pallas_calls
+
+        ks = jax.random.split(jax.random.PRNGKey(t + window + nq), 3)
+        q, k, v = (jax.random.normal(key, (t, n * w)).astype(dtype)
+                   for key, n, w in zip(ks, (nq, nkv, nkv), (HD, HD, value)))
+
+        def call(q, k, v, block=None):
+            return causal_attention(
+                q, k, v, num_heads=nq, num_kv_heads=nkv, head_dim=HD,
+                value_dim=value, scale=0.3, interpret=True, window=window,
+                block_q=block, block_k=block)
+
+        folded, = pallas_calls(call, q, k, v)
+        # a key head's whole group of these tiny heads shares a step
+        assert folded.params["grid_mapping"].grid == (nkv, t // window)
+        got = _f32(call(q, k, v))
+        assert got.shape == (t, nq * value) and np.isfinite(got).all()
+        tol = F32_TOL if dtype == jnp.float32 else BF16_TOL
+        np.testing.assert_allclose(got, _f32(_plain(
+            q, k, v, nq, nkv, 0.3, window=window, value=value)), atol=tol)
+        np.testing.assert_allclose(got, _f32(lm_blocks.attention_core(
+            q.reshape(t, nq, HD), k.reshape(t, nkv, HD),
+            v.reshape(t, nkv, value), num_heads=nq, num_kv_heads=nkv,
+            scale=0.3, block=64, window=window)), atol=tol)
+        np.testing.assert_allclose(got, _f32(call(q, k, v, window)), atol=tol)
+        # block 0: no key before the sequence, whatever the band
+        np.testing.assert_allclose(got[:window], _f32(causal_attention(
+            q[:window], k[:window], v[:window], num_heads=nq,
+            num_kv_heads=nkv, head_dim=HD, value_dim=value, scale=0.3,
+            interpret=True))[:window], atol=tol)
+
+    @pytest.mark.parametrize("group, block, width, itemsize, heads", [
+        # the cell: all eight of a bfloat16 group, half of a float32 one
+        (8, 512, 128, 2, 8), (8, 512, 128, 4, 4), (6, 512, 128, 2, 6),
+        (6, 512, 128, 4, 3), (7, 512, 128, 4, 1), (8, 128, 128, 4, 8),
+        (8, 512, 256, 2, 4), (1, 512, 128, 2, 1), (5, 1024, 256, 4, 1)])
+    def test_the_heads_a_step_of_a_narrow_band(self, group, block, width,
+                                               itemsize, heads):
+        """As many of a key-value group's query heads as keep q's block
+        within a MiB, a divisor of the group."""
+        assert pallas_attention.band_heads(group, block, width,
+                                           itemsize) == heads
+        assert block * heads * width * itemsize <= max(
+            pallas_attention.BAND_Q_BYTES, block * width * itemsize)
+
+    def test_heads_that_share_a_step_are_the_heads_alone(self, monkeypatch):
+        """A step of two heads of a group of four and a step of one give
+        the same context to the last bit: the heads of a step are unrolled
+        bodies that share nothing but the key and value blocks."""
+        q, k, v = _qkv(512, 8, 2, jnp.float32, seed=3)
+        wide = _f32(_kernel(q, k, v, 8, 2, 0.3, None, window=128))
+        jax.clear_caches()
+        monkeypatch.setattr(pallas_attention, "BAND_Q_BYTES", 128 * 2 * HD * 4)
+        from pallas_costs import pallas_calls
+
+        call, = pallas_calls(lambda q, k, v: _kernel(
+            q, k, v, 8, 2, 0.3, None, window=128), q, k, v)
+        assert call.params["grid_mapping"].grid == (4, 4)
+        np.testing.assert_array_equal(
+            _f32(_kernel(q, k, v, 8, 2, 0.3, None, window=128)), wide)
+        jax.clear_caches()
+
+    @pytest.mark.parametrize("case", ["paired", "not a divisor", "not rows",
+                                      "shared", "beside"])
+    def test_a_narrow_band_the_fold_refuses_keeps_the_grid(self, case):
+        """``band_block`` turns away heads in pairs, a band that does not
+        divide the sequence and one that is no whole 128-row blocks
+        (``call_form`` then says the XLA form); a call that reaches the
+        kernel all the same, or one with a shared part or values beside
+        their keys, runs the grid with its key axis, as before."""
+        from pallas_costs import pallas_calls
+
+        t, nh = 512, 4
+        window = {"not a divisor": 384, "not rows": 192}.get(case, 128)
+        paired = case == "paired"
+        assert (band_block(window, t, paired) is None) == (
+            case in ("paired", "not a divisor", "not rows"))
+        assert call_form("kernel", window, t, paired) == (
+            "xla" if band_block(window, t, paired) is None else "kernel")
+        q, k, v, qs, ks = _parts(t, nh, HD, 8 if case == "shared" else 0,
+                                 2 * HD if paired else HD, jnp.float32, seed=2)
+        if paired:
+            v = v[:, :nh // 2 * 2 * HD]
+        if case == "beside":
+            k, v = jnp.concatenate([k.reshape(t, nh, HD), v.reshape(
+                t, nh, HD)], -1).reshape(t, -1), None
+
+        def call(q, k, v, qs, ks):
+            return causal_attention(
+                q, k, v, qs, ks, num_heads=nh, num_kv_heads=nh, head_dim=HD,
+                value_dim=2 * HD if paired else HD, scale=0.3,
+                interpret=True, window=window, paired=paired)
+
+        one, = pallas_calls(call, q, k, v, qs, ks)
+        assert len(one.params["grid_mapping"].grid) == 3
+        assert np.isfinite(_f32(call(q, k, v, qs, ks))).all()
 
     @pytest.mark.parametrize("window", [5, 8, 20, 32])
     @pytest.mark.parametrize("group", [1, 2])
@@ -685,6 +803,9 @@ class TestTheBand:
         assert grid(64) == grid(1000) == (4, 8, 8)
         assert grid(24, 16, 8) == (4, 4, 5)
         assert grid(24, 8, 16) == (4, 8, 3)
+        # no blocks given: the sequence is the block (the interpreter's),
+        # a band of 16 no whole 128-row blocks: ONE step of a key axis
+        assert grid(16, None, None) == (4, 1, 1)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_the_core_takes_the_kernel_where_the_band_spans_a_block(
@@ -714,6 +835,36 @@ class TestTheBand:
             atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
         assert traced(100, True)[0] == traced(100, False)[0]
         assert traced(384, True)[0] == traced(None, True)[0]
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_the_core_takes_the_kernel_where_the_band_can_be_the_block(
+            self, dtype):
+        """512 positions are ONE of the kernel's blocks: inside a scope a
+        call under a band of 128 keys is ONE ``pallas_call`` (the band as
+        its block) and gives what the XLA form gives; under 192
+        keys, and with the heads in pairs, it traces the XLA form's
+        equations."""
+        t, nq, nkv = 512, 4, 2
+        q, k, v = _qkv(t, nq, nkv, dtype, seed=12)
+
+        def traced(window, scoped, paired=False):
+            def core(q, k, v):   # a new closure a trace: jit caches by it
+                return _core_in_blocks(q, k, v, nq, nkv, 0.3, 64, window,
+                                       paired)
+
+            if not scoped:
+                return str(jax.make_jaxpr(core)(q, k, v)), core(q, k, v)
+            with kernel_scope(interpret=True):
+                return str(jax.make_jaxpr(core)(q, k, v)), core(q, k, v)
+
+        (inside, got), (outside, want) = traced(128, True), traced(128, False)
+        assert (inside.count("pallas_call["),
+                outside.count("pallas_call[")) == (1, 0)
+        np.testing.assert_allclose(
+            _f32(got), _f32(want),
+            atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
+        assert traced(192, True)[0] == traced(192, False)[0]
+        assert traced(128, True, True)[0] == traced(128, False, True)[0]
 
 
 class TestTheCallersScale:
@@ -823,28 +974,52 @@ class TestTheRule:
     def test_the_kernels_block(self, length, block):
         assert kernel_block(length) == block
 
-    @pytest.mark.parametrize("form, window, length, took", [
+    @pytest.mark.parametrize("form, window, length, paired, took", [
         # smallthinker-es-16k-1chip: a band of four of the kernel's blocks
-        ("kernel", 4096, 16384, "kernel"), ("kernel", None, 16384, "kernel"),
-        ("xla", 4096, 16384, "xla"), ("xla", None, 16384, "xla"),
-        # phi4-flash-es-8k-1chip: half a block of band
-        ("kernel", 512, 8192, "xla"), ("kernel", None, 8192, "kernel"),
+        ("kernel", 4096, 16384, False, "kernel"),
+        ("kernel", None, 16384, False, "kernel"),
+        ("xla", 4096, 16384, False, "xla"), ("xla", None, 16384, False, "xla"),
+        # phi4-flash-es-8k-1chip: half a block of band over heads in PAIRS
+        ("kernel", 512, 8192, True, "xla"),
+        ("kernel", None, 8192, True, "kernel"),
         # at one block it turns; by the block of THIS length
-        ("kernel", 1024, 8192, "kernel"), ("kernel", 1023, 8192, "xla"),
-        ("kernel", 128, 384, "kernel"), ("kernel", 127, 384, "xla"),
-        ("kernel", 512, 1536, "kernel"), ("kernel", 511, 1536, "xla"),
+        ("kernel", 1024, 8192, False, "kernel"),
+        ("kernel", 1023, 8192, False, "xla"),
+        ("kernel", 128, 384, False, "kernel"),
+        ("kernel", 127, 384, False, "xla"),
+        ("kernel", 512, 1536, False, "kernel"),
+        ("kernel", 511, 1536, False, "xla"),
         # the whole sequence or more is plain causal attention
-        ("kernel", 16384, 16384, "kernel"), ("kernel", 10 ** 6, 4096,
-                                             "kernel"),
+        ("kernel", 16384, 16384, False, "kernel"),
+        ("kernel", 10 ** 6, 4096, False, "kernel"),
         # a length the kernel has no block for (the interpreter's): the
         # sequence is the block
-        ("kernel", 6, 32, "xla"), ("kernel", 32, 32, "kernel"),
+        ("kernel", 6, 32, False, "xla"), ("kernel", 32, 32, False, "kernel"),
+        # laguna-xs2-es-16k-1chip: half a block of band over whole column
+        # blocks is the block itself; so is any narrower band of whole
+        # 128-lane rows that divides the sequence
+        ("kernel", 512, 16384, False, "kernel"),
+        ("xla", 512, 16384, False, "xla"),
+        ("kernel", 128, 16384, False, "kernel"),
+        ("kernel", 256, 1536, False, "kernel"),
+        ("kernel", 512, 8192, False, "kernel"),
+        ("kernel", 128, 512, False, "kernel"),
+        # and the narrow bands it turns away: pairs, a band that does not
+        # divide the sequence, one that is no whole 128-lane rows
+        ("kernel", 128, 512, True, "xla"), ("kernel", 256, 1024, True, "xla"),
+        ("kernel", 384, 1024, False, "xla"),
+        ("kernel", 640, 8192, False, "xla"),
+        ("kernel", 192, 1536, False, "xla"), ("kernel", 64, 512, False, "xla"),
+        ("kernel", 500, 16384, False, "xla"),
     ])
     def test_a_call_with_a_window_by_its_band_against_the_block(
-            self, form, window, length, took):
+            self, form, window, length, paired, took):
         """THE rule of a banded call: the kernel where it may be traced,
-        the shapes fit and the band spans at least one of its blocks."""
-        assert call_form(form, window, length) == took
+        the shapes fit and the band spans at least one of its blocks, or
+        is narrower and can be the block itself."""
+        assert call_form(form, window, length, paired) == took
+        assert (band_block(window, length, paired) is not None) <= (
+            call_form("kernel", window, length, paired) == "kernel")
 
     @pytest.mark.parametrize(
         "platform, widths, kv_heads, length, windows, by_kind, why", [
@@ -863,19 +1038,27 @@ class TestTheRule:
              {"window": 512, "full_kv": None, "cross": None},
              "window:xla,full_kv:xla,cross:xla",
              "the devices are 'cpu', not TPUs"),
+            # laguna-xs2-es-16k-1chip: half a block of band, whole heads
+            ("tpu", 128, 8, 16384, {"sliding": 512, "full": None},
+             "sliding:kernel,full:kernel",
+             "layers with a window of 512 in the kernel"),
+            ("cpu", 128, 8, 16384, {"sliding": 512, "full": None},
+             "sliding:xla,full:xla", "the devices are 'cpu', not TPUs"),
         ])
     def test_each_kind_of_layer_at_the_two_banded_cells_shapes(
             self, platform, widths, kv_heads, length, windows, by_kind, why):
         """What the engine's ``attention_form_by_kind`` is made of
         (``_resolve_kernel_forms``: the program's form, then
-        ``call_form`` a kind), at the published shapes of the two cells
+        ``call_form`` a kind), at the published shapes of the three cells
         whose models have a banded layer, on one TPU device and on a CPU
         mesh."""
         band = next(w for w in windows.values() if w is not None)
         form, reason = attention_form_why(platform, 1, widths, length, band,
                                           kv_heads)
         assert reason.endswith(why)
-        assert ",".join(f"{kind}:{call_form(form, window, length)}"
+        paired = heads_in_pairs(widths, kv_heads)
+        assert paired == (widths == (64, 0, 128))
+        assert ",".join(f"{kind}:{call_form(form, window, length, paired)}"
                         for kind, window in windows.items()) == by_kind
 
     def test_published_shapes_are_what_the_rows_say(self):
@@ -1092,6 +1275,33 @@ class TestTheDeclaredCost:
             64, 4, 2, HD, HD, 0, 8, 8, 4, window=32)
         # 8 blocks, a band of 4: 1 + 2 + 3 + 4 + 4 x 5 = 30 tiles of 36
         assert one.flops == 4 * 30 * 2 * 8 * 8 * (HD + HD)
+
+    def test_a_band_in_one_step_declares_its_two_tiles_a_block(self):
+        """The folded form's declaration is ``attention_cost`` at blocks
+        of the band: one tile for query block 0, two for every other."""
+        from pallas_costs import declared_costs
+
+        q, k, v = _qkv(512, 8, 1, jnp.bfloat16)
+
+        def call(q, k, v):
+            return _kernel(q, k, v, 8, 1, 0.3, None, window=128)
+
+        one, = declared_costs(call, q, k, v)
+        assert one == pallas_attention.attention_cost(
+            512, 8, 1, HD, HD, 0, 128, 128, 2, window=128)
+        assert one.flops == 8 * 7 * 2 * 128 * 128 * (HD + HD)
+
+    def test_sixty_three_tiles_of_512_under_the_narrow_cells_band(self):
+        """laguna-xs2-es-16k-1chip: 32 blocks of 512 under a band of 512:
+        63 tiles a head, 16.5 M pairs multiplied for the 8,257,792 the band
+        holds (``benchmark/costs_swg``), 541 GFLOP a member and layer."""
+        cost = pallas_attention.attention_cost(
+            16384, 64, 8, 128, 128, 0, 512, 512, 2, window=512)
+        assert cost.flops / (64 * 2 * 512 * 512 * 256) == 63
+        assert cost.flops == 541165879296
+        visible = sum(min(t + 1, 512) for t in range(16384))
+        assert visible == 8_257_792
+        assert visible / (63 * 512 * 512) == pytest.approx(0.50, abs=1e-3)
 
     def test_a_call_without_a_window_traces_the_kernel_it_traced(self):
         """The band is a branch at TRACE time: without a window (and under

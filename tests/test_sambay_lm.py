@@ -23,7 +23,7 @@ from estorch_tpu.ops.lowrank import (lowrank_tree_noise,
                                      make_lowrank_tree_spec)
 from estorch_tpu.ops.pallas_attention import (attention_form,
                                               attention_form_why, call_form,
-                                              kernel_scope)
+                                              heads_in_pairs, kernel_scope)
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
                                        unmatched_leaves)
 
@@ -383,10 +383,16 @@ def test_the_attention_rule_takes_pairs_and_leaves_the_band_to_the_call(
                               kv_heads)[1].startswith("the devices are 'cpu'")
     assert "4 devices" in attention_form_why("tpu", 4, widths, length,
                                              window, kv_heads)[1]
-    # which form a CALL takes in a program of that form
-    assert call_form(form, window, length) == (
-        "kernel" if form == "kernel" and window is None else "xla")
-    assert call_form(form, None, length) == form
+    # which form a CALL takes in a program of that form: half a block of
+    # band is the block itself over whole column blocks, never over pairs
+    paired = heads_in_pairs(widths, kv_heads)
+    assert paired == (widths == (64, 0, 128) and (kv_heads or 1) % 2 == 0)
+    assert call_form(form, window, length, paired) == (
+        "kernel" if form == "kernel" and (window is None or not paired)
+        else "xla")
+    assert ("in the kernel" in reason) == (
+        form == "kernel" and window is not None and not paired)
+    assert call_form(form, None, length, paired) == form
 
 
 # ------------------------------------------- (d) differential attention

@@ -1525,6 +1525,43 @@ def test_attention_kernel_compiles_for_the_v5e_in_groups_of_seven(
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
+def test_attention_kernel_compiles_for_the_v5e_under_half_a_block_of_band(
+        dtype, v5e_chip):
+    """Mosaic accepts the attention kernel at the sliding layers of
+    ``laguna-xs2-es-16k-1chip``, PUBLISHED shapes no other cell has: 64
+    query heads over 8 key-value heads of 128 (q ``[16384, 8192]``, k and v
+    ``[16384, 1024]``) under a band of 512 keys, half of the kernel's block
+    at 16,384 positions, which ``band_block`` makes the block itself: a
+    grid of (64 heads, 32 query blocks) with no key axis, the previous and
+    the own key block both in the step, inside the default scoped VMEM (the
+    compile is the check); no float32 score array and no copy beside it in
+    the program, one member at a time as the cell evaluates them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import (band_block, call_form,
+                                                  causal_attention, fits)
+
+    assert fits(128, 0, 128, None, 16384)
+    assert band_block(512, 16384) == 512
+    assert call_form("kernel", 512, 16384) == "kernel"
+
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (1, 1, 16384, width), dtype,
+            sharding=SingleDeviceSharding(v5e_chip))
+
+    compiled = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
+        q, k, v, num_heads=64, num_kv_heads=8, head_dim=128,
+        scale=128 ** -0.5, interpret=False, window=512)))).lower(
+            operand(8192), operand(1024), operand(1024)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " copy(" not in text.split("ENTRY")[1]
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
 def test_attention_kernel_compiles_for_the_v5e_at_heads_of_256(dtype,
                                                                v5e_chip):
     """Mosaic accepts the attention kernel at ``qwen3next-es-16k-1chip``'s
@@ -1554,7 +1591,8 @@ def test_attention_kernel_compiles_for_the_v5e_at_heads_of_256(dtype,
 
 @pytest.mark.parametrize("window, by_kind, parts", [
     (512, "window:kernel,global:kernel", ["global", "window", "window"]),
-    (128, "window:xla,global:kernel", ["global"])])
+    (128, "window:kernel,global:kernel", ["global", "window", "window"]),
+    (192, "window:xla,global:kernel", ["global"])])
 def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
                                                           by_kind, parts):
     """A small decoder with a global and two window layers on a one-device
@@ -1564,8 +1602,10 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
     the compiled generation program holds THREE Mosaic calls
     ``causal_attention`` under es.attn inside es.policy, the global
     layer's in the part ``of.global`` and the window layers' in
-    ``of.window``; under 128 keys only the global layer's, and the window
-    layers' float32 scores are XLA's: the device trace books them to
+    ``of.window``, and under 128 keys too (a quarter of a block, whole
+    128-lane rows that divide the sequence: the band is the block, both key
+    blocks in one grid step); under 192 keys only the global layer's, and
+    the window layers' float32 scores are XLA's: the device trace books them to
     ``swa.global_attn_share`` and ``swa.window_attn_share`` either way.
     The head takes its kernel beside them."""
     from estorch_tpu.envs import TokenScoreEnv
@@ -1601,7 +1641,7 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
     assert engine.attention_form_by_kind == by_kind
     assert engine.attention_form_why.endswith(
         f"layers with a window of {window} in the "
-        + ("kernel" if window == 512 else "XLA form"))
+        + ("XLA form" if window == 192 else "kernel"))
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -1627,7 +1667,7 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
     banded = [n for line in text.splitlines() if "f32[" in line
               for n in re.findall(r'op_name="([^"]*)"', line)
               if PART.findall(n) == ["window"] and ATTN in SCOPE.findall(n)]
-    assert bool(banded) == (window == 128)
+    assert bool(banded) == (window == 192)
 
 
 @pytest.mark.parametrize("use", ["context", "decorator"])
