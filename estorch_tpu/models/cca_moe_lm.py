@@ -74,11 +74,33 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import HEAD, MIX, ROPE, part, stage
+from ..ops import pallas_attention, pallas_combine, pallas_head
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
-from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+from .perturbed import (F32, MODEL_AXIS, PolicyDeclaration, perturbed_dense,
                         perturbed_embed, perturbed_leaf)
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis: the decoder's frame and the expert layer (models/lm_blocks.py: q, k,
+# v, o, the embedding, the stacked experts, the selection bias), and the
+# latent's own.  The head-mixing convolution's STACKED ``[taps · heads, d,
+# d]`` matrices shard their (tap, head) axis, as the experts shard theirs;
+# the depthwise taps, both convolutions' biases and the temperatures are a
+# few thousand values that every head's slice reads and replicate.  The
+# router (a down-projection into a norm over its whole width, a state's
+# scale, a three-matrix MLP 256 wide) replicates, as the one-matrix routers
+# do: every device routes every token.
+PARTITION_RULES = (
+    lm_blocks.DECODER_PARTITION_RULES + lm_blocks.EXPERT_PARTITION_RULES + (
+        (r"attn/conv_head$", P(MODEL_AXIS, None, None)),
+        (r"attn/(conv_time|conv_time_bias|conv_head_bias|temperature)$", P()),
+        (r"moe/(router_down|router_down_bias|router_state)$", P()),
+        (r"moe/router_norm/scale$", P()),
+        (r"moe/router_mlp/[wb][123]$", P()),
+    ))
 
 CCA_LAYER = "hybrid"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -243,16 +265,18 @@ class CCAMoELM:
         rows = (self.num_experts_per_tok * lm_blocks.EXPERT_CAPACITY_MARGIN
                 / self.expert_group_size)
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # heads scored and summed at one width; one kind of
+                # attention layer, full causal
+                (pallas_attention.attention_facts,
+                 (self.head_dim, self.num_key_value_heads)),
+                (pallas_head.head_facts, (self.hidden_size,)),
+                # the token rows the expert layer's combine adds into
+                (pallas_combine.combine_facts, (self.hidden_size,))),
             leaf_rows_per_token=dict.fromkeys(self.expert_leaves, rows),
             stacked_leaves=self.stacked_leaves,
             float32_leaves=self.float32_leaves,
-            # heads scored and summed at one width; one kind of attention
-            # layer, full causal
-            attention_widths=self.head_dim,
-            attention_kv_heads=self.num_key_value_heads,
-            head_width=self.hidden_size,
-            # the token rows the expert layer's combine adds into
-            combine_width=self.hidden_size,
             # after what the env scores: the pairs per held expert
             outputs=("expert_load",),
             facts={"experts_held": self.num_experts,

@@ -55,8 +55,8 @@ precision, in two forms of the same terms: the kernels of
 ops/pallas_delta.py (a chunk's system and the carried state in VMEM, ``q``,
 ``k``, ``v``, ``o`` left ``[T, heads · dim]``) inside an engine's
 ``pallas_attention.kernel_scope`` where the shapes fit (``pallas_delta.fits``:
-heads of whole 128-lane blocks, a chunk of 16 to 128; which at build,
-``ShardedESEngine.delta_form`` from the ``delta_widths`` the model states),
+heads of whole 128-lane blocks, a chunk of 16 to 128; which is in the run's
+records, ``delta_form``, by ``pallas_delta.delta_facts`` in ``declaration()``),
 and an XLA form (batched products over head-major chunks, ``lax.scan`` over
 the chunks) everywhere else: the CPU, a call outside a scope, other shapes.
 Nothing of ``HybridLM._ssd`` is shared but the idea of a chunk, whose ``C Bᵀ
@@ -92,12 +92,38 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import ATTN, DENSE, HEAD, ROPE, SSM, part, stage
-from ..ops import pallas_attention, pallas_delta
+from ..ops import (pallas_attention, pallas_combine, pallas_delta,
+                   pallas_head)
 from . import lm_blocks
 from .lm_blocks import layer_name, subtree, zero_centred_rmsnorm
-from .perturbed import (F32, PolicyDeclaration, leaf_columns, perturbed_dense,
-                        perturbed_embed, perturbed_leaf)
+from .perturbed import (F32, MODEL_AXIS, PolicyDeclaration, leaf_columns,
+                        perturbed_dense, perturbed_embed, perturbed_leaf)
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis: the decoder's frame and the expert layer (models/lm_blocks.py: k, v,
+# o, norms, router, shared and stacked experts, and the full layers' ``q``,
+# each head's query and gate side by side), and the linear mixer's own.  Its
+# fused ``[q | k | v | z]`` projection and the conv over ``[q | k | v]`` go
+# by column (a layout, not a cut by head: GSPMD moves what the split into
+# parts needs), closed by the row-parallel ``out_proj``; ``A_log`` and
+# ``dt_bias`` by value head; the narrow ``[b | a]`` projection, the gated
+# norm's one head of weights, the per-head norms of q and k and the shared
+# expert's one-column gate replicate.  ``A_log``, ``dt_bias`` and
+# ``norm_scale`` are named HERE because the general rules would cut them by
+# their suffixes.
+PARTITION_RULES = (
+    lm_blocks.DECODER_PARTITION_RULES + lm_blocks.EXPERT_PARTITION_RULES + (
+        (r"(q_norm|k_norm)/scale$", P()),
+        (r"delta/in_proj_qkvz$", P(None, MODEL_AXIS)),
+        (r"delta/conv$", P(None, None, MODEL_AXIS)),
+        (r"delta/(A_log|dt_bias)$", P(MODEL_AXIS)),
+        (r"delta/(in_proj_ba|norm_scale)$", P()),
+        (r"delta/out_proj$", P(MODEL_AXIS, None)),
+        (r"moe/shared_gate$", P()),
+    ))
 
 LINEAR_LAYER, FULL_LAYER = "linear", "full"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -413,18 +439,23 @@ class DeltaMoELM:
                 / self.expert_group_size)
         full = FULL_LAYER in self.layer_types
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # the full layers' heads, scored and summed at one width
+                *([(pallas_attention.attention_facts,
+                    (self.head_dim, self.num_key_value_heads))]
+                  if full else []),
+                (pallas_head.head_facts, (self.hidden_size,)),
+                (pallas_combine.combine_facts, (self.hidden_size,)),
+                # a key head's width, a value head's, the chunk
+                *([(pallas_delta.delta_facts,
+                    (self.linear_key_head_dim, self.linear_value_head_dim,
+                     self.delta_chunk))]
+                  if LINEAR_LAYER in self.layer_types else [])),
             leaf_rows={"head/kernel": self.head_block},
             leaf_rows_per_token=dict.fromkeys(self.stacked_leaves, rows),
             stacked_leaves=self.stacked_leaves,
             float32_leaves=self.float32_leaves,
-            # the full layers' heads, scored and summed at one width
-            attention_widths=self.head_dim if full else None,
-            attention_kv_heads=self.num_key_value_heads if full else None,
-            head_width=self.hidden_size,
-            combine_width=self.hidden_size,
-            delta_widths=(self.linear_key_head_dim,
-                          self.linear_value_head_dim, self.delta_chunk)
-            if LINEAR_LAYER in self.layer_types else None,
             outputs=("expert_load",),
             facts={"experts_held": self.num_experts,
                    "experts_total": self.experts_total,

@@ -41,9 +41,9 @@ The leaves of a layer differ in SHAPE by its kind: ``W_q [hidden, n_l · d]``,
 ``W_o [n_l · d, hidden]`` and ``W_g [hidden, n_l]`` are as wide as the
 layer's own head count, so the tree is not a stack of one layer's shapes.
 One table of rotations a KIND (two ``rope_theta``, two rotated widths, one
-of them scaled) is made once a sequence.  The engine learns each kind's band
-from ``declaration()`` (``attention_windows``) and says which form each took
-(``attention_form_by_kind``: the published band, half of the kernel's block,
+of them scaled) is made once a sequence.  ``declaration()`` names each
+kind's band with the attention's rule, and the run's records say which form
+each took (``attention_form_by_kind``: the published band, half of the kernel's block,
 is the block itself there, ``pallas_attention.call_form``).  The gate is the
 head-wise one of arXiv 2505.06708: ``W_g`` is ``n_l`` columns, not a second
 query-sized projection.  The router scores by sigmoid and builds NO selection
@@ -80,15 +80,28 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import ATTN, DENSE, HEAD, ROPE, part, stage
+from ..ops import pallas_attention, pallas_combine, pallas_head
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
 from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
                         perturbed_embed, perturbed_leaf)
 
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis: the decoder's frame and the expert layer (models/lm_blocks.py: q, k,
+# v, o as wide as the layer's own heads, norms, dense FFN, router, shared
+# and stacked experts), and the gate's projection, one column a head, 48 or
+# 64 of them: it replicates, as the other narrow projections do.
+PARTITION_RULES = (
+    lm_blocks.DECODER_PARTITION_RULES + lm_blocks.EXPERT_PARTITION_RULES + (
+        (r"attn/head_gate$", P()),
+    ))
+
 FULL_LAYER, SLIDING_LAYER = "full_attention", "sliding_attention"
 # what a kind is called in a trace (``of.<kind>`` under es.attn) and in the
-# engine's ``attention_form_by_kind``
+# records' ``attention_form_by_kind``
 KIND = {SLIDING_LAYER: "sliding", FULL_LAYER: "full"}
 DENSE_MLP, SPARSE_MLP = "dense", "sparse"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -278,20 +291,22 @@ class GatedWindowMoELM:
         bands = {SLIDING_LAYER: self.sliding_window, FULL_LAYER: None}
         count = self.layer_types.count
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # heads scored and summed at one width in both kinds; each
+                # kind of attention layer the stack holds, with its band
+                (pallas_attention.attention_facts,
+                 (self.head_dim, self.num_key_value_heads,
+                  tuple((KIND[kind], band) for kind, band in bands.items()
+                        if kind in self.layer_types))),
+                (pallas_head.head_facts, (self.hidden_size,)),
+                # the token rows the expert layer's combine adds into
+                (pallas_combine.combine_facts, (self.hidden_size,))),
             # the head runs in blocks of ``head_block`` positions
             leaf_rows={"head/kernel": self.head_block},
             leaf_rows_per_token=dict.fromkeys(self.stacked_leaves, rows),
             stacked_leaves=self.stacked_leaves,
             float32_leaves=self.float32_leaves,
-            # heads scored and summed at one width in both kinds; each kind
-            # of attention layer the stack holds, with its band
-            attention_widths=self.head_dim,
-            attention_windows={KIND[kind]: band for kind, band in bands.items()
-                               if kind in self.layer_types},
-            attention_kv_heads=self.num_key_value_heads,
-            head_width=self.hidden_size,
-            # the token rows the expert layer's combine adds into
-            combine_width=self.hidden_size,
             # after what the env scores: the pairs per held expert
             outputs=("expert_load",),
             # the sparse-expert facts under MoELM's names (no MTP module),

@@ -54,14 +54,31 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import HEAD, SSM, part, stage
+from ..ops import pallas_attention, pallas_head
 from . import lm_blocks
 from .lm_blocks import (causal_conv as _causal_conv, layer_name,
                         rmsnorm as _rmsnorm, subtree)
-from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+from .perturbed import (F32, MODEL_AXIS, PolicyDeclaration, perturbed_dense,
                         perturbed_embed, perturbed_leaf)
 
 MAMBA, ATTENTION = "mamba", "attention"
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis.  The Mamba-2 mixer's projections are a column- then row-parallel
+# pair (in_z/in_x/in_dt -> out_proj), so one all-reduce closes it; its
+# heads, their conv channels, dt, A_log, D and the gated norm go by head; B
+# and C (one group, read by every head) replicate.  The rest is the
+# decoder's frame (models/lm_blocks.py).
+PARTITION_RULES = (
+    (r"mamba/(in_z|in_x|in_dt)$", P(None, MODEL_AXIS)),
+    (r"mamba/conv_x_kernel$", P(None, None, MODEL_AXIS)),
+    (r"mamba/(conv_x_bias|A_log|D|dt_bias|norm_scale)$", P(MODEL_AXIS)),
+    (r"mamba/(in_bc|conv_bc_kernel|conv_bc_bias)$", P()),
+    (r"mamba/out_proj$", P(MODEL_AXIS, None)),
+) + lm_blocks.DECODER_PARTITION_RULES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,11 +135,13 @@ class HybridLM:
         """What the engine that runs this model and the run's records read
         of it, stated once (models/perturbed.py::PolicyDeclaration)."""
         return PolicyDeclaration(
-            # heads of ONE width, scored and summed
-            attention_widths=self.head_dim,
-            attention_kv_heads=self.num_key_value_heads,
-            # the width the next-token head contracts
-            head_width=self.hidden_size)
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # heads of ONE width, scored and summed
+                (pallas_attention.attention_facts,
+                 (self.head_dim, self.num_key_value_heads)),
+                # the width the next-token head contracts
+                (pallas_head.head_facts, (self.hidden_size,))))
 
     def param_shapes(self) -> dict:
         """The parameter tree as shapes (float32)."""

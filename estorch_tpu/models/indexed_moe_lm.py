@@ -71,11 +71,28 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import ATTN, HEAD, INDEX, ROPE, part, stage
+from ..ops import pallas_attention, pallas_combine, pallas_head
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
-from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+from .perturbed import (F32, MODEL_AXIS, PolicyDeclaration, perturbed_dense,
                         perturbed_embed, perturbed_leaf)
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis: the decoder's frame and the expert layer (models/lm_blocks.py), and
+# the indexer's own.  Its query projection goes by index head
+# (column-parallel); its ONE key head, that key's LayerNorm and the per-head
+# weights ``index_w`` (16 columns) are read whole by every index head and
+# replicate, as the per-head norms of q and k do.
+PARTITION_RULES = (
+    lm_blocks.DECODER_PARTITION_RULES + lm_blocks.EXPERT_PARTITION_RULES + (
+        (r"(q_norm|k_norm)/scale$", P()),
+        (r"indexer/index_q$", P(None, MODEL_AXIS)),
+        (r"indexer/(index_k|index_w)$", P()),
+        (r"indexer/index_norm/(scale|bias)$", P()),
+    ))
 
 MOE_LAYER = "moe"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -222,19 +239,21 @@ class IndexedMoELM:
         rows = (self.num_experts_per_tok * lm_blocks.EXPERT_CAPACITY_MARGIN
                 / self.expert_group_size)
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # heads scored and summed at one width; one kind of
+                # attention layer, over a selection of keys, no band
+                (pallas_attention.attention_facts,
+                 (self.head_dim, self.num_key_value_heads,
+                  (("selected", None),))),
+                (pallas_head.head_facts, (self.hidden_size,)),
+                # the token rows the expert layer's combine adds into
+                (pallas_combine.combine_facts, (self.hidden_size,))),
             # the head runs in blocks of ``head_block`` positions
             leaf_rows={"head/kernel": self.head_block},
             leaf_rows_per_token=dict.fromkeys(self.stacked_leaves, rows),
             stacked_leaves=self.stacked_leaves,
             float32_leaves=self.float32_leaves,
-            # heads scored and summed at one width; one kind of attention
-            # layer, over a selection of keys, no band
-            attention_widths=self.head_dim,
-            attention_windows={"selected": None},
-            attention_kv_heads=self.num_key_value_heads,
-            head_width=self.hidden_size,
-            # the token rows the expert layer's combine adds into
-            combine_width=self.hidden_size,
             selection_bytes=self.selection_bytes,
             # after what the env scores: the pairs per held expert, then
             # the (query, key) pairs selected

@@ -73,17 +73,17 @@ of ONE block and an even number of key heads (a pair); a shared part of 64
 or a multiple of 128; the sequence a whole number of the kernel's blocks;
 and, of a call with a ``window``, a band of at least one of those blocks
 or one that can be the block: ``call_form``), and the XLA form otherwise and
-everywhere else, so ``apply`` outside an engine is the XLA form.  The engine says at
-build which form that is (``ShardedESEngine.attention_form``, by
-``ops.pallas_attention.attention_form``: the same conditions on the widths
-the model states).
-The engine reads what its rule needs from the model's declaration
-(``perturbed.PolicyDeclaration``, the model's ``declaration()``):
-``attention_widths``, the widths the kernel's column blocks are cut by, one
-``int`` for heads of one width or ``(a head's own part, the shared part,
-the value width)``; its key heads; and, where its attention layers are of
-several kinds, each kind's band (``attention_windows``), from which the
-engine says which form each kind took (``attention_form_by_kind``).
+everywhere else, so ``apply`` outside an engine is the XLA form.  The run's
+records say which form that is (``attention_form``, its reason, and
+``attention_form_by_kind`` where the attention layers are of several kinds):
+the model names the kernel's own rule, ``pallas_attention.attention_facts``,
+in its declaration's ``kernels`` (``perturbed.PolicyDeclaration``) with what
+it calls the core with: the widths the kernel's column blocks are cut by,
+one ``int`` for heads of one width or ``(a head's own part, the shared part,
+the value width)``; its key heads; and each kind's band.  The engine
+evaluates it at build with what it observed (ops/kernel_facts.py) and
+carries the answer to the gauges and the manifest
+(``ShardedESEngine.kernel_facts``): the same conditions, said of the model.
 
 The next-token scorer (:func:`score_next_tokens`: hidden states and the
 head's leaf -> each next token's log-probability) takes the leaf itself,
@@ -105,16 +105,16 @@ fit (``pallas_head.fits``: a hidden width of whole 128-lane blocks and at
 most 16 KiB a row, the sequence a whole number of the kernel's row tiles)
 and the noise is factored or none, whatever form the attention beside it
 takes: a model whose heads the attention's kernel turns away (64 wide with
-values of 64) scores in the head's kernel all the same.  The engine says
-which at build, and why (``ShardedESEngine.head_form`` and
-``head_form_why`` by ``pallas_head.head_form_why``, from the ``head_width``
-the model states).  The last position's logits (the behaviour) are the
-one-row XLA matmul in both forms.
+values of 64) scores in the head's kernel all the same.  Which, and why,
+is in the run's records (``head_form``, ``head_form_why``: the model's
+declaration names ``pallas_head.head_facts`` with the width the head
+contracts).  The last position's logits (the behaviour) are the one-row XLA
+matmul in both forms.
 
 One more kernel is taken inside that scope, by a model and not by this
 module: Mamba-1's selective scan (``sambay_lm.selective_scan`` asks
 ``pallas_attention.scoped_interpret()`` as the dispatches here do;
-ops/pallas_scan.py, ``ShardedESEngine.scan_form``).
+ops/pallas_scan.py, ``scan_form`` in the records by its ``scan_facts``).
 
 The indexer (:func:`select_keys`: DeepSeek-V3.2's sparse attention, whose
 ``sa_config`` keys a configuration carries) scores every visible key of a
@@ -155,9 +155,15 @@ every routed pair, in the pass's row order), has TWO forms:
 It takes the kernel inside the SAME ``kernel_scope`` where its own shapes fit
 (``pallas_combine.fits``: token rows of whole 128-lane blocks, a member's
 tokens a whole number of the kernel's tiles), whatever forms the kernels
-beside it take.  The engine says which at build
-(``ShardedESEngine.combine_form`` by ``pallas_combine.combine_form``, from
-the ``combine_width`` the model states).
+beside it take.  Which is in the run's records (``combine_form``: a model
+with an expert layer names ``pallas_combine.combine_facts`` with the width
+of a token's row).
+
+The leaves these blocks name are cut over a mesh's ``model`` axis by the
+rules at the end of this file (``DECODER_PARTITION_RULES``,
+``EXPERT_PARTITION_RULES``); a model composes them with the rules of the
+leaves only it has, beside its ``param_shapes``, into its declaration's
+``partition_rules`` (docs/sharding.md).
 
 Functions, not a base class: a model hands in its own ``dense`` (the
 ``(p, noise, c, name, x) -> x @ (p[name] + c·noise[name])`` of the class,
@@ -180,11 +186,12 @@ import jax.numpy as jnp
 import functools
 
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..obs.trace import (ATTN, DENSE, DIFF, DISPATCH, EXPERT, HEAD, INDEX,
                          MIX, PERTURB, ROPE, ROUTE, SELECT, part, stage)
 from ..ops import pallas_attention, pallas_combine, pallas_head
-from .perturbed import (F32, is_factored, perturbed_dense,
+from .perturbed import (F32, MODEL_AXIS, is_factored, perturbed_dense,
                         perturbed_grouped_dense, perturbed_headwise_dense,
                         perturbed_leaf)
 
@@ -1098,3 +1105,38 @@ def score_next_tokens(h, tokens, w, noise, c, block: int,
         targets.reshape(n_blocks, block)))
     last = last_logits()
     return logp.reshape(-1)[:t - 1], last
+
+
+# --------------------------------------------------------------------------
+# partition rules of the leaves the blocks above name
+# --------------------------------------------------------------------------
+# ``(regex, PartitionSpec)`` pairs, first match wins
+# (parallel/mesh.py::match_partition_rules).  A model lists these and the
+# rules of its own leaves in its declaration's ``partition_rules``, in the
+# order IT needs; the engine tries a model's list before the general rules.
+
+# A decoder's frame.  Projections are column- then row-parallel in pairs
+# (q/k/v -> o, gate/up -> down), so one all-reduce closes each pair; the
+# embedding goes by vocabulary row and an untied head by vocabulary column;
+# the block norms replicate.
+DECODER_PARTITION_RULES = (
+    (r"embed/embedding$", P(MODEL_AXIS, None)),
+    (r"attn/(q|k|v)$", P(None, MODEL_AXIS)),
+    (r"attn/o$", P(MODEL_AXIS, None)),
+    (r"mlp/(gate|up)$", P(None, MODEL_AXIS)),
+    (r"mlp/down$", P(MODEL_AXIS, None)),
+    (r"(norm[1-4]|final_norm)/scale$", P()),
+    (r"head/kernel$", P(None, MODEL_AXIS)),
+)
+
+# The expert layer (:func:`routed_experts`).  The STACKED expert leaves
+# ``[experts, m, n]`` shard their EXPERT axis: a device holds whole experts,
+# as expert parallelism does.  The shared expert is a gated FFN like
+# ``mlp``.  The one-matrix router and its selection bias replicate: every
+# device routes every token.
+EXPERT_PARTITION_RULES = (
+    (r"experts/(gate|up|down)$", P(MODEL_AXIS, None, None)),
+    (r"shared/(gate|up)$", P(None, MODEL_AXIS)),
+    (r"shared/down$", P(MODEL_AXIS, None)),
+    (r"moe/(router|router_bias)$", P()),
+)

@@ -51,11 +51,21 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import EXIT, part, stage
+from ..ops import pallas_attention, pallas_head
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
 from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
                         perturbed_embed, perturbed_leaf)
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis: the decoder's frame (models/lm_blocks.py), and the exit gate, one
+# column, which replicates.
+PARTITION_RULES = lm_blocks.DECODER_PARTITION_RULES + (
+    (r"exit_gate/(kernel|bias)$", P()),
+)
 
 FULL_ATTENTION = "full_attention"
 NORMS = ("norm1", "norm2", "norm3", "norm4")
@@ -131,14 +141,16 @@ class LoopedLM:
         """What the engine that runs this model and the run's records read
         of it, stated once (models/perturbed.py::PolicyDeclaration)."""
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # heads of ONE width, scored and summed
+                (pallas_attention.attention_facts,
+                 (self.head_dim, self.num_key_value_heads)),
+                # the width the next-token head contracts
+                (pallas_head.head_facts, (self.hidden_size,))),
             # the head runs in blocks of ``head_block`` positions: its
             # widest activation is ``[head_block, vocab]``, not ``[T, vocab]``
             leaf_rows={"head/kernel": self.head_block},
-            # heads of ONE width, scored and summed
-            attention_widths=self.head_dim,
-            attention_kv_heads=self.num_key_value_heads,
-            # the width the next-token head contracts
-            head_width=self.hidden_size,
             # its passes, and the layer-applications a token goes through
             facts={"loop_steps": self.total_ut_steps,
                    "layer_applications_per_token":

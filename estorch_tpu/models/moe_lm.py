@@ -71,11 +71,29 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import DENSE, HEAD, ROPE, part, stage
+from ..ops import pallas_attention, pallas_combine, pallas_head
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
-from .perturbed import (F32, PolicyDeclaration, leaf_columns, perturbed_dense,
-                        perturbed_embed, perturbed_leaf)
+from .perturbed import (F32, MODEL_AXIS, PolicyDeclaration, leaf_columns,
+                        perturbed_dense, perturbed_embed, perturbed_leaf)
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis: the decoder's frame and the expert layer (models/lm_blocks.py), and
+# latent attention's own.  Its up-projections go by head (column-parallel,
+# closed by the row-parallel ``attn/o``); its two down-projections are
+# narrow and feed a norm over their whole width, so they replicate, as do
+# those norms and the MTP module's; the MTP module's ``eh`` is
+# column-parallel.
+PARTITION_RULES = (
+    lm_blocks.DECODER_PARTITION_RULES + lm_blocks.EXPERT_PARTITION_RULES + (
+        (r"attn/(q_b|kv_b)$", P(None, MODEL_AXIS)),
+        (r"attn/(q_a|kv_a)$", P()),
+        (r"(q_norm|kv_norm|embed_norm|hidden_norm)/scale$", P()),
+        (r"mtp/eh$", P(None, MODEL_AXIS)),
+    ))
 
 DENSE_LAYER, MOE_LAYER = "dense", "moe"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -232,19 +250,22 @@ class MoELM:
         rows = (self.num_experts_per_tok * lm_blocks.EXPERT_CAPACITY_MARGIN
                 / self.expert_group_size)
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # (a head's own query/key part, the rotated part whose key
+                # all heads share, the value width)
+                (pallas_attention.attention_facts,
+                 ((self.qk_nope_head_dim, self.qk_rope_head_dim,
+                   self.v_head_dim),)),
+                # the width the next-token head contracts
+                (pallas_head.head_facts, (self.hidden_size,)),
+                # the token rows the expert layer's combine adds into
+                (pallas_combine.combine_facts, (self.hidden_size,))),
             # the head runs in blocks of ``head_block`` positions
             leaf_rows={"head/kernel": self.head_block},
             leaf_rows_per_token=dict.fromkeys(self.stacked_leaves, rows),
             stacked_leaves=self.stacked_leaves,
             float32_leaves=self.float32_leaves,
-            # (a head's own query/key part, the rotated part whose key all
-            # heads share, the value width)
-            attention_widths=(self.qk_nope_head_dim, self.qk_rope_head_dim,
-                              self.v_head_dim),
-            # the width the next-token head contracts
-            head_width=self.hidden_size,
-            # the token rows the expert layer's combine adds into
-            combine_width=self.hidden_size,
             # after what the env scores: the pairs per held expert
             outputs=("expert_load",),
             facts={"experts_held": self.n_routed_experts,

@@ -197,17 +197,38 @@ def perturbed_leaf(w, noise, c):
         return w + c * noise.astype(F32)
 
 
+# the mesh axis a policy's partition rules may name: the one parameter
+# leaves are cut over (parallel/mesh.py builds its meshes with it)
+MODEL_AXIS = "model"
+
+
 @dataclasses.dataclass(frozen=True)
 class PolicyDeclaration:
     """What a policy states of itself, once, for the engine that runs it and
     for the run's records (:func:`declaration_of`).  Every field has the
     value of a policy that states nothing: an MLP, a conv or recurrent
     policy.  A sequence model builds its own in ONE place, its
-    ``declaration()``, from its sizes; the param-sharded engine reads the
-    fields where it sizes chunks and resolves forms (parallel/sharded.py),
-    ``lowrank_spec_for`` where it lays out the noise, ``ES`` merges
-    ``facts`` into the gauges and ``run_manifest()["config"]``.
+    ``declaration()``, from its sizes, beside the ``param_shapes`` that
+    name its leaves; the param-sharded engine reads the fields where it
+    cuts the state, sizes chunks and reports its build
+    (parallel/sharded.py), ``lowrank_spec_for`` where it lays out the
+    noise, ``ES`` merges ``facts`` into the gauges and
+    ``run_manifest()["config"]``.  Nothing under ``parallel/`` knows a
+    model's leaves or a kernel's name: both are said here.
 
+    - ``partition_rules``: ``(regex, PartitionSpec over MODEL_AXIS)`` pairs
+      for the leaves THIS model names, first match wins
+      (``parallel/mesh.py::match_partition_rules``); the engine tries them
+      before the general rules of a policy that states none
+      (``DEFAULT_PARTITION_RULES``).  Rules for the leaves of shared blocks
+      are models/lm_blocks.py's, composed by each model in the order it
+      needs;
+    - ``kernels``: ``(rule, widths)`` pairs, one a hand-written kernel the
+      forward calls: ``rule`` the ``*_facts`` function of the kernel's own
+      module (``ops/pallas_*.py``), ``widths`` what this model calls it
+      with, plain tuples so that two declarations compare equal.  The
+      engine reports ``rule(scope, *widths)`` of each at build
+      (ops/kernel_facts.py) and knows none by name;
     - ``leaf_rows``: ``{leaf path: positions per application}`` of the
       leaves a sequence does NOT pass whole (an untied head run in blocks);
     - ``leaf_rows_per_token``: ``{stacked leaf path: rows of the leaf's
@@ -218,19 +239,6 @@ class PolicyDeclaration:
       reads, dense noise whatever the factoring rule says of their shape;
     - ``float32_leaves``: leaves the forward reads in float32 whatever the
       compute dtype;
-    - ``attention_widths`` (one ``int``, or ``(a head's own part, the part
-      scored against one shared key, the value width)``),
-      ``attention_windows`` (``{attention layer kind: its band | None}``;
-      ``None``: one kind, no band) and ``attention_kv_heads``: what the
-      attention form's rule reads (ops/pallas_attention.py);
-      ``head_width``: the head form's (ops/pallas_head.py);
-      ``scan_widths`` ``(d_inner, d_state)``: the scan form's
-      (ops/pallas_scan.py); ``combine_width``: the floats of a token's row
-      that an expert layer's combine adds the routed rows into, which the
-      combine form's rule reads (ops/pallas_combine.py); ``delta_widths``
-      ``(a key head's width, a value head's, the chunk)`` of the gated
-      delta rule: the delta form's (ops/pallas_delta.py).  ``None``: the
-      policy has no such layer;
     - ``selection_bytes``: ``horizon -> bytes`` of the temporaries ONE
       member's learned selection of keys holds, for the chunk rule;
     - ``outputs``: the names, in order, of what the policy returns after
@@ -238,18 +246,13 @@ class PolicyDeclaration:
     - ``facts``: what the model itself adds to the gauges and the manifest.
     """
 
+    partition_rules: tuple = ()
+    kernels: tuple = ()
     leaf_rows: dict = dataclasses.field(default_factory=dict)
     leaf_rows_per_token: dict = dataclasses.field(default_factory=dict)
     stacked_leaves: tuple = ()
     dense_noise_leaves: tuple = ()
     float32_leaves: tuple = ()
-    attention_widths: int | tuple | None = None
-    attention_windows: dict | None = None
-    attention_kv_heads: int | None = None
-    head_width: int | None = None
-    scan_widths: tuple | None = None
-    combine_width: int | None = None
-    delta_widths: tuple | None = None
     selection_bytes: Callable[[int], int] | None = None
     outputs: tuple = ()
     facts: dict = dataclasses.field(default_factory=dict)

@@ -85,9 +85,9 @@ and VMEM, ``Δ``, ``x``, ``B``, ``C`` streamed a time chunk at a time and
 chip, ``pallas_attention.traced_why``) where its own shapes fit
 (``pallas_scan.fits``: ``d_inner`` whole 128-lane blocks, at most 16
 states, the sequence whole time chunks of 256), whatever form the attention
-takes, and the ``lax.scan`` anywhere else; the engine says
-which at build (``ShardedESEngine.scan_form`` from the ``scan_widths`` the
-model states).  Mamba-1's decay is per (channel, state), so there is no
+takes, and the ``lax.scan`` anywhere else; the run's records say
+which (``scan_form``, by ``pallas_scan.scan_facts`` in ``declaration()``
+with the scans' widths).  Mamba-1's decay is per (channel, state), so there is no
 matmul form of it as Mamba-2's.
 
 As an ES policy the module maps ``tokens [T]`` to ``(log p of each next
@@ -103,12 +103,40 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from ..obs.trace import ATTN, DIFF, GMU, HEAD, SSM, part, stage
-from ..ops import pallas_attention, pallas_scan
+from ..ops import pallas_attention, pallas_head, pallas_scan
 from . import lm_blocks
 from .lm_blocks import layer_name, subtree
-from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
+from .perturbed import (F32, MODEL_AXIS, PolicyDeclaration, perturbed_dense,
                         perturbed_embed, perturbed_leaf)
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis.  Its fused projections are column-parallel (``in_proj``, ``qkv``,
+# and the cross layers' ``q``, which the decoder's frame names), closed by
+# the row-parallel ``out_proj`` / ``attn/o``; Mamba-1's channels, their conv
+# taps, ``dt_proj``'s columns, ``A_log``'s rows, ``dt_bias`` and ``D`` go by
+# channel; ``x_proj`` contracts the channels into Δ's rank, B and C, which
+# every channel reads (row-parallel, one all-reduce); a gated memory unit is
+# a column- then row-parallel pair whose gate product is by channel, like
+# the memory it multiplies.  The differential λ vectors and the norm over a
+# head pair's values are a head wide and replicate, as do the LayerNorms'
+# biases.  The rest is the decoder's frame (models/lm_blocks.py).
+PARTITION_RULES = lm_blocks.DECODER_PARTITION_RULES + (
+    (r"mamba/in_proj$", P(None, MODEL_AXIS)),
+    (r"mamba/conv_kernel$", P(None, None, MODEL_AXIS)),
+    (r"mamba/(conv_bias|A_log|D|dt_bias)$", P(MODEL_AXIS)),
+    (r"mamba/x_proj$", P(MODEL_AXIS, None)),
+    (r"mamba/dt_proj$", P(None, MODEL_AXIS)),
+    (r"mamba/out_proj$", P(MODEL_AXIS, None)),
+    (r"attn/qkv$", P(None, MODEL_AXIS)),
+    (r"attn/(qkv_bias|q_bias)$", P(MODEL_AXIS)),
+    (r"attn/(o_bias|subln|lambda_[qk][12])$", P()),
+    (r"gmu/gmu_in$", P(None, MODEL_AXIS)),
+    (r"gmu/gmu_out$", P(MODEL_AXIS, None)),
+    (r"(norm[1-4]|final_norm)/bias$", P()),
+)
 
 MAMBA, WINDOW, MAMBA_MEM, FULL_KV, GMU_LAYER, CROSS = (
     "mamba", "window", "mamba_mem", "full_kv", "gmu", "cross")
@@ -289,27 +317,32 @@ class SambaYLM:
         of it, stated once (models/perturbed.py::PolicyDeclaration)."""
         mamba = {MAMBA, MAMBA_MEM} & set(self.layer_types)
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                (pallas_attention.attention_facts, (
+                    # (a score head's width, no shared part, the value
+                    # width): a map's heads are ``head_dim`` wide where they
+                    # are scored and a PAIR's values, twice that, where they
+                    # are summed; at the published 64 a pair is one 128-lane
+                    # block, two score heads side by side over one value
+                    # block, which the kernel takes as it lies
+                    (self.head_dim, 0, 2 * self.head_dim),
+                    self.num_key_value_heads,
+                    # (attention layer kind held, the band of its calls of
+                    # the core | None), in layer order: a call with a window
+                    # takes the kernel or the XLA form by its band
+                    # (pallas_attention.call_form)
+                    tuple((kind, self.sliding_window if kind == WINDOW
+                           else None)
+                          for kind in dict.fromkeys(self.layer_types)
+                          if kind in ATTENTION_PARTS))),
+                # the width the next-token head contracts
+                (pallas_head.head_facts, (self.hidden_size,)),
+                # the selective scans, where a Mamba layer is held
+                *([(pallas_scan.scan_facts,
+                    (self.d_inner, self.mamba_d_state))] if mamba else [])),
             dense_noise_leaves=self.dense_noise_leaves,
             float32_leaves=self.float32_leaves,
-            # (a score head's width, no shared part, the value width): a
-            # map's heads are ``head_dim`` wide where they are scored and a
-            # PAIR's values, twice that, where they are summed; at the
-            # published 64 a pair is one 128-lane block, two score heads
-            # side by side over one value block, which the kernel takes as
-            # it lies
-            attention_widths=(self.head_dim, 0, 2 * self.head_dim),
-            # {attention layer kind held: the band of its calls of the core
-            # | None}, in layer order: a call with a window takes the kernel
-            # or the XLA form by its band (pallas_attention.call_form)
-            attention_windows={
-                kind: self.sliding_window if kind == WINDOW else None
-                for kind in dict.fromkeys(self.layer_types)
-                if kind in ATTENTION_PARTS},
-            attention_kv_heads=self.num_key_value_heads,
-            # the width the next-token head contracts
-            head_width=self.hidden_size,
-            # of the selective scans, where a Mamba layer is held
-            scan_widths=(self.d_inner, self.mamba_d_state) if mamba else None,
             # layers of several kinds, two of which hand state to the
             # layers above them
             facts={"layer_kinds": ",".join(self.layer_types),

@@ -31,8 +31,8 @@ and the sort that plans the dispatch read ``a``, the experts read ``b`` with
 those routes (``lm_blocks.route`` and ``lm_blocks.routed_experts``, called
 apart; every other expert model routes and computes from ONE state through
 ``lm_blocks.routed_ffn``).  The two kinds of layer differ in BOTH band and
-position: the engine learns each kind's band from ``declaration()``
-(``attention_windows``) and says which form each took
+position: ``declaration()`` names each kind's band with the attention's
+rule, and the run's records say which form each took
 (``attention_form_by_kind``: where the kernel is traced a ``window`` layer
 takes it too if its band spans at least one of the kernel's blocks or can
 be the block, ``pallas_attention.call_form``).  The expert layer is told which experts
@@ -68,10 +68,17 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.trace import ATTN, HEAD, ROPE, part, stage
+from ..ops import pallas_attention, pallas_combine, pallas_head
 from . import lm_blocks
 from .lm_blocks import layer_name, rmsnorm, subtree
 from .perturbed import (F32, PolicyDeclaration, perturbed_dense,
                         perturbed_embed, perturbed_leaf)
+
+# How this model's leaves (``param_shapes``) are cut over a mesh's ``model``
+# axis: it has none of its own beside the decoder's frame and the expert
+# layer (models/lm_blocks.py).
+PARTITION_RULES = (lm_blocks.DECODER_PARTITION_RULES
+                   + lm_blocks.EXPERT_PARTITION_RULES)
 
 WINDOW_LAYER, GLOBAL_LAYER = "window", "global"
 EXPERT_LEAVES = ("gate", "up", "down")
@@ -195,20 +202,22 @@ class WindowMoELM:
                 * lm_blocks.EXPERT_CAPACITY_MARGIN / self.expert_group_size)
         bands = {WINDOW_LAYER: self.sliding_window_size, GLOBAL_LAYER: None}
         return PolicyDeclaration(
+            partition_rules=PARTITION_RULES,
+            kernels=(
+                # heads scored and summed at one width; each kind of
+                # attention layer the stack holds, with its band
+                (pallas_attention.attention_facts,
+                 (self.head_dim, self.num_key_value_heads,
+                  tuple((kind, band) for kind, band in bands.items()
+                        if kind in self.layer_types))),
+                (pallas_head.head_facts, (self.hidden_size,)),
+                # the token rows the expert layer's combine adds into
+                (pallas_combine.combine_facts, (self.hidden_size,))),
             # the head runs in blocks of ``head_block`` positions
             leaf_rows={"head/kernel": self.head_block},
             leaf_rows_per_token=dict.fromkeys(self.stacked_leaves, rows),
             stacked_leaves=self.stacked_leaves,
             float32_leaves=self.float32_leaves,
-            # heads scored and summed at one width; each kind of attention
-            # layer the stack holds, with its band
-            attention_widths=self.head_dim,
-            attention_windows={kind: band for kind, band in bands.items()
-                               if kind in self.layer_types},
-            attention_kv_heads=self.num_key_value_heads,
-            head_width=self.hidden_size,
-            # the token rows the expert layer's combine adds into
-            combine_width=self.hidden_size,
             # after what the env scores: the pairs per held expert
             outputs=("expert_load",),
             # the sparse-expert facts under MoELM's names (no MTP module),

@@ -135,10 +135,13 @@ form.  Inside the scope a CALL of the core decides by its own shapes
 turn away stays in the XLA form beside a head in its kernel), and a call
 with a ``window`` by its band too: the kernel where the band spans at least
 one of its blocks or can be the block itself, the XLA form under any other
-(:func:`call_form` has the rule; ``lm_blocks.attention_core`` and the engine
-both read it).  The engine says at build which form the model's attention takes
-(:func:`attention_form`, from the widths the model states) and which each
-kind of layer took (``attention_form_by_kind``).
+(:func:`call_form` has the rule; ``lm_blocks.attention_core`` and
+:func:`attention_facts` both read it).  Nothing in a program depends on what
+is SAID of it: a model names :func:`attention_facts` in its declaration's
+``kernels`` with the widths, key heads and bands it calls the core with,
+and an engine's build evaluates it once for the run's records
+(``attention_form``, its reason, ``attention_form_by_kind``;
+ops/kernel_facts.py), knowing neither this module's name nor the model's.
 """
 
 from __future__ import annotations
@@ -305,9 +308,17 @@ def attention_form_why(platform: str, n_devices: int, widths, length: int,
     window takes the kernel or the XLA form by the band against the
     kernel's block (:func:`call_form`) and every other call the kernel;
     ``why`` says which."""
+    return _form_why(traced_why(platform, n_devices, centre_form), widths,
+                     length, window, kv_heads)
+
+
+def _form_why(traced: tuple[bool, str], widths, length: int,
+              window: int | None, kv_heads: int | None) -> tuple[str, str]:
+    """:func:`attention_form_why` from ``traced``, the answer of
+    :func:`traced_why` (an engine's build has it already)."""
     head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
                            else widths)
-    traced, where = traced_why(platform, n_devices, centre_form)
+    traced, where = traced
     failed = ([] if traced else [where]) + _shape_failures(
         head, shared, value, kv_heads, length)
     if failed:
@@ -328,7 +339,7 @@ def call_form(form: str, window: int | None, length: int,
     the kernel may be traced and the call's shapes fit it (``form`` is
     ``"kernel"``; anything else stays what it is, the XLA form).  THE rule
     of a call with a ``window``, said once, here (``attention_core`` and
-    the engine's ``attention_form_by_kind`` both read it), a rule of
+    :func:`attention_facts`' ``attention_form_by_kind`` both read it), a rule of
     shapes: the kernel where the band spans at least ONE of the kernel's
     blocks (``window >= kernel_block(length)``: the grid's key axis follows
     the band; a window of the sequence or more is plain causal attention),
@@ -801,6 +812,35 @@ def heads_in_pairs(widths, kv_heads: int | None) -> bool:
     head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
                            else widths)
     return _pair(head, shared, value, kv_heads)
+
+
+# what :func:`attention_facts` answers for: the names of an engine's
+# gauges and manifest entries (ops/kernel_facts.py collects the kernels')
+FACTS = ("attention_form", "attention_form_why", "attention_form_by_kind")
+
+
+def attention_facts(scope, widths, kv_heads: int | None = None,
+                    windows: tuple | None = None) -> dict:
+    """What an engine's build reports of a model's attention, as the model
+    names it in ``PolicyDeclaration.kernels``: ``widths`` and ``kv_heads``
+    as :func:`attention_form_why` reads them, ``windows`` the ``(attention
+    layer kind, the band of its calls | None)`` pairs in layer order (one
+    kind, ``"causal"``, no band, where it states none).  ``scope`` is the
+    engine's (``ops.kernel_facts.BuildScope``): whether kernels may be
+    traced, and the sequence length.  ``attention_form`` and its reason by
+    :func:`attention_form_why`'s rule; ``attention_form_by_kind``,
+    ``"<kind>:<form>,…"``, the form the calls of each kind take by
+    :func:`call_form`'s."""
+    windows = windows or (("causal", None),)
+    form, why = _form_why(
+        scope.traced, widths, scope.horizon,
+        next((band for _, band in windows if band is not None), None),
+        kv_heads)
+    paired = heads_in_pairs(widths, kv_heads)
+    return {"attention_form": form, "attention_form_why": why,
+            "attention_form_by_kind": ",".join(
+                f"{kind}:" + call_form(form, band, scope.horizon, paired)
+                for kind, band in windows)}
 
 
 def band_block(window: int | None, length: int,

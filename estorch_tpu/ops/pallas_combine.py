@@ -35,7 +35,8 @@ pass that starts a layer (``first``) does not read ``y`` at all.
 ES takes no gradient: there is no ``custom_vjp`` and nothing is saved.
 
 ``interpret`` is a required argument, as in ops/pallas_attention.py.  Which
-form a program takes is observed, not configured (:func:`combine_form`):
+form a program takes is observed, not configured (:func:`combine_form`; a
+model names :func:`combine_facts` in its declaration and the run's records say):
 ``lm_blocks.routed_experts`` takes the kernel inside an engine's
 ``pallas_attention.kernel_scope`` where its shapes fit (:func:`fits`).
 """
@@ -105,6 +106,21 @@ def combine_form(traced: bool, hidden: int, length: int) -> str:
     ``lm_blocks.routed_experts`` does while it is traced, said once at
     build."""
     return "kernel" if traced and fits(hidden, length) else "xla"
+
+
+# what :func:`combine_facts` answers for (ops/kernel_facts.py collects them)
+FACTS = ("combine_form",)
+
+
+def combine_facts(scope, hidden: int) -> dict:
+    """What an engine's build reports of the combine of a model's expert
+    layers, as the model names it in ``PolicyDeclaration.kernels``:
+    ``hidden``, the floats of a token's row the combine adds the routed
+    rows into; ``scope`` (``ops.kernel_facts.BuildScope``) has whether
+    kernels may be traced and the sequence length.  :func:`combine_form`'s
+    answer under its name."""
+    return {"combine_form": combine_form(scope.traced[0], hidden,
+                                         scope.horizon)}
 
 
 def chunk_rows(rows: int, held: int, tiles: int) -> int:

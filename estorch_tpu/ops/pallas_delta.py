@@ -73,7 +73,8 @@ them in front of the grid; a head's first block zeroes the state for every
 member).
 
 ``interpret`` is a required argument, as in ops/pallas_attention.py.  Which
-form a program takes is observed, not configured (:func:`delta_form`):
+form a program takes is observed, not configured (:func:`delta_form`; a
+model names :func:`delta_facts` in its declaration and the run's records say):
 ``delta_moe_lm.gated_delta_rule`` takes the kernels inside an engine's
 ``pallas_attention.kernel_scope`` where its shapes fit (:func:`fits`).
 """
@@ -139,6 +140,20 @@ def delta_form(traced: bool, key_dim: int, value_dim: int, chunk: int,
     build."""
     return ("kernel" if traced and fits(key_dim, value_dim, chunk, length)
             else "xla")
+
+
+# what :func:`delta_facts` answers for (ops/kernel_facts.py collects them)
+FACTS = ("delta_form",)
+
+
+def delta_facts(scope, key_dim: int, value_dim: int, chunk: int) -> dict:
+    """What an engine's build reports of a model's gated delta rule, as
+    the model names it in ``PolicyDeclaration.kernels``: a key head's
+    width, a value head's, the chunk; ``scope``
+    (``ops.kernel_facts.BuildScope``) has whether kernels may be traced
+    and the sequence length.  :func:`delta_form`'s answer under its name."""
+    return {"delta_form": delta_form(scope.traced[0], key_dim, value_dim,
+                                     chunk, scope.horizon)}
 
 
 def block_rows(length: int) -> int:

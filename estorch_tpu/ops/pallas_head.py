@@ -50,7 +50,8 @@ the same operands the kernel is off by 8e-6, the XLA form by 2e-3; PERF.md,
 PR 36).
 
 ``interpret`` is a required argument, as in ops/pallas_attention.py.  Which
-form a program takes is observed, not configured (:func:`head_form_why`):
+form a program takes is observed, not configured (:func:`head_form_why`; a
+model names :func:`head_facts` in its declaration and the run's records say):
 ``lm_blocks.score_next_tokens`` takes the kernel inside an engine's
 ``pallas_attention.kernel_scope`` where its shapes fit (:func:`fits`),
 whatever form the attention beside it takes.  The engine opens the scope
@@ -143,6 +144,21 @@ def head_form_why(traced: tuple[bool, str], hidden: int, length: int,
             f"{ROW_BYTES_MAX} bytes a row over whole row tiles of {ROW_TILE}")
     return "kernel", (f"{where}; a hidden width of whole {LANES}-lane "
                       f"blocks, whole row tiles of {ROW_TILE}")
+
+
+# what :func:`head_facts` answers for (ops/kernel_facts.py collects them)
+FACTS = ("head_form", "head_form_why")
+
+
+def head_facts(scope, hidden: int) -> dict:
+    """What an engine's build reports of a model's next-token head, as the
+    model names it in ``PolicyDeclaration.kernels``: ``hidden``, the width
+    the head contracts; ``scope`` (``ops.kernel_facts.BuildScope``) has
+    whether kernels may be traced, the sequence length and the compute
+    dtype's item size.  :func:`head_form_why`'s answer under its names."""
+    form, why = head_form_why(scope.traced, hidden, scope.horizon,
+                              scope.itemsize)
+    return {"head_form": form, "head_form_why": why}
 
 
 # --------------------------------------------------------------------------

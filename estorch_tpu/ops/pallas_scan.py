@@ -44,7 +44,8 @@ Members enter through ``vmap`` (the batching rule of ``pallas_call`` puts
 them in front of the grid); ``a`` may be batched or not.
 
 ``interpret`` is a required argument, as in ops/pallas_attention.py.  Which
-form a program takes is observed, not configured (:func:`scan_form`):
+form a program takes is observed, not configured (:func:`scan_form`; a
+model names :func:`scan_facts` in its declaration and the run's records say):
 ``sambay_lm.selective_scan`` takes the kernel inside an engine's
 ``pallas_attention.kernel_scope`` where its shapes fit (:func:`fits`).
 """
@@ -106,6 +107,19 @@ def scan_form(traced: bool, d_inner: int, d_state: int, length: int) -> str:
     fit (:func:`fits`).  What ``sambay_lm.selective_scan`` does while it is
     traced, said once at build."""
     return "kernel" if traced and fits(d_inner, d_state, length) else "xla"
+
+
+# what :func:`scan_facts` answers for (ops/kernel_facts.py collects them)
+FACTS = ("scan_form",)
+
+
+def scan_facts(scope, d_inner: int, d_state: int) -> dict:
+    """What an engine's build reports of a model's selective scans, as the
+    model names them in ``PolicyDeclaration.kernels``; ``scope``
+    (``ops.kernel_facts.BuildScope``) has whether kernels may be traced
+    and the sequence length.  :func:`scan_form`'s answer under its name."""
+    return {"scan_form": scan_form(scope.traced[0], d_inner, d_state,
+                                   scope.horizon)}
 
 
 # --------------------------------------------------------------------------

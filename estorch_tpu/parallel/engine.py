@@ -40,6 +40,7 @@ from ..envs.rollout import carry_init_takes_params, make_obs_probe, make_rollout
 from ..obs.spans import NULL_TELEMETRY
 from ..obs.trace import (GATHER, GRAD, NOISE, PERTURB, RANK, SAMPLE, UPDATE,
                          stage)
+from ..ops import kernel_facts
 from ..ops.gradient import es_gradient, rank_weighted_noise_sum
 from ..ops.noise import NoiseTable, member_offsets, pair_signs, sample_pair_offsets
 from ..ops.params import ParamSpec
@@ -291,22 +292,22 @@ NOISE_KERNEL_MAX_DIM = 1_000_000  # the row kernels hold a few windows of
 # engine's ``build_facts()`` names each fact as its gauge and
 # ``run_manifest()["config"]`` do; an engine without one (pooled, host)
 # resolves none.  The manifest names these for EVERY engine (``None``: not
-# resolved by this one), and the rest where an engine reports them
+# resolved by this one): the engines' own, and whatever a policy's
+# hand-written kernels may report (ops/kernel_facts.py has their names)
 MANIFEST_BUILD_FACTS = (
     "forward_form", "noise_rows_per_generation", "noise_gather_form",
-    "attention_form", "attention_form_by_kind", "head_form", "scan_form",
-    "combine_form", "delta_form", "attention_form_why", "head_form_why")
-_NO_GAUGE = frozenset({"attention_form_why", "head_form_why"})  # sentences
+    *kernel_facts.FACT_NAMES)
 # the manifest has the mesh as ``mesh_axes``
 _NOT_IN_MANIFEST = frozenset({"mesh_shape", "param_bytes_per_chip"})
 
 
 def build_fact_gauges(engine) -> dict:
     """The gauges of what ``engine`` resolved at build: a fact it did not
-    resolve (``None``) sets none."""
+    resolve (``None``) sets none, nor does a kernel rule's reason (a
+    sentence: the manifest carries it)."""
     facts = getattr(engine, "build_facts", dict)()
     return {name: value for name, value in facts.items()
-            if value is not None and name not in _NO_GAUGE}
+            if value is not None and name not in kernel_facts.SENTENCES}
 
 
 def build_fact_manifest(engine) -> dict:

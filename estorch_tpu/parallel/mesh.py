@@ -25,8 +25,9 @@ from typing import Any, Sequence
 import jax
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from ..models.perturbed import MODEL_AXIS  # the axis a policy's rules name
+
 POP_AXIS = "pop"
-MODEL_AXIS = "model"
 
 
 def _auto_mesh(shape: tuple[int, ...], names: tuple[str, ...],
@@ -110,149 +111,18 @@ def padded_count(n: int, n_shards: int) -> int:
 # regex partition rules  (the `match_partition_rules` idiom)
 # ---------------------------------------------------------------------------
 
-# Default rules for the bundled policy families (models/policies.py):
-# conv kernels shard their output-channel dim, dense kernels their output
-# dim, 1-D vectors (biases, scales, learned carries) shard outright, and
-# everything else replicates.  The trailing catch-all makes the defaults
-# total over ANY tree; strict user rule sets omit it and get the
-# unmatched-leaf error instead.
-# The sequence models' leaves (models/hybrid_lm.py, models/looped_lm.py;
-# models/moe_lm.py's own follow in MOE_LM_PARTITION_RULES) come first.  Projections are column- then row-parallel in pairs
-# (in_z/in_x/in_dt → out_proj, gate/up → down, q/k/v → o), so one
-# all-reduce closes each pair; Mamba heads, their conv channels, dt,
-# A_log, D and the gated norm go by head; the embedding by vocabulary row
-# and an untied head by vocabulary column; B and C (one group, read by
-# every head), the block norms and a looped model's exit gate (one
-# column) replicate.
-HYBRID_LM_PARTITION_RULES = (
-    (r"embed/embedding$", P(MODEL_AXIS, None)),
-    (r"mamba/(in_z|in_x|in_dt)$", P(None, MODEL_AXIS)),
-    (r"mamba/conv_x_kernel$", P(None, None, MODEL_AXIS)),
-    (r"mamba/(conv_x_bias|A_log|D|dt_bias|norm_scale)$", P(MODEL_AXIS)),
-    (r"mamba/(in_bc|conv_bc_kernel|conv_bc_bias)$", P()),
-    (r"mamba/out_proj$", P(MODEL_AXIS, None)),
-    (r"attn/(q|k|v)$", P(None, MODEL_AXIS)),
-    (r"attn/o$", P(MODEL_AXIS, None)),
-    (r"mlp/(gate|up)$", P(None, MODEL_AXIS)),
-    (r"mlp/down$", P(MODEL_AXIS, None)),
-    (r"(norm[1-4]|final_norm)/scale$", P()),
-    (r"head/kernel$", P(None, MODEL_AXIS)),
-    (r"exit_gate/(kernel|bias)$", P()),
-)
-
-# A sparse-expert model with latent attention (models/moe_lm.py).  The
-# STACKED expert leaves ``[experts, m, n]`` shard their EXPERT axis: a
-# device holds whole experts, as expert parallelism does.  Latent
-# attention's up-projections go by head (column-parallel, closed by the
-# row-parallel ``attn/o`` above); its two down-projections are narrow and
-# feed a norm over their whole width, so they replicate, as do the norms,
-# the router and its selection bias (every device routes every token).
-# The shared expert is a gated FFN like ``mlp``.
-MOE_LM_PARTITION_RULES = (
-    (r"experts/(gate|up|down)$", P(MODEL_AXIS, None, None)),
-    (r"shared/(gate|up)$", P(None, MODEL_AXIS)),
-    (r"shared/down$", P(MODEL_AXIS, None)),
-    (r"attn/(q_b|kv_b)$", P(None, MODEL_AXIS)),
-    (r"attn/(q_a|kv_a)$", P()),
-    (r"(q_norm|kv_norm|embed_norm|hidden_norm)/scale$", P()),
-    (r"moe/(router|router_bias)$", P()),
-    (r"mtp/eh$", P(None, MODEL_AXIS)),
-)
-
-# A decoder of Mamba-1, differential attention and gated memory units
-# (models/sambay_lm.py).  Its fused projections are column-parallel
-# (``in_proj``, ``qkv``, and the cross layers' ``q``, which the rule for
-# ``attn/(q|k|v)`` above already names), closed by the row-parallel
-# ``out_proj`` / ``attn/o`` above; Mamba-1's channels, their conv taps,
-# ``dt_proj``'s columns, ``A_log``'s rows, ``dt_bias`` and ``D`` go by
-# channel (the last three by the Mamba-2 rule above, which names them);
-# ``x_proj`` contracts the channels into Δ's rank, B and C, which every
-# channel reads (row-parallel, one all-reduce); a gated
-# memory unit is a column- then row-parallel pair whose gate product is by
-# channel, like the memory it multiplies.  The differential λ vectors and
-# the norm over a head pair's values are a head wide and replicate, as do
-# the LayerNorms' biases.
-SAMBAY_LM_PARTITION_RULES = (
-    (r"mamba/in_proj$", P(None, MODEL_AXIS)),
-    (r"mamba/conv_kernel$", P(None, None, MODEL_AXIS)),
-    (r"mamba/conv_bias$", P(MODEL_AXIS)),
-    (r"mamba/x_proj$", P(MODEL_AXIS, None)),
-    (r"mamba/dt_proj$", P(None, MODEL_AXIS)),
-    (r"attn/qkv$", P(None, MODEL_AXIS)),
-    (r"attn/(qkv_bias|q_bias)$", P(MODEL_AXIS)),
-    (r"attn/(o_bias|subln|lambda_[qk][12])$", P()),
-    (r"gmu/gmu_in$", P(None, MODEL_AXIS)),
-    (r"gmu/gmu_out$", P(MODEL_AXIS, None)),
-    (r"(norm[1-4]|final_norm)/bias$", P()),
-)
-
-# A sparse-expert decoder whose attention reads a learned selection of keys
-# (models/indexed_moe_lm.py).  Its q, k, v, o, router and stacked experts go
-# by the rules above that name them.  The indexer's query projection goes by
-# index head (column-parallel); its ONE key head, that key's LayerNorm and
-# the per-head weights ``index_w`` (16 columns) are read whole by every
-# index head and replicate, as the per-head norms of q and k do.
-INDEXED_MOE_LM_PARTITION_RULES = (
-    (r"indexer/index_q$", P(None, MODEL_AXIS)),
-    (r"indexer/(index_k|index_w)$", P()),
-    (r"indexer/index_norm/(scale|bias)$", P()),
-    (r"k_norm/scale$", P()),
-)
-
-# A sparse-expert decoder whose attention is computed inside a compressed
-# latent (models/cca_moe_lm.py).  Its q, k, v, o, embedding, stacked experts
-# and selection bias go by the rules above that name them.  The head-mixing
-# convolution's STACKED ``[taps · heads, d, d]`` matrices shard their
-# (tap, head) axis, as the experts shard theirs; the depthwise taps, both
-# convolutions' biases and the temperatures are a few thousand values that
-# every head's slice reads and replicate.  The router (a down-projection
-# into a norm over its whole width, a state's scale, a three-matrix MLP 256
-# wide) replicates, as the one-matrix routers do: every device routes every
-# token.
-CCA_MOE_LM_PARTITION_RULES = (
-    (r"attn/conv_head$", P(MODEL_AXIS, None, None)),
-    (r"attn/(conv_time|conv_time_bias|conv_head_bias|temperature)$", P()),
-    (r"moe/(router_down|router_down_bias|router_state)$", P()),
-    (r"moe/router_norm/scale$", P()),
-    (r"moe/router_mlp/[wb][123]$", P()),
-)
-
-# A sparse-expert decoder of gated-delta-rule and gated full-attention
-# layers (models/delta_moe_lm.py).  Its k, v, o, norms, router, shared and
-# stacked experts go by the rules above that name them; the full layers'
-# ``q`` (each head's query and gate side by side) by the rule for
-# ``attn/(q|k|v)``.  The linear mixer's fused ``[q | k | v | z]`` projection
-# and the conv over ``[q | k | v]`` go by column (a layout, not a cut by
-# head: GSPMD moves what the split into parts needs), closed by the
-# row-parallel ``out_proj``; ``A_log`` and ``dt_bias`` by value head; the
-# narrow ``[b | a]`` projection, the gated norm's one head of weights and
-# the shared expert's one-column gate replicate.
-DELTA_MOE_LM_PARTITION_RULES = (
-    (r"delta/in_proj_qkvz$", P(None, MODEL_AXIS)),
-    (r"delta/conv$", P(None, None, MODEL_AXIS)),
-    (r"delta/(A_log|dt_bias)$", P(MODEL_AXIS)),
-    (r"delta/(in_proj_ba|norm_scale)$", P()),
-    (r"delta/out_proj$", P(MODEL_AXIS, None)),
-    (r"moe/shared_gate$", P()),
-)
-
-# A sparse-expert decoder whose two kinds of attention layer differ in
-# their head count, with a gate a head (models/gated_window_moe_lm.py).  Its
-# q, k, v, o (as wide as the layer's own heads), norms, dense FFN, router,
-# shared and stacked experts go by the rules above that name them.  The
-# gate's projection is one column a head, 48 or 64 of them: it replicates,
-# as the other narrow projections do.
-GATED_WINDOW_MOE_LM_PARTITION_RULES = (
-    (r"attn/head_gate$", P()),
-)
-
+# The rules of a policy that states none (models/policies.py's families),
+# and the LAST rules tried for one that states its own
+# (``PolicyDeclaration.partition_rules``, written beside the model's
+# ``param_shapes``: no table here names a model's leaves): conv kernels shard
+# their output-channel dim, dense kernels their output dim, 1-D vectors
+# (biases, scales, learned carries) shard outright, and everything else
+# replicates.  The trailing catch-all makes the defaults total over ANY
+# tree; strict user rule sets omit it and get the unmatched-leaf error
+# instead.
 CATCH_ALL = r".*"
 
 DEFAULT_PARTITION_RULES = (
-        HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
-        + SAMBAY_LM_PARTITION_RULES + INDEXED_MOE_LM_PARTITION_RULES
-        + CCA_MOE_LM_PARTITION_RULES + DELTA_MOE_LM_PARTITION_RULES
-        + GATED_WINDOW_MOE_LM_PARTITION_RULES) + (
     (r"conv[^/]*/kernel$", P(None, None, None, MODEL_AXIS)),
     (r"kernel$", P(None, MODEL_AXIS)),
     (r"(bias|scale|embedding|carry0[^/]*)$", P(MODEL_AXIS)),

@@ -72,13 +72,12 @@ Two evaluation bodies, chosen by the engine from what it observes
     row-split projections' partial products).
 
   The state, the optimizer, the update and the emitted best member are
-  sharded over ``model`` in both.  Where the policy's three Pallas kernels
-  (attention, next-token head, selective scan) may be traced follows from
-  the same observations: TPU devices and whole members on a chip, which is
-  a mesh of one device or the ``gathered`` form
-  (``ops.pallas_attention.traced_why`` has the rule;
-  :meth:`ShardedESEngine._resolve_kernel_forms` what each kernel's own
-  shapes then decide).
+  sharded over ``model`` in both.  Where the policy's Pallas kernels may be
+  traced follows from the same observations: TPU devices and whole members
+  on a chip, which is a mesh of one device or the ``gathered`` form
+  (``ops.pallas_attention.traced_why`` has the rule).  Which kernels those
+  are and what each one's own shapes then decide is the policy's and the
+  kernels' to say (:meth:`ShardedESEngine._resolve_kernel_facts`).
 - ``materialised``: ``leaf[None] + σ·s·ε`` per member of a chunk, for
   full-rank noise and in-program low-rank noise on small trees.
 
@@ -114,12 +113,8 @@ from ..ops.lowrank import (lowrank_program_factors, lowrank_program_leaf_noise,
                            lowrank_tree_noise, lowrank_tree_weighted_sum)
 from ..ops.noise import (NoiseTable, leaf_noise_keys, program_noise,
                          row_noise_key, sample_pair_offsets)
-from ..ops.pallas_attention import (attention_form_why, call_form,
-                                    heads_in_pairs, kernel_scope, traced_why)
-from ..ops.pallas_combine import combine_form
-from ..ops.pallas_delta import delta_form
-from ..ops.pallas_head import head_form_why
-from ..ops.pallas_scan import scan_form
+from ..ops.kernel_facts import BuildScope, resolve as resolve_kernel_facts
+from ..ops.pallas_attention import kernel_scope, traced_why
 from ..ops.params import ParamSpec
 from ..ops.ranks import centered_rank_safe
 from .engine import (EngineConfig, _bf16_io_apply, _bf16_obs,
@@ -403,10 +398,12 @@ class ShardedESEngine:
                         and r * (shape[0] + shape[1]) < shape[0] * shape[1]):
                     self._factored[i] = (shape[0], shape[1])
 
-        # ---- partition rules → shardings (params + optax state) ----
+        # ---- partition rules → shardings (params + optax state): the
+        # caller's, else the policy's own for the leaves it names, tried
+        # before the general rules of a policy that states none
         self.partition_rules = tuple(
             partition_rules if partition_rules is not None
-            else DEFAULT_PARTITION_RULES)
+            else policy.partition_rules + DEFAULT_PARTITION_RULES)
         self.param_shardings = match_partition_rules(
             self.partition_rules, params_shape, mesh)
         opt_shape = jax.eval_shape(optimizer.init, params_shape)
@@ -456,7 +453,7 @@ class ShardedESEngine:
         if self.forward_form == "perturbed":
             logging.getLogger(__name__).info(
                 "centre_form %s (%s)", self.centre_form, self.centre_form_why)
-        self._resolve_kernel_forms()
+        self._resolve_kernel_facts()
 
         # ---- population layout (ghost-padded like the replicated path) --
         cfg = config
@@ -546,96 +543,42 @@ class ShardedESEngine:
             out_shardings=self.param_shardings)
         self._copy_into_compiled = None
 
-    def _resolve_kernel_forms(self):
-        """Which form each of the policy's five hand-written kernels takes
-        in this engine's programs (models/lm_blocks.py and
-        models/sambay_lm.py have the forms), resolved once, at build, from
-        the mesh, the centre's form, the sequence length and what the
-        policy states (run manifest + telemetry gauges).  ONE question is
-        the engine's, whether Mosaic kernels may be traced at all
-        (``pallas_attention.traced_why``: TPU devices and whole members on
-        a chip); the scope it opens around the policy's trace says that and
-        nothing more, and each kernel's form then follows from its own
-        shapes, as the call sites decide while they are traced."""
-        policy, horizon = self.policy, self.config.horizon
-        self.kernels_traced, self.kernels_traced_why = self._traced_rule()
-        # {attention layer kind: the band of its calls | None}, as the
-        # policy states them (one kind, no band, where it states none)
-        self._attention_windows = policy.attention_windows or {"causal": None}
-        # "kernel" | "xla": the form of the policy's causal attention, from
-        # the widths, the bands and the key heads it states, a pair of
-        # which may share a column block; None for a policy that has none
-        widths = policy.attention_widths
-        self.attention_form = self.attention_form_why = None
-        self.attention_form_by_kind = None
-        if widths is not None:
-            # the form, and which condition of the rule decided
-            self.attention_form, self.attention_form_why = (
-                self._attention_rule(widths))
-            # "<kind>:<form>,…": the form the calls of each attention layer
-            # kind take here (a kind with a window by call_form's rule)
-            paired = heads_in_pairs(widths, policy.attention_kv_heads)
-            self.attention_form_by_kind = ",".join(f"{kind}:" + call_form(
-                self.attention_form, window, horizon, paired)
-                for kind, window in self._attention_windows.items())
-        # "kernel" | "xla", and what decided: the form of the policy's
-        # next-token head (lm_blocks.score_next_tokens), by the head's own
-        # rule; None for a policy that states no head
-        self.head_form, self.head_form_why = (
-            (None, None) if policy.head_width is None
-            else head_form_why(
-                (self.kernels_traced, self.kernels_traced_why),
-                policy.head_width, horizon, jnp.dtype(self._dtype).itemsize))
-        # "kernel" | "xla": the form of the policy's selective scans
-        # (sambay_lm.selective_scan), by the scan's own rule; None for a
-        # policy that states no scan
-        self.scan_form = (
-            None if policy.scan_widths is None
-            else scan_form(self.kernels_traced, *policy.scan_widths, horizon))
-        # "kernel" | "xla": the form of the combine that closes a pass of
-        # the policy's expert layers (lm_blocks.routed_experts), by the
-        # combine's own rule; None for a policy that states no expert layer
-        self.combine_form = (
-            None if policy.combine_width is None
-            else combine_form(self.kernels_traced, policy.combine_width,
-                              horizon))
-        # "kernel" | "xla": the form of the policy's gated delta rule
-        # (delta_moe_lm.gated_delta_rule), by the rule's own; None for a
-        # policy that states no such layer
-        self.delta_form = (
-            None if policy.delta_widths is None
-            else delta_form(self.kernels_traced, *policy.delta_widths,
-                            horizon))
-        if {self.attention_form, self.head_form, self.scan_form,
-                self.combine_form, self.delta_form} != {None}:
+    def _resolve_kernel_facts(self):
+        """What the policy's hand-written kernels report of this engine's
+        programs, resolved once, at build (run manifest + telemetry
+        gauges).  ONE question is the engine's, whether Mosaic kernels may
+        be traced at all (``pallas_attention.traced_why``: TPU devices and
+        whole members on a chip); the scope it opens around the policy's
+        trace says that and nothing more, and each call site then takes its
+        form from its own shapes while it is traced.  Which kernels the
+        policy calls, with which widths, is the policy's to state
+        (``PolicyDeclaration.kernels``), and what each then reports its own
+        module's rule: the engine hands every rule what only it observed
+        and names none."""
+        scope = self._build_scope()
+        self.kernels_traced, self.kernels_traced_why = scope.traced
+        # {gauge / manifest name: value}, in the order the policy names its
+        # kernels; {} for a policy that calls none
+        self.kernel_facts = resolve_kernel_facts(scope, self.policy.kernels)
+        if self.kernel_facts:
             logging.getLogger(__name__).info(
-                "attention_form %s (%s; %s); head_form %s (%s); scan_form "
-                "%s; combine_form %s; delta_form %s",
-                self.attention_form, self.attention_form_why,
-                self.attention_form_by_kind, self.head_form,
-                self.head_form_why, self.scan_form, self.combine_form,
-                self.delta_form)
+                "kernels traced: %s (%s); %s", self.kernels_traced,
+                self.kernels_traced_why, self.kernel_facts)
 
-    def _traced_rule(self) -> tuple[bool, str]:
-        """``(may Mosaic kernels be traced in the policy's forward?,
-        why)``: see ``pallas_attention.traced_why``."""
-        return traced_why(self.mesh.devices.flat[0].platform,
-                          self.n_devices, self.centre_form)
-
-    def _attention_rule(self, widths) -> tuple[str, str]:
-        """``("kernel" | "xla", why)``: see ``attention_form_why``."""
-        return attention_form_why(
-            self.mesh.devices.flat[0].platform, self.n_devices,
-            widths, self.config.horizon,
-            next((w for w in self._attention_windows.values()
-                  if w is not None), None),
-            self.policy.attention_kv_heads, self.centre_form)
+    def _build_scope(self) -> BuildScope:
+        """What this engine observed, for the kernels' rules."""
+        platform = self.mesh.devices.flat[0].platform
+        return BuildScope(
+            platform=platform, n_devices=self.n_devices,
+            centre_form=self.centre_form,
+            traced=traced_why(platform, self.n_devices, self.centre_form),
+            horizon=self.config.horizon,
+            itemsize=jnp.dtype(self._dtype).itemsize)
 
     def _in_kernel_scope(self, rollout):
         """``rollout`` traced where this engine may trace Mosaic kernels:
-        the policy's ``attention_core``, ``score_next_tokens``,
-        ``selective_scan`` and ``routed_experts`` learn of it by the scope
-        open while they are traced, and take their XLA forms with none."""
+        the policy's call sites learn of it by the scope open while they
+        are traced, and take their XLA forms with none."""
         if not self.kernels_traced:
             return rollout
 
@@ -1247,19 +1190,18 @@ class ShardedESEngine:
              zip(self.leaf_shapes, self._leaf_dtypes)],
             self._centre_shardings)
 
-    # what this engine resolves at build, by attribute, under the names of
-    # its gauges and manifest entries (engine.py::build_fact_gauges): a
-    # form the next PR resolves is named HERE, and nowhere in ``ES``
+    # what this engine itself resolves at build, by attribute, under the
+    # names of its gauges and manifest entries (engine.py::build_fact_gauges)
     BUILD_FACTS = (
-        "forward_form", "noise_rows_per_generation", "attention_form",
-        "attention_form_why", "attention_form_by_kind", "head_form",
-        "head_form_why", "scan_form", "combine_form", "delta_form",
-        "mesh_shape",
+        "forward_form", "noise_rows_per_generation", "mesh_shape",
         "param_bytes_per_chip", "centre_form", "centre_form_why",
         "centre_bytes_per_chip")
 
     def build_facts(self) -> dict:
-        return {name: getattr(self, name) for name in self.BUILD_FACTS}
+        """The engine's own facts and, under the names their rules give
+        them, what the policy's kernels report (``kernel_facts``)."""
+        return {**{name: getattr(self, name) for name in self.BUILD_FACTS},
+                **self.kernel_facts}
 
     def memory_facts(self) -> dict:
         """XLA per-device byte facts of the compiled generation program

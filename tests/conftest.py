@@ -119,7 +119,7 @@ def tiny_widths(monkeypatch):
     takes the kernel at ANY widths: the suite's tiny models have heads of 8
     and sequences of 16, which the call's own rule
     (``pallas_attention.fits``: Mosaic's 128-lane column blocks) turns
-    away and the Pallas interpreter runs.  The engine-level rule
+    away and the Pallas interpreter runs.  The rule said of a MODEL
     (``attention_form_why``) is not touched.  A fake substituted by the
     test: nothing in the package reads it."""
     from estorch_tpu.ops import pallas_attention
@@ -129,26 +129,28 @@ def tiny_widths(monkeypatch):
 
 @pytest.fixture
 def as_tpu(monkeypatch):
-    """``with as_tpu():`` — sharded engines built inside resolve their
-    kernels' rules as on a mesh of TPU devices (where Mosaic kernels may be
-    traced, ``ShardedESEngine._traced_rule``, and the attention's form)
-    from the suite's CPU mesh as it is: its size, the centre's form, the
-    shapes.  ``_pallas_interpret`` still comes from the mesh, so what the
-    rules admit runs under the Pallas interpreter.  A fake substituted by
-    the test: nothing in the package reads it."""
-    from estorch_tpu.ops import pallas_attention
-    from estorch_tpu.parallel import sharded
+    """``with as_tpu():`` — sharded engines built inside hand their
+    kernels' rules the scope of a mesh of TPU devices
+    (``ShardedESEngine._build_scope``: the platform, and with it where
+    Mosaic kernels may be traced) from the suite's CPU mesh as it is: its
+    size, the centre's form, the shapes.  ``_pallas_interpret`` still comes
+    from the mesh, so what the rules admit runs under the Pallas
+    interpreter.  A fake substituted by the test: nothing in the package
+    reads it."""
+    from estorch_tpu.ops.pallas_attention import traced_why
+    from estorch_tpu.parallel.sharded import ShardedESEngine
 
-    def on_tpu(rule):
-        return lambda platform, *observed: rule("tpu", *observed)
+    observed = ShardedESEngine._build_scope
+
+    def on_tpu(self):
+        scope = observed(self)
+        return scope._replace(platform="tpu", traced=traced_why(
+            "tpu", scope.n_devices, scope.centre_form))
 
     @contextlib.contextmanager
     def forced():
         with monkeypatch.context() as m:
-            m.setattr(sharded, "traced_why",
-                      on_tpu(pallas_attention.traced_why))
-            m.setattr(sharded, "attention_form_why",
-                      on_tpu(pallas_attention.attention_form_why))
+            m.setattr(ShardedESEngine, "_build_scope", on_tpu)
             yield
 
     return forced
@@ -158,22 +160,27 @@ def as_tpu(monkeypatch):
 def kernel_attention(monkeypatch, tiny_widths):
     """``with kernel_attention():`` — sharded engines built inside open
     their ``kernel_scope`` on the suite's CPU mesh, where the rule says no
-    kernel may be traced, and resolve ``attention_form == "kernel"`` at the
-    tiny widths of the suite's models, whose calls then take the kernel
-    (``tiny_widths``); ``_pallas_interpret`` comes from the mesh, so the
-    kernels run under the Pallas interpreter.  The next-token head's and
-    the scan's forms follow from their own shapes (ops/pallas_head.py,
-    ops/pallas_scan.py).  A fake substituted by the test: nothing in the
-    package reads it."""
+    kernel may be traced, and report ``attention_form == "kernel"`` at the
+    tiny widths of the suite's models (the rule's conditions on the shapes
+    are waived, as ``tiny_widths`` waives them for the calls, which then
+    take the kernel); ``_pallas_interpret`` comes from the mesh, so the
+    kernels run under the Pallas interpreter.  The next-token head's, the
+    scan's, the combine's and the delta rule's forms follow from their own
+    shapes (ops/pallas_head.py, ops/pallas_scan.py, ...).  A fake
+    substituted by the test: nothing in the package reads it."""
+    from estorch_tpu.ops import pallas_attention
     from estorch_tpu.parallel.sharded import ShardedESEngine
+
+    observed = ShardedESEngine._build_scope
 
     @contextlib.contextmanager
     def forced():
         with monkeypatch.context() as m:
-            m.setattr(ShardedESEngine, "_traced_rule",
-                      lambda self: (True, "forced by the test"))
-            m.setattr(ShardedESEngine, "_attention_rule",
-                      lambda self, widths: ("kernel", "forced by the test"))
+            m.setattr(ShardedESEngine, "_build_scope",
+                      lambda self: observed(self)._replace(
+                          traced=(True, "forced by the test")))
+            m.setattr(pallas_attention, "_shape_failures",
+                      lambda *shapes: [])
             yield
 
     return forced

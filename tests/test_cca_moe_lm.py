@@ -19,12 +19,13 @@ import cca_moe_tiny as tiny_model
 from estorch_tpu.models import CCAMoELM, lm_blocks
 from estorch_tpu.models.perturbed import (lowrank_spec_for,
                                           perturbed_headwise_dense)
-from estorch_tpu.ops.pallas_attention import (attention_form_why,
+from estorch_tpu.ops.pallas_attention import (attention_facts,
+                                              attention_form_why,
                                               kernel_scope)
-from estorch_tpu.parallel.mesh import (CCA_MOE_LM_PARTITION_RULES,
-                                       DEFAULT_PARTITION_RULES,
-                                       HYBRID_LM_PARTITION_RULES,
-                                       MOE_LM_PARTITION_RULES,
+from estorch_tpu.ops.pallas_combine import combine_facts
+from estorch_tpu.ops.pallas_head import head_facts
+from estorch_tpu.ops.pallas_scan import scan_facts
+from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
@@ -774,9 +775,12 @@ def test_the_declaration_names_leaves_the_tree_has(tiny):
         p for p in shapes if "/moe/router" in p}
     assert len(stated.float32_leaves) == 3 * 11
     assert stated.dense_noise_leaves == () and stated.leaf_rows == {}
-    assert (stated.attention_widths, stated.attention_kv_heads,
-            stated.head_width, stated.attention_windows) == (8, 2, 32, None)
-    assert stated.scan_widths is None and stated.selection_bytes is None
+    # heads of 8 over 2 key heads, one kind of layer and no band; the
+    # head and the combine at the hidden width; no scan
+    kernels = dict(stated.kernels)
+    assert (kernels[attention_facts], kernels[head_facts],
+            kernels[combine_facts]) == ((8, 2), (32,), (32,))
+    assert scan_facts not in kernels and stated.selection_bytes is None
     assert stated.outputs == ("expert_load",)
     assert stated.facts == {
         "experts_held": 2, "experts_total": 4, "experts_per_token": 1,
@@ -843,10 +847,11 @@ def test_published_sizes_and_layouts(ref):
     assert (hybrid["rope_theta"], hybrid["partial_rotary_factor"]) == (
         lm.rope_theta, lm.partial_rotary_factor)
     stated = lm.declaration()
-    assert (stated.attention_widths, stated.head_width) == (128, 2048)
-    assert attention_form_why("tpu", 1, stated.attention_widths,
-                              cfg["horizon"], None,
-                              stated.attention_kv_heads)[0] == "kernel"
+    kernels = dict(stated.kernels)
+    widths, kv_heads = kernels[attention_facts]
+    assert (widths, kernels[head_facts]) == (128, (2048,))
+    assert attention_form_why("tpu", 1, widths, cfg["horizon"], None,
+                              kv_heads)[0] == "kernel"
     shapes = lm.param_shapes()
     paths = ["/".join(str(k.key) for k in p) for p, _ in
              jax.tree_util.tree_flatten_with_path(shapes)[0]]
@@ -871,7 +876,7 @@ def test_published_sizes_and_layouts(ref):
     assert dense == {"scale", "conv_time", "conv_time_bias", "conv_head_bias",
                      "temperature", "router_down_bias", "router_state", "b1",
                      "b2", "b3", "router_bias"}
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
+    assert unmatched_leaves(stated.partition_rules, shapes) == {}
     assert about["expert_flops_per_member_step"] == int(
         layers * 1 * 8 / 16 * 2 * 3 * 2048 * 2048)
     assert about["dense_flops_per_member_step"] == layers * 2 * attention
@@ -886,19 +891,25 @@ def test_published_sizes_and_layouts(ref):
 
 def test_no_leaf_falls_to_the_catch_all(tiny):
     shapes = tiny["lm"].param_shapes()
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
-    own = (HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
-           + CCA_MOE_LM_PARTITION_RULES)
+    # the model's own rules name every leaf: the blocks' (models/
+    # lm_blocks.py) and the latent's and its router's, which they do not
+    own = tiny["lm"].declaration().partition_rules
     assert unmatched_leaves(own, shapes) == {}
+    assert own[:len(lm_blocks.DECODER_PARTITION_RULES)] == (
+        lm_blocks.DECODER_PARTITION_RULES)
     assert unmatched_leaves(
-        HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES, shapes) != {}
+        lm_blocks.DECODER_PARTITION_RULES + lm_blocks.EXPERT_PARTITION_RULES,
+        shapes) != {}
 
 
 @pytest.mark.parametrize("pop, model", [(2, 4), (1, 2)])
 def test_partition_rules_name_the_new_leaves(devices8, pop, model):
     mesh = hyperscale_mesh(pop, model, devices8[:pop * model])
-    shapes = CCAMoELM(**{**TINY, "num_experts": 4}).param_shapes()
-    sh = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
+    lm = CCAMoELM(**{**TINY, "num_experts": 4})
+    shapes = lm.param_shapes()
+    sh = match_partition_rules(
+        lm.declaration().partition_rules + DEFAULT_PARTITION_RULES, shapes,
+        mesh)
 
     def spec(*path):
         node = sh
@@ -968,7 +979,7 @@ class TestThroughTheShardedEngine:
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
         assert es.engine.centre_form == (
             centre_form if model > 1 else "split")
-        assert es.engine.attention_form == "xla"
+        assert es.engine.kernel_facts["attention_form"] == "xla"
         report = es.engine.sharding_report()
         assert report["layer_01/moe/experts/gate"].startswith(
             "PartitionSpec('model'")
@@ -990,10 +1001,12 @@ class TestThroughTheShardedEngine:
     def test_one_device_run_its_gauges_and_its_counters(self, one_device):
         es = one_device["es"]
         assert es.engine.forward_form == "perturbed"
-        assert (es.engine.attention_form, es.engine.head_form) == ("xla",
-                                                                   "xla")
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["head_form"]) == (
+                    "xla", "xla")
         # the expert layers' combine too: the scatter-add on a CPU mesh
-        assert (es.engine.combine_form, es.obs.counters.get("combine_form"),
+        assert (es.engine.kernel_facts["combine_form"],
+                es.obs.counters.get("combine_form"),
                 es.run_manifest()["config"]["combine_form"]) == ("xla",) * 3
         assert [r["env_steps"] for r in es.history] == [8 * 21] * 2
         assert -4.6 < es.history[0]["reward_mean"] < -3.9   # about -log 64
@@ -1080,9 +1093,11 @@ class TestThroughTheShardedEngine:
         with kernel_attention():
             kern = _es(devices8[:1], 1, compute_dtype=dtype,
                        policy_kwargs=wide, agent_kwargs=env)
-        assert (ref_es.engine.attention_form,
-                kern.engine.attention_form) == ("xla", "kernel")
-        assert kern.engine.attention_form_by_kind == "causal:kernel"
+        assert (ref_es.engine.kernel_facts["attention_form"],
+                kern.engine.kernel_facts["attention_form"]) == (
+                    "xla", "kernel")
+        assert kern.engine.kernel_facts["attention_form_by_kind"] == (
+            "causal:kernel")
         programs = [str(jax.make_jaxpr(es.engine._generation_step)(
             es.state, es.table.data)) for es in (ref_es, kern)]
         assert [text.count("jaxpr=causal_attention")

@@ -26,9 +26,14 @@ from estorch_tpu.models.delta_moe_lm import (gated_delta_rule,
                                              unit_lower_inverse)
 from estorch_tpu.ops import pallas_attention
 from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
-from estorch_tpu.ops.pallas_attention import attention_form_why, kernel_scope
+from estorch_tpu.ops.pallas_attention import (attention_facts,
+                                              attention_form_why,
+                                              kernel_scope)
+from estorch_tpu.ops.pallas_combine import combine_facts
+from estorch_tpu.ops.pallas_delta import delta_facts
+from estorch_tpu.ops.pallas_head import head_facts
+from estorch_tpu.ops.pallas_scan import scan_facts
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       DELTA_MOE_LM_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
@@ -722,10 +727,14 @@ def test_init_draws_the_declared_tree(tiny):
 
 def test_the_declaration(tiny):
     stated = tiny["lm"].declaration()
-    assert stated.attention_windows is None
-    assert (stated.attention_widths, stated.attention_kv_heads,
-            stated.head_width, stated.combine_width, stated.scan_widths) == (
-        16, 2, 32, 32, None)
+    # heads of 16 over 2 key heads, one kind of attention layer and no
+    # band; the head and the combine at the hidden width; the delta rule's
+    # heads of 8 and 8 in chunks of 8; no scan
+    kernels = dict(stated.kernels)
+    assert (kernels[attention_facts], kernels[head_facts],
+            kernels[combine_facts], kernels[delta_facts]) == (
+        (16, 2), (32,), (32,), (8, 8, 8))
+    assert scan_facts not in kernels
     assert stated.leaf_rows == {"head/kernel": 8}
     assert stated.leaf_rows_per_token == dict.fromkeys(
         tiny["lm"].stacked_leaves, 3 * 1.25 / 4)
@@ -739,7 +748,8 @@ def test_the_declaration(tiny):
         "delta_chunk": 8}
     # a stack without a full layer states no attention at all
     alone = dataclasses.replace(tiny["lm"], layer_types=("linear",))
-    assert alone.declaration().attention_widths is None
+    assert attention_facts not in dict(alone.declaration().kernels)
+    assert delta_facts in dict(alone.declaration().kernels)
 
 
 def test_published_sizes_and_layouts(ref):
@@ -798,16 +808,17 @@ def test_published_sizes_and_layouts(ref):
         assert fields[key] == cfg[key], key
     assert cfg["horizon"] == 16384
     stated = lm.declaration()
-    assert (stated.attention_widths, stated.head_width, stated.combine_width,
-            stated.attention_kv_heads, stated.attention_windows) == (
-        256, 2048, 2048, 2, None)
+    kernels = dict(stated.kernels)
+    widths, kv_heads = kernels[attention_facts]
+    assert (widths, kv_heads, kernels[head_facts], kernels[combine_facts],
+            kernels[delta_facts]) == (
+        256, 2, (2048,), (2048,), (128, 128, 64))
     assert stated.leaf_rows_per_token == dict.fromkeys(
         lm.stacked_leaves, 10 * 1.25 / 16)
     # 16 query heads over 2 key heads of 256 at 16,384: two column blocks a
     # head, so the full layer takes the kernel on one chip
-    form, _ = attention_form_why("tpu", 1, stated.attention_widths,
-                                 cfg["horizon"], None,
-                                 stated.attention_kv_heads)
+    form, _ = attention_form_why("tpu", 1, widths, cfg["horizon"], None,
+                                 kv_heads)
     assert form == "kernel"
     assert pallas_attention.fits(256, 0, 256, None, 16384)
     shapes = lm.param_shapes()
@@ -831,7 +842,7 @@ def test_published_sizes_and_layouts(ref):
     dense = {paths[i].rsplit("/", 1)[1] for i, *_ in spec.dense_leaves}
     assert dense == {"scale", "norm_scale", "A_log", "dt_bias", "conv",
                      "shared_gate"}
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
+    assert unmatched_leaves(stated.partition_rules, shapes) == {}
     assert about["expert_flops_per_member_step"] == int(
         layers * 10 * 32 / 512 * 2 * 3 * 2048 * 512)
     shared = 3 * 2048 * 512 + 2048
@@ -878,11 +889,18 @@ def test_the_seeded_weights_keep_the_mechanism_alive(ref):
 
 def test_no_leaf_falls_to_the_catch_all(tiny):
     """The linear mixer's leaves and the shared expert's gate are named by
-    this model's rules, the rest by rules that were there."""
+    this model's own rules, the rest by the blocks' (models/lm_blocks.py)
+    that it lists ahead of them."""
     shapes = tiny["lm"].param_shapes()
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
-    without = tuple(r for r in DEFAULT_PARTITION_RULES
-                    if r not in DELTA_MOE_LM_PARTITION_RULES)
+    own = tiny["lm"].declaration().partition_rules
+    assert unmatched_leaves(own, shapes) == {}
+    blocks = (lm_blocks.DECODER_PARTITION_RULES
+              + lm_blocks.EXPERT_PARTITION_RULES)
+    assert own[:len(blocks)] == blocks
+    # what this model adds to the blocks' rules, and the general ones: the
+    # model's list with its own part taken out
+    without = blocks + (own[len(blocks)],) + DEFAULT_PARTITION_RULES
+    assert own[len(blocks)][0] == r"(q_norm|k_norm)/scale$"
     missed = {p.split("/", 1)[1] for p in unmatched_leaves(without, shapes)}
     # (``dt_bias`` and ``norm_scale`` would fall to the suffix rules for
     # biases and scales, which cut them over ``model``)
@@ -894,8 +912,11 @@ def test_no_leaf_falls_to_the_catch_all(tiny):
 @pytest.mark.parametrize("pop, model", [(2, 4), (1, 2)])
 def test_partition_rules_name_the_leaves(devices8, pop, model):
     mesh = hyperscale_mesh(pop, model, devices8[:pop * model])
-    shapes = DeltaMoELM(**TINY).param_shapes()
-    sh = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
+    lm = DeltaMoELM(**TINY)
+    shapes = lm.param_shapes()
+    sh = match_partition_rules(
+        lm.declaration().partition_rules + DEFAULT_PARTITION_RULES, shapes,
+        mesh)
 
     def spec(*path):
         node = sh
@@ -971,18 +992,22 @@ def forward_hash(cls, module) -> str:
 @pytest.mark.parametrize("name", ["hybrid", "looped", "moe", "sambay",
                                   "indexed_moe", "cca_moe", "window_moe"])
 def test_the_other_models_outputs_are_what_they_were(name):
-    """What this model added to ``lm_blocks`` and to the partition rules
-    leaves every other model's forward the PROGRAM it was, operation for
-    operation (so its outputs, bit for bit), and no rule of this model names
-    a leaf of theirs."""
+    """What this model added to ``lm_blocks`` leaves every other model's
+    forward the PROGRAM it was, operation for operation (so its outputs, bit
+    for bit), and no rule of this model can reach a leaf of theirs: their
+    own rules, which the engine tries first, name every one."""
     cls, module = _other_models()[name]
     assert forward_hash(cls, module) == PARENT_PROGRAMS[name]
-    shapes = cls(**module.TINY).param_shapes()
-    without = tuple(r for r in DEFAULT_PARTITION_RULES
-                    if r not in DELTA_MOE_LM_PARTITION_RULES)
+    other = cls(**module.TINY)
+    shapes = other.param_shapes()
+    own = other.declaration().partition_rules
+    assert unmatched_leaves(own, shapes) == {}
     mesh = hyperscale_mesh(1, 2, jax.devices()[:2])
-    ours = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
-    theirs = match_partition_rules(without, shapes, mesh)
+    ours = match_partition_rules(
+        own + DeltaMoELM(**TINY).declaration().partition_rules
+        + DEFAULT_PARTITION_RULES, shapes, mesh)
+    theirs = match_partition_rules(own + DEFAULT_PARTITION_RULES, shapes,
+                                   mesh)
     assert all(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
         lambda a, b: a.spec == b.spec, ours, theirs)))
 
@@ -1023,7 +1048,7 @@ class TestThroughTheShardedEngine:
         assert es.engine.forward_form == "perturbed"
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
         assert es.engine.centre_form == centre_form
-        assert es.engine.attention_form == "xla"
+        assert es.engine.kernel_facts["attention_form"] == "xla"
         report = es.engine.sharding_report()
         assert report["layer_01/moe/experts/gate"].startswith(
             "PartitionSpec('model'")
@@ -1044,7 +1069,8 @@ class TestThroughTheShardedEngine:
     def test_records_gauges_and_manifest(self, one_device):
         es, records = one_device["es"], one_device["records"]
         assert es.engine.forward_form == "perturbed"
-        assert (es.engine.attention_form, es.engine.combine_form) == (
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["combine_form"]) == (
             "xla", "xla")
         pairs = 8 * 21 * 3 * 2          # members x tokens x k x layers
         for r in records:

@@ -21,11 +21,11 @@ from pallas_costs import pallas_calls
 from estorch_tpu.models import GatedWindowMoELM, MoELM, WindowMoELM, lm_blocks
 from estorch_tpu.models.gated_window_moe_lm import FULL_LAYER, SLIDING_LAYER
 from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
-from estorch_tpu.ops.pallas_attention import call_form, kernel_scope
+from estorch_tpu.ops.pallas_attention import (attention_facts, call_form,
+                                              kernel_scope)
+from estorch_tpu.ops.pallas_combine import combine_facts
+from estorch_tpu.ops.pallas_head import head_facts
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       GATED_WINDOW_MOE_LM_PARTITION_RULES,
-                                       HYBRID_LM_PARTITION_RULES,
-                                       MOE_LM_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
@@ -736,10 +736,12 @@ def test_init_draws_the_declared_tree(tiny):
 
 def test_the_declaration(tiny):
     stated = tiny["lm"].declaration()
-    assert stated.attention_windows == {"sliding": 6, "full": None}
-    assert list(stated.attention_windows) == ["sliding", "full"]
-    assert (stated.attention_widths, stated.attention_kv_heads,
-            stated.head_width, stated.combine_width) == (8, 2, 32, 32)
+    # heads of 8 over 2 key heads; each kind of attention layer with its
+    # band, in layer order; the head and the combine at the hidden width
+    kernels = dict(stated.kernels)
+    assert kernels[attention_facts] == (
+        8, 2, (("sliding", 6), ("full", None)))
+    assert (kernels[head_facts], kernels[combine_facts]) == ((32,), (32,))
     assert stated.leaf_rows == {"head/kernel": 8}
     assert stated.leaf_rows_per_token == dict.fromkeys(
         tiny["lm"].stacked_leaves, 3 * 1.25 / 4)
@@ -754,7 +756,7 @@ def test_the_declaration(tiny):
     one = GatedWindowMoELM(**{
         **TINY, "layer_types": (SLIDING,), "mlp_layer_types": ("sparse",),
         "num_attention_heads_per_layer": (6,)}).declaration()
-    assert one.attention_windows == {"sliding": 6}
+    assert dict(one.kernels)[attention_facts][2] == (("sliding", 6),)
     assert "full_heads" not in one.facts and one.facts["full_layers"] == 0
 
 
@@ -793,8 +795,8 @@ def test_published_sizes_and_counts(ref):
     assert total == 33_442_596_864
     assert cfg["published"]["parameters"].startswith("33,442,596,864")
     assert lm.rotary_dim(FULL) == 64 and lm.rotary_dim(SLIDING) == 128
-    assert lm.declaration().attention_windows == {"sliding": 512,
-                                                  "full": None}
+    assert dict(lm.declaration().kernels)[attention_facts][2] == (
+        ("sliding", 512), ("full", None))
     # the system's flat layout is the reference's
     paths = ["/".join(str(k.key) for k in p) for p, _ in
              jax.tree_util.tree_flatten_with_path(shapes)[0]]
@@ -812,22 +814,26 @@ def test_published_sizes_and_counts(ref):
 # ---------------------------------------------------- (i) partition rules
 
 def test_no_leaf_falls_to_the_catch_all(tiny):
-    """The model's leaves are named by rules that were there but for the
-    gate's narrow projection, which the new table names."""
+    """The model's leaves are named by the blocks' rules
+    (models/lm_blocks.py) but for the gate's narrow projection, which the
+    model's own table names."""
     shapes = tiny["lm"].param_shapes()
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
-    older = HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
-    assert set(unmatched_leaves(older, shapes)) == {
+    blocks = (lm_blocks.DECODER_PARTITION_RULES
+              + lm_blocks.EXPERT_PARTITION_RULES)
+    assert set(unmatched_leaves(blocks, shapes)) == {
         f"layer_{i:02d}/attn/head_gate" for i in range(4)}
     assert unmatched_leaves(
-        older + GATED_WINDOW_MOE_LM_PARTITION_RULES, shapes) == {}
+        tiny["lm"].declaration().partition_rules, shapes) == {}
 
 
 @pytest.mark.parametrize("pop, model", [(2, 4), (1, 2)])
 def test_partition_rules_name_the_leaves(devices8, pop, model):
     mesh = hyperscale_mesh(pop, model, devices8[:pop * model])
-    shapes = GatedWindowMoELM(**TINY).param_shapes()
-    sh = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
+    lm = GatedWindowMoELM(**TINY)
+    shapes = lm.param_shapes()
+    sh = match_partition_rules(
+        lm.declaration().partition_rules + DEFAULT_PARTITION_RULES, shapes,
+        mesh)
 
     def spec(*path):
         node = sh
@@ -885,7 +891,7 @@ class TestThroughTheShardedEngine:
         assert es.engine.forward_form == "perturbed"
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
         assert es.engine.centre_form == centre_form
-        assert es.engine.attention_form == "xla"
+        assert es.engine.kernel_facts["attention_form"] == "xla"
         report = es.engine.sharding_report()
         assert report["layer_01/moe/experts/gate"].startswith(
             "PartitionSpec('model'")
@@ -905,8 +911,10 @@ class TestThroughTheShardedEngine:
     def test_one_device_run_its_gauges_and_its_counters(self, one_device):
         es = one_device["es"]
         assert es.engine.forward_form == "perturbed"
-        assert (es.engine.attention_form, es.engine.head_form,
-                es.engine.combine_form) == ("xla", "xla", "xla")
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["head_form"],
+                es.engine.kernel_facts["combine_form"]) == ("xla", "xla",
+                                                            "xla")
         assert [r["env_steps"] for r in es.history] == [8 * 21] * 2
         assert -4.6 < es.history[0]["reward_mean"] < -3.9   # about -log 64
         gauges = es.obs.counters
@@ -982,10 +990,12 @@ class TestThroughTheShardedEngine:
         with kernel_attention():
             kern = _es(devices8[:1], 1, compute_dtype=dtype,
                        policy_kwargs=wide, agent_kwargs=env)
-        assert (ref_es.engine.attention_form,
-                kern.engine.attention_form) == ("xla", "kernel")
-        assert ref_es.engine.attention_form_by_kind == "sliding:xla,full:xla"
-        assert kern.engine.attention_form_by_kind == (
+        assert (ref_es.engine.kernel_facts["attention_form"],
+                kern.engine.kernel_facts["attention_form"]) == (
+                    "xla", "kernel")
+        assert ref_es.engine.kernel_facts["attention_form_by_kind"] == (
+            "sliding:xla,full:xla")
+        assert kern.engine.kernel_facts["attention_form_by_kind"] == (
             f"sliding:{sliding},full:kernel")
         assert kern.run_manifest()["config"][
             "attention_form_by_kind"] == f"sliding:{sliding},full:kernel"

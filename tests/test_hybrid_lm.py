@@ -14,8 +14,7 @@ from estorch_tpu.models import HybridLM
 from estorch_tpu.models.perturbed import (perturbed_dense, perturbed_embed,
                                           perturbed_leaf)
 from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
-from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       unmatched_leaves)
+from estorch_tpu.parallel.mesh import unmatched_leaves
 
 # the models here are tiny (heads of 8, sequences of 16): inside a
 # ``kernel_scope`` their attention calls take the kernel all the same
@@ -401,4 +400,4 @@ def test_published_sizes_and_layouts(ref):
     assert dense == {"A_log", "D", "dt_bias", "scale", "norm_scale",
                      "conv_x_kernel", "conv_x_bias", "conv_bc_kernel",
                      "conv_bc_bias"}
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
+    assert unmatched_leaves(lm.declaration().partition_rules, shapes) == {}

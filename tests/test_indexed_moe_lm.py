@@ -19,12 +19,11 @@ from jax.flatten_util import ravel_pytree
 import indexed_moe_tiny as tiny_model
 from estorch_tpu.models import IndexedMoELM, MoELM, lm_blocks
 from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
-from estorch_tpu.ops.pallas_attention import (attention_form_why,
+from estorch_tpu.ops.pallas_attention import (attention_facts,
+                                              attention_form_why,
                                               kernel_scope)
+from estorch_tpu.ops.pallas_head import head_facts
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       HYBRID_LM_PARTITION_RULES,
-                                       INDEXED_MOE_LM_PARTITION_RULES,
-                                       MOE_LM_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
@@ -711,11 +710,12 @@ def test_published_sizes_and_layouts(ref):
         assert fields[key] == cfg["sa_config"][key], key
     assert list(lm.mrope_section) == cfg["rope_scaling"]["mrope_section"]
     stated = lm.declaration()
-    assert (stated.attention_widths, stated.head_width,
-            stated.attention_windows) == (128, 2048, {"selected": None})
-    assert attention_form_why("tpu", 1, stated.attention_widths,
-                              cfg["horizon"], None,
-                              stated.attention_kv_heads)[0] == "kernel"
+    kernels = dict(stated.kernels)
+    widths, kv_heads, windows = kernels[attention_facts]
+    assert (widths, kernels[head_facts], windows) == (
+        128, (2048,), (("selected", None),))
+    assert attention_form_why("tpu", 1, widths, cfg["horizon"], None,
+                              kv_heads)[0] == "kernel"
     # 16,384 positions: the selection and the index scores of one member
     assert lm.selection_bytes(16384) == 16384 ** 2 + 4 * 16 * 512 * 16384
     shapes = lm.param_shapes()
@@ -740,7 +740,7 @@ def test_published_sizes_and_layouts(ref):
     assert {"index_q", "index_k", "index_w", "router"} <= factored
     dense = {paths[i].rsplit("/", 1)[1] for i, *_ in spec.dense_leaves}
     assert dense == {"scale", "bias"}
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
+    assert unmatched_leaves(stated.partition_rules, shapes) == {}
     assert about["expert_flops_per_member_step"] == int(
         layers * 8 * 16 / 128 * 2 * 3 * 2048 * 768)
     assert about["dense_flops_per_member_step"] == layers * 2 * (
@@ -749,19 +749,25 @@ def test_published_sizes_and_layouts(ref):
 
 def test_no_leaf_falls_to_the_catch_all(tiny):
     shapes = tiny["lm"].param_shapes()
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
-    own = (HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES
-           + INDEXED_MOE_LM_PARTITION_RULES)
+    # the model's own rules name every leaf: the blocks' (models/
+    # lm_blocks.py) and the indexer's and the per-head norms', which the
+    # blocks' do not
+    own = tiny["lm"].declaration().partition_rules
     assert unmatched_leaves(own, shapes) == {}
-    assert unmatched_leaves(
-        HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES, shapes) != {}
+    blocks = (lm_blocks.DECODER_PARTITION_RULES
+              + lm_blocks.EXPERT_PARTITION_RULES)
+    assert own[:len(blocks)] == blocks
+    assert unmatched_leaves(blocks, shapes) != {}
 
 
 @pytest.mark.parametrize("pop, model", [(2, 4), (1, 2)])
 def test_partition_rules_name_the_new_leaves(devices8, pop, model):
     mesh = hyperscale_mesh(pop, model, devices8[:pop * model])
-    shapes = IndexedMoELM(**TINY).param_shapes()
-    sh = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
+    lm = IndexedMoELM(**TINY)
+    shapes = lm.param_shapes()
+    sh = match_partition_rules(
+        lm.declaration().partition_rules + DEFAULT_PARTITION_RULES, shapes,
+        mesh)
 
     def spec(*path):
         node = sh
@@ -824,7 +830,7 @@ class TestThroughTheShardedEngine:
         # both layouts of the centre; nothing to gather on a model axis of 1
         assert es.engine.centre_form == (
             centre_form if model > 1 else "split")
-        assert es.engine.attention_form == "xla"
+        assert es.engine.kernel_facts["attention_form"] == "xla"
         report = es.engine.sharding_report()
         assert report["layer_01/moe/experts/gate"].startswith(
             "PartitionSpec('model'")
@@ -845,10 +851,12 @@ class TestThroughTheShardedEngine:
     def test_one_device_run_its_gauges_and_its_counters(self, one_device):
         es = one_device["es"]
         assert es.engine.forward_form == "perturbed"
-        assert (es.engine.attention_form, es.engine.head_form) == ("xla",
-                                                                   "xla")
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["head_form"]) == (
+                    "xla", "xla")
         # the expert layers' combine too: the scatter-add on a CPU mesh
-        assert (es.engine.combine_form, es.obs.counters.get("combine_form"),
+        assert (es.engine.kernel_facts["combine_form"],
+                es.obs.counters.get("combine_form"),
                 es.run_manifest()["config"]["combine_form"]) == ("xla",) * 3
         assert [r["env_steps"] for r in es.history] == [8 * 21] * 2
         assert -4.6 < es.history[0]["reward_mean"] < -3.9   # about -log 64
@@ -936,9 +944,11 @@ class TestThroughTheShardedEngine:
         with kernel_attention():
             kern = _es(devices8[:1], 1, compute_dtype=dtype,
                        policy_kwargs=wide, agent_kwargs=env)
-        assert (ref_es.engine.attention_form,
-                kern.engine.attention_form) == ("xla", "kernel")
-        assert kern.engine.attention_form_by_kind == "selected:kernel"
+        assert (ref_es.engine.kernel_facts["attention_form"],
+                kern.engine.kernel_facts["attention_form"]) == (
+                    "xla", "kernel")
+        assert kern.engine.kernel_facts["attention_form_by_kind"] == (
+            "selected:kernel")
         assert kern.run_manifest()["config"][
             "attention_form_by_kind"] == "selected:kernel"
         programs = [str(jax.make_jaxpr(es.engine._generation_step)(

@@ -17,7 +17,6 @@ from estorch_tpu.models import HybridLM, LoopedLM
 from estorch_tpu.models import lm_blocks
 from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       HYBRID_LM_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
@@ -467,14 +466,21 @@ def test_published_sizes_and_layouts(ref):
     assert {"exit_gate/kernel", "exit_gate/bias"} <= dense
     assert {p.rsplit("/", 1)[1] for p in dense} == {"scale", "kernel",
                                                     "bias"}
-    assert unmatched_leaves(HYBRID_LM_PARTITION_RULES, shapes) == {}
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
+    # the model's own rules name every leaf: the decoder's frame that
+    # models/lm_blocks.py has, and the exit gate beside it
+    own = lm.declaration().partition_rules
+    assert unmatched_leaves(own, shapes) == {}
+    assert set(unmatched_leaves(lm_blocks.DECODER_PARTITION_RULES, shapes)
+               ) == {"exit_gate/kernel"}        # (its bias is one value)
 
 
 def test_partition_rules_shard_the_new_leaves(devices8):
     mesh = hyperscale_mesh(2, 2, devices8[:4])
-    shapes = LoopedLM(**loop_tiny.TINY).param_shapes()
-    sh = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
+    lm = LoopedLM(**loop_tiny.TINY)
+    shapes = lm.param_shapes()
+    sh = match_partition_rules(
+        lm.declaration().partition_rules + DEFAULT_PARTITION_RULES, shapes,
+        mesh)
     spec = lambda *path: tuple(  # noqa: E731
         jax.tree_util.tree_reduce(lambda a, b: b, sh[path[0]][path[1]]
                                   if len(path) == 2 else

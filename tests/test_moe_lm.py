@@ -4,6 +4,7 @@ loops over layers and over the held experts with a boolean mask each, one
 full masked softmax per head, the rotation written from the formula, every
 perturbed leaf (and expert) materialised, routes of its own."""
 
+import dataclasses
 import math
 import re
 
@@ -21,9 +22,9 @@ from estorch_tpu.models.perturbed import (leaf_columns, perturbed_dense,
 from estorch_tpu.ops.lowrank import (lowrank_tree_noise,
                                      lowrank_tree_weighted_sum,
                                      make_lowrank_tree_spec)
-from estorch_tpu.ops.pallas_attention import attention_form
+from estorch_tpu.ops.pallas_attention import (attention_facts,
+                                              attention_form)
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       MOE_LM_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
@@ -499,14 +500,17 @@ def test_the_models_say_the_width_their_heads_are_scored_at(tiny):
     from estorch_tpu.models import HybridLM
 
     assert tiny["lm"].qk_head_dim == 8 + 4
-    assert tiny["lm"].declaration().attention_widths == (8, 4, 6)
-    assert LoopedLM(**loop_tiny.TINY).declaration().attention_widths == 8
+    def widths(lm):
+        return dict(lm.declaration().kernels)[attention_facts][0]
+
+    assert widths(tiny["lm"]) == (8, 4, 6)
+    assert widths(LoopedLM(**loop_tiny.TINY)) == 8
     hybrid = HybridLM(**lm_tiny.TINY)
-    assert hybrid.declaration().attention_widths == hybrid.head_dim
+    assert widths(hybrid) == hybrid.head_dim
     published = MoELM(**moe_tiny.published()["build"]["kwargs"][
         "policy_kwargs"])
     assert published.qk_head_dim == 192
-    assert published.declaration().attention_widths == (128, 64, 128)
+    assert widths(published) == (128, 64, 128)
 
 
 @pytest.mark.parametrize("noise_form", ["factored", "dense", "none"])
@@ -609,33 +613,39 @@ def test_the_mtp_term_scores_the_token_after_next(ref, tiny):
     assert float(want_mtp[-1]) == 0.0
 
 
-def test_the_behaviour_is_a_mean_over_the_last_positions(ref):
+def test_the_behaviour_is_a_mean_over_the_last_positions(ref, tiny):
     """The second output: the main head's logits averaged over the last
     ``behaviour_positions`` positions (all of them where the sequence is
     shorter); 1 is the last position's logits, what the other two models
     give.  One token's discrete choice of experts cannot make a mean of
-    hundreds jump (PERF.md §6, PR 33)."""
+    hundreds jump (PERF.md §6, PR 33).
+
+    The file's tiny build and its weights throughout (``behaviour_positions``
+    shapes no leaf), every forward jitted: un-jitted, a forward compiles
+    each of its primitives by itself at every new length, 7 s a length."""
+    params = tiny["params"]
+    lms = {k: dataclasses.replace(tiny["lm"], behaviour_positions=k)
+           for k in (1, 4, 512)}
+    behaviour = {k: jax.jit(lambda p, t, lm=lm: lm.heads(p, None, 0.0, t)[2])
+                 for k, lm in lms.items()}
     tokens = _tokens(21, 5)
-    logits = {}
-    for positions in (1, 4, 512):
-        built = _built(ref, behaviour_positions=positions)
-        _, _, last, _ = built["lm"].heads(built["params"], None, 0.0, tokens)
-        member = ref.Member(built["s"], built["theta"], None, 0.0)
-        _, _, want = ref.heads(built["s"], member, tokens, head_block=8)
-        np.testing.assert_allclose(last, want, atol=TOL, rtol=0)
-        logits[positions] = last
-    # the same weights in all three: the means are means of the same rows
-    built = _built(ref, behaviour_positions=1)
-    p, lm = built["params"], built["lm"]
-    rows = []
-    for t in range(1, 22):
-        _, _, last, _ = lm.heads(p, None, 0.0, tokens[:t])
-        rows.append(last)
-    np.testing.assert_allclose(logits[1], rows[-1], atol=1e-6)
-    np.testing.assert_allclose(logits[4], jnp.mean(jnp.stack(rows[-4:]), 0),
-                               atol=1e-5)
-    np.testing.assert_allclose(logits[512], jnp.mean(jnp.stack(rows), 0),
-                               atol=1e-5)
+    for k in lms:
+        s = ref.sizes(moe_tiny.config(
+            rank=2, policy={"behaviour_positions": k}))
+        member = ref.Member(s, tiny["theta"], None, 0.0)
+        _, _, want = ref.heads(s, member, tokens, head_block=8)
+        np.testing.assert_allclose(behaviour[k](params, tokens), want,
+                                   atol=TOL, rtol=0)
+    # the same weights in all three: the means are means of the same rows,
+    # each the last position's logits of a prefix
+    short = tokens[:5]
+    rows = [behaviour[1](params, short[:t]) for t in range(1, 6)]
+    np.testing.assert_allclose(behaviour[1](params, short), rows[-1],
+                               atol=1e-6)
+    np.testing.assert_allclose(behaviour[4](params, short),
+                               jnp.mean(jnp.stack(rows[-4:]), 0), atol=1e-5)
+    np.testing.assert_allclose(behaviour[512](params, short),
+                               jnp.mean(jnp.stack(rows), 0), atol=1e-5)
     with pytest.raises(ValueError, match="behaviour_positions"):
         MoELM(**{**moe_tiny.TINY, "behaviour_positions": 0})
 
@@ -856,7 +866,7 @@ def test_published_sizes_and_layouts(ref):
     assert len(spec.stacked_leaves) == 15           # 5 expert layers x 3
     dense = {paths[i].rsplit("/", 1)[1] for i, *_ in spec.dense_leaves}
     assert dense == {"scale", "router_bias"}
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
+    assert unmatched_leaves(lm.declaration().partition_rules, shapes) == {}
     # the work a token is: 0.6 GFLOP of matmul, the experts' part expected
     assert about["expert_flops_per_member_step"] == int(
         5 * 8 * held / 256 * 2 * 3 * 2048 * 768)
@@ -864,19 +874,27 @@ def test_published_sizes_and_layouts(ref):
 
 
 def test_no_leaf_falls_to_the_catch_all(tiny):
+    from estorch_tpu.models import lm_blocks
+
     shapes = tiny["lm"].param_shapes()
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
-    # the model's own rules name everything the other models' do not
-    from estorch_tpu.parallel.mesh import HYBRID_LM_PARTITION_RULES
-    assert unmatched_leaves(
-        HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES, shapes) == {}
-    assert unmatched_leaves(HYBRID_LM_PARTITION_RULES, shapes) != {}
+    # the model's own rules name every leaf: the blocks' (models/
+    # lm_blocks.py) and latent attention's, which the blocks' do not
+    assert unmatched_leaves(tiny["lm"].declaration().partition_rules,
+                            shapes) == {}
+    blocks = (lm_blocks.DECODER_PARTITION_RULES
+              + lm_blocks.EXPERT_PARTITION_RULES)
+    assert {name.rsplit("/", 1)[1] for name in unmatched_leaves(
+        blocks, shapes)} == {"q_a", "q_b", "kv_a", "kv_b", "scale", "eh"}
+    assert unmatched_leaves(lm_blocks.DECODER_PARTITION_RULES, shapes) != {}
 
 
 def test_partition_rules_shard_the_expert_axis(devices8):
     mesh = hyperscale_mesh(2, 4, devices8)
-    shapes = MoELM(**moe_tiny.TINY).param_shapes()
-    sh = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
+    lm = MoELM(**moe_tiny.TINY)
+    shapes = lm.param_shapes()
+    sh = match_partition_rules(
+        lm.declaration().partition_rules + DEFAULT_PARTITION_RULES, shapes,
+        mesh)
 
     def spec(*path):
         node = sh
@@ -961,7 +979,7 @@ class TestThroughTheShardedEngine:
     def test_one_device_run_its_gauges_and_its_counters(self, one_device):
         es = one_device["es"]
         assert es.engine.forward_form == "perturbed"
-        assert es.engine.attention_form == "xla"
+        assert es.engine.kernel_facts["attention_form"] == "xla"
         assert [r["env_steps"] for r in es.history] == [8 * 21] * 2
         # about -1.1 log(64): the main head and a tenth of the MTP's
         assert -5.0 < es.history[0]["reward_mean"] < -4.2

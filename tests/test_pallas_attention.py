@@ -19,7 +19,8 @@ import loop_tiny
 import moe_tiny
 from estorch_tpu.models import HybridLM, LoopedLM, MoELM, lm_blocks
 from estorch_tpu.ops import pallas_attention
-from estorch_tpu.ops.pallas_attention import (attention_form,
+from estorch_tpu.ops.pallas_attention import (attention_facts,
+                                              attention_form,
                                               attention_form_why, band_block,
                                               call_form, causal_attention,
                                               heads_in_pairs, kernel_block,
@@ -1047,11 +1048,14 @@ class TestTheRule:
         ])
     def test_each_kind_of_layer_at_the_two_banded_cells_shapes(
             self, platform, widths, kv_heads, length, windows, by_kind, why):
-        """What the engine's ``attention_form_by_kind`` is made of
-        (``_resolve_kernel_forms``: the program's form, then
-        ``call_form`` a kind), at the published shapes of the three cells
-        whose models have a banded layer, on one TPU device and on a CPU
-        mesh."""
+        """What ``attention_form_by_kind`` is made of (``attention_facts``:
+        the program's form, then ``call_form`` a kind), at the published
+        shapes of the three cells whose models have a banded layer, on one
+        TPU device and on a CPU mesh; and ``attention_facts`` itself, as an
+        engine's build calls it with what the model's declaration names."""
+        from estorch_tpu.ops.kernel_facts import BuildScope
+        from estorch_tpu.ops.pallas_attention import traced_why
+
         band = next(w for w in windows.values() if w is not None)
         form, reason = attention_form_why(platform, 1, widths, length, band,
                                           kv_heads)
@@ -1060,6 +1064,12 @@ class TestTheRule:
         assert paired == (widths == (64, 0, 128))
         assert ",".join(f"{kind}:{call_form(form, window, length, paired)}"
                         for kind, window in windows.items()) == by_kind
+        scope = BuildScope(platform, 1, None, traced_why(platform, 1),
+                           length, 2)
+        assert attention_facts(scope, widths, kv_heads,
+                               tuple(windows.items())) == {
+            "attention_form": form, "attention_form_why": reason,
+            "attention_form_by_kind": by_kind}
 
     def test_published_shapes_are_what_the_rows_say(self):
         ouro, granite = loop_tiny.published(), lm_tiny.published()
@@ -1085,9 +1095,12 @@ class TestTheRule:
         assert "pallas_call" in inside
 
     def test_models_have_the_head_size_es_hands_the_engine(self):
-        assert LoopedLM(**loop_tiny.TINY).declaration().attention_widths == 8
-        assert HybridLM(**lm_tiny.TINY).declaration().attention_widths == 8
-        assert MoELM(**moe_tiny.TINY).declaration().attention_widths == (8, 4, 6)
+        def widths(lm):
+            return dict(lm.declaration().kernels)[attention_facts][0]
+
+        assert widths(LoopedLM(**loop_tiny.TINY)) == 8
+        assert widths(HybridLM(**lm_tiny.TINY)) == 8
+        assert widths(MoELM(**moe_tiny.TINY)) == (8, 4, 6)
 
 
 # ----------------------------------------------------- through the engine
@@ -1116,7 +1129,7 @@ class TestThroughTheShardedEngine:
     def test_every_cpu_mesh_resolves_xla(self, devices8, policy, n_devices,
                                          model_shards):
         es = _lm_es(devices8[:n_devices], model_shards, policy=policy)
-        assert es.engine.attention_form == "xla"
+        assert es.engine.kernel_facts["attention_form"] == "xla"
         assert es.run_manifest()["config"]["attention_form"] == "xla"
         assert es.obs.counters.snapshot()["attention_form"] == "xla"
         # one kind of attention layer, which states no band
@@ -1135,7 +1148,7 @@ class TestThroughTheShardedEngine:
                 agent_kwargs={"env": CartPole(), "horizon": 5},
                 optimizer_kwargs={"learning_rate": 1e-2},
                 shard_params=True, device=list(devices8[:1]))
-        assert es.engine.attention_form is None
+        assert "attention_form" not in es.engine.kernel_facts
         assert es.run_manifest()["config"]["attention_form"] is None
         assert "attention_form" not in es.obs.counters.snapshot()
         assert es.run_manifest()["config"]["attention_form_by_kind"] is None
@@ -1150,7 +1163,8 @@ class TestThroughTheShardedEngine:
         ref = _lm_es(devices8[:1], policy=policy)
         with kernel_attention():
             kern = _lm_es(devices8[:1], policy=policy)
-        assert (ref.engine.attention_form, kern.engine.attention_form) \
+        assert (ref.engine.kernel_facts["attention_form"],
+                kern.engine.kernel_facts["attention_form"]) \
             == ("xla", "kernel")
         assert kern.run_manifest()["config"]["attention_form"] == "kernel"
         assert kern.obs.counters.snapshot()["attention_form"] == "kernel"

@@ -412,7 +412,8 @@ class TestWhichFormAnEngineTakes:
         with kernel_attention():
             kern = _sequence_es(WindowMoELM, window_moe_tiny, devices8[:1],
                                 **wide)
-        assert (xla.engine.combine_form, kern.engine.combine_form) == (
+        assert (xla.engine.kernel_facts["combine_form"],
+                kern.engine.kernel_facts["combine_form"]) == (
             "xla", "kernel")
         for es, form in ((xla, "xla"), (kern, "kernel")):
             assert es.obs.counters.get("combine_form") == form
@@ -431,7 +432,8 @@ class TestWhichFormAnEngineTakes:
 
         with kernel_attention():
             es = _sequence_es(WindowMoELM, window_moe_tiny, devices8[:1])
-        assert (es.engine.attention_form, es.engine.combine_form) == (
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["combine_form"]) == (
             "kernel", "xla")
         assert es.run_manifest()["config"]["combine_form"] == "xla"
         names = _kernel_names(es)
@@ -453,7 +455,7 @@ class TestWhichFormAnEngineTakes:
         with kernel_attention():
             es = _sequence_es(policy, tiny, devices8[:1])
         assert es.engine.kernels_traced
-        assert es.engine.combine_form is None
+        assert "combine_form" not in es.engine.kernel_facts
         assert es.obs.counters.get("combine_form", None) is None
         assert es.run_manifest()["config"]["combine_form"] is None
         assert "combine_rows" not in _kernel_names(es)
@@ -470,7 +472,7 @@ class TestWhichFormAnEngineTakes:
                 agent_kwargs={"env": CartPole(), "horizon": 20},
                 optimizer_kwargs={"learning_rate": 1e-2},
                 device=devices8[:2], shard_params=True, model_shards=2)
-        assert es.engine.combine_form is None
+        assert "combine_form" not in es.engine.kernel_facts
         assert "combine_form" in es.run_manifest()["config"]
         assert es.run_manifest()["config"]["combine_form"] is None
         assert es.obs.counters.get("combine_form", None) is None

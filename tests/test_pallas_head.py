@@ -23,8 +23,8 @@ from pallas_costs import declared_costs, pallas_calls
 from estorch_tpu.models import HybridLM, LoopedLM, MoELM, lm_blocks
 from estorch_tpu.models.perturbed import perturbed_dense
 from estorch_tpu.ops.pallas_attention import kernel_scope
-from estorch_tpu.ops.pallas_head import (fits, head_cost, head_form_why,
-                                         score_rows)
+from estorch_tpu.ops.pallas_head import (fits, head_cost, head_facts,
+                                         head_form_why, score_rows)
 
 # float32 on both sides, sums in another order: measured up to 2e-6 on
 # scores of magnitude 7
@@ -304,9 +304,9 @@ class TestTheRule:
         import lm_tiny
         import moe_tiny
 
-        assert LoopedLM(**loop_tiny.TINY).declaration().head_width == 32
-        assert HybridLM(**lm_tiny.TINY).declaration().head_width == 32
-        assert MoELM(**moe_tiny.TINY).declaration().head_width == 32
+        for lm in (LoopedLM(**loop_tiny.TINY), HybridLM(**lm_tiny.TINY),
+                   MoELM(**moe_tiny.TINY)):
+            assert dict(lm.declaration().kernels)[head_facts] == (32,)
 
 
 class TestTheDeclaredCost:
@@ -444,7 +444,7 @@ class TestThroughTheShardedEngine:
     def test_every_cpu_mesh_resolves_xla(self, devices8, n_devices,
                                          model_shards):
         es = _lm_es(devices8[:n_devices], model_shards)
-        assert es.engine.head_form == "xla"
+        assert es.engine.kernel_facts["head_form"] == "xla"
         assert es.run_manifest()["config"]["head_form"] == "xla"
         assert es.obs.counters.snapshot()["head_form"] == "xla"
 
@@ -458,7 +458,7 @@ class TestThroughTheShardedEngine:
                 agent_kwargs={"env": CartPole(), "horizon": 5},
                 optimizer_kwargs={"learning_rate": 1e-2},
                 shard_params=True, device=list(devices8[:1]))
-        assert es.engine.head_form is None
+        assert "head_form" not in es.engine.kernel_facts
         assert es.run_manifest()["config"]["head_form"] is None
         assert "head_form" not in es.obs.counters.snapshot()
 
@@ -472,8 +472,8 @@ class TestThroughTheShardedEngine:
             fit = _lm_es(devices8[:1])
             narrow = _lm_es(devices8[:1], hidden_size=96)
         for es, form in ((fit, "kernel"), (narrow, "xla")):
-            assert es.engine.attention_form == "kernel"
-            assert es.engine.head_form == form
+            assert es.engine.kernel_facts["attention_form"] == "kernel"
+            assert es.engine.kernel_facts["head_form"] == form
             assert es.run_manifest()["config"]["head_form"] == form
             assert es.obs.counters.snapshot()["head_form"] == form
             text = str(jax.make_jaxpr(es.engine._generation_step)(
@@ -489,7 +489,8 @@ class TestThroughTheShardedEngine:
         ref = _lm_es(devices8[:1])
         with kernel_attention():
             kern = _lm_es(devices8[:1])
-        assert (ref.engine.head_form, kern.engine.head_form) == (
+        assert (ref.engine.kernel_facts["head_form"],
+                kern.engine.kernel_facts["head_form"]) == (
             "xla", "kernel")
         ref.train(2, verbose=False)
         kern.train(2, verbose=False)
@@ -520,7 +521,8 @@ class TestOnAMeshOfSeveralDevices:
             kern = _lm_es(devices8[:4], 2, head, population_size=8)
         assert (ref.engine.centre_form, kern.engine.centre_form) == (
             "gathered", "gathered")
-        assert (ref.engine.head_form, kern.engine.head_form) == (
+        assert (ref.engine.kernel_facts["head_form"],
+                kern.engine.kernel_facts["head_form"]) == (
             "xla", "kernel")
         assert [len(pallas_calls(_program(es))) for es in (ref, kern)] == [
             0, 1]
@@ -585,10 +587,11 @@ class TestOnAMeshOfSeveralDevices:
                            if attention == "kernel" or head == "untied"
                            else {}))
         engine = es.engine
-        assert (engine.attention_form, engine.head_form) == (attention, form)
-        assert says in engine.head_form_why
+        assert (engine.kernel_facts["attention_form"],
+                engine.kernel_facts["head_form"]) == (attention, form)
+        assert says in engine.kernel_facts["head_form_why"]
         assert es.run_manifest()["config"]["head_form_why"] == (
-            engine.head_form_why)
+            engine.kernel_facts["head_form_why"])
         assert "head_form_why" not in es.obs.counters.snapshot()
         names = " ".join(eqn.params["name"]
                          for eqn in pallas_calls(_program(es)))
@@ -598,7 +601,9 @@ class TestOnAMeshOfSeveralDevices:
     def test_a_cpu_mesh_without_the_fixture_traces_no_kernel(self, devices8):
         es = _lm_es(devices8[:4], 2, "tied_scaled", population_size=8)
         assert es.engine.centre_form == "gathered"
-        assert (es.engine.kernels_traced, es.engine.head_form) == (
+        assert (es.engine.kernels_traced,
+                es.engine.kernel_facts["head_form"]) == (
             False, "xla")
-        assert es.engine.head_form_why == "the devices are 'cpu', not TPUs"
+        assert es.engine.kernel_facts["head_form_why"] == (
+            "the devices are 'cpu', not TPUs")
         assert pallas_calls(_program(es)) == []

@@ -5,10 +5,13 @@ its ``declaration()``), an engine reports what it resolved at build ONCE
 (``build_facts()``), and ``ES`` carries both to the gauges and to
 ``run_manifest()["config"]`` without naming a field of either.  Moving the
 seam moved nothing: the literals of ``policy_contract_parent.py`` were read
-at the parent commit.
+at the parent commit of PR 43, those of ``policy_seam_parent.py`` at the
+parent of PR 56, when the partition rules and the kernels' rules left
+``parallel/`` for the declaration.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,13 +28,23 @@ import moe_tiny
 import sambay_tiny
 import window_moe_tiny
 from policy_contract_parent import PARENT
+from policy_seam_parent import BUILDS
 
 from estorch_tpu import ES, JaxAgent, MLPPolicy
 from estorch_tpu.envs import CartPole, TokenScoreEnv
 from estorch_tpu.models import (CCAMoELM, DeltaMoELM, GatedWindowMoELM,
                                 HybridLM, IndexedMoELM, LoopedLM, MoELM,
                                 SambaYLM, WindowMoELM)
+from estorch_tpu.models import (cca_moe_lm, delta_moe_lm, gated_window_moe_lm,
+                                hybrid_lm, indexed_moe_lm, looped_lm, moe_lm,
+                                sambay_lm, window_moe_lm)
 from estorch_tpu.models.perturbed import PolicyDeclaration, declaration_of
+from estorch_tpu.ops import kernel_facts
+from estorch_tpu.ops.pallas_attention import attention_facts
+from estorch_tpu.ops.pallas_combine import combine_facts
+from estorch_tpu.ops.pallas_delta import delta_facts
+from estorch_tpu.ops.pallas_head import head_facts
+from estorch_tpu.ops.pallas_scan import scan_facts
 from estorch_tpu.parallel.engine import MANIFEST_BUILD_FACTS
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
                                        hyperscale_mesh,
@@ -53,8 +66,10 @@ SEQUENCE_MODELS = {
 }
 
 
+@functools.lru_cache(maxsize=None)
 def build(name):
-    """One of the seven builds the parent's literals were read from."""
+    """One of the eleven builds the parents' literals were read from (built
+    once: the tests read it and step nothing)."""
     devices = jax.devices()
     if name in SEQUENCE_MODELS:
         policy, tiny, n_devices, shards = SEQUENCE_MODELS[name]
@@ -106,18 +121,19 @@ def test_manifest_gauges_and_sizes_are_the_parents(name, devices8):
         else "the devices are 'cpu', not TPUs")
     # stated since PR 51, when the expert layer's combine got its two
     # forms: the scatter-add on these CPU meshes, nothing without experts
-    combine = (None if declaration_of(es.module).combine_width is None
-               else "xla")
+    kernels = dict(declaration_of(es.module).kernels)
+    combine = "xla" if combine_facts in kernels else None
     assert config.pop("combine_form") == combine
     # stated since PR 53, when the gated delta rule got its two forms: the
     # XLA form on these CPU meshes, nothing without a linear layer
-    delta = (None if declaration_of(es.module).delta_widths is None
-             else "xla")
+    delta = "xla" if delta_facts in kernels else None
     assert config.pop("delta_form") == delta
     assert sorted(config) == sorted(want["config"])
     assert config == want["config"]
-    assert rules == (partition_rules_to_json(DEFAULT_PARTITION_RULES)
-                     if es._shard_params else None)
+    # since PR 56: the model's own rules, then the general four
+    assert rules == (partition_rules_to_json(
+        declaration_of(es.module).partition_rules + DEFAULT_PARTITION_RULES)
+        if es._shard_params else None)
     gauges = es.obs.counters.snapshot()
     assert gauges.pop("combine_form", None) == combine
     assert gauges.pop("delta_form", None) == delta
@@ -126,32 +142,63 @@ def test_manifest_gauges_and_sizes_are_the_parents(name, devices8):
     assert sized(es.engine) == want["sized"]
 
 
+@pytest.mark.parametrize("name", sorted(BUILDS))
+def test_the_declarations_rules_and_kernels_moved_nothing(name, devices8):
+    """(i, PR 56) EVERY key and value of ``run_manifest()["config"]``
+    (``partition_rules`` apart, which now lists the model's own rules ahead
+    of the general four), every gauge and every chunk size of the nine
+    sequence models' builds and the MLP's two are what they were when
+    ``parallel/mesh.py`` held every model's rules in one list and
+    ``parallel/sharded.py`` evaluated every kernel's rule itself."""
+    es = build(name)
+    want = BUILDS[name]
+    config = es.run_manifest()["config"]
+    rules = config.pop("partition_rules", None)
+    assert set(MANIFEST_BUILD_FACTS) <= set(config)
+    assert config == want["config"]
+    assert es.obs.counters.snapshot() == want["gauges"]
+    assert sized(es.engine) == want["sized"]
+    assert (rules is not None) == es._shard_params
+    # a kernel rule's reason is a sentence: in the manifest, never a gauge
+    assert not set(want["gauges"]) & kernel_facts.SENTENCES
+
+
 # ------------------------------------------------- a model declares itself
 
-# what each model states at its tiny sizes: the fields the engine's rules
-# read, as the parent's ``ES`` read them off the module's attributes
+# what each model states at its tiny sizes: the kernels it calls with the
+# widths it calls them with (``(rule, widths)``; the attention's widths are
+# ``(head widths, key heads, ((layer kind, band), ...))``), and the fields
+# the engine's chunk rule and the records read
 STATED = {
-    "hybrid": dict(attention_widths=8, attention_kv_heads=2, head_width=32),
+    "hybrid": dict(
+        partition_rules=hybrid_lm.PARTITION_RULES,
+        kernels=((attention_facts, (8, 2)), (head_facts, (32,)))),
     "looped": dict(
-        leaf_rows={"head/kernel": 8}, attention_widths=8,
-        attention_kv_heads=2, head_width=32,
+        partition_rules=looped_lm.PARTITION_RULES,
+        kernels=((attention_facts, (8, 2)), (head_facts, (32,))),
+        leaf_rows={"head/kernel": 8},
         facts={"loop_steps": 4, "layer_applications_per_token": 8}),
     "moe": dict(
-        leaf_rows={"head/kernel": 8}, attention_widths=(8, 4, 6),
-        head_width=32, combine_width=32, outputs=("expert_load",),
+        partition_rules=moe_lm.PARTITION_RULES,
+        kernels=((attention_facts, ((8, 4, 6),)), (head_facts, (32,)),
+                 (combine_facts, (32,))),
+        leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 1}),
     "sambay": dict(
-        attention_widths=(4, 0, 8), attention_kv_heads=4, head_width=32,
-        attention_windows={"window": 5, "full_kv": None, "cross": None},
-        scan_widths=(64, 4),
+        partition_rules=sambay_lm.PARTITION_RULES,
+        kernels=((attention_facts, (
+            (4, 0, 8), 4,
+            (("window", 5), ("full_kv", None), ("cross", None)))),
+            (head_facts, (32,)), (scan_facts, (64, 4))),
         facts={"layer_kinds": "mamba,window,mamba_mem,full_kv,gmu,cross",
                "window": 5, "scan_chunk": 4, "kv_shared_by": 1,
                "memory_shared_by": 1}),
     "indexed_moe": dict(
-        leaf_rows={"head/kernel": 8}, attention_widths=8,
-        attention_kv_heads=2, head_width=32, combine_width=32,
-        attention_windows={"selected": None},
+        partition_rules=indexed_moe_lm.PARTITION_RULES,
+        kernels=((attention_facts, (8, 2, (("selected", None),))),
+                 (head_facts, (32,)), (combine_facts, (32,))),
+        leaf_rows={"head/kernel": 8},
         outputs=("expert_load", "selected_pairs"),
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 0, "sparse_topk": 6,
@@ -160,8 +207,10 @@ STATED = {
     # ONE expert a token over two shares reaches the EXPERTS' stacked
     # leaves; the head-mixing convolution's stack sees every position
     "cca_moe": dict(
-        attention_widths=8, attention_kv_heads=2, head_width=32,
-        combine_width=32, outputs=("expert_load",),
+        partition_rules=cca_moe_lm.PARTITION_RULES,
+        kernels=((attention_facts, (8, 2)), (head_facts, (32,)),
+                 (combine_facts, (32,))),
+        outputs=("expert_load",),
         leaf_rows_per_token=lambda lm: dict.fromkeys(lm.expert_leaves,
                                                      1.25 / 2),
         facts={"experts_held": 2, "experts_total": 4,
@@ -170,19 +219,20 @@ STATED = {
     # two kinds of attention layer, each with its band; the routes are
     # taken ahead of attention, which the declaration need not say
     "window_moe": dict(
-        leaf_rows={"head/kernel": 8}, attention_widths=8,
-        attention_kv_heads=2, head_width=32, combine_width=32,
-        attention_windows={"window": 6, "global": None},
-        outputs=("expert_load",),
+        partition_rules=window_moe_lm.PARTITION_RULES,
+        kernels=((attention_facts, (8, 2, (("window", 6), ("global", None)))),
+                 (head_facts, (32,)), (combine_facts, (32,))),
+        leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 0, "sliding_window": 6,
                "window_layers": 2, "global_layers": 1}),
     # two kinds of MIXER and one kind of attention layer: no band to state;
     # the delta rule's chunk and the form its inverse takes are facts
     "delta_moe": dict(
-        leaf_rows={"head/kernel": 8}, attention_widths=16,
-        attention_kv_heads=2, head_width=32, combine_width=32,
-        delta_widths=(8, 8, 8), outputs=("expert_load",),
+        partition_rules=delta_moe_lm.PARTITION_RULES,
+        kernels=((attention_facts, (16, 2)), (head_facts, (32,)),
+                 (combine_facts, (32,)), (delta_facts, (8, 8, 8))),
+        leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 0, "linear_layers": 3,
                "full_layers": 1, "delta_chunk": 8,
@@ -190,13 +240,13 @@ STATED = {
                                 "(I - A)(I + A^2)(I + A^4)..., merged in "
                                 "pairs"}),
     # two kinds of attention layer that differ in their HEAD COUNT too: the
-    # engine's rule reads the key heads and the widths, which the kinds
+    # attention's rule reads the key heads and the widths, which the kinds
     # share; each kind's heads are facts
     "gated_window_moe": dict(
-        leaf_rows={"head/kernel": 8}, attention_widths=8,
-        attention_kv_heads=2, head_width=32, combine_width=32,
-        attention_windows={"sliding": 6, "full": None},
-        outputs=("expert_load",),
+        partition_rules=gated_window_moe_lm.PARTITION_RULES,
+        kernels=((attention_facts, (8, 2, (("sliding", 6), ("full", None)))),
+                 (head_facts, (32,)), (combine_facts, (32,))),
+        leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
                "experts_per_token": 3, "mtp_depth": 0, "sliding_window": 6,
                "dense_layers": 1, "sliding_layers": 2, "full_layers": 2,
@@ -224,6 +274,10 @@ def test_a_model_declares_itself_once(name):
     assert set(stated.outputs) <= set(OUTPUT_REDUCTIONS)
     if selection is not None:
         assert stated.selection_bytes(21) == 21 * 21 + 4 * 2 * 8 * 21
+    # every rule it names is one ops/kernel_facts.py collects the names of
+    scope = kernel_facts.BuildScope("cpu", 1, None, (False, "a CPU"), 21, 2)
+    assert set(kernel_facts.resolve(scope, stated.kernels)) <= set(
+        kernel_facts.FACT_NAMES)
 
 
 def test_a_module_that_states_nothing_gets_the_defaults(devices8):
@@ -232,15 +286,16 @@ def test_a_module_that_states_nothing_gets_the_defaults(devices8):
     assert declaration_of(MLPPolicy(action_dim=2, hidden=(16, 16))) == (
         PolicyDeclaration())
     assert declaration_of(object()) == PolicyDeclaration()
-    engine = build("mlp_sharded").engine
+    es = build("mlp_sharded")
+    engine = es.engine
     assert engine.policy == PolicyDeclaration()
-    assert (engine.attention_form, engine.head_form, engine.scan_form,
-            engine.combine_form, engine.delta_form) == (None,) * 5
-    facts = engine.build_facts()
-    assert [facts[k] for k in ("attention_form", "attention_form_by_kind",
-                               "attention_form_why", "head_form",
-                               "scan_form", "combine_form",
-                               "delta_form")] == [None] * 7
+    assert engine.kernel_facts == {}
+    assert not set(engine.build_facts()) & set(kernel_facts.FACT_NAMES)
+    # the manifest has every kernel's names all the same, each ``None``
+    config = es.run_manifest()["config"]
+    assert [config[k] for k in kernel_facts.FACT_NAMES] == [None] * 8
+    # and its rules are the general ones alone
+    assert engine.partition_rules == DEFAULT_PARTITION_RULES
 
 
 # ------------------------------------------- what the policy returns, named

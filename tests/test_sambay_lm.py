@@ -21,11 +21,11 @@ from estorch_tpu.models.sambay_lm import (lambda_init, layer_kinds,
 from estorch_tpu.ops.lowrank import (lowrank_tree_noise,
                                      lowrank_tree_weighted_sum,
                                      make_lowrank_tree_spec)
-from estorch_tpu.ops.pallas_attention import (attention_form,
+from estorch_tpu.ops.pallas_attention import (attention_facts,
+                                              attention_form,
                                               attention_form_why, call_form,
                                               heads_in_pairs, kernel_scope)
-from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       unmatched_leaves)
+from estorch_tpu.parallel.mesh import unmatched_leaves
 
 # the models here are tiny (heads of 8, sequences of 16): inside a
 # ``kernel_scope`` their attention calls take the kernel all the same
@@ -186,10 +186,11 @@ def test_the_six_layer_cut_keeps_the_published_indices():
     assert published["published_layer_types"] == list(layer_kinds(32))
     assert published["layers_held"] == list(lm.layer_indices)
     assert (lm.kv_shared_by, lm.memory_shared_by) == (1, 1)
-    assert lm.declaration().attention_windows == {
-        "window": 512, "full_kv": None, "cross": None}
-    assert SambaYLM(**{**kwargs, "layer_indices": (16, 17)}
-                    ).declaration().attention_windows == {"full_kv": None}
+    assert dict(lm.declaration().kernels)[attention_facts][2] == (
+        ("window", 512), ("full_kv", None), ("cross", None))
+    assert dict(SambaYLM(**{**kwargs, "layer_indices": (16, 17)}
+                         ).declaration().kernels)[attention_facts][2] == (
+        ("full_kv", None),)
     for index, want in [(1, 0.3555), (17, 0.7963), (19, 0.7980)]:
         assert lambda_init(index) == pytest.approx(
             0.8 - 0.6 * math.exp(-0.3 * index))
@@ -624,7 +625,7 @@ def test_a_log_takes_dense_noise_and_the_matrices_factored(tiny):
 
 
 def test_every_leaf_has_a_partition_rule(tiny):
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES,
+    assert unmatched_leaves(tiny["lm"].declaration().partition_rules,
                             tiny["lm"].param_shapes()) == {}
 
 
@@ -682,8 +683,9 @@ class TestThroughTheShardedEngine:
     def test_one_device_run_its_gauges_and_its_manifest(self, one_device):
         es = one_device["es"]
         assert es.engine.forward_form == "perturbed"
-        assert (es.engine.attention_form, es.engine.head_form) == ("xla",
-                                                                   "xla")
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["head_form"]) == (
+                    "xla", "xla")
         assert [r["env_steps"] for r in es.history] == [8 * 21] * 2
         assert -4.6 < es.history[0]["reward_mean"] < -3.9   # about -log 64
         gauges = es.obs.counters
@@ -696,13 +698,14 @@ class TestThroughTheShardedEngine:
         assert gauges.get("attention_form") == "xla"
         # a CPU mesh: every kind of attention layer in the XLA form
         by_kind = "window:xla,full_kv:xla,cross:xla"
-        assert es.engine.attention_form_by_kind == by_kind
+        assert es.engine.kernel_facts["attention_form_by_kind"] == by_kind
         assert gauges.get("attention_form_by_kind") == by_kind
         assert gauges.get("head_form") == "xla"
         # the scans too: no scope on a CPU mesh, whatever their shapes
-        assert es.engine.scan_form == gauges.get("scan_form") == "xla"
+        assert (es.engine.kernel_facts["scan_form"]
+                == gauges.get("scan_form") == "xla")
         # no expert layer: no combine, no form of it
-        assert es.engine.combine_form is None
+        assert "combine_form" not in es.engine.kernel_facts
         assert gauges.get("combine_form", None) is None
         assert gauges.get("experts_held", None) is None
         cfg = es.run_manifest()["config"]
@@ -727,10 +730,11 @@ class TestThroughTheShardedEngine:
         ref_es = _sambay_es(devices8[:1], 1, compute_dtype=dtype)
         with kernel_attention():
             kern = _sambay_es(devices8[:1], 1, compute_dtype=dtype)
-        assert (ref_es.engine.attention_form,
-                kern.engine.attention_form) == ("xla", "kernel")
+        assert (ref_es.engine.kernel_facts["attention_form"],
+                kern.engine.kernel_facts["attention_form"]) == (
+                    "xla", "kernel")
         by_kind = "window:xla,full_kv:kernel,cross:kernel"
-        assert kern.engine.attention_form_by_kind == by_kind
+        assert kern.engine.kernel_facts["attention_form_by_kind"] == by_kind
         assert kern.obs.counters.get("attention_form_by_kind") == by_kind
         assert kern.run_manifest()["config"][
             "attention_form_by_kind"] == by_kind
@@ -759,7 +763,8 @@ class TestThroughTheShardedEngine:
         ref_es = _sambay_es(devices8[:1], 1, **over)
         with kernel_attention():
             kern = _sambay_es(devices8[:1], 1, **over)
-        assert (ref_es.engine.scan_form, kern.engine.scan_form) == (
+        assert (ref_es.engine.kernel_facts["scan_form"],
+                kern.engine.kernel_facts["scan_form"]) == (
             "xla", "kernel")
         assert kern.obs.counters.get("scan_form") == "kernel"
         assert kern.run_manifest()["config"]["scan_form"] == "kernel"
@@ -782,7 +787,8 @@ class TestThroughTheShardedEngine:
         ``lax.scan``, and the engine says which."""
         with kernel_attention():
             es = _sambay_es(devices8[:1], 1)
-        assert (es.engine.attention_form, es.engine.scan_form) == (
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["scan_form"]) == (
             "kernel", "xla")
         assert es.run_manifest()["config"]["scan_form"] == "xla"
 
@@ -795,10 +801,11 @@ class TestThroughTheShardedEngine:
                         agent_kwargs={"env": TokenScoreEnv(**loop_tiny.ENV)})
         assert es.obs.counters.get("layer_kinds", None) is None
         assert "kv_shared_by" not in es.run_manifest()["config"]
-        assert es.engine._attention_windows == {"causal": None}
-        assert es.engine.attention_form_by_kind == "causal:xla"
+        assert len(dict(es.module.declaration().kernels)[
+            attention_facts]) == 2          # no kinds stated: one, no band
+        assert es.engine.kernel_facts["attention_form_by_kind"] == "causal:xla"
         # no scan stated: no form, no gauge, null in the manifest
-        assert es.engine.scan_form is None
+        assert "scan_form" not in es.engine.kernel_facts
         assert es.obs.counters.get("scan_form", None) is None
         assert es.run_manifest()["config"]["scan_form"] is None
 

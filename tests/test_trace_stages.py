@@ -24,6 +24,7 @@ from estorch_tpu.obs.trace import (ATTN, DENSE, DIFF, DISPATCH, ENV, EXIT,
                                    RANK, ROPE, ROUTE, SAMPLE, SCOPE_PREFIX,
                                    SELECT, SSM, STAGES, UPDATE, annotate,
                                    part, stage, trace)
+from estorch_tpu.ops.pallas_attention import attention_facts
 
 # the stages of every generation program; a sequence model nests more
 # inside es.policy (DENSE, SSM, ATTN, HEAD; a looped one ROPE and EXIT; a
@@ -720,7 +721,7 @@ def _looped_engine_on(devices, model_shards, head_dim, length, latent=False,
         shard_params=True, low_rank=1, noise_mode="table",
         compute_dtype="bfloat16", table_size=1 << 18,
         device=jax.devices()[:1])
-    assert es.engine.attention_form == "xla"  # a CPU mesh
+    assert es.engine.kernel_facts["attention_form"] == "xla"  # a CPU mesh
     lr_apply, lr_spec = es._perturbed_form(
         jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
     engine = ShardedESEngine(
@@ -749,7 +750,7 @@ def test_attention_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim,
     resolves, with nothing compiled."""
     _, engine = _looped_engine_on(v5e_2x2[:n_devices], model_shards,
                                   head_dim, length)
-    assert engine.attention_form == form
+    assert engine.kernel_facts["attention_form"] == form
 
 
 @pytest.mark.parametrize("n_devices, model_shards, head_dim, length, form", [
@@ -770,7 +771,7 @@ def test_head_rule_on_a_tpu_mesh(n_devices, model_shards, head_dim, length,
     whatever form the attention takes; the XLA form on every other."""
     _, engine = _looped_engine_on(v5e_2x2[:n_devices], model_shards,
                                   head_dim, length)
-    assert engine.head_form == form
+    assert engine.kernel_facts["head_form"] == form
     assert engine.kernels_traced == (n_devices == 1 or model_shards == 2)
 
 
@@ -786,9 +787,11 @@ def test_on_a_2x2_mesh_a_chips_head_call_holds_its_own_members(v5e_2x2):
     collective: what crosses the chips before the evaluation is the
     centre's gather, as without a kernel."""
     es, engine = _looped_engine_on(v5e_2x2, 2, 64, 512, population_size=16)
-    assert (engine.centre_form, engine.attention_form, engine.head_form) == (
+    assert (engine.centre_form, engine.kernel_facts["attention_form"],
+            engine.kernel_facts["head_form"]) == (
         "gathered", "xla", "kernel")
-    assert "4 TPU devices, whole members on each" in engine.head_form_why
+    assert "4 TPU devices, whole members on each" in engine.kernel_facts[
+        "head_form_why"]
     assert (engine.pair_chunk, engine.n_pair_chunks) == (8, 1)
     text = _compiled_generation(es, engine)
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
@@ -814,7 +817,8 @@ def test_kernel_form_books_the_heads_kernel_to_head(latent, v5e_chip):
     to ``part.head_flops_util`` (two calls for the latent model: the main
     head and the MTP head)."""
     es, engine = _looped_engine_on([v5e_chip], 1, 128, 512, latent=latent)
-    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
+    assert (engine.kernel_facts["attention_form"],
+            engine.kernel_facts["head_form"]) == ("kernel", "kernel")
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -839,7 +843,7 @@ def test_kernel_form_books_its_kernel_to_attn(latent, v5e_chip):
     (``moe.attn_share`` for latent attention, whose widths 128 + 64 shared
     the rule is told by the model)."""
     es, engine = _looped_engine_on([v5e_chip], 1, 128, 256, latent=latent)
-    assert engine.attention_form == "kernel"
+    assert engine.kernel_facts["attention_form"] == "kernel"
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -980,14 +984,15 @@ def test_kernel_form_books_a_differential_pairs_kernel_by_its_kind(v5e_chip):
     each to ``sambay.full_attn_share`` / ``sambay.window_attn_share`` as
     before."""
     es, engine = _sambay_engine_on(v5e_chip)
-    assert es.module.declaration().attention_widths == (64, 0, 128)
-    assert es.engine.attention_form_by_kind == (
+    assert dict(es.module.declaration().kernels)[attention_facts][0] == (
+        64, 0, 128)
+    assert es.engine.kernel_facts["attention_form_by_kind"] == (
         "window:xla,full_kv:xla,cross:xla")        # a CPU mesh
-    assert engine.attention_form == "kernel"
-    assert engine.attention_form_why == (
+    assert engine.kernel_facts["attention_form"] == "kernel"
+    assert engine.kernel_facts["attention_form_why"] == (
         "one TPU device, two score heads a column block, whole row blocks; "
         "layers with a window of 64 in the XLA form")
-    assert engine.attention_form_by_kind == (
+    assert engine.kernel_facts["attention_form_by_kind"] == (
         "window:xla,full_kv:kernel,cross:kernel")
     text = _compiled_generation(es, engine)
     kernels = [name for line in text.splitlines()
@@ -1095,8 +1100,9 @@ def test_kernel_form_books_the_scans_kernel_to_ssm(v5e_chip):
     (``sambay.ssm_share``, ``sambay.ssm_hbm_util``); no ``while`` loop is
     left under es.ssm."""
     es, engine = _sambay_engine_on(v5e_chip)
-    assert es.engine.scan_form == "xla"            # a CPU mesh
-    assert (engine.attention_form, engine.scan_form) == ("kernel", "kernel")
+    assert es.engine.kernel_facts["scan_form"] == "xla"            # a CPU mesh
+    assert (engine.kernel_facts["attention_form"],
+            engine.kernel_facts["scan_form"]) == ("kernel", "kernel")
     text = _compiled_generation(es, engine)
     kernels = [name for line in text.splitlines()
                if "tpu_custom_call" in line and "selective_scan" in line
@@ -1114,7 +1120,8 @@ def test_a_scan_the_rule_refuses_stays_a_loop_on_the_chip(v5e_chip):
     their kernels, the scans in the ``lax.scan`` (a ``while`` under
     es.ssm), and the engine says so."""
     es, engine = _sambay_engine_on(v5e_chip, mamba_d_state=24)
-    assert (engine.attention_form, engine.scan_form) == ("kernel", "xla")
+    assert (engine.kernel_facts["attention_form"],
+            engine.kernel_facts["scan_form"]) == ("kernel", "xla")
     text = _compiled_generation(es, engine)
     assert not [line for line in text.splitlines()
                 if "tpu_custom_call" in line and "selective_scan" in line]
@@ -1192,7 +1199,8 @@ def test_kernel_form_books_the_delta_rule_to_its_two_parts(
     with kernel_attention():
         es = _delta_es()
     engine = es.engine
-    assert (engine.delta_form, es.obs.counters.get("delta_form"),
+    assert (engine.kernel_facts["delta_form"],
+            es.obs.counters.get("delta_form"),
             es.run_manifest()["config"]["delta_form"]) == ("kernel",) * 3
     text = engine._generation_step.lower(
         es.state, engine.table.data).as_text(debug_info=True)
@@ -1215,7 +1223,8 @@ def test_the_delta_case_at_tiny_widths_says_xla():
     """The suite's tiny model (heads of 8 in chunks of 8) builds the XLA
     form whatever the scope, and says so."""
     es = _sequence_es(SEQUENCE_MODELS["delta"])
-    assert (es.engine.delta_form, es.obs.counters.get("delta_form"),
+    assert (es.engine.kernel_facts["delta_form"],
+            es.obs.counters.get("delta_form"),
             es.run_manifest()["config"]["delta_form"]) == ("xla",) * 3
 
 
@@ -1278,8 +1287,8 @@ def test_kernel_form_books_the_delta_rules_kernels_on_the_chip(v5e_chip):
     two rooflines); no ``while`` of the XLA form's chain is left under
     es.ssm."""
     es, engine = _delta_engine_on(v5e_chip)
-    assert es.engine.delta_form == "xla"           # a CPU mesh
-    assert engine.delta_form == "kernel"
+    assert es.engine.kernel_facts["delta_form"] == "xla"           # a CPU mesh
+    assert engine.kernel_facts["delta_form"] == "kernel"
     text = _compiled_generation(es, engine)
     for kernel, part in (("delta_solve", "solve"), ("delta_chain", "carry")):
         names = [name for line in text.splitlines()
@@ -1298,7 +1307,8 @@ def test_a_chunk_the_rule_refuses_stays_in_xla_on_the_chip(v5e_chip):
     rule's XLA form, a ``while`` under es.ssm, and the engine says so; its
     attention and head keep their kernels."""
     es, engine = _delta_engine_on(v5e_chip, delta_chunk=8)
-    assert (engine.attention_form, engine.delta_form) == ("kernel", "xla")
+    assert (engine.kernel_facts["attention_form"],
+            engine.kernel_facts["delta_form"]) == ("kernel", "xla")
     text = _compiled_generation(es, engine)
     assert not [line for line in text.splitlines()
                 if "tpu_custom_call" in line and "delta_" in line]
@@ -1359,8 +1369,9 @@ def test_kernel_form_books_the_selected_attention_by_its_kind(v5e_chip):
         shard_params=True, low_rank=1, noise_mode="table",
         compute_dtype="bfloat16", table_size=1 << 18,
         device=jax.devices()[:1])
-    assert es.module.declaration().attention_widths == 128
-    assert es.engine.attention_form_by_kind == "selected:xla"   # a CPU mesh
+    assert dict(es.module.declaration().kernels)[attention_facts][0] == 128
+    assert es.engine.kernel_facts["attention_form_by_kind"] == (
+        "selected:xla")                                        # a CPU mesh
     lr_apply, lr_spec = es._perturbed_form(
         jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
     engine = ShardedESEngine(
@@ -1369,10 +1380,11 @@ def test_kernel_form_books_the_selected_attention_by_its_kind(v5e_chip):
         partition_rules=es._partition_rules, noise_mode="table",
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
         policy=declaration_of(es.module))
-    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
-    assert engine.attention_form_why == (
+    assert (engine.kernel_facts["attention_form"],
+            engine.kernel_facts["head_form"]) == ("kernel", "kernel")
+    assert engine.kernel_facts["attention_form_why"] == (
         "one TPU device, whole column blocks, whole row blocks")
-    assert engine.attention_form_by_kind == "selected:kernel"
+    assert engine.kernel_facts["attention_form_by_kind"] == "selected:kernel"
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -1446,7 +1458,8 @@ def test_kernel_form_books_the_latents_attention_and_the_tied_head(v5e_chip):
         shard_params=True, low_rank=1, noise_mode="table",
         compute_dtype="bfloat16", table_size=1 << 18,
         device=jax.devices()[:1])
-    assert (es.engine.attention_form, es.engine.head_form) == ("xla", "xla")
+    assert (es.engine.kernel_facts["attention_form"],
+            es.engine.kernel_facts["head_form"]) == ("xla", "xla")
     lr_apply, lr_spec = es._perturbed_form(
         jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
     engine = ShardedESEngine(
@@ -1455,9 +1468,11 @@ def test_kernel_form_books_the_latents_attention_and_the_tied_head(v5e_chip):
         partition_rules=es._partition_rules, noise_mode="table",
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
         policy=declaration_of(es.module))
-    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
-    assert engine.attention_form_by_kind == "causal:kernel"
-    assert (es.engine.combine_form, engine.combine_form) == ("xla", "kernel")
+    assert (engine.kernel_facts["attention_form"],
+            engine.kernel_facts["head_form"]) == ("kernel", "kernel")
+    assert engine.kernel_facts["attention_form_by_kind"] == "causal:kernel"
+    assert (es.engine.kernel_facts["combine_form"],
+            engine.kernel_facts["combine_form"]) == ("xla", "kernel")
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -1628,7 +1643,8 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
         shard_params=True, low_rank=1, noise_mode="table",
         compute_dtype="bfloat16", table_size=1 << 18,
         device=jax.devices()[:1])
-    assert es.engine.attention_form_by_kind == "window:xla,global:xla"
+    assert es.engine.kernel_facts["attention_form_by_kind"] == (
+        "window:xla,global:xla")
     lr_apply, lr_spec = es._perturbed_form(
         jax.ShapeDtypeStruct((es._spec.dim,), jnp.float32))
     engine = ShardedESEngine(
@@ -1637,9 +1653,10 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
         partition_rules=es._partition_rules, noise_mode="table",
         perturbed_apply=lr_apply, lowrank_spec=lr_spec,
         policy=declaration_of(es.module))
-    assert (engine.attention_form, engine.head_form) == ("kernel", "kernel")
-    assert engine.attention_form_by_kind == by_kind
-    assert engine.attention_form_why.endswith(
+    assert (engine.kernel_facts["attention_form"],
+            engine.kernel_facts["head_form"]) == ("kernel", "kernel")
+    assert engine.kernel_facts["attention_form_by_kind"] == by_kind
+    assert engine.kernel_facts["attention_form_why"].endswith(
         f"layers with a window of {window} in the "
         + ("XLA form" if window == 192 else "kernel"))
     state = jax.tree_util.tree_map(

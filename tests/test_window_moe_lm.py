@@ -23,11 +23,11 @@ from estorch_tpu.models import (CCAMoELM, HybridLM, IndexedMoELM, LoopedLM,
                                 MoELM, SambaYLM, WindowMoELM, lm_blocks)
 from estorch_tpu.ops import pallas_attention
 from estorch_tpu.ops.lowrank import make_lowrank_tree_spec
-from estorch_tpu.ops.pallas_attention import (attention_form_why, call_form,
+from estorch_tpu.ops.pallas_attention import (attention_facts,
+                                              attention_form_why, call_form,
                                               kernel_scope)
+from estorch_tpu.ops.pallas_head import head_facts
 from estorch_tpu.parallel.mesh import (DEFAULT_PARTITION_RULES,
-                                       HYBRID_LM_PARTITION_RULES,
-                                       MOE_LM_PARTITION_RULES,
                                        hyperscale_mesh, match_partition_rules,
                                        unmatched_leaves)
 
@@ -699,9 +699,12 @@ def test_init_draws_the_declared_tree(tiny):
 
 def test_the_declaration(tiny):
     stated = tiny["lm"].declaration()
-    assert stated.attention_windows == {"window": 6, "global": None}
-    assert (stated.attention_widths, stated.attention_kv_heads,
-            stated.head_width) == (8, 2, 32)
+    # heads of 8 over 2 key heads, each kind of attention layer with its
+    # band; the head at the hidden width
+    kernels = dict(stated.kernels)
+    assert kernels[attention_facts] == (
+        8, 2, (("window", 6), ("global", None)))
+    assert kernels[head_facts] == (32,)
     assert stated.leaf_rows == {"head/kernel": 8}
     assert stated.leaf_rows_per_token == dict.fromkeys(
         tiny["lm"].stacked_leaves, 3 * 1.25 / 4)
@@ -712,12 +715,13 @@ def test_the_declaration(tiny):
         "mtp_depth": 0, "sliding_window": 6, "window_layers": 2,
         "global_layers": 1}
     # a stack of one kind states that kind alone
-    assert dataclasses.replace(
-        tiny["lm"], layer_types=("global",)).declaration(
-            ).attention_windows == {"global": None}
-    assert dataclasses.replace(
-        tiny["lm"], layer_types=("window",)).declaration(
-            ).attention_windows == {"window": 6}
+    def kinds(layer_types):
+        return dict(dataclasses.replace(
+            tiny["lm"], layer_types=layer_types).declaration().kernels)[
+                attention_facts][2]
+
+    assert kinds(("global",)) == (("global", None),)
+    assert kinds(("window",)) == (("window", 6),)
 
 
 def test_published_sizes_and_layouts(ref):
@@ -776,23 +780,22 @@ def test_published_sizes_and_layouts(ref):
         assert fields[key] == cfg[key], key
     assert cfg["horizon"] == cfg["max_position_embeddings"] == 16384
     stated = lm.declaration()
-    assert (stated.attention_widths, stated.head_width,
-            stated.attention_kv_heads, stated.attention_windows) == (
-        128, 2560, 4, {"window": 4096, "global": None})
+    kernels = dict(stated.kernels)
+    widths, kv_heads, windows = kernels[attention_facts]
+    assert (widths, kernels[head_facts], kv_heads, windows) == (
+        128, (2560,), 4, (("window", 4096), ("global", None)))
     assert stated.leaf_rows_per_token == dict.fromkeys(
         lm.stacked_leaves, 6 * 1.25 / 4)
     # 28 query heads over 4 key heads of 128 at 16,384: whole column
     # blocks, so the global layer takes the kernel on one chip, and the
     # window layers too: their band is four of its blocks of 1,024
-    form, why = attention_form_why("tpu", 1, stated.attention_widths,
-                                   cfg["horizon"], 4096,
-                                   stated.attention_kv_heads)
+    form, why = attention_form_why("tpu", 1, widths, cfg["horizon"], 4096,
+                                   kv_heads)
     assert form == "kernel" and why.endswith(
         "layers with a window of 4096 in the kernel")
     assert pallas_attention.fits(128, 0, 128, None, 16384)
-    assert [call_form(form, w, cfg["horizon"])
-            for w in stated.attention_windows.values()] == ["kernel",
-                                                            "kernel"]
+    assert [call_form(form, band, cfg["horizon"])
+            for _, band in windows] == ["kernel", "kernel"]
     shapes = lm.param_shapes()
     paths = ["/".join(str(k.key) for k in p) for p, _ in
              jax.tree_util.tree_flatten_with_path(shapes)[0]]
@@ -813,7 +816,7 @@ def test_published_sizes_and_layouts(ref):
     assert len(spec.stacked_leaves) == 3 * layers
     dense = {paths[i].rsplit("/", 1)[1] for i, *_ in spec.dense_leaves}
     assert dense == {"scale"}
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
+    assert unmatched_leaves(stated.partition_rules, shapes) == {}
     assert about["expert_flops_per_member_step"] == int(
         layers * 6 * 16 / 64 * 2 * 3 * 2560 * 768)
     assert about["dense_flops_per_member_step"] == layers * 2 * attention
@@ -824,20 +827,26 @@ def test_published_sizes_and_layouts(ref):
 
 
 def test_no_leaf_falls_to_the_catch_all(tiny):
-    """The model's leaves are all named by rules that were there: q, k, v,
-    o, the router, the stacked experts, the norms, embedding and head."""
+    """The model's leaves are all named by the blocks' rules
+    (models/lm_blocks.py): q, k, v, o, the norms, embedding and head by
+    the decoder's frame, the router and the stacked experts by the expert
+    layer's; its own list is those two and nothing else."""
     shapes = tiny["lm"].param_shapes()
-    assert unmatched_leaves(DEFAULT_PARTITION_RULES, shapes) == {}
-    assert unmatched_leaves(
-        HYBRID_LM_PARTITION_RULES + MOE_LM_PARTITION_RULES, shapes) == {}
-    assert unmatched_leaves(HYBRID_LM_PARTITION_RULES, shapes) != {}
+    own = tiny["lm"].declaration().partition_rules
+    assert unmatched_leaves(own, shapes) == {}
+    assert own == (lm_blocks.DECODER_PARTITION_RULES
+                   + lm_blocks.EXPERT_PARTITION_RULES)
+    assert unmatched_leaves(lm_blocks.DECODER_PARTITION_RULES, shapes) != {}
 
 
 @pytest.mark.parametrize("pop, model", [(2, 4), (1, 2)])
 def test_partition_rules_name_the_leaves(devices8, pop, model):
     mesh = hyperscale_mesh(pop, model, devices8[:pop * model])
-    shapes = WindowMoELM(**TINY).param_shapes()
-    sh = match_partition_rules(DEFAULT_PARTITION_RULES, shapes, mesh)
+    lm = WindowMoELM(**TINY)
+    shapes = lm.param_shapes()
+    sh = match_partition_rules(
+        lm.declaration().partition_rules + DEFAULT_PARTITION_RULES, shapes,
+        mesh)
 
     def spec(*path):
         node = sh
@@ -894,7 +903,7 @@ class TestThroughTheShardedEngine:
         assert (es.engine.pop_shards, es.engine.model_shards) == (pop, model)
         assert es.engine.centre_form == (
             centre_form if model > 1 else "split")
-        assert es.engine.attention_form == "xla"
+        assert es.engine.kernel_facts["attention_form"] == "xla"
         report = es.engine.sharding_report()
         assert report["layer_01/moe/experts/gate"].startswith(
             "PartitionSpec('model'")
@@ -914,10 +923,12 @@ class TestThroughTheShardedEngine:
     def test_one_device_run_its_gauges_and_its_counters(self, one_device):
         es = one_device["es"]
         assert es.engine.forward_form == "perturbed"
-        assert (es.engine.attention_form, es.engine.head_form) == ("xla",
-                                                                   "xla")
+        assert (es.engine.kernel_facts["attention_form"],
+                es.engine.kernel_facts["head_form"]) == (
+                    "xla", "xla")
         # the expert layers' combine too: the scatter-add on a CPU mesh
-        assert (es.engine.combine_form, es.obs.counters.get("combine_form"),
+        assert (es.engine.kernel_facts["combine_form"],
+                es.obs.counters.get("combine_form"),
                 es.run_manifest()["config"]["combine_form"]) == ("xla",) * 3
         assert [r["env_steps"] for r in es.history] == [8 * 21] * 2
         assert -4.6 < es.history[0]["reward_mean"] < -3.9   # about -log 64
@@ -998,11 +1009,12 @@ class TestThroughTheShardedEngine:
         with kernel_attention():
             kern = _es(devices8[:1], 1, compute_dtype=dtype,
                        policy_kwargs=wide, agent_kwargs=env)
-        assert (ref_es.engine.attention_form,
-                kern.engine.attention_form) == ("xla", "kernel")
-        assert ref_es.engine.attention_form_by_kind == (
+        assert (ref_es.engine.kernel_facts["attention_form"],
+                kern.engine.kernel_facts["attention_form"]) == (
+                    "xla", "kernel")
+        assert ref_es.engine.kernel_facts["attention_form_by_kind"] == (
             "window:xla,global:xla")
-        assert kern.engine.attention_form_by_kind == by_kind
+        assert kern.engine.kernel_facts["attention_form_by_kind"] == by_kind
         assert kern.run_manifest()["config"][
             "attention_form_by_kind"] == by_kind
         assert [len(pallas_calls(es.engine._generation_step, es.state,
