@@ -270,7 +270,8 @@ class CCAMoELM:
                 # heads scored and summed at one width; one kind of
                 # attention layer, full causal
                 (pallas_attention.attention_facts,
-                 (self.head_dim, self.num_key_value_heads)),
+                 (self.head_dim, self.num_key_value_heads, None,
+                  self.num_attention_heads)),
                 (pallas_head.head_facts, (self.hidden_size,)),
                 # the token rows the expert layer's combine adds into
                 (pallas_combine.combine_facts, (self.hidden_size,))),

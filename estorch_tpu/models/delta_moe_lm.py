@@ -443,7 +443,8 @@ class DeltaMoELM:
             kernels=(
                 # the full layers' heads, scored and summed at one width
                 *([(pallas_attention.attention_facts,
-                    (self.head_dim, self.num_key_value_heads))]
+                    (self.head_dim, self.num_key_value_heads, None,
+                     self.num_attention_heads))]
                   if full else []),
                 (pallas_head.head_facts, (self.hidden_size,)),
                 (pallas_combine.combine_facts, (self.hidden_size,)),

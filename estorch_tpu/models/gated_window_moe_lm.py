@@ -298,6 +298,9 @@ class GatedWindowMoELM:
                 (pallas_attention.attention_facts,
                  (self.head_dim, self.num_key_value_heads,
                   tuple((KIND[kind], band) for kind, band in bands.items()
+                        if kind in self.layer_types),
+                  # the query heads of each of those kinds' calls
+                  tuple(self.heads_of(kind) for kind in bands
                         if kind in self.layer_types))),
                 (pallas_head.head_facts, (self.hidden_size,)),
                 # the token rows the expert layer's combine adds into

@@ -139,7 +139,8 @@ class HybridLM:
             kernels=(
                 # heads of ONE width, scored and summed
                 (pallas_attention.attention_facts,
-                 (self.head_dim, self.num_key_value_heads)),
+                 (self.head_dim, self.num_key_value_heads, None,
+                  self.num_attention_heads)),
                 # the width the next-token head contracts
                 (pallas_head.head_facts, (self.hidden_size,))))
 

@@ -245,7 +245,7 @@ class IndexedMoELM:
                 # attention layer, over a selection of keys, no band
                 (pallas_attention.attention_facts,
                  (self.head_dim, self.num_key_value_heads,
-                  (("selected", None),))),
+                  (("selected", None),), self.num_attention_heads)),
                 (pallas_head.head_facts, (self.hidden_size,)),
                 # the token rows the expert layer's combine adds into
                 (pallas_combine.combine_facts, (self.hidden_size,))),

@@ -256,7 +256,7 @@ class MoELM:
                 # all heads share, the value width)
                 (pallas_attention.attention_facts,
                  ((self.qk_nope_head_dim, self.qk_rope_head_dim,
-                   self.v_head_dim),)),
+                   self.v_head_dim), None, None, self.num_attention_heads)),
                 # the width the next-token head contracts
                 (pallas_head.head_facts, (self.hidden_size,)),
                 # the token rows the expert layer's combine adds into

@@ -335,7 +335,8 @@ class SambaYLM:
                     tuple((kind, self.sliding_window if kind == WINDOW
                            else None)
                           for kind in dict.fromkeys(self.layer_types)
-                          if kind in ATTENTION_PARTS))),
+                          if kind in ATTENTION_PARTS),
+                    self.num_attention_heads)),
                 # the width the next-token head contracts
                 (pallas_head.head_facts, (self.hidden_size,)),
                 # the selective scans, where a Mamba layer is held

@@ -209,7 +209,8 @@ class WindowMoELM:
                 (pallas_attention.attention_facts,
                  (self.head_dim, self.num_key_value_heads,
                   tuple((kind, band) for kind, band in bands.items()
-                        if kind in self.layer_types))),
+                        if kind in self.layer_types),
+                  self.num_attention_heads)),
                 (pallas_head.head_facts, (self.hidden_size,)),
                 # the token rows the expert layer's combine adds into
                 (pallas_combine.combine_facts, (self.hidden_size,))),

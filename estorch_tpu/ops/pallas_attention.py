@@ -81,12 +81,37 @@ ONE grid step with no key axis (the last section of this file).  A branch at
 TRACE time: without a window the traced kernel is the one above, operand
 for operand.  Which calls take it is :func:`call_form`'s rule.
 
-Grid ``(heads, query blocks, key blocks)``, the key axis innermost and
-sequential; running max, sum and a float32 accumulator in VMEM scratch.
+Grid ``(heads / heads a step, query blocks, key blocks)``, the key axis
+innermost and sequential; running max, sum and a float32 accumulator in VMEM
+scratch, a head of the step its own columns of each.  A STEP HOLDS SEVERAL
+QUERY HEADS where the heads are plain (values apart, no shared part, no
+pairs) and come in key-value groups: :func:`step_heads` of the group's
+heads, contiguous columns ``(block_q, heads · width)`` of q and of the
+context (a group's heads ARE contiguous in ``[T, heads · 128]``) over ONE
+key and value block, fetched once for them; the body folds them one after
+another in a loop at dynamic lane offsets.  What that buys is the grid's
+steps, not the body: a head of 16,384 positions is 256 steps of which 120
+lie beyond the diagonal, skipped and still paid (0.16 µs each), and the body
+is scheduled no tighter for unrolled heads (PERF.md §6, PR 57, has Mosaic's
+bundle counts and the kernel-only table).  The step's VMEM (a head's
+lane-replicated max and sum, its accumulator, two copies of its q and
+context: 2.5 MiB a head at 1,024 rows of 128 lanes beside the 10 MiB of ONE
+head's float32 tile, exponential and probabilities) is asked for with
+``vmem_limit_bytes``, 64 of the v5e's 128 MiB, as the head's and the
+combine's kernels ask.  A branch at TRACE time: a call the rule leaves one
+head a step traces the kernel above, operand for operand.
+
 Causal by construction: a key block beyond the query block's last row is
 skipped (``pl.when``) AND not fetched (its index map clamps to the last
 visible block, and a block whose index did not change is not copied
-again); the mask is applied only inside tiles the diagonal crosses.
+again); the mask is applied only inside tiles the diagonal crosses, and
+where the blocks are square such a tile is made by sub-tiles of a QUARTER of
+the block (:func:`diagonal_sub`): the six of its sixteen above the diagonal
+hold no visible pair and are never multiplied, masked or exponentiated, the
+four on the diagonal carry the mask, the six below none (3,515 bundles of
+Mosaic's schedule where the masked tile takes 5,001 and a tile below the
+diagonal 4,817: mostly the mask's iota, compare and select and the dead
+sub-tiles' exponentials, not the products).
 Members enter through ``vmap`` (the batching rule of ``pallas_call`` puts
 them in front of the grid).
 
@@ -95,7 +120,9 @@ Precision, the same as the XLA form's: operands in the dtype handed in
 probabilities cast to the operands' dtype for P·V with float32
 accumulation, ONE divide at the end.  Nothing is approximated or dropped;
 what differs from the XLA form is the order of the float32 sums and that
-the un-normalised probabilities are what is rounded to bfloat16.
+the un-normalised probabilities are what is rounded to bfloat16.  Several
+heads a step change no number of any head; the diagonal's sub-tiles change
+the order of P·V's float32 partial sums inside that tile and nothing else.
 
 What Mosaic dictated (learned by compiling for the v5e, not by reading):
 a block's last two dimensions must be divisible by 8 and 128 or span the
@@ -316,8 +343,7 @@ def _form_why(traced: tuple[bool, str], widths, length: int,
               window: int | None, kv_heads: int | None) -> tuple[str, str]:
     """:func:`attention_form_why` from ``traced``, the answer of
     :func:`traced_why` (an engine's build has it already)."""
-    head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
-                           else widths)
+    head, shared, value = _parts_of(widths)
     traced, where = traced
     failed = ([] if traced else [where]) + _shape_failures(
         head, shared, value, kv_heads, length)
@@ -388,6 +414,74 @@ def scoped_interpret() -> bool | None:
 # --------------------------------------------------------------------------
 
 
+# a step of several heads asks Mosaic for this much of the v5e's 128 MiB of
+# VMEM (the default 16 MiB of scoped VMEM holds ONE head's float32 tile, its
+# exponential and the probabilities, about 10 MiB at 1,024 x 1,024, and each
+# head of the step adds 1.5 MiB of scratch and 1 MiB of q and context), as
+# ops/pallas_head.py and ops/pallas_combine.py ask; and the rule keeps a
+# step, by its own count, within this much of it
+STEP_VMEM_LIMIT = 64 << 20
+STEP_VMEM_BYTES = 48 << 20
+# the most heads a step holds: a grid step's cost is already an eighth
+STEP_MOST_HEADS = 8
+
+
+def step_vmem_bytes(heads: int, head_dim: int, value_dim: int,
+                    itemsize: int, block_q: int, block_k: int) -> int:
+    """The VMEM a grid step of ``heads`` query heads holds, counted from
+    its shapes: the float32 score tile, its exponential and the
+    probabilities in the operands' dtype on the stack (one head's at a
+    time: the heads are a loop); a head's running max and sum
+    (lane-replicated ``[block_q, 128]`` float32) and accumulator; the
+    pipeline's two copies of q and of the context, of the step's key and
+    value blocks and of a selection's int8 tile (counted whether the call
+    has one or not: the rule does not read it)."""
+    stack = block_q * block_k * (4 + 4 + itemsize)
+    scratch = heads * block_q * (2 * LANES + value_dim) * 4
+    blocks = 2 * itemsize * (heads * block_q * (head_dim + value_dim)
+                             + block_k * (head_dim + value_dim))
+    return stack + scratch + blocks + 2 * block_q * block_k
+
+
+def step_heads(group: int, head_dim: int, value_dim: int, itemsize: int,
+               block_q: int, block_k: int) -> int:
+    """How many query heads ONE grid step of the causal kernel holds for a
+    call of plain heads (values apart, no shared part, no pairs) in
+    key-value groups of ``group``: the largest divisor of the group, at
+    most :data:`STEP_MOST_HEADS`, whose step is within
+    :data:`STEP_VMEM_BYTES` by :func:`step_vmem_bytes`.  A step's heads
+    are contiguous columns of q and of the context and read ONE key-value
+    head, fetched once for them; 256 grid steps a head at 16,384 positions
+    (120 of them beyond the diagonal, skipped and still paid) become 256 a
+    step's heads.  A function of the call's shapes alone: 6 of `laguna`'s
+    group of 6, 7 of 7, 8 of 8 (heads of 128 and of 256 in bfloat16), 4 of
+    8 float32 heads of 256, 1 where heads have no groups."""
+    return max((h for h in range(1, min(group, STEP_MOST_HEADS) + 1)
+                if group % h == 0 and step_vmem_bytes(
+                    h, head_dim, value_dim, itemsize, block_q,
+                    block_k) <= STEP_VMEM_BYTES), default=1)
+
+
+def diagonal_sub(block_q: int, block_k: int) -> int:
+    """The width of the sub-tiles a tile on the diagonal is made by: a
+    QUARTER of the block where the blocks are square and the quarter is
+    whole 128-lane blocks (256 of 1,024: ten of the tile's sixteen sub-tiles
+    are multiplied, masked (the four on the diagonal alone) and
+    exponentiated; the six above it hold no visible pair and are never
+    made), else half of it (128 of 256); 0 (the whole tile, masked) for
+    unequal blocks and for blocks of 128.  On the v5e (PERF.md §6, PR 57)
+    the masked tile is 5,001 bundles of Mosaic's schedule and the sub-tiled
+    one 3,556 in halves, 3,515 in quarters, 3,498 in eighths, most of it the
+    mask's iota, compare and select and the dead sub-tiles' exponentials; a
+    call at 16,384 positions reads the same in all three (26.12 ms for
+    26.95), the latent heads' 256-deep scores at 4,096 positions 1.816 ms in
+    halves and 1.743 in quarters and eighths (2.007 masked)."""
+    if block_q != block_k:
+        return 0
+    return next((block_k // n for n in (4, 2)
+                 if block_k % n == 0 and block_k // n % LANES == 0), 0)
+
+
 def _last_visible(i, block_q: int, block_k: int):
     """Index of the last key block the rows of query block ``i`` see."""
     return ((i + 1) * block_q - 1) // block_k
@@ -420,7 +514,8 @@ def attention_cost(length: int, num_heads: int, num_kv_heads: int,
                    block_q: int, block_k: int, itemsize: int,
                    paired: bool = False,
                    selected: bool = False,
-                   window: int | None = None) -> pl.CostEstimate:
+                   window: int | None = None,
+                   sub: int = 0) -> pl.CostEstimate:
     """What ONE call of the kernel does, from its grid and blocks: the
     declaration ``pallas_call`` hands XLA (the scheduler reads it, and a
     profiler's trace carries it as the custom call's ``flops`` and
@@ -430,32 +525,41 @@ def attention_cost(length: int, num_heads: int, num_kv_heads: int,
     block_q · block_k · (head_dim + shared_dim + value_dim)``: the widths
     the model states, although the MXU contracts a shared 64 as 128 deep
     and multiplies the masked half of a diagonal tile too (and a pair's
-    64-wide score heads as 128 deep: two heads a block).
+    64-wide score heads as 128 deep: two heads a block).  ``sub``: the
+    square tile on the diagonal, one a query block, is multiplied by
+    sub-tiles of ``sub`` that hold a visible pair alone, ``n (n + 1) / 2``
+    of its ``n²`` (ten of sixteen in quarters), products and exponentials
+    alike; 0: whole.
     Transcendentals: a tile's exponentials, one a score and one a row for
     the rescaling.  Bytes: q, k, v, the shared parts and the context ONCE
     each (the algorithm's least; k and v are fetched again for every query
-    block that sees them, 2.5 times at four blocks; ``paired``: ONE value
-    block a pair of key heads; ``selected``: the int8 tiles of the
-    selection the kernel computes under, once, although every head fetches
-    them again).  ``vmap`` scales all three by the members in front of the
-    grid."""
+    block that sees them, once a grid step whatever heads it holds;
+    ``paired``: ONE value block a pair of key heads; ``selected``: the int8
+    tiles of the selection the kernel computes under, once, although every
+    step fetches them again).  ``vmap`` scales all three by the members in
+    front of the grid."""
     tiles = sum(_band_blocks(length, block_q, block_k, window))
+    # of each query block's diagonal tile the sub-tiles above the diagonal
+    # are not made, (n - 1) / 2n of it (none where the tile is whole)
+    n = block_k // sub if sub else 1
+    scores = (tiles * 2 * n - length // block_q * (n - 1)) * (
+        block_q * block_k) // (2 * n)
     value_heads = num_kv_heads // 2 if paired else num_kv_heads
     elements = length * (
         num_heads * (head_dim + shared_dim + value_dim)        # q, q_shared, out
         + num_kv_heads * head_dim + value_heads * value_dim    # k, v
         + shared_dim)                                          # k_shared
     return pl.CostEstimate(
-        flops=2 * num_heads * tiles * block_q * block_k
-        * (head_dim + shared_dim + value_dim),
-        transcendentals=num_heads * tiles * block_q * (block_k + 1),
+        flops=2 * num_heads * scores * (head_dim + shared_dim + value_dim),
+        transcendentals=num_heads * (scores + tiles * block_q),
         bytes_accessed=elements * itemsize
         + (tiles * block_q * block_k if selected else 0))
 
 
 def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
                       block_k: int, selected: bool = False,
-                      window: int | None = None):
+                      window: int | None = None, heads: int = 1,
+                      sub: int = 0):
     # with a shared score term: each head's second query part, the one key;
     # with a selection: its tile, last of the operands
     *shared, o_ref, m_ref, l_ref, acc_ref = refs
@@ -474,46 +578,123 @@ def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
+    def cut(ref, h):
+        """Head ``h``'s columns of a block that holds the step's heads side
+        by side, whole lane blocks each (the block itself where the step
+        holds one).  ``h``: a Python ``int`` or a loop's index."""
+        if heads == 1:
+            return ref
+        width = ref.shape[1] // heads
+        if isinstance(h, int):
+            return ref.at[:, h * width:(h + 1) * width]
+        return ref.at[:, pl.ds(pl.multiple_of(h * width, LANES), width)]
+
+    # sub-blocks of a square tile the diagonal crosses: only the sub-tiles
+    # that hold a visible pair are made (`diagonal_sub`)
+    n = block_k // sub if sub else 0
+
+    def stack(*parts):
+        # the parts that hold rows, one under another
+        parts = [x for x in parts if x is not None]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+
+    def diagonal_scores(q, k):
+        """``q kᵀ`` of the tile on the diagonal, key sub-block ``c`` against
+        the rows from its first on (the rows above it see none of its keys:
+        ``-inf`` with no product, no mask and no exponential made of it),
+        the causal mask inside the sub-tile on the diagonal alone."""
+        own = (jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+               <= jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0))
+        tile = []
+        for c in range(n):
+            lo, hi = c * sub, (c + 1) * sub
+            s = jax.lax.dot_general(q[lo:], k[lo:hi], _QK_DIMS,
+                                    preferred_element_type=jnp.float32)
+            tile.append(stack(
+                jnp.full((lo, sub), -jnp.inf) if lo else None,
+                jnp.where(own, s[:sub], -jnp.inf),
+                s[sub:] if hi < block_k else None))
+        return jnp.concatenate(tile, axis=1) * scale
+
+    def diagonal_context(p, v):
+        """``p v`` of the tile on the diagonal: a key sub-block's values
+        against the rows that see it (``p`` is 0 above the diagonal)."""
+        terms = [[] for _ in range(n)]      # a query sub-block's
+        for c in range(n):
+            lo, hi = c * sub, (c + 1) * sub
+            product = jnp.dot(p[lo:, lo:hi].astype(v.dtype), v[lo:hi],
+                              preferred_element_type=jnp.float32)
+            for a in range(c, n):
+                terms[a].append(product[(a - c) * sub:(a - c + 1) * sub])
+        return stack(*(sum(t[1:], t[0]) for t in terms))
+
     def fold(masked: bool, edge: bool = False):
-        """This key block into the running max, sum and accumulator."""
-        v = v_ref[...]
-        q, k = q_ref[...], k_ref[...]
-        if shared:
-            # q kᵀ + q_shared k_sharedᵀ as ONE contraction over the parts
-            # side by side: the MXU sums both terms where it accumulates
-            qs_ref, ks_ref = shared
-            q = jnp.concatenate([q, qs_ref[...]], axis=1)
-            k = jnp.concatenate([k, ks_ref[...]], axis=1)
-        s = jax.lax.dot_general(q, k, _QK_DIMS,
-                                preferred_element_type=jnp.float32) * scale
-        if masked:
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            seen = cols <= rows
-            if edge:
-                seen = jnp.logical_and(seen, cols > rows - window)
-            s = jnp.where(seen, s, -jnp.inf)
-        if selected:
-            s = jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, -jnp.inf)
-        # without a band or a selection every row sees key 0, which block 0
-        # holds: after the first block the running max is finite, so
-        # exp(-inf - max) is 0, never NaN
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_base = m_next
-        if selected or window is not None:
-            # a row that has seen no key yet (it selected none so far; the
-            # band's first block holds none of a late row's keys): max
-            # -inf, and its exponentials are taken against 0 (all of them 0)
-            m_base = jnp.where(m_next == -jnp.inf, 0.0, m_next)
-        alpha = jnp.exp(m_prev - m_base)
-        p = jnp.exp(s - m_base[:, :1])
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_next
-        acc_ref[...] = alpha[:, :1] * acc_ref[...] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        """This key block into the running max, sum and accumulator of
+        every head of the step."""
+        sub_tiles = bool(sub) and masked and not edge
+
+        def head(h):
+            # the step's heads read its ONE key and value block
+            v = v_ref[...]
+            q, k = cut(q_ref, h)[...], k_ref[...]
+            if shared:
+                # q kᵀ + q_shared k_sharedᵀ as ONE contraction over the parts
+                # side by side: the MXU sums both terms where it accumulates
+                qs_ref, ks_ref = shared
+                q = jnp.concatenate([q, qs_ref[...]], axis=1)
+                k = jnp.concatenate([k, ks_ref[...]], axis=1)
+            if sub_tiles:
+                s = diagonal_scores(q, k)
+            else:
+                s = jax.lax.dot_general(
+                    q, k, _QK_DIMS,
+                    preferred_element_type=jnp.float32) * scale
+                if masked:
+                    rows = i * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 0)
+                    cols = j * block_k + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 1)
+                    seen = cols <= rows
+                    if edge:
+                        seen = jnp.logical_and(seen, cols > rows - window)
+                    s = jnp.where(seen, s, -jnp.inf)
+            if selected:
+                s = jnp.where(sel_ref[...].astype(jnp.int32) != 0, s,
+                              -jnp.inf)
+            # without a band or a selection every row sees key 0, which
+            # block 0 holds: after the first block the running max is
+            # finite, so exp(-inf - max) is 0, never NaN
+            m_prev = cut(m_ref, h)[...]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_base = m_next
+            if selected or window is not None:
+                # a row that has seen no key yet (it selected none so far;
+                # the band's first block holds none of a late row's keys):
+                # max -inf, and its exponentials are taken against 0 (all
+                # of them 0)
+                m_base = jnp.where(m_next == -jnp.inf, 0.0, m_next)
+            alpha = jnp.exp(m_prev - m_base)
+            p = jnp.exp(s - m_base[:, :1])
+            cut(l_ref, h)[...] = alpha * cut(l_ref, h)[...] + jnp.sum(
+                p, axis=1, keepdims=True)
+            cut(m_ref, h)[...] = m_next
+            cut(acc_ref, h)[...] = alpha[:, :1] * cut(acc_ref, h)[...] + (
+                diagonal_context(p, v) if sub_tiles else jnp.dot(
+                    p.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32))
+
+        if heads == 1:
+            head(0)
+        else:
+            # a LOOP over the step's heads, each at its own lane offset:
+            # at 1,024 x 1,024 Mosaic schedules unrolled heads no tighter
+            # than one (4,920 to 5,140 bundles a head for 4,817) and takes
+            # six times as long to compile them (PERF.md, PR 57)
+            def one(h, carry):
+                head(h)
+                return carry
+
+            jax.lax.fori_loop(0, heads, one, 0)
 
     # a tile needs the mask where its last key lies beyond its first row
     crosses = (j + 1) * block_k - 1 > i * block_q
@@ -539,12 +720,15 @@ def _attention_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
 
     @pl.when(j == last)
     def _finish():
-        o_ref[...] = (acc_ref[...] / l_ref[...][:, :1]).astype(o_ref.dtype)
+        for h in range(heads):
+            cut(o_ref, h)[...] = (cut(acc_ref, h)[...]
+                                  / cut(l_ref, h)[...][:, :1]).astype(
+                                      o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "num_heads", "num_kv_heads", "head_dim", "value_dim", "scale", "block_q",
-    "block_k", "interpret", "paired", "window"))
+    "block_k", "interpret", "paired", "window", "heads_a_step", "sub"))
 def causal_attention(
     q: jax.Array,  # [T, num_heads · head_dim], rotated, compute dtype
     k: jax.Array,  # [T, num_kv_heads · head_dim], rotated, compute dtype
@@ -563,6 +747,8 @@ def causal_attention(
     block_k: int | None = None,
     paired: bool = False,
     window: int | None = None,
+    heads_a_step: int | None = None,
+    sub: int | None = None,
 ) -> jax.Array:
     """The context ``softmax(scale · s + causal mask) v`` per head, ``[T,
     num_heads · value_dim]`` in q's dtype, with grouped heads (query head
@@ -615,7 +801,16 @@ def causal_attention(
     ``block_q``, ``block_k``: rows of a query and of a key block; where
     none is given :func:`kernel_block` of ``T`` (all of it where it has
     none: the interpreter's).  On the chip widths and blocks are multiples
-    of 128, the shared width 64 too (:func:`attention_form`)."""
+    of 128, the shared width 64 too (:func:`attention_form`).
+
+    ``heads_a_step``, ``sub``: the query heads ONE grid step holds and the
+    width of the sub-tiles a tile on the diagonal is made by (0: the whole
+    masked tile); where not given, as for the blocks, the rule's
+    (:func:`step_heads`, :func:`diagonal_sub`: functions of the call's
+    shapes; ONE head a step for heads in pairs, with a shared part or with
+    their values beside their keys).  Several heads a step are plain heads,
+    a divisor of the key-value group; sub-tiles cut a square block in
+    several."""
     t = q.shape[0]
     value_dim = value_dim or head_dim
     own = kernel_block(t) or t
@@ -677,8 +872,27 @@ def causal_attention(
             j = _first_visible(i, block_q, block_k, window) + j
         return jnp.minimum(j, _last_visible(i, block_q, block_k))
 
+    plain = not (beside or shared or paired)
+    heads = heads_a_step or (step_heads(
+        group, head_dim, value_dim, q.dtype.itemsize, block_q, block_k)
+        if plain else 1)
+    if sub is None:
+        sub = diagonal_sub(block_q, block_k)
+    if heads > 1 and not (plain and group % heads == 0):
+        raise ValueError(
+            f"{heads} heads a step: plain heads alone, a divisor of the "
+            f"key-value group of {group}")
+    if sub and (block_q != block_k or block_k % sub or block_k == sub):
+        raise ValueError(
+            f"sub-tiles of {sub} cut a square tile into several; got "
+            f"blocks ({block_q}, {block_k})")
+    # a step holds `heads` query heads of ONE key-value head, contiguous
+    # columns of q and of the context; the group's next steps read the same
+    # key and value block (`steps` a group: the block is not copied again)
+    steps = group // heads
+
     def kv_block(h, i, j):
-        return kv_row(i, j), h // group
+        return kv_row(i, j), h // steps
 
     def key_beside(h, i, j):
         return kv_row(i, j), 2 * (h // group)
@@ -686,8 +900,9 @@ def causal_attention(
     def values_beside(h, i, j):
         return kv_row(i, j), 2 * (h // group) + 1
 
-    # the column block of q and of v that grid head h reads
-    q_width, own_q, own_v = head_dim, (lambda h: h), (lambda h: h // group)
+    # the column block of q and of v that grid step h reads
+    q_width, k_width, own_q, own_v = heads * head_dim, head_dim, (
+        lambda h: h), (lambda h: h // steps)
     if paired:
         # two heads a column block: [k₀, 0 | 0, k₁] a key pair, whose
         # column block 2p + m picks head m's half of q's block by its
@@ -702,11 +917,12 @@ def causal_attention(
         q_width, own_q, own_v = 2 * head_dim, (
             lambda h: h // (2 * group) * group + h % group), (
             lambda h: h // (2 * group))
+        k_width = q_width
 
     operands = [q, k, k if beside else v]
     in_specs = [
         pl.BlockSpec((block_q, q_width), lambda h, i, j: (i, own_q(h))),
-        pl.BlockSpec((block_k, q_width), key_beside if beside else kv_block),
+        pl.BlockSpec((block_k, k_width), key_beside if beside else kv_block),
         pl.BlockSpec((block_k, value_dim),
                      values_beside if beside
                      else lambda h, i, j: (kv_row(i, j), own_v(h))),
@@ -715,7 +931,7 @@ def causal_attention(
     cost = attention_cost(
         t, num_heads, num_kv_heads, head_dim, value_dim,
         k_shared.shape[-1] if shared else 0, block_q, block_k,
-        q.dtype.itemsize, paired, choose, window)
+        q.dtype.itemsize, paired, choose, window, sub)
     if shared:
         width = k_shared.shape[-1]
         if (q_shared.shape != (t, num_heads * width)
@@ -760,23 +976,26 @@ def causal_attention(
         num_scalar_prefetch=0,
         # the key axis: the most key blocks a query block sees (all of them
         # without a band: its last query block sees every one)
-        grid=(num_heads, t // block_q,
+        grid=(num_heads // heads, t // block_q,
               max(_band_blocks(t, block_q, block_k, window))),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_q, value_dim), lambda h, i, j: (i, h)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, LANES), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, value_dim), jnp.float32),  # accumulator
+        out_specs=pl.BlockSpec((block_q, heads * value_dim),
+                               lambda h, i, j: (i, h)),
+        scratch_shapes=[    # a head of the step its own columns of each
+            pltpu.VMEM((block_q, heads * LANES), jnp.float32),  # running max
+            pltpu.VMEM((block_q, heads * LANES), jnp.float32),  # running sum
+            pltpu.VMEM((block_q, heads * value_dim), jnp.float32),  # accumulator
         ],
     )
     return pl.pallas_call(
         functools.partial(_attention_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, selected=choose, window=window),
+                          block_k=block_k, selected=choose, window=window,
+                          heads=heads, sub=sub),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, num_heads * value_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **({} if heads == 1 else {"vmem_limit_bytes": STEP_VMEM_LIMIT})),
         cost_estimate=cost,
         name="causal_attention",
         interpret=interpret,
@@ -804,43 +1023,83 @@ def causal_attention(
 # its callers' lines.)
 
 
+def _parts_of(widths) -> tuple:
+    """``(a head's own part, the shared part, the value width)`` of heads
+    as a model states them: one width, or the three."""
+    return (widths, 0, widths) if isinstance(widths, int) else tuple(widths)
+
+
 def heads_in_pairs(widths, kv_heads: int | None) -> bool:
     """Whether heads of ``widths`` (as a model states them, see
     :func:`attention_form_why`) over ``kv_heads`` key heads reach the core
     two a column block (``attention_core(paired=True)``): what
     :func:`call_form` asks of a call, said of a model."""
-    head, shared, value = ((widths, 0, widths) if isinstance(widths, int)
-                           else widths)
-    return _pair(head, shared, value, kv_heads)
+    return _pair(*_parts_of(widths), kv_heads)
 
 
 # what :func:`attention_facts` answers for: the names of an engine's
 # gauges and manifest entries (ops/kernel_facts.py collects the kernels')
-FACTS = ("attention_form", "attention_form_why", "attention_form_by_kind")
+FACTS = ("attention_form", "attention_form_why", "attention_form_by_kind",
+         "attention_heads_a_step")
 
 
 def attention_facts(scope, widths, kv_heads: int | None = None,
-                    windows: tuple | None = None) -> dict:
+                    windows: tuple | None = None,
+                    query_heads: int | tuple | None = None) -> dict:
     """What an engine's build reports of a model's attention, as the model
     names it in ``PolicyDeclaration.kernels``: ``widths`` and ``kv_heads``
     as :func:`attention_form_why` reads them, ``windows`` the ``(attention
     layer kind, the band of its calls | None)`` pairs in layer order (one
-    kind, ``"causal"``, no band, where it states none).  ``scope`` is the
-    engine's (``ops.kernel_facts.BuildScope``): whether kernels may be
-    traced, and the sequence length.  ``attention_form`` and its reason by
+    kind, ``"causal"``, no band, where it states none), ``query_heads`` the
+    query heads of its calls (one number, or one a kind in ``windows``'
+    order).  ``scope`` is the engine's (``ops.kernel_facts.BuildScope``):
+    whether kernels may be traced, the sequence length, the compute dtype's
+    item size.  ``attention_form`` and its reason by
     :func:`attention_form_why`'s rule; ``attention_form_by_kind``,
     ``"<kind>:<form>,…"``, the form the calls of each kind take by
-    :func:`call_form`'s."""
+    :func:`call_form`'s; ``attention_heads_a_step``, ``"<kind>:<heads>,…"``
+    over the kinds in the kernel form, the query heads ONE grid step of
+    their calls holds (:func:`call_heads`), ``None`` where no kind is or the
+    model states no ``query_heads``."""
     windows = windows or (("causal", None),)
     form, why = _form_why(
         scope.traced, widths, scope.horizon,
         next((band for _, band in windows if band is not None), None),
         kv_heads)
     paired = heads_in_pairs(widths, kv_heads)
+    forms = [call_form(form, band, scope.horizon, paired)
+             for _, band in windows]
+    if not isinstance(query_heads, tuple):
+        query_heads = (query_heads,) * len(windows)
+    steps = [f"{kind}:" + str(call_heads(widths, kv_heads, heads, band,
+                                         scope.horizon, scope.itemsize))
+             for (kind, band), took, heads in zip(windows, forms, query_heads)
+             if took == "kernel" and heads]
     return {"attention_form": form, "attention_form_why": why,
             "attention_form_by_kind": ",".join(
-                f"{kind}:" + call_form(form, band, scope.horizon, paired)
-                for kind, band in windows)}
+                f"{kind}:{took}" for (kind, _), took in zip(windows, forms)),
+            "attention_heads_a_step": ",".join(steps) or None}
+
+
+def call_heads(widths, kv_heads: int | None, query_heads: int,
+               window: int | None, length: int, itemsize: int) -> int:
+    """The query heads ONE grid step holds in a kernel call over ``length``
+    positions of ``query_heads`` heads of ``widths`` over ``kv_heads``
+    key-value heads (as a model states them) under a band of ``window``:
+    what :func:`causal_attention` takes for such a call, said of a model.
+    :func:`band_heads` where the band is the block itself,
+    :func:`step_heads` for every other call of plain heads, 1 for heads in
+    pairs, with a shared part or with their values beside their keys."""
+    head, shared, value = _parts_of(widths)
+    if shared or not kv_heads or _pair(head, shared, value, kv_heads):
+        return 1
+    group = query_heads // kv_heads
+    block = band_block(window, length)
+    if block:
+        return band_heads(group, block, head, itemsize)
+    own = kernel_block(length) or length
+    return step_heads(group, head, value, itemsize, own,
+                      key_block(own, max(head, value), itemsize))
 
 
 def band_block(window: int | None, length: int,

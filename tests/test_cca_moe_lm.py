@@ -779,7 +779,7 @@ def test_the_declaration_names_leaves_the_tree_has(tiny):
     # head and the combine at the hidden width; no scan
     kernels = dict(stated.kernels)
     assert (kernels[attention_facts], kernels[head_facts],
-            kernels[combine_facts]) == ((8, 2), (32,), (32,))
+            kernels[combine_facts]) == ((8, 2, None, 8), (32,), (32,))
     assert scan_facts not in kernels and stated.selection_bytes is None
     assert stated.outputs == ("expert_load",)
     assert stated.facts == {
@@ -848,8 +848,8 @@ def test_published_sizes_and_layouts(ref):
         lm.rope_theta, lm.partial_rotary_factor)
     stated = lm.declaration()
     kernels = dict(stated.kernels)
-    widths, kv_heads = kernels[attention_facts]
-    assert (widths, kernels[head_facts]) == (128, (2048,))
+    widths, kv_heads, _, query_heads = kernels[attention_facts]
+    assert (widths, kernels[head_facts], query_heads) == (128, (2048,), 8)
     assert attention_form_why("tpu", 1, widths, cfg["horizon"], None,
                               kv_heads)[0] == "kernel"
     shapes = lm.param_shapes()

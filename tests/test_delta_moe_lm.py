@@ -733,7 +733,7 @@ def test_the_declaration(tiny):
     kernels = dict(stated.kernels)
     assert (kernels[attention_facts], kernels[head_facts],
             kernels[combine_facts], kernels[delta_facts]) == (
-        (16, 2), (32,), (32,), (8, 8, 8))
+        (16, 2, None, 4), (32,), (32,), (8, 8, 8))
     assert scan_facts not in kernels
     assert stated.leaf_rows == {"head/kernel": 8}
     assert stated.leaf_rows_per_token == dict.fromkeys(
@@ -809,10 +809,10 @@ def test_published_sizes_and_layouts(ref):
     assert cfg["horizon"] == 16384
     stated = lm.declaration()
     kernels = dict(stated.kernels)
-    widths, kv_heads = kernels[attention_facts]
-    assert (widths, kv_heads, kernels[head_facts], kernels[combine_facts],
-            kernels[delta_facts]) == (
-        256, 2, (2048,), (2048,), (128, 128, 64))
+    widths, kv_heads, _, query_heads = kernels[attention_facts]
+    assert (widths, kv_heads, query_heads, kernels[head_facts],
+            kernels[combine_facts], kernels[delta_facts]) == (
+        256, 2, 16, (2048,), (2048,), (128, 128, 64))
     assert stated.leaf_rows_per_token == dict.fromkeys(
         lm.stacked_leaves, 10 * 1.25 / 16)
     # 16 query heads over 2 key heads of 256 at 16,384: two column blocks a

@@ -740,7 +740,7 @@ def test_the_declaration(tiny):
     # band, in layer order; the head and the combine at the hidden width
     kernels = dict(stated.kernels)
     assert kernels[attention_facts] == (
-        8, 2, (("sliding", 6), ("full", None)))
+        8, 2, (("sliding", 6), ("full", None)), (6, 4))
     assert (kernels[head_facts], kernels[combine_facts]) == ((32,), (32,))
     assert stated.leaf_rows == {"head/kernel": 8}
     assert stated.leaf_rows_per_token == dict.fromkeys(
@@ -756,7 +756,8 @@ def test_the_declaration(tiny):
     one = GatedWindowMoELM(**{
         **TINY, "layer_types": (SLIDING,), "mlp_layer_types": ("sparse",),
         "num_attention_heads_per_layer": (6,)}).declaration()
-    assert dict(one.kernels)[attention_facts][2] == (("sliding", 6),)
+    assert dict(one.kernels)[attention_facts][2:] == ((("sliding", 6),),
+                                                      (6,))
     assert "full_heads" not in one.facts and one.facts["full_layers"] == 0
 
 

@@ -711,9 +711,9 @@ def test_published_sizes_and_layouts(ref):
     assert list(lm.mrope_section) == cfg["rope_scaling"]["mrope_section"]
     stated = lm.declaration()
     kernels = dict(stated.kernels)
-    widths, kv_heads, windows = kernels[attention_facts]
-    assert (widths, kernels[head_facts], windows) == (
-        128, (2048,), (("selected", None),))
+    widths, kv_heads, windows, query_heads = kernels[attention_facts]
+    assert (widths, kernels[head_facts], windows, query_heads) == (
+        128, (2048,), (("selected", None),), 32)
     assert attention_form_why("tpu", 1, widths, cfg["horizon"], None,
                               kv_heads)[0] == "kernel"
     # 16,384 positions: the selection and the index scores of one member

@@ -56,11 +56,11 @@ SINGLE_TERM_PINNED = {
 }
 
 
-def _qkv(t, nq, nkv, dtype, seed=0, spread=1.0):
+def _qkv(t, nq, nkv, dtype, seed=0, spread=1.0, head=HD):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     return tuple(
-        (spread * jax.random.normal(k, (t, n * HD), jnp.float32)).astype(dtype)
-        for k, n in zip(ks, (nq, nkv, nkv)))
+        (spread * jax.random.normal(k, (t, n * head), jnp.float32)).astype(
+            dtype) for k, n in zip(ks, (nq, nkv, nkv)))
 
 
 def _parts(t, nh, head, shared, value, dtype, seed=0):
@@ -108,15 +108,17 @@ def _selection(t, topk, seed=0):
 
 
 def _plain(q, k, v, nq, nkv, scale, paired=False, selected=None,
-           window=None, value=HD):
+           window=None, value=None, head=HD):
     """Full masked softmax per head, float32 ``highest``; under a
     ``selected [T, T]`` the keys it marks alone, under a ``window`` the
-    keys ``(t - window, t]``; values ``value`` wide."""
+    keys ``(t - window, t]``; heads ``head`` wide, values ``value`` (as
+    wide as the heads where not given)."""
     if paired:
         return _plain_pairs(q, k, v, nq, nkv, scale)
+    value = value or head
     t, f32, hi = q.shape[0], jnp.float32, "highest"
-    qh = q.astype(f32).reshape(t, nq, HD)
-    kh = jnp.repeat(k.astype(f32).reshape(t, nkv, HD), nq // nkv, axis=1)
+    qh = q.astype(f32).reshape(t, nq, head)
+    kh = jnp.repeat(k.astype(f32).reshape(t, nkv, head), nq // nkv, axis=1)
     vh = jnp.repeat(v.astype(f32).reshape(t, nkv, value), nq // nkv, axis=1)
     s = jnp.einsum("qhd,khd->hqk", qh, kh, precision=hi) * scale
     seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
@@ -679,6 +681,57 @@ class TestTheBand:
         assert block * heads * width * itemsize <= max(
             pallas_attention.BAND_Q_BYTES, block * width * itemsize)
 
+    @pytest.mark.parametrize(
+        "group, width, itemsize, block_q, block_k, heads", [
+            # the cells: laguna's full layers, smallthinker's, keye's (the
+            # rule leaves room for a selection's tile whether there is one
+            # or not), zaya1's, qwen3next's heads of two lane blocks
+            (6, 128, 2, 1024, 1024, 6), (7, 128, 2, 1024, 1024, 7),
+            (8, 128, 2, 1024, 1024, 8), (4, 128, 2, 1024, 1024, 4),
+            (8, 256, 2, 1024, 1024, 8),
+            # float32: a group of 6 of one lane block still fits; 8 heads
+            # of two lane blocks (key blocks of 512) do not, 4 do
+            (6, 128, 4, 1024, 1024, 6), (8, 256, 4, 1024, 512, 4),
+            # no group, no step to share
+            (1, 128, 2, 1024, 1024, 1), (1, 256, 4, 1024, 512, 1),
+            # at most eight, a divisor of the group
+            (16, 128, 2, 1024, 1024, 8), (12, 128, 2, 1024, 1024, 6),
+            (9, 128, 2, 1024, 1024, 3), (11, 128, 2, 1024, 1024, 1),
+            # heads of four lane blocks: a divisor that fits
+            (8, 512, 2, 1024, 1024, 4), (8, 512, 4, 1024, 512, 2),
+            # the interpreter's tiny blocks: the whole group
+            (2, 8, 4, 16, 8, 2), (7, 8, 2, 16, 16, 7)])
+    def test_the_heads_a_step_of_the_causal_kernel(self, group, width,
+                                                   itemsize, block_q,
+                                                   block_k, heads):
+        """``step_heads``: the largest divisor of the key-value group, at
+        most eight, whose step is within the rule's VMEM by its own count;
+        a function of the call's shapes alone."""
+        took = pallas_attention.step_heads(group, width, width, itemsize,
+                                           block_q, block_k)
+        assert took == heads and group % took == 0
+        assert took <= pallas_attention.STEP_MOST_HEADS
+        assert took == 1 or pallas_attention.step_vmem_bytes(
+            took, width, width, itemsize, block_q,
+            block_k) <= pallas_attention.STEP_VMEM_BYTES < (
+                pallas_attention.STEP_VMEM_LIMIT)
+        # the count itself, by hand, at laguna's step of six: the float32
+        # tile, its exponential and the bfloat16 probabilities 10 MiB, six
+        # heads' max, sum and accumulator 9, q and the context twice 6, k
+        # and v twice 1, a selection's tile twice 2
+        assert pallas_attention.step_vmem_bytes(
+            6, 128, 128, 2, 1024, 1024) == (10 + 9 + 6 + 1 + 2) << 20
+
+    @pytest.mark.parametrize("block_q, block_k, sub", [
+        (1024, 1024, 256), (512, 512, 128), (256, 256, 128), (128, 128, 0),
+        (1024, 512, 0), (512, 1024, 0), (16, 16, 0), (384, 384, 0)])
+    def test_the_sub_tiles_of_a_tile_on_the_diagonal(self, block_q, block_k,
+                                                     sub):
+        """``diagonal_sub``: quarters of a square block where a quarter is
+        whole lane blocks, else halves; the whole masked tile for every
+        other pair."""
+        assert pallas_attention.diagonal_sub(block_q, block_k) == sub
+
     def test_heads_that_share_a_step_are_the_heads_alone(self, monkeypatch):
         """A step of two heads of a group of four and a step of one give
         the same context to the last bit: the heads of a step are unrolled
@@ -796,17 +849,18 @@ class TestTheBand:
                 q, k, v, 4, 2, 0.3, block_q, block_k, window=window), q, k, v)
             return call.params["grid_mapping"].grid
 
-        assert grid(None) == (4, 8, 8)
-        assert grid(32) == (4, 8, 5)      # the cell's: a band of 4 blocks
-        assert grid(20) == (4, 8, 4)
-        assert grid(3) == grid(8) == (4, 8, 2)
-        assert grid(1) == (4, 8, 1)       # a query's own key: the diagonal
-        assert grid(64) == grid(1000) == (4, 8, 8)
-        assert grid(24, 16, 8) == (4, 4, 5)
-        assert grid(24, 8, 16) == (4, 8, 3)
+        # 4 query heads over 2 key heads: a group's two heads a grid step
+        assert grid(None) == (2, 8, 8)
+        assert grid(32) == (2, 8, 5)      # the cell's: a band of 4 blocks
+        assert grid(20) == (2, 8, 4)
+        assert grid(3) == grid(8) == (2, 8, 2)
+        assert grid(1) == (2, 8, 1)       # a query's own key: the diagonal
+        assert grid(64) == grid(1000) == (2, 8, 8)
+        assert grid(24, 16, 8) == (2, 4, 5)
+        assert grid(24, 8, 16) == (2, 8, 3)
         # no blocks given: the sequence is the block (the interpreter's),
         # a band of 16 no whole 128-row blocks: ONE step of a key axis
-        assert grid(16, None, None) == (4, 1, 1)
+        assert grid(16, None, None) == (2, 1, 1)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_the_core_takes_the_kernel_where_the_band_spans_a_block(
@@ -866,6 +920,164 @@ class TestTheBand:
             atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
         assert traced(192, True)[0] == traced(192, False)[0]
         assert traced(128, True, True)[0] == traced(128, False, True)[0]
+
+
+# (case, query heads, key heads, head width, positions, block, heads a step
+# (None: the rule's), sub-tile width (None: the rule's), band, keys selected)
+STEPS = [
+    # a key-value group's heads in ONE grid step, ``h`` from the rule
+    ("group of 1", 4, 4, HD, 64, 16, None, None, None, None),
+    ("group of 2", 4, 2, HD, 64, 16, None, None, None, None),
+    ("group of 6", 6, 1, HD, 64, 16, None, None, None, None),
+    ("group of 7", 14, 2, HD, 64, 16, None, None, None, None),
+    ("group of 8", 16, 2, HD, 64, 16, None, None, None, None),
+    # a divisor of the group
+    ("half a group", 6, 1, HD, 64, 16, 3, 0, None, None),
+    ("a third of a group", 12, 2, HD, 64, 16, 2, 0, None, None),
+    # a selection: later query blocks select nothing in their first key
+    # blocks (three keys a query of 64)
+    ("selected", 8, 2, HD, 64, 16, None, None, None, 3),
+    ("selected, sub-tiles", 8, 2, HD, 64, 16, 4, 8, None, 5),
+    # a band of a block and more: the key axis follows it, the edge's fold
+    ("a band of a block", 6, 1, HD, 96, 16, None, None, 16, None),
+    ("a band of two and a half", 14, 2, HD, 96, 16, None, None, 40, None),
+    ("a band, sub-tiles", 6, 1, HD, 96, 16, 6, 8, 40, None),
+    # heads of two lane blocks (here two ``HD``)
+    ("wide heads", 8, 1, 2 * HD, 64, 16, None, None, None, None),
+    ("wide heads, sub-tiles", 8, 1, 2 * HD, 64, 32, 4, 16, None, None),
+    # only the visible sub-tiles of a tile the diagonal crosses: at the
+    # lanes' own width and at the shipped one (quarters of the block, or
+    # halves where a quarter is no whole lane block)
+    ("sub-tiles of 128", 2, 1, HD, 512, 256, None, 128, None, None),
+    ("sub-tiles of 256 in two", 2, 2, HD, 512, 512, 1, 256, None, None),
+    ("the shipped sub-tiles", 2, 1, HD, 512, 256, None, None, None, None),
+    ("the shipped quarters", 2, 2, HD, 512, 512, None, None, None, None),
+    ("halves, one head", 4, 4, HD, 64, 32, 1, 16, None, None),
+    ("eighths", 6, 2, HD, 64, 32, 3, 4, None, None),
+    # unequal blocks: the whole masked tile
+    ("unequal blocks", 6, 1, HD, 64, (16, 32), None, None, None, None),
+    ("unequal blocks, the other way", 6, 1, HD, 64, (32, 16), 6, None, None,
+     None),
+]
+
+
+class TestTheHeadsOfAStep:
+    """A grid step of the causal kernel holds several query heads of a
+    key-value group and multiplies, in a tile the diagonal crosses, only the
+    sub-tiles that hold a visible pair: against
+    the float32 masked softmax, and the one-head step to the last bit
+    where no sub-tile is cut."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize(
+        "nq, nkv, head, t, block, heads, sub, window, topk",
+        [case[1:] for case in STEPS], ids=[case[0] for case in STEPS])
+    def test_a_step_of_several_heads_is_the_masked_softmax(
+            self, nq, nkv, head, t, block, heads, sub, window, topk, dtype):
+        q, k, v = _qkv(t, nq, nkv, dtype, seed=7, head=head)
+        chosen = _selection(t, topk, seed=2)
+        block_q, block_k = block if isinstance(block, tuple) else (block,
+                                                                   block)
+
+        def call(**form):
+            return _f32(causal_attention(
+                q, k, v, selected=chosen, num_heads=nq, num_kv_heads=nkv,
+                head_dim=head, scale=0.3, block_q=block_q, block_k=block_k,
+                window=window, interpret=True, **form))
+
+        got = call(heads_a_step=heads, sub=sub)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, _f32(_plain(
+            q, k, v, nq, nkv, 0.3, selected=chosen, window=window,
+            head=head)),
+            atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
+        whole = call(heads_a_step=1, sub=0)
+        if pallas_attention.diagonal_sub(block_q, block_k) == 0 and not sub:
+            # the heads of a step share its key and value blocks and its
+            # masks and nothing else: a head's context to the last bit
+            np.testing.assert_array_equal(got, whole)
+        else:
+            # a diagonal tile's float32 partial sums in another order
+            np.testing.assert_allclose(got, whole, atol=(
+                F32_TOL if dtype == jnp.float32 else BF16_TOL))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("form", ["beside", "shared", "pair"])
+    def test_heads_the_rule_leaves_one_a_step(self, form, dtype):
+        """Values beside their keys, a shared score term, heads in pairs:
+        one head a step whatever the group, and a call that asks for more
+        is refused."""
+        from pallas_costs import pallas_calls
+
+        t, nh = 32, 4
+        q, k, v, qs, ks = _parts(t, nh, HD, 4, HD, dtype, seed=5)
+        args, kw = {
+            "beside": ((q, jnp.concatenate([
+                k.reshape(t, nh, HD), v.reshape(t, nh, HD)],
+                -1).reshape(t, -1), None), dict(num_kv_heads=nh)),
+            "shared": ((q, k, v, qs, ks), dict(num_kv_heads=nh)),
+            "pair": ((q, k[:, :2 * HD], v[:, :2 * HD]),
+                     dict(num_kv_heads=4, paired=True, head_dim=HD // 2,
+                          num_heads=8)),
+        }[form]
+        kw = dict(dict(num_heads=nh, head_dim=HD, value_dim=HD, scale=0.3,
+                       block_q=16, block_k=16, interpret=True), **kw)
+        call, = pallas_calls(lambda *a: causal_attention(*a, **kw), *args)
+        assert call.params["grid_mapping"].grid[0] == kw["num_heads"]
+        with pytest.raises(ValueError, match="heads a step"):
+            causal_attention(*args, heads_a_step=2, **kw)
+        # the diagonal's sub-tiles are theirs too (the rule's quarters at
+        # the cells' blocks): the whole masked tile's context, to the order of
+        # the tile's float32 partial sums
+        np.testing.assert_allclose(
+            _f32(causal_attention(*args, sub=8, **kw)),
+            _f32(causal_attention(*args, sub=0, **kw)),
+            atol=F32_TOL if dtype == jnp.float32 else BF16_TOL)
+
+    def test_the_heads_of_a_step_are_a_loop(self):
+        """Eight heads of two key heads, two or four a step: the fold's body is
+        traced ONCE for the step's heads (a ``scan`` over them at dynamic
+        lane offsets), so the body grows by the loop and not by the heads,
+        and the grid's first axis is the steps."""
+        from pallas_costs import pallas_calls, primitive_names
+
+        q, k, v = _qkv(64, 8, 2, jnp.float32)
+
+        def body(heads):
+            call, = pallas_calls(lambda q, k, v: causal_attention(
+                q, k, v, num_heads=8, num_kv_heads=2, head_dim=HD,
+                scale=0.3, block_q=16, block_k=16, interpret=True,
+                heads_a_step=heads), q, k, v)
+            assert call.params["grid_mapping"].grid == (8 // heads, 4, 4)
+            # several heads' scratch, q and context: more than the default
+            # 16 MiB of scoped VMEM at the cells' blocks
+            assert call.params["compiler_params"][
+                "mosaic_tpu"].vmem_limit_bytes == (
+                    None if heads == 1 else pallas_attention.STEP_VMEM_LIMIT)
+            return primitive_names(call.params["jaxpr"])
+
+        one, two, four = body(1), body(2), body(4)
+        # one loop a fold: the tile on the diagonal's and the tiles' below
+        assert "scan" not in one and four.count("scan") == 2
+        assert four.count("dot_general") == one.count("dot_general")
+        # the trip count tells two heads a step from four, and the divide
+        # at a query block's end, which is written out a head
+        assert two.count("scan") == 2 and (
+            two.count("dot_general") == four.count("dot_general"))
+        assert four.count("div") - two.count("div") == 2
+
+    @pytest.mark.parametrize("case, match", [
+        (dict(heads_a_step=3), "heads a step"),
+        (dict(heads_a_step=8), "heads a step"),
+        (dict(sub=16), "sub-tiles"),
+        (dict(sub=5), "sub-tiles"),
+        (dict(sub=8, block_k=32), "sub-tiles")])
+    def test_a_steps_form_is_validated(self, case, match):
+        q, k, v = _qkv(64, 8, 2, jnp.float32)
+        kw = dict(dict(num_heads=8, num_kv_heads=2, head_dim=HD, scale=0.3,
+                       block_q=16, block_k=16, interpret=True), **case)
+        with pytest.raises(ValueError, match=match):
+            causal_attention(q, k, v, **kw)
 
 
 class TestTheCallersScale:
@@ -1069,7 +1281,18 @@ class TestTheRule:
         assert attention_facts(scope, widths, kv_heads,
                                tuple(windows.items())) == {
             "attention_form": form, "attention_form_why": reason,
-            "attention_form_by_kind": by_kind}
+            "attention_form_by_kind": by_kind,
+            # no query heads stated: nothing said of a step
+            "attention_heads_a_step": None}
+        # the heads ONE grid step holds, by the kinds in the kernel form:
+        # smallthinker's 28 over 4, phi4's 40 in pairs (one a step),
+        # laguna's 64 under the narrow band and 48 in the full layers
+        heads = {4: 28, 20: 40, 8: (64, 48)}[kv_heads]
+        assert attention_facts(scope, widths, kv_heads, tuple(
+            windows.items()), heads)["attention_heads_a_step"] == (
+            None if platform == "cpu" else {
+                4: "window:7,global:7", 20: "full_kv:1,cross:1",
+                8: "sliding:8,full:6"}[kv_heads])
 
     def test_published_shapes_are_what_the_rows_say(self):
         ouro, granite = loop_tiny.published(), lm_tiny.published()
@@ -1170,6 +1393,15 @@ class TestThroughTheShardedEngine:
         assert kern.obs.counters.snapshot()["attention_form"] == "kernel"
         assert kern.obs.counters.snapshot()["attention_form_by_kind"] == (
             "causal:kernel")
+        # the heads ONE grid step holds, in the gauges and the manifest: a
+        # group's two of the looped and the hybrid model's 4 over 2, one of
+        # the latent heads (values beside their keys, a shared part)
+        steps = "causal:1" if policy is MoELM else "causal:2"
+        assert kern.run_manifest()["config"][
+            "attention_heads_a_step"] == steps
+        assert kern.obs.counters.snapshot()["attention_heads_a_step"] == steps
+        assert ref.run_manifest()["config"]["attention_heads_a_step"] is None
+        assert "attention_heads_a_step" not in ref.obs.counters.snapshot()
         programs = [str(jax.make_jaxpr(es.engine._generation_step)(
             es.state, es.table.data)) for es in (ref, kern)]
         assert ["pallas_call" in text for text in programs] == [False, True]
@@ -1322,7 +1554,8 @@ class TestTheDeclaredCost:
         one of the whole sequence) the ``pallas_call`` has the operands,
         the grid, the index maps, the body and the declared cost it had
         before the kernel learned a band (the literals are PR 48's tree's,
-        for these operands); a banded call's body differs."""
+        for these operands, ONE head a grid step as every call had then);
+        a banded call's body differs."""
         import hashlib
 
         from pallas_costs import pallas_calls, primitive_names
@@ -1332,8 +1565,8 @@ class TestTheDeclaredCost:
         def facts(window):
             call, = pallas_calls(lambda q, k, v: causal_attention(
                 q, k, v, num_heads=4, num_kv_heads=2, head_dim=8, scale=0.25,
-                block_q=16, block_k=8, interpret=True, window=window),
-                q, k, v)
+                block_q=16, block_k=8, interpret=True, window=window,
+                heads_a_step=1), q, k, v)
             mapping, cost = call.params["grid_mapping"], call.params[
                 "cost_estimate"]
             body = primitive_names(call.params["jaxpr"])
@@ -1353,6 +1586,118 @@ class TestTheDeclaredCost:
         assert banded[:2] == (parents[0], (4, 2, 4))
         # a third fold, for the tiles the band's edge crosses
         assert banded[3] > parents[3] and banded[4] != parents[4]
+
+    @pytest.mark.parametrize("form", ["latent", "pair", "no groups"])
+    def test_a_call_the_rule_leaves_at_one_head_traces_the_parents_kernel(
+            self, form):
+        """Heads with their values beside their keys and a shared part,
+        heads in pairs, heads without groups under blocks the diagonal's
+        sub-tiles do not cut: the rule leaves them ONE head a grid step and the
+        whole masked tile, and the ``pallas_call`` is the one PR 56's tree
+        traced for these operands, operand for operand: its operands, grid,
+        index maps, body (the primitives in program order) and declared cost
+        (the literals are that tree's)."""
+        import hashlib
+
+        from pallas_costs import pallas_calls, primitive_names
+
+        z = jnp.zeros
+        kw = dict(head_dim=8, scale=0.25, block_q=16, block_k=16,
+                  interpret=True)
+        f, args, parents = {
+            "latent": (
+                lambda q, k, qs, ks: causal_attention(
+                    q, k, None, qs, ks, num_heads=4, num_kv_heads=4,
+                    value_dim=8, **kw),
+                (z((32, 32)), z((32, 64)), z((32, 16)), z((32, 4))),
+                (["float32[32,32]", "float32[32,64]", "float32[32,64]",
+                  "float32[32,16]", "float32[32,16]"], (4, 2, 2),
+                 [0, 29, 30, 12, 29, 0], 120, "1e055dc094a4d15c",
+                 (122880, 3264, 18944))),
+            "pair": (
+                lambda q, k, v: causal_attention(
+                    q, k, v, num_heads=8, num_kv_heads=4, value_dim=8,
+                    paired=True, **{**kw, "head_dim": 4}),
+                (z((32, 32)), z((32, 16)), z((32, 16))),
+                (["float32[32,32]", "float32[32,32]", "float32[32,16]"],
+                 (8, 2, 2), [27, 28, 28, 0], 112, "ec3f9130b925a7d5",
+                 (147456, 6528, 16384))),
+            "no groups": (
+                lambda q, k, v: causal_attention(
+                    q, k, v, num_heads=4, num_kv_heads=4, **kw),
+                (z((32, 32)), z((32, 32)), z((32, 32))),
+                (["float32[32,32]", "float32[32,32]", "float32[32,32]"],
+                 (4, 2, 2), [0, 28, 28, 0], 112, "ec3f9130b925a7d5",
+                 (98304, 3264, 16384))),
+        }[form]
+        call, = pallas_calls(f, *args)
+        mapping, cost = call.params["grid_mapping"], call.params[
+            "cost_estimate"]
+        body = primitive_names(call.params["jaxpr"])
+        assert ([str(x.aval) for x in call.invars], mapping.grid,
+                [len(primitive_names(m.index_map_jaxpr.jaxpr))
+                 for m in mapping.block_mappings], len(body),
+                hashlib.sha256(",".join(body).encode()).hexdigest()[:16],
+                (cost.flops, cost.transcendentals,
+                 cost.bytes_accessed)) == parents
+        # and asks Mosaic for no more VMEM than it did
+        assert call.params["compiler_params"][
+            "mosaic_tpu"].vmem_limit_bytes is None
+
+    @pytest.mark.parametrize("length, block, sub, window", [
+        (64, 16, 8, None), (64, 32, 8, None), (64, 32, 16, None),
+        (96, 32, 16, 40), (64, 16, 4, 32), (32, 32, 8, None)])
+    def test_the_diagonals_sub_tiles_counted_sub_tile_by_sub_tile(
+            self, length, block, sub, window):
+        """Under ``sub`` the tile on the diagonal, one a query block,
+        declares the sub-tiles at or below the diagonal alone, products and
+        exponentials; every other tile whole; the bytes as before."""
+        heads, kv_heads, hd, vd, itemsize = 4, 2, 8, 16, 2
+        scores = 0
+        for i in range(length // block):
+            for j in range(length // block):
+                seen = j * block <= (i + 1) * block - 1 and (
+                    window is None
+                    or (j + 1) * block - 1 >= i * block - window + 1)
+                if seen and i == j:
+                    n = block // sub
+                    scores += sum(sub * sub for a in range(n)
+                                  for c in range(n) if c <= a)
+                elif seen:
+                    scores += block * block
+        cost = pallas_attention.attention_cost(
+            length, heads, kv_heads, hd, vd, 0, block, block, itemsize,
+            window=window, sub=sub)
+        whole = pallas_attention.attention_cost(
+            length, heads, kv_heads, hd, vd, 0, block, block, itemsize,
+            window=window)
+        tiles = sum(pallas_attention._band_blocks(length, block, block,
+                                                  window))
+        assert cost.flops == heads * 2 * scores * (hd + vd) < whole.flops
+        assert cost.transcendentals == heads * (scores + tiles * block)
+        assert cost.bytes_accessed == whole.bytes_accessed
+
+    def test_a_step_of_a_groups_heads_declares_what_one_head_a_step_did(self):
+        """The grid's first axis is steps, not heads: the declaration is
+        the call's all the same (heads x tiles), and with the diagonal's
+        quarters it is 130 tiles' worth of laguna's 136 a head."""
+        from pallas_costs import declared_costs
+
+        q, k, v = _qkv(64, 6, 1, jnp.float32)
+
+        def call(heads, sub):
+            return declared_costs(lambda q, k, v: causal_attention(
+                q, k, v, num_heads=6, num_kv_heads=1, head_dim=HD,
+                scale=0.3, block_q=16, block_k=16, interpret=True,
+                heads_a_step=heads, sub=sub), q, k, v)[0]
+
+        assert call(6, 0) == call(1, 0) == pallas_attention.attention_cost(
+            64, 6, 1, HD, HD, 0, 16, 16, 4)
+        assert call(6, 8) == pallas_attention.attention_cost(
+            64, 6, 1, HD, HD, 0, 16, 16, 4, sub=8)
+        full = pallas_attention.attention_cost(
+            16384, 48, 8, 128, 128, 0, 1024, 1024, 2, sub=256)
+        assert full.flops == 48 * 2 * 130 * 1024 * 1024 * 256
 
     def test_ten_tiles_of_sixteen_over_the_exact_triangle(self):
         # the cells' geometry: 4,096 positions in blocks of 1,024

@@ -128,6 +128,9 @@ def test_manifest_gauges_and_sizes_are_the_parents(name, devices8):
     # XLA form on these CPU meshes, nothing without a linear layer
     delta = "xla" if delta_facts in kernels else None
     assert config.pop("delta_form") == delta
+    # stated since PR 57, when a step of the causal kernel learned to hold
+    # several heads: no kind of layer is in the kernel on these CPU meshes
+    assert config.pop("attention_heads_a_step") is None
     assert sorted(config) == sorted(want["config"])
     assert config == want["config"]
     # since PR 56: the model's own rules, then the general four
@@ -155,6 +158,9 @@ def test_the_declarations_rules_and_kernels_moved_nothing(name, devices8):
     config = es.run_manifest()["config"]
     rules = config.pop("partition_rules", None)
     assert set(MANIFEST_BUILD_FACTS) <= set(config)
+    # stated since PR 57 (the heads a grid step of the causal kernel holds,
+    # by layer kind): nothing where no kind is in the kernel, and no gauge
+    assert config.pop("attention_heads_a_step") is None
     assert config == want["config"]
     assert es.obs.counters.snapshot() == want["gauges"]
     assert sized(es.engine) == want["sized"]
@@ -167,20 +173,22 @@ def test_the_declarations_rules_and_kernels_moved_nothing(name, devices8):
 
 # what each model states at its tiny sizes: the kernels it calls with the
 # widths it calls them with (``(rule, widths)``; the attention's widths are
-# ``(head widths, key heads, ((layer kind, band), ...))``), and the fields
-# the engine's chunk rule and the records read
+# ``(head widths, key heads, ((layer kind, band), ...), query heads)``: one
+# count, or one a kind), and the fields the engine's chunk rule and the
+# records read
 STATED = {
     "hybrid": dict(
         partition_rules=hybrid_lm.PARTITION_RULES,
-        kernels=((attention_facts, (8, 2)), (head_facts, (32,)))),
+        kernels=((attention_facts, (8, 2, None, 4)), (head_facts, (32,)))),
     "looped": dict(
         partition_rules=looped_lm.PARTITION_RULES,
-        kernels=((attention_facts, (8, 2)), (head_facts, (32,))),
+        kernels=((attention_facts, (8, 2, None, 4)), (head_facts, (32,))),
         leaf_rows={"head/kernel": 8},
         facts={"loop_steps": 4, "layer_applications_per_token": 8}),
     "moe": dict(
         partition_rules=moe_lm.PARTITION_RULES,
-        kernels=((attention_facts, ((8, 4, 6),)), (head_facts, (32,)),
+        kernels=((attention_facts, ((8, 4, 6), None, None, 4)),
+                 (head_facts, (32,)),
                  (combine_facts, (32,))),
         leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
@@ -189,14 +197,14 @@ STATED = {
         partition_rules=sambay_lm.PARTITION_RULES,
         kernels=((attention_facts, (
             (4, 0, 8), 4,
-            (("window", 5), ("full_kv", None), ("cross", None)))),
+            (("window", 5), ("full_kv", None), ("cross", None)), 8)),
             (head_facts, (32,)), (scan_facts, (64, 4))),
         facts={"layer_kinds": "mamba,window,mamba_mem,full_kv,gmu,cross",
                "window": 5, "scan_chunk": 4, "kv_shared_by": 1,
                "memory_shared_by": 1}),
     "indexed_moe": dict(
         partition_rules=indexed_moe_lm.PARTITION_RULES,
-        kernels=((attention_facts, (8, 2, (("selected", None),))),
+        kernels=((attention_facts, (8, 2, (("selected", None),), 4)),
                  (head_facts, (32,)), (combine_facts, (32,))),
         leaf_rows={"head/kernel": 8},
         outputs=("expert_load", "selected_pairs"),
@@ -208,7 +216,7 @@ STATED = {
     # leaves; the head-mixing convolution's stack sees every position
     "cca_moe": dict(
         partition_rules=cca_moe_lm.PARTITION_RULES,
-        kernels=((attention_facts, (8, 2)), (head_facts, (32,)),
+        kernels=((attention_facts, (8, 2, None, 8)), (head_facts, (32,)),
                  (combine_facts, (32,))),
         outputs=("expert_load",),
         leaf_rows_per_token=lambda lm: dict.fromkeys(lm.expert_leaves,
@@ -220,7 +228,8 @@ STATED = {
     # taken ahead of attention, which the declaration need not say
     "window_moe": dict(
         partition_rules=window_moe_lm.PARTITION_RULES,
-        kernels=((attention_facts, (8, 2, (("window", 6), ("global", None)))),
+        kernels=((attention_facts, (8, 2, (("window", 6), ("global", None)),
+                                    6)),
                  (head_facts, (32,)), (combine_facts, (32,))),
         leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
@@ -230,7 +239,7 @@ STATED = {
     # the delta rule's chunk and the form its inverse takes are facts
     "delta_moe": dict(
         partition_rules=delta_moe_lm.PARTITION_RULES,
-        kernels=((attention_facts, (16, 2)), (head_facts, (32,)),
+        kernels=((attention_facts, (16, 2, None, 4)), (head_facts, (32,)),
                  (combine_facts, (32,)), (delta_facts, (8, 8, 8))),
         leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
@@ -244,7 +253,8 @@ STATED = {
     # share; each kind's heads are facts
     "gated_window_moe": dict(
         partition_rules=gated_window_moe_lm.PARTITION_RULES,
-        kernels=((attention_facts, (8, 2, (("sliding", 6), ("full", None)))),
+        kernels=((attention_facts, (8, 2, (("sliding", 6), ("full", None)),
+                                    (6, 4))),
                  (head_facts, (32,)), (combine_facts, (32,))),
         leaf_rows={"head/kernel": 8}, outputs=("expert_load",),
         facts={"experts_held": 4, "experts_total": 16,
@@ -293,7 +303,7 @@ def test_a_module_that_states_nothing_gets_the_defaults(devices8):
     assert not set(engine.build_facts()) & set(kernel_facts.FACT_NAMES)
     # the manifest has every kernel's names all the same, each ``None``
     config = es.run_manifest()["config"]
-    assert [config[k] for k in kernel_facts.FACT_NAMES] == [None] * 8
+    assert [config[k] for k in kernel_facts.FACT_NAMES] == [None] * 9
     # and its rules are the general ones alone
     assert engine.partition_rules == DEFAULT_PARTITION_RULES
 

@@ -801,8 +801,8 @@ class TestThroughTheShardedEngine:
                         agent_kwargs={"env": TokenScoreEnv(**loop_tiny.ENV)})
         assert es.obs.counters.get("layer_kinds", None) is None
         assert "kv_shared_by" not in es.run_manifest()["config"]
-        assert len(dict(es.module.declaration().kernels)[
-            attention_facts]) == 2          # no kinds stated: one, no band
+        assert dict(es.module.declaration().kernels)[
+            attention_facts][2] is None     # no kinds stated: one, no band
         assert es.engine.kernel_facts["attention_form_by_kind"] == "causal:xla"
         # no scan stated: no form, no gauge, null in the manifest
         assert "scan_form" not in es.engine.kernel_facts

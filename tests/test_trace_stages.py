@@ -1385,6 +1385,8 @@ def test_kernel_form_books_the_selected_attention_by_its_kind(v5e_chip):
     assert engine.kernel_facts["attention_form_why"] == (
         "one TPU device, whole column blocks, whole row blocks")
     assert engine.kernel_facts["attention_form_by_kind"] == "selected:kernel"
+    assert engine.kernel_facts["attention_heads_a_step"].startswith(
+        "selected:")
     state = jax.tree_util.tree_map(
         lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
         es.state, engine.state_shardings)
@@ -1604,6 +1606,52 @@ def test_attention_kernel_compiles_for_the_v5e_at_heads_of_256(dtype,
     assert " copy(" not in text.split("ENTRY")[1]
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("cell, length, heads, kv_heads", [
+    ("laguna", 16384, 48, 8), ("zaya1", 8192, 8, 2)])
+def test_attention_kernel_compiles_for_the_v5e_a_groups_heads_a_step(
+        cell, length, heads, kv_heads, dtype, v5e_chip):
+    """Mosaic accepts the causal kernel in the form :func:`step_heads` and
+    :func:`diagonal_sub` give it at two cells' PUBLISHED shapes that no
+    other case compiles: the full layers of ``laguna-xs2-es-16k-1chip`` (48
+    query heads over 8 key-value heads of 128, groups of SIX, 16,384
+    positions) and ``zaya1-es-8k-1chip``'s (8 over 2, groups of four, 8,192
+    positions), one member at a time; the step's heads, their running
+    max, sum and accumulator and the float32 tile fit the VMEM the call asks
+    for (the compile is the check: a refusal is a tier-1 failure, not a chip
+    minute); nothing else in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from estorch_tpu.ops.pallas_attention import (STEP_VMEM_LIMIT,
+                                                  causal_attention,
+                                                  key_block, kernel_block,
+                                                  step_heads,
+                                                  step_vmem_bytes)
+
+    def operand(width):
+        return jax.ShapeDtypeStruct(
+            (1, 1, length, width), dtype,
+            sharding=SingleDeviceSharding(v5e_chip))
+
+    itemsize, group = jnp.dtype(dtype).itemsize, heads // kv_heads
+    block = kernel_block(length)
+    took = step_heads(group, 128, 128, itemsize, block,
+                      key_block(block, 128, itemsize))
+    assert took > 1 and heads % took == 0
+    assert step_vmem_bytes(took, 128, 128, itemsize, block,
+                           block) <= STEP_VMEM_LIMIT
+    compiled = jax.jit(jax.vmap(jax.vmap(lambda q, k, v: causal_attention(
+        q, k, v, num_heads=heads, num_kv_heads=kv_heads, head_dim=128,
+        scale=128 ** -0.5, interpret=False)))).lower(
+            operand(heads * 128), operand(kv_heads * 128),
+            operand(kv_heads * 128)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " copy(" not in text.split("ENTRY")[1]
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("window, by_kind, parts", [
     (512, "window:kernel,global:kernel", ["global", "window", "window"]),
     (128, "window:kernel,global:kernel", ["global", "window", "window"]),
@@ -1656,6 +1704,12 @@ def test_kernel_form_books_each_kind_of_layer_by_its_part(v5e_chip, window,
     assert (engine.kernel_facts["attention_form"],
             engine.kernel_facts["head_form"]) == ("kernel", "kernel")
     assert engine.kernel_facts["attention_form_by_kind"] == by_kind
+    # a group's SEVEN heads share a grid step in every kernel kind's calls:
+    # the causal grid's, the band's of one block and the band as the block
+    assert engine.kernel_facts["attention_heads_a_step"] == (
+        "global:7" if window == 192 else "window:7,global:7")
+    assert engine.build_facts()["attention_heads_a_step"] == (
+        engine.kernel_facts["attention_heads_a_step"])
     assert engine.kernel_facts["attention_form_why"].endswith(
         f"layers with a window of {window} in the "
         + ("XLA form" if window == 192 else "kernel"))

@@ -703,7 +703,7 @@ def test_the_declaration(tiny):
     # band; the head at the hidden width
     kernels = dict(stated.kernels)
     assert kernels[attention_facts] == (
-        8, 2, (("window", 6), ("global", None)))
+        8, 2, (("window", 6), ("global", None)), 6)
     assert kernels[head_facts] == (32,)
     assert stated.leaf_rows == {"head/kernel": 8}
     assert stated.leaf_rows_per_token == dict.fromkeys(
@@ -781,9 +781,9 @@ def test_published_sizes_and_layouts(ref):
     assert cfg["horizon"] == cfg["max_position_embeddings"] == 16384
     stated = lm.declaration()
     kernels = dict(stated.kernels)
-    widths, kv_heads, windows = kernels[attention_facts]
-    assert (widths, kernels[head_facts], kv_heads, windows) == (
-        128, (2560,), 4, (("window", 4096), ("global", None)))
+    widths, kv_heads, windows, query_heads = kernels[attention_facts]
+    assert (widths, kernels[head_facts], kv_heads, windows, query_heads) == (
+        128, (2560,), 4, (("window", 4096), ("global", None)), 28)
     assert stated.leaf_rows_per_token == dict.fromkeys(
         lm.stacked_leaves, 6 * 1.25 / 4)
     # 28 query heads over 4 key heads of 128 at 16,384: whole column
